@@ -35,33 +35,4 @@ import jax as _jax
 # stay float32 (see ops.dtype.default_float), so TPU hot paths are unaffected.
 _jax.config.update("jax_enable_x64", True)
 
-# The parallel layer targets the stable ``jax.shard_map`` API (with its
-# ``check_vma`` kwarg).  Older jax releases only ship
-# ``jax.experimental.shard_map.shard_map`` (kwarg named ``check_rep``):
-# adapt once here so ring attention, the GPipe schedule and explicit-EP
-# MoE run on both.
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def _shard_map_compat(f, mesh, in_specs, out_specs, **kw):
-        kw.pop("check_vma", None)
-        # the old replication checker cannot express the new vma types
-        # (scan carries marked varying via lax.pcast) — disable it; the
-        # new-jax path keeps full checking
-        kw["check_rep"] = False
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-# Same vintage skew for Pallas: newer code says ``pltpu.CompilerParams``,
-# older releases only have ``TPUCompilerParams`` (same fields).  One
-# alias site here covers every kernel module (ops/pallas_fused, ring).
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-    if not hasattr(_pltpu, "CompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover - pallas unavailable on this backend
-    pass
-
 from deeplearning4j_tpu.ops import Nd4j, NDArray, DataType  # noqa: F401

@@ -11,3 +11,5 @@ from deeplearning4j_tpu.compile.aotcache import (  # noqa: F401
     AotCache, AotDispatch, aot_cache, set_aot_cache, device_fingerprint,
     model_digest, plan_digest, preload_model, version_fingerprint,
     wrap_jit, wrap_serving_model)
+from deeplearning4j_tpu.compile.jaxcache import (  # noqa: F401
+    DEFAULT_CACHE_DIR, enable_compile_cache)

@@ -254,18 +254,26 @@ def _pack_executable(compiled) -> Dict[str, Any]:
             "in_skel": jax.tree_util.tree_unflatten(
                 in_tree, list(range(in_tree.num_leaves))),
             "out_skel": jax.tree_util.tree_unflatten(
-                out_tree, list(range(out_tree.num_leaves)))}
+                out_tree, list(range(out_tree.num_leaves))),
+            # the devices it was compiled for, in assignment order:
+            # loading onto anything else (the default is EVERY device of
+            # the backend) breaks a one-device executable on a
+            # multi-device host
+            "device_ids": [d.id for d in
+                           compiled.runtime_executable().local_devices()]}
 
 
 def _unpack_executable(exe: Dict[str, Any]):
     import jax
     from jax.experimental import serialize_executable
-    if "in_skel" not in exe:        # entry from a pre-skeleton build
+    if "in_skel" not in exe or "device_ids" not in exe:
         raise ValueError("legacy executable entry format")
     in_tree = jax.tree_util.tree_structure(exe["in_skel"])
     out_tree = jax.tree_util.tree_structure(exe["out_skel"])
+    byId = {d.id: d for d in jax.devices()}
     return serialize_executable.deserialize_and_load(
-        exe["payload"], in_tree, out_tree)
+        exe["payload"], in_tree, out_tree,
+        execution_devices=[byId[i] for i in exe["device_ids"]])
 
 
 # ---------------------------------------------------------------------------

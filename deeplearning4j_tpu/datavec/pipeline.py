@@ -1,15 +1,18 @@
 """Sharded multi-process input pipeline with double-buffered async H2D.
 
-The streaming gap this closes (ROADMAP item 2, BENCH_r05): the
-device-resident pipeline sustains 2382 images/sec while the real streaming
-path feeds 47 — the chip starves the moment data doesn't already live on
-device.  Two serial bottlenecks cause it: Python decode/augment runs on
+The streaming gap this closes: a device-resident pipeline keeps the chip
+fed while a single-process streaming path feeds it a small fraction of
+that — the chip starves the moment data doesn't already live on device
+(today's ratio on the chip: not measured).  Two serial bottlenecks cause
+it: Python decode/augment runs on
 one GIL, and every batch's host->device copy blocks the step that needs
 it.  This module splits both out of the training loop:
 
 1. **Producer pool** — ``numWorkers`` OS processes (``multiprocessing``,
-   fork by default so the decode code needs no re-import), each handed a
-   deterministic :class:`ShardSpec`.  The record source shards per
+   fork by default so the decode code needs no re-import; the parent may
+   hold the chip, which belongs to one process, so workers stay
+   numpy-only — ``chip_smoke.py`` runs exactly this on the TPU), each
+   handed a deterministic :class:`ShardSpec`.  The record source shards per
    worker — per-host first (the ``SharedTrainingMaster`` /
    ``jax.process_index()`` convention, the per-host data sharding of
    Spark DataVec in the source paper), then per-worker within the host —

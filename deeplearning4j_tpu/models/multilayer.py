@@ -605,8 +605,8 @@ class MultiLayerNetwork:
             # values; no stale buffer survives the in-place refresh
             self.state_.update(new_state)
         # Keep the loss as an async device scalar: syncing it here would
-        # serialize every step on a host round-trip (fatal over a TPU
-        # tunnel).  score() materializes it lazily on demand.
+        # serialize every step on a host round-trip.  score()
+        # materializes it lazily on demand.
         self._scoreArr = loss
         if panic_enabled():
             # NAN_PANIC/INF_PANIC (reference: profilingConfigurableHookOut)

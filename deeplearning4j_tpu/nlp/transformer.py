@@ -70,25 +70,31 @@ class TransformerLM:
             return jnp.asarray(
                 (rng.randn(*shape) * c.initializerRange).astype(np.float32))
 
+        # float32 spelled out on every leaf: the package enables x64, so
+        # a dtype-less jnp.ones/zeros is float64 and promotes everything
+        # after the first LayerNorm (a TPU has no f64 unit)
+        f32 = jnp.float32
         p = {"emb": init(c.vocabSize, H), "pos": init(c.maxLen, H),
-             "lnf_g": jnp.ones((H,)), "lnf_b": jnp.zeros((H,)),
+             "lnf_g": jnp.ones((H,), f32), "lnf_b": jnp.zeros((H,), f32),
              "layers": []}
         for _ in range(c.nLayers):
             p["layers"].append({
-                "ln1_g": jnp.ones((H,)), "ln1_b": jnp.zeros((H,)),
+                "ln1_g": jnp.ones((H,), f32), "ln1_b": jnp.zeros((H,), f32),
                 "Wq": init(H, H), "Wk": init(H, H), "Wv": init(H, H),
                 "Wo": init(H, H),
-                "ln2_g": jnp.ones((H,)), "ln2_b": jnp.zeros((H,)),
-                "Wi": init(H, F), "bi": jnp.zeros((F,)),
-                "Wp": init(F, H), "bp": jnp.zeros((H,))})
+                "ln2_g": jnp.ones((H,), f32), "ln2_b": jnp.zeros((H,), f32),
+                "Wi": init(H, F), "bi": jnp.zeros((F,), f32),
+                "Wp": init(F, H), "bp": jnp.zeros((H,), f32)})
         return p
 
     # ------------------------------------------------------------------
     @staticmethod
     def _ln(x, g, b):
-        mu = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * g + b
+        # variance spelled out: jnp.var lowers with a scalar f64 NaN
+        # constant under x64, and the serving executables carry no f64
+        xc = x - jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+        return xc * jax.lax.rsqrt(var + 1e-5) * g + b
 
     def _heads(self, y):
         b, t, _ = y.shape
@@ -452,13 +458,14 @@ class TransformerLM:
         ff = jax.nn.gelu(jnp.matmul(h, lp["Wi"]) + lp["bi"])
         return x + jnp.matmul(ff, lp["Wp"]) + lp["bp"], poolK, poolV
 
-    def _paged_step_math(self, params, poolK, poolV, toks, pageTable,
-                         pos, start):
+    def pagedLogits(self, params, poolK, poolV, toks, pageTable, pos,
+                    start):
         """toks (S, tq) against the stacked pools (L, pages, h, ps, d):
-        returns ((S, tq) greedy tokens, pools).  Position-embedding ids
-        are clipped so a speculative over-write past ``maxLen`` (tokens
-        that will be discarded by the accept rule) can't index out of
-        the table."""
+        returns ((S, tq, vocab) logits, pools) — what the paged decode
+        step takes its arg-max of, and what a parity check compares with
+        :meth:`forward`.  Position-embedding ids are clipped so a
+        speculative over-write past ``maxLen`` (tokens that will be
+        discarded by the accept rule) can't index out of the table."""
         tq = toks.shape[1]
         pos_ids = jnp.clip(
             (pos - start)[:, None] + jnp.arange(tq, dtype=jnp.int32),
@@ -469,9 +476,14 @@ class TransformerLM:
                                           pageTable, pos, start)
             poolK = poolK.at[li].set(pk)
             poolV = poolV.at[li].set(pv)
-        greedy = jnp.argmax(self._logits(params, x),
-                            axis=-1).astype(jnp.int32)
-        return greedy, poolK, poolV
+        return self._logits(params, x), poolK, poolV
+
+    def _paged_step_math(self, params, poolK, poolV, toks, pageTable,
+                         pos, start):
+        """:meth:`pagedLogits` reduced to (S, tq) greedy tokens."""
+        logits, poolK, poolV = self.pagedLogits(params, poolK, poolV, toks,
+                                                pageTable, pos, start)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), poolK, poolV
 
     def buildPagedDecodeFn(self):
         """FRESH jitted paged decode/verify step over a

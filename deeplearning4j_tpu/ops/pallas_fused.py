@@ -1,7 +1,7 @@
 """Pallas fused-epilogue kernels: matmul with batch-norm statistics.
 
-Motivation (PROFILE_r03.md): the ResNet-50 train step is HBM-bound, and
-BatchNorm's statistics passes account for ~21 GB/step of that traffic —
+Motivation: an earlier round profiled the ResNet-50 train step as
+HBM-bound, with BatchNorm's statistics passes ~21 GB/step of the traffic —
 XLA computes ``y = conv(x, w)`` (one full write of y), then reduces y
 again for the per-channel mean/variance (one full re-READ of y).  On TPU
 the conv/matmul is a fusion *boundary*, so XLA cannot sink the reduction
@@ -20,10 +20,11 @@ forward helper (cudnnBatchNormalizationForwardTraining fuses the same
 way on GPU — SURVEY §2.5); the TPU-native answer is a Pallas epilogue
 rather than a cuDNN call.
 
-Measured verdict on v5e (PROFILE_r04.md §1b): **negative** — XLA's
-matmul kernels beat this hand-tiled Pallas GEMM by 0.5–4 ms at ResNet
-conv-as-GEMM shapes, an order of magnitude more than the one-read-of-y
-the epilogue saves (0.03–0.5 ms).  The kernel stays in-tree as the
+Verdict measured on a v5e in an earlier round, on older code (today's
+code: not measured): **negative** — XLA's matmul kernels beat this
+hand-tiled Pallas GEMM by 0.5–4 ms at ResNet conv-as-GEMM shapes, an
+order of magnitude more than the one-read-of-y the epilogue saves
+(0.03–0.5 ms).  The kernel stays in-tree as the
 measured prototype and as the template for epilogue fusions where XLA
 has no fused primitive at all (cf. the flash-attention kernel in
 parallel/ring.py, which does win).  Do NOT wire this into the conv+BN
@@ -36,18 +37,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["matmul_bn_stats", "matmul_bn_stats_reference", "have_pallas"]
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas import is cheap; kernels only compile when called
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
-def have_pallas() -> bool:
-    return _HAVE_PALLAS
+__all__ = ["matmul_bn_stats", "matmul_bn_stats_reference"]
 
 
 def matmul_bn_stats_reference(x, w):
@@ -87,8 +80,6 @@ def matmul_bn_stats(x, w, block_m: int = 512, block_n: int = 128,
     Returns (y (M,N) x.dtype, sum (N,) f32, sumsq (N,) f32).
     Stats accumulate in f32 regardless of input dtype.
     """
-    if not _HAVE_PALLAS:
-        return matmul_bn_stats_reference(x, w)
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)

@@ -26,7 +26,11 @@ class RandomGenerator:
 
     def setSeed(self, seed: int) -> None:
         self._seed = int(seed)
-        self._key = jax.random.PRNGKey(int(seed) & 0xFFFFFFFFFFFFFFFF)
+        # made at the first draw: building a key initializes the backend,
+        # and importing the package (the module-level default generator)
+        # must not take the chip — it belongs to one process, and a
+        # spawned worker that merely imports would fight its parent for it
+        self._key = None
 
     def getSeed(self) -> int:
         return self._seed
@@ -34,6 +38,9 @@ class RandomGenerator:
     def split(self, n: int = 1):
         """Advance the counter and return ``n`` fresh subkeys (jit-safe input)."""
         with self._lock:
+            if self._key is None:
+                self._key = jax.random.PRNGKey(
+                    self._seed & 0xFFFFFFFFFFFFFFFF)
             keys = jax.random.split(self._key, n + 1)
             self._key = keys[0]
         return keys[1] if n == 1 else keys[1:]

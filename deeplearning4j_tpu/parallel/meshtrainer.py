@@ -460,6 +460,13 @@ class MeshTrainer:
         osh = self.plan.opt_shardings(net)
         if net.optState_ is not None and osh is not None:
             net.optState_ = jax.device_put(net.optState_, osh)
+        if getattr(net, "state_", None):
+            # aux layer state (BatchNorm running stats) is replicated, and
+            # committed here: left where init put it, the first step would
+            # compile for that placement and the second for the one the
+            # step's outputs came back with
+            net.state_ = jax.device_put(net.state_,
+                                        self.plan.mesh.replicated())
 
     # -- compilation ----------------------------------------------------
     def _install(self) -> None:
@@ -471,11 +478,12 @@ class MeshTrainer:
         psh = self.plan.param_shardings(net)
         osh = self.plan.opt_shardings(net)
         nargs = len(inspect.signature(net._stepFn).parameters)
+        rep = self.plan.mesh.replicated()
         in_sh = [None] * nargs
-        in_sh[0], in_sh[1] = psh, osh
+        in_sh[0], in_sh[1], in_sh[2] = psh, osh, rep
         jitted = jax.jit(net._stepFn, donate_argnums=(0, 1, 2),
                          in_shardings=tuple(in_sh),
-                         out_shardings=(psh, osh, None, None, None))
+                         out_shardings=(psh, osh, rep, None, None))
         # AOT cache (when configured): the sharded step dispatches
         # through the persistent executable cache, keyed on THIS plan's
         # digest + device set — so a boot (or post-remesh re-install)

@@ -32,13 +32,9 @@ __all__ = ["PipelineStack", "pipeline_apply"]
 
 
 def _varying(x, axis_name):
-    """Mark ``x`` varying over ``axis_name`` under the new shard_map
-    vma type system (``lax.pcast``); identity on jax releases with the
-    older check_rep system, which has no varying type at scan
-    boundaries to satisfy."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    return x
+    """Mark ``x`` varying over ``axis_name`` for shard_map's vma type
+    system, which checks scan carries at the loop boundary."""
+    return lax.pcast(x, axis_name, to="varying")
 
 
 def pipeline_apply(mesh, block_fn: Callable, stacked_params, x,

@@ -14,7 +14,8 @@ Three implementations of softmax(QKᵀ/√d)·V, one semantics:
   ring.
 - :func:`flash_attention` — Pallas TPU kernel (grid over (batch·heads,
   q-blocks, k-blocks), f32 accumulators in VMEM scratch); the single-chip hot
-  path.  Falls back to :func:`blockwise_attention` off-TPU.
+  path.  TPU only: it raises where it cannot run, and
+  :func:`dot_product_attention` is where another implementation is chosen.
 - :func:`ring_attention` — called under ``shard_map`` with Q/K/V sharded on
   the time dimension over a mesh axis: each step computes one local block
   update, then rotates K/V one hop around the ring with ``lax.ppermute``
@@ -31,6 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["blockwise_attention", "flash_attention", "ring_attention",
@@ -271,42 +274,47 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-try:  # pallas import is cheap; kernels only compile when called
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+_FLASH_BLOCK = 1024     # default (block_q, block_k) edge
+_FLASH_MIN_T = 1024     # "auto" prefers the XLA-fused dense chain below this
 
 
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 1024,
-                    block_k: int = 1024, interpret: bool = False):
+def _flash_refusal(tq: int, tk: int, block_q: int = _FLASH_BLOCK,
+                   block_k: int = _FLASH_BLOCK,
+                   interpret: bool = False) -> Optional[str]:
+    """Why the Pallas kernel cannot serve these lengths here, or None when
+    it can.  ``impl="auto"`` chooses with it; :func:`flash_attention`
+    raises with it."""
+    if tq % min(block_q, tq) or tk % min(block_k, tk):
+        return (f"sequence lengths ({tq}, {tk}) are not multiples of the "
+                f"({min(block_q, tq)}, {min(block_k, tk)}) blocks")
+    if not interpret and jax.default_backend() != "tpu":
+        return f"the platform is {jax.default_backend()!r}, not 'tpu'"
+    return None
+
+
+def flash_attention(q, k, v, causal: bool = False,
+                    block_q: int = _FLASH_BLOCK, block_k: int = _FLASH_BLOCK,
+                    interpret: bool = False):
     """Pallas TPU flash attention.  q/k/v: (b, h, t, d).
 
     Grid (b·h, q-blocks, k-blocks); the k dimension is sequential so the
-    online-softmax accumulators live in VMEM scratch across k steps.  Off
-    TPU (and not ``interpret``) falls back to :func:`blockwise_attention`.
-    1024-wide blocks measured fastest on v5e (5.7 ms vs 13.5 ms at 256²
-    for b=4 h=12 t=4096 d=64 causal bf16 — PROFILE_r05.md).
+    online-softmax accumulators live in VMEM scratch across k steps.
+    Raises ``ValueError`` off TPU or when a length is not a multiple of
+    its block — it never computes with another implementation
+    (``interpret`` runs the same kernels in the Pallas interpreter and is
+    for tests only).
 
     Differentiable with FLASH backward kernels: the forward also banks the
     per-row logsumexp; the backward recomputes p block-by-block in two
     Pallas passes (dk/dv with the q-axis sequential, dq with the k-axis
     sequential) — no O(T²) residuals are ever stored.
     """
-    on_tpu = any(d.platform == "tpu" for d in jax.devices())
-    if not _HAVE_PALLAS or (not on_tpu and not interpret):
-        return blockwise_attention(q, k, v, causal=causal,
-                                   block_k=min(block_k, 512))
-
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    if tq % block_q or tk % block_k:
-        return blockwise_attention(q, k, v, causal=causal,
-                                   block_k=min(block_k, 512))
-    return _flash(q, k, v, causal, block_q, block_k, interpret)
+    tq, tk = q.shape[2], k.shape[2]
+    why = _flash_refusal(tq, tk, block_q, block_k, interpret)
+    if why is not None:
+        raise ValueError(f"flash_attention cannot run: {why}")
+    return _flash(q, k, v, causal, min(block_q, tq), min(block_k, tk),
+                  interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -523,25 +531,26 @@ def dot_product_attention(qh, kh, vh, mask=None, causal: bool = False,
 
     impl: "dense" (materialised softmax — reference semantics,
     ``multi_head_dot_product_attention``), "blockwise", "flash", "ring"
-    (sequence-parallel over the active mesh's seq axis), or "auto"
-    (ring when a ParallelWrapper fit is compiling against a mesh with a
-    seq axis; flash on TPU for long sequences; dense otherwise — XLA
-    fuses the small case fine).
+    (sequence-parallel over the active mesh's seq axis), or "auto".
+    A named impl runs or raises; only "auto" chooses, from what it can
+    observe: ring when a ParallelWrapper fit is compiling against a mesh
+    with a seq axis; on TPU from ``_FLASH_MIN_T`` up, the flash kernel
+    for unmasked block-multiple lengths and blockwise for unmasked
+    others; dense otherwise (XLA fuses the small case fine, and dense
+    honors a key mask exactly).
     """
     if impl == "auto":
         from deeplearning4j_tpu.parallel.mesh import active_mesh
         am = active_mesh()
+        tq, tk = qh.shape[2], kh.shape[2]
         if am is not None and getattr(am, "seqSize", 1) > 1 \
-                and qh.shape[2] % am.seqSize == 0 \
-                and kh.shape[2] % am.seqSize == 0:
+                and tq % am.seqSize == 0 and tk % am.seqSize == 0:
             impl = "ring"
+        elif tq < _FLASH_MIN_T or mask is not None \
+                or jax.default_backend() != "tpu":
+            impl = "dense"
         else:
-            # The flash kernel does not take a key mask — masked batches
-            # route to blockwise/dense, which honor it exactly.
-            long_seq = qh.shape[2] >= 1024
-            on_tpu = any(d.platform == "tpu" for d in jax.devices())
-            impl = "flash" if (long_seq and on_tpu and mask is None) \
-                else "dense"
+            impl = "flash" if _flash_refusal(tq, tk) is None else "blockwise"
     if impl == "ring":
         from deeplearning4j_tpu.parallel.mesh import active_mesh
         am = active_mesh()
@@ -552,10 +561,13 @@ def dot_product_attention(qh, kh, vh, mask=None, causal: bool = False,
                                           causal=causal)
     if impl == "flash":
         if mask is not None:
-            return blockwise_attention(qh, kh, vh, mask=mask, causal=causal)
+            raise ValueError("impl='flash' cannot run: the kernel takes no "
+                             "key mask")
         return flash_attention(qh, kh, vh, causal=causal)
     if impl == "blockwise":
         return blockwise_attention(qh, kh, vh, mask=mask, causal=causal)
+    if impl != "dense":
+        raise ValueError(f"unknown attention impl {impl!r}")
     s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * _scale(qh)
     if mask is not None:
         s = jnp.where(mask.astype(bool)[:, None, None, :], s,

@@ -188,6 +188,10 @@ class ResNet50(ZooModel):
     executable, with batchnorm+relu fused into the conv epilogues by XLA.
     """
 
+    #: (bottleneck width, blocks, first stride) per stage; a subclass with
+    #: fewer blocks is the same builder at a fraction of the compile
+    stages = ((64, 3, 1), (128, 4, 2), (256, 6, 2), (512, 3, 2))
+
     def graphBuilder(self):
         gb = (NeuralNetConfiguration.builder().seed(self.seed)
               .updater(Nesterovs(1e-1, momentum=0.9)).weightInit("RELU")
@@ -229,8 +233,7 @@ class ResNet50(ZooModel):
                     .kernelSize(3, 3).stride(2, 2)
                     .convolutionMode(ConvolutionMode.Same).build(), x)
         x = "stem_pool"
-        stages = [(64, 3, 1), (128, 4, 2), (256, 6, 2), (512, 3, 2)]
-        for si, (nOut, reps, stride) in enumerate(stages):
+        for si, (nOut, reps, stride) in enumerate(self.stages):
             for r in range(reps):
                 x = bottleneck(f"res{si}_{r}", x, nOut,
                                stride if r == 0 else 1, r == 0)

@@ -3,8 +3,7 @@
 Shaped like dl4j-examples' zoo usage: instantiate from the zoo, feed an
 ImageNet-shaped pipeline, train.  Offline this generates synthetic
 ImageNet-shaped batches; point an ImageRecordReader at real data to swap in
-(see deeplearning4j_tpu.datavec).  bf16 mixed precision by default
-(~1300 images/sec/chip on v5e, `python bench.py`).
+(see deeplearning4j_tpu.datavec).  bf16 mixed precision by default.
 """
 import os as _os
 import sys as _sys

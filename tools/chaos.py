@@ -26,35 +26,6 @@ import sys
 import tempfile
 
 
-def _reexec_cpu(devices: int = 8) -> None:
-    """The soak needs ``devices`` virtual XLA host devices, configured
-    before jax initializes — same contract as ``bench.py --mesh``.  If
-    the environment isn't already set (or jax is already imported on
-    another platform), re-exec with the proxy env."""
-    import re
-    import subprocess
-    flags = os.environ.get("XLA_FLAGS", "")
-    want = f"--xla_force_host_platform_device_count={devices}"
-    # value-aware, not substring-presence: a pre-set SMALLER count
-    # would otherwise be accepted and the 4-device mesh construction
-    # would fail in a way that reads as a chaos finding
-    m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
-    enough = m is not None and int(m.group(1)) >= devices
-    if os.environ.get("_DL4J_CHAOS_CHILD") != "1" and (
-            not enough
-            or os.environ.get("JAX_PLATFORMS") != "cpu"
-            or "jax" in sys.modules):
-        if m and not enough:
-            flags = flags.replace(m.group(0), "").strip()
-        env = dict(os.environ,
-                   XLA_FLAGS=(flags + " " + want).strip(),
-                   JAX_PLATFORMS="cpu",
-                   _DL4J_CHAOS_CHILD="1")
-        out = subprocess.run([sys.executable, os.path.abspath(__file__)]
-                             + sys.argv[1:], env=env)
-        sys.exit(out.returncode)
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--seed", type=int, required=True,
@@ -81,7 +52,7 @@ def main(argv=None) -> int:
         # package import still pays for jax (fault/__init__ pulls the
         # supervisor chain), so pin the CPU platform first: the
         # schedule path must never claim an accelerator.
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
         if "jax" in sys.modules:
             import jax
             try:
@@ -95,7 +66,9 @@ def main(argv=None) -> int:
                          sort_keys=True))
         return 0
 
-    _reexec_cpu()
+    # the soak needs 8 virtual host devices, configured before jax loads
+    from tools.cpu_proxy import reexec_on_cpu_proxy
+    reexec_on_cpu_proxy(8, __file__, sys.argv[1:])
     from deeplearning4j_tpu.fault.chaos import ChaosSoak
     runDir = args.dir or tempfile.mkdtemp(prefix="dl4j_chaos_")
     cleanup = args.dir is None

@@ -4,9 +4,8 @@ baseline, reporters.
 The analyzer exists because this repo's worst bugs are *invisible in
 review*: a ``jax.jit`` of a fresh closure re-traces on every call (the
 warm-bucket serving tier exists precisely to avoid that), a stray
-``.item()`` on the step path stalls the chip on a host sync (the
-47 images/sec starvation of BENCH_r05), and a lock acquired in a
-different order on two paths deadlocks only under production load.
+``.item()`` on the step path stalls the chip on a host sync, and a
+lock acquired in a different order on two paths deadlocks only under production load.
 Compiler stacks make such invariants checkable properties of the program
 representation (Relay arXiv:1810.00952, nGraph arXiv:1801.08058); this
 module does the same for the Python/JAX layer so they gate tier-1

@@ -1,6 +1,6 @@
 """Hidden host↔device sync rule for declared hot-path modules.
 
-BENCH_r05's 47 images/sec streaming collapse was exactly this class of
+A streaming input path that starves the chip is exactly this class of
 bug: the device can only stay busy while the host keeps its distance,
 and every ``.item()`` / ``float(loss)`` / ``np.asarray(device_buf)`` on
 a hot path is a silent ``block_until_ready`` — the step (or the serving
