@@ -37,8 +37,8 @@ from deeplearning4j_tpu.nn.conf.layers import Layer
 from deeplearning4j_tpu.ops import NDArray
 from deeplearning4j_tpu.optimize.listeners import notifyListeners
 from deeplearning4j_tpu.profiler import check_panic, panic_enabled
-from deeplearning4j_tpu.telemetry import (etl_fetch, in_microbatch,
-                                          tracer, train_step_span)
+from deeplearning4j_tpu.telemetry import (etl_fetch, h2d_span,
+                                          in_microbatch, train_step_span)
 
 
 class ComputationGraph:
@@ -343,7 +343,7 @@ class ComputationGraph:
     def _fitBatch(self, ds) -> None:
         pb = self._place_batch
         fmask = None
-        with tracer().span("h2d"):
+        with h2d_span():
             if isinstance(ds, MultiDataSet):
                 inputs = tuple(pb(f.jax.astype(self._dtype))
                                for f in ds.features)

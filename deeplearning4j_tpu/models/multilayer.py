@@ -31,8 +31,8 @@ from deeplearning4j_tpu.nn.conf import (GradientNormalization,
 from deeplearning4j_tpu.ops import NDArray
 from deeplearning4j_tpu.optimize.listeners import notifyListeners
 from deeplearning4j_tpu.profiler import check_panic, panic_enabled
-from deeplearning4j_tpu.telemetry import (etl_fetch, in_microbatch,
-                                          tracer, train_step_span)
+from deeplearning4j_tpu.telemetry import (etl_fetch, h2d_span,
+                                          in_microbatch, train_step_span)
 
 Params = Dict[str, Dict[str, jax.Array]]
 
@@ -530,7 +530,7 @@ class MultiLayerNetwork:
 
     def _fitBatch(self, ds: DataSet) -> None:
         from deeplearning4j_tpu.nn.conf import BackpropType
-        with tracer().span("h2d"):
+        with h2d_span():
             x = self._place_batch(ds.features.jax.astype(self._dtype))
             y = self._place_batch(ds.labels.jax)
             fmask = self._place_batch(

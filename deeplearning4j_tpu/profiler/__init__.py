@@ -129,21 +129,16 @@ def panic_enabled() -> bool:
 
 def start_trace(log_dir: str) -> None:
     """XLA-level profiling via jax.profiler (kernel timings on the chip).
-    While active, every ``telemetry.tracer().span(...)`` also enters a
-    ``jax.profiler.TraceAnnotation`` so host spans line up with the
-    kernel timeline in the capture."""
+    Every ``telemetry.tracer().span(name)`` is a
+    ``jax.profiler.TraceAnnotation`` named ``dl4j.<name>``, so host spans
+    line up with the kernel timeline in this capture as in any other
+    ``jax.profiler`` session."""
     import jax
-
-    from deeplearning4j_tpu.telemetry import set_device_trace_active
     jax.profiler.start_trace(log_dir)
-    set_device_trace_active(True)
 
 
 def stop_trace() -> None:
     import jax
-
-    from deeplearning4j_tpu.telemetry import set_device_trace_active
-    set_device_trace_active(False)
     jax.profiler.stop_trace()
 
 

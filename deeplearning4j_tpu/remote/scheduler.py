@@ -46,6 +46,7 @@ traced constraints.
 """
 from __future__ import annotations
 
+import functools
 import queue as _stdqueue
 import random
 import threading
@@ -65,7 +66,8 @@ from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                DeadlineExceeded,
                                                NoHealthyReplicas,
                                                ServiceOverloaded)
-from deeplearning4j_tpu.telemetry import (RequestContext, ThresholdRule,
+from deeplearning4j_tpu.telemetry import (SERVING_LOOP_PHASES,
+                                          RequestContext, ThresholdRule,
                                           current_context, flight_recorder,
                                           observe_exemplar, serving_metrics,
                                           timeline_store, tracer)
@@ -353,6 +355,9 @@ class ContinuousBatcher:
         self._cacheSeen: Optional[int] = None
         self._busySteps = 0.0
         self._steps = 0
+        self._phaseObservers = {
+            p: functools.partial(self._observePhase, p)
+            for p in SERVING_LOOP_PHASES}
         if plan is not None:
             self.applyPlan(plan)            # shards params, builds pools
         else:
@@ -514,6 +519,7 @@ class ContinuousBatcher:
         sm.inter_token_seconds()
         sm.queue_wait_seconds()
         sm.prefill_seconds()
+        sm.loop_phase_seconds()
         self.warm()
         self._updatePageGauges()
         self._cacheSeen = self.compileCacheSize()
@@ -785,14 +791,38 @@ class ContinuousBatcher:
         return sum(p for _, p in log[1:]) / dt
 
     # -- scheduler loop -------------------------------------------------
+    def _phase(self, phase: str):
+        """One phase of the loop thread (``SERVING_LOOP_PHASES``): the
+        span ``serving.loop.<phase>``, its profiler annotation and one
+        observation of ``dl4j_tpu_serving_loop_phase_seconds``, all from
+        the same two clock reads.  Loop thread only; never entered while
+        ``_cv`` is held (scheduler -> registry lock order)."""
+        return tracer().span("serving.loop." + phase,
+                             observe=self._phaseObservers[phase])
+
+    def _observePhase(self, phase: str, seconds: float) -> None:
+        serving_metrics().loop_phase_seconds().observe(
+            seconds, model=self.name, phase=phase)
+
+    def _idle(self) -> bool:
+        return self._queuedRows == 0 and \
+            not any(s is not None for s in self._slotSeq)
+
     def _loop(self) -> None:
         while True:
             with self._cv:
-                while self._running and self._queuedRows == 0 and \
-                        not any(s is not None for s in self._slotSeq):
-                    self._cv.wait(0.1)
                 if not self._running:
                     return
+                idle = self._idle()
+            if idle:
+                # one slice of waiting per iteration, so that an idle
+                # stretch is on the record (and in a capture) while it
+                # lasts; the condition is looked at again under the lock,
+                # so an enqueue between the two looks is not slept through
+                with self._phase("wait"), self._cv:
+                    if self._running and self._idle():
+                        self._cv.wait(0.1)
+                continue
             try:
                 if _inj.replica_dead(self.name):
                     # a crashed replica's loop idles instead of serving:
@@ -807,9 +837,14 @@ class ContinuousBatcher:
                     # serving (fresh fns against the fresh buffers)
                     self.warm()
                     self._cacheSeen = self.compileCacheSize()
-                self._admit()
-                if any(s is not None for s in self._slotSeq):
-                    self._stepOnce()
+                # the parent of everything a busy iteration does: what a
+                # span costs between two phases is then inside a span too,
+                # so no instant of the loop thread is without a name
+                with tracer().span("serving.loop.iteration"):
+                    with self._phase("admit"):
+                        self._admit()
+                    if any(s is not None for s in self._slotSeq):
+                        self._stepOnce()
             except Exception as e:
                 # the scheduler thread must survive ANY dispatch failure
                 # (device OOM, a jit error): fail the affected work, not
@@ -927,38 +962,39 @@ class ContinuousBatcher:
         # through the model's restart hook — same executable + bucket as
         # a first admission, but the hook is the seam a survivor with
         # different numerics can override
-        prefillT0 = time.perf_counter()
         prefill = getattr(self.lm, "restartFromPrompt",
                           self.lm.prefillRaw) \
             if seq.restarts > 0 else self.lm.prefillRaw
-        logits, ks, vs = prefill(padded, lengths=[seq.realLen])
-        ids = jnp.asarray(self.pool.heldIds(slot)[:nP], jnp.int32)
-        self.pool.k, self.pool.v = self._stepFns["write"](
-            self.pool.k, self.pool.v, ks[:, 0], vs[:, 0], ids)
-        if self.draft is not None:
-            _l, dks, dvs = self.draft.prefillRaw(padded,
-                                                 lengths=[seq.realLen])
-            dids = jnp.asarray(self.draftPool.heldIds(slot)[:nP],
-                               jnp.int32)
-            self.draftPool.k, self.draftPool.v = self._stepFns["dwrite"](
-                self.draftPool.k, self.draftPool.v, dks[:, 0], dvs[:, 0],
-                dids)
-        # jaxlint: sync-ok -- the prefill's greedy token seeds the host-side slot state
-        first = int(np.argmax(np.asarray(logits[0])))
-        if seq.forced and len(seq.emitted) < len(seq.forced):
-            # teacher-forced replay: the first token was already
-            # computed (and maybe delivered) before the move — force it
-            # so the delivered prefix survives any cross-replica
-            # numeric drift, and so the KV the step writes next is
-            # conditioned on the prefix the client actually saw
-            first = int(seq.forced[0])
-        prefillDt = time.perf_counter() - prefillT0
-        observe_exemplar("dl4j_tpu_serving_prefill_seconds", prefillDt,
-                         trace_id=tid, model=self.name)
-        tracer().record_complete(
-            "serving.prefill", prefillT0, prefillDt,
-            args={"replica": self.name, "slot": slot, "bucket": Tp,
-                  "trace_id": tid})
+        span = tracer().span(
+            "serving.prefill",
+            observe=lambda dt: observe_exemplar(
+                "dl4j_tpu_serving_prefill_seconds", dt, trace_id=tid,
+                model=self.name),
+            replica=self.name, slot=slot, bucket=Tp, trace_id=tid)
+        with span:
+            logits, ks, vs = prefill(padded, lengths=[seq.realLen])
+            ids = jnp.asarray(self.pool.heldIds(slot)[:nP], jnp.int32)
+            self.pool.k, self.pool.v = self._stepFns["write"](
+                self.pool.k, self.pool.v, ks[:, 0], vs[:, 0], ids)
+            if self.draft is not None:
+                _l, dks, dvs = self.draft.prefillRaw(
+                    padded, lengths=[seq.realLen])
+                dids = jnp.asarray(self.draftPool.heldIds(slot)[:nP],
+                                   jnp.int32)
+                self.draftPool.k, self.draftPool.v = \
+                    self._stepFns["dwrite"](
+                        self.draftPool.k, self.draftPool.v, dks[:, 0],
+                        dvs[:, 0], dids)
+            # jaxlint: sync-ok -- the prefill's greedy token seeds the host-side slot state
+            first = int(np.argmax(np.asarray(logits[0])))
+            if seq.forced and len(seq.emitted) < len(seq.forced):
+                # teacher-forced replay: the first token was already
+                # computed (and maybe delivered) before the move — force
+                # it so the delivered prefix survives any cross-replica
+                # numeric drift, and so the KV the step writes next is
+                # conditioned on the prefix the client actually saw
+                first = int(seq.forced[0])
+        prefillDt = span.seconds
         self._slotSeq[slot] = seq
         self._pos[slot] = Tp
         self._start[slot] = Tp - seq.realLen
@@ -1020,20 +1056,103 @@ class ContinuousBatcher:
         return self.eosToken is not None and tok == self.eosToken
 
     def _stepOnce(self) -> None:
-        sm = serving_metrics()
         delay = _inj.replica_slowdown(self.name)
         if delay:
             time.sleep(delay)           # injected brownout (SlowReplica)
+        with tracer().span("serving.decode.step",
+                           replica=self.name) as stepArgs:
+            with self._phase("grow"):
+                active, deferred = self._growPages()
+            stepArgs["active"] = len(active)
+            if not active:
+                return
+            with self._phase("upload"):
+                if deferred:
+                    # mask deferred rows onto the scratch page with
+                    # zeroed state: the fixed-shape step still computes
+                    # them, but their writes land in scratch and their
+                    # REAL page tables / slot state stay untouched for
+                    # the next round
+                    ptH = self.pool.pageTable.copy()
+                    posH = self._pos.copy()
+                    startH = self._start.copy()
+                    tokH = self._tok.copy()
+                    for s in deferred:
+                        ptH[s, :] = 0
+                        posH[s] = startH[s] = tokH[s] = 0
+                else:
+                    ptH, posH, startH, tokH = (self.pool.pageTable,
+                                               self._pos, self._start,
+                                               self._tok)
+                pt = jnp.asarray(ptH)
+                pos = jnp.asarray(posH)
+                startA = jnp.asarray(startH)
+                if self.draft is not None:
+                    dptH = self.draftPool.pageTable
+                    if deferred:
+                        dptH = dptH.copy()
+                        for s in deferred:
+                            dptH[s, :] = 0
+                    tokA, dpt = jnp.asarray(tokH), jnp.asarray(dptH)
+                else:
+                    tokA, dpt = jnp.asarray(tokH[:, None]), None
+            step = self._stepFns["step"]
+            with self._phase("dispatch"):
+                if self.draft is not None:
+                    props, self.draftPool.k, self.draftPool.v = \
+                        self._stepFns["propose"](
+                            self.draft.params, self.draftPool.k,
+                            self.draftPool.v, tokA, dpt, pos, startA)
+                    # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
+                    propsH = np.asarray(props)
+                    verifyIn = np.concatenate([tokH[:, None], propsH],
+                                              axis=1)
+                    greedy, self.pool.k, self.pool.v = step(
+                        self.lm.params, self.pool.k, self.pool.v,
+                        jnp.asarray(verifyIn), pt, pos, startA)
+                else:
+                    props = propsH = None
+                    greedy, self.pool.k, self.pool.v = step(
+                        self.lm.params, self.pool.k, self.pool.v,
+                        tokA, pt, pos, startA)
+            with self._phase("fetch"):
+                # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
+                g = np.asarray(greedy)
+            with self._phase("emit"):
+                self._emitStep(active, g, propsH)
+            with self._phase("bookkeep"):
+                # the step's device arrays die here and not at the return,
+                # so that freeing them is inside a phase (tens of
+                # microseconds: the loop's time is to be accounted for)
+                del greedy, props, tokA, dpt, pt, pos, startA
+                sm = serving_metrics()
+                self._steps += 1
+                self._busySteps += len(active) / self.maxSlots
+                sm.decode_steps().inc(model=self.name)
+                sm.slot_occupancy().set(len(active) / self.maxSlots,
+                                        model=self.name)
+                after = self.compileCacheSize()
+                if self._cacheSeen is not None and after > self._cacheSeen:
+                    sm.compile_misses().inc(after - self._cacheSeen,
+                                            model=self.name)
+                    self._cacheSeen = after
+                else:
+                    sm.compile_hits().inc(model=self.name)
+
+    def _growPages(self) -> Tuple[List[int], set]:
+        """Between steps: retire what ran out of time, then give every
+        slot the pages its next step writes; returns the slots that step
+        and those deferred a round."""
         now = time.monotonic()
         for s, seq in enumerate(self._slotSeq):
             # deadline sweep BETWEEN steps: an expired sequence's pages
             # go back to the free list before the next dispatch
             if seq is not None and seq.deadline is not None and \
                     now >= seq.deadline:
-                sm.deadline_sheds().inc(model=self.name, stage="decode")
+                serving_metrics().deadline_sheds().inc(model=self.name,
+                                                       stage="decode")
                 self._retireSlot(s, error=DeadlineExceeded(
                     "end-to-end deadline expired mid-decode"))
-        stepT0 = time.perf_counter()
         tq = self.draftK + 1 if self.draft is not None else 1
         # page growth in ADMISSION-AGE order: a slot may only preempt
         # YOUNGER slots, and when none are left it DEFERS one step
@@ -1058,50 +1177,12 @@ class ContinuousBatcher:
                 self._preempt(victim)
         active = [i for i, s in enumerate(self._slotSeq)
                   if s is not None and i not in deferred]
-        if not active:
-            return
-        if deferred:
-            # mask deferred rows onto the scratch page with zeroed
-            # state: the fixed-shape step still computes them, but their
-            # writes land in scratch and their REAL page tables / slot
-            # state stay untouched for the next round
-            ptH = self.pool.pageTable.copy()
-            posH = self._pos.copy()
-            startH = self._start.copy()
-            tokH = self._tok.copy()
-            for s in deferred:
-                ptH[s, :] = 0
-                posH[s] = startH[s] = tokH[s] = 0
-        else:
-            ptH, posH, startH, tokH = (self.pool.pageTable, self._pos,
-                                       self._start, self._tok)
-        pt = jnp.asarray(ptH)
-        pos = jnp.asarray(posH)
-        startA = jnp.asarray(startH)
-        step = self._stepFns["step"]
-        if self.draft is not None:
-            dptH = self.draftPool.pageTable
-            if deferred:
-                dptH = dptH.copy()
-                for s in deferred:
-                    dptH[s, :] = 0
-            props, self.draftPool.k, self.draftPool.v = \
-                self._stepFns["propose"](
-                    self.draft.params, self.draftPool.k, self.draftPool.v,
-                    jnp.asarray(tokH), jnp.asarray(dptH), pos, startA)
-            # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
-            propsH = np.asarray(props)
-            verifyIn = np.concatenate([tokH[:, None], propsH], axis=1)
-            greedy, self.pool.k, self.pool.v = step(
-                self.lm.params, self.pool.k, self.pool.v,
-                jnp.asarray(verifyIn), pt, pos, startA)
-        else:
-            propsH = None
-            greedy, self.pool.k, self.pool.v = step(
-                self.lm.params, self.pool.k, self.pool.v,
-                jnp.asarray(tokH[:, None]), pt, pos, startA)
-        # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
-        g = np.asarray(greedy)
+        return active, deferred
+
+    def _emitStep(self, active: List[int], g, propsH) -> None:
+        """Deliver one step's tokens, slot by slot: accept rule, emission,
+        slot state, timeline note, retirement."""
+        sm = serving_metrics()
         for s in active:
             seq = self._slotSeq[s]
             if seq is None:
@@ -1143,21 +1224,6 @@ class ContinuousBatcher:
                 tokens=len(seq.emitted))
             if done:
                 self._retireSlot(s)
-        self._steps += 1
-        self._busySteps += len(active) / self.maxSlots
-        tracer().record_complete(
-            "serving.decode.step", stepT0, time.perf_counter() - stepT0,
-            args={"replica": self.name, "active": len(active)})
-        sm.decode_steps().inc(model=self.name)
-        sm.slot_occupancy().set(len(active) / self.maxSlots,
-                                model=self.name)
-        after = self.compileCacheSize()
-        if self._cacheSeen is not None and after > self._cacheSeen:
-            sm.compile_misses().inc(after - self._cacheSeen,
-                                    model=self.name)
-            self._cacheSeen = after
-        else:
-            sm.compile_hits().inc(model=self.name)
 
     def _preempt(self, slot: int) -> None:
         """Evict the youngest slot to free pages: release everything it

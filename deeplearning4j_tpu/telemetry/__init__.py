@@ -9,7 +9,8 @@ One place every layer reports through (SURVEY.md §5.1's ``OpProfiler`` /
   ``/metrics`` on both ``remote.JsonModelServer`` and ``ui.UIServer``.
 - :mod:`.tracing` — nested ``span(name, **attrs)`` contexts merged with
   the ``OpProfiler`` Chrome-trace events into ONE trace file;
-  ``jax.profiler.TraceAnnotation`` attach when a device trace is active.
+  every span is also a ``jax.profiler.TraceAnnotation`` (``dl4j.<name>``)
+  in any profiler capture.
 - :mod:`.flight` — ring buffer of the last N step records, dumped to JSON
   on ``InvalidStepException``/divergence/crash (``CrashReportingUtil``
   analogue).
@@ -44,10 +45,11 @@ from deeplearning4j_tpu.telemetry.health import (  # noqa: F401
     ReplicaStragglerRule, ThresholdRule, TrainingStallRule, default_rules,
     health_summary, recsys_hash_collision_rule)
 from deeplearning4j_tpu.telemetry.instrument import (  # noqa: F401
-    STEP_PHASES, AotCacheMetrics, CoordMetrics, ElasticMetrics, EtlMetrics,
+    SERVING_LOOP_PHASES, STEP_PHASES, AotCacheMetrics, CoordMetrics, ElasticMetrics, EtlMetrics,
     MeshMetrics, RecsysMetrics, ReplicaTimingListener, ServingMetrics,
     StepPhaseMetrics, aot_metrics, clear_exemplars, coord_metrics,
-    elastic_metrics, etl_fetch, etl_metrics, exemplar_for, in_microbatch,
+    elastic_metrics, etl_fetch, etl_metrics, exemplar_for, h2d_span,
+    in_microbatch,
     latency_exemplars, mesh_metrics, microbatch_scope, note_etl_wait,
     observe_exemplar, observe_step_phase, record_crash, record_logical_step,
     recsys_metrics, replica_step_gauge, serving_metrics, step_phase_metrics,
@@ -64,5 +66,4 @@ from deeplearning4j_tpu.telemetry.registry import (  # noqa: F401
 from deeplearning4j_tpu.telemetry.timeseries import (  # noqa: F401
     MetricsRetention, ensure_retention, retention, set_retention)
 from deeplearning4j_tpu.telemetry.tracing import (  # noqa: F401
-    Tracer, device_trace_active, set_device_trace_active, set_tracer,
-    tracer)
+    Tracer, set_tracer, tracer)

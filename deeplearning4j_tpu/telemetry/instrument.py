@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import bisect
 import contextlib
+import functools
 import threading
 import time
 from typing import Optional, Sequence
@@ -41,7 +42,8 @@ __all__ = ["train_step_span", "record_crash", "etl_fetch", "note_etl_wait",
            "AotCacheMetrics", "aot_metrics", "replica_step_gauge",
            "observe_exemplar", "exemplar_for", "latency_exemplars",
            "clear_exemplars", "STEP_PHASES", "StepPhaseMetrics",
-           "step_phase_metrics", "observe_step_phase"]
+           "step_phase_metrics", "observe_step_phase", "h2d_span",
+           "SERVING_LOOP_PHASES"]
 
 # set while a fault supervisor owns the step: a step-level
 # InvalidStepException/panic is then a RECOVERABLE divergence (the
@@ -283,6 +285,19 @@ SERVING_LATENCY_BUCKETS = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0)
 
+#: the decode loop's host phases run from ten microseconds (bookkeep) to
+#: one device step (fetch): finer at the low end than any latency above
+SERVING_LOOP_PHASE_BUCKETS = (
+    0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
+    0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0)
+
+#: what the continuous batcher's loop thread is doing, in loop order
+#: (``remote/scheduler.py``): every phase is a span
+#: ``serving.loop.<phase>`` and one observation of
+#: ``dl4j_tpu_serving_loop_phase_seconds`` from the same two clock reads
+SERVING_LOOP_PHASES = ("wait", "admit", "grow", "upload", "dispatch",
+                       "fetch", "emit", "bookkeep")
+
 #: a ladder warm-up spans "every bucket loads from the AOT cache" (ms)
 #: to "a deep generative ladder compiles from scratch" (minutes) —
 #: DEFAULT_BUCKETS can't resolve both ends
@@ -521,6 +536,22 @@ class ServingMetrics:
             "+ first-token argmax) inside slot admission, per model",
             labelnames=("model",), buckets=SERVING_LATENCY_BUCKETS)
 
+    def loop_phase_seconds(self):
+        return get_registry().histogram(
+            "dl4j_tpu_serving_loop_phase_seconds",
+            "Host wall time of the continuous batcher's loop thread, by "
+            "phase: wait (idle, nothing queued, at most 0.1 s a slice), "
+            "admit (queue head to slot: prefill, pool write, first "
+            "token), grow (deadline sweep, page growth, preemption), "
+            "upload (host slot state to device arrays), dispatch (the "
+            "step executable's call until it returns), fetch (the device "
+            "step and its tokens' D2H, as the host waits for them), emit "
+            "(accept rule, delivery, timeline, retire), bookkeep "
+            "(counters, gauges, compile-cache size); one observation a "
+            "phase a loop iteration, per model",
+            labelnames=("model", "phase"),
+            buckets=SERVING_LOOP_PHASE_BUCKETS)
+
 
 _SERVING_METRICS = ServingMetrics()
 
@@ -593,9 +624,11 @@ def clear_exemplars():
 
 
 #: The five seams one logical train step decomposes into — instrumented
-#: at etl_fetch (data_wait), the prefetcher's staged-batch materialize
-#: (h2d), the fused-step dispatch (compute), the supervisor's sealed save
-#: (checkpoint) and the pod barrier (barrier).
+#: at etl_fetch (data_wait), the prefetcher's staged-batch materialize and
+#: ``_fitBatch``'s placement / re-shard (h2d), the host wall around the
+#: ASYNCHRONOUS dispatch of the jitted step (compute: enqueue time, not
+#: the device's compute, which the host does not wait for), the
+#: supervisor's sealed save (checkpoint) and the pod barrier (barrier).
 STEP_PHASES = ("data_wait", "h2d", "compute", "checkpoint", "barrier")
 
 
@@ -622,14 +655,18 @@ class StepPhaseMetrics:
     def h2d_seconds(self):
         return get_registry().histogram(
             "dl4j_tpu_step_h2d_seconds",
-            "Step time staging batches host-to-device (issue + "
-            "materialize wait)", buckets=DEFAULT_BUCKETS)
+            "Step time staging batches host-to-device: the prefetcher's "
+            "issue + materialize wait, and the fit loop's placement or "
+            "re-shard of the batch before the step is enqueued",
+            buckets=DEFAULT_BUCKETS)
 
     def compute_seconds(self):
         return get_registry().histogram(
             "dl4j_tpu_step_compute_seconds",
-            "Step time in the fused-step dispatch (host wall around the "
-            "jitted call)", buckets=DEFAULT_BUCKETS)
+            "Host wall time around the asynchronous dispatch of the "
+            "jitted fused step (enqueue; the device's compute is not "
+            "waited for, so this is NOT device time)",
+            buckets=DEFAULT_BUCKETS)
 
     def checkpoint_seconds(self):
         return get_registry().histogram(
@@ -690,6 +727,14 @@ def observe_step_phase(phase: str, seconds: float,
     else:
         raise ValueError(f"unknown step phase {phase!r}; "
                          f"expected one of {STEP_PHASES}")
+
+
+def h2d_span():
+    """``_fitBatch``'s placement of one batch (host-to-device, or the
+    re-shard over a mesh) as the ``h2d`` phase: span, profiler annotation
+    and ``dl4j_tpu_step_h2d_seconds`` from the same two clock reads."""
+    return tracer().span(
+        "h2d", observe=functools.partial(observe_step_phase, "h2d"))
 
 
 class MeshMetrics:
@@ -1044,24 +1089,27 @@ def etl_fetch(iterator):
     visible as ``dl4j_tpu_etl_stall_seconds`` regardless of which loop
     drives it — including async iterators whose blocking happens in
     ``hasNext`` (handed over via :func:`note_etl_wait`)."""
-    reg = get_registry()
     pending = getattr(iterator, "_telemetry_pending_wait", 0.0)
     if pending:
         iterator._telemetry_pending_wait = 0.0
-    t0 = time.perf_counter()
-    ds = iterator.next()
-    dt = (time.perf_counter() - t0) + pending
-    # start is backdated over the hasNext wait so the trace slice spans
-    # the whole time the loop stood still for data
-    tracer().record_complete("etl", t0 - pending, dt)
-    observe_step_phase("data_wait", dt)
-    reg.gauge("dl4j_tpu_etl_stall_seconds",
-              "Host wall time the train loop spent waiting on the last "
-              "batch fetch (async prefetch waits included)").set(dt)
-    reg.counter("dl4j_tpu_etl_stall_seconds_total",
-                "Cumulative seconds the train loop waited on batch "
-                "fetches").inc(dt)
-    return ds
+
+    def observe(seconds: float) -> None:
+        # the hasNext wait handed over is part of the time the loop stood
+        # still for data: in the histogram and gauges, and in the span's
+        # args (the span itself is the real time inside next())
+        dt = seconds + pending
+        reg = get_registry()
+        observe_step_phase("data_wait", dt)
+        reg.gauge("dl4j_tpu_etl_stall_seconds",
+                  "Host wall time the train loop spent waiting on the "
+                  "last batch fetch (async prefetch waits included)").set(dt)
+        reg.counter("dl4j_tpu_etl_stall_seconds_total",
+                    "Cumulative seconds the train loop waited on batch "
+                    "fetches").inc(dt)
+
+    with tracer().span("etl", observe=observe,
+                       waited_before_s=round(pending, 6)):
+        return iterator.next()
 
 
 def replica_step_gauge():

@@ -9,39 +9,75 @@ merges them with the :class:`~deeplearning4j_tpu.profiler.OpProfiler`
 singleton's events into ONE file (load it at ``chrome://tracing`` or
 Perfetto).
 
-When a device trace is active (``profiler.start_trace``), each span also
-enters a ``jax.profiler.TraceAnnotation`` so the host span shows up
-aligned with the XLA kernel timeline in the TensorBoard/XPlane capture.
+Every span also enters a ``jax.profiler.TraceAnnotation`` named
+``"dl4j." + name`` for its real duration, so the host span sits on the
+profiler's clock beside the XLA kernel timeline in ANY capture —
+``jax.profiler.start_trace``, ``start_server``, or this package's
+``profiler.start_trace``.  Outside a profiler session the annotation is a
+no-op in the runtime (well under a microsecond), so there is no switch.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import threading
 import time
 from collections import deque
-from typing import Deque, List, Optional
+from typing import Callable, Deque, List, Optional
 
-__all__ = ["Tracer", "tracer", "set_tracer", "device_trace_active",
-           "set_device_trace_active"]
+from jax.profiler import TraceAnnotation
 
-# flipped by profiler.start_trace/stop_trace (module owns the flag so the
-# two modules don't import-cycle: profiler -> telemetry only)
-_device_trace_active = False
+__all__ = ["Tracer", "tracer", "set_tracer"]
 
-
-def device_trace_active() -> bool:
-    return _device_trace_active
-
-
-def set_device_trace_active(active: bool) -> None:
-    global _device_trace_active
-    _device_trace_active = bool(active)
+#: what a span's name is prefixed with on the profiler's host plane — a
+#: trace reduction picks the program's own spans out by it
+ANNOTATION_PREFIX = "dl4j."
 
 
 class _ThreadTrack(threading.local):
     def __init__(self):
         self.depth = 0
+
+
+class _Span:
+    """One entered region of :meth:`Tracer.span` (a plain context manager:
+    the decode loop enters nine of these per token, and a generator-based
+    one costs several times as much)."""
+
+    __slots__ = ("_tracer", "_name", "_observe", "_attrs", "_start",
+                 "_depth", "_id", "_ann", "seconds")
+
+    def __init__(self, tr: "Tracer", name: str, observe, attrs: dict):
+        self._tracer, self._name = tr, name
+        self._observe, self._attrs = observe, attrs
+        #: the region's duration, once it has been left
+        self.seconds: Optional[float] = None
+
+    def __enter__(self) -> dict:
+        tr = self._tracer
+        self._start = start = time.perf_counter()
+        self._ann = ann = TraceAnnotation(ANNOTATION_PREFIX + self._name)
+        ann.__enter__()
+        tr._track.depth += 1
+        self._depth = depth = tr._track.depth
+        with tr._lock:
+            tr._next_span_id += 1
+            self._id = tr._next_span_id
+            tr._live[self._id] = {"name": self._name, "start": start,
+                                  "depth": depth, "attrs": self._attrs}
+        return self._attrs
+
+    def __exit__(self, *_exc) -> None:
+        tr = self._tracer
+        tr._track.depth -= 1
+        with tr._lock:
+            tr._live.pop(self._id, None)
+        self._ann.__exit__(None, None, None)
+        self.seconds = seconds = time.perf_counter() - self._start
+        self._attrs["depth"] = self._depth
+        tr.record_complete(self._name, self._start, seconds,
+                           args=self._attrs)
+        if self._observe is not None:
+            self._observe(seconds)
 
 
 class Tracer:
@@ -61,40 +97,16 @@ class Tracer:
         self._next_span_id = 0
 
     # -- spans ------------------------------------------------------------
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        """Time a nested region.  Yields a dict the body may add attrs to;
-        everything lands in the Chrome event's ``args``."""
-        self._track.depth += 1
-        depth = self._track.depth
-        start = time.perf_counter()
-        live_attrs = dict(attrs)
-        with self._lock:
-            self._next_span_id += 1
-            span_id = self._next_span_id
-            self._live[span_id] = {"name": name, "start": start,
-                                   "depth": depth, "attrs": live_attrs}
-        ann = None
-        if _device_trace_active:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
-        try:
-            yield live_attrs
-        finally:
-            if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
-            self._track.depth -= 1
-            with self._lock:
-                self._live.pop(span_id, None)
-            self.record_complete(name, start, time.perf_counter() - start,
-                                 args=dict(live_attrs, depth=depth))
+    def span(self, name: str, observe: Optional[Callable] = None, **attrs):
+        """Time a nested region: ``with tracer().span(name) as args``.
+        The body may add to ``args``; everything lands in the Chrome
+        event's ``args``.  Entering reads the clock once and leaving reads
+        it once, and from those two reads come the Chrome event, the
+        profiler annotation ``"dl4j." + name`` and — where the phase has
+        a histogram — its one observation, ``observe(seconds)``: the
+        three cannot disagree.  A body that raises still closes all
+        three."""
+        return _Span(self, name, observe, attrs)
 
     def record_complete(self, name: str, start: float, duration: float,
                         args: Optional[dict] = None,

@@ -1,0 +1,362 @@
+"""Phase spans on the profiler's clock (ISSUE 24).
+
+One seam (``Tracer.span``): the Chrome-trace event, the profiler
+annotation ``dl4j.<name>`` and the phase's histogram observation come
+from the same two clock reads.  Covers: the program's spans inside a
+capture started by ``jax.profiler.start_trace`` directly (no wrapper of
+the program's), the decode loop's phases (one observation each a step,
+together covering the loop thread's time), the idle loop, ``h2d``
+observed in every fit path, the Chrome trace's nesting, and a phase left
+by an exception.
+"""
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu import telemetry
+from deeplearning4j_tpu.datasets import DataSet, ListDataSetIterator
+from deeplearning4j_tpu.learning import Adam
+from deeplearning4j_tpu.models import MultiLayerNetwork
+from deeplearning4j_tpu.nlp.transformer import TransformerLM
+from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+from deeplearning4j_tpu.parallel import DeviceMesh, ParallelWrapper
+from deeplearning4j_tpu.remote import ContinuousBatcher
+from deeplearning4j_tpu.telemetry import (SERVING_LOOP_PHASES,
+                                          MetricsRegistry, RequestContext,
+                                          Tracer, etl_fetch, get_registry,
+                                          request_context, set_tracer,
+                                          tracer)
+
+pytestmark = pytest.mark.telemetry
+
+STEP_PHASES = ("grow", "upload", "dispatch", "fetch", "emit", "bookkeep")
+LOOP_HIST = "dl4j_tpu_serving_loop_phase_seconds"
+
+
+@pytest.fixture(autouse=True)
+def fresh_telemetry():
+    prev_reg = telemetry.set_registry(MetricsRegistry())
+    prev_tr = set_tracer(Tracer())
+    yield
+    set_tracer(prev_tr)
+    telemetry.set_registry(prev_reg)
+
+
+def _lm(layers=1, seed=5, vocab=40, heads=2, headSize=8):
+    return TransformerLM(vocabSize=vocab, nLayers=layers, nHeads=heads,
+                         headSize=headSize, maxLen=64, seed=seed)
+
+
+def _mlp():
+    conf = (NeuralNetConfiguration.builder().seed(3).updater(Adam(0.01))
+            .list()
+            .layer(DenseLayer.builder().nIn(8).nOut(16)
+                   .activation("relu").build())
+            .layer(OutputLayer.builder("mcxent").nOut(4)
+                   .activation("softmax").build())
+            .setInputType(InputType.feedForward(8)).build())
+    net = MultiLayerNetwork(conf)
+    net.init()
+    return net
+
+
+def _ds(n=16, seed=0):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(n, 8).astype(np.float32)
+    y = np.eye(4, dtype=np.float32)[rng.randint(0, 4, n)]
+    return DataSet(x, y)
+
+
+def _phase_cells(model):
+    """{phase: (count, sum)} of the loop-phase histogram for ``model``."""
+    h = get_registry().get(LOOP_HIST)
+    if h is None:
+        return {}
+    d = h.data()
+    out = {}
+    for key, cell in d["cells"]:
+        lab = dict(zip(d["labelnames"], key))
+        if lab["model"] == model:
+            out[lab["phase"]] = (cell["count"], cell["sum"])
+    return out
+
+
+def _generate(cb, quota, prompt=(1, 2, 3)):
+    ctx = RequestContext.new()
+    with request_context(ctx):
+        toks = [t for t in cb.submitStream(
+            {"tokens": list(prompt), "maxNewTokens": quota})
+            if isinstance(t, int)]
+    assert len(toks) == quota
+    return ctx
+
+
+def _host_lines(log_dir):
+    """[{annotation name: [(start_ns, end_ns), ...]}] per thread of the
+    capture's host plane."""
+    from jax.profiler import ProfileData
+    paths = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert paths, "the profiler wrote no capture"
+    lines = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for ln in plane.lines:
+            names = {}
+            for e in ln.events:
+                if e.name.startswith("dl4j."):
+                    names.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+            if names:
+                lines.append(names)
+    return lines
+
+
+# ----------------------------- a capture nobody told the program about --
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """One profiler session started through ``jax.profiler`` itself; the
+    loop thread serves a request, the main thread fits two batches and
+    leaves one span by an exception, then enters a sibling."""
+    prev_reg = telemetry.set_registry(MetricsRegistry())
+    prev_tr = set_tracer(Tracer())
+    log_dir = str(tmp_path_factory.mktemp("capture"))
+    cb = ContinuousBatcher(_lm(), name="cap", maxSlots=2,
+                           pageSize=8).start()
+    net = _mlp()
+    net.fit(_ds())                  # compile outside the capture
+    try:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        try:
+            _generate(cb, 4)
+            net.fit(_ds(seed=1))
+            net.fit(ListDataSetIterator([_ds(seed=2)], batch=16))
+            with pytest.raises(KeyError):
+                with tracer().span("boom"):
+                    raise KeyError("left by an exception")
+            with tracer().span("after_boom"):
+                time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        cb.shutdown()
+        set_tracer(prev_tr)
+        telemetry.set_registry(prev_reg)
+    return _host_lines(log_dir)
+
+
+def test_capture_holds_the_loop_threads_spans(capture):
+    want = {"dl4j.serving.decode.step", "dl4j.serving.loop.fetch",
+            "dl4j.serving.prefill", "dl4j.serving.loop.admit",
+            "dl4j.serving.loop.iteration"}
+    holders = [ln for ln in capture if "dl4j.serving.loop.fetch" in ln]
+    assert len(holders) == 1, "the loop's phases sit on ONE thread's line"
+    loop = holders[0]
+    assert want <= set(loop), sorted(loop)
+    # 4 tokens = 1 from the prefill + 3 decode steps, each with a fetch
+    assert len(loop["dl4j.serving.loop.fetch"]) == 3
+    assert len(loop["dl4j.serving.decode.step"]) == 3
+    assert len(loop["dl4j.serving.prefill"]) == 1
+    # and the training spans are NOT on the loop thread's line
+    assert "dl4j.step" not in loop and "dl4j.h2d" not in loop
+
+
+def test_capture_holds_the_fit_paths_spans(capture):
+    holders = [ln for ln in capture if "dl4j.step" in ln]
+    assert len(holders) == 1
+    main = holders[0]
+    assert len(main["dl4j.step"]) == 2 and len(main["dl4j.h2d"]) == 2
+    assert len(main["dl4j.etl"]) == 1       # the iterator-driven fit
+    # phases of one step do not overlap: h2d ends before its step starts
+    for (_h0, h1), (s0, _s1) in zip(sorted(main["dl4j.h2d"]),
+                                    sorted(main["dl4j.step"])):
+        assert h1 <= s0
+
+
+def test_capture_closes_a_span_left_by_an_exception(capture):
+    main = next(ln for ln in capture if "dl4j.boom" in ln)
+    (b0, b1), = main["dl4j.boom"]
+    (a0, _a1), = main["dl4j.after_boom"]
+    assert b0 <= b1 <= a0, "the annotation was left open past its body"
+
+
+# ----------------------------------------------- the decode loop's phases --
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "draft"])
+def test_every_phase_once_a_step_and_the_loop_is_covered(spec):
+    quota = 25
+    kw = dict(draft=_lm(seed=9, vocab=512), draftK=2) if spec else {}
+    # wide enough that a step takes milliseconds on the CPU: what lies
+    # between two phases (a span's own bookkeeping) is some 20 us
+    cb = ContinuousBatcher(_lm(layers=4, vocab=512, heads=4, headSize=32),
+                           name="ph", maxSlots=2, pageSize=8, **kw).start()
+    try:
+        _generate(cb, quota)
+    finally:
+        cb.shutdown()
+    steps = int(get_registry().get(
+        "dl4j_tpu_serving_decode_steps_total").value(model="ph"))
+    assert steps >= 1 if spec else steps == quota - 1
+    cells = _phase_cells("ph")
+    for p in STEP_PHASES:
+        assert cells[p][0] == steps, (p, cells[p], steps)
+    assert cells["admit"][0] >= steps       # once an iteration
+    # the loop thread's wall time, off the Chrome trace: first admit to
+    # the end of the last bookkeep; the phases are siblings on one thread
+    evs = [e for e in tracer().events()
+           if e["name"].startswith("serving.loop.")
+           and e["name"] not in ("serving.loop.wait",
+                                 "serving.loop.iteration")]
+    assert len({e["tid"] for e in evs}) == 1
+    t0 = min(e["ts"] for e in evs if e["name"] == "serving.loop.admit")
+    t1 = max(e["ts"] + e["dur"] for e in evs)
+    inside = [e for e in evs if e["ts"] >= t0]
+    covered = sum(e["dur"] for e in inside)
+    wall = t1 - t0
+    assert covered <= wall * (1 + 1e-9)
+    assert covered >= 0.90 * wall, (covered, wall)
+    # and what lies between two phases is inside the iteration's own span
+    its = [e for e in tracer().events()
+           if e["name"] == "serving.loop.iteration"]
+    assert len(its) == cells["admit"][0]
+    assert all(any(i["ts"] <= e["ts"] and
+                   e["ts"] + e["dur"] <= i["ts"] + i["dur"] for i in its)
+               for e in evs)
+    # span and histogram are the same two clock reads: they agree
+    by_name = {}
+    for e in evs:
+        by_name[e["name"]] = by_name.get(e["name"], 0.0) + e["dur"] * 1e-6
+    for p in STEP_PHASES + ("admit",):
+        assert by_name["serving.loop." + p] == \
+            pytest.approx(cells[p][1], rel=1e-6, abs=1e-9)
+
+
+def test_an_idle_batcher_accrues_wait_and_nothing_else():
+    t0 = time.perf_counter()
+    cb = ContinuousBatcher(_lm(), name="idle", maxSlots=2,
+                           pageSize=8).start()
+    try:
+        time.sleep(0.35)
+    finally:
+        cb.shutdown()
+    elapsed = time.perf_counter() - t0
+    cells = _phase_cells("idle")
+    assert set(cells) == {"wait"}, cells
+    count, total = cells["wait"]
+    assert count >= 3                       # slices of at most 0.1 s
+    assert 0.25 <= total <= elapsed
+    evs = tracer().events()
+    assert {e["name"] for e in evs} == {"serving.loop.wait"}
+    assert sum(e["dur"] for e in evs) * 1e-6 == pytest.approx(total)
+    assert max(e["dur"] for e in evs) * 1e-6 < 0.2      # slices, not one
+
+
+# ------------------------------------------------ h2d in every fit path --
+
+def _fit_dataset(net, batches):
+    for ds in batches:
+        net.fit(ds)
+
+
+def _fit_wrapper4(net, batches):
+    pw = ParallelWrapper(net, mesh=DeviceMesh(
+        data=4, devices=jax.devices()[:4]))
+    for ds in batches:
+        pw.fit(ListDataSetIterator([ds], batch=16))
+
+
+@pytest.mark.parametrize("fit", [_fit_dataset, _fit_wrapper4],
+                         ids=["fit_dataset", "parallel_wrapper4"])
+def test_h2d_is_observed_once_a_step(fit):
+    net = _mlp()
+    fit(net, [_ds(seed=s) for s in range(3)])
+    reg = get_registry()
+    assert reg.get("dl4j_tpu_step_h2d_seconds").count() == 3
+    assert reg.get("dl4j_tpu_step_compute_seconds").count() == 3
+    h2d = [e for e in tracer().events() if e["name"] == "h2d"]
+    assert len(h2d) == 3
+    assert sum(e["dur"] for e in h2d) * 1e-6 == pytest.approx(
+        reg.get("dl4j_tpu_step_h2d_seconds").sum(), rel=1e-6, abs=1e-9)
+
+
+def test_etl_is_a_real_span_and_keeps_the_folded_wait():
+    it = ListDataSetIterator([_ds()], batch=16)
+    it._telemetry_pending_wait = 0.25       # handed over by hasNext()
+    t0 = time.perf_counter()
+    etl_fetch(it)
+    real = time.perf_counter() - t0
+    ev, = [e for e in tracer().events() if e["name"] == "etl"]
+    assert ev["dur"] * 1e-6 <= real         # not backdated over the wait
+    assert ev["args"]["waited_before_s"] == 0.25
+    waited = get_registry().get("dl4j_tpu_step_data_wait_seconds")
+    assert waited.count() == 1
+    assert 0.25 <= waited.sum() <= 0.25 + real
+    assert it._telemetry_pending_wait == 0.0
+
+
+# ------------------------------------------- the Chrome trace's promises --
+
+def test_chrome_trace_keeps_serving_spans_with_args_and_nesting():
+    cb = ContinuousBatcher(_lm(), name="ct", maxSlots=2,
+                           pageSize=8).start()
+    try:
+        ctx = _generate(cb, 5)
+    finally:
+        cb.shutdown()
+    evs = tracer().events()
+    prefill, = [e for e in evs if e["name"] == "serving.prefill"]
+    assert prefill["args"].pop("bucket") in cb.ladder.seqLens
+    assert prefill["args"] == {"replica": "ct", "slot": 0, "depth": 3,
+                               "trace_id": ctx.traceId}
+    admit = [e for e in evs if e["name"] == "serving.loop.admit"
+             and e["ts"] <= prefill["ts"]
+             and prefill["ts"] + prefill["dur"] <= e["ts"] + e["dur"]]
+    assert len(admit) == 1, "the prefill nests inside ONE admit phase"
+    assert admit[0]["args"]["depth"] == 2   # under serving.loop.iteration
+    steps = [e for e in evs if e["name"] == "serving.decode.step"]
+    assert len(steps) == 4
+    for st in steps:
+        assert st["args"]["replica"] == "ct" and st["args"]["active"] == 1
+        kids = [e for e in evs if e["tid"] == st["tid"]
+                and e["name"].startswith("serving.loop.")
+                and st["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= st["ts"] + st["dur"]]
+        assert [k["name"].rsplit(".", 1)[1]
+                for k in sorted(kids, key=lambda e: e["ts"])] == \
+            list(STEP_PHASES)
+        assert all(k["args"]["depth"] == st["args"]["depth"] + 1
+                   for k in kids)
+
+
+def test_a_phase_left_by_an_exception_closes_span_and_observation():
+    seen = []
+    tr = tracer()
+    with pytest.raises(ValueError):
+        with tr.span("outer"):
+            with tr.span("inner", observe=seen.append, why="test") as args:
+                args["late"] = 1
+                raise ValueError("boom")
+    assert tr.open_spans() == []
+    inner, outer = tr.events()
+    assert (inner["name"], outer["name"]) == ("inner", "outer")
+    assert inner["args"] == {"why": "test", "late": 1, "depth": 2}
+    assert seen == [pytest.approx(inner["dur"] * 1e-6)]
+    with tr.span("next") as _:
+        pass
+    assert tr.events()[-1]["args"]["depth"] == 1    # depth was restored
+
+
+def test_every_loop_phase_is_named_and_nothing_switches_spans_on():
+    assert SERVING_LOOP_PHASES == ("wait", "admit") + STEP_PHASES
+    for gone in ("device_trace_active", "set_device_trace_active"):
+        assert not hasattr(telemetry, gone)
+        assert not hasattr(telemetry.tracing, gone)
