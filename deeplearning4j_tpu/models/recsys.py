@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.nn.conf.attention import paged_prefill_write
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer, register_layer
 from deeplearning4j_tpu.nn.lossfunctions import get_loss
@@ -229,22 +230,24 @@ class RetrievalLM:
     def buildPagedDecodeFn(self):
         """FRESH jitted retrieval step: ``(params, poolK, poolV,
         toks (S, 1), pageTable, pos, start) -> (next item (S, 1), poolK,
-        poolV)``.  ``toks`` carries each slot's last-emitted item; the
+        poolV)`` over the token-major pools ``(1, numPages, pageSize,
+        d)`` (one layer, one head: a row is one position's ``d``
+        channels).  ``toks`` carries each slot's last-emitted item; the
         step writes it into the V pool at ``pos``, masks every item the
         pool says was already emitted, and emits the next-ranked item.
         Pool buffers are donated; fresh identity per build for the same
         cache-hygiene reasons as the transformer decode."""
         def step(params, poolK, poolV, toks, pageTable, pos, start):
             S = toks.shape[0]
-            ps = poolV.shape[3]
+            ps = poolV.shape[2]
             rows = jnp.arange(S)
             # query embedding: position 0 of each slot's first page
-            u = poolK[0, pageTable[:, 0], 0, 0, :]          # (S, d)
+            u = poolK[0, pageTable[:, 0], 0, :]             # (S, d)
             scores = u @ params["items"].T                  # (S, vocab)
             # emitted-item history from the V pool (channel 0 over every
             # held page position; prompt region holds -1 sentinels and
             # unwritten positions are gated by pos)
-            hist = poolV[0, pageTable, 0, :, 0].reshape(S, -1)
+            hist = poolV[0, pageTable, :, 0].reshape(S, -1)
             posidx = jnp.arange(hist.shape[1], dtype=jnp.int32)
             emitted = jnp.where(posidx[None, :] < pos[:, None],
                                 hist.astype(jnp.int32), -1)
@@ -259,7 +262,7 @@ class RetrievalLM:
             # page in the last-emitted item at pos (inactive slots write
             # to the scratch page through their zeroed page tables)
             page = pageTable[rows, pos // ps]
-            poolV = poolV.at[0, page, 0, pos % ps, 0].set(
+            poolV = poolV.at[0, page, pos % ps, 0].set(
                 last.astype(poolV.dtype))
             return nxt[:, None], poolK, poolV
         return jax.jit(step, donate_argnums=(1, 2))
@@ -267,18 +270,11 @@ class RetrievalLM:
     def buildPagedPrefillWriteFn(self):
         """FRESH jitted pool write — identical contract to the
         transformer's: one sequence's stacked prefill K/V
-        ((1, 1, Tp, d)) into the pages named by ``pageIds``."""
+        ((1, 1, Tp, d)) into the pages ``pageIds`` of the ``(1,
+        numPages, pageSize, d)`` pools."""
         def write(poolK, poolV, kStack, vStack, pageIds):
-            L, h, Tp, d = kStack.shape
-            ps = poolK.shape[3]
-            nP = Tp // ps
-            kPages = kStack.reshape(L, h, nP, ps, d).transpose(
-                0, 2, 1, 3, 4)
-            vPages = vStack.reshape(L, h, nP, ps, d).transpose(
-                0, 2, 1, 3, 4)
-            poolK = poolK.at[:, pageIds].set(kPages.astype(poolK.dtype))
-            poolV = poolV.at[:, pageIds].set(vPages.astype(poolV.dtype))
-            return poolK, poolV
+            return paged_prefill_write(poolK, poolV, kStack, vStack,
+                                       pageIds)
         return jax.jit(write, donate_argnums=(0, 1))
 
     def compileCacheSize(self) -> int:

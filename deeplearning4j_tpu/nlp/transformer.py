@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.attention import (KVCache, cached_attention,
-                                                  paged_attention)
+                                                  paged_attention,
+                                                  paged_prefill_write)
 
 __all__ = ["TransformerLMConfig", "TransformerLM"]
 
@@ -443,15 +444,15 @@ class TransformerLM:
         exactly-once contract)."""
         return self.prefillRaw(tokens, lengths=lengths)
 
-    def _paged_block(self, lp, x, poolK, poolV, pageTable, pos, start):
-        """One transformer block against a paged pool layer (the
+    def _paged_block(self, lp, li, x, poolK, poolV, pageTable, pos, start):
+        """Transformer block ``li`` against the stacked paged pools (the
         ``_block_cached`` math with :func:`paged_attention` in place of
         the private dense cache)."""
         h = self._ln(x, lp["ln1_g"], lp["ln1_b"])
         qh = self._heads(jnp.matmul(h, lp["Wq"]))
         kh = self._heads(jnp.matmul(h, lp["Wk"]))
         vh = self._heads(jnp.matmul(h, lp["Wv"]))
-        ctx, poolK, poolV = paged_attention(qh, kh, vh, poolK, poolV,
+        ctx, poolK, poolV = paged_attention(qh, kh, vh, poolK, poolV, li,
                                             pageTable, pos, start)
         x = x + jnp.matmul(self._merge(ctx), lp["Wo"])
         h = self._ln(x, lp["ln2_g"], lp["ln2_b"])
@@ -460,22 +461,22 @@ class TransformerLM:
 
     def pagedLogits(self, params, poolK, poolV, toks, pageTable, pos,
                     start):
-        """toks (S, tq) against the stacked pools (L, pages, h, ps, d):
-        returns ((S, tq, vocab) logits, pools) — what the paged decode
-        step takes its arg-max of, and what a parity check compares with
-        :meth:`forward`.  Position-embedding ids are clipped so a
-        speculative over-write past ``maxLen`` (tokens that will be
-        discarded by the accept rule) can't index out of the table."""
+        """toks (S, tq) against the stacked pools (L, pages, pageSize,
+        nHeads*headSize): returns ((S, tq, vocab) logits, pools) — what
+        the paged decode step takes its arg-max of, and what a parity
+        check compares with :meth:`forward`.  Every layer writes and
+        reads the stacked pools in place.  Position-embedding ids are
+        clipped so a speculative over-write past ``maxLen`` (tokens that
+        will be discarded by the accept rule) can't index out of the
+        table."""
         tq = toks.shape[1]
         pos_ids = jnp.clip(
             (pos - start)[:, None] + jnp.arange(tq, dtype=jnp.int32),
             0, self.config.maxLen - 1)
         x = params["emb"][toks] + params["pos"][pos_ids]
         for li, lp in enumerate(params["layers"]):
-            x, pk, pv = self._paged_block(lp, x, poolK[li], poolV[li],
-                                          pageTable, pos, start)
-            poolK = poolK.at[li].set(pk)
-            poolV = poolV.at[li].set(pv)
+            x, poolK, poolV = self._paged_block(lp, li, x, poolK, poolV,
+                                                pageTable, pos, start)
         return self._logits(params, x), poolK, poolV
 
     def _paged_step_math(self, params, poolK, poolV, toks, pageTable,
@@ -526,18 +527,13 @@ class TransformerLM:
         """FRESH jitted pool write: copy one sequence's stacked prefill
         K/V ((L, h, Tp, d), Tp a page multiple) into the pages named by
         ``pageIds`` ((Tp/pageSize,) int32).  One cache entry per prompt
-        bucket (warmed at start)."""
+        bucket (warmed at start).  The layout is
+        :func:`paged_prefill_write`'s; the wrapper gives each build its
+        own identity and the program the name traces know it by
+        (``jit_write``)."""
         def write(poolK, poolV, kStack, vStack, pageIds):
-            L, h, Tp, d = kStack.shape
-            ps = poolK.shape[3]
-            nP = Tp // ps
-            kPages = kStack.reshape(L, h, nP, ps, d).transpose(
-                0, 2, 1, 3, 4)
-            vPages = vStack.reshape(L, h, nP, ps, d).transpose(
-                0, 2, 1, 3, 4)
-            poolK = poolK.at[:, pageIds].set(kPages.astype(poolK.dtype))
-            poolV = poolV.at[:, pageIds].set(vPages.astype(poolV.dtype))
-            return poolK, poolV
+            return paged_prefill_write(poolK, poolV, kStack, vStack,
+                                       pageIds)
         return jax.jit(write, donate_argnums=(0, 1))
 
     def compileCacheSize(self) -> int:

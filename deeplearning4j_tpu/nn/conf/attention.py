@@ -25,7 +25,8 @@ from deeplearning4j_tpu.nn.weights import init_weight
 
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
-           "KVCache", "cached_attention", "paged_attention"]
+           "KVCache", "cached_attention", "paged_attention",
+           "paged_prefill_write"]
 
 
 def _mha(x_btn, Wq, Wk, Wv, Wo, nHeads, mask=None, q_btn=None, impl="auto",
@@ -122,7 +123,7 @@ def cached_attention(qh, kh_new, vh_new, cache: KVCache):
     return ctx, KVCache(k, v, pos + tq, cache.start)
 
 
-def paged_attention(qh, kh_new, vh_new, poolK, poolV, pageTable, pos,
+def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
                     start):
     """Causal attention of ``tq`` new positions against a PAGED KV pool.
 
@@ -137,9 +138,14 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, pageTable, pos,
 
     - ``qh``/``kh_new``/``vh_new``: (slots, heads, tq, headSize) for the
       new positions only;
-    - ``poolK``/``poolV``: (numPages, heads, pageSize, headSize) — ONE
-      layer's shared page pool (page 0 is the scratch page inactive
-      slots write into);
+    - ``poolK``/``poolV``: (nLayers, numPages, pageSize, heads*headSize)
+      — the STACKED pools of every layer, token-major: one row per
+      position holds all heads side by side, so the two minor
+      dimensions are what the TPU tiles as they stand (page 0 is the
+      scratch page inactive slots write into);
+    - ``li``: the layer whose pages this call reads and writes.  The
+      stacked pool is indexed in place; no layer is sliced out and put
+      back, so nothing the size of a pool is ever copied;
     - ``pageTable``: (slots, maxPagesPerSeq) int32 physical page ids in
       logical order (unallocated tail entries point at the scratch
       page and are masked out by ``pos``);
@@ -148,7 +154,7 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, pageTable, pos,
       ``KVCache.pos``/``KVCache.start``, but per slot instead of per
       batch).
 
-    Writes the new K/V into their pages (``tq`` may span a page
+    Writes the new K/V rows into their pages (``tq`` may span a page
     boundary — each token's page/offset is computed independently),
     gathers every slot's pages back in logical order and attends with
     the same validity mask as :func:`cached_attention` (key index
@@ -160,22 +166,39 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, pageTable, pos,
     wpos = pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
     phys = jnp.take_along_axis(pageTable, wpos // pageSize, axis=1)
     off = wpos % pageSize                                    # (S, tq)
-    poolK = poolK.at[phys, :, off, :].set(
-        kh_new.transpose(0, 2, 1, 3).astype(poolK.dtype))
-    poolV = poolV.at[phys, :, off, :].set(
-        vh_new.transpose(0, 2, 1, 3).astype(poolV.dtype))
+
+    def rows(new, pool):                    # (S, h, tq, d) -> (S, tq, h*d)
+        return new.transpose(0, 2, 1, 3).reshape(S, tq, h * d).astype(
+            pool.dtype)
+    poolK = poolK.at[li, phys, off].set(rows(kh_new, poolK))
+    poolV = poolV.at[li, phys, off].set(rows(vh_new, poolV))
     cap = pageTable.shape[1] * pageSize
-    k = poolK[pageTable].transpose(0, 2, 1, 3, 4).reshape(S, h, cap, d)
-    v = poolV[pageTable].transpose(0, 2, 1, 3, 4).reshape(S, h, cap, d)
+    k = poolK[li, pageTable].reshape(S, cap, h, d)
+    v = poolV[li, pageTable].reshape(S, cap, h, d)
     kpos = jnp.arange(cap, dtype=jnp.int32)
     valid = (kpos[None, None, :] <= wpos[:, :, None]) & \
         (kpos[None, None, :] >= start[:, None, None])        # (S, tq, cap)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, k.astype(qh.dtype))
+    s = jnp.einsum("bhqd,bkhd->bhqk", qh, k.astype(qh.dtype))
     s = s * (1.0 / jnp.sqrt(jnp.asarray(d, s.dtype)))
     s = jnp.where(valid[:, None], s, jnp.asarray(-1e30, s.dtype))
     w = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhqk,bhkd->bhqd", w, v.astype(qh.dtype))
+    ctx = jnp.einsum("bhqk,bkhd->bhqd", w, v.astype(qh.dtype))
     return ctx, poolK, poolV
+
+
+def paged_prefill_write(poolK, poolV, kStack, vStack, pageIds):
+    """Copy one sequence's stacked prefill K/V ((L, h, Tp, d), ``Tp`` a
+    page multiple) into the pages of the token-major pools ((L,
+    numPages, pageSize, h*d), see :func:`paged_attention`) named by
+    ``pageIds`` ((Tp/pageSize,) int32).  Returns the two pools."""
+    L, h, Tp, d = kStack.shape
+    ps = poolK.shape[2]
+
+    def pages(stack, pool):
+        return stack.transpose(0, 2, 1, 3).reshape(
+            L, Tp // ps, ps, h * d).astype(pool.dtype)
+    return (poolK.at[:, pageIds].set(pages(kStack, poolK)),
+            poolV.at[:, pageIds].set(pages(vStack, poolV)))
 
 
 @dataclasses.dataclass

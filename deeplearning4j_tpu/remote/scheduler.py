@@ -90,9 +90,12 @@ def _probe_fn():
 
 
 class KVCachePool:
-    """Paged KV memory for one model: ``(nLayers, numPages, nHeads,
-    pageSize, headSize)`` device buffers plus a host-side free list and
-    per-slot page tables.
+    """Paged KV memory for one model: ``(nLayers, numPages, pageSize,
+    nHeads*headSize)`` device buffers plus a host-side free list and
+    per-slot page tables.  Pages are token-major (a row is one position,
+    all heads side by side): the two minor dimensions are what the TPU
+    tiles without a re-layout, so the decode step and the prefill write
+    update ``k``/``v`` in place (``paged_attention``).
 
     Page 0 is the SCRATCH page: inactive slots' table entries point at
     it, so the fixed-shape decode step can write their (ignored) K/V
@@ -116,8 +119,8 @@ class KVCachePool:
             raise ValueError(
                 f"numPages={self.numPages} must exceed maxPagesPerSeq="
                 f"{self.maxPagesPerSeq} (page 0 is reserved scratch)")
-        k = jnp.zeros((int(nLayers), self.numPages, int(nHeads),
-                       self.pageSize, int(headSize)), dtype)
+        k = jnp.zeros((int(nLayers), self.numPages, self.pageSize,
+                       int(nHeads) * int(headSize)), dtype)
         v = jnp.zeros_like(k)
         if sharding is not None:
             k = jax.device_put(k, sharding)
@@ -378,8 +381,9 @@ class ContinuousBatcher:
         from jax.sharding import NamedSharding, PartitionSpec as P
         mesh = self.plan.mesh
         if mesh.modelSize > 1 and nHeads % mesh.modelSize == 0:
-            # pool heads live with their TP-sharded projection columns
-            return NamedSharding(mesh.mesh, P(None, None,
+            # the merged heads*headSize dimension splits into whole
+            # heads, beside their TP-sharded projection columns
+            return NamedSharding(mesh.mesh, P(None, None, None,
                                               self.plan.modelAxis))
         return NamedSharding(mesh.mesh, P())
 

@@ -37,46 +37,97 @@ def _post(port, path, obj, timeout=60):
 
 # ------------------------------------------------- paged attention ----
 
-def test_paged_attention_matches_cached_attention():
+def _token_major(hist, ps):
+    """(h, cap, d) dense history -> (cap/ps, ps, h*d) token-major pages:
+    one row per position, all heads side by side."""
+    h, cap, d = hist.shape
+    return hist.transpose(1, 0, 2).reshape(cap // ps, ps, h * d)
+
+
+@pytest.mark.parametrize("tq,layers,pos,start", [
+    (1, 1, 7, 2),       # the plain decode step
+    (3, 2, 6, 1),       # a speculative verify: writes 6, 7 | 8 cross a page
+])
+def test_paged_attention_matches_cached_attention(tq, layers, pos, start):
     """The pooled page-table lookup is numerically the same attention as
-    the dense per-batch KVCache (same validity mask, same math)."""
+    the dense per-batch KVCache (same validity mask, same math); each new
+    token lands in its own page and row of layer 0 of the stacked
+    token-major pool, and nothing else — no other row, no other layer —
+    is touched."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.conf.attention import (KVCache,
                                                       cached_attention,
                                                       paged_attention)
-    rng = np.random.RandomState(0)
+    rng = np.random.RandomState(tq)
     S, h, d, ps, P = 2, 2, 4, 4, 3          # capacity = 12
-    qh = jnp.asarray(rng.randn(S, h, 1, d), jnp.float32)
-    kh = jnp.asarray(rng.randn(S, h, 1, d), jnp.float32)
-    vh = jnp.asarray(rng.randn(S, h, 1, d), jnp.float32)
+    qh = jnp.asarray(rng.randn(S, h, tq, d), jnp.float32)
+    kh = jnp.asarray(rng.randn(S, h, tq, d), jnp.float32)
+    vh = jnp.asarray(rng.randn(S, h, tq, d), jnp.float32)
     hist_k = rng.randn(S, h, 12, d).astype(np.float32)
     hist_v = rng.randn(S, h, 12, d).astype(np.float32)
-    pos, start = 7, 2
     # dense reference
     cache = KVCache(jnp.asarray(hist_k), jnp.asarray(hist_v),
                     jnp.asarray(pos, jnp.int32),
                     jnp.full((S,), start, jnp.int32))
     ref, _ = cached_attention(qh, kh, vh, cache)
-    # paged: the same history sliced into pages (0 = scratch; slot 0
-    # gets pages 1..3, slot 1 pages 4..6 — hist (h, cap, d) slices
-    # straight into the (h, ps, d) page layout)
-    poolK = np.zeros((8, h, ps, d), np.float32)
-    poolV = np.zeros((8, h, ps, d), np.float32)
-    table = np.zeros((S, P), np.int32)
+    # paged: the same history cut into token-major pages of layer 0
+    # (page 0 = scratch; slot 0 holds pages 6, 5, 4 and slot 1 pages
+    # 3, 2, 1: not in physical order); every other row holds noise
+    pools = {"k": rng.randn(layers, 8, ps, h * d).astype(np.float32),
+             "v": rng.randn(layers, 8, ps, h * d).astype(np.float32)}
+    table = np.asarray([[6 - s * P - i for i in range(P)]
+                        for s in range(S)], np.int32)
     for s in range(S):
-        table[s] = [1 + s * P + i for i in range(P)]
-        for i, pid in enumerate(table[s]):
-            poolK[pid] = hist_k[s][:, i * ps:(i + 1) * ps]
-            poolV[pid] = hist_v[s][:, i * ps:(i + 1) * ps]
+        pools["k"][0, table[s]] = _token_major(hist_k[s], ps)
+        pools["v"][0, table[s]] = _token_major(hist_v[s], ps)
     got, pk, pv = paged_attention(
-        qh, kh, vh, jnp.asarray(poolK), jnp.asarray(poolV),
+        qh, kh, vh, jnp.asarray(pools["k"]), jnp.asarray(pools["v"]), 0,
         jnp.asarray(table), jnp.full((S,), pos, jnp.int32),
         jnp.full((S,), start, jnp.int32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
-    # the new K/V landed in the right page slot
-    pagedK = np.asarray(pk)[table[0, pos // ps], :, pos % ps, :]
-    np.testing.assert_allclose(pagedK, np.asarray(kh)[0, :, 0, :])
+    # the new K/V landed at [0, table[s, p // ps], p % ps], heads side by
+    # side, and nothing else moved
+    for new, out, before in ((kh, pk, pools["k"]), (vh, pv, pools["v"])):
+        want = before.copy()
+        for s in range(S):
+            for i in range(tq):
+                p = pos + i
+                want[0, table[s, p // ps], p % ps] = \
+                    np.asarray(new)[s, :, i, :].ravel()
+        np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_prefill_write_then_paged_step_equals_forward_logits():
+    """The prefill write puts position ``t`` of layer ``l`` (all heads side
+    by side) at ``[l, pageIds[t // ps], t % ps]``; and, end to end, a
+    LEFT-padded prompt prefilled and written into pool pages, then decoded
+    teacher-forced through ``pagedLogits``, gives ``lm.forward``'s logits
+    row for row — ``chip_smoke.py``'s own check, as it runs on the chip."""
+    import chip_smoke
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf.attention import paged_prefill_write
+    from deeplearning4j_tpu.remote import KVCachePool
+    L, h, d, ps, ids = 2, 2, 4, 4, [3, 1]
+    stack = np.random.RandomState(2).randn(L, h, 2 * ps, d).astype(np.float32)
+    pool = KVCachePool(L, h, d, ps, numPages=5, maxSlots=2, maxPagesPerSeq=4)
+    assert pool.k.shape == pool.v.shape == (L, 5, ps, h * d)
+    pk, pv = paged_prefill_write(pool.k, pool.v, jnp.asarray(stack),
+                                 jnp.asarray(-stack),
+                                 jnp.asarray(ids, jnp.int32))
+    want = np.zeros(pool.k.shape, np.float32)
+    for t in range(2 * ps):
+        want[:, ids[t // ps], t % ps] = stack[:, :, t].reshape(L, h * d)
+    np.testing.assert_array_equal(np.asarray(pk), want)
+    np.testing.assert_array_equal(np.asarray(pv), -want)
+
+    r = chip_smoke.Report()             # the decode crosses two pages
+    chip_smoke._check_paged_parity(r, _lm(layers=2, maxLen=32), pageSize=4,
+                                   promptLen=5, bucket=8, decodeSteps=6)
+    assert not r.failed, r.failed
+    assert r.values["paged_rows"] == 7
+    assert r.values["paged_greedy_mismatches"] == 0
+    assert r.values["paged_logit_err"] <= 1e-4 * r.values["paged_logit_scale"]
 
 
 # --------------------------------------- scheduler core invariants ----

@@ -1,0 +1,119 @@
+"""Ahead-of-time compiles for a DESCRIBED TPU v5e (no chip attached): what
+the chip's compiler makes of the serving tier's paged programs at real
+widths.  Nothing runs, so these say nothing of results or times — they
+guard the program's shape: no pool-sized layout copy, temporaries under
+one pool.
+
+The topology is described inside a fixture (never at import): one process
+at a time may load libtpu, and under xdist every worker imports this file.
+Keep every such compile in THIS file, so one worker holds the library.
+"""
+import os
+import re
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+pytestmark = pytest.mark.cbatch
+
+# gpt2_xl as the serving cells run it (benchmark/configs/gpt2_xl.json):
+# 48 layers (the whole step compiles in ~10 s; at fewer layers the logits'
+# temporaries outweigh the pool and the bound on them says nothing), and
+# the cells' pool: 4 slots, 129 pages of 16 positions
+LAYERS, HEADS, HEAD_SIZE, VOCAB, MAX_LEN = 48, 25, 64, 50257, 1024
+SLOTS, PAGE_SIZE, NUM_PAGES = 4, 16, 129
+PER_SEQ = MAX_LEN // PAGE_SIZE
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def paged(one_chip):
+    """The model's hooks and its arguments as shapes on the described
+    chip: ``(lm, params, pool, i32)``.  ``eval_shape`` allocates nothing."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.transformer import (TransformerLM,
+                                                    TransformerLMConfig)
+    from deeplearning4j_tpu.remote import KVCachePool
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    # a one-layer model of distinct toy sizes gives the parameter tree;
+    # its sizes are then read as the real ones (drawing 1.56 B weights
+    # on the host to learn their shapes would take minutes)
+    lm = TransformerLM(TransformerLMConfig(vocabSize=3, nLayers=1, nHeads=1,
+                                           headSize=8, ffnMult=4, maxLen=5))
+    real = {3: VOCAB, 5: MAX_LEN, 8: HEADS * HEAD_SIZE,
+            32: 4 * HEADS * HEAD_SIZE}
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        tuple(real[n] for n in a.shape), a.dtype), lm.params)
+    params["layers"] = params["layers"] * LAYERS
+    params = on_chip(params)
+    lm.config = TransformerLMConfig(vocabSize=VOCAB, nLayers=LAYERS,
+                                    nHeads=HEADS, headSize=HEAD_SIZE,
+                                    maxLen=MAX_LEN)
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool(
+        LAYERS, HEADS, HEAD_SIZE, PAGE_SIZE, NUM_PAGES, SLOTS, PER_SEQ).k))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    return lm, params, pool, i32
+
+
+def _pool_copies(compiled, pool):
+    """Instructions of the optimized program whose result has the pool's
+    shape and that are a ``copy`` (a change of layout: it moves the whole
+    pool once)."""
+    shape = "f32[" + ",".join(str(n) for n in pool.shape) + "]"
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?(%?[\w.\-]+) = (\S+) copy\(", line)
+        if m and m.group(2).startswith(shape):
+            found.append(m.group(1))
+    return found
+
+
+def _assert_in_place(compiled, pool, what):
+    poolBytes = pool.size * pool.dtype.itemsize
+    copies = _pool_copies(compiled, pool)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert not copies, (
+        f"{what}: {len(copies)} pool-shaped copies {copies[:6]} "
+        f"(temp {temp / 1e9:.2f} GB, one pool {poolBytes / 1e9:.2f} GB)")
+    assert temp < poolBytes, (
+        f"{what}: temporaries {temp / 1e9:.3f} GB are not under one "
+        f"pool's {poolBytes / 1e9:.3f} GB")
+
+
+def test_paged_decode_step_updates_the_pool_in_place(paged):
+    lm, params, pool, i32 = paged
+    compiled = lm.buildPagedDecodeFn().lower(
+        params, pool, pool, i32(SLOTS, 1), i32(SLOTS, PER_SEQ), i32(SLOTS),
+        i32(SLOTS)).compile()
+    _assert_in_place(compiled, pool, "jit_step")
+
+
+@pytest.mark.parametrize("bucket", [16, 256])
+def test_paged_prefill_write_updates_the_pool_in_place(paged, bucket):
+    import jax
+    import jax.numpy as jnp
+    lm, _params, pool, i32 = paged
+    stack = jax.ShapeDtypeStruct((LAYERS, HEADS, bucket, HEAD_SIZE),
+                                 jnp.float32, sharding=pool.sharding)
+    compiled = lm.buildPagedPrefillWriteFn().lower(
+        pool, pool, stack, stack, i32(bucket // PAGE_SIZE)).compile()
+    _assert_in_place(compiled, pool, f"jit_write[{bucket}]")
