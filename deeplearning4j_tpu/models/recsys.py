@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.attention import paged_prefill_write
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  paged_prefill_write)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer, register_layer
 from deeplearning4j_tpu.nn.lossfunctions import get_loss
@@ -192,6 +193,10 @@ class RetrievalLM:
         W = net.params_[layerKey]["W"]
         return cls(W, W, maxLen=maxLen)
 
+    def cacheSpec(self) -> CacheSpec:
+        c = self.config
+        return CacheSpec(c.nLayers, c.nHeads, c.headSize)
+
     # -- prefill --------------------------------------------------------
     @functools.cached_property
     def _prefillRawFn(self):
@@ -272,7 +277,7 @@ class RetrievalLM:
         transformer's: one sequence's stacked prefill K/V
         ((1, 1, Tp, d)) into the pages ``pageIds`` of the ``(1,
         numPages, pageSize, d)`` pools."""
-        def write(poolK, poolV, kStack, vStack, pageIds):
+        def write(poolK, poolV, kStack, vStack, pageIds, slot=None):
             return paged_prefill_write(poolK, poolV, kStack, vStack,
                                        pageIds)
         return jax.jit(write, donate_argnums=(0, 1))

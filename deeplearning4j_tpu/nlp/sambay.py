@@ -1,0 +1,517 @@
+"""SambaY decoder-hybrid-decoder LM (Phi-4-mini-flash-reasoning,
+arXiv:2507.06607) for the serving tier: Mamba, sliding-window and full
+differential attention (arXiv:2410.05258), cross-attention onto ONE
+shared KV layer, and Gated Memory Units.
+
+Layers ``i = 0..L-1`` with ``half = L // 2``: Mamba at even ``i <= half``
+(layer ``half`` also hands its scan output ``m = y`` to the GMUs), window
+attention at odd ``i < half``, full attention at ``half + 1``, GMU at
+even ``i > half``, cross-attention (queries only, onto the full layer's
+keys and values) at odd ``i > half + 1``.  Every layer is pre-LayerNorm
+mixer + pre-LayerNorm gated-SiLU FFN; no positional encoding anywhere;
+tied input/output embedding.
+
+What the model keeps between decode steps is THREE kinds of state, named
+by :meth:`SambaYLM.cacheSpec` and held side by side by the scheduler's
+``KVCachePool``:
+
+- *paged* — the full layer's K/V rows, one per position, in pages that
+  grow with the sequence; written by one layer, read by it and by every
+  cross layer;
+- *ring* — each window layer's last ``W`` K/V rows per slot, written
+  modulo ``W``.  Which position a ring row holds follows from ``pos``
+  alone, so a reused slot's stale rows are masked, never zeroed;
+- *recurrent* — each Mamba layer's float32 state ``(N, d_in)`` and the
+  convolution's last ``K - 1`` inputs per slot, overwritten every step.
+
+Precision: weights, residual stream and K/V in the parameters' dtype
+(bfloat16 as served); the SSM state, ``Δ``/``exp``, softmax, norms and
+logits in float32; every matmul accumulates in float32.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  paged_prefill_write)
+
+__all__ = ["SambaYConfig", "SambaYLM"]
+
+_F32 = jnp.float32
+_I32 = jnp.int32
+_NEG = -1e30
+
+
+@dataclasses.dataclass
+class SambaYConfig:
+    vocabSize: int = 256
+    nLayers: int = 8
+    hiddenSize: int = 64
+    nHeads: int = 8
+    nKvHeads: int = 4
+    ffnSize: int = 128
+    window: int = 8
+    mbPerLayer: int = 2
+    stateSize: int = 4          # N
+    convKernel: int = 4         # K
+    expand: int = 2             # d_in = expand * hiddenSize
+    dtRank: int = 4             # R
+    eps: float = 1e-5
+    maxLen: int = 128           # positions a slot may hold (bucket + new)
+    initializerRange: float = 0.02
+    seed: int = 0
+    dtype: str = "bfloat16"
+
+    @property
+    def headSize(self) -> int:
+        return self.hiddenSize // self.nHeads
+
+    @property
+    def innerSize(self) -> int:
+        return self.expand * self.hiddenSize
+
+    def layerKinds(self) -> List[str]:
+        half = self.nLayers // 2
+        kinds = []
+        for i in range(self.nLayers):
+            if i % self.mbPerLayer == 0:
+                kinds.append("mamba" if i <= half else "gmu")
+            elif i < half:
+                kinds.append("window")
+            else:
+                kinds.append("full" if i == half + 1 else "cross")
+        return kinds
+
+
+def _lambda_init(i: int) -> float:
+    return 0.8 - 0.6 * math.exp(-0.3 * i)
+
+
+def _mm(a, w):
+    """``a @ w`` in the weight's dtype on the way in, float32 out."""
+    return jnp.matmul(a.astype(w.dtype), w, preferred_element_type=_F32)
+
+
+def _ln(x, g, b, eps):
+    x = x.astype(_F32)
+    xc = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return xc * jax.lax.rsqrt(var + eps) * g.astype(_F32) + b.astype(_F32)
+
+
+def _ssm_step(s, Dt, ut, Bt, Ct, AT):
+    """One step of the selective scan over a batch: state ``s (b, N,
+    d_in)`` float32, ``Dt, ut (b, d_in)``, ``Bt, Ct (b, N)``, ``AT (N,
+    d_in)``; returns ``(s, y (b, d_in))`` before the ``D`` skip."""
+    s = jnp.exp(Dt[:, None, :] * AT[None]) * s \
+        + (Dt * ut)[:, None, :] * Bt[:, :, None]
+    return s, jnp.sum(s * Ct[:, :, None], axis=1)
+
+
+class SambaYLM:
+    """The served model: ``forward`` (the recompute baseline), a bucketed
+    left-padded ``prefillRaw`` that also returns every kind of cache
+    state, and the scheduler's fixed-shape decode step and admission
+    write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``, the
+    hooks ``TransformerLM`` has)."""
+
+    def __init__(self, config: Optional[SambaYConfig] = None, params=None,
+                 **kw):
+        self.config = config or SambaYConfig(**kw)
+        self.params = params if params is not None else self._init_params()
+
+    # ------------------------------------------------------------------
+    def _init_params(self) -> Dict:
+        """Seeded weights drawn ON THE DEVICE in the configured dtype
+        (3.85 B of them at the published sizes: a host draw would take
+        minutes), one small program per kind of layer."""
+        c = self.config
+        dt = jnp.dtype(c.dtype)
+        d, ff, dIn = c.hiddenSize, c.ffnSize, c.innerSize
+        N, K, R, dh = c.stateSize, c.convKernel, c.dtRank, c.headSize
+        qd, kvd = c.nHeads * dh, c.nKvHeads * dh
+        std = c.initializerRange
+
+        @functools.partial(jax.jit, static_argnames=("kind",))
+        def layer(key, kind):
+            keys = iter(jax.random.split(key, 16))
+            normal = lambda shape, s=std: (s * jax.random.normal(
+                next(keys), shape, _F32)).astype(dt)
+            uniform = lambda shape, b: jax.random.uniform(
+                next(keys), shape, _F32, -b, b).astype(dt)
+            p = {"ln1_g": jnp.ones((d,), dt), "ln1_b": jnp.zeros((d,), dt),
+                 "ln2_g": jnp.ones((d,), dt), "ln2_b": jnp.zeros((d,), dt),
+                 "Wgate": normal((d, ff)), "Wup": normal((d, ff)),
+                 "Wdown": normal((ff, d))}
+            if kind == "mamba":
+                dtv = jnp.exp(jax.random.uniform(next(keys), (dIn,), _F32)
+                              * (math.log(1e-1) - math.log(1e-3))
+                              + math.log(1e-3))
+                p.update(
+                    Win=normal((d, 2 * dIn)),
+                    convW=uniform((K, dIn), K ** -0.5),
+                    convB=uniform((dIn,), K ** -0.5),
+                    Wx=normal((dIn, R + 2 * N)),
+                    Wdt=uniform((R, dIn), R ** -0.5),
+                    bdt=(dtv + jnp.log(-jnp.expm1(-dtv))).astype(dt),
+                    AlogT=jnp.broadcast_to(jnp.log(jnp.arange(
+                        1, N + 1, dtype=_F32))[:, None], (N, dIn)).astype(dt),
+                    D=jnp.ones((dIn,), dt), Wout=normal((dIn, d)))
+            elif kind == "gmu":
+                p.update(W1=normal((d, dIn)), W2=normal((dIn, d)))
+            else:
+                p.update(Wq=normal((d, qd)), Wo=normal((qd, d)),
+                         sublnG=jnp.ones((2 * dh,), dt))
+                for name in ("lq1", "lk1", "lq2", "lk2"):
+                    p[name] = normal((dh,), 0.1)
+                if kind != "cross":
+                    p.update(Wk=normal((d, kvd)), Wv=normal((d, kvd)))
+            return p
+
+        @jax.jit
+        def embedding(key):
+            return (std * jax.random.normal(key, (c.vocabSize, d), _F32)
+                    ).astype(dt)
+
+        key = jax.random.PRNGKey(c.seed)
+        return {"emb": embedding(jax.random.fold_in(key, 0)),
+                "lnf_g": jnp.ones((d,), dt),
+                "lnf_b": jnp.zeros((d,), dt),
+                "layers": [layer(jax.random.fold_in(key, i + 1), kind)
+                           for i, kind in enumerate(c.layerKinds())]}
+
+    # ------------------------------------------------------------------
+    def cacheSpec(self) -> CacheSpec:
+        """What each layer keeps between steps, for the scheduler's pool:
+        pages for the ONE full layer, a ring of ``window`` rows a slot
+        for every window layer, and the Mamba layers' recurrent state."""
+        c = self.config
+        kinds = c.layerKinds()
+        nM = kinds.count("mamba")
+        dt = jnp.dtype(c.dtype)
+        return CacheSpec(
+            pagedLayers=kinds.count("full"), kvHeads=c.nKvHeads,
+            headSize=c.headSize, dtype=dt,
+            ringLayers=kinds.count("window"), ringRows=c.window,
+            slotState=(("ssm", (nM, c.stateSize, c.innerSize), _F32),
+                       ("conv", (nM, c.convKernel - 1, c.innerSize), dt)))
+
+    # -- pieces shared by the full-sequence and the step forms ----------
+    def _ffn(self, lp, x):
+        u = _ln(x, lp["ln2_g"], lp["ln2_b"], self.config.eps)
+        g = jax.nn.silu(_mm(u, lp["Wgate"])) * _mm(u, lp["Wup"])
+        return x + _mm(g, lp["Wdown"]).astype(x.dtype)
+
+    def _ssm_inputs(self, lp, u):
+        """From the convolved ``u (..., d_in)`` float32: ``(Δ, B, C)``."""
+        c = self.config
+        R, N = c.dtRank, c.stateSize
+        dbc = _mm(u, lp["Wx"])
+        Dt = jax.nn.softplus(_mm(dbc[..., :R], lp["Wdt"])
+                             + lp["bdt"].astype(_F32))
+        return Dt, dbc[..., R:R + N], dbc[..., R + N:]
+
+    def _diff_attend(self, lp, i, q, kRows, vRows, valid):
+        """Differential attention of ``q (b, tq, H*dh)`` against rows
+        ``kRows, vRows (b, T, KV*dh)`` with ``valid (b, tq, T)``.
+
+        Query heads pair up (20 pairs) and KV heads pair up (10 pairs);
+        query pair ``p`` reads KV pair ``g = p // R``.  A row is kept as
+        ``G`` groups of ``2*dh`` channels — ``[k_g1 ; k_g2]``, a whole
+        128-lane tile at the published head size — and each query is
+        laid into its own half of that width (zeros in the other), so
+        that K and V are contracted as they are stored and never split
+        into 64-wide heads."""
+        c = self.config
+        b, tq, _ = q.shape
+        T = kRows.shape[1]
+        dh = c.headSize
+        G = c.nKvHeads // 2
+        R = (c.nHeads // 2) // G
+        cd = kRows.dtype
+        q5 = q.reshape(b, tq, G, R, 2, 1, dh)
+        eye = jnp.eye(2, dtype=q.dtype)[:, :, None]
+        qe = (q5 * eye).reshape(b, tq, G, R * 2, 2 * dh).astype(cd)
+        k4 = kRows.reshape(b, T, G, 2 * dh)
+        v4 = vRows.reshape(b, T, G, 2 * dh)
+        s = jnp.einsum("bqgac,btgc->bgqat", qe, k4,
+                       preferred_element_type=_F32) * (1.0 / math.sqrt(dh))
+        s = jnp.where(valid[:, None, :, None, :], s, _NEG)
+        a = jax.nn.softmax(s, axis=-1).reshape(b, G, tq, R, 2, T)
+        f = lambda n: lp[n].astype(_F32)
+        li = _lambda_init(i)
+        lam = jnp.exp(jnp.sum(f("lq1") * f("lk1"))) \
+            - jnp.exp(jnp.sum(f("lq2") * f("lk2"))) + li
+        a = a[..., 0, :] - lam * a[..., 1, :]               # (b, G, tq, R, T)
+        o = jnp.einsum("bgqrt,btgc->bqgrc", a.astype(cd), v4,
+                       preferred_element_type=_F32)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + 1e-5) * f("sublnG") * (1.0 - li)
+        return o.reshape(b, tq, G * R * 2 * dh)
+
+    def _logits(self, params, x):
+        h = _ln(x, params["lnf_g"], params["lnf_b"], self.config.eps)
+        emb = params["emb"]
+        return jax.lax.dot_general(
+            h.astype(emb.dtype), emb,
+            (((h.ndim - 1,), (1,)), ((), ())), preferred_element_type=_F32)
+
+    # ------------------------------------------------------------------
+    # full-sequence form: forward and prefill
+    # ------------------------------------------------------------------
+    def _run_full(self, params, tokens, start):
+        """``tokens (b, T)`` LEFT-padded, ``start (b,)`` the first real
+        position.  Returns the last layer's output and the cache state a
+        decode would continue from: the full layer's K/V rows, every
+        window layer's K/V rows, every Mamba layer's final state and
+        last ``K - 1`` convolution inputs.  A pad position advances
+        nothing: its ``u`` and its ``Δ`` are zero, and no key is valid
+        there."""
+        c = self.config
+        b, T = tokens.shape
+        dIn, K, W = c.innerSize, c.convKernel, c.window
+        half = c.nLayers // 2
+        kpos = jnp.arange(T, dtype=_I32)
+        real = (kpos[None, :] >= start[:, None])             # (b, T)
+        realF = real.astype(_F32)[..., None]
+        causal = kpos[None, :, None] >= kpos[None, None, :]
+        valid = causal & real[:, None, :]                    # (b, T, T)
+        inWin = kpos[None, :, None] - kpos[None, None, :] < W
+        x = params["emb"][tokens]
+        cd = x.dtype
+        mem = kvRows = None
+        pagedK, pagedV, ringK, ringV, ssm, conv = [], [], [], [], [], []
+        for i, (kind, lp) in enumerate(zip(c.layerKinds(),
+                                           params["layers"])):
+            h = _ln(x, lp["ln1_g"], lp["ln1_b"], c.eps)
+            if kind == "mamba":
+                xz = _mm(h, lp["Win"])
+                u = xz[..., :dIn] * realF
+                z = xz[..., dIn:]
+                conv.append(u[:, T - (K - 1):].astype(cd))
+                up = jnp.concatenate(
+                    [jnp.zeros((b, K - 1, dIn), _F32), u], axis=1)
+                cw = lp["convW"].astype(_F32)
+                u = jax.nn.silu(sum(cw[k] * up[:, k:k + T] for k in range(K))
+                                + lp["convB"].astype(_F32))
+                Dt, B, C = self._ssm_inputs(lp, u)
+                Dt = Dt * realF
+                AT = -jnp.exp(lp["AlogT"].astype(_F32))
+
+                def step(s, t, AT=AT):
+                    return _ssm_step(s, *t, AT)
+                tm = lambda a: jnp.swapaxes(a, 0, 1)         # time-major
+                s, y = jax.lax.scan(
+                    step, jnp.zeros((b, c.stateSize, dIn), _F32),
+                    (tm(Dt), tm(u), tm(B), tm(C)), unroll=8)
+                ssm.append(s)
+                y = tm(y) + lp["D"].astype(_F32) * u
+                if i == half:
+                    mem = y
+                out = _mm(y * jax.nn.silu(z), lp["Wout"])
+            elif kind == "gmu":
+                out = _mm(jax.nn.silu(_mm(h, lp["W1"])) * mem, lp["W2"])
+            else:
+                q = _mm(h, lp["Wq"]).astype(cd)
+                if kind == "cross":
+                    kR, vR = kvRows
+                else:
+                    kR = _mm(h, lp["Wk"]).astype(cd)
+                    vR = _mm(h, lp["Wv"]).astype(cd)
+                if kind == "full":
+                    kvRows = (kR, vR)
+                    pagedK.append(kR)
+                    pagedV.append(vR)
+                elif kind == "window":
+                    ringK.append(self._ring_rows(kR))
+                    ringV.append(self._ring_rows(vR))
+                o = self._diff_attend(
+                    lp, i, q, kR, vR,
+                    valid & inWin if kind == "window" else valid)
+                out = _mm(o, lp["Wo"])
+            x = self._ffn(lp, x + out.astype(cd))
+        # the paged stacks in paged_prefill_write's form (L, b, h, T, d):
+        # one "head" as wide as a row
+        state = (jnp.stack(pagedK)[:, :, None], jnp.stack(pagedV)[:, :, None],
+                 jnp.stack(ringK), jnp.stack(ringV), jnp.stack(ssm),
+                 jnp.stack(conv))
+        return x, state
+
+    def _ring_rows(self, rows):
+        """The last ``min(T, W)`` rows of ``rows (b, T, w)`` in ring
+        order: position ``p`` sits at ring row ``p % W``."""
+        W = self.config.window
+        T = rows.shape[1]
+        if T <= W:
+            return rows
+        return jnp.roll(rows[:, T - W:], T % W, axis=1)
+
+    @functools.cached_property
+    def _fwd(self):
+        def run(params, tokens):
+            start = jnp.zeros((tokens.shape[0],), _I32)
+            x, _ = self._run_full(params, tokens, start)
+            return self._logits(params, x)
+        return jax.jit(run)
+
+    def forward(self, tokens) -> jax.Array:
+        """Full causal forward: (b, t) int32 -> (b, t, vocab) float32."""
+        return self._fwd(self.params, jnp.asarray(tokens, _I32))
+
+    @functools.cached_property
+    def _prefillRawFn(self):
+        def run(params, tokens, start):
+            x, state = self._run_full(params, tokens, start)
+            return (self._logits(params, x[:, -1]),) + state
+        return jax.jit(run)
+
+    def prefillRaw(self, tokens, lengths=None):
+        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
+        kStack, vStack, ringK, ringV, ssm, conv)``: the paged stacks in
+        :func:`paged_prefill_write`'s form ``(1, b, 1, t, KV*dh)`` and
+        the slot state ``(layers, b, ...)`` in the pool's order.  One
+        executable per prompt bucket."""
+        tokens = jnp.asarray(tokens, _I32)
+        t = tokens.shape[1]
+        if t > self.config.maxLen:
+            raise ValueError(f"prompt length {t} exceeds the capacity "
+                             f"{self.config.maxLen}")
+        if lengths is None:
+            start = jnp.zeros((tokens.shape[0],), _I32)
+        else:
+            start = t - jnp.asarray(lengths, _I32)
+        return self._prefillRawFn(self.params, tokens, start)
+
+    # ------------------------------------------------------------------
+    # step form — the continuous-batching scheduler's executables
+    # ------------------------------------------------------------------
+    def pagedLogits(self, params, k, v, ringK, ringV, ssm, conv, toks,
+                    pageTable, pos, start):
+        """One token per slot (``toks (S, 1)``) against the pool's
+        arrays: ``((S, 1, vocab) logits, k, v, ringK, ringV, ssm,
+        conv)``.  A slot whose ``pos`` is 0 holds no sequence (or is
+        deferred a round): its paged write lands on the scratch page
+        through its zeroed page table, and its ring rows and recurrent
+        state are left as they are."""
+        c = self.config
+        S, tq = toks.shape
+        if tq != 1:
+            raise ValueError(
+                "a recurrent state advances one token a step: speculative "
+                "verification (tq > 1) would need its roll-back")
+        dIn, K, W = c.innerSize, c.convKernel, c.window
+        half = c.nLayers // 2
+        ps = k.shape[2]
+        rows = jnp.arange(S, dtype=_I32)
+        active = pos > 0
+        # the paged layer: this step's row, and every held row in order
+        phys = pageTable[rows, pos // ps]
+        off = pos % ps
+        cap = pageTable.shape[1] * ps
+        kpos = jnp.arange(cap, dtype=_I32)[None, :]
+        validP = ((kpos <= pos[:, None]) & (kpos >= start[:, None]))[:, None]
+        # a ring row r holds the newest position <= pos that is r mod W
+        rIdx = pos % W
+        r = jnp.arange(W, dtype=_I32)[None, :]
+        held = pos[:, None] - (pos[:, None] - r) % W
+        validR = (held >= start[:, None])[:, None]            # (S, 1, W)
+        x = params["emb"][toks[:, 0]]                         # (S, d)
+        cd = x.dtype
+        keep = lambda new, old: jnp.where(
+            active.reshape((S,) + (1,) * (new.ndim - 1)), new, old)
+        mem = kAll = vAll = None
+        mi = wi = 0
+        for i, (kind, lp) in enumerate(zip(c.layerKinds(),
+                                           params["layers"])):
+            h = _ln(x, lp["ln1_g"], lp["ln1_b"], c.eps)
+            if kind == "mamba":
+                xz = _mm(h, lp["Win"])
+                u, z = xz[:, :dIn], xz[:, dIn:]
+                win = jnp.concatenate(
+                    [conv[mi].astype(_F32), u[:, None]], axis=1)  # (S, K, dIn)
+                conv = conv.at[mi].set(keep(win[:, 1:].astype(conv.dtype),
+                                            conv[mi]))
+                u = jax.nn.silu(
+                    jnp.sum(win * lp["convW"].astype(_F32)[None], axis=1)
+                    + lp["convB"].astype(_F32))
+                Dt, B, C = self._ssm_inputs(lp, u)
+                s, y = _ssm_step(ssm[mi], Dt, u, B, C,
+                                 -jnp.exp(lp["AlogT"].astype(_F32)))
+                ssm = ssm.at[mi].set(keep(s, ssm[mi]))
+                y = y + lp["D"].astype(_F32) * u
+                if i == half:
+                    mem = y
+                out = _mm(y * jax.nn.silu(z), lp["Wout"])
+                mi += 1
+            elif kind == "gmu":
+                out = _mm(jax.nn.silu(_mm(h, lp["W1"])) * mem, lp["W2"])
+            else:
+                q = _mm(h, lp["Wq"]).astype(cd)[:, None]      # (S, 1, H*dh)
+                if kind != "cross":
+                    kN = _mm(h, lp["Wk"]).astype(cd)
+                    vN = _mm(h, lp["Wv"]).astype(cd)
+                if kind == "window":
+                    ringK = ringK.at[wi, rows, rIdx].set(
+                        keep(kN, ringK[wi, rows, rIdx]))
+                    ringV = ringV.at[wi, rows, rIdx].set(
+                        keep(vN, ringV[wi, rows, rIdx]))
+                    o = self._diff_attend(lp, i, q, ringK[wi], ringV[wi],
+                                          validR)
+                    wi += 1
+                else:
+                    if kind == "full":
+                        k = k.at[0, phys, off].set(kN.astype(k.dtype))
+                        v = v.at[0, phys, off].set(vN.astype(v.dtype))
+                        kAll = k[0, pageTable].reshape(S, cap, -1)
+                        vAll = v[0, pageTable].reshape(S, cap, -1)
+                    o = self._diff_attend(lp, i, q, kAll, vAll, validP)
+                out = _mm(o[:, 0], lp["Wo"])
+            x = self._ffn(lp, x + out.astype(cd))
+        return (self._logits(params, x)[:, None], k, v, ringK, ringV, ssm,
+                conv)
+
+    def buildPagedDecodeFn(self):
+        """FRESH jitted decode step over the pool's arrays: ``(params,
+        k, v, ringK, ringV, ssm, conv, toks (S, 1), pageTable, pos,
+        start) -> (greedy (S, 1), k, v, ringK, ringV, ssm, conv)``.  The
+        six arrays are DONATED; a fresh identity per build, as
+        ``TransformerLM.buildPagedDecodeFn`` explains."""
+        def step(params, k, v, ringK, ringV, ssm, conv, toks, pageTable,
+                 pos, start):
+            out = self.pagedLogits(params, k, v, ringK, ringV, ssm, conv,
+                                   toks, pageTable, pos, start)
+            return (jnp.argmax(out[0], axis=-1).astype(_I32),) + out[1:]
+        return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5, 6))
+
+    def buildPagedPrefillWriteFn(self):
+        """FRESH jitted admission write: one sequence's prefill state
+        (:meth:`prefillRaw`'s, batch row taken) into the pages
+        ``pageIds`` and into slot ``slot``'s ring rows and recurrent
+        state, which it overwrites whole."""
+        def write(k, v, ringK, ringV, ssm, conv, kStack, vStack, rK, rV,
+                  ssmS, convS, pageIds, slot):
+            k, v = paged_prefill_write(k, v, kStack, vStack, pageIds)
+            z = jnp.zeros((), _I32)
+            put = lambda pool, part: jax.lax.dynamic_update_slice(
+                pool, part[:, None].astype(pool.dtype),
+                (z, slot.astype(_I32)) + (z,) * (pool.ndim - 2))
+            return (k, v, put(ringK, rK), put(ringV, rV), put(ssm, ssmS),
+                    put(conv, convS))
+        return jax.jit(write, donate_argnums=(0, 1, 2, 3, 4, 5))
+
+    def compileCacheSize(self) -> int:
+        n = 0
+        for name in ("_fwd", "_prefillRawFn"):
+            fn = self.__dict__.get(name)
+            if fn is not None:
+                try:
+                    n += int(fn._cache_size())
+                except Exception:
+                    pass
+        return n

@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.attention import (KVCache, cached_attention,
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec, KVCache,
+                                                  cached_attention,
                                                   paged_attention,
                                                   paged_prefill_write)
 
@@ -444,6 +445,12 @@ class TransformerLM:
         exactly-once contract)."""
         return self.prefillRaw(tokens, lengths=lengths)
 
+    def cacheSpec(self) -> CacheSpec:
+        """What the layers keep between decode steps, for the
+        scheduler's pool: every layer owns K/V pages, nothing else."""
+        c = self.config
+        return CacheSpec(c.nLayers, c.nHeads, c.headSize)
+
     def _paged_block(self, lp, li, x, poolK, poolV, pageTable, pos, start):
         """Transformer block ``li`` against the stacked paged pools (the
         ``_block_cached`` math with :func:`paged_attention` in place of
@@ -530,8 +537,9 @@ class TransformerLM:
         bucket (warmed at start).  The layout is
         :func:`paged_prefill_write`'s; the wrapper gives each build its
         own identity and the program the name traces know it by
-        (``jit_write``)."""
-        def write(poolK, poolV, kStack, vStack, pageIds):
+        (``jit_write``).  The scheduler also passes the ``slot``; pages
+        are all this model keeps, so it goes unused."""
+        def write(poolK, poolV, kStack, vStack, pageIds, slot=None):
             return paged_prefill_write(poolK, poolV, kStack, vStack,
                                        pageIds)
         return jax.jit(write, donate_argnums=(0, 1))

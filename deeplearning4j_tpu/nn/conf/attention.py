@@ -14,7 +14,7 @@ dispatch.  Data format follows the DL4J RNN convention (b, nIn, t); masks are
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +26,7 @@ from deeplearning4j_tpu.nn.weights import init_weight
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
            "KVCache", "cached_attention", "paged_attention",
-           "paged_prefill_write"]
+           "paged_prefill_write", "CacheSpec"]
 
 
 def _mha(x_btn, Wq, Wk, Wv, Wo, nHeads, mask=None, q_btn=None, impl="auto",
@@ -184,6 +184,36 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
     w = jax.nn.softmax(s, axis=-1)
     ctx = jnp.einsum("bhqk,bkhd->bhqd", w, v.astype(qh.dtype))
     return ctx, poolK, poolV
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a served model's layers keep between decode steps — the
+    model tells the scheduler's ``KVCachePool`` through its
+    ``cacheSpec()``; the pool allocates exactly this and nothing selects
+    between layouts.  Three kinds of state:
+
+    - *paged*: ``pagedLayers`` layers own K/V pages that grow with the
+      sequence, rows ``kvHeads * headSize`` wide (written by their layer,
+      readable by others).  A GPT-style stack is the case "every layer
+      paged";
+    - *ring*: ``ringLayers`` layers keep the last ``ringRows`` K/V rows
+      of every slot, written modulo ``ringRows``;
+    - *recurrent*: ``slotState`` names fixed-size arrays ``(name,
+      (layers, *shape a slot), dtype)``, overwritten every step and whole
+      at admission.
+    """
+    pagedLayers: int
+    kvHeads: int
+    headSize: int
+    dtype: Any = jnp.float32
+    ringLayers: int = 0
+    ringRows: int = 0
+    slotState: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
+
+    @property
+    def rowWidth(self) -> int:
+        return self.kvHeads * self.headSize
 
 
 def paged_prefill_write(poolK, poolV, kStack, vStack, pageIds):

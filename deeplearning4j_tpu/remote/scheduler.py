@@ -61,6 +61,7 @@ import numpy as np
 # injection registries only — fault/chaos.py imports THIS module lazily,
 # so the package-level import here cannot cycle
 from deeplearning4j_tpu.fault import injection as _inj
+from deeplearning4j_tpu.nn.conf.attention import CacheSpec
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
                                                DeadlineExceeded,
@@ -90,25 +91,48 @@ def _probe_fn():
 
 
 class KVCachePool:
-    """Paged KV memory for one model: ``(nLayers, numPages, pageSize,
-    nHeads*headSize)`` device buffers plus a host-side free list and
-    per-slot page tables.  Pages are token-major (a row is one position,
-    all heads side by side): the two minor dimensions are what the TPU
-    tiles without a re-layout, so the decode step and the prefill write
-    update ``k``/``v`` in place (``paged_attention``).
+    """The cache memory of one served model, as its ``cacheSpec()``
+    (:class:`~deeplearning4j_tpu.nn.conf.attention.CacheSpec`) names it:
+    three kinds of state side by side, device buffers in ``arrays`` and
+    the host-side bookkeeping here.
+
+    - *paged*: ``k``/``v`` ``(pagedLayers, numPages, pageSize,
+      kvHeads*headSize)`` plus a free list and per-slot page tables.
+      Pages are token-major (a row is one position, all heads side by
+      side): the two minor dimensions are what the TPU tiles without a
+      re-layout, so the decode step and the prefill write update them in
+      place (``paged_attention``).  Only the layers that own pages have
+      any: 48 of 48 for GPT-2 XL, one of 32 for a SambaY stack.
+    - *ring*: ``ringK``/``ringV`` ``(ringLayers, maxSlots, ringRows,
+      kvHeads*headSize)``, a slot's last ``ringRows`` positions written
+      modulo ``ringRows``.
+    - *recurrent*: one array ``(layers, maxSlots, ...)`` for each entry
+      of ``spec.slotState``, overwritten every step.
+
+    ``arrays`` is the tuple the step and the admission write take and
+    return, in this order: ``(k, v[, ringK, ringV][, *slotState])`` —
+    ``(k, v)`` when every layer is paged.
 
     Page 0 is the SCRATCH page: inactive slots' table entries point at
     it, so the fixed-shape decode step can write their (ignored) K/V
     somewhere harmless without a gather/scatter shape ever depending on
-    how many slots are live.  ``ensure``/``release`` are plain list
-    edits — allocation never reallocates device memory and never changes
-    an executable shape.
+    how many slots are live.  Ring rows and recurrent state have no
+    scratch: the step leaves them as they are for a slot whose ``pos``
+    is 0, admission overwrites the recurrent state whole, and which
+    position a ring row holds follows from ``pos`` alone, so a reused
+    slot's stale rows are never valid.  ``ensure``/``release`` are plain
+    list edits — allocation never reallocates device memory and never
+    changes an executable shape.
     """
 
     def __init__(self, nLayers: int, nHeads: int, headSize: int,
                  pageSize: int = 8, numPages: int = 64, maxSlots: int = 4,
                  maxPagesPerSeq: int = 8, dtype=jnp.float32,
-                 sharding=None):
+                 sharding=None, spec: Optional[CacheSpec] = None,
+                 slotSharding=None):
+        self.spec = spec if spec is not None else CacheSpec(
+            int(nLayers), int(nHeads), int(headSize), dtype)
+        spec = self.spec
         self.pageSize = int(pageSize)
         self.numPages = int(numPages)
         self.maxSlots = int(maxSlots)
@@ -119,17 +143,79 @@ class KVCachePool:
             raise ValueError(
                 f"numPages={self.numPages} must exceed maxPagesPerSeq="
                 f"{self.maxPagesPerSeq} (page 0 is reserved scratch)")
-        k = jnp.zeros((int(nLayers), self.numPages, self.pageSize,
-                       int(nHeads) * int(headSize)), dtype)
-        v = jnp.zeros_like(k)
-        if sharding is not None:
-            k = jax.device_put(k, sharding)
-            v = jax.device_put(v, sharding)
-        self.k, self.v = k, v
+
+        def zeros(shape, dt, sh):
+            a = jnp.zeros(shape, dt)
+            return a if sh is None else jax.device_put(a, sh)
+        paged = (spec.pagedLayers, self.numPages, self.pageSize,
+                 spec.rowWidth)
+        arrays = [zeros(paged, spec.dtype, sharding) for _ in range(2)]
+        if spec.ringLayers:
+            ring = (spec.ringLayers, self.maxSlots, spec.ringRows,
+                    spec.rowWidth)
+            arrays += [zeros(ring, spec.dtype, slotSharding)
+                       for _ in range(2)]
+        for _name, shape, dt in spec.slotState:
+            arrays.append(zeros((shape[0], self.maxSlots) + tuple(shape[1:]),
+                                dt, slotSharding))
+        self._arrays = tuple(arrays)
         self.pageTable = np.zeros((self.maxSlots, self.maxPagesPerSeq),
                                   np.int32)
         self._free = deque(range(1, self.numPages))
         self._held: List[List[int]] = [[] for _ in range(self.maxSlots)]
+        itemsize = jnp.dtype(spec.dtype).itemsize
+        rowBytes = 2 * spec.rowWidth * itemsize             # K and V
+        #: bytes of each kind per live unit: a page, a ring row (all
+        #: ring layers), a slot's recurrent state
+        self.pageBytes = spec.pagedLayers * self.pageSize * rowBytes
+        self.ringRowBytes = spec.ringLayers * rowBytes
+        self.slotStateBytes = sum(
+            int(np.prod(shape)) * jnp.dtype(dt).itemsize
+            for _name, shape, dt in spec.slotState)
+
+    @classmethod
+    def forSpec(cls, spec: CacheSpec, pageSize: int, numPages: int,
+                maxSlots: int, maxPagesPerSeq: int, sharding=None,
+                slotSharding=None) -> "KVCachePool":
+        return cls(spec.pagedLayers, spec.kvHeads, spec.headSize, pageSize,
+                   numPages, maxSlots, maxPagesPerSeq, spec.dtype, sharding,
+                   spec=spec, slotSharding=slotSharding)
+
+    @property
+    def arrays(self) -> tuple:
+        return self._arrays
+
+    @arrays.setter
+    def arrays(self, arrays) -> None:
+        self._arrays = tuple(arrays)
+
+    # the paged buffers under the names they have always had
+    @property
+    def k(self):
+        return self.arrays[0]
+
+    @k.setter
+    def k(self, a) -> None:
+        self.arrays = (a,) + self.arrays[1:]
+
+    @property
+    def v(self):
+        return self.arrays[1]
+
+    @v.setter
+    def v(self, a) -> None:
+        self.arrays = self.arrays[:1] + (a,) + self.arrays[2:]
+
+    def stateSlots(self) -> int:
+        """Slots that hold a sequence's state (pages, ring rows,
+        recurrent state): from ``ensure`` to ``release``."""
+        return sum(1 for held in self._held if held)
+
+    def ringRowsFor(self, lengths) -> int:
+        """Ring rows that are live for sequences of ``lengths``
+        positions: each holds its last ``ringRows`` at most."""
+        # jaxlint: disable=host-sync -- lengths are the scheduler's host-side slot positions
+        return int(np.minimum(lengths, self.spec.ringRows).sum())
 
     def freePages(self) -> int:
         return len(self._free)
@@ -296,6 +382,12 @@ class ContinuousBatcher:
                 raise ValueError("draftK must be >= 1 with a draft model")
             if draft.config.vocabSize != lm.config.vocabSize:
                 raise ValueError("draft and target must share a vocabulary")
+            specs = (lm.cacheSpec(), draft.cacheSpec())
+            if any(s.ringLayers or s.slotState for s in specs):
+                raise ValueError(
+                    "speculative decode rolls a rejected proposal back by "
+                    "position alone: a model with ring or recurrent state "
+                    "cannot be the target or the draft")
         self.name = str(name)
         cfg = lm.config
         self.pageSize = int(pageSize)
@@ -388,23 +480,22 @@ class ContinuousBatcher:
         return NamedSharding(mesh.mesh, P())
 
     def _buildPools(self) -> None:
-        cfg = self.lm.config
-        self.pool = KVCachePool(
-            cfg.nLayers, cfg.nHeads, cfg.headSize, self.pageSize,
-            self._numPages, self.maxSlots, self._maxPagesPerSeq,
-            sharding=self._poolSharding(cfg.nHeads))
-        if self.draft is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            dc = self.draft.config
-            # the draft replicates on a TP mesh (its params do too)
-            dsh = NamedSharding(self.plan.mesh.mesh, P()) \
-                if self.plan is not None else self._poolSharding(dc.nHeads)
-            self.draftPool = KVCachePool(
-                dc.nLayers, dc.nHeads, dc.headSize, self.pageSize,
-                self._numPages, self.maxSlots, self._maxPagesPerSeq,
-                sharding=dsh)
-        else:
-            self.draftPool = None
+        """One pool per model, shaped by what the model says its layers
+        keep (``cacheSpec()``).  On a TP mesh the paged buffers split by
+        heads; ring and recurrent state, and the draft's pool, replicate
+        (as the draft's params do)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        spec = self.lm.cacheSpec()
+        whole = NamedSharding(self.plan.mesh.mesh, P()) \
+            if self.plan is not None else self._poolSharding(1)
+        self.pool = KVCachePool.forSpec(
+            spec, self.pageSize, self._numPages, self.maxSlots,
+            self._maxPagesPerSeq, sharding=self._poolSharding(spec.kvHeads),
+            slotSharding=whole)
+        self.draftPool = None if self.draft is None else \
+            KVCachePool.forSpec(
+                self.draft.cacheSpec(), self.pageSize, self._numPages,
+                self.maxSlots, self._maxPagesPerSeq, sharding=whole)
 
     def applyPlan(self, plan) -> None:
         """Inference-mode :class:`~deeplearning4j_tpu.parallel.
@@ -475,32 +566,31 @@ class ContinuousBatcher:
         zeros = jnp.zeros(S, jnp.int32)
         pt = jnp.asarray(self.pool.pageTable)
         step = self._stepFns["step"]
-        g, self.pool.k, self.pool.v = step(
-            self.lm.params, self.pool.k, self.pool.v,
+        _g, *self.pool.arrays = step(
+            self.lm.params, *self.pool.arrays,
             jnp.zeros((S, 1), jnp.int32), pt, zeros, zeros)
         if self.draft is not None:
-            g, self.pool.k, self.pool.v = step(
-                self.lm.params, self.pool.k, self.pool.v,
+            _g, *self.pool.arrays = step(
+                self.lm.params, *self.pool.arrays,
                 jnp.zeros((S, self.draftK + 1), jnp.int32), pt, zeros,
                 zeros)
             dpt = jnp.asarray(self.draftPool.pageTable)
-            _p, self.draftPool.k, self.draftPool.v = \
-                self._stepFns["propose"](
-                    self.draft.params, self.draftPool.k, self.draftPool.v,
-                    zeros, dpt, zeros, zeros)
+            _p, *self.draftPool.arrays = self._stepFns["propose"](
+                self.draft.params, *self.draftPool.arrays, zeros, dpt,
+                zeros, zeros)
+        slot0 = jnp.zeros((), jnp.int32)
         for Tp in self.ladder.seqLens:
             dummy = np.zeros((1, Tp), np.int32)
             ids = jnp.zeros(Tp // self.pageSize, jnp.int32)   # scratch
-            logits, ks, vs = self.lm.prefillRaw(dummy, lengths=[1])
-            self.pool.k, self.pool.v = self._stepFns["write"](
-                self.pool.k, self.pool.v, ks[:, 0], vs[:, 0], ids)
+            # slot 0's ring rows and recurrent state take the dummy
+            # writes: an admission overwrites them before any step reads
+            _l, *state = self.lm.prefillRaw(dummy, lengths=[1])
+            self._writeState(self.pool, "write", state, ids, slot0)
             if self.draft is not None:
-                _l, dks, dvs = self.draft.prefillRaw(dummy, lengths=[1])
-                self.draftPool.k, self.draftPool.v = \
-                    self._stepFns["dwrite"](
-                        self.draftPool.k, self.draftPool.v,
-                        dks[:, 0], dvs[:, 0], ids)
-        jax.block_until_ready(self.pool.k)  # jaxlint: sync-ok -- warm-up fence: compile cost must land in warmup_seconds, not the first request
+                _l, *state = self.draft.prefillRaw(dummy, lengths=[1])
+                self._writeState(self.draftPool, "dwrite", state, ids,
+                                 slot0)
+        jax.block_until_ready(self.pool.arrays)  # jaxlint: sync-ok -- warm-up fence: compile cost must land in warmup_seconds, not the first request
         self._warmed = True
         dt = time.perf_counter() - t0
         sm.warmup_seconds().observe(dt, model=self.name)
@@ -976,19 +1066,19 @@ class ContinuousBatcher:
                 model=self.name),
             replica=self.name, slot=slot, bucket=Tp, trace_id=tid)
         with span:
-            logits, ks, vs = prefill(padded, lengths=[seq.realLen])
+            slotA = jnp.asarray(slot, jnp.int32)
             ids = jnp.asarray(self.pool.heldIds(slot)[:nP], jnp.int32)
-            self.pool.k, self.pool.v = self._stepFns["write"](
-                self.pool.k, self.pool.v, ks[:, 0], vs[:, 0], ids)
+            logits, *state = prefill(padded, lengths=[seq.realLen])
+            with tracer().span("serving.state.write", replica=self.name,
+                               slot=slot):
+                self._writeState(self.pool, "write", state, ids, slotA)
             if self.draft is not None:
-                _l, dks, dvs = self.draft.prefillRaw(
+                _l, *state = self.draft.prefillRaw(
                     padded, lengths=[seq.realLen])
                 dids = jnp.asarray(self.draftPool.heldIds(slot)[:nP],
                                    jnp.int32)
-                self.draftPool.k, self.draftPool.v = \
-                    self._stepFns["dwrite"](
-                        self.draftPool.k, self.draftPool.v, dks[:, 0],
-                        dvs[:, 0], dids)
+                self._writeState(self.draftPool, "dwrite", state, dids,
+                                 slotA)
             # jaxlint: sync-ok -- the prefill's greedy token seeds the host-side slot state
             first = int(np.argmax(np.asarray(logits[0])))
             if seq.forced and len(seq.emitted) < len(seq.forced):
@@ -1014,6 +1104,15 @@ class ContinuousBatcher:
         self._updatePageGauges()
         if self._emit(seq, first):
             self._retireSlot(slot)
+
+    def _writeState(self, pool: KVCachePool, fn: str, state, pageIds,
+                    slot) -> None:
+        """Write what one sequence's prefill leaves behind (``state``:
+        ``prefillRaw``'s parts after the logits, batch row 0) — pages,
+        ring rows, recurrent state — into ``pool``, at ``pageIds`` and
+        ``slot``."""
+        pool.arrays = self._stepFns[fn](
+            *pool.arrays, *(part[:, 0] for part in state), pageIds, slot)
 
     def _emit(self, seq: _Seq, tok: int) -> bool:
         """Deliver one token; True when the sequence is finished.  After
@@ -1103,21 +1202,21 @@ class ContinuousBatcher:
             step = self._stepFns["step"]
             with self._phase("dispatch"):
                 if self.draft is not None:
-                    props, self.draftPool.k, self.draftPool.v = \
+                    props, *self.draftPool.arrays = \
                         self._stepFns["propose"](
-                            self.draft.params, self.draftPool.k,
-                            self.draftPool.v, tokA, dpt, pos, startA)
+                            self.draft.params, *self.draftPool.arrays,
+                            tokA, dpt, pos, startA)
                     # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
                     propsH = np.asarray(props)
                     verifyIn = np.concatenate([tokH[:, None], propsH],
                                               axis=1)
-                    greedy, self.pool.k, self.pool.v = step(
-                        self.lm.params, self.pool.k, self.pool.v,
+                    greedy, *self.pool.arrays = step(
+                        self.lm.params, *self.pool.arrays,
                         jnp.asarray(verifyIn), pt, pos, startA)
                 else:
                     props = propsH = None
-                    greedy, self.pool.k, self.pool.v = step(
-                        self.lm.params, self.pool.k, self.pool.v,
+                    greedy, *self.pool.arrays = step(
+                        self.lm.params, *self.pool.arrays,
                         tokA, pt, pos, startA)
             with self._phase("fetch"):
                 # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
@@ -1135,6 +1234,8 @@ class ContinuousBatcher:
                 sm.decode_steps().inc(model=self.name)
                 sm.slot_occupancy().set(len(active) / self.maxSlots,
                                         model=self.name)
+                if self.pool.spec.ringLayers:
+                    self._updateRingGauges(active)
                 after = self.compileCacheSize()
                 if self._cacheSeen is not None and after > self._cacheSeen:
                     sm.compile_misses().inc(after - self._cacheSeen,
@@ -1381,12 +1482,33 @@ class ContinuousBatcher:
     def _finishSeq(self, seq: _Seq, error: Optional[BaseException]) -> None:
         _finish_seq(seq, error, self.name)
 
+    def _updateRingGauges(self, active: List[int]) -> None:
+        """After a step: the ring rows that are live (they grow with a
+        sequence until it passes the window) and the rings that wrapped
+        this step."""
+        sm = serving_metrics()
+        lengths = (self._pos - self._start)[active]
+        rows = self.pool.ringRowsFor(lengths)
+        sm.ring_rows_in_use().set(rows, model=self.name)
+        sm.cache_bytes().set(rows * self.pool.ringRowBytes,
+                             model=self.name, kind="ring")
+        wraps = int(np.count_nonzero(
+            (lengths > 0) & (lengths % self.pool.spec.ringRows == 0)))
+        if wraps:
+            sm.ring_wraps().inc(wraps, model=self.name)
+
     def _updatePageGauges(self) -> None:
         sm = serving_metrics()
         sm.kv_pages_in_use().set(self.pool.usedPages(), model=self.name,
                                  pool="target")
         sm.kv_pages_free().set(self.pool.freePages(), model=self.name,
                                pool="target")
+        slots = self.pool.stateSlots()
+        sm.state_slots_in_use().set(slots, model=self.name)
+        sm.cache_bytes().set(self.pool.usedPages() * self.pool.pageBytes,
+                             model=self.name, kind="paged")
+        sm.cache_bytes().set(slots * self.pool.slotStateBytes,
+                             model=self.name, kind="recurrent")
         if self.draftPool is not None:
             sm.kv_pages_in_use().set(self.draftPool.usedPages(),
                                      model=self.name, pool="draft")

@@ -422,6 +422,35 @@ class ServingMetrics:
             "admission controller's page-headroom signal",
             labelnames=("model", "pool"))
 
+    def state_slots_in_use(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_state_slots_in_use",
+            "Decode slots whose cache state (pages, ring rows, recurrent "
+            "state) belongs to an admitted sequence, per model",
+            labelnames=("model",))
+
+    def ring_rows_in_use(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_ring_rows_in_use",
+            "Live rows of the window layers' K/V rings, summed over "
+            "slots: a sequence holds its last ringRows positions at most",
+            labelnames=("model",))
+
+    def cache_bytes(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_cache_bytes",
+            "Bytes of live cache state by kind: paged (allocated pages of "
+            "the layers that own pages), ring (live ring rows of every "
+            "window layer), recurrent (fixed state of the slots in use)",
+            labelnames=("model", "kind"))
+
+    def ring_wraps(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_ring_wraps_total",
+            "Times a sequence's length passed a multiple of the window, "
+            "so that its rings began to overwrite their oldest rows",
+            labelnames=("model",))
+
     def preemptions(self):
         return get_registry().counter(
             "dl4j_tpu_serving_preemptions_total",

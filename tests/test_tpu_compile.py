@@ -117,3 +117,85 @@ def test_paged_prefill_write_updates_the_pool_in_place(paged, bucket):
     compiled = lm.buildPagedPrefillWriteFn().lower(
         pool, pool, stack, stack, i32(bucket // PAGE_SIZE)).compile()
     _assert_in_place(compiled, pool, f"jit_write[{bucket}]")
+
+
+# -- Phi-4-mini-flash-reasoning as benchmark/configs/phi4_mini_flash.json
+# serves it: all 32 layers and 200064 rows in bfloat16, 32 slots of 2,560
+# positions (160 pages of 16) -- three kinds of cache state in one pool
+PHI_SLOTS, PHI_CAP = 32, 2560
+HBM_BYTES = 15.75e9         # what the compiler grants a program on a v5e
+
+
+@pytest.fixture(scope="module")
+def sambay(one_chip):
+    """``(lm, params, pool arrays, i32)`` as shapes on the described
+    chip, at the published sizes."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.sambay import SambaYConfig, SambaYLM
+    from deeplearning4j_tpu.remote import KVCachePool
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    lm = SambaYLM(SambaYConfig(
+        vocabSize=200064, nLayers=32, hiddenSize=2560, nHeads=40,
+        nKvHeads=20, ffnSize=10240, window=512, stateSize=16, convKernel=4,
+        expand=2, dtRank=160, maxLen=PHI_CAP), params={})
+    params = on_chip(jax.eval_shape(lm._init_params))
+    perSeq = PHI_CAP // PAGE_SIZE
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool.forSpec(
+        lm.cacheSpec(), PAGE_SIZE, 1 + PHI_SLOTS * perSeq, PHI_SLOTS,
+        perSeq).arrays))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    return lm, params, pool, i32
+
+
+def _whole_array_copies(compiled, arrays):
+    """``copy`` instructions whose result is as large as one of the
+    pool's arrays (a ring layer's slice re-laid for its matmul is not)."""
+    shapes = {str(a.dtype).replace("bfloat16", "bf16").replace(
+        "float32", "f32") + "[" + ",".join(str(n) for n in a.shape) + "]"
+        for a in arrays}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?(%?[\w.\-]+) = (\S+) copy\(", line)
+        if m and any(m.group(2).startswith(s) for s in shapes):
+            found.append(m.group(1))
+    return found
+
+
+def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
+    lm, params, pool, i32 = sambay
+    perSeq = PHI_CAP // PAGE_SIZE
+    compiled = lm.buildPagedDecodeFn().lower(
+        params, *pool, i32(PHI_SLOTS, 1), i32(PHI_SLOTS, perSeq),
+        i32(PHI_SLOTS), i32(PHI_SLOTS)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    # the six arrays are donated and come back aliased, not copied
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert mem.alias_size_in_bytes >= poolBytes
+    assert not _whole_array_copies(compiled, pool)
+
+
+def test_sambay_prefill_and_admission_write_fit(sambay):
+    import jax
+    lm, params, pool, i32 = sambay
+    bucket = 512
+    compiled = lm._prefillRawFn.lower(params, i32(1, bucket),
+                                      i32(1)).compile()
+    mem = compiled.memory_analysis()
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + poolBytes \
+        < HBM_BYTES
+    state = jax.eval_shape(lm._prefillRawFn, params, i32(1, bucket),
+                           i32(1))[1:]
+    parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
+                                  sharding=pool[0].sharding) for p in state]
+    write = lm.buildPagedPrefillWriteFn().lower(
+        *pool, *parts, i32(bucket // PAGE_SIZE), i32()).compile()
+    assert not _whole_array_copies(write, pool)
+    assert write.memory_analysis().temp_size_in_bytes < 64e6

@@ -263,11 +263,21 @@ class _DonationIndex:
                         (model.src.relpath, cls, ctext))
         out: List[str] = []
         if spec:
+            # a starred argument spreads over positions only the callee
+            # knows: it is donated when a donated position lies at or
+            # past it, and what follows it cannot be placed at all
+            star = next((i for i, a in enumerate(call.args)
+                         if isinstance(a, ast.Starred)), len(call.args))
             for p in spec.positions:
-                if 0 <= p < len(call.args):
+                if 0 <= p < star:
                     t = expr_text(call.args[p])
                     if t:
                         out.append(t)
+            if star < len(call.args) and \
+                    any(p >= star for p in spec.positions):
+                t = expr_text(call.args[star].value)
+                if t:
+                    out.append(t)
             for n in spec.names:
                 for kw in call.keywords:
                     if kw.arg == n:
