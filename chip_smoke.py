@@ -427,7 +427,8 @@ def _check_lm_dtypes(r: Report, lm, pool, slots: int) -> None:
                                           jnp.zeros((1,), jnp.int32)),
         "decode": lm.buildPagedDecodeFn().lower(
             lm.params, pool.k, pool.v, jnp.zeros((slots, 1), jnp.int32),
-            jnp.asarray(pool.pageTable), zeros, zeros)}
+            jnp.zeros((slots, 1), jnp.int32), jnp.asarray(pool.pageTable),
+            zeros, zeros)}
     for name, low in lowered.items():
         n = _f64_count(low)
         r.values[f"f64_in_{name}"] = n

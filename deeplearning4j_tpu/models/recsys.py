@@ -41,7 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  paged_prefill_write)
+                                                  paged_prefill_write,
+                                                  paged_step_tokens)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer, register_layer
 from deeplearning4j_tpu.nn.lossfunctions import get_loss
@@ -234,15 +235,18 @@ class RetrievalLM:
     # -- decode ---------------------------------------------------------
     def buildPagedDecodeFn(self):
         """FRESH jitted retrieval step: ``(params, poolK, poolV,
-        toks (S, 1), pageTable, pos, start) -> (next item (S, 1), poolK,
-        poolV)`` over the token-major pools ``(1, numPages, pageSize,
-        d)`` (one layer, one head: a row is one position's ``d``
-        channels).  ``toks`` carries each slot's last-emitted item; the
-        step writes it into the V pool at ``pos``, masks every item the
-        pool says was already emitted, and emits the next-ranked item.
-        Pool buffers are donated; fresh identity per build for the same
-        cache-hygiene reasons as the transformer decode."""
-        def step(params, poolK, poolV, toks, pageTable, pos, start):
+        toks (S, 1), prev (S, 1), pageTable, pos, start) -> (next item
+        (S, 1), poolK, poolV)`` over the token-major pools ``(1,
+        numPages, pageSize, d)`` (one layer, one head: a row is one
+        position's ``d`` channels).  ``toks`` carries each slot's
+        last-emitted item, or -1 where it is the step before's output
+        ``prev``; the step writes it into the V pool at ``pos``, masks
+        every item the pool says was already emitted, and emits the
+        next-ranked item.  Pool buffers are donated; fresh identity per
+        build for the same cache-hygiene reasons as the transformer
+        decode."""
+        def step(params, poolK, poolV, toks, prev, pageTable, pos, start):
+            toks = paged_step_tokens(toks, prev)
             S = toks.shape[0]
             ps = poolV.shape[2]
             rows = jnp.arange(S)

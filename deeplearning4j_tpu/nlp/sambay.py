@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  paged_prefill_write)
+                                                  paged_prefill_write,
+                                                  paged_step_tokens)
 
 __all__ = ["SambaYConfig", "SambaYLM"]
 
@@ -478,14 +479,16 @@ class SambaYLM:
 
     def buildPagedDecodeFn(self):
         """FRESH jitted decode step over the pool's arrays: ``(params,
-        k, v, ringK, ringV, ssm, conv, toks (S, 1), pageTable, pos,
-        start) -> (greedy (S, 1), k, v, ringK, ringV, ssm, conv)``.  The
-        six arrays are DONATED; a fresh identity per build, as
+        k, v, ringK, ringV, ssm, conv, toks (S, 1), prev (S, 1),
+        pageTable, pos, start) -> (greedy (S, 1), k, v, ringK, ringV,
+        ssm, conv)``.  The six arrays are DONATED, a slot whose ``toks``
+        is -1 takes ``prev``; a fresh identity per build, all as
         ``TransformerLM.buildPagedDecodeFn`` explains."""
-        def step(params, k, v, ringK, ringV, ssm, conv, toks, pageTable,
-                 pos, start):
+        def step(params, k, v, ringK, ringV, ssm, conv, toks, prev,
+                 pageTable, pos, start):
             out = self.pagedLogits(params, k, v, ringK, ringV, ssm, conv,
-                                   toks, pageTable, pos, start)
+                                   paged_step_tokens(toks, prev), pageTable,
+                                   pos, start)
             return (jnp.argmax(out[0], axis=-1).astype(_I32),) + out[1:]
         return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5, 6))
 

@@ -34,7 +34,8 @@ import numpy as np
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec, KVCache,
                                                   cached_attention,
                                                   paged_attention,
-                                                  paged_prefill_write)
+                                                  paged_prefill_write,
+                                                  paged_step_tokens)
 
 __all__ = ["TransformerLMConfig", "TransformerLM"]
 
@@ -496,17 +497,20 @@ class TransformerLM:
     def buildPagedDecodeFn(self):
         """FRESH jitted paged decode/verify step over a
         ``KVCachePool``'s buffers: ``(params, poolK, poolV, toks (S,tq),
-        pageTable, pos, start) -> (greedy (S,tq), poolK, poolV)``.  tq=1
-        is the plain decode step; tq=draftK+1 the speculative verify.
-        Pool buffers are DONATED (the pool swaps in the returned
-        arrays).  A fresh function identity per build is deliberate:
-        JAX's jaxpr cache keys on function identity + avals, so reusing
-        one closure across a pool/plan rebuild could resurrect
-        constraints traced for the old layout — the scheduler pops and
-        rebuilds these on every pool/plan change."""
-        def step(params, poolK, poolV, toks, pageTable, pos, start):
-            return self._paged_step_math(params, poolK, poolV, toks,
-                                         pageTable, pos, start)
+        prev (S,1), pageTable, pos, start) -> (greedy (S,tq), poolK,
+        poolV)``.  tq=1 is the plain decode step; tq=draftK+1 the
+        speculative verify.  A slot whose ``toks`` is -1 takes ``prev``,
+        the step before's greedy output, still on the device
+        (:func:`paged_step_tokens`).  Pool buffers are DONATED (the pool
+        swaps in the returned arrays).  A fresh function identity per
+        build is deliberate: JAX's jaxpr cache keys on function identity
+        + avals, so reusing one closure across a pool/plan rebuild could
+        resurrect constraints traced for the old layout — the scheduler
+        pops and rebuilds these on every pool/plan change."""
+        def step(params, poolK, poolV, toks, prev, pageTable, pos, start):
+            return self._paged_step_math(
+                params, poolK, poolV, paged_step_tokens(toks, prev),
+                pageTable, pos, start)
         return jax.jit(step, donate_argnums=(1, 2))
 
     def buildPagedProposeFn(self, draftK: int):

@@ -26,7 +26,7 @@ from deeplearning4j_tpu.nn.weights import init_weight
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
            "KVCache", "cached_attention", "paged_attention",
-           "paged_prefill_write", "CacheSpec"]
+           "paged_prefill_write", "paged_step_tokens", "CacheSpec"]
 
 
 def _mha(x_btn, Wq, Wk, Wv, Wo, nHeads, mask=None, q_btn=None, impl="auto",
@@ -229,6 +229,17 @@ def paged_prefill_write(poolK, poolV, kStack, vStack, pageIds):
             L, Tp // ps, ps, h * d).astype(pool.dtype)
     return (poolK.at[:, pageIds].set(pages(kStack, poolK)),
             poolV.at[:, pageIds].set(pages(vStack, poolV)))
+
+
+def paged_step_tokens(toks, prev):
+    """Where each slot's input token of a paged decode step comes from:
+    ``toks`` ((S, tq) int32, from the host) wherever it names a token,
+    and the step before's output ``prev`` ((S, 1), still on the device)
+    wherever the host wrote ``-1`` because it had not read that token
+    yet.  Part of the step's own program in every served model, so the
+    scheduler's loop can dispatch a step before it has fetched the one
+    before (``ContinuousBatcher``)."""
+    return jnp.where(toks < 0, prev, toks)
 
 
 @dataclasses.dataclass

@@ -320,6 +320,22 @@ class _Seq:
         self.lastTokT: Optional[float] = None  # last FRESH token's time
 
 
+class _Flight:
+    """One dispatched decode step whose tokens the host has not read yet:
+    the step's output on the device, the slots that took part, the
+    sequence each of them held at dispatch and its length after the
+    step.  A token is delivered by that identity, never by slot number:
+    by the time it is read the slot may be empty or hold a newcomer."""
+    __slots__ = ("greedy", "slots", "seqs", "lengths")
+
+    def __init__(self, greedy, slots: List[int],
+                 seqs: List[Optional[_Seq]], lengths: np.ndarray):
+        self.greedy = greedy            # (S, tq) int32, on the device
+        self.slots = slots
+        self.seqs = seqs                # by slot; None where it sat out
+        self.lengths = lengths          # by position in ``slots``
+
+
 def _finish_seq(seq: _Seq, error: Optional[BaseException],
                 model: str) -> None:
     """Deliver a sequence's final verdict to its request.  Module-level
@@ -365,6 +381,35 @@ class ContinuousBatcher:
     :class:`~deeplearning4j_tpu.remote.serving.BucketedExecutor` —
     ``{"tokens": [...], "maxNewTokens": n}`` payloads, plus
     ``{"stream": true}`` for per-token NDJSON streaming.
+
+    The loop runs ONE step ahead of the device: an iteration dispatches
+    step k and only then reads step k-1's tokens, so the device computes
+    while the host emits, does its books and prepares the next step.  A
+    slot that took part in the unread step takes its input token from
+    that step's output on the device (``paged_step_tokens``); a slot
+    admitted since, resumed after a deferred round or under
+    teacher-forced replay takes the host's.  ``pos`` advances at
+    dispatch.  A sequence that ends by its quota is known ahead: the
+    dispatch of the step that computes its last token frees its slot and
+    pages for the next admission, and the tokens still unread reach it
+    by the flight's record of who held the slot (``_parted``).  One whose
+    end the host learns from the token itself or from outside (EOS,
+    cancel, deadline, preemption) has one more token computed, which is
+    discarded by that same identity
+    (``dl4j_tpu_serving_decode_tokens_discarded_total``).  Either way
+    the last K/V row went into a page the sequence held at dispatch, and
+    device order puts the next occupant's prefill write after it.  At
+    most one step is ever unread, and it is read before the loop waits
+    and dropped when the loop exits or a batch fails.  With a draft
+    model the accept rule moves ``pos`` by a number only the host knows,
+    so nothing is left unread: the same loop reads each step as soon as
+    it is dispatched.
+
+    The price is paid by an arrival: its prefill queues on the device
+    behind the step just dispatched, so the first token comes up to one
+    step later than when the device stood idle between steps (GPT-2 XL
+    on a v5e: TTFT p50 26-30 -> 36-43 ms at 1.3 req/s, PERF.md s6), in
+    exchange for every later token coming sooner.
     """
 
     def __init__(self, lm, name: str = "default", draft=None,
@@ -430,6 +475,14 @@ class ContinuousBatcher:
         self._start = np.zeros(self.maxSlots, np.int32)
         self._tok = np.zeros(self.maxSlots, np.int32)
         self._admitOrder: deque = deque()   # slots, oldest admission first
+        # the dispatched step whose tokens are still on the device, and
+        # what stands in for its output when there is none (``warm``)
+        self._inflight: Optional[_Flight] = None
+        self._noPrev = None
+        # sequences that have left their slot, and given back their
+        # pages, with tokens still unread on the device: the step that
+        # computes a quota's last token is known at its dispatch
+        self._parted: List[_Seq] = []
         # request queue — guarded by _cv
         self._queue: deque = deque()
         self._queuedRows = 0
@@ -564,16 +617,22 @@ class ContinuousBatcher:
         self._ensureFns()
         S = self.maxSlots
         zeros = jnp.zeros(S, jnp.int32)
+        tok0 = jnp.zeros((S, 1), jnp.int32)
         pt = jnp.asarray(self.pool.pageTable)
         step = self._stepFns["step"]
-        _g, *self.pool.arrays = step(
-            self.lm.params, *self.pool.arrays,
-            jnp.zeros((S, 1), jnp.int32), pt, zeros, zeros)
+        prev, *self.pool.arrays = step(
+            self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
+        # and with a step's own output for ``prev``, as every later call
+        # has it: beside committed params that is another entry of the
+        # jit's cache than fresh zeros.  It stands in wherever no step is
+        # unread.
+        self._noPrev, *self.pool.arrays = step(
+            self.lm.params, *self.pool.arrays, tok0, prev, pt, zeros, zeros)
         if self.draft is not None:
             _g, *self.pool.arrays = step(
                 self.lm.params, *self.pool.arrays,
-                jnp.zeros((S, self.draftK + 1), jnp.int32), pt, zeros,
-                zeros)
+                jnp.zeros((S, self.draftK + 1), jnp.int32), self._noPrev,
+                pt, zeros, zeros)
             dpt = jnp.asarray(self.draftPool.pageTable)
             _p, *self.draftPool.arrays = self._stepFns["propose"](
                 self.draft.params, *self.draftPool.arrays, zeros, dpt,
@@ -607,6 +666,8 @@ class ContinuousBatcher:
         sm.queue_depth().set(0, model=self.name)
         sm.compile_hits().inc(0, model=self.name)
         sm.compile_misses().inc(0, model=self.name)
+        sm.decode_steps_overlapped().inc(0, model=self.name)
+        sm.decode_tokens_discarded().inc(0, model=self.name)
         # register the latency-decomposition histograms up front so the
         # hot path's observe_exemplar() finds them already constructed
         sm.ttft_seconds()
@@ -645,10 +706,15 @@ class ContinuousBatcher:
         for slot, seq in enumerate(self._slotSeq):
             if seq is not None:
                 self._retireSlot(slot, error=err)
+        for seq in self._takeParted():
+            self._finishSeq(seq, err)
         serving_metrics().queue_depth().set(0, model=self.name)
 
     def busy(self) -> bool:
-        return any(s is not None for s in self._slotSeq)
+        """A sequence holds a slot, or has parted from one and is still
+        owed its last token."""
+        return bool(self._parted) or \
+            any(s is not None for s in self._slotSeq)
 
     def queuedRows(self) -> int:
         with self._cv:
@@ -899,13 +965,17 @@ class ContinuousBatcher:
             seconds, model=self.name, phase=phase)
 
     def _idle(self) -> bool:
-        return self._queuedRows == 0 and \
+        return self._queuedRows == 0 and self._inflight is None and \
             not any(s is not None for s in self._slotSeq)
 
     def _loop(self) -> None:
         while True:
             with self._cv:
                 if not self._running:
+                    # the unread step is dropped: whoever stopped the
+                    # loop fails or replays its sequences from what they
+                    # had emitted
+                    self._inflight = None
                     return
                 idle = self._idle()
             if idle:
@@ -931,19 +1001,23 @@ class ContinuousBatcher:
                     # serving (fresh fns against the fresh buffers)
                     self.warm()
                     self._cacheSeen = self.compileCacheSize()
-                # the parent of everything a busy iteration does: what a
-                # span costs between two phases is then inside a span too,
-                # so no instant of the loop thread is without a name
-                with tracer().span("serving.loop.iteration"):
-                    with self._phase("admit"):
-                        self._admit()
-                    if any(s is not None for s in self._slotSeq):
-                        self._stepOnce()
+                self._iterate()
             except Exception as e:
                 # the scheduler thread must survive ANY dispatch failure
                 # (device OOM, a jit error): fail the affected work, not
                 # every future request (cf. BucketedExecutor._loop)
                 self._failBatch(e)
+
+    def _iterate(self) -> None:
+        """One busy iteration: admit, then the decode work.  The span is
+        the parent of everything in it: what a span costs between two
+        phases is then inside a span too, so no instant of the loop
+        thread is without a name."""
+        with tracer().span("serving.loop.iteration"):
+            with self._phase("admit"):
+                self._admit()
+            if self.busy() or self._inflight is not None:
+                self._stepOnce()
 
     def _failBatch(self, error: BaseException) -> None:
         """Last-resort recovery for a failed shared step: hand every
@@ -954,21 +1028,22 @@ class ContinuousBatcher:
         so the old arrays cannot be trusted (or even alive)."""
         handler = self.onSequenceFailure
         handed: List[_Seq] = []
+        self._inflight = None           # its tokens are never delivered
         for slot, seq in enumerate(self._slotSeq):
             if seq is None:
                 continue
             if handler is not None and not seq.cancelled:
-                self.pool.release(slot)
-                if self.draftPool is not None:
-                    self.draftPool.release(slot)
-                self._slotSeq[slot] = None
-                self._pos[slot] = self._start[slot] = self._tok[slot] = 0
-                if slot in self._admitOrder:
-                    self._admitOrder.remove(slot)
+                self._vacate(slot)
                 self._resetForReplay(seq)
                 handed.append(seq)
             else:
                 self._retireSlot(slot, error=error)
+        for seq in self._takeParted():
+            if handler is not None and not seq.cancelled:
+                self._resetForReplay(seq)
+                handed.append(seq)
+            else:
+                self._finishSeq(seq, error)
         self._buildPools()
         self._invalidateFns()
         self._updatePageGauges()
@@ -1159,6 +1234,9 @@ class ContinuousBatcher:
         return self.eosToken is not None and tok == self.eosToken
 
     def _stepOnce(self) -> None:
+        """One iteration's decode work: dispatch the next step, then read
+        the one that was dispatched an iteration ago — or, with a draft,
+        the one just dispatched."""
         delay = _inj.replica_slowdown(self.name)
         if delay:
             time.sleep(delay)           # injected brownout (SlowReplica)
@@ -1167,85 +1245,122 @@ class ContinuousBatcher:
             with self._phase("grow"):
                 active, deferred = self._growPages()
             stepArgs["active"] = len(active)
-            if not active:
-                return
-            with self._phase("upload"):
-                if deferred:
-                    # mask deferred rows onto the scratch page with
-                    # zeroed state: the fixed-shape step still computes
-                    # them, but their writes land in scratch and their
-                    # REAL page tables / slot state stay untouched for
-                    # the next round
-                    ptH = self.pool.pageTable.copy()
-                    posH = self._pos.copy()
-                    startH = self._start.copy()
-                    tokH = self._tok.copy()
-                    for s in deferred:
-                        ptH[s, :] = 0
-                        posH[s] = startH[s] = tokH[s] = 0
-                else:
-                    ptH, posH, startH, tokH = (self.pool.pageTable,
-                                               self._pos, self._start,
-                                               self._tok)
-                pt = jnp.asarray(ptH)
-                pos = jnp.asarray(posH)
-                startA = jnp.asarray(startH)
-                if self.draft is not None:
-                    dptH = self.draftPool.pageTable
-                    if deferred:
-                        dptH = dptH.copy()
-                        for s in deferred:
-                            dptH[s, :] = 0
-                    tokA, dpt = jnp.asarray(tokH), jnp.asarray(dptH)
-                else:
-                    tokA, dpt = jnp.asarray(tokH[:, None]), None
-            step = self._stepFns["step"]
-            with self._phase("dispatch"):
-                if self.draft is not None:
-                    props, *self.draftPool.arrays = \
-                        self._stepFns["propose"](
-                            self.draft.params, *self.draftPool.arrays,
-                            tokA, dpt, pos, startA)
-                    # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
-                    propsH = np.asarray(props)
-                    verifyIn = np.concatenate([tokH[:, None], propsH],
-                                              axis=1)
-                    greedy, *self.pool.arrays = step(
-                        self.lm.params, *self.pool.arrays,
-                        jnp.asarray(verifyIn), pt, pos, startA)
-                else:
-                    props = propsH = None
-                    greedy, *self.pool.arrays = step(
-                        self.lm.params, *self.pool.arrays,
-                        tokA, pt, pos, startA)
-            with self._phase("fetch"):
-                # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
-                g = np.asarray(greedy)
-            with self._phase("emit"):
-                self._emitStep(active, g, propsH)
-            with self._phase("bookkeep"):
-                # the step's device arrays die here and not at the return,
-                # so that freeing them is inside a phase (tens of
-                # microseconds: the loop's time is to be accounted for)
-                del greedy, props, tokA, dpt, pt, pos, startA
-                sm = serving_metrics()
-                self._steps += 1
-                self._busySteps += len(active) / self.maxSlots
-                sm.decode_steps().inc(model=self.name)
-                sm.slot_occupancy().set(len(active) / self.maxSlots,
+            flight, propsH = self._dispatch(active, deferred) if active \
+                else (None, None)
+            if self.draft is None:
+                # one step ahead: what was just dispatched stays unread
+                # while the host delivers the step before it
+                flight, self._inflight = self._inflight, flight
+            if flight is not None:
+                self._land(flight, propsH)
+
+    def _dispatch(self, active: List[int], deferred: set
+                  ) -> Tuple[_Flight, Optional[np.ndarray]]:
+        """Upload the slots' state and dispatch one step for ``active``;
+        the host's ``pos`` moves on by what was dispatched, and a
+        sequence whose quota this step fills leaves its slot.  Returns
+        the step and the draft's proposals (None without a draft)."""
+        ahead = self._inflight
+        with self._phase("upload"):
+            # copies: the slots' state moves on while the step runs, and
+            # on the CPU a device array may alias the numpy buffer it
+            # was made from
+            ptH = self.pool.pageTable.copy()
+            posH = self._pos.copy()
+            startH = self._start.copy()
+            tokH = self._tok.copy()
+            # deferred rows go to the scratch page with zeroed state: the
+            # fixed-shape step still computes them, but their writes land
+            # in scratch and their REAL page tables / slot state stay
+            # untouched for the next round
+            for s in deferred:
+                ptH[s, :] = 0
+                posH[s] = startH[s] = tokH[s] = 0
+            seqs: List[Optional[_Seq]] = [None] * self.maxSlots
+            for s in active:
+                seq = seqs[s] = self._slotSeq[s]
+                if ahead is not None and ahead.seqs[s] is seq:
+                    # its input is the unread step's output: on the
+                    # device, unless a replay forces it (known ahead)
+                    n = len(seq.emitted)
+                    tokH[s] = seq.forced[n] if n < len(seq.forced) else -1
+            pt = jnp.asarray(ptH)
+            pos = jnp.asarray(posH)
+            startA = jnp.asarray(startH)
+            if self.draft is not None:
+                dptH = self.draftPool.pageTable.copy()
+                for s in deferred:
+                    dptH[s, :] = 0
+                tokA, dpt = jnp.asarray(tokH), jnp.asarray(dptH)
+            else:
+                tokA = jnp.asarray(tokH[:, None])
+        step = self._stepFns["step"]
+        with self._phase("dispatch"):
+            prev = self._noPrev if ahead is None else ahead.greedy
+            if self.draft is not None:
+                props, *self.draftPool.arrays = \
+                    self._stepFns["propose"](
+                        self.draft.params, *self.draftPool.arrays,
+                        tokA, dpt, pos, startA)
+                # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
+                propsH = np.asarray(props)
+                tokA = jnp.asarray(np.concatenate([tokH[:, None], propsH],
+                                                  axis=1))
+                del props, dpt
+            else:
+                propsH = None
+            greedy, *self.pool.arrays = step(
+                self.lm.params, *self.pool.arrays, tokA, prev, pt, pos,
+                startA)
+            # the step's inputs die here and not at the return, so that
+            # freeing them is inside a phase (tens of microseconds: the
+            # loop's time is to be accounted for)
+            del tokA, prev, pt, pos, startA
+            if ahead is not None:
+                serving_metrics().decode_steps_overlapped().inc(
+                    model=self.name)
+            self._pos[active] += 1
+            flight = _Flight(greedy, active, seqs,
+                             (self._pos - self._start)[active])
+            for s in active:
+                seq = seqs[s]
+                unread = ahead is not None and ahead.seqs[s] is seq
+                if len(seq.emitted) + unread + 1 >= seq.quota:
+                    # this step computes its last token: known ahead, so
+                    # the slot and its pages are free for the next
+                    # admission now (device order keeps that one's
+                    # prefill write behind this step's K/V row); the
+                    # token finds the sequence by the flight's record
+                    self._retireSlot(s, parting=True)
+            return flight, propsH
+
+    def _land(self, flight: _Flight, propsH) -> None:
+        """Read a dispatched step's tokens, deliver them, do the books."""
+        with self._phase("fetch"):
+            # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
+            g = np.asarray(flight.greedy)
+        with self._phase("emit"):
+            self._emitStep(flight, g, propsH)
+        with self._phase("bookkeep"):
+            flight.greedy = None        # freed inside a phase, as above
+            sm = serving_metrics()
+            occupied = len(flight.slots) / self.maxSlots
+            self._steps += 1
+            self._busySteps += occupied
+            sm.decode_steps().inc(model=self.name)
+            sm.slot_occupancy().set(occupied, model=self.name)
+            if self.pool.spec.ringLayers:
+                self._updateRingGauges(flight.lengths)
+            after = self.compileCacheSize()
+            if self._cacheSeen is not None and after > self._cacheSeen:
+                sm.compile_misses().inc(after - self._cacheSeen,
                                         model=self.name)
-                if self.pool.spec.ringLayers:
-                    self._updateRingGauges(active)
-                after = self.compileCacheSize()
-                if self._cacheSeen is not None and after > self._cacheSeen:
-                    sm.compile_misses().inc(after - self._cacheSeen,
-                                            model=self.name)
-                    self._cacheSeen = after
-                else:
-                    sm.compile_hits().inc(model=self.name)
+                self._cacheSeen = after
+            else:
+                sm.compile_hits().inc(model=self.name)
 
     def _growPages(self) -> Tuple[List[int], set]:
-        """Between steps: retire what ran out of time, then give every
+        """Before a step: retire what ran out of time, then give every
         slot the pages its next step writes; returns the slots that step
         and those deferred a round."""
         now = time.monotonic()
@@ -1284,16 +1399,20 @@ class ContinuousBatcher:
                   if s is not None and i not in deferred]
         return active, deferred
 
-    def _emitStep(self, active: List[int], g, propsH) -> None:
+    def _emitStep(self, flight: _Flight, g, propsH) -> None:
         """Deliver one step's tokens, slot by slot: accept rule, emission,
         slot state, timeline note, retirement."""
         sm = serving_metrics()
-        for s in active:
-            seq = self._slotSeq[s]
-            if seq is None:
+        for s in flight.slots:
+            seq = flight.seqs[s]
+            held = self._slotSeq[s] is seq
+            if not held and seq not in self._parted:
+                # it ended after the dispatch (EOS, cancelled, expired,
+                # preempted): this token is nobody's
+                sm.decode_tokens_discarded().inc(model=self.name)
                 continue
             if seq.cancelled:
-                self._retireSlot(s)
+                self._retire(s, seq)
                 continue
             remForced = len(seq.forced) - len(seq.emitted)
             if propsH is not None and remForced <= 0:
@@ -1321,27 +1440,31 @@ class ContinuousBatcher:
                 done = self._emit(seq, int(t))
                 if done:
                     break
-            self._pos[s] += len(newToks)
-            self._tok[s] = int(newToks[-1])
+            if held:
+                self._pos[s] += len(newToks) - 1    # one went at dispatch
+                self._tok[s] = int(newToks[-1])
             timeline_store().note(
                 seq.ctx.traceId if seq.ctx is not None else None,
                 "serving.decode.step", replica=self.name, slot=s,
                 tokens=len(seq.emitted))
             if done:
-                self._retireSlot(s)
+                self._retire(s, seq)
+
+    def _retire(self, slot: int, seq: _Seq) -> None:
+        """Finish ``seq`` at the read of a step it took part in in
+        ``slot``: it still holds the slot, or it has parted from it."""
+        if seq in self._parted:
+            self._parted.remove(seq)
+            self._finishSeq(seq, None)
+        else:
+            self._retireSlot(slot)
 
     def _preempt(self, slot: int) -> None:
         """Evict the youngest slot to free pages: release everything it
         holds and requeue it at the FRONT.  Greedy decode is
         deterministic, so the restart regenerates the identical prefix;
         ``streamSkip`` swallows the re-emissions."""
-        seq = self._slotSeq[slot]
-        freed = self.pool.release(slot)
-        if self.draftPool is not None:
-            freed += self.draftPool.release(slot)
-        self._slotSeq[slot] = None
-        self._pos[slot] = self._start[slot] = self._tok[slot] = 0
-        self._admitOrder.remove(slot)
+        seq, _freed = self._vacate(slot)
         self._resetForReplay(seq)
         with self._cv:
             self._queue.appendleft(seq)
@@ -1421,17 +1544,9 @@ class ContinuousBatcher:
         if joined:
             self._thread = None
             for slot in list(self._admitOrder):
-                seq = self._slotSeq[slot]
-                if seq is None:
-                    continue
-                self.pool.release(slot)
-                if self.draftPool is not None:
-                    self.draftPool.release(slot)
-                self._slotSeq[slot] = None
-                self._pos[slot] = self._start[slot] = 0
-                self._tok[slot] = 0
-                inflight.append(seq)
-            self._admitOrder.clear()
+                if self._slotSeq[slot] is not None:
+                    inflight.append(self._vacate(slot)[0])
+            inflight.extend(self._takeParted())
             self._updatePageGauges()
         else:
             # wedged mid-step: its slots cannot be failed over safely
@@ -1441,11 +1556,13 @@ class ContinuousBatcher:
 
             def reap():
                 wedged.join()
+                err = RuntimeError(f"replica {self.name!r} evacuated "
+                                   f"while wedged mid-step")
                 for slot, seq in enumerate(self._slotSeq):
                     if seq is not None:
-                        self._retireSlot(slot, error=RuntimeError(
-                            f"replica {self.name!r} evacuated while "
-                            f"wedged mid-step"))
+                        self._retireSlot(slot, error=err)
+                for seq in self._takeParted():
+                    self._finishSeq(seq, err)
             threading.Thread(target=reap, daemon=True,
                              name=f"cbatch-wedge-reap-{self.name}"
                              ).start()
@@ -1463,8 +1580,23 @@ class ContinuousBatcher:
         serving_metrics().queue_depth().set(0, model=self.name)
         return out
 
-    def _retireSlot(self, slot: int, error: Optional[BaseException] = None
-                    ) -> None:
+    def _retireSlot(self, slot: int, error: Optional[BaseException] = None,
+                    parting: bool = False) -> None:
+        seq, freed = self._vacate(slot)
+        self._retireLog.append((time.monotonic(), freed))
+        sm = serving_metrics()
+        sm.sequences_retired().inc(model=self.name)
+        self._updatePageGauges()
+        if parting:
+            # its last token is still on the device: the verdict waits
+            # for the read of that step
+            self._parted.append(seq)
+        else:
+            self._finishSeq(seq, error)
+
+    def _vacate(self, slot: int) -> Tuple[Optional[_Seq], int]:
+        """Take the sequence out of ``slot`` and give back its pages;
+        returns it and the number of pages freed."""
         seq = self._slotSeq[slot]
         freed = self.pool.release(slot)
         if self.draftPool is not None:
@@ -1473,27 +1605,28 @@ class ContinuousBatcher:
         self._pos[slot] = self._start[slot] = self._tok[slot] = 0
         if slot in self._admitOrder:
             self._admitOrder.remove(slot)
-        self._retireLog.append((time.monotonic(), freed))
-        sm = serving_metrics()
-        sm.sequences_retired().inc(model=self.name)
-        self._updatePageGauges()
-        self._finishSeq(seq, error)
+        return seq, freed
+
+    def _takeParted(self) -> List[_Seq]:
+        """The sequences that parted from their slots and were not read
+        to their end, when the step that owes them a token is dropped."""
+        parted, self._parted = self._parted, []
+        return parted
 
     def _finishSeq(self, seq: _Seq, error: Optional[BaseException]) -> None:
         _finish_seq(seq, error, self.name)
 
-    def _updateRingGauges(self, active: List[int]) -> None:
-        """After a step: the ring rows that are live (they grow with a
-        sequence until it passes the window) and the rings that wrapped
-        this step."""
+    def _updateRingGauges(self, lengths) -> None:
+        """After a step is read: the ring rows that are live now (they
+        grow with a sequence until it passes the window) and the rings
+        that wrapped in that step, whose slots then held ``lengths``."""
         sm = serving_metrics()
-        lengths = (self._pos - self._start)[active]
-        rows = self.pool.ringRowsFor(lengths)
+        rows = self.pool.ringRowsFor(self._pos - self._start)
         sm.ring_rows_in_use().set(rows, model=self.name)
         sm.cache_bytes().set(rows * self.pool.ringRowBytes,
                              model=self.name, kind="ring")
         wraps = int(np.count_nonzero(
-            (lengths > 0) & (lengths % self.pool.spec.ringRows == 0)))
+            lengths % self.pool.spec.ringRows == 0))
         if wraps:
             sm.ring_wraps().inc(wraps, model=self.name)
 

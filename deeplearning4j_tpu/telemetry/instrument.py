@@ -478,6 +478,24 @@ class ServingMetrics:
             "(one fixed-shape executable call per step)",
             labelnames=("model",))
 
+    def decode_steps_overlapped(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_decode_steps_overlapped_total",
+            "Decode steps dispatched while the step before was still "
+            "unread on the device, so that the device ran while the host "
+            "emitted and prepared (over decode_steps_total: the share of "
+            "steps for which the loop was one step ahead; 0 with a draft "
+            "model)",
+            labelnames=("model",))
+
+    def decode_tokens_discarded(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_decode_tokens_discarded_total",
+            "Tokens computed for a sequence that had left its slot by "
+            "the time they were read (EOS, cancel, deadline or preemption "
+            "learnt one step late): the price of running one step ahead",
+            labelnames=("model",))
+
     def draft_proposed(self):
         return get_registry().counter(
             "dl4j_tpu_serving_draft_tokens_proposed_total",
@@ -573,8 +591,11 @@ class ServingMetrics:
             "admit (queue head to slot: prefill, pool write, first "
             "token), grow (deadline sweep, page growth, preemption), "
             "upload (host slot state to device arrays), dispatch (the "
-            "step executable's call until it returns), fetch (the device "
-            "step and its tokens' D2H, as the host waits for them), emit "
+            "step executable's call until it returns), fetch (the wait for "
+            "the step dispatched an iteration earlier, or with a draft "
+            "model the one just dispatched, and its tokens' D2H: what is "
+            "left of the device step once the other phases ran beside "
+            "it), emit "
             "(accept rule, delivery, timeline, retire), bookkeep "
             "(counters, gauges, compile-cache size); one observation a "
             "phase a loop iteration, per model",

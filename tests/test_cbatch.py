@@ -251,6 +251,302 @@ def test_preemption_restarts_and_recovers_bit_identical():
         cb.shutdown()
 
 
+# ------------------------------------ the loop one step ahead ----
+
+def _count(name, model):
+    return int(get_registry().get(
+        f"dl4j_tpu_serving_{name}_total").value(model=model))
+
+
+def _by_hand(lm, name, **kw):
+    """A warmed batcher that takes requests and has no loop thread: the
+    test calls ``_iterate`` itself, so what is unread on the device at
+    every point of a scenario is known, with no clock in it."""
+    cb = ContinuousBatcher(lm, name=name, pageSize=8, **kw)
+    cb.start()
+    with cb._cv:
+        cb._running = False
+        cb._cv.notify_all()
+    cb._thread.join(10)
+    assert not cb._thread.is_alive()
+    cb._thread = None
+    cb._running = True
+    return cb
+
+
+def _run_out(cb, limit=400):
+    """Iterate until nothing is queued, held or unread."""
+    for _ in range(limit):
+        if cb._idle():
+            return
+        cb._iterate()
+    raise AssertionError("the batcher did not run out of work")
+
+
+def _greedy(lm, prompt, n, eos=None):
+    """``generate``'s tokens, cut after the first ``eos``."""
+    out = lm.generate(np.asarray([prompt], np.int32), n)[0].tolist()
+    return out[:out.index(eos) + 1] if eos in out else out
+
+
+def _stream(cb, prompt, n):
+    """Submit one streamed request; returns (its generator, its _Seq)."""
+    gen = cb.submitStream({"tokens": list(prompt), "maxNewTokens": n})
+    return gen, cb._queue[-1]
+
+
+def test_one_step_ahead_serves_generates_tokens_under_churn():
+    """The real loop, more requests than slots, mixed lengths, streamed
+    and returned: token for token the unbatched greedy ``generate``; the
+    loop was a step ahead for most steps, no token was computed for
+    nothing (every end is a quota's, known ahead), nothing compiled and
+    every page came back."""
+    lm, ref_lm = _lm(layers=1), _lm(layers=1)
+    cb = ContinuousBatcher(lm, name="cb-ahead", pageSize=8,
+                           maxSlots=3).start()
+    try:
+        seen = cb.compileCacheSize()
+        rng = np.random.RandomState(4)
+        lens, n = (4, 9, 14, 23), 9
+        prompts = [rng.randint(1, 40, lens[i % 4]).tolist()
+                   for i in range(n)]
+        quotas = [int(rng.randint(2, 24)) for _ in range(n)]
+        outs = [None] * n
+
+        def run(i):
+            req = {"tokens": prompts[i], "maxNewTokens": quotas[i]}
+            outs[i] = list(cb.submitStream(req)) if i % 2 else \
+                cb.submit(req, timeout=120)[0].tolist()
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(n)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=180)
+        assert all(not th.is_alive() for th in threads)
+        for i in range(n):
+            assert outs[i] == _greedy(ref_lm, prompts[i], quotas[i]), i
+        steps = _count("decode_steps", "cb-ahead")
+        # a step is not ahead only when it follows an idle loop
+        assert _count("decode_steps_overlapped", "cb-ahead") >= 0.8 * steps
+        assert _count("decode_tokens_discarded", "cb-ahead") == 0
+        assert cb.compileCacheSize() == seen
+        assert _count("compile_cache_misses", "cb-ahead") == 0
+        assert cb.pool.usedPages() == 0
+    finally:
+        cb.shutdown()
+
+
+@pytest.mark.parametrize("cause", ["eos", "cancel", "deadline"])
+def test_an_end_learnt_a_step_late_costs_one_discarded_token(cause):
+    """B ends while the step after its last is already dispatched: that
+    step's token for B is discarded by B's identity (the counter reads
+    exactly 1), B's pages are free at once, and C, admitted into B's slot
+    while that step is still unread, never sees B's token.  A, beside
+    them, is untouched."""
+    from deeplearning4j_tpu.remote.serving import DeadlineExceeded
+    lm, ref = _lm(layers=1), _lm(layers=1)  # the reference compiles apart
+    rng = np.random.RandomState(0)
+    pa, pb, pc = (rng.randint(1, 40, k).tolist() for k in (9, 6, 12))
+    refB = _greedy(ref, pb, 12)
+    # an end-of-sequence token B reaches at its third token and A never
+    eos = refB[2]
+    assert eos not in refB[:2] and eos not in _greedy(ref, pa, 14)
+    name = f"cb-late-{cause}"
+    cb = _by_hand(lm, name, maxSlots=2,
+                  eosToken=eos if cause == "eos" else None)
+    try:
+        seen = cb.compileCacheSize()
+        ga, sa = _stream(cb, pa, 14)
+        gb, sb = _stream(cb, pb, 12)
+        cb._iterate()                   # both admitted, step 1 dispatched
+        cb._iterate()                   # step 2 dispatched, step 1 read
+        assert cb._inflight is not None and cb._slotSeq == [sa, sb]
+        assert sb.emitted == refB[:2]
+        if cause == "cancel":
+            assert next(gb) == refB[0]
+            gb.close()                  # the client hangs up
+        elif cause == "deadline":
+            sb.deadline = time.monotonic() - 1.0
+        gc_, sc = _stream(cb, pc, 7)
+        # step 3 goes out with B in it (deadline: swept before it), then
+        # step 2 is read: B's end is learnt (eos: from that very token)
+        cb._iterate()
+        assert cb._slotSeq == [sa, None] and cb.pool.heldIds(1) == []
+        assert cb._inflight is not None
+        late = cb._inflight.seqs[1] is sb       # B's token nobody wants
+        assert late == (cause != "deadline")
+        assert _count("decode_tokens_discarded", name) == (0 if late else 1)
+        cb._iterate()                   # C admitted behind the unread step
+        assert cb._slotSeq == [sa, sc]
+        assert _count("decode_tokens_discarded", name) == 1
+        _run_out(cb)
+        assert list(ga) == _greedy(ref, pa, 14)
+        assert list(gc_) == _greedy(ref, pc, 7, eos if cause == "eos"
+                                    else None)
+        if cause == "eos":
+            assert list(gb) == refB[:3]
+        elif cause == "deadline":
+            with pytest.raises(DeadlineExceeded):
+                list(gb)
+        assert sb.emitted == (refB[:3] if cause == "eos" else refB[:2])
+        assert _count("decode_tokens_discarded", name) == 1
+        assert cb.pool.usedPages() == 0 and cb._inflight is None
+        assert cb.compileCacheSize() == seen
+    finally:
+        cb.shutdown()
+
+
+@pytest.mark.parametrize("then", ["refill", "cancel", "fail_over", "fail",
+                                  "evacuate", "shutdown"])
+def test_a_quota_end_frees_its_slot_when_its_last_step_goes_out(then):
+    """B's quota is known ahead: the dispatch of the step that computes
+    its last token frees B's slot and pages, so C is admitted in the next
+    iteration, as it was when every step was read at once, and no step is
+    computed for nothing.  B's unread tokens find it by the flight's
+    record, not by the slot (C's by then); a cancel drops them; a failed
+    batch, an evacuation and a shutdown treat B like a sequence in a
+    slot."""
+    lm, ref = _lm(layers=1), _lm(layers=1)
+    rng = np.random.RandomState(2)
+    pa, pb, pc = (rng.randint(1, 40, k).tolist() for k in (9, 6, 12))
+    refB = _greedy(ref, pb, 3)
+    name = f"cb-part-{then}"
+    cb = _by_hand(lm, name, maxSlots=2)
+    try:
+        seen = cb.compileCacheSize()
+        ga, sa = _stream(cb, pa, 14)
+        gb, sb = _stream(cb, pb, 3)
+        cb._iterate()                   # both admitted, step 1 dispatched
+        assert cb.compileCacheSize() == seen    # the first real dispatch
+        cb._iterate()                   # step 2 (B's last) out, step 1 read
+        assert cb._slotSeq == [sa, None] and cb.pool.heldIds(1) == []
+        assert cb._parted == [sb] and cb._inflight.seqs[1] is sb
+        assert sb.emitted == refB[:2]
+        assert cb.compileCacheSize() == seen    # and one fed from a step
+        if then in ("refill", "cancel"):
+            if then == "cancel":
+                assert next(gb) == refB[0]
+                gb.close()
+            gc_, sc = _stream(cb, pc, 7)
+            cb._iterate()               # C into B's slot; step 2 read
+            assert cb._slotSeq == [sa, sc] and cb._parted == []
+            assert cb._inflight.seqs == [sa, sc]
+            assert sb.emitted == (refB if then == "refill" else refB[:2])
+            cb._iterate()               # step 3 read: A and C, as 1 and 2
+            assert cb._steps == 3 and cb.occupancy() == 1.0
+            _run_out(cb)
+            if then == "refill":
+                assert list(gb) == refB
+            assert list(ga) == _greedy(ref, pa, 14)
+            assert list(gc_) == _greedy(ref, pc, 7)
+        elif then in ("fail_over", "fail"):
+            handed = []
+            if then == "fail_over":
+                cb.onSequenceFailure = lambda src, seqs, err: \
+                    handed.extend(seqs)
+            cb._failBatch(RuntimeError("boom"))
+            assert cb._parted == [] and cb._inflight is None
+            if then == "fail_over":
+                assert handed == [sa, sb]
+                assert sb.forced == refB[:2] and sb.emitted == []
+            else:
+                with pytest.raises(RuntimeError, match="boom"):
+                    list(gb)
+        elif then == "evacuate":
+            assert cb.evacuate() == [sa, sb]
+            assert sb.forced == refB[:2] and cb._parted == []
+        else:
+            cb.shutdown()
+            assert cb._parted == []
+            with pytest.raises(RuntimeError, match="shut down"):
+                list(gb)
+        assert _count("decode_tokens_discarded", name) == 0
+        assert cb.pool.usedPages() == 0
+        if not then.startswith("fail"):     # that rebuilds pools and fns
+            assert cb.compileCacheSize() == seen
+    finally:
+        cb.shutdown()
+
+
+def test_a_parted_sequence_keeps_the_batcher_busy_until_it_is_read():
+    """A drain (``ReplicaSet._drainStop``) shuts a replica down when it is
+    no longer ``busy()``: a sequence that has given up its slot and is
+    still owed its last token counts."""
+    lm, ref = _lm(layers=1), _lm(layers=1)
+    prompt = np.random.RandomState(3).randint(1, 40, 7).tolist()
+    cb = _by_hand(lm, "cb-part-busy", maxSlots=2)
+    try:
+        g, seq = _stream(cb, prompt, 3)
+        cb._iterate()
+        cb._iterate()
+        assert cb._slotSeq == [None, None] and cb._parted == [seq]
+        assert cb.busy() and not cb._idle()
+        cb._iterate()                   # nothing to dispatch: only the read
+        assert not cb.busy() and cb._idle()
+        assert list(g) == _greedy(ref, prompt, 3)
+        assert cb.pool.usedPages() == 0
+    finally:
+        cb.shutdown()
+
+
+@pytest.mark.parametrize("how", ["preempted_by_hand", "pool_squeeze"])
+def test_preempt_defer_and_replay_with_a_step_unread_deliver_once(how):
+    """A preemption with a step unread loses that step's token for the
+    victim and nothing else: the replay is teacher-forced from what was
+    emitted (forced tokens go to the step from the host, known ahead),
+    ``streamSkip`` swallows the re-emission, and each client sees each
+    token once and in order.  ``pool_squeeze``: a pool too small for two
+    sequences preempts the younger and defers it for rounds on end."""
+    lm, ref = (_lm(layers=1, maxLen=48, seed=6) for _ in range(2))
+    rng = np.random.RandomState(1)
+    pa, pb = (rng.randint(1, 40, 12).tolist() for _ in range(2))
+    name = f"cb-replay-{how}"
+    cb = _by_hand(lm, name, maxSlots=2,
+                  numPages=9 if how == "pool_squeeze" else None)
+    try:
+        seen = cb.compileCacheSize()
+        ga, sa = _stream(cb, pa, 30)
+        gb, sb = _stream(cb, pb, 30)
+        events = {"grows": 0, "deferred_ahead": 0, "forced_ahead": 0}
+        grow = cb._growPages
+
+        def watched():
+            events["grows"] += 1
+            if how == "preempted_by_hand" and events["grows"] == 7:
+                # where the loop preempts: after the admissions, before
+                # the dispatch, with step 6 (B in it) unread
+                assert cb._inflight.seqs[1] is sb and len(sb.emitted) == 6
+                cb._preempt(1)
+            active, deferred = grow()
+            ahead = cb._inflight
+            if ahead is not None:
+                events["deferred_ahead"] += any(
+                    ahead.seqs[s] is None for s in deferred)
+                events["forced_ahead"] += any(
+                    ahead.seqs[s] is cb._slotSeq[s] and
+                    len(cb._slotSeq[s].emitted) < len(cb._slotSeq[s].forced)
+                    for s in active)
+            return active, deferred
+        cb._growPages = watched
+        _run_out(cb)
+        assert list(ga) == _greedy(ref, pa, 30)
+        assert list(gb) == _greedy(ref, pb, 30)
+        assert sb.restarts >= 1 and sb.streamSkip == 0
+        assert _count("preemptions", name) == sb.restarts + sa.restarts
+        # each preemption found the victim's next token on the device
+        assert _count("decode_tokens_discarded", name) == \
+            _count("preemptions", name)
+        assert events["forced_ahead"] >= 1
+        if how == "pool_squeeze":
+            assert sa.restarts == 0         # the oldest always progresses
+            assert events["deferred_ahead"] >= 1
+        assert cb.pool.usedPages() == 0 and cb._inflight is None
+        assert cb.compileCacheSize() == seen
+    finally:
+        cb.shutdown()
+
+
 # ------------------------------------------------ speculative decode ----
 
 def test_speculative_decode_bit_identical_to_greedy():
@@ -309,6 +605,11 @@ def test_speculative_decode_bit_identical_to_greedy():
                                           target.generate(prompts[i], 8))
         sm = serving_metrics()
         assert sm.draft_proposed().value(model="cb-spec") > 0
+        # the accept rule needs every step's tokens before the next can
+        # be formed: with a draft the loop is never a step ahead
+        assert sm.decode_steps().value(model="cb-spec") > 0
+        assert sm.decode_steps_overlapped().value(model="cb-spec") == 0
+        assert sm.decode_tokens_discarded().value(model="cb-spec") == 0
     finally:
         cb.shutdown()
 

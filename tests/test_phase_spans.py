@@ -162,9 +162,12 @@ def test_capture_holds_the_loop_threads_spans(capture):
     assert len(holders) == 1, "the loop's phases sit on ONE thread's line"
     loop = holders[0]
     assert want <= set(loop), sorted(loop)
-    # 4 tokens = 1 from the prefill + 3 decode steps, each with a fetch
+    # 4 tokens = 1 from the prefill + 3 decode steps, each with a fetch;
+    # the loop reads a step one iteration after it dispatched it, so the
+    # last is read in an iteration of its own
     assert len(loop["dl4j.serving.loop.fetch"]) == 3
-    assert len(loop["dl4j.serving.decode.step"]) == 3
+    assert len(loop["dl4j.serving.loop.dispatch"]) == 3
+    assert len(loop["dl4j.serving.decode.step"]) == 4
     assert len(loop["dl4j.serving.prefill"]) == 1
     # and the training spans are NOT on the loop thread's line
     assert "dl4j.step" not in loop and "dl4j.h2d" not in loop
@@ -201,15 +204,31 @@ def test_every_phase_once_a_step_and_the_loop_is_covered(spec):
                            name="ph", maxSlots=2, pageSize=8, **kw).start()
     try:
         _generate(cb, quota)
+        # the stream has ended, so its last step was read: the loop goes
+        # idle with nothing unread on the device
+        assert cb._inflight is None
     finally:
         cb.shutdown()
-    steps = int(get_registry().get(
-        "dl4j_tpu_serving_decode_steps_total").value(model="ph"))
-    assert steps >= 1 if spec else steps == quota - 1
+    count = lambda name: int(get_registry().get(name).value(model="ph"))
+    steps = count("dl4j_tpu_serving_decode_steps_total")
+    ahead = count("dl4j_tpu_serving_decode_steps_overlapped_total")
+    assert count("dl4j_tpu_serving_decode_tokens_discarded_total") == 0
     cells = _phase_cells("ph")
+    if spec:
+        # a draft's accept rule needs each step's tokens before the next
+        # can be formed: dispatched and read in one iteration, as ever
+        assert steps >= 1 and ahead == 0
+        grows = steps
+    else:
+        # one stream that never waits: every step but the first was
+        # dispatched while the one before it was unread, and the last is
+        # read by an iteration that grows nothing and dispatches nothing
+        assert steps == quota - 1 and ahead == steps - 1
+        grows = steps + 1
     for p in STEP_PHASES:
-        assert cells[p][0] == steps, (p, cells[p], steps)
-    assert cells["admit"][0] >= steps       # once an iteration
+        want = grows if p == "grow" else steps
+        assert cells[p][0] == want, (p, cells[p], want)
+    assert cells["admit"][0] >= grows       # once an iteration
     # the loop thread's wall time, off the Chrome trace: first admit to
     # the end of the last bookkeep; the phases are siblings on one thread
     evs = [e for e in tracer().events()
@@ -322,17 +341,23 @@ def test_chrome_trace_keeps_serving_spans_with_args_and_nesting():
              and prefill["ts"] + prefill["dur"] <= e["ts"] + e["dur"]]
     assert len(admit) == 1, "the prefill nests inside ONE admit phase"
     assert admit[0]["args"]["depth"] == 2   # under serving.loop.iteration
-    steps = [e for e in evs if e["name"] == "serving.decode.step"]
-    assert len(steps) == 4
-    for st in steps:
-        assert st["args"]["replica"] == "ct" and st["args"]["active"] == 1
+    steps = sorted((e for e in evs if e["name"] == "serving.decode.step"),
+                   key=lambda e: e["ts"])
+    # 4 decode steps, each read one iteration after its dispatch: the
+    # first iteration only dispatches, the fifth only reads
+    assert len(steps) == 5
+    for i, st in enumerate(steps):
+        assert st["args"]["replica"] == "ct"
+        assert st["args"]["active"] == (1 if i < 4 else 0)
         kids = [e for e in evs if e["tid"] == st["tid"]
                 and e["name"].startswith("serving.loop.")
                 and st["ts"] <= e["ts"]
                 and e["ts"] + e["dur"] <= st["ts"] + st["dur"]]
+        want = [p for p in STEP_PHASES
+                if (i < 4 or p not in ("upload", "dispatch"))
+                and (i > 0 or p not in ("fetch", "emit", "bookkeep"))]
         assert [k["name"].rsplit(".", 1)[1]
-                for k in sorted(kids, key=lambda e: e["ts"])] == \
-            list(STEP_PHASES)
+                for k in sorted(kids, key=lambda e: e["ts"])] == want
         assert all(k["args"]["depth"] == st["args"]["depth"] + 1
                    for k in kids)
 

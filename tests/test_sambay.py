@@ -276,6 +276,70 @@ def test_preempt_replay_and_evacuate_return_the_same_tokens(ref, weights,
             :len(s.forced)]
 
 
+@pytest.mark.parametrize("end", ["hangup", "quota"])
+def test_admission_behind_an_unread_step_that_wrote_the_slots_state(
+        ref, weights, batcher, end):
+    """The loop is one step ahead, and Y is admitted into X's slot while
+    a step that wrote X's ring rows, recurrent state and page row is
+    still unread; device order puts Y's admission write behind it, so Y
+    and its neighbours get the reference's tokens and the books are
+    empty.  ``hangup``: X's end is learnt with one more step dispatched,
+    whose token is discarded.  ``quota``: X's end is known ahead, its
+    slot is free from the dispatch of its last step, and that step's
+    token still reaches X.  Iterated by hand, so no clock decides what is
+    unread when."""
+    from deeplearning4j_tpu.telemetry import serving_metrics
+    sm = serving_metrics()
+    counts = lambda: np.asarray([c.value(model="sambay") for c in (
+        sm.decode_tokens_discarded(), sm.decode_steps(),
+        sm.decode_steps_overlapped())])
+    before = counts()
+    with batcher._cv:
+        batcher._running = False
+        batcher._cv.notify_all()
+    batcher._thread.join(10)
+    assert not batcher._thread.is_alive()
+    batcher._thread, batcher._running = None, True
+    pa, px, pz, py = _prompts([9, 6, 13, 7], seed=5)
+
+    def stream(prompt, n=30):
+        gen = batcher.submitStream({"tokens": prompt, "maxNewTokens": n})
+        return gen, batcher._queue[-1]
+    # 5 tokens: one from the prefill, the last from the fourth step
+    (ga, sa), (gx, sx), (gz, sz) = \
+        stream(pa), stream(px, 5 if end == "quota" else 30), stream(pz)
+    for _ in range(4):
+        batcher._iterate()
+    assert batcher._inflight.seqs == [sa, sx, sz]
+    if end == "quota":
+        assert batcher._parted == [sx]
+    else:
+        assert batcher._slotSeq == [sa, sx, sz]
+        next(gx)
+        gx.close()                              # X's client hangs up
+    gy, sy = stream(py)
+    if end == "hangup":
+        batcher._iterate()      # a step with X in it goes out, X retires
+    assert batcher._slotSeq == [sa, None, sz]
+    assert batcher._inflight.seqs[1] is sx
+    batcher._iterate()          # Y's admission, behind that unread step
+    assert batcher._slotSeq == [sa, sy, sz]
+    while not batcher._idle():
+        batcher._iterate()
+    served = [(pa, ga, 30), (pz, gz, 30), (py, gy, 30)]
+    if end == "quota":
+        served.append((px, gx, 5))
+    for p, g, n in served:
+        toks = list(g)
+        assert len(toks) == n
+        assert _served_gap(ref, weights, p, toks) < TOL_F32
+    discarded, steps, ahead = counts() - before
+    assert discarded == (end == "hangup") and ahead == steps - 1
+    pool = batcher.pool
+    assert pool.usedPages() == 0 and pool.stateSlots() == 0
+    assert batcher._inflight is None and batcher._parted == []
+
+
 def test_published_configuration_counts_its_parameters(ref, family):
     """``jax.eval_shape`` of the published sizes: 3.85 B parameters (the
     model card says 3.8 B) in layers of kinds 9 / 8 / 1 / 7 / 7."""

@@ -99,12 +99,25 @@ def _assert_in_place(compiled, pool, what):
         f"pool's {poolBytes / 1e9:.3f} GB")
 
 
+def _assert_one_step_program(compiled, cache):
+    """The step, with the choice between the host's token and the step
+    before's output inside it, is ONE program under the name the traces
+    know (``jit_step``), and every cache array it is given comes back
+    aliased."""
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_step"), text[:80]
+    assert text.count("\nHloModule ") == 0
+    cacheBytes = sum(a.size * a.dtype.itemsize for a in cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cacheBytes
+
+
 def test_paged_decode_step_updates_the_pool_in_place(paged):
     lm, params, pool, i32 = paged
     compiled = lm.buildPagedDecodeFn().lower(
-        params, pool, pool, i32(SLOTS, 1), i32(SLOTS, PER_SEQ), i32(SLOTS),
-        i32(SLOTS)).compile()
+        params, pool, pool, i32(SLOTS, 1), i32(SLOTS, 1),
+        i32(SLOTS, PER_SEQ), i32(SLOTS), i32(SLOTS)).compile()
     _assert_in_place(compiled, pool, "jit_step")
+    _assert_one_step_program(compiled, [pool, pool])
 
 
 @pytest.mark.parametrize("bucket", [16, 256])
@@ -171,13 +184,12 @@ def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
     lm, params, pool, i32 = sambay
     perSeq = PHI_CAP // PAGE_SIZE
     compiled = lm.buildPagedDecodeFn().lower(
-        params, *pool, i32(PHI_SLOTS, 1), i32(PHI_SLOTS, perSeq),
-        i32(PHI_SLOTS), i32(PHI_SLOTS)).compile()
+        params, *pool, i32(PHI_SLOTS, 1), i32(PHI_SLOTS, 1),
+        i32(PHI_SLOTS, perSeq), i32(PHI_SLOTS), i32(PHI_SLOTS)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     # the six arrays are donated and come back aliased, not copied
-    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
-    assert mem.alias_size_in_bytes >= poolBytes
+    _assert_one_step_program(compiled, pool)
     assert not _whole_array_copies(compiled, pool)
 
 
