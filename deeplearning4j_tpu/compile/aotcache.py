@@ -628,19 +628,14 @@ class AotDispatch:
     ``BucketedExecutor``) read it as "recompiles", and a disk load is
     not a recompile; this is exactly what makes
     ``dl4j_tpu_train_compile_seconds_total`` ~0 on a warm boot.
-
-    ``static_argnums`` name positions that are compile-time constants
-    (they key the signature, feed ``lower``, and are dropped from the
-    AOT call — a Compiled takes only the runtime operands).
     """
 
     def __init__(self, jitted, cache: AotCache, keyBase: Dict[str, Any],
-                 kind: str, static_argnums: Sequence[int] = ()):
+                 kind: str):
         self._jitted = jitted
         self._cache = cache
         self._keyBase = keyBase
         self.kind = kind
-        self._static = tuple(sorted(static_argnums))
         self.group = _digest(keyBase)
         # two-tier lookup: the hot dict is keyed by the cheap tuple
         # signature computed per call; preloaded executables sit keyed
@@ -663,11 +658,6 @@ class AotDispatch:
     def entryDigest(self, signature: str) -> str:
         return _digest({"base": self._keyBase, "signature": signature})
 
-    def _runtime_args(self, args: tuple) -> tuple:
-        if not self._static:
-            return args
-        return tuple(a for i, a in enumerate(args) if i not in self._static)
-
     def preload(self) -> int:
         """Load every executable on this group's ladder (boot-path hook:
         MeshTrainer install, supervisor resume, serving warm).  Returns
@@ -689,7 +679,7 @@ class AotDispatch:
         key = _sig_key(args)
         exe = self._loaded.get(key)
         if exe is not None:
-            return exe(*self._runtime_args(args))
+            return exe(*args)
         with self._lock:
             exe = self._loaded.get(key)
             if exe is None:
@@ -699,7 +689,7 @@ class AotDispatch:
                     exe = self._miss(sig, args)
                 self._loaded[key] = exe
                 self._promoted.add(sig)
-        return exe(*self._runtime_args(args))
+        return exe(*args)
 
     def _miss(self, sig: str, args: tuple):
         digest = self.entryDigest(sig)
@@ -721,7 +711,7 @@ class AotDispatch:
 # ---------------------------------------------------------------------------
 
 def wrap_jit(jitted, *, kind: str, model=None, plan=None,
-             static_argnums: Sequence[int] = (), preload: bool = True):
+             preload: bool = True):
     """Wrap a ``jax.jit`` object in an :class:`AotDispatch` when the
     process-global cache is configured; otherwise return it UNCHANGED
     (zero behavior change with the cache off).  ``model``/``plan``
@@ -752,8 +742,7 @@ def wrap_jit(jitted, *, kind: str, model=None, plan=None,
         log.warning("AOT cache: could not key %s (%s: %s); falling back "
                     "to plain jit", kind, type(e).__name__, e)
         return jitted
-    disp = AotDispatch(jitted, cache, keyBase, kind,
-                       static_argnums=static_argnums)
+    disp = AotDispatch(jitted, cache, keyBase, kind)
     if preload:
         n = disp.preload()
         if n:
@@ -763,32 +752,16 @@ def wrap_jit(jitted, *, kind: str, model=None, plan=None,
 
 
 def wrap_serving_model(model) -> bool:
-    """AOT-wrap a serving model's inference executables in place (the
-    ``BucketedExecutor.warm()`` hook): ``_outputFn`` for forward models,
-    ``_prefillFn``/``_decodeFn`` for KV-cache LMs.  No-op (False) with
-    the cache off or for models without those surfaces."""
-    if aot_cache() is None or model is None:
+    """AOT-wrap a forward model's ``_outputFn`` in place (the
+    ``BucketedExecutor.warm()`` hook).  No-op (False) with the cache off
+    or for a model without one."""
+    if aot_cache() is None or not hasattr(model, "_outputFn"):
         return False
-    wrapped = False
-    if hasattr(model, "_outputFn"):
-        fn = model._outputFn          # builds the cached_property jit
-        if not isinstance(fn, AotDispatch):
-            model.__dict__["_outputFn"] = wrap_jit(
-                fn, kind="output", model=model)
-        wrapped = True
-    if hasattr(model, "_prefillFn") and hasattr(model, "_decodeFn"):
-        fn = model._prefillFn
-        if not isinstance(fn, AotDispatch):
-            # position 3 is the static `padded` flag (see
-            # TransformerLM._prefillFn static_argnames)
-            model.__dict__["_prefillFn"] = wrap_jit(
-                fn, kind="prefill", model=model, static_argnums=(3,))
-        fn = model._decodeFn
-        if not isinstance(fn, AotDispatch):
-            model.__dict__["_decodeFn"] = wrap_jit(
-                fn, kind="decode", model=model)
-        wrapped = True
-    return wrapped
+    fn = model._outputFn              # builds the cached_property jit
+    if not isinstance(fn, AotDispatch):
+        model.__dict__["_outputFn"] = wrap_jit(
+            fn, kind="output", model=model)
+    return True
 
 
 def preload_model(model) -> int:

@@ -7,7 +7,7 @@ process on the same disk skip that, but only if both processes name the
 same directory: the path is part of nothing JAX hashes, yet a directory
 that moves (``tempfile``, a pid, the time) is never found again.
 
-The chip entry points (``chip_smoke.py``, ``bench.py``) call
+The chip entry points (``chip_smoke.py``, ``benchmark/run.py``) call
 :func:`enable_compile_cache` before their first compilation.  Tests do
 not.  This is the only place in the repo that sets
 ``jax_compilation_cache_dir``.  (``AotCache`` in :mod:`.aotcache` is a

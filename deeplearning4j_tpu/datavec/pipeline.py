@@ -39,7 +39,8 @@ Telemetry reports through the shared ``dl4j_tpu_etl_*`` namespace
 consumers-waiting and producer-active gauges keep the watchdog's
 ``etl_starvation`` rule working unchanged, and the new
 ``dl4j_tpu_etl_h2d_bytes_total`` / ``dl4j_tpu_etl_h2d_seconds`` series
-measure the transfer stage itself (``bench.py --streaming`` reads them).
+measure the transfer stage itself (bytes put on the device, and the
+seconds the put took).
 
 The fit paths (``MultiLayerNetwork.fit``, ``ParallelWrapper.fit``,
 ``FaultTolerantTrainer``) engage this automatically via

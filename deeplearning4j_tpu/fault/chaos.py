@@ -267,6 +267,36 @@ class _TrackedFault(_inj.Fault):
             self._mark()
 
 
+class _CrashUnderLoad(_inj.Fault):
+    """The serving soak's replica crash, armed with a sequence to hand
+    over.  A crash that finds its replica idle is consumed by the
+    health probe and nothing fails over — what a wall-clock schedule
+    gets whenever the router sent that replica nothing.  So the crash
+    is armed and ``launch()`` puts one streaming client on the replica
+    under the replica set's lock: retirement takes the same lock, so
+    the replica is still routed when the sequence lands on it, and an
+    armed or consumed crash stops its loop before it finishes anything.
+    Whether the loop or the probe consumes the crash, the sequence is
+    there to be handed over."""
+
+    def __init__(self, inner: "_inj.ReplicaCrashAtStep", lock, launch):
+        self.inner = inner
+        self.lock = lock
+        self.launch = launch
+
+    @property
+    def fired(self) -> bool:
+        return self.inner.fired
+
+    def before_step(self, step, net, ds):
+        if self.inner.fired:
+            return
+        with self.lock:
+            self.inner.before_step(step, net, ds)
+            if self.inner.fired:
+                self.launch(self.inner.replica)
+
+
 class _PhantomPeer:
     """An in-process stand-in for another pod host: a real
     :class:`PodCoordinator` whose ``poll()`` loop runs on a background
@@ -924,6 +954,30 @@ class ServingChaosSoak:
         th.start()
         threads.append(th)
 
+    def _launchOnReplica(self, rs, replica: str, prompts, threads,
+                         handedOver, errors) -> None:
+        """A streaming client enqueued on the replica named ``replica``
+        (the call ``rs.submitStream`` makes once it has picked one),
+        read to its end on a thread of its own into ``handedOver``
+        (prompt 0's tokens).  Called with ``rs._lock`` held."""
+        ex = next((e for e in rs._replicas if e.name == replica), None)
+        if ex is None:
+            return                      # retired before its crash tick
+        gen = ex.submitStream({"tokens": prompts[0].tolist(),
+                               "maxNewTokens": self.maxNewTokens,
+                               "keepAliveSeconds": 0.1})
+
+        def run():
+            try:
+                handedOver.append([t for t in gen if isinstance(t, int)])
+            except Exception as e:
+                errors.append(f"client on {replica}: "
+                              f"{type(e).__name__}: {e}")
+        th = threading.Thread(target=run, daemon=True,
+                              name="soak-crash-client")
+        th.start()
+        threads.append(th)
+
     def _fireStorm(self, rs, prompts, rng, results, n: int) -> None:
         """``n`` already-expired requests: each must shed 504
         (``DeadlineExceeded``) at admission, never holding a slot."""
@@ -941,14 +995,19 @@ class ServingChaosSoak:
                 results.append(False)
 
     def _buildFaults(self, rs, prompts, rng, hangupThreads, stormResults,
-                     firedLog: List[str]) -> List[_inj.Fault]:
+                     firedLog: List[str], handedOver,
+                     errors) -> List[_inj.Fault]:
         faults: List[_inj.Fault] = []
         for e in self.schedule():
             kind = e["kind"]
             if kind == "replica_crash":
-                faults.append(_TrackedFault(kind, _inj.ReplicaCrashAtStep(
-                    f"{self.name}/{e['replica']}", step=e["step"]),
-                    firedLog))
+                faults.append(_TrackedFault(kind, _CrashUnderLoad(
+                    _inj.ReplicaCrashAtStep(
+                        f"{self.name}/{e['replica']}", step=e["step"]),
+                    rs._lock,
+                    lambda replica: self._launchOnReplica(
+                        rs, replica, prompts, hangupThreads, handedOver,
+                        errors)), firedLog))
             elif kind == "slow_replica":
                 faults.append(_TrackedFault(kind, _inj.SlowReplica(
                     f"{self.name}/{e['replica']}", seconds=e["seconds"],
@@ -1052,6 +1111,7 @@ class ServingChaosSoak:
         latencies: List[float] = []
         hangupThreads: List[threading.Thread] = []
         stormResults: List[bool] = []
+        handedOver: List[List[int]] = []    # streams a crash was armed under
         clientThreads: List[threading.Thread] = []
         t0 = time.perf_counter()
         try:
@@ -1090,7 +1150,8 @@ class ServingChaosSoak:
                 clientThreads.append(th)
 
             faults = self._buildFaults(rs, prompts, rng, hangupThreads,
-                                       stormResults, firedLog)
+                                       stormResults, firedLog, handedOver,
+                                       errors)
             hardStop = time.monotonic() + self.maxSeconds
             with _inj.inject(*faults) as inj:
                 tick = 0
@@ -1120,7 +1181,8 @@ class ServingChaosSoak:
             crashFired = "replica_crash" in firedLog
             inv["exactly_once_tokens"] = bool(
                 not errors and
-                all(results[i] == refs[i] for i in range(self.clients)))
+                all(results[i] == refs[i] for i in range(self.clients))
+                and all(got == refs[0] for got in handedOver))
             with rs._lock:
                 live = list(rs._replicas)
             inv["all_pages_freed"] = bool(live) and all(

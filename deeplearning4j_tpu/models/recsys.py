@@ -41,8 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  drop_served_jits,
                                                   paged_prefill_write,
-                                                  paged_step_tokens)
+                                                  paged_step_tokens,
+                                                  served_jit_entries)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer, register_layer
 from deeplearning4j_tpu.nn.lossfunctions import get_loss
@@ -287,18 +289,13 @@ class RetrievalLM:
         return jax.jit(write, donate_argnums=(0, 1))
 
     def compileCacheSize(self) -> int:
-        """Jit-cache entries across this adapter's executables (the
-        serving tier's compile hit/miss probe)."""
-        n = 0
-        for name in ("_fwd", "_prefillFn", "_decodeFn", "_verifyFn",
-                     "_prefillRawFn"):
-            fn = self.__dict__.get(name)
-            if fn is not None:
-                try:
-                    n += int(fn._cache_size())
-                except Exception:
-                    pass
-        return n
+        """Jit-cache entries of the prefill (the serving tier's compile
+        hit/miss probe)."""
+        return served_jit_entries(self)
+
+    def dropCompiled(self) -> None:
+        """Forget the cached jits (pool or plan changed)."""
+        drop_served_jits(self)
 
 
 def topk_retrieve(batcher, userIds, k: int, timeout=None) -> np.ndarray:

@@ -39,8 +39,10 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  drop_served_jits,
                                                   paged_prefill_write,
-                                                  paged_step_tokens)
+                                                  paged_step_tokens,
+                                                  served_jit_entries)
 
 __all__ = ["SambaYConfig", "SambaYLM"]
 
@@ -509,12 +511,7 @@ class SambaYLM:
         return jax.jit(write, donate_argnums=(0, 1, 2, 3, 4, 5))
 
     def compileCacheSize(self) -> int:
-        n = 0
-        for name in ("_fwd", "_prefillRawFn"):
-            fn = self.__dict__.get(name)
-            if fn is not None:
-                try:
-                    n += int(fn._cache_size())
-                except Exception:
-                    pass
-        return n
+        return served_jit_entries(self)
+
+    def dropCompiled(self) -> None:
+        drop_served_jits(self)

@@ -1,22 +1,23 @@
-"""Decoder-only transformer LM with incremental (KV-cached) decode.
+"""Decoder-only transformer LM, served through a paged KV pool.
 
 The training side of this repo already runs transformer encoders (the
 SameDiff BERT of ``zoo/bert.py``, flash attention for long context);
 serving generative traffic needs the *decode* discipline those graphs
-don't have: generation re-run through a full forward is O(t) per token and
-re-traces on every prompt length.  This model keeps decode O(1) per token
-by carrying a :class:`~deeplearning4j_tpu.nn.conf.attention.KVCache`
-through every attention layer, with all executable shapes STATIC:
+don't have: generation re-run through a full forward is O(t) per token.
+This model keeps decode O(1) per token by writing every layer's K/V into
+the pages of a ``KVCachePool`` (``remote/scheduler.py``), with all
+executable shapes STATIC:
 
-- :meth:`prefill` runs the prompt through the stack once (causal
-  attention dispatching through ``parallel.ring.dot_product_attention``,
-  i.e. the flash kernel on TPU for long prompts) and fills the caches;
-- :meth:`decodeStep` feeds ONE token per example against the caches —
-  fixed (batch, capacity) shapes, so the serving tier warms exactly one
-  executable per batch bucket and never re-traces in steady state;
-- left-padding support (``lengths``) keeps ragged prompts bucketable:
-  every example ends at the same position, so the cache write position
-  stays one scalar (see ``KVCache.start``).
+- :meth:`prefillRaw` runs a LEFT-padded prompt bucket through the stack
+  once (causal attention dispatching through
+  ``parallel.ring.dot_product_attention``) and returns the per-layer K/V
+  for the scheduler to copy into pool pages;
+- :meth:`buildPagedDecodeFn` builds the step that feeds ONE token per
+  slot against the pool (:func:`~deeplearning4j_tpu.nn.conf.attention.
+  paged_attention`) — fixed (slots, capacity) shapes, so the batcher
+  warms one executable and never re-traces in steady state;
+- :meth:`forward` is the plain causal forward, and :meth:`generate` the
+  greedy recompute over it: the reference the served path is held to.
 
 Weights follow the pre-LN GPT block (LN → attention → residual, LN → FFN
 → residual) with tied input/output embeddings.
@@ -25,17 +26,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.attention import (CacheSpec, KVCache,
-                                                  cached_attention,
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  drop_served_jits,
                                                   paged_attention,
                                                   paged_prefill_write,
-                                                  paged_step_tokens)
+                                                  paged_step_tokens,
+                                                  served_jit_entries)
 
 __all__ = ["TransformerLMConfig", "TransformerLM"]
 
@@ -47,7 +49,7 @@ class TransformerLMConfig:
     nHeads: int = 4
     headSize: int = 16
     ffnMult: int = 4
-    maxLen: int = 128          # cache capacity == max prompt + generation
+    maxLen: int = 128          # positions: max prompt + generation
     initializerRange: float = 0.02
     seed: int = 0
 
@@ -57,7 +59,9 @@ class TransformerLMConfig:
 
 
 class TransformerLM:
-    """GPT-style causal LM; ``generate`` == prefill + N decode steps."""
+    """GPT-style causal LM: one block body, attended causally over the
+    sequence (``forward``, ``prefillRaw``) or against pool pages (the
+    paged decode step)."""
 
     def __init__(self, config: Optional[TransformerLMConfig] = None, **kw):
         self.config = config or TransformerLMConfig(**kw)
@@ -108,31 +112,29 @@ class TransformerLM:
         b, _, t, _ = ctx.shape
         return ctx.transpose(0, 2, 1, 3).reshape(b, t, -1)
 
-    def _block_full(self, lp, x, mask):
-        """Full-sequence causal block (prefill/training).  Dispatches the
-        score chain through ``dot_product_attention`` — flash on TPU for
-        long unmasked prompts, mask-honoring dense/blockwise otherwise."""
-        from deeplearning4j_tpu.parallel.ring import dot_product_attention
+    def _block(self, lp, x, attend):
+        """The pre-LN block: LayerNorm → Q/K/V → ``attend`` → ``Wo`` →
+        residual, LayerNorm → FFN → residual.  ``attend(qh, kh, vh)``
+        returns ``(ctx, k, v)``: the context and what the caller keeps
+        of this layer's K/V (the heads themselves, or the pools they
+        were written into); the block returns ``(x, k, v)``."""
         h = self._ln(x, lp["ln1_g"], lp["ln1_b"])
         qh = self._heads(jnp.matmul(h, lp["Wq"]))
         kh = self._heads(jnp.matmul(h, lp["Wk"]))
         vh = self._heads(jnp.matmul(h, lp["Wv"]))
-        ctx = dot_product_attention(qh, kh, vh, mask=mask, causal=True)
+        ctx, k, v = attend(qh, kh, vh)
         x = x + jnp.matmul(self._merge(ctx), lp["Wo"])
         h = self._ln(x, lp["ln2_g"], lp["ln2_b"])
         ff = jax.nn.gelu(jnp.matmul(h, lp["Wi"]) + lp["bi"])
-        return x + jnp.matmul(ff, lp["Wp"]) + lp["bp"], (kh, vh)
+        return x + jnp.matmul(ff, lp["Wp"]) + lp["bp"], k, v
 
-    def _block_cached(self, lp, x, cache: KVCache):
-        h = self._ln(x, lp["ln1_g"], lp["ln1_b"])
-        qh = self._heads(jnp.matmul(h, lp["Wq"]))
-        kh = self._heads(jnp.matmul(h, lp["Wk"]))
-        vh = self._heads(jnp.matmul(h, lp["Wv"]))
-        ctx, cache = cached_attention(qh, kh, vh, cache)
-        x = x + jnp.matmul(self._merge(ctx), lp["Wo"])
-        h = self._ln(x, lp["ln2_g"], lp["ln2_b"])
-        ff = jax.nn.gelu(jnp.matmul(h, lp["Wi"]) + lp["bi"])
-        return x + jnp.matmul(ff, lp["Wp"]) + lp["bp"], cache
+    @staticmethod
+    def _causal(mask):
+        """``attend`` over the whole sequence (forward/prefill), through
+        ``dot_product_attention``'s dispatch; keeps the K/V heads."""
+        from deeplearning4j_tpu.parallel.ring import dot_product_attention
+        return lambda qh, kh, vh: (dot_product_attention(
+            qh, kh, vh, mask=mask, causal=True), kh, vh)
 
     def _embed(self, params, tokens, pos_ids):
         x = params["emb"][tokens]                      # (b, t, H)
@@ -143,7 +145,7 @@ class TransformerLM:
         return jnp.matmul(h, params["emb"].T)          # tied head
 
     # ------------------------------------------------------------------
-    # full forward (the recompute baseline the KV path must match)
+    # full forward (the recompute baseline the paged path must match)
     # ------------------------------------------------------------------
     @functools.cached_property
     def _fwd(self):
@@ -152,7 +154,7 @@ class TransformerLM:
             x = self._embed(params, tokens,
                             jnp.arange(t, dtype=jnp.int32)[None, :])
             for lp in params["layers"]:
-                x, _ = self._block_full(lp, x, None)
+                x, _, _ = self._block(lp, x, self._causal(None))
             return self._logits(params, x)
         return jax.jit(run)
 
@@ -161,256 +163,26 @@ class TransformerLM:
         return self._fwd(self.params, jnp.asarray(tokens, jnp.int32))
 
     # ------------------------------------------------------------------
-    # incremental decode
-    # ------------------------------------------------------------------
-    def initCaches(self, batch: int) -> List[KVCache]:
-        c = self.config
-        return [KVCache.create(batch, c.nHeads, c.maxLen, c.headSize)
-                for _ in range(c.nLayers)]
-
-    @functools.cached_property
-    def _prefillFn(self):
-        def run(params, tokens, start, padded):
-            # start[b] = index of the first REAL token (left padding);
-            # position ids count from the real start so padded and
-            # unpadded prompts see identical positional embeddings.
-            # ``padded`` is static: unpadded prompts keep mask=None so the
-            # causal dispatch stays flash-eligible on TPU for long context
-            b, t = tokens.shape
-            kpos = jnp.arange(t, dtype=jnp.int32)[None, :]
-            pos_ids = jnp.maximum(kpos - start[:, None], 0)
-            mask = (kpos >= start[:, None]).astype(jnp.float32) \
-                if padded else None                              # (b, t)
-            x = self._embed(params, tokens, pos_ids)
-            caches = []
-            for lp in params["layers"]:
-                x, (kh, vh) = self._block_full(lp, x, mask)
-                cache = KVCache.create(b, self.config.nHeads,
-                                       self.config.maxLen,
-                                       self.config.headSize,
-                                       kh.dtype, start=start)
-                k = jax.lax.dynamic_update_slice(cache.k, kh, (0, 0, 0, 0))
-                v = jax.lax.dynamic_update_slice(cache.v, vh, (0, 0, 0, 0))
-                caches.append(KVCache(k, v, jnp.asarray(t, jnp.int32),
-                                      start))
-            return self._logits(params, x[:, -1:])[:, 0], caches
-        return jax.jit(run, static_argnames=("padded",))
-
-    def prefill(self, tokens, lengths=None):
-        """Run the prompt once, filling every layer's cache.
-
-        ``tokens`` (b, t) int32, LEFT-padded when ragged; ``lengths`` (b,)
-        gives each example's real token count (defaults to full t).
-        Returns ``(last_logits (b, vocab), caches)`` — the logits predict
-        the first generated token.
-        """
-        tokens = jnp.asarray(tokens, jnp.int32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds cache capacity "
-                             f"{self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), jnp.int32)
-        else:
-            start = t - jnp.asarray(lengths, jnp.int32)
-        return self._prefillFn(self.params, tokens, start,
-                               lengths is not None)
-
-    def _decode_math(self, params, tok, caches):
-        """One incremental step against dense caches: tok (b,) ->
-        ((b, vocab) logits, new caches).  The shared body of
-        ``_decodeFn`` and the draft-proposal scan."""
-        pos_ids = (caches[0].pos - caches[0].start)[:, None]  # (b, 1)
-        x = self._embed(params, tok[:, None], pos_ids)
-        new = []
-        for lp, cache in zip(params["layers"], caches):
-            x, cache = self._block_cached(lp, x, cache)
-            new.append(cache)
-        return self._logits(params, x)[:, 0], new
-
-    @functools.cached_property
-    def _decodeFn(self):
-        def run(params, tok, caches):
-            # tok: (b,) int32 — ONE new token per example
-            return self._decode_math(params, tok, caches)
-        return jax.jit(run)
-
-    def decodeStep(self, tok, caches):
-        """One generated token per example: (b,) int32 + caches ->
-        ((b, vocab) logits, new caches).  O(capacity) per call — the
-        prefix never re-enters the layer stack."""
-        return self._decodeFn(self.params, jnp.asarray(tok, jnp.int32),
-                              caches)
-
-    # ------------------------------------------------------------------
-    # speculative decode: draft proposes, target verifies in ONE forward
-    # ------------------------------------------------------------------
-    @functools.cached_property
-    def _verifyFn(self):
-        """Verify ``k`` proposed tokens in ONE batched forward: feeds all
-        k against the caches (``cached_attention`` handles tq > 1) and
-        returns the target's greedy token AFTER each prefix — the
-        accept-prefix comparison happens on the host."""
-        def run(params, toks, caches):
-            b, k = toks.shape
-            pos_ids = jnp.maximum(
-                (caches[0].pos - caches[0].start)[:, None] +
-                jnp.arange(k, dtype=jnp.int32)[None, :], 0)
-            x = self._embed(params, toks, pos_ids)
-            new = []
-            for lp, cache in zip(params["layers"], caches):
-                x, cache = self._block_cached(lp, x, cache)
-                new.append(cache)
-            greedy = jnp.argmax(self._logits(params, x),
-                                axis=-1).astype(jnp.int32)
-            return greedy, new
-        return jax.jit(run)
-
-    def verifySteps(self, toks, caches):
-        """Target-side verification: toks (b, k) int32 (the last emitted
-        token followed by the draft's proposals) -> ((b, k) greedy
-        tokens, caches advanced k).  Greedy token j is the target's
-        prediction after prefix ``toks[:, :j+1]`` — identical math to j
-        sequential :meth:`decodeStep` calls, ONE dispatch.  On a partial
-        accept the caller rolls back by rebuilding the caches with a
-        smaller ``pos`` (stale K/V past ``pos`` are overwritten before
-        they can ever be attended)."""
-        return self._verifyFn(self.params, jnp.asarray(toks, jnp.int32),
-                              caches)
-
-    def _proposeFn(self, k: int):
-        """Jitted draft proposal: ``k`` greedy tokens in ONE dispatch
-        (the per-token loop is a ``lax.scan`` INSIDE the executable, so
-        a cheap draft model is not billed k dispatch round-trips).  The
-        scan runs k+1 steps so the cache also holds K/V for the k-th
-        proposal — a full accept then needs no cache repair."""
-        fns = self.__dict__.setdefault("_proposeFns", {})
-        if k not in fns:
-            def run(params, tok, caches):
-                def body(carry, _):
-                    tok, caches = carry
-                    logits, caches = self._decode_math(params, tok, caches)
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    return (nxt, caches), nxt
-                (_, caches), props = jax.lax.scan(
-                    body, (tok, caches), None, length=k + 1)
-                return jnp.transpose(props)[:, :k], caches
-            fns[k] = jax.jit(run)
-        return fns[k]
-
-    def proposeK(self, tok, caches, k: int):
-        """Draft entry point: (b,) last tokens -> ((b, k) proposals,
-        caches advanced k+1)."""
-        return self._proposeFn(int(k))(
-            self.params, jnp.asarray(tok, jnp.int32), caches)
-
-    def speculative_generate(self, draft: "TransformerLM", prompts,
-                             maxNewTokens: int, draftK: int = 4,
-                             lengths=None, returnStats: bool = False):
-        """Greedy decode accelerated by a small draft model — output is
-        BIT-IDENTICAL to :meth:`generate` (accept-prefix rule: every
-        emitted token is the target's own greedy argmax; the draft only
-        decides how many of them one verification dispatch yields).
-
-        Per round: the draft proposes ``draftK`` tokens in one fused
-        scan, the target verifies all of them in ONE batched forward,
-        and the longest matching prefix plus the target's first
-        correction are emitted — between 1 and ``draftK + 1`` tokens for
-        two dispatches, vs one token per dispatch for plain decode.
-
-        Serves ONE sequence per call (per-example accept lengths
-        diverge under batching; the continuous-batching scheduler's
-        per-slot page tables handle that case).  Requires
-        ``t + maxNewTokens + draftK <= maxLen``: a rejected round still
-        wrote its speculative K/V before the roll-back, so the cache
-        needs the extra headroom.
-        """
-        prompts = np.asarray(prompts, np.int32)
-        if prompts.ndim == 1:
-            prompts = prompts[None, :]
-        if prompts.shape[0] != 1:
-            raise ValueError(
-                "speculative_generate serves one sequence at a time "
-                "(per-example accept lengths diverge; use the "
-                "continuous-batching scheduler for batched speculation)")
-        draftK = int(draftK)
-        if draftK < 1:
-            raise ValueError("draftK must be >= 1")
-        if draft.config.vocabSize != self.config.vocabSize:
-            raise ValueError("draft and target must share a vocabulary")
-        t = prompts.shape[1]
-        if t + maxNewTokens + draftK > self.config.maxLen:
-            raise ValueError(
-                f"prompt {t} + maxNewTokens {maxNewTokens} + draftK "
-                f"{draftK} exceeds cache capacity {self.config.maxLen} "
-                "(speculative rounds write draftK tokens of K/V ahead)")
-        if t + maxNewTokens + draftK > draft.config.maxLen:
-            raise ValueError(
-                f"draft cache capacity {draft.config.maxLen} cannot hold "
-                f"prompt {t} + maxNewTokens {maxNewTokens} + draftK "
-                f"{draftK}")
-        logits, caches = self.prefill(prompts, lengths)
-        _, dcaches = draft.prefill(prompts, lengths)
-        # jaxlint: sync-ok -- the accept-prefix rule is a host decision: one small D2H per round by design
-        tok = int(np.argmax(np.asarray(logits[0])))
-        emitted = [tok]
-        proposed = accepted = rounds = 0
-        while len(emitted) < maxNewTokens:
-            # pre-propose/pre-verify write indices: the roll-back below
-            # rebuilds both cache sets relative to THESE (reading pos
-            # after the dispatch would bake the speculative advance in)
-            pos0 = caches[0].pos
-            dpos0 = dcaches[0].pos
-            props, dcaches = draft.proposeK(
-                np.asarray([tok], np.int32), dcaches, draftK)
-            # jaxlint: sync-ok -- proposals feed the verify batch through host concat (accept rule is host-side)
-            props = np.asarray(props)[0]                     # (draftK,)
-            verifyIn = np.concatenate(
-                [np.asarray([tok], np.int32), props])[None, :]
-            greedy, caches = self.verifySteps(verifyIn, caches)
-            # jaxlint: sync-ok -- greedy tokens ARE the output; comparison against proposals is host-side
-            greedy = np.asarray(greedy)[0]                   # (draftK+1,)
-            a = 0
-            while a < draftK and props[a] == greedy[a]:
-                a += 1
-            emitted.extend(int(g) for g in greedy[:a + 1])
-            tok = int(greedy[a])
-            proposed += draftK
-            accepted += a
-            rounds += 1
-            # roll back: only the accepted prefix (plus the verified
-            # input token) is real — stale K/V past pos are overwritten
-            # before any later query can attend to them
-            newPos = pos0 + a + 1
-            caches = [KVCache(c.k, c.v, newPos, c.start) for c in caches]
-            dcaches = [KVCache(c.k, c.v, dpos0 + a + 1, c.start)
-                       for c in dcaches]
-        out = np.asarray(emitted[:maxNewTokens], np.int32)[None, :]
-        if returnStats:
-            return out, {"proposed": proposed, "accepted": accepted,
-                         "rounds": rounds,
-                         "acceptRate": accepted / proposed if proposed
-                         else 0.0}
-        return out
-
-    # ------------------------------------------------------------------
     # paged decode — the continuous-batching scheduler's executables
     # ------------------------------------------------------------------
     @functools.cached_property
     def _prefillRawFn(self):
         """Prefill that returns the per-layer K/V heads STACKED
-        ((nLayers, b, h, t, d)) instead of materializing full-capacity
-        dense caches — the continuous scheduler copies them straight
-        into pool pages."""
+        ((nLayers, b, h, t, d)) — the continuous scheduler copies them
+        straight into pool pages."""
         def run(params, tokens, start):
+            # start[b] = index of the first REAL token (left padding);
+            # position ids count from the real start so padded and
+            # unpadded prompts see identical positional embeddings
             b, t = tokens.shape
             kpos = jnp.arange(t, dtype=jnp.int32)[None, :]
             pos_ids = jnp.maximum(kpos - start[:, None], 0)
             mask = (kpos >= start[:, None]).astype(jnp.float32)
             x = self._embed(params, tokens, pos_ids)
             ks, vs = [], []
+            attend = self._causal(mask)
             for lp in params["layers"]:
-                x, (kh, vh) = self._block_full(lp, x, mask)
+                x, kh, vh = self._block(lp, x, attend)
                 ks.append(kh)
                 vs.append(vh)
             return (self._logits(params, x[:, -1:])[:, 0],
@@ -452,21 +224,6 @@ class TransformerLM:
         c = self.config
         return CacheSpec(c.nLayers, c.nHeads, c.headSize)
 
-    def _paged_block(self, lp, li, x, poolK, poolV, pageTable, pos, start):
-        """Transformer block ``li`` against the stacked paged pools (the
-        ``_block_cached`` math with :func:`paged_attention` in place of
-        the private dense cache)."""
-        h = self._ln(x, lp["ln1_g"], lp["ln1_b"])
-        qh = self._heads(jnp.matmul(h, lp["Wq"]))
-        kh = self._heads(jnp.matmul(h, lp["Wk"]))
-        vh = self._heads(jnp.matmul(h, lp["Wv"]))
-        ctx, poolK, poolV = paged_attention(qh, kh, vh, poolK, poolV, li,
-                                            pageTable, pos, start)
-        x = x + jnp.matmul(self._merge(ctx), lp["Wo"])
-        h = self._ln(x, lp["ln2_g"], lp["ln2_b"])
-        ff = jax.nn.gelu(jnp.matmul(h, lp["Wi"]) + lp["bi"])
-        return x + jnp.matmul(ff, lp["Wp"]) + lp["bp"], poolK, poolV
-
     def pagedLogits(self, params, poolK, poolV, toks, pageTable, pos,
                     start):
         """toks (S, tq) against the stacked pools (L, pages, pageSize,
@@ -483,8 +240,10 @@ class TransformerLM:
             0, self.config.maxLen - 1)
         x = params["emb"][toks] + params["pos"][pos_ids]
         for li, lp in enumerate(params["layers"]):
-            x, poolK, poolV = self._paged_block(lp, li, x, poolK, poolV,
-                                                pageTable, pos, start)
+            # called inside _block, before the pools are rebound below
+            x, poolK, poolV = self._block(
+                lp, x, lambda qh, kh, vh: paged_attention(
+                    qh, kh, vh, poolK, poolV, li, pageTable, pos, start))
         return self._logits(params, x), poolK, poolV
 
     def _paged_step_math(self, params, poolK, poolV, toks, pageTable,
@@ -549,42 +308,37 @@ class TransformerLM:
         return jax.jit(write, donate_argnums=(0, 1))
 
     def compileCacheSize(self) -> int:
-        """Total jit-cache entries across the forward/prefill/decode/
-        verify/propose executables — the serving tier's compile hit/miss
-        probe."""
-        n = 0
-        fns = [self.__dict__.get(name)
-               for name in ("_fwd", "_prefillFn", "_decodeFn",
-                            "_verifyFn", "_prefillRawFn")]
-        fns.extend(self.__dict__.get("_proposeFns", {}).values())
-        for fn in fns:
-            if fn is not None:
-                try:
-                    n += int(fn._cache_size())
-                except Exception:
-                    pass
-        return n
+        """Jit-cache entries of the forward and the prefill — the
+        serving tier's compile hit/miss probe."""
+        return served_jit_entries(self)
+
+    def dropCompiled(self) -> None:
+        """Forget the cached jits (the scheduler calls this when the
+        pool or the plan changes; the next call traces afresh)."""
+        drop_served_jits(self)
 
     # ------------------------------------------------------------------
-    def generate(self, prompts, maxNewTokens: int, lengths=None
-                 ) -> np.ndarray:
-        """Greedy decode: (b, t) prompts -> (b, maxNewTokens) int32.
+    def generate(self, prompts, maxNewTokens: int) -> np.ndarray:
+        """Greedy decode by recompute: (b, t) prompts -> (b,
+        maxNewTokens) int32, each token the arg-max of :meth:`forward`
+        over everything before it.  No cache of any kind: this is the
+        reference the served path (``ContinuousBatcher``) is held to,
+        and it costs a full forward a token.  Every forward runs on one
+        ``(b, maxLen)`` right-padded shape (causal, so no real position
+        attends the pad): one compile whatever the lengths.
 
-        Capacity check: t + maxNewTokens must fit ``maxLen`` (the caches
-        are fixed-size by design — growing them would re-trace)."""
+        Capacity check: t + maxNewTokens must fit ``maxLen``."""
         prompts = np.asarray(prompts, np.int32)
         if prompts.ndim == 1:
             prompts = prompts[None, :]
-        t = prompts.shape[1]
+        b, t = prompts.shape
         if t + maxNewTokens > self.config.maxLen:
             raise ValueError(
-                f"prompt {t} + maxNewTokens {maxNewTokens} exceeds cache "
+                f"prompt {t} + maxNewTokens {maxNewTokens} exceeds "
                 f"capacity {self.config.maxLen}")
-        logits, caches = self.prefill(prompts, lengths)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out = [tok]
-        for _ in range(maxNewTokens - 1):   # token 0 came from prefill —
-            logits, caches = self.decodeStep(tok, caches)   # N-1 steps
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(tok)
-        return np.stack([np.asarray(o) for o in out], axis=1)
+        toks = np.zeros((b, self.config.maxLen), np.int32)
+        toks[:, :t] = prompts
+        for i in range(t, t + maxNewTokens):
+            greedy = jnp.argmax(self.forward(toks), axis=-1)
+            toks[:, i] = np.asarray(greedy)[:, i - 1]
+        return toks[:, t:t + maxNewTokens]

@@ -14,7 +14,7 @@ dispatch.  Data format follows the DL4J RNN convention (b, nIn, t); masks are
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +25,8 @@ from deeplearning4j_tpu.nn.weights import init_weight
 
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
-           "KVCache", "cached_attention", "paged_attention",
-           "paged_prefill_write", "paged_step_tokens", "CacheSpec"]
+           "paged_attention", "paged_prefill_write", "paged_step_tokens",
+           "CacheSpec", "served_jit_entries", "drop_served_jits"]
 
 
 def _mha(x_btn, Wq, Wk, Wv, Wo, nHeads, mask=None, q_btn=None, impl="auto",
@@ -52,89 +52,16 @@ def _mha(x_btn, Wq, Wk, Wv, Wo, nHeads, mask=None, q_btn=None, impl="auto",
     return jnp.matmul(ctx, Wo)                       # (b, tq, nOut)
 
 
-# ---------------------------------------------------------------------------
-# incremental (KV-cached) decode — the serving tier's O(1)-per-token path
-# ---------------------------------------------------------------------------
-
-class KVCache(NamedTuple):
-    """Per-layer key/value cache for incremental causal decode.
-
-    A NamedTuple of jax arrays IS a pytree, so a cache flows through
-    ``jax.jit`` unchanged and the decode executable's shapes stay STATIC:
-    ``k``/``v`` are allocated at full ``capacity`` up front and written
-    in place with ``lax.dynamic_update_slice``, so serving one more token
-    never re-traces — the compile-once/serve-many discipline the bucketed
-    executor (``remote/serving.py``) is built on.
-
-    ``start`` carries per-example left-padding offsets: bucketed serving
-    left-pads ragged prompts to one prompt bucket, which keeps the write
-    position ``pos`` a single scalar for the whole batch (a right-padded
-    layout would need per-example scatter writes every step).  Keys before
-    ``start[b]`` are masked out of every attention.
-    """
-    k: jax.Array        # (b, nHeads, capacity, headSize)
-    v: jax.Array        # (b, nHeads, capacity, headSize)
-    pos: jax.Array      # () int32 — next write index (tokens cached so far)
-    start: jax.Array    # (b,) int32 — first VALID key index per example
-
-    @staticmethod
-    def create(batch: int, nHeads: int, capacity: int, headSize: int,
-               dtype=jnp.float32, start=None) -> "KVCache":
-        return KVCache(
-            k=jnp.zeros((batch, nHeads, capacity, headSize), dtype),
-            v=jnp.zeros((batch, nHeads, capacity, headSize), dtype),
-            pos=jnp.asarray(0, jnp.int32),
-            start=(jnp.zeros((batch,), jnp.int32) if start is None
-                   else jnp.asarray(start, jnp.int32)))
-
-    @property
-    def capacity(self) -> int:
-        return int(self.k.shape[2])
-
-
-def cached_attention(qh, kh_new, vh_new, cache: KVCache):
-    """Causal attention of ``tq`` NEW positions against a KV cache.
-
-    ``qh``/``kh_new``/``vh_new``: (b, h, tq, d) for the new positions only.
-    Writes the new K/V at ``[pos, pos+tq)`` and attends over the whole
-    fixed-capacity cache with validity masking (key index within
-    ``[start[b], pos+i]`` for query ``i``) — per-token cost is
-    O(capacity), independent of how many tokens were generated, and the
-    prefix is never recomputed through the layer stack.
-    """
-    b, h, tq, d = qh.shape
-    pos = jnp.asarray(cache.pos, jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-    k = jax.lax.dynamic_update_slice(
-        cache.k, kh_new.astype(cache.k.dtype), (zero, zero, pos, zero))
-    v = jax.lax.dynamic_update_slice(
-        cache.v, vh_new.astype(cache.v.dtype), (zero, zero, pos, zero))
-    cap = k.shape[2]
-    kpos = jnp.arange(cap, dtype=jnp.int32)
-    qpos = pos + jnp.arange(tq, dtype=jnp.int32)
-    valid = (kpos[None, :] <= qpos[:, None])[None]          # (1, tq, cap)
-    valid = valid & (kpos[None, None, :] >=
-                     cache.start[:, None, None])            # (b, tq, cap)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, k.astype(qh.dtype))
-    s = s * (1.0 / jnp.sqrt(jnp.asarray(d, s.dtype)))
-    s = jnp.where(valid[:, None], s, jnp.asarray(-1e30, s.dtype))
-    w = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhqk,bhkd->bhqd", w, v.astype(qh.dtype))
-    return ctx, KVCache(k, v, pos + tq, cache.start)
-
-
 def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
                     start):
     """Causal attention of ``tq`` new positions against a PAGED KV pool.
 
-    Where :func:`cached_attention` owns a private fixed-capacity buffer
-    per batch, this is the pooled variant the continuous-batching
-    scheduler (``remote/scheduler.py``) decodes through: K/V live in a
-    shared pool of fixed-size pages and each decode SLOT addresses its
-    own pages through a page table, so sequences of wildly different
-    lengths share one preallocated buffer and admitting/retiring a
-    sequence is a host-side page-table edit — never a reallocation, and
-    never a new executable shape.
+    The continuous-batching scheduler (``remote/scheduler.py``) decodes
+    through this: K/V live in a shared pool of fixed-size pages and each
+    decode SLOT addresses its own pages through a page table, so
+    sequences of wildly different lengths share one preallocated buffer
+    and admitting/retiring a sequence is a host-side page-table edit —
+    never a reallocation, and never a new executable shape.
 
     - ``qh``/``kh_new``/``vh_new``: (slots, heads, tq, headSize) for the
       new positions only;
@@ -149,17 +76,17 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
     - ``pageTable``: (slots, maxPagesPerSeq) int32 physical page ids in
       logical order (unallocated tail entries point at the scratch
       page and are masked out by ``pos``);
-    - ``pos``/``start``: (slots,) int32 — next write index and first
-      valid key index per slot (identical semantics to
-      ``KVCache.pos``/``KVCache.start``, but per slot instead of per
-      batch).
+    - ``pos``/``start``: (slots,) int32 — per slot, the next write
+      index (tokens cached so far) and the index of the first VALID key
+      (a left-padded prompt's pad rows lie before it).
 
     Writes the new K/V rows into their pages (``tq`` may span a page
     boundary — each token's page/offset is computed independently),
-    gathers every slot's pages back in logical order and attends with
-    the same validity mask as :func:`cached_attention` (key index
-    within ``[start[s], pos[s]+i]`` for query ``i``).  Returns
-    ``(ctx, newPoolK, newPoolV)``.
+    gathers every slot's pages back in logical order and attends over
+    the slot's whole capacity under a validity mask: query ``i`` of slot
+    ``s`` sees key index ``j`` iff ``start[s] <= j <= pos[s] + i`` —
+    causal, and blind to the pad, the unwritten tail and the scratch
+    page.  Returns ``(ctx, newPoolK, newPoolV)``.
     """
     S, h, tq, d = qh.shape
     pageSize = poolK.shape[2]
@@ -242,6 +169,35 @@ def paged_step_tokens(toks, prev):
     return jnp.where(toks < 0, prev, toks)
 
 
+#: the jits a served model caches on itself (``cached_property``): the
+#: full forward and the prefill.  Its paged step and pool write are built
+#: fresh for the scheduler, which owns them.
+_SERVED_JITS = ("_fwd", "_prefillRawFn")
+
+
+def served_jit_entries(model) -> int:
+    """Jit-cache entries across ``model``'s own executables: a served
+    model's ``compileCacheSize()``.  The batcher reads it every decode
+    step, so it looks at built jits only and builds none."""
+    n = 0
+    for name in _SERVED_JITS:
+        fn = model.__dict__.get(name)
+        if fn is not None:
+            try:
+                n += int(fn._cache_size())
+            except Exception:
+                pass
+    return n
+
+
+def drop_served_jits(model) -> None:
+    """A served model's ``dropCompiled()``: JAX's jaxpr cache keys on
+    function identity + avals (not shardings), so after a change of pool
+    or plan a reused closure would resurrect the old placement's trace."""
+    for name in _SERVED_JITS:
+        model.__dict__.pop(name, None)
+
+
 @dataclasses.dataclass
 class SelfAttentionLayer(BaseLayer):
     """Per-timestep self-attention over the sequence.
@@ -250,9 +206,7 @@ class SelfAttentionLayer(BaseLayer):
     output (b, nOut, t).  ``projectInput`` must be true when nHeads > 1
     (matching the reference's validation).
 
-    ``causal=True`` masks attention to past-and-self (decoder style); only
-    causal layers can serve through the incremental :meth:`decodeStep`
-    path (the KV cache can't contain the future).
+    ``causal=True`` masks attention to past-and-self (decoder style).
     """
     nIn: int = 0
     nOut: int = 0
@@ -306,42 +260,6 @@ class SelfAttentionLayer(BaseLayer):
             eye = jnp.eye(self.nIn, dtype=xt.dtype)
             y = _mha(xt, eye, eye, eye, eye, 1, mask, causal=self.causal)
         return jnp.transpose(y, (0, 2, 1)), state
-
-    # -- incremental decode (KV cache) ----------------------------------
-    def initCache(self, batch: int, capacity: int, dtype=jnp.float32,
-                  start=None) -> KVCache:
-        """Fresh fixed-capacity cache for :meth:`decodeStep`."""
-        if not self.causal:
-            raise ValueError(
-                "KV-cache decode requires causal=True (an incremental "
-                "step can only ever attend to the past)")
-        h = self.nHeads if self.projectInput else 1
-        d = self.headSize if self.projectInput else self.nIn
-        return KVCache.create(batch, h, capacity, d, dtype, start=start)
-
-    def decodeStep(self, params, x, cache: KVCache):
-        """Feed ``t_new`` timesteps (x: (b, nIn, t_new)), attending to
-        everything cached so far plus the new steps — exactly the causal
-        ``forward`` restricted to new positions, at O(capacity) instead of
-        O(t²) per call.  Returns ``(y (b, nOut, t_new), new_cache)``."""
-        xt = jnp.transpose(x, (0, 2, 1))             # (b, t_new, nIn)
-        b, tq, _ = xt.shape
-
-        def heads(inp, w, n):
-            y = jnp.matmul(inp, w)
-            return y.reshape(b, tq, n, -1).transpose(0, 2, 1, 3)
-
-        if self.projectInput:
-            qh = heads(xt, params["Wq"], self.nHeads)
-            kh = heads(xt, params["Wk"], self.nHeads)
-            vh = heads(xt, params["Wv"], self.nHeads)
-            Wo = params["Wo"]
-        else:
-            qh = kh = vh = xt[:, None]               # (b, 1, t_new, nIn)
-            Wo = jnp.eye(self.nIn, dtype=xt.dtype)
-        ctx, cache = cached_attention(qh, kh, vh, cache)
-        y = jnp.matmul(ctx.transpose(0, 2, 1, 3).reshape(b, tq, -1), Wo)
-        return jnp.transpose(y, (0, 2, 1)), cache
 
 
 @dataclasses.dataclass

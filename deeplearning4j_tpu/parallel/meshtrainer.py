@@ -107,8 +107,7 @@ def reshard_tree(tree, shardings):
 #: __dict__ — every inference-mode re-placement must pop these: JAX's
 #: jaxpr cache keys on function identity + avals (NOT shardings), so a
 #: reused closure would resurrect the previous placement's trace
-_INFERENCE_CACHE_KEYS = ("_fwd", "_prefillFn", "_prefillRawFn",
-                         "_decodeFn", "_verifyFn", "_proposeFns",
+_INFERENCE_CACHE_KEYS = ("_fwd", "_prefillRawFn",
                          "_outputFn", "_scoreFn", "_trainStep")
 
 

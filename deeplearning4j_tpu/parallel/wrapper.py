@@ -175,8 +175,8 @@ class ParallelWrapper:
     def _timing(self) -> ReplicaTimingListener:
         """Persistent straggler/contention watcher for this wrapper's mesh:
         per-replica lockstep step-time gauges + the rolling max/min spread
-        (``dl4j_tpu_parallel_step_time_spread``) matching bench.py's
-        contention flag."""
+        (``dl4j_tpu_parallel_step_time_spread``; above 2.0 the window was
+        contended)."""
         if getattr(self, "_replicaTimer", None) is None:
             devices = list(self.mesh.mesh.devices.flat)
             self._replicaTimer = ReplicaTimingListener(devices)

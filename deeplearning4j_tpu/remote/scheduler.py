@@ -573,11 +573,8 @@ class ContinuousBatcher:
         constraints belong to the old layout."""
         self._stepFns.clear()
         for m in (self.lm, self.draft):
-            if m is None:
-                continue
-            for k in ("_fwd", "_prefillFn", "_prefillRawFn", "_decodeFn",
-                      "_verifyFn", "_proposeFns"):
-                m.__dict__.pop(k, None)
+            if m is not None:
+                m.dropCompiled()
         self._warmed = False
         self._cacheSeen = None
 

@@ -1,5 +1,5 @@
-"""Continuous-batching serving tier: warm bucketed executables, KV-cache
-decode, multi-model hosting, admission control.
+"""Serving tier: warm bucketed executables, multi-model hosting,
+admission control.
 
 The in-process ``JsonModelServer`` + ``ParallelInference`` pair re-traces
 on every novel batch shape and has no backpressure; this tier is the
@@ -14,10 +14,10 @@ specialization TVM argues for, PAPERS arXiv:1802.04799):
   concatenation of raw shapes), pads, dispatches, and splits results
   back per request.  Weights stay device-resident jax buffers shared by
   every worker thread — requests carry only activations;
-- :class:`ForwardServing` / :class:`GenerativeServing` — the two model
-  adapters: padded batched forward (mask-correct for sequence models)
-  and KV-cache decode (prefill once, O(1)-per-token generation through
-  :class:`~deeplearning4j_tpu.nlp.transformer.TransformerLM`);
+- :class:`ForwardServing` — the model adapter: padded batched forward
+  (mask-correct for sequence models).  A language model is not hosted
+  through an adapter: it is a ``scheduler.ContinuousBatcher`` (or a
+  ``scheduler.ReplicaSet`` of them), registered as-is;
 - :class:`AdmissionControl` — load shedding (HTTP 429 + ``Retry-After``)
   driven by ``ThresholdRule``s over the ``dl4j_tpu_serving_*`` metrics
   (queue depth, p99 read off the request histogram) — the same
@@ -27,8 +27,9 @@ specialization TVM argues for, PAPERS arXiv:1802.04799):
   default model), with the shared observability GET surface.
 
 Compile-cache accounting: every dispatch measures the model's jit cache
-size; steady state must be all hits (``bench.py --serving`` asserts the
-hit rate, and the warm ladder is the mechanism that makes it true).
+size; steady state must be all hits (the compile-cache hit rate off
+``dl4j_tpu_serving_compile_cache_{hits,misses}_total``), and the warm
+ladder is the mechanism that makes it true.
 """
 from __future__ import annotations
 
@@ -50,8 +51,8 @@ from deeplearning4j_tpu.telemetry import (RequestContext, ThresholdRule,
 
 __all__ = ["BucketLadder", "ServiceOverloaded", "DeadlineExceeded",
            "NoHealthyReplicas", "AdmissionControl", "ForwardServing",
-           "GenerativeServing", "BucketedExecutor", "ModelRegistry",
-           "InferenceServer", "histogram_quantile"]
+           "BucketedExecutor", "ModelRegistry", "InferenceServer",
+           "histogram_quantile"]
 
 
 class ServiceOverloaded(RuntimeError):
@@ -418,120 +419,6 @@ class ForwardServing:
             return None
 
 
-class GenerativeServing:
-    """Bucketed KV-cache generation for :class:`TransformerLM`.
-
-    Requests are ``{"tokens": [...], "maxNewTokens": n}``; the group key
-    is the PROMPT bucket, prompts are LEFT-padded to it (uniform cache
-    write position — see ``KVCache.start``), and one prefill + max(n)
-    decode steps serve the whole group.  Decode executables exist per
-    batch bucket only — generation length never changes a shape.
-    """
-
-    def __init__(self, lm, ladder: Optional[BucketLadder] = None):
-        self.lm = lm
-        cap = lm.config.maxLen
-        self.ladder = ladder or BucketLadder(
-            batchSizes=(1, 2, 4, 8),
-            seqLens=tuple(s for s in (16, 32, 64, 128, 256, 512, 1024)
-                          if s <= cap // 2) or (cap // 2,))
-
-    def makeRequest(self, payload) -> _Request:
-        if not isinstance(payload, dict) or "tokens" not in payload:
-            raise ValueError('generative request needs {"tokens": [...]}')
-        # jaxlint: sync-ok -- request decode: token ids arrive as host JSON
-        toks = np.asarray(payload["tokens"], np.int32)
-        if toks.ndim == 1:
-            toks = toks[None, :]
-        if toks.ndim != 2 or toks.shape[0] < 1 or toks.shape[1] < 1:
-            # enqueue-time rejection (offender-only 400): a zero-row or
-            # empty prompt coalesced into a group would fail mid-dispatch
-            # and poison every neighbour's request
-            raise ValueError(f"tokens must be (t,) or (b, t) with b >= 1 "
-                             f"and t >= 1; got shape {toks.shape}")
-        vocab = self.lm.config.vocabSize
-        if toks.min() < 0 or toks.max() >= vocab:
-            raise ValueError(f"token ids must be in [0, {vocab})")
-        n = int(payload.get("maxNewTokens", 16))
-        if n < 1:
-            raise ValueError("maxNewTokens must be >= 1")
-        Tp = self.ladder.seqBucket(toks.shape[1])
-        if Tp + n > self.lm.config.maxLen:
-            raise ValueError(
-                f"prompt bucket {Tp} + maxNewTokens {n} exceeds cache "
-                f"capacity {self.lm.config.maxLen}")
-        return _Request({"tokens": toks, "n": n}, toks.shape[0])
-
-    def groupKey(self, req: _Request):
-        return ("gen", self.ladder.seqBucket(req.payload["tokens"].shape[1]))
-
-    def maxRowsPerDispatch(self, key) -> int:
-        return self.ladder.maxBatch
-
-    def _left_pad(self, toks: np.ndarray, Tp: int) -> np.ndarray:
-        if toks.shape[1] == Tp:
-            return toks
-        pad = np.zeros((toks.shape[0], Tp - toks.shape[1]), np.int32)
-        return np.concatenate([pad, toks], axis=1)
-
-    def dispatch(self, key, reqs: List[_Request]) -> List[np.ndarray]:
-        Tp = key[1]
-        toks = np.concatenate(
-            [self._left_pad(r.payload["tokens"], Tp) for r in reqs], axis=0)
-        lengths = np.concatenate(
-            [np.full(r.rows, r.payload["tokens"].shape[1], np.int32)
-             for r in reqs])
-        steps = max(r.payload["n"] for r in reqs)
-        rows = toks.shape[0]
-        sm = serving_metrics()
-        name = _model_name.get() or "?"
-        results: List[Optional[np.ndarray]] = [None] * len(reqs)
-        chunk_start = 0
-        outs = []
-        maxB = self.ladder.maxBatch
-        while chunk_start < rows:
-            n = min(maxB, rows - chunk_start)
-            B = self.ladder.batchBucket(n)
-            ct = toks[chunk_start:chunk_start + n]
-            cl = lengths[chunk_start:chunk_start + n]
-            if n < B:
-                # pad rows: single-token prompts, generated then dropped
-                ct = np.concatenate(
-                    [ct, np.zeros((B - n, Tp), np.int32)], axis=0)
-                cl = np.concatenate([cl, np.ones(B - n, np.int32)])
-            sm.pad_rows().inc(B - n, model=name)
-            sm.batch_occupancy().set(n / B, model=name)
-            outs.append(self.lm.generate(ct, steps, lengths=cl)[:n])
-            sm.decode_tokens().inc(B * steps, model=name)
-            chunk_start += n
-        gen = np.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-        pos = 0
-        for i, r in enumerate(reqs):
-            results[i] = gen[pos:pos + r.rows, :r.payload["n"]]
-            pos += r.rows
-        return results
-
-    def warmKeys(self):
-        return [("gen", s) for s in self.ladder.seqLens]
-
-    def warm(self, key) -> None:
-        Tp = key[1]
-        if Tp + 2 > self.lm.config.maxLen:
-            return
-        for B in self.ladder.batchSizes:
-            # 2 new tokens: token 0 comes from prefill's logits, so only
-            # a 2+-token generate compiles the decode executable too
-            toks = np.zeros((B, Tp), np.int32)
-            self.lm.generate(toks, 2,
-                             lengths=np.full(B, max(1, Tp // 2), np.int32))
-
-    def compileCacheSize(self) -> Optional[int]:
-        try:
-            return int(self.lm.compileCacheSize())
-        except Exception:
-            return None
-
-
 # ---------------------------------------------------------------------------
 # access log
 # ---------------------------------------------------------------------------
@@ -647,8 +534,7 @@ class BucketedExecutor:
         sm = serving_metrics()
         t0 = time.perf_counter()
         from deeplearning4j_tpu.compile.aotcache import wrap_serving_model
-        wrap_serving_model(getattr(self.serving, "model", None) or
-                           getattr(self.serving, "lm", None))
+        wrap_serving_model(getattr(self.serving, "model", None))
         before = self.serving.compileCacheSize()
         _model_name.name = self.name
         try:
@@ -887,9 +773,9 @@ class ModelRegistry:
     def register(self, name: str, serving,
                  admission: Optional[AdmissionControl] = None,
                  workers: int = 1):
-        """``serving`` is a model adapter (:class:`ForwardServing` /
-        :class:`GenerativeServing`, wrapped in a fresh
-        :class:`BucketedExecutor`) or an already-built executor-like —
+        """``serving`` is a model adapter (:class:`ForwardServing`,
+        wrapped in a fresh :class:`BucketedExecutor`) or an
+        already-built executor-like —
         anything with ``start``/``submit``/``shutdown`` (a
         ``BucketedExecutor``, a continuous-batching
         ``scheduler.ContinuousBatcher``, a ``scheduler.ReplicaSet``)

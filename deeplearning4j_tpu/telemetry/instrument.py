@@ -1178,9 +1178,9 @@ class ReplicaTimingListener:
 
     Under GSPMD the step is ONE executable synchronous across replicas, so
     each replica's step time IS the lockstep wall time; the straggler /
-    contention signal ``bench.py`` flags (``timing_spread``) is the
-    max/min ratio over a rolling window of those lockstep times — a
-    contended window reads as spread, not as a uniform regression."""
+    contention signal is the max/min ratio over a rolling window of
+    those lockstep times — a contended window reads as spread, not as a
+    uniform regression."""
 
     def __init__(self, devices: Sequence, window: int = 20):
         self._device_ids = [str(getattr(d, "id", i))
@@ -1248,6 +1248,6 @@ class ReplicaTimingListener:
             if lo > 0:
                 reg.gauge(
                     "dl4j_tpu_parallel_step_time_spread",
-                    "max/min step time over a rolling window (bench.py's "
-                    "contention flag fires above 2.0)").set(
+                    "max/min step time over a rolling window (above 2.0 "
+                    "the window was contended)").set(
                         max(self._times) / lo)

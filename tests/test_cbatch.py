@@ -13,9 +13,8 @@ import pytest
 
 from deeplearning4j_tpu.nlp.transformer import TransformerLM
 from deeplearning4j_tpu.remote import (AdmissionControl, ContinuousBatcher,
-                                       GenerativeServing, InferenceServer,
-                                       ModelRegistry, ReplicaSet,
-                                       ServiceOverloaded)
+                                       InferenceServer, ModelRegistry,
+                                       ReplicaSet, ServiceOverloaded)
 from deeplearning4j_tpu.telemetry import get_registry, serving_metrics
 
 pytestmark = pytest.mark.cbatch
@@ -48,16 +47,15 @@ def _token_major(hist, ps):
     (1, 1, 7, 2),       # the plain decode step
     (3, 2, 6, 1),       # a speculative verify: writes 6, 7 | 8 cross a page
 ])
-def test_paged_attention_matches_cached_attention(tq, layers, pos, start):
-    """The pooled page-table lookup is numerically the same attention as
-    the dense per-batch KVCache (same validity mask, same math); each new
-    token lands in its own page and row of layer 0 of the stacked
-    token-major pool, and nothing else — no other row, no other layer —
-    is touched."""
+def test_paged_attention_matches_masked_softmax(tq, layers, pos, start):
+    """The pooled page-table lookup is plain softmax attention over the
+    slot's history with the new rows written at ``[pos, pos + tq)``,
+    query ``i`` seeing key ``j`` iff ``start <= j <= pos + i`` (written
+    out below, no cache); each new token lands in its own page and row
+    of layer 0 of the stacked token-major pool, and nothing else — no
+    other row, no other layer — is touched."""
     import jax.numpy as jnp
-    from deeplearning4j_tpu.nn.conf.attention import (KVCache,
-                                                      cached_attention,
-                                                      paged_attention)
+    from deeplearning4j_tpu.nn.conf.attention import paged_attention
     rng = np.random.RandomState(tq)
     S, h, d, ps, P = 2, 2, 4, 4, 3          # capacity = 12
     qh = jnp.asarray(rng.randn(S, h, tq, d), jnp.float32)
@@ -65,11 +63,15 @@ def test_paged_attention_matches_cached_attention(tq, layers, pos, start):
     vh = jnp.asarray(rng.randn(S, h, tq, d), jnp.float32)
     hist_k = rng.randn(S, h, 12, d).astype(np.float32)
     hist_v = rng.randn(S, h, 12, d).astype(np.float32)
-    # dense reference
-    cache = KVCache(jnp.asarray(hist_k), jnp.asarray(hist_v),
-                    jnp.asarray(pos, jnp.int32),
-                    jnp.full((S,), start, jnp.int32))
-    ref, _ = cached_attention(qh, kh, vh, cache)
+    # reference: the dense history with the new rows in place
+    k, v = hist_k.astype(np.float64), hist_v.astype(np.float64)
+    k[:, :, pos:pos + tq], v[:, :, pos:pos + tq] = kh, vh
+    scores = np.einsum("shqd,shkd->shqk", np.asarray(qh, np.float64),
+                       k) / np.sqrt(d)
+    j, i = np.arange(12)[None, :], np.arange(tq)[:, None]
+    scores = np.where((start <= j) & (j <= pos + i), scores, -np.inf)
+    w = np.exp(scores - scores.max(-1, keepdims=True))
+    ref = np.einsum("shqk,shkd->shqd", w / w.sum(-1, keepdims=True), v)
     # paged: the same history cut into token-major pages of layer 0
     # (page 0 = scratch; slot 0 holds pages 6, 5, 4 and slot 1 pages
     # 3, 2, 1: not in physical order); every other row holds noise
@@ -227,21 +229,17 @@ def test_preemption_restarts_and_recovers_bit_identical():
     produces the exact greedy stream; the oldest slot always progresses
     (no ping-pong livelock)."""
     lm = _lm(layers=1, maxLen=48, seed=6)
-    cb = ContinuousBatcher(lm, name="cb-preempt", pageSize=8, numPages=9,
-                           maxSlots=2).start()
+    # both requests pass the door before the loop takes a step (by hand:
+    # behind a running loop the second one, a thread start later, met a
+    # pool the first had already grown into and was shed 429)
+    cb = _by_hand(lm, "cb-preempt", numPages=9, maxSlots=2)
     try:
         rng = np.random.RandomState(1)
         pa = rng.randint(1, 40, (1, 12)).astype(np.int32)
         pb = rng.randint(1, 40, (1, 12)).astype(np.int32)
-        res = [None, None]
-        ths = [threading.Thread(target=lambda i=i, p=p: res.__setitem__(
-            i, cb.submit({"tokens": p[0].tolist(), "maxNewTokens": 30},
-                         timeout=120)))
-            for i, p in enumerate((pa, pb))]
-        for th in ths:
-            th.start()
-        for th in ths:
-            th.join(timeout=120)
+        gens = [_stream(cb, p[0].tolist(), 30)[0] for p in (pa, pb)]
+        _run_out(cb)
+        res = [np.asarray([list(g)], np.int32) for g in gens]
         np.testing.assert_array_equal(res[0], lm.generate(pa, 30))
         np.testing.assert_array_equal(res[1], lm.generate(pb, 30))
         assert serving_metrics().preemptions().value(
@@ -547,28 +545,57 @@ def test_preempt_defer_and_replay_with_a_step_unread_deliver_once(how):
         cb.shutdown()
 
 
+# ----------------------------------------- dropping what was compiled ----
+
+def _served(kind):
+    if kind == "transformer":
+        return _lm(layers=1)
+    if kind == "retrieval":
+        from deeplearning4j_tpu.models.recsys import RetrievalLM
+        table = np.random.RandomState(0).randn(64, 8).astype(np.float32)
+        return RetrievalLM(table, table, maxLen=64)
+    from deeplearning4j_tpu.nlp.sambay import SambaYConfig, SambaYLM
+    return SambaYLM(SambaYConfig())
+
+
+@pytest.mark.parametrize("kind", ["transformer", "retrieval", "sambay"])
+def test_invalidate_drops_every_jit_and_warm_rebuilds_the_served_set(kind):
+    """A change of pool or plan leaves no compiled closure behind, on
+    the batcher or on the model, and the next warm compiles the served
+    set again and nothing else: prefill on the model; step and write on
+    the batcher."""
+    from deeplearning4j_tpu.remote import BucketLadder
+    lm = _served(kind)
+    cb = ContinuousBatcher(lm, name=f"cb-drop-{kind}", pageSize=4,
+                           maxSlots=3,
+                           ladder=BucketLadder(batchSizes=(3,),
+                                               seqLens=(8, 16)))
+    cb.warm()
+    first = cb.compileCacheSize()
+    assert lm.compileCacheSize() == 2           # one prefill a bucket
+    assert first > lm.compileCacheSize()
+    cb._invalidateFns()
+    assert not cb._stepFns and cb.compileCacheSize() == 0
+    assert not {"_fwd", "_prefillRawFn"} & set(vars(lm))
+    cb.warm()
+    assert set(cb._stepFns) == {"step", "write"}
+    assert {"_fwd", "_prefillRawFn"} & set(vars(lm)) == {"_prefillRawFn"}
+    assert cb.compileCacheSize() == first
+
+
 # ------------------------------------------------ speculative decode ----
 
 def test_speculative_decode_bit_identical_to_greedy():
-    """Accept-prefix speculative decode == target-only greedy, exactly:
-    standalone (dense caches) and through the continuous batcher (paged
-    pools, per-slot accept lengths), with an arbitrary draft AND a
-    zero-tail draft that accepts everything."""
+    """Accept-prefix speculative decode == target-only greedy, exactly,
+    through the continuous batcher (paged pools, per-slot accept
+    lengths), with a zero-tail draft that accepts everything."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.remote import BucketLadder
     target = _lm(layers=2, seed=7)
     draft = _lm(layers=1, seed=9)
     rng = np.random.RandomState(0)
-    p = rng.randint(1, 40, (1, 10)).astype(np.int32)
-    ref = target.generate(p, 12)
-    out, stats = target.speculative_generate(draft, p, 12, draftK=4,
-                                             returnStats=True)
-    np.testing.assert_array_equal(out, ref)
-    assert stats["proposed"] > 0
-    # zero-tail: REUSE the same instances (params are executable args,
-    # so same-shaped swaps recompile nothing) — target's second layer
-    # contributes nothing and the draft IS its first layer: logits
-    # identical => acceptance is total
+    # zero-tail: target's second layer contributes nothing and the
+    # draft IS its first layer: logits identical => acceptance is total
     lp = target.params["layers"][1]
     lp["Wo"] = jnp.zeros_like(lp["Wo"])
     lp["Wp"] = jnp.zeros_like(lp["Wp"])
@@ -578,10 +605,6 @@ def test_speculative_decode_bit_identical_to_greedy():
                     "lnf_g": target.params["lnf_g"],
                     "lnf_b": target.params["lnf_b"],
                     "layers": [target.params["layers"][0]]}
-    out2, st2 = target.speculative_generate(draft, p, 16, draftK=4,
-                                            returnStats=True)
-    np.testing.assert_array_equal(out2, target.generate(p, 16))
-    assert st2["acceptRate"] == 1.0
     # continuous batcher with the draft: concurrent ragged requests,
     # per-slot accept lengths, still bit-identical
     cb = ContinuousBatcher(target, name="cb-spec", draft=draft, draftK=3,
@@ -670,18 +693,30 @@ def test_kv_headroom_sheds_and_enqueue_rejects():
         cb.shutdown()
 
 
-def test_generative_serving_enqueue_rejection_regression():
-    """The group-at-a-time path keeps the same discipline: oversized
-    prompts / impossible quotas / zero-row batches 400 at enqueue, and
-    an offender never poisons a coalesced batch (ISSUE 15 satellite)."""
+def test_enqueue_rejection_is_offender_only():
+    """Oversized prompts / impossible quotas / zero-row batches 400 at
+    ``submit``, while a neighbour is mid-decode, and the neighbour's
+    tokens come out untouched: an offender never poisons the shared
+    batch (ISSUE 15 satellite)."""
     lm = _lm(layers=1, maxLen=64)
-    gs = GenerativeServing(lm)
-    with pytest.raises(ValueError, match="exceeds the top bucket"):
-        gs.makeRequest({"tokens": list(range(1, 36))})   # top bucket 32
-    with pytest.raises(ValueError, match="capacity"):
-        gs.makeRequest({"tokens": [1, 2, 3], "maxNewTokens": 60})
-    with pytest.raises(ValueError, match="b >= 1"):
-        gs.makeRequest({"tokens": np.zeros((0, 4), np.int32).tolist()})
+    cb = ContinuousBatcher(lm, name="cb-reject", pageSize=8,
+                           maxSlots=2).start()
+    try:
+        p = np.random.RandomState(3).randint(1, 40, (1, 9)).astype(np.int32)
+        stream = cb.submitStream({"tokens": p[0].tolist(),
+                                  "maxNewTokens": 20})
+        got = [next(stream)]                    # admitted and decoding
+        with pytest.raises(ValueError, match="exceeds the top bucket"):
+            cb.submit({"tokens": list(range(1, 36))})   # top bucket 32
+        with pytest.raises(ValueError, match="capacity"):
+            cb.submit({"tokens": [1, 2, 3], "maxNewTokens": 60})
+        with pytest.raises(ValueError, match="b >= 1"):
+            cb.submit({"tokens": np.zeros((0, 4), np.int32).tolist()})
+        got.extend(stream)
+        np.testing.assert_array_equal(np.asarray(got).ravel(),
+                                      lm.generate(p, 20)[0])
+    finally:
+        cb.shutdown()
     # ForwardServing shares the zero-row guard
     from deeplearning4j_tpu.remote import ForwardServing
     fs = ForwardServing(object(), inputShape=(4,))

@@ -57,7 +57,7 @@ def test_main_refuses_to_start_on_cpu(capsys):
 def test_import_does_not_initialize_the_backend():
     """One process owns the chip: a worker or launcher that merely imports
     the package (or this script) must not reach for it."""
-    code = ("import jax, deeplearning4j_tpu, chip_smoke, bench\n"
+    code = ("import jax, deeplearning4j_tpu, chip_smoke\n"
             "from jax._src import xla_bridge\n"
             "raise SystemExit(int(xla_bridge.backends_are_initialized()))")
     assert subprocess.run([sys.executable, "-c", code], cwd=REPO,

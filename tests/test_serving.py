@@ -1,5 +1,5 @@
-"""Continuous-batching serving-tier tests (ISSUE 8): bucketed warm
-executables, KV-cache decode, admission control, multi-model routing,
+"""Serving-tier tests (ISSUE 8): bucketed warm executables, the
+recompute reference for generation, admission control, multi-model routing,
 plus the ParallelInference shutdown-race / batch-poisoning fixes and the
 JsonModelServer client-disconnect guard."""
 import json
@@ -22,8 +22,8 @@ from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
 from deeplearning4j_tpu.nn.conf.recurrent import RnnOutputLayer
 from deeplearning4j_tpu.remote import (AdmissionControl, BucketedExecutor,
                                        BucketLadder, ForwardServing,
-                                       GenerativeServing, InferenceServer,
-                                       ModelRegistry, ServiceOverloaded)
+                                       InferenceServer, ModelRegistry,
+                                       ServiceOverloaded)
 from deeplearning4j_tpu.remote.serving import histogram_quantile
 from deeplearning4j_tpu.telemetry import get_registry, serving_metrics
 
@@ -301,86 +301,44 @@ def test_histogram_quantile_reads_bucket_bounds():
     assert histogram_quantile(h, 1.0, model="q") == 1.0
 
 
-# ------------------------------------------------------ KV-cache decode ----
-
-def test_kv_cache_decode_matches_full_recompute():
-    lm = TransformerLM(vocabSize=60, nLayers=2, nHeads=2, headSize=8,
-                       maxLen=48, seed=7)
-    rng = np.random.RandomState(0)
-    toks = rng.randint(0, 60, (3, 12)).astype(np.int32)
-    logits, caches = lm.prefill(toks)
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(lm.forward(toks))[:, -1],
-        rtol=2e-5, atol=2e-5)
-    seq = toks
-    for _ in range(3):      # each step's recompute is a fresh trace — keep
-        nxt = rng.randint(0, 60, (3,)).astype(np.int32)
-        logits, caches = lm.decodeStep(nxt, caches)
-        seq = np.concatenate([seq, nxt[:, None]], axis=1)
-        ref = np.asarray(lm.forward(seq))[:, -1]    # full recompute
-        np.testing.assert_allclose(np.asarray(logits), ref,
-                                   rtol=2e-5, atol=2e-5)
-
+# ------------------------------------- the LM's prefill and its oracle ----
 
 def test_left_padded_prefill_matches_unpadded():
     lm = TransformerLM(vocabSize=40, nLayers=1, nHeads=2, headSize=8,
                        maxLen=32, seed=9)
     rng = np.random.RandomState(1)
     toks = rng.randint(1, 40, (2, 9)).astype(np.int32)
-    ref, _ = lm.prefill(toks)
+    ref, kRef, _ = lm.prefillRaw(toks)
     padded = np.concatenate([np.zeros((2, 7), np.int32), toks], axis=1)
-    got, caches = lm.prefill(padded, lengths=[9, 9])
+    got, kGot, _ = lm.prefillRaw(padded, lengths=[9, 9])
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-    # decode off the padded cache still matches the unpadded recompute
-    nxt = np.array([5, 6], np.int32)
-    logits, _ = lm.decodeStep(nxt, caches)
-    ref2 = np.asarray(lm.forward(
-        np.concatenate([toks, nxt[:, None]], axis=1)))[:, -1]
-    np.testing.assert_allclose(np.asarray(logits), ref2,
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(lm.forward(toks))[:, -1],
                                rtol=2e-5, atol=2e-5)
+    # the real positions' K rows are the unpadded ones, behind the pad
+    np.testing.assert_allclose(np.asarray(kGot)[:, :, :, 7:],
+                               np.asarray(kRef), rtol=2e-5, atol=2e-5)
 
 
-def test_self_attention_layer_decode_step():
-    """The layer-level KV cache: causal forward == chained decodeStep."""
-    import jax
-    import jax.numpy as jnp
-    lay = SelfAttentionLayer(nIn=8, nHeads=2, headSize=4, causal=True)
-    it = InputType.recurrent(8, 6)
-    lay.inferNIn(it)
-    p = lay.initParams(jax.random.PRNGKey(0), it)
-    x = jnp.asarray(np.random.RandomState(2).randn(3, 8, 6), jnp.float32)
-    yfull, _ = lay.forward(p, x, False, None, {})
-    cache = lay.initCache(3, 6)
-    ys = []
-    for t in range(6):
-        yt, cache = lay.decodeStep(p, x[:, :, t:t + 1], cache)
-        ys.append(yt)
-    np.testing.assert_allclose(
-        np.asarray(jnp.concatenate(ys, axis=2)), np.asarray(yfull),
-        rtol=2e-5, atol=2e-5)
-    # non-causal layers cannot serve incrementally
-    with pytest.raises(ValueError, match="causal"):
-        SelfAttentionLayer(nIn=8, nHeads=2, headSize=4).initCache(1, 6)
-
-
-def test_generative_serving_bucketed_generation():
-    lm = TransformerLM(vocabSize=32, nLayers=1, nHeads=2, headSize=8,
-                       maxLen=64, seed=5)
-    gs = GenerativeServing(lm, BucketLadder(batchSizes=(1, 2),
-                                            seqLens=(8, 16)))
-    ex = BucketedExecutor(gs, name="gen").start()
-    try:
-        prompt = np.arange(1, 6, dtype=np.int32)     # ragged: buckets to 8
-        out = ex.submit({"tokens": prompt.tolist(), "maxNewTokens": 6})
-        ref = lm.generate(prompt[None, :], 6)
-        np.testing.assert_array_equal(out, ref)
-        # generation length capacity is validated per request
-        with pytest.raises(ValueError, match="capacity"):
-            ex.submit({"tokens": prompt.tolist(), "maxNewTokens": 1000})
-        assert serving_metrics().decode_tokens().value(model="gen") > 0
-    finally:
-        ex.shutdown()
+@pytest.mark.parametrize("t", [3, 12])
+def test_generate_is_stepwise_argmax_of_forward(t):
+    """``generate`` is the reference the served path is held to, so it
+    is itself held to the plainest statement: token i is the arg-max of
+    ``forward`` over the UNPADDED sequence so far."""
+    lm = TransformerLM(vocabSize=60, nLayers=2, nHeads=2, headSize=8,
+                       maxLen=24, seed=7)
+    seq = np.random.RandomState(t).randint(0, 60, (2, t)).astype(np.int32)
+    got = lm.generate(seq, 5)
+    assert got.shape == (2, 5) and got.dtype == np.int32
+    for i in range(5):
+        nxt = np.asarray(lm.forward(seq))[:, -1].argmax(-1)
+        np.testing.assert_array_equal(got[:, i], nxt)
+        seq = np.concatenate([seq, nxt[:, None].astype(np.int32)], axis=1)
+    assert lm.compileCacheSize() == 1 + 5     # (2, maxLen), then 5 lengths
+    lm.generate(seq[0, :t], 24 - t)           # one prompt, to the brim
+    with pytest.raises(ValueError, match="capacity"):
+        lm.generate(seq[:, :t], 24 - t + 1)
 
 
 # --------------------------------------------------- multi-model HTTP ----

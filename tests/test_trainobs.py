@@ -307,27 +307,6 @@ class TestStepPhaseExemplars:
         with pytest.raises(ValueError):
             observe_step_phase("teleport", 0.01)
 
-    def test_bench_decomposition_math(self):
-        """bench.py's quantile/share math over a before/after snapshot
-        delta: shares sum to 1 over observed phases, p50/p99 read off
-        the bucket upper bounds, unobserved phases stay null."""
-        import bench
-        before = bench._phase_snapshot()
-        with run_scope(RunContext.new()):
-            for _ in range(20):
-                observe_step_phase("compute", 0.09, step=1)
-            for _ in range(20):
-                observe_step_phase("data_wait", 0.009, step=1)
-        dec = bench._phase_decomposition(before)
-        assert set(dec) == {"data_wait", "h2d", "compute", "checkpoint",
-                            "barrier"}
-        assert dec["h2d"]["p50_ms"] is None and dec["h2d"]["share"] == 0.0
-        assert dec["compute"]["p50_ms"] == pytest.approx(100.0)
-        assert dec["data_wait"]["p50_ms"] == pytest.approx(10.0)
-        assert dec["compute"]["share"] == pytest.approx(0.9, abs=0.02)
-        assert dec["data_wait"]["share"] + dec["compute"]["share"] == \
-            pytest.approx(1.0)
-
 
 # ------------------------------------------------- health-event tagging --
 

@@ -14,10 +14,6 @@ Usage::
     python -m tools.aotc bake --cache-dir /ckpts/aot \\
         --mlp 32,64,10 --batches 1,2,4,8 --train
 
-    # generative ladder for a TransformerLM
-    python -m tools.aotc bake --cache-dir /ckpts/aot \\
-        --lm 128,2,4,16,128 --gen-batches 1,2,4 --seqs 16,32
-
     # sharded train step on a data=N mesh
     python -m tools.aotc bake --cache-dir /ckpts/aot \\
         --mlp 32,64,10 --train --mesh-data 2
@@ -29,8 +25,7 @@ The bake must run on the SAME device topology and jax/jaxlib build the
 fleet boots with — both are part of every cache key, so a mismatched
 bake is simply never loaded (a miss, not a wrong executable).
 
-Prints one JSON line per subcommand (driver-parseable, same convention
-as ``bench.py``).
+Prints one JSON line per subcommand (driver-parseable).
 """
 from __future__ import annotations
 
@@ -105,24 +100,6 @@ def _bake_train_step(net, nIn, nOut, batches, meshData, stats) -> None:
         stats["mesh_data"] = int(meshData)
 
 
-def _bake_lm_ladder(dims, genBatches, seqs, stats) -> None:
-    from deeplearning4j_tpu.nlp.transformer import TransformerLM
-    from deeplearning4j_tpu.remote import BucketLadder, GenerativeServing
-    vocab, nLayers, nHeads, headSize, maxLen = dims
-    lm = TransformerLM(vocabSize=vocab, nLayers=nLayers, nHeads=nHeads,
-                       headSize=headSize, maxLen=maxLen, seed=0)
-    from deeplearning4j_tpu.compile.aotcache import wrap_serving_model
-    wrap_serving_model(lm)
-    serving = GenerativeServing(lm, BucketLadder(batchSizes=genBatches,
-                                                 seqLens=seqs))
-    t0 = time.perf_counter()
-    for key in serving.warmKeys():
-        serving.warm(key)
-    stats["lm_ladder_seconds"] = round(time.perf_counter() - t0, 3)
-    stats["lm_buckets"] = {"batches": list(genBatches),
-                           "seqs": list(seqs)}
-
-
 def cmd_bake(args) -> dict:
     from deeplearning4j_tpu.compile.aotcache import (aot_cache,
                                                      set_aot_cache)
@@ -143,13 +120,6 @@ def cmd_bake(args) -> dict:
         if args.train:
             _bake_train_step(net, dims[0], dims[2], batches,
                              args.mesh_data, stats)
-    if args.lm:
-        dims = _ints(args.lm)
-        if len(dims) != 5:
-            raise SystemExit(
-                "aotc: --lm wants vocab,layers,heads,headSize,maxLen")
-        _bake_lm_ladder(dims, _ints(args.gen_batches), _ints(args.seqs),
-                        stats)
     reg = get_registry()
     h = reg.get("dl4j_tpu_aot_cache_hits_total")
     stats["entries_baked"] = len(cache.entries()) - before
@@ -200,13 +170,6 @@ def main(argv=None) -> int:
                       help="also bake the fused train step per batch")
     bake.add_argument("--mesh-data", type=int, default=0,
                       help="bake the train step on a data=N mesh")
-    bake.add_argument("--lm", help="vocab,layers,heads,headSize,maxLen "
-                                   "TransformerLM")
-    bake.add_argument("--gen-batches", default="1,2,4",
-                      help="batch buckets for the generative ladder")
-    bake.add_argument("--seqs", default="16,32,64",
-                      help="prompt-length buckets for the generative "
-                           "ladder")
 
     ls = sub.add_parser("ls", help="list cache entries")
     ls.add_argument("--cache-dir", required=True)

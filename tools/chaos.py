@@ -13,7 +13,7 @@ Usage::
     python tools/chaos.py --seed 7 --schedule-only  # just the schedule
     python tools/chaos.py --seed 7 --events 6 --epochs 3 --dir /tmp/run
 
-Output is ONE JSON line (the bench.py convention) with the schedule,
+Output is ONE JSON line with the schedule,
 the events that actually fired, the final mesh generation, the
 leader-failover count, and the per-invariant verdicts; exit code 0 iff
 every invariant held.

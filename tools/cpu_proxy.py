@@ -1,7 +1,7 @@
 """The CPU-proxy child: N virtual XLA host devices and no way to the chip.
 
-``bench.py --mesh/--recsys``, ``tools/chaos.py`` and
-``__graft_entry__.dryrun_multichip`` need N virtual host devices, which
+``tools/chaos.py`` and ``__graft_entry__.dryrun_multichip`` need N
+virtual host devices, which
 must be configured before jax initializes, so each re-executes itself in a
 child process.  A chip belongs to one process at a time and the parent may
 be holding it: the child's environment therefore names the CPU outright

@@ -22,8 +22,8 @@ hit that cache — and one line of Python can quietly defeat it:
   ``static_argnums``/``static_argnames``: a str argument fails tracing
   outright, and a bool flag either concretization-errors or doubles the
   executable count invisibly.  Declare the config args static (see
-  ``nlp/transformer.py`` ``static_argnames=("padded",)`` for the
-  compliant idiom).
+  ``nlp/sambay.py`` ``static_argnames=("kind",)`` for the compliant
+  idiom).
 """
 from __future__ import annotations
 
