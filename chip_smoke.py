@@ -433,6 +433,13 @@ def _check_lm_dtypes(r: Report, lm, pool, slots: int) -> None:
         n = _f64_count(low)
         r.values[f"f64_in_{name}"] = n
         r.check(f"no_f64_in_{name}", n == 0, n)
+    # on one TPU the step reads its pages through the kernel (one Mosaic
+    # function, called by every layer); anywhere else it gathers
+    calls = _mosaic_calls(lowered["decode"])
+    r.values["paged_kernel_in_decode"] = calls
+    onTpu = jax.devices()[0].platform == "tpu"
+    r.check("decode_reads_pages_through_the_kernel",
+            calls == (1 if onTpu else 0), calls)
 
 
 def _check_paged_parity(r: Report, lm, pageSize: int, promptLen: int,

@@ -20,6 +20,7 @@ Public surface:
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -45,6 +46,21 @@ def _compile() -> Optional[Path]:
     if not srcs:
         return None
     _BUILD_DIR.mkdir(parents=True, exist_ok=True)
+    # one builder at a time ACROSS processes: on a fresh checkout every
+    # xdist worker gets here together, and two cmake runs in one directory
+    # (or a g++ writing the library another process is loading) fail one
+    # of them.  The lock is the build directory's; closing it releases it.
+    fd = os.open(_BUILD_DIR, os.O_RDONLY)
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        if out.exists() and not _stale(out):
+            return out                  # built while this process waited
+        return _compile_locked(out, srcs)
+    finally:
+        os.close(fd)
+
+
+def _compile_locked(out: Path, srcs) -> Optional[Path]:
     try:
         subprocess.run(
             ["cmake", "-G", "Ninja", "-S", str(_NATIVE_DIR), "-B", str(_BUILD_DIR)],

@@ -14,8 +14,10 @@ executable shapes STATIC:
   for the scheduler to copy into pool pages;
 - :meth:`buildPagedDecodeFn` builds the step that feeds ONE token per
   slot against the pool (:func:`~deeplearning4j_tpu.nn.conf.attention.
-  paged_attention`) — fixed (slots, capacity) shapes, so the batcher
-  warms one executable and never re-traces in steady state;
+  paged_attention`: lowered for one TPU it reads each slot's live pages
+  where they lie, anywhere else it gathers the slot's capacity under a
+  mask) — fixed (slots, page-table width) shapes, so the batcher warms
+  one executable and never re-traces in steady state;
 - :meth:`forward` is the plain causal forward, and :meth:`generate` the
   greedy recompute over it: the reference the served path is held to.
 

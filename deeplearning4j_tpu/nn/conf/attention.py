@@ -14,10 +14,16 @@ dispatch.  Data format follows the DL4J RNN convention (b, nIn, t); masks are
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend import core as jex_core
+from jax.interpreters import mlir
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer, register_layer
@@ -25,7 +31,8 @@ from deeplearning4j_tpu.nn.weights import init_weight
 
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
-           "paged_attention", "paged_prefill_write", "paged_step_tokens",
+           "paged_attention", "paged_kernel_lowerings",
+           "paged_prefill_write", "paged_step_tokens",
            "CacheSpec", "served_jit_entries", "drop_served_jits"]
 
 
@@ -81,12 +88,20 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
       (a left-padded prompt's pad rows lie before it).
 
     Writes the new K/V rows into their pages (``tq`` may span a page
-    boundary — each token's page/offset is computed independently),
-    gathers every slot's pages back in logical order and attends over
-    the slot's whole capacity under a validity mask: query ``i`` of slot
-    ``s`` sees key index ``j`` iff ``start[s] <= j <= pos[s] + i`` —
-    causal, and blind to the pad, the unwritten tail and the scratch
+    boundary — each token's page/offset is computed independently) and
+    attends over the slot's rows under a validity mask: query ``i`` of
+    slot ``s`` sees key index ``j`` iff ``start[s] <= j <= pos[s] + i``
+    — causal, and blind to the pad, the unwritten tail and the scratch
     page.  Returns ``(ctx, newPoolK, newPoolV)``.
+
+    How the rows are read is decided where the program is lowered, from
+    what it is lowered for: for ONE TPU, a kernel reads the slot's live
+    pages where they lie (:func:`_attend_pages`); anywhere else (the
+    CPU, a pool split over several devices) every slot's whole capacity
+    is gathered and attended under the mask (:func:`_attend_gathered`,
+    the reference formulation).  Both give a result that depends on a
+    slot's logical content alone: not on which physical pages hold it,
+    nor on the other slots.
     """
     S, h, tq, d = qh.shape
     pageSize = poolK.shape[2]
@@ -99,7 +114,21 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
             pool.dtype)
     poolK = poolK.at[li, phys, off].set(rows(kh_new, poolK))
     poolV = poolV.at[li, phys, off].set(rows(vh_new, poolV))
-    cap = pageTable.shape[1] * pageSize
+    ctx = _attend_p.bind(qh, poolK, poolV, pageTable, pos, start, li=li)
+    return ctx, poolK, poolV
+
+
+_NEG = -1e30              # a masked score
+
+
+def _attend_gathered(qh, poolK, poolV, pageTable, pos, start, *, li):
+    """The reference formulation of :func:`paged_attention`'s read:
+    gather every slot's pages in logical order ((S, capacity, h*d)),
+    split the rows into heads, and run scores, softmax and context over
+    the whole capacity under the validity mask."""
+    S, h, tq, d = qh.shape
+    cap = pageTable.shape[1] * poolK.shape[2]
+    wpos = pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
     k = poolK[li, pageTable].reshape(S, cap, h, d)
     v = poolV[li, pageTable].reshape(S, cap, h, d)
     kpos = jnp.arange(cap, dtype=jnp.int32)
@@ -107,10 +136,249 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
         (kpos[None, None, :] >= start[:, None, None])        # (S, tq, cap)
     s = jnp.einsum("bhqd,bkhd->bhqk", qh, k.astype(qh.dtype))
     s = s * (1.0 / jnp.sqrt(jnp.asarray(d, s.dtype)))
-    s = jnp.where(valid[:, None], s, jnp.asarray(-1e30, s.dtype))
+    s = jnp.where(valid[:, None], s, jnp.asarray(_NEG, s.dtype))
     w = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhqk,bkhd->bhqd", w, v.astype(qh.dtype))
-    return ctx, poolK, poolV
+    return jnp.einsum("bhqk,bkhd->bhqd", w, v.astype(qh.dtype))
+
+
+# -- the kernel: attention over the live pages, where they lie ---------
+
+#: rows of K (and of V) a place of the kernel's grid works on.  At
+#: gpt2_xl's sizes and the cells' lengths 64 and 128 cost a layer the same
+#: (26.5 us), 32 and 256 a fifth more (my chip run, PR 29): a matmul
+#: against the 0/1 matrix takes as long for 64 rows as for 128
+_CHUNK_ROWS = 128
+
+
+@functools.partial(jax.jit, static_argnames=("tq", "pageSize", "C"))
+def _work_list(pageTable, pos, start, *, tq, pageSize, C):
+    """The chunks of LIVE pages of one step, slot after slot, for the
+    places of the kernel's grid: ``ceil((pos + tq) / pageSize)`` pages
+    hold a slot's rows, less those that lie wholly in its left pad.  For
+    each place: the ``C`` physical pages its buffers hold, its slot, the
+    position of its first row, and whether it opens (1) / closes (2) a
+    slot's pass.  ``total`` places are live; the arrays have room for
+    every slot at full capacity, and the places past ``total`` repeat
+    the last live one.  A few small integer ops, the same for every
+    layer of a step: a jit of its own, so a step traces them once and XLA
+    computes them once."""
+    i32 = jnp.int32
+    S, P = pageTable.shape
+    W = S * -(-P // C)
+    n = jnp.minimum((pos + (tq + pageSize - 1)) // pageSize, P).astype(i32)
+    p0 = jnp.minimum(start // pageSize, n - 1).astype(i32)   # (S,)
+    nch = (n - p0 + (C - 1)) // C
+    ends = jnp.cumsum(nch).astype(i32)
+    total = ends[-1]
+    w = jnp.arange(W, dtype=i32)
+    wl = jnp.minimum(w, total - 1)
+    slot = jnp.sum(wl[:, None] >= ends[None, :], axis=1).astype(i32)
+    chunk = wl - (ends - nch)[slot]
+    page = (p0[slot] + chunk * C)[:, None] + jnp.arange(C, dtype=i32)
+    phys = jnp.take_along_axis(pageTable[slot], jnp.minimum(page, P - 1),
+                               axis=1)                       # (W, C)
+    # a buffer whose page is not live keeps the page it held the place
+    # before (an unchanged index is not copied again); page 0 before any
+    at = jax.lax.cummax(jnp.where(page < n[slot][:, None], w[:, None], -1),
+                        axis=0)
+    phys = jnp.where(at >= 0, jnp.take_along_axis(
+        phys, jnp.maximum(at, 0), axis=0), 0)
+    flag = (chunk == 0) * 1 + (chunk == nch[slot] - 1) * 2
+    return (phys.reshape(-1).astype(i32), slot, (page[:, 0] * pageSize)
+            .astype(i32), flag.astype(i32), total)
+
+
+def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
+                  start_ref, q_ref, *refs, C, ps, tq, d):
+    """One place of the grid: ``C`` pages of K and of V of one slot
+    (``k_refs``/``v_refs``, each ``(ps, h*d)``, copied in by the
+    pipeline while the place before computes), all ``tq`` queries of that
+    slot.  Heads are never split: ``K * q`` over the merged lanes, the
+    per-head sum as a matmul with the 0/1 matrix ``E`` ((h*d, heads)),
+    an online softmax per head across the slot's chunks, the weights
+    spread back over the lanes by ``E``'s transpose."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    k_refs, v_refs = refs[:C], refs[C:2 * C]
+    o_ref, e_ref, et_ref, m_ref, l_ref, acc_ref = refs[2 * C:]
+    w = pl.program_id(0)
+    R = C * ps
+    hd, hp = e_ref.shape
+
+    def dot3(x, m_ref_):
+        """``x @ m`` for a float32 ``x`` and a 0/1 ``m``: three bfloat16
+        passes over ``x`` split into its high, middle and low bits, summed
+        in float32 — every bit of ``x`` takes part, where one pass would
+        round it to eight."""
+        m_, parts = m_ref_[...], []
+        for _ in range(3):
+            hi = x.astype(bf16)
+            x = x - hi.astype(f32)
+            parts.append(jnp.dot(hi, m_, preferred_element_type=f32))
+        return parts[0] + parts[1] + parts[2]
+
+    @pl.when(w == 0)
+    def _():
+        for ref, lanes, heads in ((e_ref, 0, 1), (et_ref, 1, 0)):
+            lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape, lanes)
+            head = jax.lax.broadcasted_iota(jnp.int32, ref.shape, heads)
+            ref[...] = ((lane >= head * d) & (lane < head * d + d)).astype(
+                ref.dtype)
+
+    flag = flag_ref[w]
+
+    @pl.when((flag & 1) != 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    s = slot_ref[w]
+    pos, start = pos_ref[s], start_ref[s]
+    j = j0_ref[w] + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+    for i in range(tq):
+        qi = q_ref[pl.ds(i, 1), :]
+        valid = (j >= start) & (j <= pos + i)
+        prod = jnp.concatenate(
+            [k_refs[c][...].astype(f32) * qi for c in range(C)], axis=0)
+        sc = jnp.where(valid, dot3(prod, e_ref), f32(_NEG))  # (R, hp)
+        mOld = m_ref[i]                             # (8, hp), rows alike
+        mNew = jnp.maximum(mOld, jnp.max(sc, axis=0, keepdims=True))
+        p = jnp.where(valid, jnp.exp(sc - mNew[0:1]), f32(0))
+        # the chunk's weights and, in 8 rows more, what the sums so far
+        # are scaled by, spread over the lanes in one matmul
+        wa = dot3(jnp.concatenate([p, jnp.exp(mOld - mNew)], axis=0),
+                  et_ref)                                    # (R + 8, hd)
+        wcs = [wa[c * ps:(c + 1) * ps] for c in range(C)]
+        ws = functools.reduce(operator.add, wcs)
+        wv = functools.reduce(operator.add, (
+            wc * v_refs[c][...].astype(f32) for c, wc in enumerate(wcs)))
+        a = wa[R:]
+        acc_ref[i] = a * acc_ref[i] + jnp.sum(wv, axis=0, keepdims=True)
+        l_ref[i] = a * l_ref[i] + jnp.sum(ws, axis=0, keepdims=True)
+        m_ref[i] = mNew
+
+    @pl.when((flag & 2) != 0)
+    def _():
+        for i in range(tq):
+            o_ref[pl.ds(i, 1), :] = (acc_ref[i] / l_ref[i])[0:1].astype(
+                o_ref.dtype)
+
+
+def _attend_pages(qh, poolK, poolV, pageTable, pos, start, *, li,
+                  interpret=False):
+    """:func:`paged_attention`'s read as a Pallas TPU kernel, one call a
+    layer: K and V are read from the pool's pages WHERE THEY LIE —
+    token-major rows of ``heads*headSize`` lanes, all heads side by side
+    — and only the pages that hold live rows of a slot.  Nothing is
+    gathered into a capacity-wide copy, no row is re-laid into heads,
+    no score is taken over a dead position.  ``interpret`` is for tests
+    (the CPU)."""
+    S, h, tq, d = qh.shape
+    ps, hd = poolK.shape[2:]
+    i32 = jnp.int32
+    q = (qh * jnp.asarray(d ** -0.5, qh.dtype)).transpose(
+        0, 2, 1, 3).reshape(S, tq, hd).astype(jnp.float32)
+    pos, start = pos.astype(i32), start.astype(i32)
+    work = _work_list(pageTable.astype(i32), pos, start, tq=tq, pageSize=ps,
+                      C=max(1, min(_CHUNK_ROWS // ps, pageTable.shape[1])))
+    out = _pages_call(jnp.full((1,), li, i32), *work, pos, start, q, poolK,
+                      poolV, headSize=d, interpret=interpret)
+    return out.reshape(S, tq, h, d).transpose(0, 2, 1, 3).astype(qh.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("headSize", "interpret"))
+def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
+                *, headSize, interpret):
+    """The kernel's call.  The stacked pool goes in whole (the layer is
+    an index, so no layer is sliced out), once for each of a chunk's
+    ``2 C`` page buffers: each buffer's block is one ``(pageSize, h*d)``
+    page, named for every place of the grid by the scalar-prefetched
+    work list, and copied in by the kernel's pipeline while the place
+    before computes (a page of 1,600 lanes is no multiple of the 128-lane
+    tile: the pipeline copies it whole, a hand-written copy of a slice
+    of such an array does not compile).  The grid has as many places as
+    the step has chunks of live pages.  A jit of its own with the layer
+    as an argument: every layer of a step is then the same computation,
+    traced and lowered to Mosaic once a program and not once a layer."""
+    S, tq, hd = q.shape
+    ps = poolK.shape[2]
+    C = tbl.shape[0] // slot.shape[0]
+    d = headSize
+    hp = -(-(hd // d) // 128) * 128
+    f32 = jnp.float32
+
+    # index maps: ``w * 0`` and not ``0`` (the package enables x64, and a
+    # bare literal would be an int64 Mosaic has not)
+    def page_spec(c):
+        return pl.BlockSpec(
+            (None, None, ps, hd),
+            lambda w, li, tbl, *_: (li[0], tbl[w * C + c], w * 0, w * 0))
+    row_spec = pl.BlockSpec(
+        (None, tq, hd), lambda w, li, tbl, slot, *_: (slot[w], w * 0, w * 0))
+    return pl.pallas_call(
+        functools.partial(_pages_kernel, C=C, ps=ps, tq=tq, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(total,),
+            in_specs=[row_spec] + [page_spec(c) for c in range(C)] * 2,
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hd, hp), jnp.bfloat16),          # E
+                pltpu.VMEM((hp, hd), jnp.bfloat16),          # its transpose
+                pltpu.VMEM((tq, 8, hp), f32),                # running max
+                pltpu.VMEM((tq, 8, hd), f32),                # running sum
+                pltpu.VMEM((tq, 8, hd), f32),                # context
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, tq, hd), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        name="paged_attention",
+        interpret=interpret,
+    )(li, tbl, slot, j0, flag, pos, start, q, *([poolK] * C),
+      *([poolV] * C))
+
+
+#: how often the step's read was lowered as the kernel (program
+#: telemetry: the batcher's gauge reads it around its warm-up)
+_kernelLowerings = [0]
+
+
+def paged_kernel_lowerings() -> int:
+    """How many times :func:`paged_attention`'s read has been lowered as
+    the TPU kernel in this process (once a layer of each program built
+    for one TPU; never on the CPU or for a pool split over devices)."""
+    return _kernelLowerings[0]
+
+
+def _attend_lowering(ctx, *args, li):
+    """Choose by what the program is lowered for, not by a knob: one TPU
+    -> the kernel; the CPU, or several devices (a pool whose lanes are
+    split over a mesh, which a Mosaic kernel cannot be partitioned over)
+    -> the gathered reference."""
+    mc = ctx.module_context
+    kernel = tuple(mc.platforms) == ("tpu",) and \
+        getattr(mc.axis_context, "num_devices", None) == 1
+    if kernel:
+        _kernelLowerings[0] += 1
+    return mlir.lower_fun(
+        functools.partial(_attend_pages if kernel else _attend_gathered,
+                          li=li), multiple_results=False)(ctx, *args)
+
+
+_attend_p = jex_core.Primitive("paged_attend")
+
+
+@functools.partial(jax.jit, static_argnames=("li",))
+def _attend_eager(*args, li):
+    """Outside any jit the primitive runs as a program of its own."""
+    return _attend_p.bind(*args, li=li)
+
+
+_attend_p.def_impl(_attend_eager)
+_attend_p.def_abstract_eval(
+    lambda qh, *_, li: jax.core.ShapedArray(qh.shape, qh.dtype))
+mlir.register_lowering(_attend_p, _attend_lowering)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -13,7 +13,8 @@ replica busy:
   sequence is a host-side free-list edit; the decode executable's shapes
   (slots x page-table width x pool) never change, so churn never
   re-traces (``nn/conf/attention.py paged_attention`` is the device-side
-  math).
+  math: on a TPU a kernel that reads each slot's live pages where they
+  lie, elsewhere a gather of every slot's capacity under a mask).
 - :class:`ContinuousBatcher` — the iteration-level scheduler: a fixed
   slot array steps through ONE shared decode executable; finished
   sequences retire and queued ones admit BETWEEN steps (strict-FIFO
@@ -61,7 +62,8 @@ import numpy as np
 # injection registries only — fault/chaos.py imports THIS module lazily,
 # so the package-level import here cannot cycle
 from deeplearning4j_tpu.fault import injection as _inj
-from deeplearning4j_tpu.nn.conf.attention import CacheSpec
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  paged_kernel_lowerings)
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
                                                DeadlineExceeded,
@@ -101,8 +103,10 @@ class KVCachePool:
       Pages are token-major (a row is one position, all heads side by
       side): the two minor dimensions are what the TPU tiles without a
       re-layout, so the decode step and the prefill write update them in
-      place (``paged_attention``).  Only the layers that own pages have
-      any: 48 of 48 for GPT-2 XL, one of 32 for a SambaY stack.
+      place and the step's kernel reads a page as it lies, all heads of
+      a row at once (``paged_attention``).  Only the layers that own
+      pages have any: 48 of 48 for GPT-2 XL, one of 32 for a SambaY
+      stack.
     - *ring*: ``ringK``/``ringV`` ``(ringLayers, maxSlots, ringRows,
       kvHeads*headSize)``, a slot's last ``ringRows`` positions written
       modulo ``ringRows``.
@@ -617,8 +621,11 @@ class ContinuousBatcher:
         tok0 = jnp.zeros((S, 1), jnp.int32)
         pt = jnp.asarray(self.pool.pageTable)
         step = self._stepFns["step"]
+        lowered = paged_kernel_lowerings()
         prev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
+        sm.paged_attention_kernel().set(
+            int(paged_kernel_lowerings() > lowered), model=self.name)
         # and with a step's own output for ``prev``, as every later call
         # has it: beside committed params that is another entry of the
         # jit's cache than fresh zeros.  It stands in wherever no step is

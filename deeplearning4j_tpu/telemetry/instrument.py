@@ -415,6 +415,17 @@ class ServingMetrics:
             "per model and pool (target / draft)",
             labelnames=("model", "pool"))
 
+    def paged_attention_kernel(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_paged_attention_kernel",
+            "1 when the batcher's decode step was built with the kernel "
+            "that reads the live KV pages where they lie (lowered for one "
+            "TPU), 0 when it gathers every slot's whole capacity (the "
+            "CPU, a pool split over devices, a model with its own step); "
+            "the share of capacity a kernel step reads is "
+            "kv_pages_in_use / (maxSlots x maxPagesPerSeq)",
+            labelnames=("model",))
+
     def kv_pages_free(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_kv_pages_free",

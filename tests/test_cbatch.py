@@ -100,6 +100,135 @@ def test_paged_attention_matches_masked_softmax(tq, layers, pos, start):
         np.testing.assert_array_equal(np.asarray(out), want)
 
 
+def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2):
+    """Random pools and queries for ``len(pos)`` slots of ``P`` pages of
+    ``ps`` rows: ``(qh, poolK, poolV, table, pos, start)`` as
+    ``paged_attention``'s two formulations take them.  ``table`` defaults
+    to each slot's pages in a scrambled physical order (page 0 is the
+    scratch page and belongs to no slot)."""
+    import jax.numpy as jnp
+    S = len(pos)
+    numPages = 1 + S * P
+    if table is None:
+        table = 1 + rng.permutation(S * P).reshape(S, P)
+    return (jnp.asarray(rng.randn(S, h, tq, d), jnp.float32),
+            jnp.asarray(rng.randn(layers, numPages, ps, h * d), jnp.float32),
+            jnp.asarray(rng.randn(layers, numPages, ps, h * d), jnp.float32),
+            jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(start, jnp.int32))
+
+
+# the kernel works in chunks of 128 rows: with pages of 16 rows a slot of
+# 20 pages takes up to three chunks, so the running maximum, sum and
+# context are carried and rescaled across them
+@pytest.mark.parametrize("case", [
+    # the cases of test_paged_attention_matches_masked_softmax
+    dict(id="decode", h=2, d=4, ps=4, P=3, tq=1, pos=[7, 7], start=[2, 2]),
+    dict(id="verify_crossing_a_page", h=2, d=4, ps=4, P=3, tq=3,
+         pos=[6, 6], start=[1, 1]),
+    # gpt2_xl's row: 25 heads of 64 = 1,600 lanes, no multiple of 128
+    dict(id="row_of_1600_lanes", h=25, d=64, ps=16, P=10, tq=1,
+         pos=[150, 37], start=[3, 0]),
+    # the new row is the first of a fresh page / four rows straddle one
+    dict(id="tq1_opens_a_page", h=3, d=8, ps=16, P=20, tq=1,
+         pos=[160, 16, 304], start=[0, 0, 40]),
+    dict(id="tq4_crossing_a_page", h=3, d=8, ps=16, P=20, tq=4,
+         pos=[158, 14, 301], start=[0, 5, 130]),
+    dict(id="pages_in_physical_order", h=3, d=8, ps=16, P=20, tq=1,
+         pos=[200, 90], start=[0, 0], ordered=True),
+    # a left pad longer than a chunk: its pages are never read
+    dict(id="start_past_a_chunk", h=3, d=8, ps=16, P=20, tq=2,
+         pos=[300, 250], start=[135, 249]),
+    # slot 1 is inactive: every entry of its table is the scratch page
+    dict(id="inactive_slot_on_the_scratch_page", h=3, d=8, ps=16, P=20,
+         tq=1, pos=[77, 0, 210], start=[0, 0, 9], parked=[1]),
+    dict(id="one_live_page", h=3, d=8, ps=16, P=20, tq=1,
+         pos=[15, 3], start=[0, 2]),
+    dict(id="the_whole_capacity", h=3, d=8, ps=16, P=20, tq=1,
+         pos=[319, 318], start=[0, 100]),
+    dict(id="verify_to_the_last_row", h=3, d=8, ps=16, P=20, tq=4,
+         pos=[316, 40], start=[0, 0]),
+], ids=lambda c: c["id"])
+def test_paged_kernel_matches_the_gathered_reference(case):
+    """The TPU kernel (Pallas interpret mode, here on the CPU) against
+    the reference formulation it stands in for: the same context for
+    every slot, query and head, from the pages where they lie."""
+    from deeplearning4j_tpu.nn.conf import attention as A
+    rng = np.random.RandomState(len(case["id"]))
+    S, P = len(case["pos"]), case["P"]
+    table = None
+    if case.get("ordered"):
+        table = 1 + np.arange(S * P).reshape(S, P)
+    args = _paged_case(rng, case["h"], case["d"], case["ps"], P, case["tq"],
+                       case["pos"], case["start"], table)
+    for s in case.get("parked", ()):
+        args = args[:3] + (args[3].at[s].set(0),) + args[4:]
+    want = A._attend_gathered(*args, li=1)
+    got = A._attend_pages(*args, li=1, interpret=True)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("how", ["slots_swapped", "pages_moved",
+                                 "neighbour_changed"])
+def test_paged_kernel_depends_on_a_slots_logical_content_alone(how):
+    """Bit for bit: two slots swapped give swapped results; the same rows
+    held by other physical pages give the same result; and what another
+    slot holds (its length, its rows) changes nothing — what preemption's
+    replay and speculative decoding's token identity rest on."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf import attention as A
+    rng = np.random.RandomState(11)
+    h, d, ps, P = 3, 8, 16, 20
+    qh, pk, pv, table, pos, start = _paged_case(
+        rng, h, d, ps, P, 2, [170, 45], [20, 0])
+    base = np.asarray(A._attend_pages(qh, pk, pv, table, pos, start, li=0,
+                                      interpret=True))
+    if how == "slots_swapped":
+        swap = jnp.asarray([1, 0])
+        got = np.asarray(A._attend_pages(
+            qh[swap], pk, pv, table[swap], pos[swap], start[swap], li=0,
+            interpret=True))
+        np.testing.assert_array_equal(got, base[::-1])
+    elif how == "pages_moved":
+        perm = np.concatenate([[0], 1 + rng.permutation(2 * P)])
+        inv = np.argsort(perm)          # page p's rows move to inv[p]
+        got = np.asarray(A._attend_pages(
+            qh, pk[:, perm], pv[:, perm], jnp.asarray(inv)[table], pos,
+            start, li=0, interpret=True))
+        np.testing.assert_array_equal(got, base)
+    else:
+        pages1 = np.asarray(table[1])
+        got = np.asarray(A._attend_pages(
+            qh.at[1].set(0.5), pk.at[0, pages1].set(1.0), pv, table,
+            pos.at[1].set(300), start.at[1].set(64), li=0, interpret=True))
+        np.testing.assert_array_equal(got[0], base[0])
+
+
+def test_paged_attention_lowers_the_reference_off_the_tpu():
+    """``paged_attention`` chooses where it is lowered, from what it is
+    lowered for: on the CPU that is the gathered reference (no kernel is
+    counted), and the batcher's gauge says so."""
+    import jax
+    from deeplearning4j_tpu.nn.conf import attention as A
+    args = _paged_case(np.random.RandomState(0), 2, 4, 4, 3, 1, [5, 2],
+                       [0, 1])
+    before = A.paged_kernel_lowerings()
+    text = jax.jit(lambda *a: A._attend_p.bind(*a, li=0)).lower(
+        *args).as_text()
+    assert "custom_call" not in text
+    assert A.paged_kernel_lowerings() == before
+    cb = ContinuousBatcher(_lm(), name="gauge-lm", maxSlots=2, pageSize=4)
+    try:
+        cb.warm()
+    finally:
+        cb.shutdown()
+    assert get_registry().get(
+        "dl4j_tpu_serving_paged_attention_kernel").value(
+            model="gauge-lm") == 0
+
+
 def test_prefill_write_then_paged_step_equals_forward_logits():
     """The prefill write puts position ``t`` of layer ``l`` (all heads side
     by side) at ``[l, pageIds[t // ps], t % ps]``; and, end to end, a

@@ -27,14 +27,18 @@ PER_SEQ = MAX_LEN // PAGE_SIZE
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -112,12 +116,67 @@ def _assert_one_step_program(compiled, cache):
 
 
 def test_paged_decode_step_updates_the_pool_in_place(paged):
+    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
     lm, params, pool, i32 = paged
+    before = paged_kernel_lowerings()
     compiled = lm.buildPagedDecodeFn().lower(
         params, pool, pool, i32(SLOTS, 1), i32(SLOTS, 1),
         i32(SLOTS, PER_SEQ), i32(SLOTS), i32(SLOTS)).compile()
     _assert_in_place(compiled, pool, "jit_step")
     _assert_one_step_program(compiled, [pool, pool])
+    # every layer attends through the kernel that reads the live pages
+    # where they lie: lowered for one TPU, so chosen with no knob, under
+    # JAX_PLATFORMS=cpu
+    text = compiled.as_text()
+    assert paged_kernel_lowerings() - before == LAYERS
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == LAYERS
+    # so no slot's capacity is gathered (K or V of all four slots, 256
+    # pages of 16 rows) and no gathered row is re-laid into heads
+    # (f32[256,16,1600], f32[4,1024,25,64])
+    gathered = f"f32[{SLOTS * PER_SEQ},{PAGE_SIZE},{HEADS * HEAD_SIZE}]"
+    split = f"f32[{SLOTS},{MAX_LEN},{HEADS},{HEAD_SIZE}]"
+    assert gathered not in text and split not in text
+    # found: 407,141,376 bytes (403,424,256 with the gather: the
+    # temporaries are the tied embedding table re-laid for the logits,
+    # 321 MB, not attention's); one pool is 634 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_paged_attention_is_the_kernel_for_one_tpu_only(topo, one_chip,
+                                                        chips):
+    """The choice is made where the program is lowered: for one described
+    TPU the kernel; for four with the pool's lanes split over them (the
+    tensor-parallel replica's placement, which a Mosaic kernel cannot be
+    partitioned over) the gathered reference — no option says which."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_attention, paged_kernel_lowerings)
+    S, h, d, perSeq = 2, 4, 32, 4
+    if chips == 1:
+        rows = lanes = one_chip
+    else:
+        mesh = Mesh(np.array(topo.devices), ("model",))
+        rows = NamedSharding(mesh, P())
+        lanes = NamedSharding(mesh, P(None, None, None, "model"))
+
+    def sds(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    new = sds((S, h, 1, d), jnp.float32, rows)
+    pool = sds((2, 1 + S * perSeq, PAGE_SIZE, h * d), jnp.float32, lanes)
+    before = paged_kernel_lowerings()
+    text = jax.jit(
+        lambda q, k, v, pk, pv, pt, pos, start: paged_attention(
+            q, k, v, pk, pv, 1, pt, pos, start)).lower(
+        new, new, new, pool, pool, sds((S, perSeq), jnp.int32, rows),
+        sds((S,), jnp.int32, rows), sds((S,), jnp.int32, rows)).as_text()
+    kernels = text.count("tpu_custom_call")
+    assert kernels == paged_kernel_lowerings() - before
+    assert kernels == (1 if chips == 1 else 0)
 
 
 @pytest.mark.parametrize("bucket", [16, 256])
