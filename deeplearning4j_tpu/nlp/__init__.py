@@ -8,6 +8,8 @@ from deeplearning4j_tpu.nlp.tokenization import (BertWordPieceTokenizer,  # noqa
                                                  DefaultTokenizer,
                                                  DefaultTokenizerFactory)
 from deeplearning4j_tpu.nlp.bert_iterator import BertIterator  # noqa: F401
+from deeplearning4j_tpu.nlp.olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig, OlmoHybridLM)
 from deeplearning4j_tpu.nlp.sambay import SambaYConfig, SambaYLM  # noqa: F401
 from deeplearning4j_tpu.nlp.transformer import (  # noqa: F401
     TransformerLM, TransformerLMConfig)

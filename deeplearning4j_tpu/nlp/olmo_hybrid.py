@@ -1,0 +1,573 @@
+"""Olmo-Hybrid LM for the serving tier: three Gated-DeltaNet
+linear-attention layers (arXiv:2412.06464) to every full-attention layer,
+in the Olmo 2/3 block with its reordered norm (arXiv:2501.00656):
+``y = x + RMSNorm(mixer(x))``, ``out = y + RMSNorm(FFN(y))``, q/k
+normalised over the whole width, no positional encoding (the
+convolutions and the decay carry order), a final RMSNorm and an untied
+head.  Layer ``i`` is full attention where ``i % 4 == 3``.
+
+What the model keeps between decode steps is TWO kinds of state, named by
+:meth:`OlmoHybridLM.cacheSpec` and held side by side by the scheduler's
+``KVCachePool``:
+
+- *paged* — every full layer's K (after its norm) and V rows, one per
+  position, in pages that grow with the sequence; read through
+  :func:`~deeplearning4j_tpu.nn.conf.attention.paged_attention`, so on
+  one TPU by the kernel that reads the live pages where they lie;
+- *recurrent* — every linear layer's float32 state, a ``(dk, dv)``
+  MATRIX a head updated by a rank-one delta rule, and the last ``K - 1``
+  inputs of its three depthwise convolutions; overwritten every step, in
+  place.
+
+The delta rule has two forms here.  The decode step runs the recurrence
+itself, one token a slot: ``S' = a S; u = b (v - S'^T k); S = S' + k
+u^T; o = S^T q``.  Forward and prefill run its CHUNKED form
+(:func:`delta_rule_chunked`): within a chunk of ``C`` positions the
+rank-one updates are folded into matmuls by the UT transform, and only
+the ``(H, dk, dv)`` state is carried from chunk to chunk, so a prompt of
+4,096 positions is 64 steps of a scan and not 4,096.
+
+Precision: weights, residual stream and K/V in the parameters' dtype
+(bfloat16 as served); the delta state, ``alpha``/``beta``, the q/k
+normalisations, the chunk's transform, softmax, norms and logits in
+float32; every matmul accumulates in float32.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  drop_served_jits,
+                                                  paged_attention,
+                                                  paged_prefill_write,
+                                                  paged_step_tokens,
+                                                  served_jit_entries)
+from deeplearning4j_tpu.nlp.sambay import _mm
+
+__all__ = ["OlmoHybridConfig", "OlmoHybridLM", "delta_rule_chunked"]
+
+_F32 = jnp.float32
+_I32 = jnp.int32
+_NEG = -1e30
+_HI = jax.lax.Precision.HIGHEST
+#: queries a block of the full-sequence attention holds against every key
+_QUERY_BLOCK = 512
+
+
+@dataclasses.dataclass
+class OlmoHybridConfig:
+    vocabSize: int = 256
+    nLayers: int = 8
+    hiddenSize: int = 64
+    nHeads: int = 4             # full-attention heads of hiddenSize / nHeads
+    ffnSize: int = 128
+    linHeads: int = 4           # key heads = value heads of the linear layers
+    linKeyDim: int = 8          # dk
+    linValueDim: int = 16       # dv
+    convKernel: int = 4         # K
+    fullEvery: int = 4          # layer i is full where i % fullEvery is last
+    chunk: int = 64             # C of the chunked delta rule, a power of two
+    eps: float = 1e-6
+    maxLen: int = 128           # positions a slot may hold (bucket + new)
+    initializerRange: float = 0.02
+    seed: int = 0
+    dtype: str = "bfloat16"
+
+    @property
+    def headSize(self) -> int:
+        return self.hiddenSize // self.nHeads
+
+    @property
+    def convWidth(self) -> int:
+        """Channels of the three convolutions side by side: q, k, v."""
+        return self.linHeads * (2 * self.linKeyDim + self.linValueDim)
+
+    def layerKinds(self) -> List[str]:
+        return ["full" if i % self.fullEvery == self.fullEvery - 1
+                else "linear" for i in range(self.nLayers)]
+
+
+def _rms(x, g, eps):
+    x = x.astype(_F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * g.astype(_F32)
+
+
+def _l2(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _inv_unit_lower(A):
+    """``(I + A)^-1`` for strictly lower-triangular ``A (..., C, C)``,
+    ``C`` a power of two, by doubling: with ``X`` the inverse of the
+    diagonal blocks of size ``s``, the blocks of size ``2 s`` have the
+    inverse ``[[X1, 0], [-X2 A21 X1, X2]]``, which is ``X - X A_off X``
+    for ``A_off`` the ``A21`` corners alone.  ``log2 C`` rounds of two
+    matmuls, no substitution row by row, and exact up to rounding (no
+    power of ``A`` is ever formed)."""
+    C = A.shape[-1]
+    if C & (C - 1):
+        raise ValueError(f"the chunk {C} is no power of two")
+    i = np.arange(C)
+    X = jnp.broadcast_to(jnp.eye(C, dtype=A.dtype), A.shape)
+    s = 1
+    while s < C:
+        corner = (i[:, None] // (2 * s) == i[None, :] // (2 * s)) \
+            & (i[:, None] % (2 * s) >= s) & (i[None, :] % (2 * s) < s)
+        off = jnp.where(corner, A, 0)
+        X = X - jnp.matmul(jnp.matmul(X, off, precision=_HI), X,
+                           precision=_HI)
+        s *= 2
+    return X
+
+
+def delta_rule_chunked(q, k, v, beta, g, chunk: int):
+    """The gated delta rule over whole sequences, chunk by chunk.
+
+    ``q, k (b, T, H, dk)``, ``v (b, T, H, dv)``, ``beta (b, T, H)`` and
+    ``g = log alpha (b, T, H)``, all float32; the state starts at zero.
+    Returns ``(o (b, T, H, dv), S_T (b, H, dk, dv))`` of the recurrence
+    ``S' = alpha_t S; u_t = beta_t (v_t - S'^T k_t); S = S' + k_t u_t^T;
+    o_t = S^T q_t``.
+
+    Within a chunk, with ``G_i`` the running sum of ``g`` from the
+    chunk's start, ``gamma = exp(G)`` and ``Gam_ij = exp(G_i - G_j)``
+    (taken in log space: no division by a ``gamma`` that has underflowed),
+    the ``u`` of the chunk solve ``(I + A) u = diag(beta) (V - diag(gamma)
+    K S)`` with ``A = strictly-lower(diag(beta) (K K^T * Gam))`` and ``S``
+    the state the chunk starts from.  So with ``T = (I + A)^-1
+    diag(beta)``, ``W = T diag(gamma) K`` and ``U = T V`` (no ``S`` in
+    them: computed for every chunk at once), the scan over chunks is
+    ``u = U - W S; o = diag(gamma) Q S + (Q K^T * Gam * lower) u; S <-
+    gamma_C S + (diag(gamma_C / gamma) K)^T u``.
+
+    A length that is no multiple of the chunk is padded on the right with
+    positions that change nothing (``beta`` 0, ``alpha`` 1, ``k`` 0)."""
+    b, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = int(chunk)
+    pad = -T % C
+    if pad:
+        z = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        q, k, v, beta, g = z(q), z(k), z(v), z(beta), z(g)
+    n = (T + pad) // C
+    # chunk-major, heads before rows: (n, b, H, C, ...)
+    rows = lambda a: a.reshape(b, n, C, H, -1).transpose(1, 0, 3, 2, 4)
+    q, k, v = rows(q), rows(k), rows(v)
+    beta, g = rows(beta)[..., 0], rows(g)[..., 0]            # (n, b, H, C)
+    G = jnp.cumsum(g, axis=-1)
+    i = np.arange(C)
+    lower = i[:, None] >= i[None, :]
+    Gam = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :],
+                            -jnp.inf))                       # 0 above
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    kT = jnp.swapaxes(k, -1, -2)
+    A = jnp.where(i[:, None] > i[None, :],
+                  beta[..., None] * mm(k, kT) * Gam, 0)
+    Tm = _inv_unit_lower(A) * beta[..., None, :]
+    gamma = jnp.exp(G)[..., None]
+    W = mm(Tm, gamma * k)
+    U = mm(Tm, v)
+    M = mm(q, kT) * Gam
+    Qg = gamma * q
+    KhT = jnp.swapaxes(jnp.exp(G[..., -1:] - G)[..., None] * k, -1, -2)
+    gC = jnp.exp(G[..., -1])[..., None, None]                # (n, b, H, 1, 1)
+
+    def body(S, xs):
+        W, U, M, Qg, KhT, gC = xs
+        u = U - mm(W, S)
+        o = mm(Qg, S) + mm(M, u)
+        return gC * S + mm(KhT, u), o
+    S, o = jax.lax.scan(body, jnp.zeros((b, H, dk, dv), _F32),
+                        (W, U, M, Qg, KhT, gC))
+    o = o.transpose(1, 0, 3, 2, 4).reshape(b, n * C, H, dv)
+    return o[:, :T], S
+
+
+class _JitByLength:
+    """``run(params, tokens, start)`` jitted once for each length of
+    ``tokens`` under that length's name (``jit_prefill_2048``), where the
+    other served models keep one jit named ``jit_run`` for every bucket: a
+    prefill of 512 positions and one of 4,096 differ by eight times in work,
+    and a device trace then says which one it holds.  Stands where the one
+    jit stood (called, counted by ``served_jit_entries``); ``at(t)`` is a
+    length's own jit, for ``lower`` and ``trace``."""
+
+    def __init__(self, run, name: str):
+        self._run, self._name, self._jits = run, name, {}
+
+    def at(self, t: int):
+        if t not in self._jits:
+            def run(*args):
+                return self._run(*args)
+            run.__name__ = f"{self._name}_{t}"
+            self._jits[t] = jax.jit(run)
+        return self._jits[t]
+
+    def __call__(self, params, tokens, start):
+        return self.at(tokens.shape[1])(params, tokens, start)
+
+    def _cache_size(self) -> int:
+        return sum(fn._cache_size() for fn in self._jits.values())
+
+
+class OlmoHybridLM:
+    """The served model: ``forward`` (the recompute baseline), a bucketed
+    left-padded ``prefillRaw`` that also returns both kinds of cache
+    state, and the scheduler's fixed-shape decode step and admission
+    write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``, the
+    hooks ``TransformerLM`` and ``SambaYLM`` have)."""
+
+    def __init__(self, config: Optional[OlmoHybridConfig] = None,
+                 params=None, **kw):
+        self.config = config or OlmoHybridConfig(**kw)
+        self.params = params if params is not None else self._init_params()
+
+    # ------------------------------------------------------------------
+    def _init_params(self) -> Dict:
+        """Seeded weights drawn ON THE DEVICE in the configured dtype, one
+        small program per kind of layer."""
+        c = self.config
+        dt = jnp.dtype(c.dtype)
+        d, ff, H, dk, dv = (c.hiddenSize, c.ffnSize, c.linHeads,
+                            c.linKeyDim, c.linValueDim)
+        K, std = c.convKernel, c.initializerRange
+
+        @functools.partial(jax.jit, static_argnames=("kind",))
+        def layer(key, kind):
+            keys = iter(jax.random.split(key, 20))
+            normal = lambda shape: (std * jax.random.normal(
+                next(keys), shape, _F32)).astype(dt)
+            conv = lambda width: jax.random.uniform(
+                next(keys), (K, width), _F32, -K ** -0.5, K ** -0.5
+            ).astype(dt)
+            ones = lambda n: jnp.ones((n,), dt)
+            p = {"norm1": ones(d), "norm2": ones(d),
+                 "Wgate": normal((d, ff)), "Wup": normal((d, ff)),
+                 "Wdown": normal((ff, d))}
+            if kind == "linear":
+                A = jax.random.uniform(next(keys), (H,), _F32, 1e-4, 16.0)
+                dtv = jnp.exp(jax.random.uniform(next(keys), (H,), _F32)
+                              * (math.log(1e-1) - math.log(1e-3))
+                              + math.log(1e-3))
+                p.update(
+                    Wq=normal((d, H * dk)), Wk=normal((d, H * dk)),
+                    Wv=normal((d, H * dv)), Wg=normal((d, H * dv)),
+                    Wo=normal((H * dv, d)), Wa=normal((d, H)),
+                    Wb=normal((d, H)), convQ=conv(H * dk),
+                    convK=conv(H * dk), convV=conv(H * dv),
+                    Alog=jnp.log(A).astype(dt),
+                    dtBias=(dtv + jnp.log(-jnp.expm1(-dtv))).astype(dt),
+                    gnorm=ones(dv))
+            else:
+                p.update(Wq=normal((d, d)), Wk=normal((d, d)),
+                         Wv=normal((d, d)), Wo=normal((d, d)),
+                         qnorm=ones(d), knorm=ones(d))
+            return p
+
+        @jax.jit
+        def ends(key):
+            ke, kh = jax.random.split(key)
+            return ((std * jax.random.normal(ke, (c.vocabSize, d), _F32)
+                     ).astype(dt),
+                    (std * jax.random.normal(kh, (d, c.vocabSize), _F32)
+                     ).astype(dt))
+
+        key = jax.random.PRNGKey(c.seed)
+        emb, head = ends(jax.random.fold_in(key, 0))
+        return {"emb": emb, "head": head, "normf": jnp.ones((d,), dt),
+                "layers": [layer(jax.random.fold_in(key, i + 1), kind)
+                           for i, kind in enumerate(c.layerKinds())]}
+
+    # ------------------------------------------------------------------
+    def cacheSpec(self) -> CacheSpec:
+        """What each layer keeps between steps, for the scheduler's pool:
+        pages for the full layers (rows of all heads side by side), no
+        ring, and the linear layers' delta state and convolution
+        windows."""
+        c = self.config
+        kinds = c.layerKinds()
+        nL = kinds.count("linear")
+        dt = jnp.dtype(c.dtype)
+        return CacheSpec(
+            pagedLayers=kinds.count("full"), kvHeads=c.nHeads,
+            headSize=c.headSize, dtype=dt,
+            slotState=(("delta", (nL, c.linHeads, c.linKeyDim,
+                                  c.linValueDim), _F32),
+                       ("conv", (nL, c.convKernel - 1, c.convWidth), dt)))
+
+    # -- pieces shared by the full-sequence and the step forms ----------
+    def _gates(self, lp, h):
+        """``beta`` and ``g = log alpha`` ``(..., H)`` from ``h (..., d)``,
+        float32."""
+        f = lambda n: lp[n].astype(_F32)
+        beta = 2.0 * jax.nn.sigmoid(_mm(h, lp["Wb"]))
+        g = -jnp.exp(f("Alog")) * jax.nn.softplus(_mm(h, lp["Wa"])
+                                                  + f("dtBias"))
+        return beta, g
+
+    def _heads(self, q, k, v):
+        """The three convolutions' outputs ``(..., H dk)``, ``(..., H
+        dk)``, ``(..., H dv)`` through their SiLU and into heads: ``q``
+        and ``k`` normalised ``(..., H, dk)``, ``v (..., H, dv)``."""
+        c = self.config
+        H, dk = c.linHeads, c.linKeyDim
+        split = lambda a: jax.nn.silu(a).reshape(a.shape[:-1] + (H, -1))
+        return _l2(split(q)) * dk ** -0.5, _l2(split(k)), split(v)
+
+    def _gdn_out(self, lp, o, h):
+        """``(RMSNorm_dv(o) * g_norm * silu(h Wg)) Wo``."""
+        o = _rms(o, lp["gnorm"], self.config.eps)
+        o = o.reshape(o.shape[:-2] + (-1,))
+        return _mm(o * jax.nn.silu(_mm(h, lp["Wg"])), lp["Wo"])
+
+    def _close_block(self, lp, x, out, hold=lambda a: a):
+        """The block around a mixer's output: both residual adds, each
+        behind its norm, and the gated FFN.  ``hold`` is applied to the
+        stream after each add."""
+        eps = self.config.eps
+        y = hold(x + _rms(out, lp["norm1"], eps).astype(x.dtype))
+        ff = _mm(jax.nn.silu(_mm(y, lp["Wgate"])) * _mm(y, lp["Wup"]),
+                 lp["Wdown"])
+        return hold(y + _rms(ff, lp["norm2"], eps).astype(x.dtype))
+
+    def _logits(self, params, x):
+        return _mm(_rms(x, params["normf"], self.config.eps), params["head"])
+
+    # ------------------------------------------------------------------
+    # full-sequence form: forward and prefill
+    # ------------------------------------------------------------------
+    def _attend_full(self, q, k, v, start):
+        """Causal softmax attention over whole sequences: ``q (b, T, d)``
+        float32 after its norm, ``k, v (b, T, d)`` as they are stored; a
+        block of queries at a time against every key, so that the scores
+        of 4,096 positions are never held at once.  No key before
+        ``start`` is valid."""
+        c = self.config
+        b, T, _ = q.shape
+        H, dh = c.nHeads, c.headSize
+        cd = k.dtype
+        B = _QUERY_BLOCK if T % _QUERY_BLOCK == 0 else T
+        q4 = q.reshape(b, T, H, dh).astype(cd)
+        k4, v4 = k.reshape(b, T, H, dh), v.reshape(b, T, H, dh)
+        kpos = jnp.arange(T, dtype=_I32)[None, None, :]
+        real = kpos >= start[:, None, None]                  # (b, 1, T)
+
+        def block(i):
+            qb = jax.lax.dynamic_slice_in_dim(q4, i * B, B, axis=1)
+            s = jnp.einsum("bqhd,bkhd->bhqk", qb, k4,
+                           preferred_element_type=_F32) * dh ** -0.5
+            rows = i * B + jnp.arange(B, dtype=_I32)
+            valid = (kpos <= rows[None, :, None]) & real     # (b, B, T)
+            a = jax.nn.softmax(jnp.where(valid[:, None], s, _NEG), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", a.astype(cd), v4,
+                              preferred_element_type=_F32)
+        o = jax.lax.map(block, jnp.arange(T // B, dtype=_I32))
+        return jnp.moveaxis(o, 0, 1).reshape(b, T, H * dh)
+
+    def _run_full(self, params, tokens, start):
+        """``tokens (b, T)`` LEFT-padded, ``start (b,)`` the first real
+        position.  Returns the last layer's output and the cache state a
+        decode would continue from: every full layer's K/V rows, every
+        linear layer's final delta state and last ``K - 1`` convolution
+        inputs.  A pad position changes nothing: its convolution inputs
+        are zero (so its q, k, v are), its ``beta`` is 0 and its
+        ``alpha`` 1, and no key is valid there."""
+        c = self.config
+        b, T = tokens.shape
+        K = c.convKernel
+        realF = (jnp.arange(T, dtype=_I32)[None, :] >= start[:, None]
+                 ).astype(_F32)[..., None]                   # (b, T, 1)
+        x = params["emb"][tokens]
+        cd = x.dtype
+        kinds = c.layerKinds()
+        spec = self.cacheSpec()
+        # each layer's state goes into its row of the stacks as soon as it
+        # exists (a list stacked at the end would hold all of it twice:
+        # 0.57 GB at the published sizes and 4,096 positions); the paged
+        # stacks in paged_prefill_write's form (L, b, h, T, d), one
+        # "head" as wide as a row
+        pagedK = pagedV = jnp.zeros(
+            (spec.pagedLayers, b, 1, T, spec.rowWidth), cd)
+        (_, dShape, _), (_, cShape, _) = spec.slotState
+        delta = jnp.zeros(dShape[:1] + (b,) + dShape[1:], _F32)
+        conv = jnp.zeros(cShape[:1] + (b,) + cShape[1:], cd)
+        li = fi = 0
+        for kind, lp in zip(kinds, params["layers"]):
+            if kind == "linear":
+                # q, k and v one after the other (side by side they are
+                # 189 MB in float32 at 4,096 positions, three times over)
+                def convolved(w, taps):
+                    u = _mm(x, lp[w]) * realF
+                    up = jnp.concatenate(
+                        [jnp.zeros((b, K - 1, u.shape[-1]), _F32), u], axis=1)
+                    t = lp[taps].astype(_F32)
+                    return (sum(t[j] * up[:, j:j + T] for j in range(K)),
+                            u[:, T - (K - 1):])
+                (q, tq), (k, tk), (v, tv) = (
+                    convolved("Wq", "convQ"), convolved("Wk", "convK"),
+                    convolved("Wv", "convV"))
+                conv = conv.at[li].set(
+                    jnp.concatenate([tq, tk, tv], axis=-1).astype(cd))
+                q, k, v = self._heads(q, k, v)
+                beta, g = self._gates(lp, x)
+                o, S = delta_rule_chunked(q, k, v, beta * realF, g * realF,
+                                          c.chunk)
+                delta = delta.at[li].set(S)
+                out = self._gdn_out(lp, o, x)
+                li += 1
+            else:
+                q = _rms(_mm(x, lp["Wq"]), lp["qnorm"], c.eps)
+                kR = _rms(_mm(x, lp["Wk"]), lp["knorm"], c.eps).astype(cd)
+                vR = _mm(x, lp["Wv"]).astype(cd)
+                pagedK = pagedK.at[fi, :, 0].set(kR)
+                pagedV = pagedV.at[fi, :, 0].set(vR)
+                out = _mm(self._attend_full(q, kR, vR, start), lp["Wo"])
+                fi += 1
+            # the stream is written out after every block: left to itself
+            # XLA keeps each block's float32 contribution instead and has
+            # every later consumer add them all up again (32 buffers of
+            # 63 MB live to the end of a 4,096-position prefill)
+            x = self._close_block(lp, x, out,
+                                  hold=jax.lax.optimization_barrier)
+        return x, (pagedK, pagedV, delta, conv)
+
+    @functools.cached_property
+    def _fwd(self):
+        def run(params, tokens):
+            start = jnp.zeros((tokens.shape[0],), _I32)
+            x, _ = self._run_full(params, tokens, start)
+            return self._logits(params, x)
+        return jax.jit(run)
+
+    def forward(self, tokens) -> jax.Array:
+        """Full causal forward: (b, t) int32 -> (b, t, vocab) float32."""
+        return self._fwd(self.params, jnp.asarray(tokens, _I32))
+
+    @functools.cached_property
+    def _prefillRawFn(self):
+        def run(params, tokens, start):
+            x, state = self._run_full(params, tokens, start)
+            return (self._logits(params, x[:, -1]),) + state
+        return _JitByLength(run, "prefill")
+
+    def prefillRaw(self, tokens, lengths=None):
+        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
+        kStack, vStack, delta, conv)``: the paged stacks in
+        :func:`paged_prefill_write`'s form ``(full layers, b, 1, t, d)``
+        and the slot state ``(linear layers, b, ...)`` in the pool's
+        order.  One executable per prompt bucket."""
+        tokens = jnp.asarray(tokens, _I32)
+        t = tokens.shape[1]
+        if t > self.config.maxLen:
+            raise ValueError(f"prompt length {t} exceeds the capacity "
+                             f"{self.config.maxLen}")
+        if lengths is None:
+            start = jnp.zeros((tokens.shape[0],), _I32)
+        else:
+            start = t - jnp.asarray(lengths, _I32)
+        return self._prefillRawFn(self.params, tokens, start)
+
+    # ------------------------------------------------------------------
+    # step form — the continuous-batching scheduler's executables
+    # ------------------------------------------------------------------
+    def pagedLogits(self, params, k, v, delta, conv, toks, pageTable, pos,
+                    start):
+        """One token per slot (``toks (S, 1)``) against the pool's
+        arrays: ``((S, 1, vocab) logits, k, v, delta, conv)``.  A slot
+        whose ``pos`` is 0 holds no sequence (or is deferred a round):
+        its paged write lands on the scratch page through its zeroed page
+        table, and its recurrent state is left as it is."""
+        c = self.config
+        S, tq = toks.shape
+        if tq != 1:
+            raise ValueError(
+                "a recurrent state advances one token a step: speculative "
+                "verification (tq > 1) would need its roll-back")
+        H, dh = c.nHeads, c.headSize
+        active = pos > 0
+        x = params["emb"][toks[:, 0]]                         # (S, d)
+        cd = x.dtype
+        keep = lambda new, old: jnp.where(
+            active.reshape((S,) + (1,) * (new.ndim - 1)), new, old)
+        heads = lambda a: a.reshape(S, 1, H, dh).transpose(0, 2, 1, 3)
+        li = fi = 0
+        for kind, lp in zip(c.layerKinds(), params["layers"]):
+            if kind == "linear":
+                qkv = jnp.concatenate([_mm(x, lp["Wq"]), _mm(x, lp["Wk"]),
+                                       _mm(x, lp["Wv"])], axis=-1)
+                win = jnp.concatenate(
+                    [conv[li].astype(_F32), qkv[:, None]], axis=1)
+                conv = conv.at[li].set(keep(win[:, 1:].astype(conv.dtype),
+                                            conv[li]))
+                taps = jnp.concatenate(
+                    [lp["convQ"], lp["convK"], lp["convV"]], axis=-1)
+                u = jnp.sum(win * taps.astype(_F32)[None], axis=1)
+                nk = c.linHeads * c.linKeyDim
+                q, kk, vv = self._heads(u[:, :nk], u[:, nk:2 * nk],
+                                        u[:, 2 * nk:])
+                beta, g = self._gates(lp, x)
+                # the recurrence itself, in float32 on the VPU (a matmul
+                # would round the state to bfloat16 on its way in)
+                Sd = jnp.exp(g)[..., None, None] * delta[li]  # (S, H, dk, dv)
+                u = beta[..., None] * (
+                    vv - jnp.sum(Sd * kk[..., None], axis=2))
+                Sd = Sd + kk[..., None] * u[..., None, :]
+                o = jnp.sum(Sd * q[..., None], axis=2)
+                delta = delta.at[li].set(keep(Sd, delta[li]))
+                out = self._gdn_out(lp, o, x)
+                li += 1
+            else:
+                q = _rms(_mm(x, lp["Wq"]), lp["qnorm"], c.eps)
+                kN = _rms(_mm(x, lp["Wk"]), lp["knorm"], c.eps)
+                ctx, k, v = paged_attention(
+                    heads(q), heads(kN), heads(_mm(x, lp["Wv"])), k, v, fi,
+                    pageTable, pos, start)
+                out = _mm(ctx.transpose(0, 2, 1, 3).reshape(S, H * dh),
+                          lp["Wo"])
+                fi += 1
+            x = self._close_block(lp, x, out)
+        return self._logits(params, x)[:, None], k, v, delta, conv
+
+    def buildPagedDecodeFn(self):
+        """FRESH jitted decode step over the pool's arrays: ``(params, k,
+        v, delta, conv, toks (S, 1), prev (S, 1), pageTable, pos, start)
+        -> (greedy (S, 1), k, v, delta, conv)``.  The four arrays are
+        DONATED, a slot whose ``toks`` is -1 takes ``prev``; a fresh
+        identity per build, all as ``TransformerLM.buildPagedDecodeFn``
+        explains."""
+        def step(params, k, v, delta, conv, toks, prev, pageTable, pos,
+                 start):
+            out = self.pagedLogits(params, k, v, delta, conv,
+                                   paged_step_tokens(toks, prev), pageTable,
+                                   pos, start)
+            return (jnp.argmax(out[0], axis=-1).astype(_I32),) + out[1:]
+        return jax.jit(step, donate_argnums=(1, 2, 3, 4))
+
+    def buildPagedPrefillWriteFn(self):
+        """FRESH jitted admission write: one sequence's prefill state
+        (:meth:`prefillRaw`'s, batch row taken) into the pages
+        ``pageIds`` and into slot ``slot``'s recurrent state, which it
+        overwrites whole."""
+        def write(k, v, delta, conv, kStack, vStack, deltaS, convS, pageIds,
+                  slot):
+            k, v = paged_prefill_write(k, v, kStack, vStack, pageIds)
+            z = jnp.zeros((), _I32)
+            put = lambda pool, part: jax.lax.dynamic_update_slice(
+                pool, part[:, None].astype(pool.dtype),
+                (z, slot.astype(_I32)) + (z,) * (pool.ndim - 2))
+            return k, v, put(delta, deltaS), put(conv, convS)
+        return jax.jit(write, donate_argnums=(0, 1, 2, 3))
+
+    def compileCacheSize(self) -> int:
+        return served_jit_entries(self)
+
+    def dropCompiled(self) -> None:
+        drop_served_jits(self)

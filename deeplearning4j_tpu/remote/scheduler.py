@@ -679,6 +679,11 @@ class ContinuousBatcher:
         sm.queue_wait_seconds()
         sm.prefill_seconds()
         sm.loop_phase_seconds()
+        # the ring series exist from the start and read 0 until a step
+        # updates them: never, for a model without window layers, so a
+        # reader of every kind of cache state finds all of them
+        sm.ring_rows_in_use().set(0, model=self.name)
+        sm.cache_bytes().set(0, model=self.name, kind="ring")
         self.warm()
         self._updatePageGauges()
         self._cacheSeen = self.compileCacheSize()
@@ -1168,6 +1173,8 @@ class ContinuousBatcher:
                 # conditioned on the prefix the client actually saw
                 first = int(seq.forced[0])
         prefillDt = span.seconds
+        sm.prefill_positions().inc(Tp, model=self.name, bucket=str(Tp))
+        sm.prefill_prompt_tokens().inc(seq.realLen, model=self.name)
         self._slotSeq[slot] = seq
         self._pos[slot] = Tp
         self._start[slot] = Tp - seq.realLen

@@ -594,6 +594,21 @@ class ServingMetrics:
             "+ first-token argmax) inside slot admission, per model",
             labelnames=("model",), buckets=SERVING_LATENCY_BUCKETS)
 
+    def prefill_positions(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_prefill_positions_total",
+            "Positions prefilled at admission, padding included: the "
+            "prompt bucket of every prefill, per model and bucket (over "
+            "prefill_prompt_tokens_total it is what the bucket ladder "
+            "wastes; over the bucket, the prefills of that shape)",
+            labelnames=("model", "bucket"))
+
+    def prefill_prompt_tokens(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_prefill_prompt_tokens_total",
+            "Real prompt tokens prefilled at admission, per model",
+            labelnames=("model",))
+
     def loop_phase_seconds(self):
         return get_registry().histogram(
             "dl4j_tpu_serving_loop_phase_seconds",
