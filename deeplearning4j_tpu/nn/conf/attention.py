@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import operator
 from typing import Any, Tuple
 
@@ -32,6 +33,7 @@ from deeplearning4j_tpu.nn.weights import init_weight
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
            "paged_attention", "paged_kernel_lowerings",
+           "paged_kernel_kv_passes",
            "paged_prefill_write", "paged_step_tokens",
            "CacheSpec", "served_jit_entries", "drop_served_jits"]
 
@@ -143,10 +145,15 @@ def _attend_gathered(qh, poolK, poolV, pageTable, pos, start, *, li):
 
 # -- the kernel: attention over the live pages, where they lie ---------
 
-#: rows of K (and of V) a place of the kernel's grid works on.  At
-#: gpt2_xl's sizes and the cells' lengths 64 and 128 cost a layer the same
-#: (26.5 us), 32 and 256 a fifth more (my chip run, PR 29): a matmul
-#: against the 0/1 matrix takes as long for 64 rows as for 128
+#: rows of K (and of V) a place of the kernel's grid works on.  With the
+#: dot products on the MXU a place costs what its pages' copies cost, and
+#: 256 rows a place cost a row what 128 do: 0.758 against 0.759 ms a call
+#: at Olmo-Hybrid's row (3,840 bfloat16 lanes, 36,100 live rows), 18.6
+#: against 18.6 us at gpt2_xl's (1,600 float32 lanes, 650 live rows); only
+#: a row of 1,280 bfloat16 lanes, which no caller has yet, gains (0.331
+#: against 0.381 ms over 37,800 live rows) (my chip run, PR 31).  So 128
+#: stays: a slot's last chunk is half empty on average, and its dead
+#: buffers are not copied
 _CHUNK_ROWS = 128
 
 
@@ -188,80 +195,148 @@ def _work_list(pageTable, pos, start, *, tq, pageSize, C):
             .astype(i32), flag.astype(i32), total)
 
 
+def _mxu_parts(dtype) -> int:
+    """How many bfloat16 pieces hold every bit of a value of ``dtype``:
+    one for each eight bits of its significand (bfloat16 1, float32 3)."""
+    return -(-(jnp.finfo(dtype).nmant + 1) // 8)
+
+
+#: the pieces of a float32 query or softmax weight
+_F32_PARTS = _mxu_parts(jnp.float32)
+
+
+def _bf16_parts(x):
+    """``x`` as :func:`_mxu_parts` arrays, each exact in bfloat16, whose
+    float32 sum is ``x`` to its last bit: the high, middle and low bits of
+    a float32 (a bfloat16 ``x`` is its own one piece).  One MXU pass a
+    piece then multiplies by all of ``x``, where one pass over ``x``
+    itself would round it to eight bits."""
+    n = _mxu_parts(x.dtype)
+    if n == 1:
+        return [x]
+    x, parts = x.astype(jnp.float32), []
+    for _ in range(n - 1):
+        hi = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536),
+            jnp.float32)                        # the top 16 bits, cut off
+        parts.append(hi)
+        x = x - hi
+    return parts + [x]
+
+
+def _lane_tiles(heads, d):
+    """How the kernel cuts a row of ``heads * d`` lanes: tiles of ``g``
+    whole heads, the fewest whose ``g * d`` lanes are whole lane tiles of
+    128 (one head of 128, two of 64), so that every tile starts on one;
+    the last tile of a row may be short.  ``(g, tiles)``, a tile as
+    ``(first lane, lanes)``."""
+    g = min(heads, math.lcm(d, 128) // d)
+    return g, [(lo, min(g * d, heads * d - lo))
+               for lo in range(0, heads * d, g * d)]
+
+
 def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
                   start_ref, q_ref, *refs, C, ps, tq, d):
     """One place of the grid: ``C`` pages of K and of V of one slot
     (``k_refs``/``v_refs``, each ``(ps, h*d)``, copied in by the
     pipeline while the place before computes), all ``tq`` queries of that
-    slot.  Heads are never split: ``K * q`` over the merged lanes, the
-    per-head sum as a matmul with the 0/1 matrix ``E`` ((h*d, heads)),
-    an online softmax per head across the slot's chunks, the weights
-    spread back over the lanes by ``E``'s transpose."""
+    slot.  Heads are never split out of the rows: the MXU contracts over
+    the lanes of the pages as they lie, a tile of ``g`` whole heads at a
+    time, with the queries as the small operand.
+
+    - *scores*: ``Q_t`` (a row for every query, head of the tile and
+      bfloat16 piece of the float32 ``q``: that head's slice of ``q`` in
+      its own lanes, zeros elsewhere) against the tile's lanes of the
+      chunk's rows of K, ``(rows, lanes) x (R, lanes) -> (rows, R)``:
+      every head's scores with the key positions on the lanes, the
+      pieces' rows summed in float32;
+    - *softmax*: online, per head, across the slot's chunks, in float32,
+      for all tiles at once;
+    - *context*: the weights' bfloat16 pieces as rows, ``(rows, R) x (R,
+      lanes)`` against the tile's lanes of V, the pieces summed, each
+      head keeping its own lanes at the end.
+
+    K and V enter the MXU as they are stored, in as many passes as
+    :func:`_mxu_parts` of the pool's dtype says (a bfloat16 pool: one, and
+    no float32 copy of a page is ever made); ``q`` and the weights keep
+    every float32 bit."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     k_refs, v_refs = refs[:C], refs[C:2 * C]
-    o_ref, e_ref, et_ref, m_ref, l_ref, acc_ref = refs[2 * C:]
+    o_ref, qt_ref, sp_ref, c_ref, m_ref, l_ref, acc_ref = refs[2 * C:]
     w = pl.program_id(0)
     R = C * ps
-    hd, hp = e_ref.shape
-
-    def dot3(x, m_ref_):
-        """``x @ m`` for a float32 ``x`` and a 0/1 ``m``: three bfloat16
-        passes over ``x`` split into its high, middle and low bits, summed
-        in float32 — every bit of ``x`` takes part, where one pass would
-        round it to eight."""
-        m_, parts = m_ref_[...], []
-        for _ in range(3):
-            hi = x.astype(bf16)
-            x = x - hi.astype(f32)
-            parts.append(jnp.dot(hi, m_, preferred_element_type=f32))
-        return parts[0] + parts[1] + parts[2]
-
-    @pl.when(w == 0)
-    def _():
-        for ref, lanes, heads in ((e_ref, 0, 1), (et_ref, 1, 0)):
-            lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape, lanes)
-            head = jax.lax.broadcasted_iota(jnp.int32, ref.shape, heads)
-            ref[...] = ((lane >= head * d) & (lane < head * d + d)).astype(
-                ref.dtype)
-
+    g, tiles = _lane_tiles(q_ref.shape[-1] // d, d)
+    G = tq * g                      # rows of one piece: (query, head)
     flag = flag_ref[w]
 
+    def rows(part, i):              # piece ``part`` of query ``i``
+        return pl.ds(part * G + i * g, g)
+
+    # row j of a tile's block belongs to the tile's head j: its lanes
+    lane = jax.lax.broadcasted_iota(jnp.int32, (g, g * d), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (g, g * d), 0)
+    own = (lane >= head * d) & (lane < head * d + d)
+
     @pl.when((flag & 1) != 0)
-    def _():
+    def _():                        # a slot's pass opens
         m_ref[...] = jnp.full(m_ref.shape, _NEG, f32)
         l_ref[...] = jnp.zeros(l_ref.shape, f32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+        qt_ref[...] = jnp.zeros(qt_ref.shape, f32)
+        for i in range(tq):
+            for t, (lo, n) in enumerate(tiles):
+                qi = jnp.broadcast_to(q_ref[pl.ds(i, 1), lo:lo + n], (g, n))
+                for part, qp in enumerate(_bf16_parts(qi)):
+                    qt_ref[t, rows(part, i), 0:n] = jnp.where(
+                        own[:, :n], qp, f32(0))
+
+    def chunk(refs_, lo, n):
+        """The tile's lanes of the chunk's ``R`` rows, as MXU operands."""
+        x = jnp.concatenate([r[:, lo:lo + n] for r in refs_], axis=0)
+        return [p.astype(bf16) for p in _bf16_parts(x)]
+
+    for t, (lo, n) in enumerate(tiles):
+        qt = qt_ref[t, :, 0:n].astype(bf16)
+        sp_ref[t] = functools.reduce(operator.add, (
+            jax.lax.dot_general(qt, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32)
+            for k in chunk(k_refs, lo, n)))
 
     s = slot_ref[w]
     pos, start = pos_ref[s], start_ref[s]
-    j = j0_ref[w] + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+    j = j0_ref[w] + jax.lax.broadcasted_iota(jnp.int32, (1, 1, R), 2)
+    scale = []                      # what each query's sums so far shrink by
     for i in range(tq):
-        qi = q_ref[pl.ds(i, 1), :]
         valid = (j >= start) & (j <= pos + i)
-        prod = jnp.concatenate(
-            [k_refs[c][...].astype(f32) * qi for c in range(C)], axis=0)
-        sc = jnp.where(valid, dot3(prod, e_ref), f32(_NEG))  # (R, hp)
-        mOld = m_ref[i]                             # (8, hp), rows alike
-        mNew = jnp.maximum(mOld, jnp.max(sc, axis=0, keepdims=True))
-        p = jnp.where(valid, jnp.exp(sc - mNew[0:1]), f32(0))
-        # the chunk's weights and, in 8 rows more, what the sums so far
-        # are scaled by, spread over the lanes in one matmul
-        wa = dot3(jnp.concatenate([p, jnp.exp(mOld - mNew)], axis=0),
-                  et_ref)                                    # (R + 8, hd)
-        wcs = [wa[c * ps:(c + 1) * ps] for c in range(C)]
-        ws = functools.reduce(operator.add, wcs)
-        wv = functools.reduce(operator.add, (
-            wc * v_refs[c][...].astype(f32) for c, wc in enumerate(wcs)))
-        a = wa[R:]
-        acc_ref[i] = a * acc_ref[i] + jnp.sum(wv, axis=0, keepdims=True)
-        l_ref[i] = a * l_ref[i] + jnp.sum(ws, axis=0, keepdims=True)
+        sc = functools.reduce(operator.add, (
+            sp_ref[:, rows(part, i), :] for part in range(_F32_PARTS)))
+        sc = jnp.where(valid, sc, f32(_NEG))                 # (T, g, R)
+        mOld = m_ref[i]                                      # (T, g, 1)
+        mNew = jnp.maximum(mOld, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(sc - mNew), f32(0))
+        scale.append(jnp.exp(mOld - mNew))
+        l_ref[i] = scale[i] * l_ref[i] + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[i] = mNew
+        for part, pp in enumerate(_bf16_parts(p)):
+            sp_ref[:, rows(part, i), :] = pp
+
+    for t, (lo, n) in enumerate(tiles):
+        pt = sp_ref[t].astype(bf16)
+        c_ref[t, :, 0:n] = functools.reduce(operator.add, (
+            jnp.dot(pt, v, preferred_element_type=f32)
+            for v in chunk(v_refs, lo, n)))
+    for i in range(tq):
+        acc_ref[i] = scale[i] * acc_ref[i] + functools.reduce(operator.add, (
+            c_ref[:, rows(part, i), :] for part in range(_F32_PARTS)))
 
     @pl.when((flag & 2) != 0)
-    def _():
+    def _():                        # and closes: each head's own lanes
         for i in range(tq):
-            o_ref[pl.ds(i, 1), :] = (acc_ref[i] / l_ref[i])[0:1].astype(
-                o_ref.dtype)
+            o = jnp.sum(jnp.where(own, acc_ref[i] / l_ref[i], f32(0)),
+                        axis=1, keepdims=True)               # (T, 1, g*d)
+            for t, (lo, n) in enumerate(tiles):
+                o_ref[pl.ds(i, 1), lo:lo + n] = o[t][:, :n].astype(
+                    o_ref.dtype)
 
 
 def _attend_pages(qh, poolK, poolV, pageTable, pos, start, *, li,
@@ -304,7 +379,11 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
     ps = poolK.shape[2]
     C = tbl.shape[0] // slot.shape[0]
     d = headSize
-    hp = -(-(hd // d) // 128) * 128
+    g, tiles = _lane_tiles(hd // d, d)
+    T, R = len(tiles), C * ps
+    # rows of a tile's block: a query's heads of the tile, piece by piece
+    # (whole bfloat16 sublane tiles of 16)
+    rp = -(-_F32_PARTS * tq * g // 16) * 16
     f32 = jnp.float32
 
     # index maps: ``w * 0`` and not ``0`` (the package enables x64, and a
@@ -323,11 +402,12 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
             in_specs=[row_spec] + [page_spec(c) for c in range(C)] * 2,
             out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((hd, hp), jnp.bfloat16),          # E
-                pltpu.VMEM((hp, hd), jnp.bfloat16),          # its transpose
-                pltpu.VMEM((tq, 8, hp), f32),                # running max
-                pltpu.VMEM((tq, 8, hd), f32),                # running sum
-                pltpu.VMEM((tq, 8, hd), f32),                # context
+                pltpu.VMEM((T, rp, g * d), f32),     # the queries' Q_t
+                pltpu.VMEM((T, rp, R), f32),         # scores, then weights
+                pltpu.VMEM((T, rp, g * d), f32),     # the chunk's context
+                pltpu.VMEM((tq, T, g, 1), f32),      # running max
+                pltpu.VMEM((tq, T, g, 1), f32),      # running sum
+                pltpu.VMEM((tq, T, g, g * d), f32),  # context
             ]),
         out_shape=jax.ShapeDtypeStruct((S, tq, hd), f32),
         compiler_params=pltpu.CompilerParams(
@@ -339,9 +419,10 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
       *([poolV] * C))
 
 
-#: how often the step's read was lowered as the kernel (program
-#: telemetry: the batcher's gauge reads it around its warm-up)
-_kernelLowerings = [0]
+#: how often the step's read was lowered as the kernel, and the MXU passes
+#: over a lane tile of K (and of V) of the latest such lowering (program
+#: telemetry: the batcher's gauges read both around its warm-up)
+_kernelLowerings = [0, 0]
 
 
 def paged_kernel_lowerings() -> int:
@@ -349,6 +430,14 @@ def paged_kernel_lowerings() -> int:
     the TPU kernel in this process (once a layer of each program built
     for one TPU; never on the CPU or for a pool split over devices)."""
     return _kernelLowerings[0]
+
+
+def paged_kernel_kv_passes() -> int:
+    """MXU passes over one lane tile of K (and one of V) a chunk, for all
+    queries of the slot, in the kernel as it was last lowered: what the
+    pool's dtype needs to enter the MXU whole (:func:`_mxu_parts`: a
+    bfloat16 pool 1, a float32 pool 3); 0 before any kernel lowering."""
+    return _kernelLowerings[1]
 
 
 def _attend_lowering(ctx, *args, li):
@@ -361,6 +450,7 @@ def _attend_lowering(ctx, *args, li):
         getattr(mc.axis_context, "num_devices", None) == 1
     if kernel:
         _kernelLowerings[0] += 1
+        _kernelLowerings[1] = _mxu_parts(ctx.avals_in[1].dtype)
     return mlir.lower_fun(
         functools.partial(_attend_pages if kernel else _attend_gathered,
                           li=li), multiple_results=False)(ctx, *args)

@@ -63,6 +63,7 @@ import numpy as np
 # so the package-level import here cannot cycle
 from deeplearning4j_tpu.fault import injection as _inj
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  paged_kernel_kv_passes,
                                                   paged_kernel_lowerings)
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
@@ -624,8 +625,10 @@ class ContinuousBatcher:
         lowered = paged_kernel_lowerings()
         prev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
-        sm.paged_attention_kernel().set(
-            int(paged_kernel_lowerings() > lowered), model=self.name)
+        kernel = paged_kernel_lowerings() > lowered
+        sm.paged_attention_kernel().set(1 if kernel else 0, model=self.name)
+        sm.paged_attention_kv_passes().set(
+            paged_kernel_kv_passes() if kernel else 0, model=self.name)
         # and with a step's own output for ``prev``, as every later call
         # has it: beside committed params that is another entry of the
         # jit's cache than fresh zeros.  It stands in wherever no step is

@@ -426,6 +426,16 @@ class ServingMetrics:
             "kv_pages_in_use / (maxSlots x maxPagesPerSeq)",
             labelnames=("model",))
 
+    def paged_attention_kv_passes(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_paged_attention_kv_passes",
+            "MXU passes over one K (and one V) lane tile a chunk a query "
+            "in the batcher's decode step as it was lowered: 1 for a "
+            "bfloat16 pool (K and V enter the MXU as stored), 3 for a "
+            "float32 pool (its high, middle and low bits), 0 where the "
+            "step gathers (paged_attention_kernel 0)",
+            labelnames=("model",))
+
     def kv_pages_free(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_kv_pages_free",

@@ -100,20 +100,21 @@ def test_paged_attention_matches_masked_softmax(tq, layers, pos, start):
         np.testing.assert_array_equal(np.asarray(out), want)
 
 
-def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2):
-    """Random pools and queries for ``len(pos)`` slots of ``P`` pages of
-    ``ps`` rows: ``(qh, poolK, poolV, table, pos, start)`` as
-    ``paged_attention``'s two formulations take them.  ``table`` defaults
-    to each slot's pages in a scrambled physical order (page 0 is the
-    scratch page and belongs to no slot)."""
+def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2,
+                pool="float32"):
+    """Random pools (of dtype ``pool``) and float32 queries for
+    ``len(pos)`` slots of ``P`` pages of ``ps`` rows: ``(qh, poolK, poolV,
+    table, pos, start)`` as ``paged_attention``'s two formulations take
+    them.  ``table`` defaults to each slot's pages in a scrambled
+    physical order (page 0 is the scratch page and belongs to no slot)."""
     import jax.numpy as jnp
     S = len(pos)
     numPages = 1 + S * P
     if table is None:
         table = 1 + rng.permutation(S * P).reshape(S, P)
     return (jnp.asarray(rng.randn(S, h, tq, d), jnp.float32),
-            jnp.asarray(rng.randn(layers, numPages, ps, h * d), jnp.float32),
-            jnp.asarray(rng.randn(layers, numPages, ps, h * d), jnp.float32),
+            jnp.asarray(rng.randn(layers, numPages, ps, h * d), pool),
+            jnp.asarray(rng.randn(layers, numPages, ps, h * d), pool),
             jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
             jnp.asarray(start, jnp.int32))
 
@@ -148,11 +149,31 @@ def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2):
          pos=[319, 318], start=[0, 100]),
     dict(id="verify_to_the_last_row", h=3, d=8, ps=16, P=20, tq=4,
          pos=[316, 40], start=[0, 0]),
+    # bfloat16 pools: K and V enter the MXU as they are stored, in one
+    # pass a lane tile, and q and the weights still with every bit.
+    # Olmo-Hybrid's row (30 heads of 128: a head a lane tile), a slot
+    # past two chunks, a left pad past a chunk
+    dict(id="bf16_row_of_3840_lanes", h=30, d=128, ps=16, P=20, tq=1,
+         pos=[300, 45], start=[140, 3], pool="bfloat16"),
+    # the row a grouped-KV caller will bring: 20 heads of 64, two a tile
+    dict(id="bf16_row_of_1280_lanes", h=20, d=64, ps=16, P=12, tq=1,
+         pos=[170, 20], start=[0, 7], pool="bfloat16"),
+    dict(id="bf16_verify_crossing_a_page", h=6, d=64, ps=16, P=12, tq=3,
+         pos=[157, 13], start=[18, 0], pool="bfloat16"),
+    # 25 heads of 64: the thirteenth lane tile is half full
+    dict(id="bf16_last_tile_half_full", h=25, d=64, ps=16, P=10, tq=1,
+         pos=[150, 37], start=[3, 0], pool="bfloat16"),
 ], ids=lambda c: c["id"])
 def test_paged_kernel_matches_the_gathered_reference(case):
     """The TPU kernel (Pallas interpret mode, here on the CPU) against
     the reference formulation it stands in for: the same context for
-    every slot, query and head, from the pages where they lie."""
+    every slot, query and head, from the pages where they lie.  A
+    bfloat16 pool is held to the reference run in float32 on the same
+    bfloat16 rows, at the float32 cases' tolerance: only float32
+    accumulation of whole products gives that (the kernel reads within
+    4e-7 of the reference here; with ``q`` or the weights cut to one
+    bfloat16 piece on their way into the MXU it misses by 4e-3: the
+    test below)."""
     from deeplearning4j_tpu.nn.conf import attention as A
     rng = np.random.RandomState(len(case["id"]))
     S, P = len(case["pos"]), case["P"]
@@ -160,7 +181,8 @@ def test_paged_kernel_matches_the_gathered_reference(case):
     if case.get("ordered"):
         table = 1 + np.arange(S * P).reshape(S, P)
     args = _paged_case(rng, case["h"], case["d"], case["ps"], P, case["tq"],
-                       case["pos"], case["start"], table)
+                       case["pos"], case["start"], table,
+                       pool=case.get("pool", "float32"))
     for s in case.get("parked", ()):
         args = args[:3] + (args[3].at[s].set(0),) + args[4:]
     want = A._attend_gathered(*args, li=1)
@@ -168,6 +190,39 @@ def test_paged_kernel_matches_the_gathered_reference(case):
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("rounded", ["q", "weights"])
+def test_paged_kernel_tolerance_refuses_a_bfloat16_pass_over(rounded,
+                                                             monkeypatch):
+    """What the bfloat16 cases' tolerance is worth: the same kernel with
+    ``q`` (or the softmax weights) going into the MXU in ONE bfloat16
+    piece, its lower sixteen bits lost, fails it."""
+    from deeplearning4j_tpu.nn.conf import attention as A
+    args = _paged_case(np.random.RandomState(5), 20, 64, 16, 12, 1,
+                       [170, 20], [0, 7], pool="bfloat16")
+    want = np.asarray(A._attend_gathered(*args, li=1))
+    whole = A._bf16_parts
+    calls = []
+
+    def lossy(x):
+        parts = whole(x)
+        calls.append(len(calls))
+        # q is split a tile's block at a time (heads, lanes), the
+        # weights for all tiles at once (tiles, heads, positions)
+        if (rounded == "q") == (x.ndim == 2) and len(parts) > 1:
+            return parts[:1] + [p * 0 for p in parts[1:]]
+        return parts
+    monkeypatch.setattr(A, "_bf16_parts", lossy)
+    A._pages_call.clear_cache()
+    try:
+        got = np.asarray(A._attend_pages(*args, li=1, interpret=True))
+    finally:
+        monkeypatch.undo()
+        A._pages_call.clear_cache()
+    assert calls
+    err = np.max(np.abs(got - want) - 2e-5 * np.abs(want))
+    assert err > 100 * 2e-6, err
 
 
 @pytest.mark.parametrize("how", ["slots_swapped", "pages_moved",
@@ -227,6 +282,34 @@ def test_paged_attention_lowers_the_reference_off_the_tpu():
     assert get_registry().get(
         "dl4j_tpu_serving_paged_attention_kernel").value(
             model="gauge-lm") == 0
+
+
+def test_kv_passes_gauge_reads_zero_for_a_gathered_step():
+    """``dl4j_tpu_serving_paged_attention_kv_passes``: the MXU passes over
+    one K (and one V) lane tile a chunk in the step as it was lowered,
+    which is what the pool's dtype needs to enter the MXU whole (bfloat16
+    1, float32 3).  Here on the CPU the step gathers: 0, whatever a kernel
+    lowering under the same name left behind."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf import attention as A
+    assert [A._mxu_parts(t) for t in (jnp.bfloat16, jnp.float16,
+                                      jnp.float32)] == [1, 2, 3]
+    x = jnp.asarray(np.random.RandomState(3).randn(4, 9) * 7, jnp.float32)
+    parts = A._bf16_parts(x)
+    assert all(np.array_equal(np.asarray(p), np.asarray(
+        p.astype(jnp.bfloat16).astype(jnp.float32))) for p in parts)
+    np.testing.assert_array_equal(
+        np.asarray((parts[2] + parts[1]) + parts[0]), np.asarray(x))
+    gauge = serving_metrics().paged_attention_kv_passes()
+    gauge.set(3, model="passes-lm")
+    cb = ContinuousBatcher(_lm(), name="passes-lm", maxSlots=2, pageSize=4)
+    try:
+        cb.warm()
+    finally:
+        cb.shutdown()
+    assert get_registry().get(
+        "dl4j_tpu_serving_paged_attention_kv_passes").value(
+            model="passes-lm") == 0
 
 
 def test_prefill_write_then_paged_step_equals_forward_logits():

@@ -116,7 +116,8 @@ def _assert_one_step_program(compiled, cache):
 
 
 def test_paged_decode_step_updates_the_pool_in_place(paged):
-    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_kernel_kv_passes, paged_kernel_lowerings)
     lm, params, pool, i32 = paged
     before = paged_kernel_lowerings()
     compiled = lm.buildPagedDecodeFn().lower(
@@ -131,6 +132,8 @@ def test_paged_decode_step_updates_the_pool_in_place(paged):
     assert paged_kernel_lowerings() - before == LAYERS
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == LAYERS
+    # a float32 pool enters the MXU in three bfloat16 pieces
+    assert paged_kernel_kv_passes() == 3
     # so no slot's capacity is gathered (K or V of all four slots, 256
     # pages of 16 rows) and no gathered row is re-laid into heads
     # (f32[256,16,1600], f32[4,1024,25,64])
@@ -177,6 +180,42 @@ def test_paged_attention_is_the_kernel_for_one_tpu_only(topo, one_chip,
     kernels = text.count("tpu_custom_call")
     assert kernels == paged_kernel_lowerings() - before
     assert kernels == (1 if chips == 1 else 0)
+
+
+# the rows the kernel's callers bring, and the one the next will (a
+# grouped-KV layer read by eight: ROADMAP S1 b): heads, head size, the
+# pool's dtype, pages a slot, slots, layers
+KERNEL_ROWS = {
+    "olmo_hybrid_30x128_bf16": (30, 128, "bfloat16", 288, 16, 4),
+    "gpt2_xl_25x64_f32": (25, 64, "float32", 64, 4, 48),
+    "grouped_kv_20x64_bf16": (20, 64, "bfloat16", 160, 32, 8),
+}
+
+
+@pytest.mark.parametrize("row", sorted(KERNEL_ROWS))
+def test_paged_kernel_alone_compiles_at_its_callers_rows(one_chip, row):
+    """``_pages_call`` by itself (a kernel compiles in a second or two):
+    Mosaic takes the body at each row shape, the last lane tile of 1,600
+    lanes half full, and nothing the size of a page is set aside in HBM
+    for it (its blocks are VMEM scratch)."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf import attention as A
+    h, d, dtype, perSeq, slots, layers = KERNEL_ROWS[row]
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    C = A._CHUNK_ROWS // PAGE_SIZE
+    places = slots * -(-perSeq // C)
+    pool = sds((layers, 1 + slots * perSeq, PAGE_SIZE, h * d), dtype)
+    compiled = A._pages_call.lower(
+        sds((1,)), sds((places * C,)), sds((places,)), sds((places,)),
+        sds((places,)), sds(()), sds((slots,)), sds((slots,)),
+        sds((slots, 1, h * d), jnp.float32), pool, pool, headSize=d,
+        interpret=False).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < PAGE_SIZE * h * d * pool.dtype.itemsize
 
 
 @pytest.mark.parametrize("bucket", [16, 256])
@@ -288,7 +327,8 @@ def olmo(one_chip):
     import jax.numpy as jnp
     from deeplearning4j_tpu.nlp.olmo_hybrid import (OlmoHybridConfig,
                                                     OlmoHybridLM)
-    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_kernel_kv_passes, paged_kernel_lowerings)
     from deeplearning4j_tpu.remote import KVCachePool
 
     def on_chip(tree):
@@ -310,6 +350,8 @@ def olmo(one_chip):
     step = lm.buildPagedDecodeFn().lower(
         params, *pool, i32(OLMO_SLOTS, 1), i32(OLMO_SLOTS, 1),
         i32(OLMO_SLOTS, perSeq), i32(OLMO_SLOTS), i32(OLMO_SLOTS)).compile()
+    # its bfloat16 pool enters the MXU as it is stored: one pass a tile
+    assert paged_kernel_kv_passes() == 1
     return lm, params, pool, i32, step, paged_kernel_lowerings() - before
 
 
