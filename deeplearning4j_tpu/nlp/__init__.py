@@ -10,6 +10,8 @@ from deeplearning4j_tpu.nlp.tokenization import (BertWordPieceTokenizer,  # noqa
 from deeplearning4j_tpu.nlp.bert_iterator import BertIterator  # noqa: F401
 from deeplearning4j_tpu.nlp.olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig, OlmoHybridLM)
+from deeplearning4j_tpu.nlp.pangu_moe import (  # noqa: F401
+    PanguMoEConfig, PanguMoELM)
 from deeplearning4j_tpu.nlp.sambay import SambaYConfig, SambaYLM  # noqa: F401
 from deeplearning4j_tpu.nlp.transformer import (  # noqa: F401
     TransformerLM, TransformerLMConfig)

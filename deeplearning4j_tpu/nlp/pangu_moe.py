@@ -1,0 +1,489 @@
+"""Pangu-Ultra-MoE LM for the serving tier, as ONE CHIP'S SHARE of an
+expert-parallel deployment: multi-head latent attention (arXiv:2405.04434)
+with rotary positions, a leading dense layer and expert layers of a
+shared expert beside ``nExperts`` routed ones of which this chip holds
+``expertsHeld``, in the sandwich-norm block of Pangu Ultra
+(arXiv:2504.07866, arXiv:2505.04519): ``y = x + RMSNorm_2(Attn(RMSNorm_1
+(x)))``, ``out = y + RMSNorm_4(FFN(RMSNorm_3(y)))``, a final RMSNorm and
+an untied head.
+
+*Attention.*  ``c_q = RMSNorm(h W_dq)``; ``q = c_q W_uq`` in heads of
+``[q_nope | q_rope]``; ``[c_kv | k_r] = h W_dkv`` with ``c_kv =
+RMSNorm(c_kv)`` and ONE rotated ``k_r = RoPE(k_r)`` for all heads;
+``k_nope = c_kv W_uk``, ``v = c_kv W_uv`` a head; ``s = (q_nope . k_nope
++ RoPE(q_rope) . k_r) / sqrt(nope + rope)``.  It has two arithmetic
+forms.  Forward and prefill run it as written (unabsorbed: keys and
+values of every position are formed).  The decode step runs it ABSORBED:
+``q~ = q_nope W_uk^T`` a head, scores ``q~ . c_kv + q_rope . k_r``
+against the cached rows themselves, ``o = (softmax(s) c_kv) W_uv``; so
+what a position keeps is one LATENT ROW ``[c_kv | k_r]`` (after norm and
+rotation), from which keys and values both come: the model's
+``cacheSpec()`` names it, the scheduler's pool holds one array of them,
+and :func:`~deeplearning4j_tpu.nn.conf.attention.paged_latent_attention`
+reads it (on one TPU the kernel over the live pages).
+
+*Rotary positions* pair lane ``i`` with lane ``i + rope / 2`` and turn
+the pair by ``pos * theta^(-2 i / rope)``; a token's position is its
+index among the REAL tokens: in a left-padded bucket ``p - start``, in
+the step ``pos - start``.  A pad has no position and is no key.
+
+*Expert layer.*  The router scores all ``nExperts`` in float32
+(``parallel/moe.py:route_sigmoid_topk``), the ``expertsPerToken``
+largest are chosen, and this chip adds to the shared expert's output the
+part of the chosen experts it HOLDS; what the absent ones would have
+added is left out (the deployment's exchange would bring it), and the
+partial result goes on.  No token is dropped.  The step computes every
+held expert over every slot (``moe_share_dense``); forward and prefill
+sort the pairs by expert and multiply by group (``moe_share_grouped``).
+Three counts of the routing are taken on the device in both
+(:data:`PanguMoELM.stepCounters`) and come back in the columns behind
+the step's tokens.
+
+Precision: weights, residual stream and latent rows in the parameters'
+dtype (bfloat16 as served); router, softmax, norms, rotary angles and
+logits in float32; every matmul accumulates in float32.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+                                                  drop_served_jits,
+                                                  paged_latent_attention,
+                                                  paged_rows_write,
+                                                  paged_step_tokens,
+                                                  served_jit_entries)
+from deeplearning4j_tpu.nlp.olmo_hybrid import _JitByLength, _rms
+from deeplearning4j_tpu.nlp.sambay import _mm
+from deeplearning4j_tpu.parallel.ring import (_FLASH_MIN_T, _flash_refusal,
+                                              flash_attention)
+from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
+                                             moe_share_dense,
+                                             moe_share_grouped,
+                                             route_sigmoid_topk)
+
+__all__ = ["PanguMoEConfig", "PanguMoELM"]
+
+_F32 = jnp.float32
+_I32 = jnp.int32
+_NEG = -1e30
+#: queries a block of the full-sequence attention holds against every
+#: key: 128 heads of float32 scores over 4,096 keys are 0.54 GB a block
+_QUERY_BLOCK = 256
+_COUNTS = ("moe_pairs_routed", "moe_pairs_absent", "moe_experts_hit")
+
+
+@dataclasses.dataclass
+class PanguMoEConfig:
+    vocabSize: int = 256        # rows of the embedding and the head HELD
+    nLayers: int = 3
+    denseLayers: int = 1        # leading layers whose FFN is dense
+    hiddenSize: int = 64
+    nHeads: int = 4
+    qRank: int = 32             # width of c_q
+    kvRank: int = 32            # width of c_kv, the latent
+    nopeDim: int = 16           # a head's q_nope / k_nope
+    ropeDim: int = 8            # q_rope a head; the one k_r
+    vDim: int = 16              # a head's v
+    ffnSize: int = 128          # the dense FFN
+    expertSize: int = 32        # a routed expert's, and the shared one's
+    nExperts: int = 16          # routed experts the router scores
+    expertsPerToken: int = 4
+    expertsHeld: Tuple[int, int] = (0, 4)   # [lo, hi): this chip's share
+    routedScale: float = 2.5
+    ropeTheta: float = 25.6e6
+    eps: float = 1e-5
+    maxLen: int = 128           # positions a slot may hold (bucket + new)
+    initializerRange: float = 0.02
+    seed: int = 0
+    dtype: str = "bfloat16"
+
+    @property
+    def nHeld(self) -> int:
+        return self.expertsHeld[1] - self.expertsHeld[0]
+
+
+def _rope(x, pos, theta: float):
+    """``x (..., D)`` float32 turned by ``pos (...)``: lane ``i`` pairs
+    with lane ``i + D / 2``, angle ``pos * theta^(-2 i / D)``."""
+    half = x.shape[-1] // 2
+    inv = jnp.asarray(theta ** (-np.arange(half) / half), _F32)
+    ang = pos[..., None].astype(_F32) * inv
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * c - b * s, b * c + a * s], axis=-1)
+
+
+class PanguMoELM:
+    """The served model: ``forward`` (the recompute baseline), a bucketed
+    left-padded ``prefillRaw`` that also returns the latent rows and the
+    routing's counts, and the scheduler's fixed-shape decode step and
+    admission write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``,
+    the hooks the other served models have)."""
+
+    #: what the step returns in the columns behind its tokens (row 0),
+    #: for the batcher to add to ``serving_metrics()``: its own counts of
+    #: the routing, then those of the prefills since the step before
+    stepCounters = tuple((name, {"phase": phase})
+                         for phase in ("step", "prefill")
+                         for name in _COUNTS)
+
+    def __init__(self, config: Optional[PanguMoEConfig] = None,
+                 params=None, **kw):
+        self.config = c = config or PanguMoEConfig(**kw)
+        lo, hi = c.expertsHeld
+        if not 0 <= lo < hi <= c.nExperts or c.ropeDim % 2:
+            raise ValueError(
+                f"expertsHeld {c.expertsHeld} names no share of "
+                f"{c.nExperts} experts, or ropeDim {c.ropeDim} is odd")
+        self.params = params if params is not None else self._init_params()
+
+    # ------------------------------------------------------------------
+    def _init_params(self) -> Dict:
+        """Seeded weights drawn ON THE DEVICE in the configured dtype, one
+        small program per kind of layer; only the held experts exist."""
+        c = self.config
+        dt = jnp.dtype(c.dtype)
+        d, H, f, n = c.hiddenSize, c.nHeads, c.expertSize, c.nHeld
+        std = c.initializerRange
+
+        @functools.partial(jax.jit, static_argnames=("dense",))
+        def layer(key, dense):
+            keys = iter(jax.random.split(key, 20))
+            normal = lambda *shape: (std * jax.random.normal(
+                next(keys), shape, _F32)).astype(dt)
+            ones = lambda n: jnp.ones((n,), dt)
+            p = {"norm1": ones(d), "norm2": ones(d), "norm3": ones(d),
+                 "norm4": ones(d), "qnorm": ones(c.qRank),
+                 "kvnorm": ones(c.kvRank),
+                 "Wdq": normal(d, c.qRank),
+                 "Wuq": normal(c.qRank, H * (c.nopeDim + c.ropeDim)),
+                 "Wdkv": normal(d, c.kvRank + c.ropeDim),
+                 # head-major, as the step's matmuls a head read them:
+                 # W_uk (rank, nope) and W_uv^T (v, rank) a head, each
+                 # contracted over its minor dimension
+                 "Wuk": normal(H, c.kvRank, c.nopeDim),
+                 "Wuv": normal(H, c.vDim, c.kvRank),
+                 "Wo": normal(H * c.vDim, d)}
+            if dense:
+                p.update(Wgate=normal(d, c.ffnSize), Wup=normal(d, c.ffnSize),
+                         Wdown=normal(c.ffnSize, d))
+            else:
+                p.update(Wr=normal(d, c.nExperts),
+                         Sgate=normal(d, f), Sup=normal(d, f),
+                         Sdown=normal(f, d), Eg=normal(n, d, f),
+                         Eu=normal(n, d, f), Ed=normal(n, f, d))
+            return p
+
+        @jax.jit
+        def ends(key):
+            ke, kh = jax.random.split(key)
+            return ((std * jax.random.normal(ke, (c.vocabSize, d), _F32)
+                     ).astype(dt),
+                    (std * jax.random.normal(kh, (d, c.vocabSize), _F32)
+                     ).astype(dt))
+
+        key = jax.random.PRNGKey(c.seed)
+        emb, head = ends(jax.random.fold_in(key, 0))
+        return {"emb": emb, "head": head, "normf": jnp.ones((d,), dt),
+                "layers": [layer(jax.random.fold_in(key, i + 1),
+                                 i < c.denseLayers)
+                           for i in range(c.nLayers)]}
+
+    # ------------------------------------------------------------------
+    def cacheSpec(self) -> CacheSpec:
+        """What each layer keeps between steps, for the scheduler's pool:
+        one latent row a position in every layer (no V pool), and beside
+        them the counts of the routing that the prefills leave for the
+        next step to return."""
+        c = self.config
+        return CacheSpec(
+            pagedLayers=c.nLayers, kvHeads=1, headSize=c.kvRank + c.ropeDim,
+            dtype=jnp.dtype(c.dtype), latentWidth=c.kvRank,
+            ropeWidth=c.ropeDim,
+            slotState=(("routing", (1, len(_COUNTS)), _I32),))
+
+    # -- pieces shared by the full-sequence and the step forms ----------
+    def _queries(self, lp, h, p):
+        """``(q_nope, RoPE(q_rope))`` ``(..., H, nope)``, ``(..., H,
+        rope)`` float32 from ``h (..., d)`` at positions ``p (...)``."""
+        c = self.config
+        q = _mm(_rms(_mm(h, lp["Wdq"]), lp["qnorm"], c.eps), lp["Wuq"])
+        q = q.reshape(q.shape[:-1] + (c.nHeads, c.nopeDim + c.ropeDim))
+        return q[..., :c.nopeDim], _rope(q[..., c.nopeDim:], p[..., None],
+                                         c.ropeTheta)
+
+    def _row_wide(self, a):
+        """``a (..., latent + rope)`` with zeros behind, to the width of a
+        stored row (whole lane tiles)."""
+        pad = self.cacheSpec().rowWidth - a.shape[-1]
+        return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
+
+    def _latent_row(self, lp, h, p, dtype):
+        """The row a position keeps, as it is stored: ``[RMSNorm(c_kv) |
+        RoPE(k_r) | zeros to whole lane tiles]``."""
+        c = self.config
+        ckr = _mm(h, lp["Wdkv"])
+        return self._row_wide(jnp.concatenate(
+            [_rms(ckr[..., :c.kvRank], lp["kvnorm"], c.eps),
+             _rope(ckr[..., c.kvRank:], p, c.ropeTheta)], axis=-1)
+        ).astype(dtype)
+
+    def _ffn(self, lp, h, real, grouped: bool):
+        """``(FFN(h), counts)`` for ``h (T, d)`` float32: the dense FFN,
+        or the shared expert plus this chip's part of the routed ones;
+        ``counts`` of the routing over the ``real (T,)`` tokens (zeros
+        for a dense layer)."""
+        c = self.config
+        gated = lambda g, u, dn: _mm(
+            jax.nn.silu(_mm(h, lp[g])) * _mm(h, lp[u]), lp[dn])
+        if "Wgate" in lp:
+            return gated("Wgate", "Wup", "Wdown"), \
+                jnp.zeros((len(_COUNTS),), _I32)
+        lo = c.expertsHeld[0]
+        idx, w = route_sigmoid_topk(h, lp["Wr"], c.expertsPerToken,
+                                    c.routedScale)
+        experts = (lp["Eg"], lp["Eu"], lp["Ed"], lo)
+        routed = moe_share_grouped(h, idx, w, *experts, real) if grouped \
+            else moe_share_dense(h, idx, w, *experts)
+        return gated("Sgate", "Sup", "Sdown") + routed, \
+            moe_share_counts(idx, lo, c.nHeld, real)
+
+    def _logits(self, params, x):
+        return _mm(_rms(x, params["normf"], self.config.eps), params["head"])
+
+    # ------------------------------------------------------------------
+    # full-sequence form: forward and prefill (attention unabsorbed)
+    # ------------------------------------------------------------------
+    def _attend_full(self, qn, qr, kn, kr, v, start):
+        """Causal softmax attention over whole sequences with every key
+        and value formed: ``qn (b, T, H, nope)``, ``qr (b, T, H, rope)``
+        float32; ``kn (b, T, H, nope)``, ``kr (b, T, rope)`` (one for all
+        heads), ``v (b, T, H, vDim)`` in the stream's dtype.  A block of
+        queries at a time against every key; no key before ``start`` is
+        valid."""
+        c = self.config
+        b, T = qn.shape[:2]
+        cd = v.dtype
+        if T >= _FLASH_MIN_T and _flash_refusal(T, T) is None:
+            return self._attend_flash(qn, qr, kn, kr, v, start)
+        B = _QUERY_BLOCK if T % _QUERY_BLOCK == 0 else T
+        qn, qr = qn.astype(cd), qr.astype(cd)
+        kpos = jnp.arange(T, dtype=_I32)[None, None, :]
+        real = kpos >= start[:, None, None]                  # (b, 1, T)
+        scale = (c.nopeDim + c.ropeDim) ** -0.5
+
+        def block(i):
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * B, B, axis=1)
+            s = jnp.einsum("bqhd,bkhd->bhqk", cut(qn), kn,
+                           preferred_element_type=_F32) \
+                + jnp.einsum("bqhd,bkd->bhqk", cut(qr), kr,
+                             preferred_element_type=_F32)
+            rows = i * B + jnp.arange(B, dtype=_I32)
+            valid = (kpos <= rows[None, :, None]) & real     # (b, B, T)
+            a = jax.nn.softmax(jnp.where(valid[:, None], s * scale, _NEG),
+                               axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", a.astype(cd), v,
+                              preferred_element_type=_F32)
+        o = jax.lax.map(block, jnp.arange(T // B, dtype=_I32))
+        return jnp.moveaxis(o, 0, 1).reshape(b, T, c.nHeads * c.vDim)
+
+    def _attend_flash(self, qn, qr, kn, kr, v, start, interpret=False):
+        """:meth:`_attend_full` through the flash kernel
+        (``parallel/ring.py``), which holds no score outside VMEM and
+        skips the blocks above the diagonal; chosen as the attention
+        layers choose it: on a TPU, from 1,024 positions, at lengths its
+        blocks divide.  The kernel is causal and takes no key mask, so
+        every sequence is turned until its real tokens come FIRST and its
+        pads lie behind them, where no real query looks; the output is
+        turned back.  Heads lead, the lanes are padded to whole tiles
+        (192 -> 256 for queries and keys, 128 -> 256 for values) and the
+        queries carry the difference between the kernel's scale
+        (lanes^-1/2) and the model's.  ``interpret`` is for tests."""
+        c = self.config
+        b, T, H, _ = qn.shape
+        cd = v.dtype
+        d = c.nopeDim + c.ropeDim
+        D = -(-d // 128) * 128
+        turn = jax.vmap(lambda a, by: jnp.roll(a, by, axis=0))
+
+        def laid(a):
+            a = jnp.pad(a.astype(cd), ((0, 0),) * 3 + ((0, D - a.shape[-1]),))
+            return turn(a, -start).transpose(0, 2, 1, 3)     # (b, H, T, D)
+        q = jnp.concatenate([qn, qr], axis=-1) * (D / d) ** 0.5
+        k = jnp.concatenate([kn, jnp.broadcast_to(
+            kr[:, :, None], (b, T, H, c.ropeDim))], axis=-1)
+        o = flash_attention(laid(q), laid(k), laid(v), causal=True,
+                            interpret=interpret)[..., :c.vDim]
+        return turn(o.transpose(0, 2, 1, 3), start).reshape(
+            b, T, H * c.vDim).astype(_F32)
+
+    def _run_full(self, params, tokens, start):
+        """``tokens (b, T)`` LEFT-padded, ``start (b,)`` the first real
+        position.  Returns the last layer's output, every layer's latent
+        rows as the step will read them ``(L, b, 1, T, W)`` and the
+        routing's counts over the real tokens ``(3,)``."""
+        c = self.config
+        b, T = tokens.shape
+        H = c.nHeads
+        at = jnp.arange(T, dtype=_I32)[None, :]
+        real = at >= start[:, None]                          # (b, T)
+        p = jnp.maximum(at - start[:, None], 0)
+        x = params["emb"][tokens]
+        cd = x.dtype
+        rows = jnp.zeros((c.nLayers, b, 1, T, self.cacheSpec().rowWidth), cd)
+        counts = jnp.zeros((len(_COUNTS),), _I32)
+        hold = jax.lax.optimization_barrier
+        for li, lp in enumerate(params["layers"]):
+            h = _rms(x, lp["norm1"], c.eps)
+            qn, qr = self._queries(lp, h, p)
+            row = self._latent_row(lp, h, p, cd)
+            rows = rows.at[li, :, 0].set(row)
+            ckv, kr = row[..., :c.kvRank], row[..., c.kvRank:c.kvRank
+                                               + c.ropeDim]
+            heads = lambda eq, W: jnp.einsum(
+                eq, ckv, W, preferred_element_type=_F32).astype(cd)
+            o = self._attend_full(
+                qn, qr, heads("btr,hrd->bthd", lp["Wuk"]), kr,
+                heads("btr,hdr->bthd", lp["Wuv"]), start)
+            # the stream is written out after every add (see
+            # OlmoHybridLM._run_full)
+            y = hold(x + _rms(_mm(o, lp["Wo"]), lp["norm2"],
+                              c.eps).astype(cd))
+            ff, n = self._ffn(lp, _rms(y, lp["norm3"], c.eps
+                                       ).reshape(b * T, -1),
+                              real.reshape(-1), grouped=True)
+            counts = counts + n
+            x = hold(y + _rms(ff, lp["norm4"], c.eps
+                              ).reshape(b, T, -1).astype(cd))
+        return x, rows, counts
+
+    @functools.cached_property
+    def _fwd(self):
+        def run(params, tokens):
+            start = jnp.zeros((tokens.shape[0],), _I32)
+            return self._logits(params, self._run_full(params, tokens,
+                                                       start)[0])
+        return jax.jit(run)
+
+    def forward(self, tokens) -> jax.Array:
+        """Full causal forward: (b, t) int32 -> (b, t, vocab) float32."""
+        return self._fwd(self.params, jnp.asarray(tokens, _I32))
+
+    @functools.cached_property
+    def _prefillRawFn(self):
+        def run(params, tokens, start):
+            x, rows, counts = self._run_full(params, tokens, start)
+            b = tokens.shape[0]
+            # the counts ride as slot state: (1 layer, b, 3), the batch
+            # row's own where there is one row (the scheduler's case)
+            return (self._logits(params, x[:, -1]), rows,
+                    jnp.broadcast_to(counts, (1, b) + counts.shape))
+        return _JitByLength(run, "prefill")
+
+    def prefillRaw(self, tokens, lengths=None):
+        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
+        rowStack, counts)``: the latent rows in
+        :func:`paged_rows_write`'s form ``(layers, b, 1, t, W)`` and the
+        routing's counts ``(1, b, 3)`` in the pool's order (the whole
+        batch's in every row: the scheduler prefills one sequence at a
+        time).  One executable per prompt bucket."""
+        tokens = jnp.asarray(tokens, _I32)
+        t = tokens.shape[1]
+        if t > self.config.maxLen:
+            raise ValueError(f"prompt length {t} exceeds the capacity "
+                             f"{self.config.maxLen}")
+        if lengths is None:
+            start = jnp.zeros((tokens.shape[0],), _I32)
+        else:
+            start = t - jnp.asarray(lengths, _I32)
+        return self._prefillRawFn(self.params, tokens, start)
+
+    # ------------------------------------------------------------------
+    # step form — the continuous-batching scheduler's executables
+    # ------------------------------------------------------------------
+    def pagedLogits(self, params, rows, routing, toks, pageTable, pos,
+                    start):
+        """One token per slot (``toks (S, 1)``) against the pool's
+        arrays, attention ABSORBED: ``((S, 1, vocab) logits, rows,
+        routing, counts (6,))``.  A slot whose ``pos`` is 0 holds no
+        sequence (or is deferred a round): its row lands on the scratch
+        page through its zeroed page table and it is not counted.
+        ``counts`` are this step's three counts of the routing, then the
+        three that the prefills since the last step left in ``routing``,
+        which comes back zeroed."""
+        c = self.config
+        S, tq = toks.shape
+        if tq != 1:
+            raise ValueError(
+                "the step takes one token a slot: speculative "
+                "verification (tq > 1) would need a position a query")
+        H, R = c.nHeads, c.kvRank
+        active = pos > 0
+        p = jnp.maximum(pos - start, 0)
+        x = params["emb"][toks[:, 0]]                         # (S, d)
+        cd = x.dtype
+        counts = jnp.zeros((len(_COUNTS),), _I32)
+        for li, lp in enumerate(params["layers"]):
+            h = _rms(x, lp["norm1"], c.eps)
+            qn, qr = self._queries(lp, h, p)                  # (S, H, .)
+            # q~ = q_nope W_uk^T a head: the query in the latent's lanes
+            qa = jnp.einsum("shd,hrd->shr", qn.astype(cd), lp["Wuk"],
+                            preferred_element_type=_F32)
+            qh = self._row_wide(jnp.concatenate([qa, qr], axis=-1))
+            ctx, rows = paged_latent_attention(
+                qh[:, :, None], self._latent_row(lp, h, p, cd)[:, None],
+                rows, li, pageTable, pos, start, valueWidth=R,
+                scale=(c.nopeDim + c.ropeDim) ** -0.5)
+            o = jnp.einsum("shr,hdr->shd", ctx[:, :, 0].astype(cd),
+                           lp["Wuv"], preferred_element_type=_F32)
+            y = x + _rms(_mm(o.reshape(S, H * c.vDim), lp["Wo"]),
+                         lp["norm2"], c.eps).astype(cd)
+            ff, n = self._ffn(lp, _rms(y, lp["norm3"], c.eps), active,
+                              grouped=False)
+            counts = counts + n
+            x = y + _rms(ff, lp["norm4"], c.eps).astype(cd)
+        left = jnp.sum(routing, axis=(0, 1)).astype(_I32)
+        return (self._logits(params, x)[:, None], rows,
+                jnp.zeros_like(routing), jnp.concatenate([counts, left]))
+
+    def buildPagedDecodeFn(self):
+        """FRESH jitted decode step over the pool's arrays: ``(params,
+        rows, routing, toks (S, 1), prev, pageTable, pos, start) ->
+        (out (S, 1 + 6), rows, routing)``.  Column 0 of ``out`` is the
+        greedy token a slot; the columns behind it hold, in row 0, the
+        counts :data:`stepCounters` names.  ``prev`` is the step before's
+        ``out`` (its first column is read), a slot whose ``toks`` is -1
+        takes it; both arrays are DONATED; a fresh identity per build,
+        all as ``TransformerLM.buildPagedDecodeFn`` explains."""
+        def step(params, rows, routing, toks, prev, pageTable, pos, start):
+            logits, rows, routing, counts = self.pagedLogits(
+                params, rows, routing, paged_step_tokens(toks, prev[:, :1]),
+                pageTable, pos, start)
+            tok = jnp.argmax(logits, axis=-1).astype(_I32)    # (S, 1)
+            tail = jnp.zeros((tok.shape[0], counts.shape[0]), _I32
+                             ).at[0].set(counts)
+            return jnp.concatenate([tok, tail], axis=1), rows, routing
+        return jax.jit(step, donate_argnums=(1, 2))
+
+    def buildPagedPrefillWriteFn(self):
+        """FRESH jitted admission write: one sequence's latent rows
+        (:meth:`prefillRaw`'s, batch row taken) into the pages
+        ``pageIds``, and its prefill's counts ADDED to slot ``slot``'s
+        row of ``routing`` (the next step returns and clears them)."""
+        def write(rows, routing, rowStack, counts, pageIds, slot):
+            return (paged_rows_write(rows, rowStack, pageIds),
+                    routing.at[:, slot].add(counts))
+        return jax.jit(write, donate_argnums=(0, 1))
+
+    def compileCacheSize(self) -> int:
+        return served_jit_entries(self)
+
+    def dropCompiled(self) -> None:
+        drop_served_jits(self)

@@ -34,7 +34,8 @@ __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
            "paged_attention", "paged_kernel_lowerings",
            "paged_kernel_kv_passes",
-           "paged_prefill_write", "paged_step_tokens",
+           "paged_latent_attention", "paged_prefill_write",
+           "paged_rows_write", "paged_step_tokens",
            "CacheSpec", "served_jit_entries", "drop_served_jits"]
 
 
@@ -440,17 +441,22 @@ def paged_kernel_kv_passes() -> int:
     return _kernelLowerings[1]
 
 
-def _attend_lowering(ctx, *args, li):
+def _lowered_as_kernel(ctx, poolDtype) -> bool:
     """Choose by what the program is lowered for, not by a knob: one TPU
     -> the kernel; the CPU, or several devices (a pool whose lanes are
     split over a mesh, which a Mosaic kernel cannot be partitioned over)
-    -> the gathered reference."""
+    -> the gathered reference.  Counts a kernel lowering."""
     mc = ctx.module_context
     kernel = tuple(mc.platforms) == ("tpu",) and \
         getattr(mc.axis_context, "num_devices", None) == 1
     if kernel:
         _kernelLowerings[0] += 1
-        _kernelLowerings[1] = _mxu_parts(ctx.avals_in[1].dtype)
+        _kernelLowerings[1] = _mxu_parts(poolDtype)
+    return kernel
+
+
+def _attend_lowering(ctx, *args, li):
+    kernel = _lowered_as_kernel(ctx, ctx.avals_in[1].dtype)
     return mlir.lower_fun(
         functools.partial(_attend_pages if kernel else _attend_gathered,
                           li=li), multiple_results=False)(ctx, *args)
@@ -471,6 +477,212 @@ _attend_p.def_abstract_eval(
 mlir.register_lowering(_attend_p, _attend_lowering)
 
 
+# -- the latent form: one row a position, key and value at once --------
+
+def paged_latent_attention(qh, rowNew, pool, li, pageTable, pos, start, *,
+                           valueWidth, scale):
+    """:func:`paged_attention` where a position keeps ONE row that all
+    query heads read, as key and as value (``CacheSpec.latentWidth``):
+    the cache of multi-head latent attention in its absorbed form.
+
+    - ``qh`` (slots, heads, tq, W): each head's query in the row's own
+      ``W`` lanes (its up-projection folded into it, the rotated part
+      behind, zeros in the row's padding);
+    - ``rowNew`` (slots, tq, W): the new positions' rows;
+    - ``pool`` (nLayers, numPages, pageSize, W): the one stacked pool;
+    - ``li``, ``pageTable``, ``pos``, ``start`` as in
+      :func:`paged_attention`; ``scale`` multiplies the scores.
+
+    Scores are taken over all ``W`` lanes, the context over the first
+    ``valueWidth``: ``(ctx (slots, heads, tq, valueWidth) float32,
+    newPool)``.  Queries and softmax weights enter the matmuls in the
+    pool's dtype, as the rows do; the softmax itself is float32.  Lowered
+    like :func:`paged_attention`: for one TPU the kernel
+    (:func:`_attend_latent_pages`) over the same work list of live
+    chunks, one page copy serving keys and values; elsewhere the
+    gathered reference (:func:`_attend_latent_gathered`)."""
+    S, h, tq, W = qh.shape
+    pageSize = pool.shape[2]
+    wpos = pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    phys = jnp.take_along_axis(pageTable, wpos // pageSize, axis=1)
+    pool = pool.at[li, phys, wpos % pageSize].set(rowNew.astype(pool.dtype))
+    q = (qh.astype(jnp.float32) * jnp.float32(scale)).astype(pool.dtype)
+    ctx = _attend_latent_p.bind(q, pool, pageTable, pos, start, li=li,
+                                valueWidth=int(valueWidth))
+    return ctx, pool
+
+
+def _attend_latent_gathered(q, pool, pageTable, pos, start, *, li,
+                            valueWidth):
+    """The reference formulation of the latent read: every slot's pages
+    gathered in logical order ((S, capacity, W)), scores, softmax and
+    context over the whole capacity under the validity mask."""
+    S, h, tq, W = q.shape
+    f32 = jnp.float32
+    cap = pageTable.shape[1] * pool.shape[2]
+    wpos = pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    rows = pool[li, pageTable].reshape(S, cap, W).astype(f32)
+    kpos = jnp.arange(cap, dtype=jnp.int32)
+    valid = (kpos[None, None, :] <= wpos[:, :, None]) & \
+        (kpos[None, None, :] >= start[:, None, None])        # (S, tq, cap)
+    s = jnp.einsum("bhqw,bkw->bhqk", q.astype(f32), rows)
+    w = jax.nn.softmax(jnp.where(valid[:, None], s, f32(_NEG)), axis=-1)
+    return jnp.einsum("bhqk,bkv->bhqv", w.astype(pool.dtype).astype(f32),
+                      rows[..., :valueWidth])
+
+
+#: rows of the latent pool a place of the grid works on (see
+#: :data:`_CHUNK_ROWS`).  A latent row is a sixth of Olmo-Hybrid's K and V
+#: together, so a chunk of 128 would be 0.16 MB, a fifth of a microsecond
+#: of copies under a place's own third of one
+_LATENT_CHUNK_ROWS = 512
+
+
+def _mxu_dot(a, b, dims):
+    """``dot_general(a, b)`` on the MXU with every bit of both operands:
+    one bfloat16 pass for each pair of their :func:`_bf16_parts` (one
+    pass for bfloat16 operands, nine for float32), summed in float32."""
+    bf16 = jnp.bfloat16
+    return functools.reduce(operator.add, (
+        jax.lax.dot_general(x.astype(bf16), y.astype(bf16), dims,
+                            preferred_element_type=jnp.float32)
+        for x in _bf16_parts(a) for y in _bf16_parts(b)))
+
+
+def _latent_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
+                   start_ref, q_ref, *refs, C, ps, tq, vw):
+    """One place of the grid: ``C`` pages of one slot's latent rows
+    (``r_refs``, each ``(ps, W)``, copied in by the pipeline while the
+    place before computes) and all heads of that slot's ``tq`` queries
+    as the ROWS of one operand (``q_ref (tq * heads, W)``): every head
+    reads the same rows, so a chunk's scores are one matmul ``(tq *
+    heads, W) x (R, W)^T`` and its context one ``(tq * heads, R) x (R,
+    vw)`` over the first ``vw`` lanes of the same copy.  Softmax online
+    across the slot's chunks, in float32."""
+    f32 = jnp.float32
+    r_refs = refs[:C]
+    o_ref, m_ref, l_ref, acc_ref = refs[C:]
+    w = pl.program_id(0)
+    R = C * ps
+    N = q_ref.shape[0]
+    flag = flag_ref[w]
+
+    @pl.when((flag & 1) != 0)
+    def _():                        # a slot's pass opens
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    rows = jnp.concatenate([r[...] for r in r_refs], axis=0)     # (R, W)
+    sc = _mxu_dot(q_ref[...], rows, (((1,), (1,)), ((), ())))    # (N, R)
+    s = slot_ref[w]
+    j = j0_ref[w] + jax.lax.broadcasted_iota(jnp.int32, (N, R), 1)
+    last = pos_ref[s]
+    if tq > 1:                      # row n is query n // heads
+        last = last + jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (N, R), 0),
+            jnp.int32(N // tq))
+    valid = (j >= start_ref[s]) & (j <= last)
+    sc = jnp.where(valid, sc, f32(_NEG))
+    mOld = m_ref[...]                                            # (N, 1)
+    mNew = jnp.maximum(mOld, jnp.max(sc, axis=-1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(sc - mNew), f32(0))
+    shrink = jnp.exp(mOld - mNew)
+    l_ref[...] = shrink * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = mNew
+    acc_ref[...] = shrink * acc_ref[...] + _mxu_dot(
+        p.astype(rows.dtype), rows[:, 0:vw], (((1,), (0,)), ((), ())))
+
+    @pl.when((flag & 2) != 0)
+    def _():                        # and closes
+        o_ref[...] = acc_ref[...] / l_ref[...]
+
+
+def _attend_latent_pages(q, pool, pageTable, pos, start, *, li, valueWidth,
+                         interpret=False):
+    """The latent read as a Pallas TPU kernel, one call a layer, over
+    :func:`_work_list`'s live chunks: only the pages that hold live rows
+    of a slot are read, each once, for keys and values.  ``interpret`` is
+    for tests (the CPU)."""
+    S, h, tq, W = q.shape
+    ps = pool.shape[2]
+    i32 = jnp.int32
+    pos, start = pos.astype(i32), start.astype(i32)
+    work = _work_list(
+        pageTable.astype(i32), pos, start, tq=tq, pageSize=ps,
+        C=max(1, min(_LATENT_CHUNK_ROWS // ps, pageTable.shape[1])))
+    out = _latent_call(
+        jnp.full((1,), li, i32), *work, pos, start,
+        q.transpose(0, 2, 1, 3).reshape(S, tq * h, W), pool,
+        valueWidth=valueWidth, tq=tq, interpret=interpret)
+    return out.reshape(S, tq, h, valueWidth).transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("valueWidth", "tq", "interpret"))
+def _latent_call(li, tbl, slot, j0, flag, total, pos, start, q, pool, *,
+                 valueWidth, tq, interpret):
+    """The latent kernel's call: :func:`_pages_call`'s grid, work list
+    and page blocks, with one pool and one set of ``C`` page buffers."""
+    S, N, W = q.shape
+    ps = pool.shape[2]
+    C = tbl.shape[0] // slot.shape[0]
+    f32 = jnp.float32
+
+    def page_spec(c):
+        return pl.BlockSpec(
+            (None, None, ps, W),
+            lambda w, li, tbl, *_: (li[0], tbl[w * C + c], w * 0, w * 0))
+
+    def row_spec(width):
+        return pl.BlockSpec(
+            (None, N, width),
+            lambda w, li, tbl, slot, *_: (slot[w], w * 0, w * 0))
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, C=C, ps=ps, tq=tq, vw=valueWidth),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(total,),
+            in_specs=[row_spec(W)] + [page_spec(c) for c in range(C)],
+            out_specs=row_spec(valueWidth),
+            scratch_shapes=[
+                pltpu.VMEM((N, 1), f32),             # running max
+                pltpu.VMEM((N, 1), f32),             # running sum
+                pltpu.VMEM((N, valueWidth), f32),    # context
+            ]),
+        out_shape=jax.ShapeDtypeStruct((S, N, valueWidth), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        name="paged_latent_attention",
+        interpret=interpret,
+    )(li, tbl, slot, j0, flag, pos, start, q, *([pool] * C))
+
+
+def _attend_latent_lowering(ctx, *args, li, valueWidth):
+    kernel = _lowered_as_kernel(ctx, ctx.avals_in[1].dtype)
+    return mlir.lower_fun(
+        functools.partial(
+            _attend_latent_pages if kernel else _attend_latent_gathered,
+            li=li, valueWidth=valueWidth),
+        multiple_results=False)(ctx, *args)
+
+
+_attend_latent_p = jex_core.Primitive("paged_attend_latent")
+
+
+@functools.partial(jax.jit, static_argnames=("li", "valueWidth"))
+def _attend_latent_eager(*args, li, valueWidth):
+    return _attend_latent_p.bind(*args, li=li, valueWidth=valueWidth)
+
+
+_attend_latent_p.def_impl(_attend_latent_eager)
+_attend_latent_p.def_abstract_eval(
+    lambda q, *_, li, valueWidth: jax.core.ShapedArray(
+        q.shape[:-1] + (valueWidth,), jnp.float32))
+mlir.register_lowering(_attend_latent_p, _attend_latent_lowering)
+
+
 @dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What a served model's layers keep between decode steps — the
@@ -478,10 +690,19 @@ class CacheSpec:
     ``cacheSpec()``; the pool allocates exactly this and nothing selects
     between layouts.  Three kinds of state:
 
-    - *paged*: ``pagedLayers`` layers own K/V pages that grow with the
-      sequence, rows ``kvHeads * headSize`` wide (written by their layer,
-      readable by others).  A GPT-style stack is the case "every layer
-      paged";
+    - *paged*: ``pagedLayers`` layers own pages that grow with the
+      sequence, one row a position, ``rowWidth`` lanes wide (written by
+      their layer, readable by others).  A row is one of two things.
+      *Keys and values*: ``kvHeads`` heads of ``headSize`` side by side,
+      in a K pool and a V pool of the same shape (a GPT-style stack is
+      the case "every layer paged").  Or a *latent* row
+      (``latentWidth > 0``): ONE pool and no V, a row of ``latentWidth``
+      lanes that every query head reads both as the bulk of its key and,
+      the same lanes again, as its value, followed by ``ropeWidth`` lanes
+      that only the keys have (the one rotated key all heads share); the
+      row is stored in whole lane tiles of 128, zeros behind the
+      ``latentWidth + ropeWidth`` that mean something
+      (:func:`paged_latent_attention`);
     - *ring*: ``ringLayers`` layers keep the last ``ringRows`` K/V rows
       of every slot, written modulo ``ringRows``;
     - *recurrent*: ``slotState`` names fixed-size arrays ``(name,
@@ -495,25 +716,46 @@ class CacheSpec:
     ringLayers: int = 0
     ringRows: int = 0
     slotState: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
+    latentWidth: int = 0
+    ropeWidth: int = 0
 
     @property
     def rowWidth(self) -> int:
+        """Lanes of one stored row of a paged (or ring) layer: the one
+        definition the pool, its sharding and its byte counts read."""
+        if self.latentWidth:
+            return -(-(self.latentWidth + self.ropeWidth) // 128) * 128
         return self.kvHeads * self.headSize
+
+    @property
+    def splitHeads(self) -> int:
+        """Equal parts of a row's lanes that a tensor-parallel mesh may
+        put on different devices: the heads; a latent row, which every
+        head reads whole, is one."""
+        return 1 if self.latentWidth else self.kvHeads
+
+    @property
+    def pagedPools(self) -> int:
+        """Arrays a paged layer's rows live in: K and V, or the one
+        latent pool."""
+        return 1 if self.latentWidth else 2
+
+
+def paged_rows_write(pool, stack, pageIds):
+    """Copy one sequence's stacked prefill rows ((L, h, Tp, d), ``Tp`` a
+    page multiple) into the pages of a token-major pool ((L, numPages,
+    pageSize, h*d)) named by ``pageIds`` ((Tp/pageSize,) int32)."""
+    L, h, Tp, d = stack.shape
+    ps = pool.shape[2]
+    return pool.at[:, pageIds].set(stack.transpose(0, 2, 1, 3).reshape(
+        L, Tp // ps, ps, h * d).astype(pool.dtype))
 
 
 def paged_prefill_write(poolK, poolV, kStack, vStack, pageIds):
-    """Copy one sequence's stacked prefill K/V ((L, h, Tp, d), ``Tp`` a
-    page multiple) into the pages of the token-major pools ((L,
-    numPages, pageSize, h*d), see :func:`paged_attention`) named by
-    ``pageIds`` ((Tp/pageSize,) int32).  Returns the two pools."""
-    L, h, Tp, d = kStack.shape
-    ps = poolK.shape[2]
-
-    def pages(stack, pool):
-        return stack.transpose(0, 2, 1, 3).reshape(
-            L, Tp // ps, ps, h * d).astype(pool.dtype)
-    return (poolK.at[:, pageIds].set(pages(kStack, poolK)),
-            poolV.at[:, pageIds].set(pages(vStack, poolV)))
+    """:func:`paged_rows_write` for the K and the V pool of
+    :func:`paged_attention`.  Returns the two pools."""
+    return (paged_rows_write(poolK, kStack, pageIds),
+            paged_rows_write(poolV, vStack, pageIds))
 
 
 def paged_step_tokens(toks, prev):

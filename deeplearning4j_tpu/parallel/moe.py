@@ -16,6 +16,18 @@ capability, built the TPU way:
   large for GSPMD's dense dispatch to keep weights resident.
 
 Auxiliary load-balancing loss follows Switch (mean fraction * mean prob).
+
+The SERVED expert layer is a third thing: one chip's share of an
+expert-parallel deployment, without the exchange.  It is told which
+experts it holds, routes every token over ALL experts
+(:func:`route_sigmoid_topk`), computes the part of the layer's output
+that its own experts contribute and leaves the rest out; no capacity
+factor, so no token is ever dropped.  Two forms of the same sum:
+:func:`moe_share_dense` (every held expert over every token: a decode
+step, where the experts' bytes bound the time whatever the form) and
+:func:`moe_share_grouped` (pairs sorted by expert, one grouped matmul a
+projection: a prefill, where all-over-all would be 16 times the work).
+:func:`moe_share_counts` counts what was routed where.
 """
 from __future__ import annotations
 
@@ -31,7 +43,8 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_expert_parallel",
-           "MoELayer", "MoEFeedForwardLayer"]
+           "MoELayer", "MoEFeedForwardLayer", "route_sigmoid_topk",
+           "moe_share_dense", "moe_share_grouped", "moe_share_counts"]
 
 
 def init_moe(key, n_experts: int, d_in: int, d_hidden: int, d_out: int,
@@ -147,6 +160,110 @@ def moe_apply_expert_parallel(mesh, params, x, capacity_factor: float = 1.25,
                        in_specs=(pspec, P("data")),
                        out_specs=(P("data"), P()), check_vma=False)
     return fn(params, x)
+
+
+# -- one chip's share of an expert-parallel layer (the serving tier) -----
+
+def route_sigmoid_topk(x, Wr, k: int, scale: float):
+    """Sigmoid gate over ALL experts, in float32: ``x (T, d)``, ``Wr (d,
+    E)`` -> the ``k`` largest of ``sigmoid(x Wr)`` as ``(idx (T, k)
+    int32, w (T, k))`` with ``w = g / (sum of the k + 1e-20) * scale``.
+    The matmul is float32 at ``HIGHEST``: which expert comes eighth is
+    decided by differences a bfloat16 pass would not see."""
+    g = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), Wr.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    top, idx = lax.top_k(g, k)
+    w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx.astype(jnp.int32), w
+
+
+def _held(idx, lo: int, n: int, real):
+    """``(idx - lo, held here)`` for the chosen experts ``idx (T, k)`` of
+    the real tokens ``real (T,)``."""
+    e = idx - lo
+    return e, (e >= 0) & (e < n) & real[:, None]
+
+
+def moe_share_counts(idx, lo: int, n: int, real):
+    """``[pairs routed here, pairs whose expert is absent, held experts
+    with a token]`` as int32, over the real tokens."""
+    e, here = _held(idx, lo, n, real)
+    routed = jnp.sum(here)
+    hit = jnp.sum(jnp.any(
+        here[..., None] & (e[..., None] == jnp.arange(n)), axis=(0, 1)))
+    return jnp.stack([routed, idx.shape[1] * jnp.sum(real) - routed,
+                      hit]).astype(jnp.int32)
+
+
+def moe_share_dense(x, idx, w, Eg, Eu, Ed, lo: int):
+    """The held experts' part of the layer's output, every held expert
+    over every token: ``sum_e c[t, e] Ed_e(silu(x Eg_e) * x Eu_e)`` with
+    ``c`` the token's weight for expert ``lo + e``, 0 where it did not
+    choose it.  ``x (T, d)``; ``Eg, Eu (n, d, f)``, ``Ed (n, f, d)``;
+    float32 out.  The down-projection contracts experts and width at
+    once, so the weighted sum over experts is inside one matmul."""
+    n, _, f = Eg.shape
+    T = x.shape[0]
+    dt = Eg.dtype
+    x = x.astype(dt)
+    c = jnp.sum(jnp.where(
+        (idx - lo)[..., None] == jnp.arange(n), w[..., None],
+        jnp.float32(0)), axis=1)
+    up = lambda W: jnp.einsum("td,edf->tef", x, W,
+                              preferred_element_type=jnp.float32)
+    h = jax.nn.silu(up(Eg)) * up(Eu) * c[..., None]           # (T, n, f)
+    return jnp.matmul(h.reshape(T, n * f).astype(dt),
+                      Ed.reshape(n * f, -1),
+                      preferred_element_type=jnp.float32)
+
+
+def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real):
+    """:func:`moe_share_dense`'s sum by GROUPS: the token-expert pairs
+    whose expert is held, sorted by expert, and one grouped matmul
+    (``lax.ragged_dot``) a projection over the rows of each expert's
+    group — the work of the pairs that exist (half a pair a token for 16
+    of 256 experts at 8 a token), not of ``n`` experts over every token.
+
+    Nothing is dropped and no shape depends on the routing: the sorted
+    pairs are taken ``T`` rows a pass, for as many passes as hold a held
+    pair (one, unless the router leans on this chip's experts; ``k`` at
+    most).  A pass gathers its rows' tokens, multiplies by group, and
+    adds each row's weighted output to its token through a 0/1 matrix on
+    the MXU (``T x T``; a scatter-add of rows would serialise)."""
+    T, k = idx.shape
+    n = Eg.shape[0]
+    dt = Eg.dtype
+    f32 = jnp.float32
+    x = x.astype(dt)
+    e, here = _held(idx, lo, n, real)
+    key = jnp.where(here, e, n).reshape(-1)        # absent pairs last
+    order = jnp.argsort(key).astype(jnp.int32)                   # (T k,)
+    ends = jnp.cumsum(jnp.sum(
+        key[:, None] == jnp.arange(n), axis=0)).astype(jnp.int32)
+    total = ends[-1]
+    wflat = w.reshape(-1)
+    at = jnp.arange(T, dtype=jnp.int32)
+    gmm = lambda a, W, sizes: lax.ragged_dot(
+        a, W, sizes, preferred_element_type=f32)
+
+    def one_pass(carry):
+        b, out = carry
+        rows = lax.dynamic_slice_in_dim(order, b * T, T)
+        live = b * T + at < total
+        edge = jnp.clip(ends - b * T, 0, T)
+        sizes = jnp.diff(edge, prepend=0).astype(jnp.int32)
+        tok = rows // k
+        xs = x[tok]
+        h = jax.nn.silu(gmm(xs, Eg, sizes)) * gmm(xs, Eu, sizes) \
+            * jnp.where(live, wflat[rows], f32(0))[:, None]
+        y = jnp.where(live[:, None], gmm(h.astype(dt), Ed, sizes), f32(0))
+        home = (at[:, None] == tok[None, :]) & live[None, :]     # (T, T)
+        return b + 1, out + jnp.matmul(home.astype(dt), y.astype(dt),
+                                       preferred_element_type=f32)
+    _, out = lax.while_loop(lambda c: c[0] * T < total, one_pass,
+                            (jnp.int32(0), jnp.zeros((T, Ed.shape[-1]), f32)))
+    return out
 
 
 @dataclasses.dataclass

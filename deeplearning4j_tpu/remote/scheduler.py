@@ -100,23 +100,26 @@ class KVCachePool:
     the host-side bookkeeping here.
 
     - *paged*: ``k``/``v`` ``(pagedLayers, numPages, pageSize,
-      kvHeads*headSize)`` plus a free list and per-slot page tables.
+      spec.rowWidth)`` plus a free list and per-slot page tables.
       Pages are token-major (a row is one position, all heads side by
       side): the two minor dimensions are what the TPU tiles without a
       re-layout, so the decode step and the prefill write update them in
       place and the step's kernel reads a page as it lies, all heads of
       a row at once (``paged_attention``).  Only the layers that own
       pages have any: 48 of 48 for GPT-2 XL, one of 32 for a SambaY
-      stack.
+      stack.  A model whose rows are latent (``spec.latentWidth``) has
+      ONE such array and no ``v``: keys and values are read from the same
+      row (``paged_latent_attention``).
     - *ring*: ``ringK``/``ringV`` ``(ringLayers, maxSlots, ringRows,
-      kvHeads*headSize)``, a slot's last ``ringRows`` positions written
+      spec.rowWidth)``, a slot's last ``ringRows`` positions written
       modulo ``ringRows``.
     - *recurrent*: one array ``(layers, maxSlots, ...)`` for each entry
       of ``spec.slotState``, overwritten every step.
 
     ``arrays`` is the tuple the step and the admission write take and
     return, in this order: ``(k, v[, ringK, ringV][, *slotState])`` —
-    ``(k, v)`` when every layer is paged.
+    ``(k, v)`` when every layer is paged, ``(rows[, *slotState])`` for
+    latent rows.
 
     Page 0 is the SCRATCH page: inactive slots' table entries point at
     it, so the fixed-shape decode step can write their (ignored) K/V
@@ -154,7 +157,8 @@ class KVCachePool:
             return a if sh is None else jax.device_put(a, sh)
         paged = (spec.pagedLayers, self.numPages, self.pageSize,
                  spec.rowWidth)
-        arrays = [zeros(paged, spec.dtype, sharding) for _ in range(2)]
+        arrays = [zeros(paged, spec.dtype, sharding)
+                  for _ in range(spec.pagedPools)]
         if spec.ringLayers:
             ring = (spec.ringLayers, self.maxSlots, spec.ringRows,
                     spec.rowWidth)
@@ -169,11 +173,13 @@ class KVCachePool:
         self._free = deque(range(1, self.numPages))
         self._held: List[List[int]] = [[] for _ in range(self.maxSlots)]
         itemsize = jnp.dtype(spec.dtype).itemsize
-        rowBytes = 2 * spec.rowWidth * itemsize             # K and V
-        #: bytes of each kind per live unit: a page, a ring row (all
-        #: ring layers), a slot's recurrent state
-        self.pageBytes = spec.pagedLayers * self.pageSize * rowBytes
-        self.ringRowBytes = spec.ringLayers * rowBytes
+        rowBytes = spec.rowWidth * itemsize
+        #: bytes of each kind per live unit: a page (K and V, or the one
+        #: latent row), a ring row (all ring layers, K and V), a slot's
+        #: recurrent state
+        self.pageBytes = spec.pagedLayers * self.pageSize \
+            * spec.pagedPools * rowBytes
+        self.ringRowBytes = spec.ringLayers * 2 * rowBytes
         self.slotStateBytes = sum(
             int(np.prod(shape)) * jnp.dtype(dt).itemsize
             for _name, shape, dt in spec.slotState)
@@ -505,6 +511,9 @@ class ContinuousBatcher:
         # path.  None (standalone batcher) errors the sequences instead.
         self.onSequenceFailure = None
         self._stepFns: Dict[str, object] = {}
+        # what the model's step counts on the device and returns in the
+        # columns behind its tokens (row 0): ``(metric, labels)`` each
+        self._stepCounters = tuple(getattr(lm, "stepCounters", ()))
         self._cacheSeen: Optional[int] = None
         self._busySteps = 0.0
         self._steps = 0
@@ -548,7 +557,8 @@ class ContinuousBatcher:
             if self.plan is not None else self._poolSharding(1)
         self.pool = KVCachePool.forSpec(
             spec, self.pageSize, self._numPages, self.maxSlots,
-            self._maxPagesPerSeq, sharding=self._poolSharding(spec.kvHeads),
+            self._maxPagesPerSeq,
+            sharding=self._poolSharding(spec.splitHeads),
             slotSharding=whole)
         self.draftPool = None if self.draft is None else \
             KVCachePool.forSpec(
@@ -1358,6 +1368,13 @@ class ContinuousBatcher:
         with self._phase("bookkeep"):
             flight.greedy = None        # freed inside a phase, as above
             sm = serving_metrics()
+            if self._stepCounters:
+                counted = g[0, g.shape[1] - len(self._stepCounters):]
+                for (metric, labels), n in zip(self._stepCounters, counted):
+                    if n:
+                        # jaxlint: disable=host-sync -- counted is a slice of g, the already-fetched host copy of this step's output
+                        getattr(sm, metric)().inc(int(n), model=self.name,
+                                                  **labels)
             occupied = len(flight.slots) / self.maxSlots
             self._steps += 1
             self._busySteps += occupied

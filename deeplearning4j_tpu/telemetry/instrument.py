@@ -619,6 +619,37 @@ class ServingMetrics:
             "Real prompt tokens prefilled at admission, per model",
             labelnames=("model",))
 
+    # routing of a served expert layer that holds a share of the experts
+    # (parallel/moe.py): counted on the device inside the step and the
+    # prefill, returned in the columns behind the step's tokens and read
+    # with them (``stepCounters`` of the served model)
+    def moe_pairs_routed(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_moe_pairs_routed_total",
+            "Token-expert pairs whose expert this replica holds, and so "
+            "computed here, summed over the expert layers; per model and "
+            "phase (step: a decode step's live slots; prefill: a "
+            "prompt's real positions)",
+            labelnames=("model", "phase"))
+
+    def moe_pairs_absent(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_moe_pairs_absent_total",
+            "Token-expert pairs the router chose whose expert another "
+            "chip of the deployment holds: their part of the layer's "
+            "output is left out here; routed + absent = experts a token x "
+            "tokens x expert layers",
+            labelnames=("model", "phase"))
+
+    def moe_experts_hit(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_moe_experts_hit_total",
+            "Held experts with at least one token, summed over the expert "
+            "layers and the steps (or prefills): over experts held x "
+            "layers x steps it is the share of the held experts' weights "
+            "a step has to read",
+            labelnames=("model", "phase"))
+
     def loop_phase_seconds(self):
         return get_registry().histogram(
             "dl4j_tpu_serving_loop_phase_seconds",

@@ -218,6 +218,38 @@ def test_paged_kernel_alone_compiles_at_its_callers_rows(one_chip, row):
         < PAGE_SIZE * h * d * pool.dtype.itemsize
 
 
+def test_latent_kernel_alone_compiles_at_its_callers_row(one_chip):
+    """``_latent_call`` by itself at the row its one caller brings: 128
+    heads as the rows of one operand against pages of 640 bfloat16 lanes
+    (512 latent + 64 rotated + 64 of padding to whole lane tiles), 384
+    pages a slot, 32 slots, 5 layers.  Nothing the size of the pool is set
+    aside in HBM: a pool of 576 lanes, which Mosaic cannot read as it
+    lies, would come back as a pool-sized copy re-laid into 640 (found:
+    1.26 GB of temporaries a call), which is why the row is stored padded."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf import attention as A
+    h, W, vw, perSeq, slots, layers = 128, 640, 512, 384, 32, 5
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def compiled(width):
+        C = A._LATENT_CHUNK_ROWS // PAGE_SIZE
+        places = slots * -(-perSeq // C)
+        pool = sds((layers, 1 + slots * perSeq, PAGE_SIZE, width),
+                   jnp.bfloat16)
+        return A._latent_call.lower(
+            sds((1,)), sds((places * C,)), sds((places,)), sds((places,)),
+            sds((places,)), sds(()), sds((slots,)), sds((slots,)),
+            sds((slots, h, width), jnp.bfloat16), pool, valueWidth=vw, tq=1,
+            interpret=False).compile()
+    padded = compiled(W)
+    assert padded.as_text().count("tpu_custom_call") == 1
+    assert padded.memory_analysis().temp_size_in_bytes < 1e6
+    assert compiled(576).memory_analysis().temp_size_in_bytes > 1e9
+
+
 @pytest.mark.parametrize("bucket", [16, 256])
 def test_paged_prefill_write_updates_the_pool_in_place(paged, bucket):
     import jax
@@ -417,3 +449,116 @@ def test_olmo_hybrid_prefill_and_admission_write_fit(olmo):
     assert write.memory_analysis().alias_size_in_bytes >= poolBytes
     # found 126 MB: one stack of 4 x 4,096 rows re-laid into pages
     assert write.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+# -- openPangu-Ultra-MoE-718B as benchmark/configs/openpangu_ultra_moe.json
+# serves it: the leading dense layer and four expert layers holding 16 of
+# 256 routed experts, an eighth of the vocabulary, every width as
+# published, bfloat16; 32 slots of 6,144 positions (384 pages of 16) of one
+# latent row a layer
+PANGU_SLOTS, PANGU_CAP, PANGU_BUCKET = 32, 6144, 4096
+
+
+@pytest.fixture(scope="module")
+def pangu(one_chip):
+    """``(lm, params, pool arrays, i32, the compiled decode step, the
+    attention kernels lowered for it)``: shapes on the described chip."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.pangu_moe import PanguMoEConfig, PanguMoELM
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_kernel_kv_passes, paged_kernel_lowerings)
+    from deeplearning4j_tpu.remote import KVCachePool
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    lm = PanguMoELM(PanguMoEConfig(
+        vocabSize=19200, nLayers=5, denseLayers=1, hiddenSize=7680,
+        nHeads=128, qRank=1536, kvRank=512, nopeDim=128, ropeDim=64,
+        vDim=128, ffnSize=18432, expertSize=2048, nExperts=256,
+        expertsPerToken=8, expertsHeld=(0, 16), maxLen=PANGU_CAP), params={})
+    params = on_chip(jax.eval_shape(lm._init_params))
+    perSeq = PANGU_CAP // PAGE_SIZE
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool.forSpec(
+        lm.cacheSpec(), PAGE_SIZE, 1 + PANGU_SLOTS * perSeq, PANGU_SLOTS,
+        perSeq).arrays))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    before = paged_kernel_lowerings()
+    # ``prev`` is a step's own output: the tokens and the six counts
+    step = lm.buildPagedDecodeFn().lower(
+        params, *pool, i32(PANGU_SLOTS, 1), i32(PANGU_SLOTS, 7),
+        i32(PANGU_SLOTS, perSeq), i32(PANGU_SLOTS),
+        i32(PANGU_SLOTS)).compile()
+    assert paged_kernel_kv_passes() == 1
+    return lm, params, pool, i32, step, paged_kernel_lowerings() - before
+
+
+def test_pangu_moe_decode_step_fits_and_reads_its_latent_rows_in_place(
+        pangu):
+    lm, params, pool, i32, compiled, kernelsLowered = pangu
+    perSeq = PANGU_CAP // PAGE_SIZE
+    mem = compiled.memory_analysis()
+    # found: 11.10 GB of arguments (9.84 of weights, 1.26 of latent rows:
+    # ONE pool of 640 bfloat16 lanes a row, no V) + 0.02 of temporaries
+    assert len(pool) == 2 and pool[0].shape == (
+        5, 1 + PANGU_SLOTS * perSeq, PAGE_SIZE, 640)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < 0.1e9
+    # the latent pool and the routing's counts are donated and come back
+    # aliased, not copied
+    _assert_one_step_program(compiled, pool)
+    assert not _whole_array_copies(compiled, pool)
+    # all five layers attend ABSORBED through the latent kernel over the
+    # live pages: no slot's capacity is gathered, no key or value formed
+    text = compiled.as_text()
+    assert kernelsLowered == 5
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 5
+    assert f"bf16[{PANGU_SLOTS * perSeq},{PAGE_SIZE},640]" not in text
+    assert f"[{PANGU_SLOTS},{PANGU_CAP},128,128]" not in text
+
+
+def test_pangu_moe_prefill_groups_its_experts_and_fits_beside_the_step(
+        pangu, monkeypatch):
+    import jax
+    from deeplearning4j_tpu.nlp import pangu_moe
+    from deeplearning4j_tpu.parallel import ring
+    lm, params, pool, i32, step, _ = pangu
+    # the program asks ``jax.default_backend()`` whether the flash kernel
+    # can run, and here that is the CPU: steer it to the chip's choice
+    for mod in (ring, pangu_moe):
+        monkeypatch.setattr(mod, "_flash_refusal", lambda *a, **k: None)
+    compiled = lm._prefillRawFn.at(PANGU_BUCKET).lower(
+        params, i32(1, PANGU_BUCKET), i32(1)).compile()
+    text = compiled.as_text()
+    # every layer's unabsorbed attention is the flash kernel: no score of
+    # 4,096 keys a query is held outside VMEM
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) >= 5
+    assert not re.search(rf"f32\[(?:1,)?128,\d+,{PANGU_BUCKET}\]", text)
+    # the held experts are multiplied by GROUP (the compiler's own ragged
+    # dot, three a layer), never all 16 over every token: no (tokens, 16,
+    # 2048) intermediate in any layout
+    assert text.count("ragged-dot") >= 3 * 4
+    assert not re.search(rf"\[(?:{PANGU_BUCKET},16,2048|16,{PANGU_BUCKET}"
+                         rf",2048|{PANGU_BUCKET},32768)\]", text)
+    mem = compiled.memory_analysis()
+    # found: 11.10 + 0.02 (the step) + 1.45 + 0.03 (the 4,096 prefill:
+    # queries, keys and values laid out for the flash kernel) = 12.60 GB
+    # (1.39 with the blocked form, 256 queries of 128 heads a block)
+    step = step.memory_analysis()
+    assert step.argument_size_in_bytes + step.temp_size_in_bytes \
+        + mem.temp_size_in_bytes + mem.output_size_in_bytes < 13.5e9
+    state = jax.eval_shape(lm._prefillRawFn, params, i32(1, PANGU_BUCKET),
+                           i32(1))[1:]
+    parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
+                                  sharding=pool[0].sharding) for p in state]
+    write = lm.buildPagedPrefillWriteFn().lower(
+        *pool, *parts, i32(PANGU_BUCKET // PAGE_SIZE), i32()).compile()
+    assert not _whole_array_copies(write, pool)
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert write.memory_analysis().alias_size_in_bytes >= poolBytes
+    assert write.memory_analysis().temp_size_in_bytes < 0.1e9
