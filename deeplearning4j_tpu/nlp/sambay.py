@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   drop_served_jits,
+                                                  paged_attention_read,
                                                   paged_prefill_write,
                                                   paged_step_tokens,
                                                   served_jit_entries)
@@ -220,43 +221,85 @@ class SambaYLM:
                              + lp["bdt"].astype(_F32))
         return Dt, dbc[..., R:R + N], dbc[..., R + N:]
 
-    def _diff_attend(self, lp, i, q, kRows, vRows, valid):
-        """Differential attention of ``q (b, tq, H*dh)`` against rows
-        ``kRows, vRows (b, T, KV*dh)`` with ``valid (b, tq, T)``.
-
-        Query heads pair up (20 pairs) and KV heads pair up (10 pairs);
-        query pair ``p`` reads KV pair ``g = p // R``.  A row is kept as
-        ``G`` groups of ``2*dh`` channels — ``[k_g1 ; k_g2]``, a whole
+    def _diff_queries(self, q):
+        """``q (b, tq, H*dh)`` as ``(b, tq, G, 2R, 2*dh)``: a row is kept
+        as ``G`` groups of ``2*dh`` channels — ``[k_g1 ; k_g2]``, a whole
         128-lane tile at the published head size — and each query is
         laid into its own half of that width (zeros in the other), so
         that K and V are contracted as they are stored and never split
         into 64-wide heads."""
         c = self.config
         b, tq, _ = q.shape
+        G = c.nKvHeads // 2
+        R = (c.nHeads // 2) // G
+        q5 = q.reshape(b, tq, G, R, 2, 1, c.headSize)
+        eye = jnp.eye(2, dtype=q.dtype)[:, :, None]
+        return (q5 * eye).reshape(b, tq, G, R * 2, 2 * c.headSize)
+
+    @staticmethod
+    def _diff_lambda(lp, i):
+        f = lambda n: lp[n].astype(_F32)
+        return jnp.exp(jnp.sum(f("lq1") * f("lk1"))) \
+            - jnp.exp(jnp.sum(f("lq2") * f("lk2"))) + _lambda_init(i)
+
+    @staticmethod
+    def _sub_norm(lp, i, o):
+        """The differential context's RMS norm over a group's ``2*dh``
+        channels, ``o (b, tq, G, R, 2*dh)`` float32 -> ``(b, tq, H*dh)``."""
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + 1e-5) * lp["sublnG"].astype(_F32) \
+            * (1.0 - _lambda_init(i))
+        return o.reshape(o.shape[:2] + (-1,))
+
+    def _diff_attend(self, lp, i, q, kRows, vRows, valid):
+        """Differential attention of ``q (b, tq, H*dh)`` against rows
+        ``kRows, vRows (b, T, KV*dh)`` with ``valid (b, tq, T)``: the
+        window layers' in every form, the full and cross layers' in the
+        full-sequence forms (their step reads pages:
+        :meth:`_diff_attend_paged`).
+
+        Query heads pair up (20 pairs) and KV heads pair up (10 pairs);
+        query pair ``p`` reads KV pair ``g = p // R``, laid out as
+        :meth:`_diff_queries` says."""
+        c = self.config
+        b, tq, _ = q.shape
         T = kRows.shape[1]
         dh = c.headSize
         G = c.nKvHeads // 2
-        R = (c.nHeads // 2) // G
         cd = kRows.dtype
-        q5 = q.reshape(b, tq, G, R, 2, 1, dh)
-        eye = jnp.eye(2, dtype=q.dtype)[:, :, None]
-        qe = (q5 * eye).reshape(b, tq, G, R * 2, 2 * dh).astype(cd)
+        qe = self._diff_queries(q).astype(cd)
         k4 = kRows.reshape(b, T, G, 2 * dh)
         v4 = vRows.reshape(b, T, G, 2 * dh)
         s = jnp.einsum("bqgac,btgc->bgqat", qe, k4,
                        preferred_element_type=_F32) * (1.0 / math.sqrt(dh))
         s = jnp.where(valid[:, None, :, None, :], s, _NEG)
-        a = jax.nn.softmax(s, axis=-1).reshape(b, G, tq, R, 2, T)
-        f = lambda n: lp[n].astype(_F32)
-        li = _lambda_init(i)
-        lam = jnp.exp(jnp.sum(f("lq1") * f("lk1"))) \
-            - jnp.exp(jnp.sum(f("lq2") * f("lk2"))) + li
-        a = a[..., 0, :] - lam * a[..., 1, :]               # (b, G, tq, R, T)
+        a = jax.nn.softmax(s, axis=-1).reshape(b, G, tq, -1, 2, T)
+        a = a[..., 0, :] - self._diff_lambda(lp, i) * a[..., 1, :]
         o = jnp.einsum("bgqrt,btgc->bqgrc", a.astype(cd), v4,
                        preferred_element_type=_F32)
-        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                              + 1e-5) * f("sublnG") * (1.0 - li)
-        return o.reshape(b, tq, G * R * 2 * dh)
+        return self._sub_norm(lp, i, o)
+
+    def _diff_attend_paged(self, lp, i, q, k, v, pageTable, pos, start):
+        """:meth:`_diff_attend` of ``q (S, tq, H*dh)`` against the paged
+        layer's rows where they lie, through
+        :func:`paged_attention_read`: in :meth:`_diff_queries`' layout it
+        is plain grouped attention, ``H`` query heads of ``2*dh`` lanes
+        on ``G`` KV heads of ``2*dh``, scores scaled by ``1/sqrt(dh)``,
+        the context over a group's whole ``2*dh`` lanes of V.  The
+        context is linear in the softmax weights, so the pair's
+        difference ``a1 - lambda a2`` is taken of the two contexts, in
+        float32, and no weight is rounded on its way to V."""
+        c = self.config
+        S, tq, _ = q.shape
+        dh = c.headSize
+        G = c.nKvHeads // 2
+        qe = self._diff_queries(q.astype(_F32)).reshape(
+            S, tq, c.nHeads, 2 * dh).transpose(0, 2, 1, 3)
+        ctx = paged_attention_read(qe, k, v, 0, pageTable, pos, start,
+                                   scale=dh ** -0.5)       # (S, H, tq, 2dh)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(S, tq, G, -1, 2, 2 * dh)
+        return self._sub_norm(
+            lp, i, ctx[..., 0, :] - self._diff_lambda(lp, i) * ctx[..., 1, :])
 
     def _logits(self, params, x):
         h = _ln(x, params["lnf_g"], params["lnf_b"], self.config.eps)
@@ -398,7 +441,12 @@ class SambaYLM:
                     pageTable, pos, start):
         """One token per slot (``toks (S, 1)``) against the pool's
         arrays: ``((S, 1, vocab) logits, k, v, ringK, ringV, ssm,
-        conv)``.  A slot whose ``pos`` is 0 holds no sequence (or is
+        conv)``.  The full layer writes its row into its page and it,
+        and the cross layers after it, read the pages through
+        :func:`paged_attention_read` (lowered for one TPU: the kernel
+        over the slots' live pages; elsewhere the gathered reference);
+        the window layers attend over their rings (:meth:`_diff_attend`).
+        A slot whose ``pos`` is 0 holds no sequence (or is
         deferred a round): its paged write lands on the scratch page
         through its zeroed page table, and its ring rows and recurrent
         state are left as they are."""
@@ -413,12 +461,9 @@ class SambaYLM:
         ps = k.shape[2]
         rows = jnp.arange(S, dtype=_I32)
         active = pos > 0
-        # the paged layer: this step's row, and every held row in order
+        # the paged layer: where this step's row goes
         phys = pageTable[rows, pos // ps]
         off = pos % ps
-        cap = pageTable.shape[1] * ps
-        kpos = jnp.arange(cap, dtype=_I32)[None, :]
-        validP = ((kpos <= pos[:, None]) & (kpos >= start[:, None]))[:, None]
         # a ring row r holds the newest position <= pos that is r mod W
         rIdx = pos % W
         r = jnp.arange(W, dtype=_I32)[None, :]
@@ -428,7 +473,7 @@ class SambaYLM:
         cd = x.dtype
         keep = lambda new, old: jnp.where(
             active.reshape((S,) + (1,) * (new.ndim - 1)), new, old)
-        mem = kAll = vAll = None
+        mem = None
         mi = wi = 0
         for i, (kind, lp) in enumerate(zip(c.layerKinds(),
                                            params["layers"])):
@@ -471,9 +516,8 @@ class SambaYLM:
                     if kind == "full":
                         k = k.at[0, phys, off].set(kN.astype(k.dtype))
                         v = v.at[0, phys, off].set(vN.astype(v.dtype))
-                        kAll = k[0, pageTable].reshape(S, cap, -1)
-                        vAll = v[0, pageTable].reshape(S, cap, -1)
-                    o = self._diff_attend(lp, i, q, kAll, vAll, validP)
+                    o = self._diff_attend_paged(lp, i, q, k, v, pageTable,
+                                                pos, start)
                 out = _mm(o[:, 0], lp["Wo"])
             x = self._ffn(lp, x + out.astype(cd))
         return (self._logits(params, x)[:, None], k, v, ringK, ringV, ssm,

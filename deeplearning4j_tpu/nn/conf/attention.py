@@ -32,7 +32,8 @@ from deeplearning4j_tpu.nn.weights import init_weight
 
 __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
-           "paged_attention", "paged_kernel_lowerings",
+           "paged_attention", "paged_attention_read",
+           "paged_kernel_lowerings",
            "paged_kernel_kv_passes",
            "paged_latent_attention", "paged_prefill_write",
            "paged_rows_write", "paged_step_tokens",
@@ -74,7 +75,8 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
     never a reallocation, and never a new executable shape.
 
     - ``qh``/``kh_new``/``vh_new``: (slots, heads, tq, headSize) for the
-      new positions only;
+      new positions only (``qh`` may bring ``nRep`` query heads for each
+      of the ``heads`` that are stored: :func:`paged_attention_read`);
     - ``poolK``/``poolV``: (nLayers, numPages, pageSize, heads*headSize)
       — the STACKED pools of every layer, token-major: one row per
       position holds all heads side by side, so the two minor
@@ -92,21 +94,10 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
 
     Writes the new K/V rows into their pages (``tq`` may span a page
     boundary — each token's page/offset is computed independently) and
-    attends over the slot's rows under a validity mask: query ``i`` of
-    slot ``s`` sees key index ``j`` iff ``start[s] <= j <= pos[s] + i``
-    — causal, and blind to the pad, the unwritten tail and the scratch
-    page.  Returns ``(ctx, newPoolK, newPoolV)``.
-
-    How the rows are read is decided where the program is lowered, from
-    what it is lowered for: for ONE TPU, a kernel reads the slot's live
-    pages where they lie (:func:`_attend_pages`); anywhere else (the
-    CPU, a pool split over several devices) every slot's whole capacity
-    is gathered and attended under the mask (:func:`_attend_gathered`,
-    the reference formulation).  Both give a result that depends on a
-    slot's logical content alone: not on which physical pages hold it,
-    nor on the other slots.
+    reads them back through :func:`paged_attention_read`, scores scaled
+    by ``headSize ** -0.5``.  Returns ``(ctx, newPoolK, newPoolV)``.
     """
-    S, h, tq, d = qh.shape
+    S, h, tq, d = kh_new.shape
     pageSize = poolK.shape[2]
     wpos = pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
     phys = jnp.take_along_axis(pageTable, wpos // pageSize, axis=1)
@@ -117,19 +108,53 @@ def paged_attention(qh, kh_new, vh_new, poolK, poolV, li, pageTable, pos,
             pool.dtype)
     poolK = poolK.at[li, phys, off].set(rows(kh_new, poolK))
     poolV = poolV.at[li, phys, off].set(rows(vh_new, poolV))
-    ctx = _attend_p.bind(qh, poolK, poolV, pageTable, pos, start, li=li)
-    return ctx, poolK, poolV
+    return (paged_attention_read(qh, poolK, poolV, li, pageTable, pos, start),
+            poolK, poolV)
+
+
+def paged_attention_read(qh, poolK, poolV, li, pageTable, pos, start, *,
+                         scale=None):
+    """:func:`paged_attention` without the write: what a layer calls
+    that reads rows another layer wrote, or that has written its own.
+
+    Query ``i`` of slot ``s`` sees key index ``j`` iff ``start[s] <= j <=
+    pos[s] + i`` — causal, and blind to the pad, the unwritten tail and
+    the scratch page.  ``qh`` may bring GROUPED queries: ``(slots,
+    kvHeads * nRep, tq, headSize)`` against a stored row of ``kvHeads *
+    headSize`` lanes, query head ``a`` reading KV head ``a // nRep``;
+    ``nRep`` is read from the two shapes and nothing sets it.  Scores are
+    multiplied by ``scale`` (``headSize ** -0.5`` where none is given: a
+    caller whose ``headSize`` lanes are not its scores' width, as a
+    differential pair laid into the halves of one 128-lane group, says
+    its own) and the context, ``qh``'s shape and dtype, is taken over all
+    ``headSize`` lanes of V.
+
+    How the rows are read is decided where the program is lowered, from
+    what it is lowered for: for ONE TPU, a kernel reads the slot's live
+    pages where they lie (:func:`_attend_pages`); anywhere else (the
+    CPU, a pool split over several devices) every slot's whole capacity
+    is gathered and attended under the mask (:func:`_attend_gathered`,
+    the reference formulation).  Both give a result that depends on a
+    slot's logical content alone: not on which physical pages hold it,
+    nor on the other slots."""
+    return _attend_p.bind(qh, poolK, poolV, pageTable, pos, start, li=li,
+                          scale=None if scale is None else float(scale))
 
 
 _NEG = -1e30              # a masked score
 
 
-def _attend_gathered(qh, poolK, poolV, pageTable, pos, start, *, li):
-    """The reference formulation of :func:`paged_attention`'s read:
-    gather every slot's pages in logical order ((S, capacity, h*d)),
-    split the rows into heads, and run scores, softmax and context over
-    the whole capacity under the validity mask."""
-    S, h, tq, d = qh.shape
+def _attend_gathered(qh, poolK, poolV, pageTable, pos, start, *, li,
+                     scale=None):
+    """The reference formulation of :func:`paged_attention_read`: gather
+    every slot's pages in logical order ((S, capacity, h*d)), split the
+    rows into heads, and run scores, softmax and context over the whole
+    capacity under the validity mask.  Grouped queries ride as further
+    queries of their KV head (``nRep * tq`` of them, one validity each
+    ``tq``)."""
+    S, H, tq, d = qh.shape
+    h = poolK.shape[3] // d
+    nRep = H // h
     cap = pageTable.shape[1] * poolK.shape[2]
     wpos = pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
     k = poolK[li, pageTable].reshape(S, cap, h, d)
@@ -137,11 +162,15 @@ def _attend_gathered(qh, poolK, poolV, pageTable, pos, start, *, li):
     kpos = jnp.arange(cap, dtype=jnp.int32)
     valid = (kpos[None, None, :] <= wpos[:, :, None]) & \
         (kpos[None, None, :] >= start[:, None, None])        # (S, tq, cap)
-    s = jnp.einsum("bhqd,bkhd->bhqk", qh, k.astype(qh.dtype))
-    s = s * (1.0 / jnp.sqrt(jnp.asarray(d, s.dtype)))
+    valid = jnp.tile(valid, (1, nRep, 1))
+    s = jnp.einsum("bhqd,bkhd->bhqk", qh.reshape(S, h, nRep * tq, d),
+                   k.astype(qh.dtype))
+    s = s * (1.0 / jnp.sqrt(jnp.asarray(d, s.dtype)) if scale is None
+             else jnp.asarray(scale, s.dtype))
     s = jnp.where(valid[:, None], s, jnp.asarray(_NEG, s.dtype))
     w = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bhqd", w, v.astype(qh.dtype))
+    return jnp.einsum("bhqk,bkhd->bhqd", w, v.astype(qh.dtype)).reshape(
+        S, H, tq, d)
 
 
 # -- the kernel: attention over the live pages, where they lie ---------
@@ -150,11 +179,12 @@ def _attend_gathered(qh, poolK, poolV, pageTable, pos, start, *, li):
 #: dot products on the MXU a place costs what its pages' copies cost, and
 #: 256 rows a place cost a row what 128 do: 0.758 against 0.759 ms a call
 #: at Olmo-Hybrid's row (3,840 bfloat16 lanes, 36,100 live rows), 18.6
-#: against 18.6 us at gpt2_xl's (1,600 float32 lanes, 650 live rows); only
-#: a row of 1,280 bfloat16 lanes, which no caller has yet, gains (0.331
-#: against 0.381 ms over 37,800 live rows) (my chip run, PR 31).  So 128
-#: stays: a slot's last chunk is half empty on average, and its dead
-#: buffers are not copied
+#: against 18.6 us at gpt2_xl's (1,600 float32 lanes, 650 live rows) (my
+#: chip run, PR 31); only SambaY's row of 1,280 bfloat16 lanes gains (0.336
+#: against 0.378 ms a call over 37,800 live rows, four query heads a KV
+#: head; in its step of 18.05 ms the eight calls 2.00 against 2.27: 1.6%)
+#: (my chip runs, PR 33).  So 128 stays: a slot's last chunk is half empty
+#: on average, and its dead buffers are not copied
 _CHUNK_ROWS = 128
 
 
@@ -245,17 +275,23 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
     the lanes of the pages as they lie, a tile of ``g`` whole heads at a
     time, with the queries as the small operand.
 
-    - *scores*: ``Q_t`` (a row for every query, head of the tile and
+    - *scores*: ``Q_t`` (a row for every query, head of the tile, query
+      head of that head's group (``rep`` of them: ``q_ref`` holds ``tq *
+      rep`` rows, each with one query head of every KV head) and
       bfloat16 piece of the float32 ``q``: that head's slice of ``q`` in
       its own lanes, zeros elsewhere) against the tile's lanes of the
       chunk's rows of K, ``(rows, lanes) x (R, lanes) -> (rows, R)``:
       every head's scores with the key positions on the lanes, the
       pieces' rows summed in float32;
-    - *softmax*: online, per head, across the slot's chunks, in float32,
-      for all tiles at once;
+    - *softmax*: online, per query head, across the slot's chunks, in
+      float32, for all tiles at once;
     - *context*: the weights' bfloat16 pieces as rows, ``(rows, R) x (R,
       lanes)`` against the tile's lanes of V, the pieces summed, each
-      head keeping its own lanes at the end.
+      row keeping its own head's lanes at the end.
+
+    Grouped queries are only more rows in a query's block of ``Q_t`` (a
+    head's ``rep`` query heads under one another, ``g * rep`` rows a
+    piece); with one query head a KV head every line is what it was.
 
     K and V enter the MXU as they are stored, in as many passes as
     :func:`_mxu_parts` of the pool's dtype says (a bfloat16 pool: one, and
@@ -267,15 +303,19 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
     w = pl.program_id(0)
     R = C * ps
     g, tiles = _lane_tiles(q_ref.shape[-1] // d, d)
-    G = tq * g                      # rows of one piece: (query, head)
+    rep = q_ref.shape[0] // tq      # query heads a KV head
+    B = g * rep                     # rows of a query: (head, its query head)
+    G = tq * B                      # rows of one piece
     flag = flag_ref[w]
 
     def rows(part, i):              # piece ``part`` of query ``i``
-        return pl.ds(part * G + i * g, g)
+        return pl.ds(part * G + i * B, B)
 
-    # row j of a tile's block belongs to the tile's head j: its lanes
-    lane = jax.lax.broadcasted_iota(jnp.int32, (g, g * d), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (g, g * d), 0)
+    # row j of a query's block belongs to the tile's head j // rep: its lanes
+    lane = jax.lax.broadcasted_iota(jnp.int32, (B, g * d), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (B, g * d), 0)
+    if rep > 1:
+        head = jax.lax.div(head, jnp.int32(rep))
     own = (lane >= head * d) & (lane < head * d + d)
 
     @pl.when((flag & 1) != 0)
@@ -286,7 +326,10 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
         qt_ref[...] = jnp.zeros(qt_ref.shape, f32)
         for i in range(tq):
             for t, (lo, n) in enumerate(tiles):
-                qi = jnp.broadcast_to(q_ref[pl.ds(i, 1), lo:lo + n], (g, n))
+                # the query's ``rep`` rows, once under each head of the tile
+                qi = q_ref[pl.ds(i * rep, rep), lo:lo + n]
+                qi = jnp.broadcast_to(qi, (g, n)) if rep == 1 else \
+                    jnp.concatenate([qi] * g, axis=0)
                 for part, qp in enumerate(_bf16_parts(qi)):
                     qt_ref[t, rows(part, i), 0:n] = jnp.where(
                         own[:, :n], qp, f32(0))
@@ -311,8 +354,8 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
         valid = (j >= start) & (j <= pos + i)
         sc = functools.reduce(operator.add, (
             sp_ref[:, rows(part, i), :] for part in range(_F32_PARTS)))
-        sc = jnp.where(valid, sc, f32(_NEG))                 # (T, g, R)
-        mOld = m_ref[i]                                      # (T, g, 1)
+        sc = jnp.where(valid, sc, f32(_NEG))                 # (T, B, R)
+        mOld = m_ref[i]                                      # (T, B, 1)
         mNew = jnp.maximum(mOld, jnp.max(sc, axis=-1, keepdims=True))
         p = jnp.where(valid, jnp.exp(sc - mNew), f32(0))
         scale.append(jnp.exp(mOld - mNew))
@@ -331,40 +374,48 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
             c_ref[:, rows(part, i), :] for part in range(_F32_PARTS)))
 
     @pl.when((flag & 2) != 0)
-    def _():                        # and closes: each head's own lanes
-        for i in range(tq):
-            o = jnp.sum(jnp.where(own, acc_ref[i] / l_ref[i], f32(0)),
-                        axis=1, keepdims=True)               # (T, 1, g*d)
-            for t, (lo, n) in enumerate(tiles):
-                o_ref[pl.ds(i, 1), lo:lo + n] = o[t][:, :n].astype(
+    def _():                        # and closes: each row's own lanes,
+        for i in range(tq):         # the heads' blocks of rows laid together
+            o = jnp.where(own, acc_ref[i] / l_ref[i], f32(0))
+            o = jnp.sum(o, axis=1, keepdims=True) if rep == 1 else \
+                functools.reduce(operator.add, (
+                    o[:, j * rep:(j + 1) * rep] for j in range(g)))
+            for t, (lo, n) in enumerate(tiles):              # (T, rep, g*d)
+                o_ref[pl.ds(i * rep, rep), lo:lo + n] = o[t][:, :n].astype(
                     o_ref.dtype)
 
 
 def _attend_pages(qh, poolK, poolV, pageTable, pos, start, *, li,
-                  interpret=False):
-    """:func:`paged_attention`'s read as a Pallas TPU kernel, one call a
+                  scale=None, interpret=False):
+    """:func:`paged_attention_read` as a Pallas TPU kernel, one call a
     layer: K and V are read from the pool's pages WHERE THEY LIE —
     token-major rows of ``heads*headSize`` lanes, all heads side by side
     — and only the pages that hold live rows of a slot.  Nothing is
     gathered into a capacity-wide copy, no row is re-laid into heads,
-    no score is taken over a dead position.  ``interpret`` is for tests
-    (the CPU)."""
-    S, h, tq, d = qh.shape
+    no score is taken over a dead position.  The queries go in as ``tq *
+    nRep`` rows a slot as wide as a stored row: row ``i * nRep + r``
+    holds, in each KV head's lanes, query ``i`` of that head's ``r``-th
+    query head.  ``interpret`` is for tests (the CPU)."""
+    S, H, tq, d = qh.shape
     ps, hd = poolK.shape[2:]
+    h = hd // d
+    nRep = H // h
     i32 = jnp.int32
-    q = (qh * jnp.asarray(d ** -0.5, qh.dtype)).transpose(
-        0, 2, 1, 3).reshape(S, tq, hd).astype(jnp.float32)
+    q = (qh * jnp.asarray(d ** -0.5 if scale is None else scale, qh.dtype)
+         ).reshape(S, h, nRep, tq, d).transpose(0, 3, 2, 1, 4).reshape(
+             S, tq * nRep, hd).astype(jnp.float32)
     pos, start = pos.astype(i32), start.astype(i32)
     work = _work_list(pageTable.astype(i32), pos, start, tq=tq, pageSize=ps,
                       C=max(1, min(_CHUNK_ROWS // ps, pageTable.shape[1])))
     out = _pages_call(jnp.full((1,), li, i32), *work, pos, start, q, poolK,
-                      poolV, headSize=d, interpret=interpret)
-    return out.reshape(S, tq, h, d).transpose(0, 2, 1, 3).astype(qh.dtype)
+                      poolV, headSize=d, tq=tq, interpret=interpret)
+    return out.reshape(S, tq, nRep, h, d).transpose(0, 3, 2, 1, 4).reshape(
+        S, H, tq, d).astype(qh.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("headSize", "interpret"))
+@functools.partial(jax.jit, static_argnames=("headSize", "tq", "interpret"))
 def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
-                *, headSize, interpret):
+                *, headSize, tq, interpret):
     """The kernel's call.  The stacked pool goes in whole (the layer is
     an index, so no layer is sliced out), once for each of a chunk's
     ``2 C`` page buffers: each buffer's block is one ``(pageSize, h*d)``
@@ -376,15 +427,16 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
     the step has chunks of live pages.  A jit of its own with the layer
     as an argument: every layer of a step is then the same computation,
     traced and lowered to Mosaic once a program and not once a layer."""
-    S, tq, hd = q.shape
+    S, nq, hd = q.shape             # nq = tq x the query heads a KV head
     ps = poolK.shape[2]
     C = tbl.shape[0] // slot.shape[0]
     d = headSize
     g, tiles = _lane_tiles(hd // d, d)
     T, R = len(tiles), C * ps
-    # rows of a tile's block: a query's heads of the tile, piece by piece
-    # (whole bfloat16 sublane tiles of 16)
-    rp = -(-_F32_PARTS * tq * g // 16) * 16
+    B = nq // tq * g                # rows of one query in a tile's block
+    # rows of a tile's block: a query's heads of the tile with their query
+    # heads, piece by piece (whole bfloat16 sublane tiles of 16)
+    rp = -(-_F32_PARTS * tq * B // 16) * 16
     f32 = jnp.float32
 
     # index maps: ``w * 0`` and not ``0`` (the package enables x64, and a
@@ -394,7 +446,7 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
             (None, None, ps, hd),
             lambda w, li, tbl, *_: (li[0], tbl[w * C + c], w * 0, w * 0))
     row_spec = pl.BlockSpec(
-        (None, tq, hd), lambda w, li, tbl, slot, *_: (slot[w], w * 0, w * 0))
+        (None, nq, hd), lambda w, li, tbl, slot, *_: (slot[w], w * 0, w * 0))
     return pl.pallas_call(
         functools.partial(_pages_kernel, C=C, ps=ps, tq=tq, d=d),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -406,11 +458,11 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
                 pltpu.VMEM((T, rp, g * d), f32),     # the queries' Q_t
                 pltpu.VMEM((T, rp, R), f32),         # scores, then weights
                 pltpu.VMEM((T, rp, g * d), f32),     # the chunk's context
-                pltpu.VMEM((tq, T, g, 1), f32),      # running max
-                pltpu.VMEM((tq, T, g, 1), f32),      # running sum
-                pltpu.VMEM((tq, T, g, g * d), f32),  # context
+                pltpu.VMEM((tq, T, B, 1), f32),      # running max
+                pltpu.VMEM((tq, T, B, 1), f32),      # running sum
+                pltpu.VMEM((tq, T, B, g * d), f32),  # context
             ]),
-        out_shape=jax.ShapeDtypeStruct((S, tq, hd), f32),
+        out_shape=jax.ShapeDtypeStruct((S, nq, hd), f32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 << 20),
@@ -428,8 +480,9 @@ _kernelLowerings = [0, 0]
 
 def paged_kernel_lowerings() -> int:
     """How many times :func:`paged_attention`'s read has been lowered as
-    the TPU kernel in this process (once a layer of each program built
-    for one TPU; never on the CPU or for a pool split over devices)."""
+    the TPU kernel in this process (once for each layer READ by a program
+    built for one TPU, however many of its layers read that one; never on
+    the CPU or for a pool split over devices)."""
     return _kernelLowerings[0]
 
 
@@ -455,25 +508,26 @@ def _lowered_as_kernel(ctx, poolDtype) -> bool:
     return kernel
 
 
-def _attend_lowering(ctx, *args, li):
+def _attend_lowering(ctx, *args, li, scale):
     kernel = _lowered_as_kernel(ctx, ctx.avals_in[1].dtype)
     return mlir.lower_fun(
         functools.partial(_attend_pages if kernel else _attend_gathered,
-                          li=li), multiple_results=False)(ctx, *args)
+                          li=li, scale=scale),
+        multiple_results=False)(ctx, *args)
 
 
 _attend_p = jex_core.Primitive("paged_attend")
 
 
-@functools.partial(jax.jit, static_argnames=("li",))
-def _attend_eager(*args, li):
+@functools.partial(jax.jit, static_argnames=("li", "scale"))
+def _attend_eager(*args, li, scale):
     """Outside any jit the primitive runs as a program of its own."""
-    return _attend_p.bind(*args, li=li)
+    return _attend_p.bind(*args, li=li, scale=scale)
 
 
 _attend_p.def_impl(_attend_eager)
 _attend_p.def_abstract_eval(
-    lambda qh, *_, li: jax.core.ShapedArray(qh.shape, qh.dtype))
+    lambda qh, *_, li, scale: jax.core.ShapedArray(qh.shape, qh.dtype))
 mlir.register_lowering(_attend_p, _attend_lowering)
 
 

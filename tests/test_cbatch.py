@@ -100,19 +100,56 @@ def test_paged_attention_matches_masked_softmax(tq, layers, pos, start):
         np.testing.assert_array_equal(np.asarray(out), want)
 
 
+@pytest.mark.parametrize("tq", [1, 3])
+def test_grouped_queries_read_their_kv_head_as_if_it_were_repeated(tq):
+    """``nRep`` query heads on each stored head (read from the shapes of
+    ``qh`` and of the pool's row): query head ``a`` reads KV head ``a //
+    nRep``, which is what the same call gives over a pool in which every
+    KV head is stored ``nRep`` times; the write lands the ``h`` new heads."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf.attention import paged_attention
+    rng = np.random.RandomState(tq)
+    S, h, nRep, d, ps, P = 2, 2, 3, 4, 4, 3
+    new = lambda heads: jnp.asarray(rng.randn(S, heads, tq, d), jnp.float32)
+    q, k, v = new(h * nRep), new(h), new(h)
+    pk, pv = (jnp.asarray(rng.randn(1, 1 + S * P, ps, h * d), jnp.float32)
+              for _ in range(2))
+
+    def repeated(pool):
+        return jnp.repeat(pool.reshape(pool.shape[:3] + (h, d)), nRep,
+                          axis=3).reshape(pool.shape[:3] + (-1,))
+    table = jnp.asarray([[1, 2, 3], [6, 5, 4]], jnp.int32)
+    pos, start = jnp.asarray([5, 2], jnp.int32), jnp.asarray([1, 0], jnp.int32)
+    got, gk, gv = paged_attention(q, k, v, pk, pv, 0, table, pos, start)
+    want, wk, _ = paged_attention(
+        q, jnp.repeat(k, nRep, axis=1), jnp.repeat(v, nRep, axis=1),
+        repeated(pk), repeated(pv), 0, table, pos, start)
+    assert got.shape == (S, h * nRep, tq, d)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(repeated(gk)), np.asarray(wk))
+
+
 def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2,
-                pool="float32"):
+                pool="float32", rep=1, halves=False):
     """Random pools (of dtype ``pool``) and float32 queries for
     ``len(pos)`` slots of ``P`` pages of ``ps`` rows: ``(qh, poolK, poolV,
     table, pos, start)`` as ``paged_attention``'s two formulations take
     them.  ``table`` defaults to each slot's pages in a scrambled
-    physical order (page 0 is the scratch page and belongs to no slot)."""
+    physical order (page 0 is the scratch page and belongs to no slot).
+    ``rep`` query heads read each of the ``h`` KV heads; with ``halves``
+    query head ``a`` keeps half ``a % 2`` of its ``d`` lanes and zeros in
+    the other (SambaY's differential pairs in a 128-lane group)."""
     import jax.numpy as jnp
     S = len(pos)
     numPages = 1 + S * P
     if table is None:
         table = 1 + rng.permutation(S * P).reshape(S, P)
-    return (jnp.asarray(rng.randn(S, h, tq, d), jnp.float32),
+    q = rng.randn(S, h * rep, tq, d)
+    if halves:
+        q *= (np.arange(d) // (d // 2) == np.arange(h * rep)[:, None] % 2
+              )[None, :, None, :]
+    return (jnp.asarray(q, jnp.float32),
             jnp.asarray(rng.randn(layers, numPages, ps, h * d), pool),
             jnp.asarray(rng.randn(layers, numPages, ps, h * d), pool),
             jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
@@ -163,6 +200,25 @@ def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2,
     # 25 heads of 64: the thirteenth lane tile is half full
     dict(id="bf16_last_tile_half_full", h=25, d=64, ps=16, P=10, tq=1,
          pos=[150, 37], start=[3, 0], pool="bfloat16"),
+    # grouped queries: ``rep`` query heads read each KV head, more rows in
+    # a tile's block of queries.  SambaY's paged layer as its step brings
+    # it: 10 groups of 128 bfloat16 lanes, each differential query in its
+    # own half of them, scores scaled for the 64 lanes that count; a left
+    # pad past one chunk, and a slot that holds nothing (``pos`` 0 on the
+    # scratch page)
+    dict(id="grouped_2_on_10x128_in_halves", h=10, d=128, rep=2, ps=16,
+         P=20, tq=1, pos=[300, 45], start=[140, 3], pool="bfloat16",
+         halves=True, scale=0.125),
+    dict(id="grouped_4_on_10x128_in_halves", h=10, d=128, rep=4, ps=16,
+         P=20, tq=1, pos=[300, 45, 0], start=[140, 3, 0], pool="bfloat16",
+         halves=True, scale=0.125, parked=[2]),
+    # two heads a lane tile with three query heads each, a float32 pool
+    # and four queries across a page: the heads' blocks of rows are laid
+    # under one another and summed back
+    dict(id="grouped_3_two_heads_a_tile_verify", h=5, d=64, rep=3, ps=16,
+         P=12, tq=4, pos=[157, 13], start=[18, 0]),
+    dict(id="grouped_2_bf16_two_heads_a_tile", h=20, d=64, rep=2, ps=16,
+         P=12, tq=1, pos=[170, 20], start=[0, 7], pool="bfloat16"),
 ], ids=lambda c: c["id"])
 def test_paged_kernel_matches_the_gathered_reference(case):
     """The TPU kernel (Pallas interpret mode, here on the CPU) against
@@ -182,11 +238,13 @@ def test_paged_kernel_matches_the_gathered_reference(case):
         table = 1 + np.arange(S * P).reshape(S, P)
     args = _paged_case(rng, case["h"], case["d"], case["ps"], P, case["tq"],
                        case["pos"], case["start"], table,
-                       pool=case.get("pool", "float32"))
+                       pool=case.get("pool", "float32"),
+                       rep=case.get("rep", 1), halves=case.get("halves"))
     for s in case.get("parked", ()):
         args = args[:3] + (args[3].at[s].set(0),) + args[4:]
-    want = A._attend_gathered(*args, li=1)
-    got = A._attend_pages(*args, li=1, interpret=True)
+    want = A._attend_gathered(*args, li=1, scale=case.get("scale"))
+    got = A._attend_pages(*args, li=1, scale=case.get("scale"),
+                          interpret=True)
     assert got.shape == want.shape and got.dtype == want.dtype
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
@@ -225,19 +283,21 @@ def test_paged_kernel_tolerance_refuses_a_bfloat16_pass_over(rounded,
     assert err > 100 * 2e-6, err
 
 
+@pytest.mark.parametrize("rep", [1, 2], ids=["", "grouped"])
 @pytest.mark.parametrize("how", ["slots_swapped", "pages_moved",
                                  "neighbour_changed"])
-def test_paged_kernel_depends_on_a_slots_logical_content_alone(how):
+def test_paged_kernel_depends_on_a_slots_logical_content_alone(how, rep):
     """Bit for bit: two slots swapped give swapped results; the same rows
     held by other physical pages give the same result; and what another
     slot holds (its length, its rows) changes nothing — what preemption's
-    replay and speculative decoding's token identity rest on."""
+    replay and speculative decoding's token identity rest on.  With two
+    query heads a KV head as with one."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.conf import attention as A
     rng = np.random.RandomState(11)
     h, d, ps, P = 3, 8, 16, 20
     qh, pk, pv, table, pos, start = _paged_case(
-        rng, h, d, ps, P, 2, [170, 45], [20, 0])
+        rng, h, d, ps, P, 2, [170, 45], [20, 0], rep=rep)
     base = np.asarray(A._attend_pages(qh, pk, pv, table, pos, start, li=0,
                                       interpret=True))
     if how == "slots_swapped":
@@ -261,6 +321,47 @@ def test_paged_kernel_depends_on_a_slots_logical_content_alone(how):
         np.testing.assert_array_equal(got[0], base[0])
 
 
+@pytest.mark.parametrize("read", ["gathered", "kernel"])
+def test_differential_pairs_through_the_grouped_read(read, monkeypatch):
+    """SambaY's paged layer through ``paged_attention_read``: its 8 query
+    heads as grouped queries of 2 groups of ``2*dh`` lanes, each in its own
+    half, the pair's ``a1 - lambda a2`` taken of the two CONTEXTS and the
+    sub-norm after it, give what ``SambaYLM._diff_attend`` gives on the
+    gathered rows with the difference taken of the softmax weights — in
+    both lowerings of the read (the kernel in interpret mode)."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp import sambay
+    from deeplearning4j_tpu.nn.conf import attention as A
+    lm = sambay.SambaYLM(sambay.SambaYConfig(dtype="float32", seed=3))
+    c = lm.config
+    li = c.layerKinds().index("full")
+    lp = dict(lm.params["layers"][li])
+    rng = np.random.RandomState(7)
+    lp["sublnG"] = jnp.asarray(1 + 0.3 * rng.randn(2 * c.headSize),
+                               jnp.float32)
+    S, ps, P = 3, 16, 12
+    pos, start = [150, 33, 0], [20, 0, 0]
+    _, k, v, table, pos, start = _paged_case(
+        rng, c.nKvHeads, c.headSize, ps, P, 1, pos, start, layers=1)
+    table = table.at[2].set(0)              # a slot that holds nothing
+    q = jnp.asarray(rng.randn(S, 1, c.nHeads * c.headSize), jnp.float32)
+    cap = P * ps
+    kpos = jnp.arange(cap)[None, :]
+    valid = ((kpos <= pos[:, None]) & (kpos >= start[:, None]))[:, None]
+    want = lm._diff_attend(lp, li, q, k[0, table].reshape(S, cap, -1),
+                           v[0, table].reshape(S, cap, -1), valid)
+    if read == "kernel":
+        monkeypatch.setattr(
+            sambay, "paged_attention_read",
+            lambda qh, pk, pv, layer, *a, scale: A._attend_pages(
+                qh, pk, pv, *a, li=layer, scale=scale, interpret=True))
+    got = lm._diff_attend_paged(lp, li, q, k, v, table, pos, start)
+    assert got.shape == want.shape == (S, 1, c.nHeads * c.headSize)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+
+
 def test_paged_attention_lowers_the_reference_off_the_tpu():
     """``paged_attention`` chooses where it is lowered, from what it is
     lowered for: on the CPU that is the gathered reference (no kernel is
@@ -270,8 +371,8 @@ def test_paged_attention_lowers_the_reference_off_the_tpu():
     args = _paged_case(np.random.RandomState(0), 2, 4, 4, 3, 1, [5, 2],
                        [0, 1])
     before = A.paged_kernel_lowerings()
-    text = jax.jit(lambda *a: A._attend_p.bind(*a, li=0)).lower(
-        *args).as_text()
+    text = jax.jit(lambda q, k, v, *a: A.paged_attention_read(
+        q, k, v, 0, *a)).lower(*args).as_text()
     assert "custom_call" not in text
     assert A.paged_kernel_lowerings() == before
     cb = ContinuousBatcher(_lm(), name="gauge-lm", maxSlots=2, pageSize=4)

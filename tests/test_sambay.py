@@ -229,6 +229,32 @@ def test_continuous_batcher_serves_the_reference_tokens(ref, weights,
     assert sm.ring_wraps().value(model="sambay") >= 20
 
 
+def test_paged_layer_is_read_through_the_gathered_lowering_here(family,
+                                                               weights):
+    """The step reads its paged layer through ``paged_attention_read``,
+    which is lowered where the program is: here on the CPU the gathered
+    reference, so the batcher's gauges read 0 (1 / 1 on one TPU, where the
+    eight readers go through the kernel: ``tests/test_tpu_compile.py``)."""
+    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.remote import BucketLadder, ContinuousBatcher
+    from deeplearning4j_tpu.telemetry import serving_metrics
+    sm = serving_metrics()
+    sm.paged_attention_kernel().set(1, model="sambay-gauge")
+    sm.paged_attention_kv_passes().set(1, model="sambay-gauge")
+    before = paged_kernel_lowerings()
+    cb = ContinuousBatcher(
+        _lm(family, weights, "float32"), name="sambay-gauge",
+        maxSlots=SLOTS, pageSize=PAGE, numPages=1 + SLOTS * (CAP // PAGE),
+        ladder=BucketLadder(batchSizes=(SLOTS,), seqLens=(8,)))
+    try:
+        cb.warm()
+    finally:
+        cb.shutdown()
+    assert paged_kernel_lowerings() == before
+    assert sm.paged_attention_kernel().value(model="sambay-gauge") == 0
+    assert sm.paged_attention_kv_passes().value(model="sambay-gauge") == 0
+
+
 def test_preempt_replay_and_evacuate_return_the_same_tokens(ref, weights,
                                                             batcher):
     """A preempted sequence restarts from its prompt: prefill rebuilds

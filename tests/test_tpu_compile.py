@@ -182,13 +182,14 @@ def test_paged_attention_is_the_kernel_for_one_tpu_only(topo, one_chip,
     assert kernels == (1 if chips == 1 else 0)
 
 
-# the rows the kernel's callers bring, and the one the next will (a
-# grouped-KV layer read by eight: ROADMAP S1 b): heads, head size, the
-# pool's dtype, pages a slot, slots, layers
+# the rows the kernel's callers bring: KV heads, head size, the pool's
+# dtype, pages a slot, slots, layers, query heads a KV head.  SambaY's one
+# paged layer keeps 20 KV heads of 64 in differential pairs: to the kernel
+# 10 heads of 128 lanes with 4 of its 40 query heads on each
 KERNEL_ROWS = {
-    "olmo_hybrid_30x128_bf16": (30, 128, "bfloat16", 288, 16, 4),
-    "gpt2_xl_25x64_f32": (25, 64, "float32", 64, 4, 48),
-    "grouped_kv_20x64_bf16": (20, 64, "bfloat16", 160, 32, 8),
+    "olmo_hybrid_30x128_bf16": (30, 128, "bfloat16", 288, 16, 4, 1),
+    "gpt2_xl_25x64_f32": (25, 64, "float32", 64, 4, 48, 1),
+    "grouped_kv_20x64_bf16": (10, 128, "bfloat16", 160, 32, 1, 4),
 }
 
 
@@ -196,12 +197,13 @@ KERNEL_ROWS = {
 def test_paged_kernel_alone_compiles_at_its_callers_rows(one_chip, row):
     """``_pages_call`` by itself (a kernel compiles in a second or two):
     Mosaic takes the body at each row shape, the last lane tile of 1,600
-    lanes half full, and nothing the size of a page is set aside in HBM
-    for it (its blocks are VMEM scratch)."""
+    lanes half full, four query heads' rows on each KV head of SambaY's,
+    and nothing the size of a page is set aside in HBM for it (its blocks
+    are VMEM scratch)."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.conf import attention as A
-    h, d, dtype, perSeq, slots, layers = KERNEL_ROWS[row]
+    h, d, dtype, perSeq, slots, layers, nRep = KERNEL_ROWS[row]
 
     def sds(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -211,8 +213,8 @@ def test_paged_kernel_alone_compiles_at_its_callers_rows(one_chip, row):
     compiled = A._pages_call.lower(
         sds((1,)), sds((places * C,)), sds((places,)), sds((places,)),
         sds((places,)), sds(()), sds((slots,)), sds((slots,)),
-        sds((slots, 1, h * d), jnp.float32), pool, pool, headSize=d,
-        interpret=False).compile()
+        sds((slots, nRep, h * d), jnp.float32), pool, pool, headSize=d,
+        tq=1, interpret=False).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes \
         < PAGE_SIZE * h * d * pool.dtype.itemsize
@@ -311,8 +313,11 @@ def _whole_array_copies(compiled, arrays):
 
 
 def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_kernel_kv_passes, paged_kernel_lowerings)
     lm, params, pool, i32 = sambay
     perSeq = PHI_CAP // PAGE_SIZE
+    before = paged_kernel_lowerings()
     compiled = lm.buildPagedDecodeFn().lower(
         params, *pool, i32(PHI_SLOTS, 1), i32(PHI_SLOTS, 1),
         i32(PHI_SLOTS, perSeq), i32(PHI_SLOTS), i32(PHI_SLOTS)).compile()
@@ -321,6 +326,24 @@ def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
     # the six arrays are donated and come back aliased, not copied
     _assert_one_step_program(compiled, pool)
     assert not _whole_array_copies(compiled, pool)
+    # the full layer and the 7 cross layers read the ONE paged layer
+    # through the kernel, its bfloat16 rows in one MXU pass a tile
+    text = compiled.as_text()
+    kinds = lm.config.layerKinds()
+    assert kinds.count("full") + kinds.count("cross") == 8
+    # (one lowering serves the eight: they bind the same read of layer 0)
+    assert paged_kernel_lowerings() > before
+    assert paged_kernel_kv_passes() == 1
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 8
+    assert len(re.findall(r"%paged_attention[\w.]* = ", text)) == 8
+    # so no slot's capacity is gathered (32 slots of 2,560 rows of 1,280
+    # lanes) and no gathered row is re-laid into its 10 groups
+    rows = PHI_SLOTS, PHI_CAP
+    assert "bf16[%d,%d,1280]" % rows not in text
+    assert "bf16[%d,%d,10,128]" % rows not in text
+    # found: 75,677,184 bytes (790,092,288 with the gather and its two
+    # re-layouts)
+    assert mem.temp_size_in_bytes < 0.1e9
 
 
 def test_sambay_prefill_and_admission_write_fit(sambay):
