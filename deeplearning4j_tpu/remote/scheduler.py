@@ -73,6 +73,7 @@ from deeplearning4j_tpu.remote.serving import (AdmissionControl,
 from deeplearning4j_tpu.telemetry import (SERVING_LOOP_PHASES,
                                           RequestContext, ThresholdRule,
                                           current_context, flight_recorder,
+                                          gc_pause_seconds,
                                           observe_exemplar, serving_metrics,
                                           timeline_store, tracer)
 
@@ -80,6 +81,12 @@ __all__ = ["KVCachePool", "ContinuousBatcher", "ReplicaSet"]
 
 
 _PROBE_FN = None
+
+#: an idle stretch of the device this long is also a Chrome event
+#: (``serving.device.idle``); a loop phase, or a stretch the device was
+#: starved for, this long is a stall and leaves ``serving.loop.stall``
+_IDLE_EVENT_SECONDS = 0.001
+_STALL_SECONDS = 0.1
 
 
 def _probe_fn():
@@ -494,6 +501,16 @@ class ContinuousBatcher:
         # pages, with tokens still unread on the device: the step that
         # computes a quota's last token is known at its dispatch
         self._parted: List[_Seq] = []
+        # the drain clock, the loop thread's own (it is the only
+        # dispatcher of device work here): what it last gave the device,
+        # the last instant it knew the device to hold work (every
+        # dispatch), the instant it learned the device had run out (a
+        # blocking read of that last thing returned; None while unknown)
+        # and what it has done since, which names the idle stretch
+        self._given = None
+        self._busyAt = 0.0
+        self._drainedAt: Optional[float] = None
+        self._idleCause = "loop"
         # request queue — guarded by _cv
         self._queue: deque = deque()
         self._queuedRows = 0
@@ -667,6 +684,7 @@ class ContinuousBatcher:
                 self._writeState(self.draftPool, "dwrite", state, ids,
                                  slot0)
         jax.block_until_ready(self.pool.arrays)  # jaxlint: sync-ok -- warm-up fence: compile cost must land in warmup_seconds, not the first request
+        self._atRest()
         self._warmed = True
         dt = time.perf_counter() - t0
         sm.warmup_seconds().observe(dt, model=self.name)
@@ -697,7 +715,9 @@ class ContinuousBatcher:
         # reader of every kind of cache state finds all of them
         sm.ring_rows_in_use().set(0, model=self.name)
         sm.cache_bytes().set(0, model=self.name, kind="ring")
+        sm.device_idle_seconds()
         self.warm()
+        self._atRest()
         self._updatePageGauges()
         self._cacheSeen = self.compileCacheSize()
         self._running = True
@@ -985,6 +1005,64 @@ class ContinuousBatcher:
     def _observePhase(self, phase: str, seconds: float) -> None:
         serving_metrics().loop_phase_seconds().observe(
             seconds, model=self.name, phase=phase)
+        if seconds >= _STALL_SECONDS and phase != "wait":
+            self._stall(phase, seconds)
+
+    def _stall(self, what: str, seconds: float) -> None:
+        """A loop phase, or a stretch the device was starved for, of
+        ``_STALL_SECONDS`` or more has just ended: leave what a process
+        can cheaply know it coincided with."""
+        tracer().instant(
+            "serving.loop.stall", replica=self.name, phase=what,
+            seconds=round(seconds, 6),
+            gc_seconds=round(
+                gc_pause_seconds(time.perf_counter() - seconds), 6),
+            threads=threading.active_count(), queued=self._queuedRows)
+
+    # -- the drain clock ------------------------------------------------
+    def _atRest(self) -> None:
+        """Nothing the loop gave the device is unfinished (the warm-up's
+        fence; a start): the device's idle time counts from here."""
+        self._given, self._idleCause = None, "loop"
+        self._drainedAt = time.perf_counter()
+
+    def _starved(self) -> Optional[Tuple[float, bool]]:
+        """Before every dispatch onto the device (a prefill, a draft's
+        proposal, a step): since when it has stood idle, and whether that
+        instant is an upper bound; None while it holds work.  From a
+        drain instant on record the stretch is exact; where none is on
+        record and what was dispatched last ``is_ready()``, the device
+        ran dry at an unknown instant since the loop last knew it to hold
+        work (the return of the dispatch before), and the stretch since
+        THAT instant is an upper bound."""
+        if self._drainedAt is not None:
+            return self._drainedAt, False
+        if self._given is not None and self._given.is_ready():
+            return self._busyAt, True
+        return None
+
+    def _fed(self, starved: Optional[Tuple[float, bool]], given) -> None:
+        """The dispatch of ``given`` has returned: the device holds work
+        from here, and a stretch it stood idle for (``_starved()``, asked
+        before the call) ends here, the call included: the device waits
+        through it for the launch.  With ``_starved`` one ``is_ready()``
+        and one clock read a dispatch; the one site that feeds
+        ``dl4j_tpu_serving_device_idle_seconds``."""
+        now = time.perf_counter()
+        if starved is not None:
+            (since, bound), cause = starved, self._idleCause
+            seconds = now - since
+            serving_metrics().device_idle_seconds().observe(
+                seconds, model=self.name, cause=cause)
+            if seconds >= _IDLE_EVENT_SECONDS:
+                tracer().record_complete(
+                    "serving.device.idle", since, seconds,
+                    args={"replica": self.name, "cause": cause,
+                          "bound": bound})
+                if seconds >= _STALL_SECONDS and cause != "wait":
+                    self._stall("device.idle." + cause, seconds)
+            self._drainedAt = None
+        self._given, self._busyAt, self._idleCause = given, now, "loop"
 
     def _idle(self) -> bool:
         return self._queuedRows == 0 and self._inflight is None and \
@@ -1005,6 +1083,7 @@ class ContinuousBatcher:
                 # stretch is on the record (and in a capture) while it
                 # lasts; the condition is looked at again under the lock,
                 # so an enqueue between the two looks is not slept through
+                self._idleCause = "wait"
                 with self._phase("wait"), self._cv:
                     if self._running and self._idle():
                         self._cv.wait(0.1)
@@ -1051,6 +1130,9 @@ class ContinuousBatcher:
         handler = self.onSequenceFailure
         handed: List[_Seq] = []
         self._inflight = None           # its tokens are never delivered
+        # nor is what the device holds known any more (the pools are
+        # rebuilt below; the warm-up that follows ends in a fence)
+        self._given = self._drainedAt = None
         for slot, seq in enumerate(self._slotSeq):
             if seq is None:
                 continue
@@ -1165,7 +1247,9 @@ class ContinuousBatcher:
         with span:
             slotA = jnp.asarray(slot, jnp.int32)
             ids = jnp.asarray(self.pool.heldIds(slot)[:nP], jnp.int32)
+            starved = self._starved()
             logits, *state = prefill(padded, lengths=[seq.realLen])
+            self._fed(starved, logits)
             with tracer().span("serving.state.write", replica=self.name,
                                slot=slot):
                 self._writeState(self.pool, "write", state, ids, slotA)
@@ -1176,8 +1260,16 @@ class ContinuousBatcher:
                                    jnp.int32)
                 self._writeState(self.draftPool, "dwrite", state, dids,
                                  slotA)
+            # the row's slice is the last thing this admission gives the
+            # device, behind the prefill and the state writes; a device
+            # runs what it is given in order, so when its read returns
+            # the device has run out of work, and stands idle from here
+            # to the next dispatch: the gap an admission leaves
+            # (ROADMAP S2)
+            self._given = logits[0]
             # jaxlint: sync-ok -- the prefill's greedy token seeds the host-side slot state
-            first = int(np.argmax(np.asarray(logits[0])))
+            first = int(np.argmax(np.asarray(self._given)))
+            self._drainedAt, self._idleCause = time.perf_counter(), "admit"
             if seq.forced and len(seq.emitted) < len(seq.forced):
                 # teacher-forced replay: the first token was already
                 # computed (and maybe delivered) before the move — force
@@ -1321,21 +1413,26 @@ class ContinuousBatcher:
         step = self._stepFns["step"]
         with self._phase("dispatch"):
             prev = self._noPrev if ahead is None else ahead.greedy
+            starved = self._starved()
             if self.draft is not None:
                 props, *self.draftPool.arrays = \
                     self._stepFns["propose"](
                         self.draft.params, *self.draftPool.arrays,
                         tokA, dpt, pos, startA)
+                self._fed(starved, props)
                 # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
                 propsH = np.asarray(props)
+                self._drainedAt = time.perf_counter()   # all it had
                 tokA = jnp.asarray(np.concatenate([tokH[:, None], propsH],
                                                   axis=1))
                 del props, dpt
+                starved = self._starved()
             else:
                 propsH = None
             greedy, *self.pool.arrays = step(
                 self.lm.params, *self.pool.arrays, tokA, prev, pt, pos,
                 startA)
+            self._fed(starved, greedy)
             # the step's inputs die here and not at the return, so that
             # freeing them is inside a phase (tens of microseconds: the
             # loop's time is to be accounted for)
@@ -1363,6 +1460,11 @@ class ContinuousBatcher:
         with self._phase("fetch"):
             # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
             g = np.asarray(flight.greedy)
+            if flight.greedy is self._given:
+                # nothing was dispatched behind this step (the last
+                # before the loop waits; every step under a draft): the
+                # device ran out of work here
+                self._drainedAt = time.perf_counter()
         with self._phase("emit"):
             self._emitStep(flight, g, propsH)
         with self._phase("bookkeep"):

@@ -669,6 +669,33 @@ class ServingMetrics:
             labelnames=("model", "phase"),
             buckets=SERVING_LOOP_PHASE_BUCKETS)
 
+    def device_idle_seconds(self):
+        return get_registry().histogram(
+            "dl4j_tpu_serving_device_idle_seconds",
+            "Stretches in which the device had nothing to run, as the "
+            "continuous batcher's loop thread (its only dispatcher) "
+            "knows them, each booked when the dispatch that ended it "
+            "returned, by cause: wait (the loop slept in it: nothing "
+            "queued, nothing in a slot, the traffic's pause), admit "
+            "(from an admission's first-token read to the next dispatch: "
+            "the loop had stopped for a prefill), loop (neither: "
+            "sequences held slots and the device ran dry between two "
+            "dispatches, the host slower than the step).  A stretch that "
+            "opens where a blocking read of the last thing dispatched "
+            "returned reads the device's gap FROM BELOW: it leaves out "
+            "that read's D2H and wake-up at its front and the launch "
+            "behind the dispatch call at its back (together 1-2 ms on a "
+            "TPU v5e); so every wait and admit stretch, and loop where "
+            "each step is read before the next (a draft model).  A loop "
+            "stretch that is_ready() alone found, at a dispatch with the "
+            "step before unread, is an UPPER BOUND: the time since the "
+            "dispatch before returned, of which the device worked one "
+            "step at most.  sum = idle seconds, count = dispatches that "
+            "found the device idle, the buckets from 0.1 s up = stalls; "
+            "per model",
+            labelnames=("model", "cause"),
+            buckets=SERVING_LOOP_PHASE_BUCKETS)
+
 
 _SERVING_METRICS = ServingMetrics()
 

@@ -20,13 +20,17 @@ time histograms in ``_seconds``.
 """
 from __future__ import annotations
 
+import gc
 import math
 import re
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+import time
+from collections import deque
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "get_registry", "set_registry", "DEFAULT_BUCKETS"]
+           "get_registry", "set_registry", "gc_pause_seconds",
+           "DEFAULT_BUCKETS"]
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -288,6 +292,29 @@ class MetricsRegistry:
     def __init__(self):
         self._metrics: Dict[str, _Metric] = {}
         self._lock = threading.Lock()
+        # the process's two garbage-collection series, a pair of cells a
+        # generation, once this registry is the process's (``_gc_series``)
+        self._gcCells: Optional[list] = None
+
+    def _gc_series(self) -> None:
+        """Give this registry the process's garbage-collection series.
+        The cells are made here, by a thread that holds no lock, because
+        the collector's hook (``_on_gc``) may take none: a collection
+        starts on whatever thread allocates, and that thread may hold
+        this registry's lock or a metric's at that instant."""
+        sec = self.counter(
+            "dl4j_tpu_process_gc_pause_seconds_total",
+            "Seconds the interpreter's garbage collector held every "
+            "thread of this process, by generation (two clock reads a "
+            "collection, from gc.callbacks)",
+            labelnames=("generation",))
+        n = self.counter(
+            "dl4j_tpu_process_gc_collections_total",
+            "Collections the interpreter's garbage collector ran, by "
+            "generation",
+            labelnames=("generation",))
+        self._gcCells = [(sec._cell({"generation": g}),
+                          n._cell({"generation": g})) for g in range(3)]
 
     def _register(self, cls, name: str, help: str, labelnames, **kw):
         with self._lock:
@@ -345,6 +372,8 @@ class MetricsRegistry:
         would otherwise leak state across test cases)."""
         with self._lock:
             self._metrics.clear()
+        if self._gcCells is not None:
+            self._gc_series()
 
     def exposition(self) -> str:
         """Prometheus text format, trailing newline included."""
@@ -367,6 +396,42 @@ class MetricsRegistry:
 _default = MetricsRegistry()
 _default_lock = threading.Lock()
 
+# -- what the collector cost, and when ------------------------------------
+# gc.callbacks runs inside the collection, on the thread whose allocation
+# started it, so the hook takes no lock and asks the registry for nothing:
+# it adds to cells the process's registry made beforehand (nobody else
+# writes them, and a reader's one attribute read cannot tear), and keeps
+# the end and length of the recent collections of a millisecond or more
+# for whoever asks what a stall coincided with.
+_gc_started = [0.0]
+_gc_recent: Deque[Tuple[float, float]] = deque(maxlen=64)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc_started[0] = time.perf_counter()
+        return
+    end = time.perf_counter()
+    seconds = end - _gc_started[0]
+    cells = _default._gcCells
+    if cells is not None:
+        pause, collections = cells[info["generation"]]
+        pause.v += seconds
+        collections.v += 1
+    if seconds >= 1e-3:
+        _gc_recent.append((end, seconds))
+
+
+def gc_pause_seconds(since: float) -> float:
+    """Seconds of garbage collection that ended after ``since`` (a
+    ``time.perf_counter()`` reading), among the last 64 collections of a
+    millisecond or more: what a stall can be held against."""
+    return sum(s for end, s in list(_gc_recent) if end >= since)
+
+
+_default._gc_series()
+gc.callbacks.append(_on_gc)
+
 
 def get_registry() -> MetricsRegistry:
     """The process-global default registry (what ``/metrics`` serves)."""
@@ -376,6 +441,8 @@ def get_registry() -> MetricsRegistry:
 def set_registry(registry: MetricsRegistry) -> MetricsRegistry:
     """Swap the process-global registry (tests); returns the previous one."""
     global _default
+    if registry._gcCells is None:
+        registry._gc_series()
     with _default_lock:
         prev, _default = _default, registry
     return prev

@@ -8,9 +8,17 @@ the program's), the decode loop's phases (one observation each a step,
 together covering the loop thread's time), the idle loop, ``h2d``
 observed in every fit path, the Chrome trace's nesting, and a phase left
 by an exception.
+
+The drain clock (ISSUE 36): the loop thread books every stretch the device
+stood idle to its cause at the dispatch that ends it
+(``dl4j_tpu_serving_device_idle_seconds{model, cause}``, the Chrome event
+``serving.device.idle``), a stall leaves ``serving.loop.stall``, and the
+collector's pauses are two process counters.
 """
+import gc
 import glob
 import os
+import threading
 import time
 
 import jax
@@ -18,6 +26,8 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu import telemetry
+from deeplearning4j_tpu.fault import injection
+from deeplearning4j_tpu.remote import scheduler
 from deeplearning4j_tpu.datasets import DataSet, ListDataSetIterator
 from deeplearning4j_tpu.learning import Adam
 from deeplearning4j_tpu.models import MultiLayerNetwork
@@ -36,6 +46,7 @@ pytestmark = pytest.mark.telemetry
 
 STEP_PHASES = ("grow", "upload", "dispatch", "fetch", "emit", "bookkeep")
 LOOP_HIST = "dl4j_tpu_serving_loop_phase_seconds"
+IDLE_HIST = "dl4j_tpu_serving_device_idle_seconds"
 
 
 @pytest.fixture(autouse=True)
@@ -72,9 +83,9 @@ def _ds(n=16, seed=0):
     return DataSet(x, y)
 
 
-def _phase_cells(model):
-    """{phase: (count, sum)} of the loop-phase histogram for ``model``."""
-    h = get_registry().get(LOOP_HIST)
+def _cells(hist, model, by):
+    """{value of label ``by``: (count, sum)} of ``hist`` for ``model``."""
+    h = get_registry().get(hist)
     if h is None:
         return {}
     d = h.data()
@@ -82,8 +93,18 @@ def _phase_cells(model):
     for key, cell in d["cells"]:
         lab = dict(zip(d["labelnames"], key))
         if lab["model"] == model:
-            out[lab["phase"]] = (cell["count"], cell["sum"])
+            out[lab[by]] = (cell["count"], cell["sum"])
     return out
+
+
+def _phase_cells(model):
+    """{phase: (count, sum)} of the loop-phase histogram for ``model``."""
+    return _cells(LOOP_HIST, model, "phase")
+
+
+def _idle_cells(model):
+    """{cause: (count, sum)} of the device-idle histogram for ``model``."""
+    return _cells(IDLE_HIST, model, "cause")
 
 
 def _generate(cb, quota, prompt=(1, 2, 3)):
@@ -232,7 +253,7 @@ def test_every_phase_once_a_step_and_the_loop_is_covered(spec):
     # the loop thread's wall time, off the Chrome trace: first admit to
     # the end of the last bookkeep; the phases are siblings on one thread
     evs = [e for e in tracer().events()
-           if e["name"].startswith("serving.loop.")
+           if e["name"].startswith("serving.loop.") and e["ph"] == "X"
            and e["name"] not in ("serving.loop.wait",
                                  "serving.loop.iteration")]
     assert len({e["tid"] for e in evs}) == 1
@@ -277,6 +298,242 @@ def test_an_idle_batcher_accrues_wait_and_nothing_else():
     assert {e["name"] for e in evs} == {"serving.loop.wait"}
     assert sum(e["dur"] for e in evs) * 1e-6 == pytest.approx(total)
     assert max(e["dur"] for e in evs) * 1e-6 < 0.2      # slices, not one
+
+
+# ------------------------------------------------------ the drain clock --
+
+DRAFT = pytest.mark.parametrize("spec", [False, True],
+                                ids=["plain", "draft"])
+
+
+def _batcher(name, spec, slots=2):
+    kw = dict(draft=_lm(seed=9), draftK=2) if spec else {}
+    return ContinuousBatcher(_lm(), name=name, maxSlots=slots, pageSize=8,
+                             **kw).start()
+
+
+def _idle_events(cause=None):
+    return sorted((e for e in tracer().events()
+                   if e["name"] == "serving.device.idle"
+                   and cause in (None, e["args"]["cause"])),
+                  key=lambda e: e["ts"])
+
+
+def _spans(name):
+    return sorted((e for e in tracer().events() if e["name"] == name),
+                  key=lambda e: e["ts"])
+
+
+def _stalls(prefix=""):
+    """The ``serving.loop.stall`` instants whose phase starts so (under
+    load a real phase may take its 0.1 s too)."""
+    return [e for e in _spans("serving.loop.stall")
+            if e["args"]["phase"].startswith(prefix)]
+
+
+@pytest.fixture
+def every_stretch_an_event(monkeypatch):
+    """On the CPU an admission's gap is under the millisecond from which
+    a stretch is also a Chrome event: keep them all."""
+    monkeypatch.setattr(scheduler, "_IDLE_EVENT_SECONDS", 0.0)
+
+
+@pytest.fixture
+def slowdown():
+    yield injection.set_replica_slowdown
+    injection.clear_serving_faults()
+
+
+@DRAFT
+def test_an_idle_stretch_before_a_request_is_booked_to_wait(spec):
+    t0 = time.perf_counter()
+    cb = _batcher("w", spec)
+    try:
+        time.sleep(0.3)
+        # one token: the prefill is the only dispatch, and the stretch it
+        # ends is the sleep, through which the loop waited
+        _generate(cb, 1)
+    finally:
+        cb.shutdown()
+    elapsed = time.perf_counter() - t0
+    cells = _idle_cells("w")
+    assert set(cells) == {"wait"}, cells
+    count, total = cells["wait"]
+    assert count == 1 and 0.3 <= total <= elapsed
+    ev, = _idle_events()
+    assert ev["args"] == {"replica": "w", "cause": "wait", "bound": False}
+    assert ev["dur"] * 1e-6 == pytest.approx(total)
+    # on the loop thread's track, over the wait slices it slept through,
+    # up to the prefill's dispatch
+    waits = _spans("serving.loop.wait")
+    prefill, = _spans("serving.prefill")
+    assert ev["tid"] == prefill["tid"] == waits[0]["tid"]
+    inside = [w for w in waits if ev["ts"] <= w["ts"]
+              and w["ts"] + w["dur"] <= ev["ts"] + ev["dur"]]
+    assert len(inside) >= 3
+    assert prefill["ts"] <= ev["ts"] + ev["dur"] \
+        <= prefill["ts"] + prefill["dur"]
+    # a pause of the traffic is not a stall of the program
+    assert not _stalls("device.idle.")
+
+
+@DRAFT
+def test_an_admission_books_one_stretch_to_admit(spec,
+                                                 every_stretch_an_event):
+    cb = _batcher("a", spec)
+    per_client, quota = 3, 6
+
+    def client():
+        for _ in range(per_client):
+            _generate(cb, quota)
+
+    try:
+        clients = [threading.Thread(target=client) for _ in range(4)]
+        for c in clients:
+            c.start()
+        for c in clients:
+            c.join()
+    finally:
+        cb.shutdown()
+    admitted = int(get_registry().get(
+        "dl4j_tpu_serving_sequences_admitted_total").value(model="a"))
+    assert admitted == 4 * per_client
+    cells = _idle_cells("a")
+    # every admission is followed by a dispatch (a step for its second
+    # token, or the next admission's prefill), which ends its stretch
+    assert cells["admit"][0] == admitted
+    stretches = _idle_events("admit")
+    assert len(stretches) == admitted
+    assert sum(e["dur"] for e in stretches) * 1e-6 == \
+        pytest.approx(cells["admit"][1])
+    assert all(e["args"]["bound"] is False for e in stretches)
+    # each opens inside an admit phase (at its first-token read) and ends
+    # inside that phase (the next admission's prefill) or the dispatch
+    # phase that follows it
+    admits = _spans("serving.loop.admit")
+    dispatches = _spans("serving.loop.dispatch")
+    for e in stretches:
+        opened = [a for a in admits
+                  if a["ts"] <= e["ts"] <= a["ts"] + a["dur"]]
+        assert len(opened) == 1
+        closes = next(d["ts"] + d["dur"] for d in dispatches
+                      if d["ts"] + d["dur"] >= e["ts"])
+        assert e["ts"] + e["dur"] <= max(
+            closes, opened[0]["ts"] + opened[0]["dur"])
+
+
+@DRAFT
+def test_a_slow_loop_is_booked_to_loop_and_not_to_admit(spec, slowdown):
+    delay, quota = 0.03, 9
+    cb = _batcher("s", spec)
+    try:
+        slowdown("s", delay)
+        t0 = time.perf_counter()
+        _generate(cb, quota)
+        elapsed = time.perf_counter() - t0
+    finally:
+        cb.shutdown()
+    steps = int(get_registry().get(
+        "dl4j_tpu_serving_decode_steps_total").value(model="s"))
+    cells = _idle_cells("s")
+    # the first step's dispatch ends the admission's stretch, which holds
+    # one delay; every later one finds the device idle for a delay and
+    # the loop's own work (with a draft: each step is read before the
+    # next, so the stretch is exact; without: is_ready() found it, and
+    # the stretch since the dispatch before is a bound)
+    assert cells["admit"][0] == 1 and cells["admit"][1] < 2 * delay + 0.1
+    count, total = cells["loop"]
+    assert steps - 1 <= count <= 2 * steps
+    assert 0.7 * delay * (steps - 1) <= total <= elapsed
+    bounds = {e["args"]["bound"] for e in _idle_events("loop")}
+    assert bounds == ({False} if spec else {True})
+
+
+@DRAFT
+def test_idle_stretches_are_disjoint_and_inside_the_wall_time(
+        spec, every_stretch_an_event, slowdown):
+    cb = _batcher("d", spec)
+    try:
+        _generate(cb, 5)
+        time.sleep(0.15)
+        slowdown("d", 0.005)
+        _generate(cb, 5)
+    finally:
+        cb.shutdown()
+    evs = _idle_events()
+    assert {e["args"]["cause"] for e in evs} == {"wait", "admit", "loop"}
+    for a, b in zip(evs, evs[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3     # us
+    # all but the wait before the first request lie between the first
+    # dispatch onto the device (its prefill) and the end of the last
+    first = _spans("serving.prefill")[0]["ts"]
+    last = max(e["ts"] + e["dur"] for e in _spans("serving.loop.dispatch"))
+    assert evs[0]["args"]["cause"] == "wait"
+    assert evs[0]["ts"] + evs[0]["dur"] >= first
+    assert sum(e["dur"] for e in evs[1:]) <= last - first
+    cells = _idle_cells("d")
+    assert sum(n for n, _s in cells.values()) == len(evs)
+    assert sum(s for _n, s in cells.values()) == pytest.approx(
+        sum(e["dur"] for e in evs) * 1e-6)
+
+
+def test_a_stall_leaves_one_instant_with_what_it_coincided_with(slowdown):
+    cb = _batcher("st", False)
+    try:
+        time.sleep(0.15)                # a wait of 0.1 s or more: no stall
+        slowdown("st", 0.12)
+        _generate(cb, 3)
+    finally:
+        cb.shutdown()
+    long = [e for e in _idle_events() if e["dur"] >= 0.1e6]
+    assert [e["args"]["cause"] for e in long] == ["wait", "admit", "loop"]
+    stalls = _stalls("device.idle.")
+    assert [e["args"]["phase"] for e in stalls] == \
+        ["device.idle.admit", "device.idle.loop"]
+    for st, e in zip(stalls, long[1:]):
+        assert st["ph"] == "i" and st["tid"] == e["tid"]
+        assert set(st["args"]) == {"replica", "phase", "seconds",
+                                   "gc_seconds", "threads", "queued"}
+        assert st["args"]["seconds"] == pytest.approx(e["dur"] * 1e-6,
+                                                      abs=1e-5)
+        assert st["args"]["threads"] >= 2 and st["args"]["queued"] == 0
+        assert 0.0 <= st["args"]["gc_seconds"] <= st["args"]["seconds"]
+    # a loop phase of 0.1 s or more leaves one too; a wait slice does not
+    seen = len(_stalls())
+    cb._observePhase("emit", 0.2)
+    cb._observePhase("wait", 0.2)
+    assert [e["args"]["phase"] for e in _stalls()[seen:]] == ["emit"]
+
+
+def test_a_collection_raises_both_gc_series():
+    def read():
+        reg = get_registry()
+        return [reg.get("dl4j_tpu_process_gc_" + n).value(generation="2")
+                for n in ("pause_seconds_total", "collections_total")]
+
+    before = read()
+    junk = [[i] for i in range(1000)]
+    junk.append(junk)
+    del junk
+    t0 = time.perf_counter()
+    gc.collect()
+    elapsed = time.perf_counter() - t0
+    after = read()
+    assert after[1] == before[1] + 1
+    assert 0.0 < after[0] - before[0] <= elapsed
+    assert telemetry.gc_pause_seconds(t0) <= elapsed
+    # the series belong to the process's registry, whichever it is
+    fresh = MetricsRegistry()
+    assert fresh.get("dl4j_tpu_process_gc_collections_total") is None
+    prev = telemetry.set_registry(fresh)
+    try:
+        gc.collect()
+        assert fresh.get("dl4j_tpu_process_gc_collections_total").value(
+            generation="2") == 1
+        assert read()[0] > 0.0
+    finally:
+        telemetry.set_registry(prev)
+    assert read()[1] == after[1]
 
 
 # ------------------------------------------------ h2d in every fit path --
@@ -350,7 +607,7 @@ def test_chrome_trace_keeps_serving_spans_with_args_and_nesting():
         assert st["args"]["replica"] == "ct"
         assert st["args"]["active"] == (1 if i < 4 else 0)
         kids = [e for e in evs if e["tid"] == st["tid"]
-                and e["name"].startswith("serving.loop.")
+                and e["name"].startswith("serving.loop.") and e["ph"] == "X"
                 and st["ts"] <= e["ts"]
                 and e["ts"] + e["dur"] <= st["ts"] + st["dur"]]
         want = [p for p in STEP_PHASES
