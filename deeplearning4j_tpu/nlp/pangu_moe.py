@@ -32,9 +32,12 @@ the step ``pos - start``.  A pad has no position and is no key.
 largest are chosen, and this chip adds to the shared expert's output the
 part of the chosen experts it HOLDS; what the absent ones would have
 added is left out (the deployment's exchange would bring it), and the
-partial result goes on.  No token is dropped.  The step computes every
-held expert over every slot (``moe_share_dense``); forward and prefill
-sort the pairs by expert and multiply by group (``moe_share_grouped``).
+partial result goes on.  No token is dropped.  The step reads only the
+held experts that a live slot's token chose (``moe_share_step``: on one
+TPU a kernel over the hit experts, their ids scalar-prefetched; on the
+CPU or several devices every held expert over every slot,
+``moe_share_dense``); forward and prefill sort the pairs by expert and
+multiply by group (``moe_share_grouped``).
 Three counts of the routing are taken on the device in both
 (:data:`PanguMoELM.stepCounters`) and come back in the columns behind
 the step's tokens.
@@ -64,8 +67,8 @@ from deeplearning4j_tpu.nlp.sambay import _mm
 from deeplearning4j_tpu.parallel.ring import (_FLASH_MIN_T, _flash_refusal,
                                               flash_attention)
 from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
-                                             moe_share_dense,
                                              moe_share_grouped,
+                                             moe_share_step,
                                              route_sigmoid_topk)
 
 __all__ = ["PanguMoEConfig", "PanguMoELM"]
@@ -250,8 +253,8 @@ class PanguMoELM:
         idx, w = route_sigmoid_topk(h, lp["Wr"], c.expertsPerToken,
                                     c.routedScale)
         experts = (lp["Eg"], lp["Eu"], lp["Ed"], lo)
-        routed = moe_share_grouped(h, idx, w, *experts, real) if grouped \
-            else moe_share_dense(h, idx, w, *experts)
+        routed = (moe_share_grouped if grouped else moe_share_step)(
+            h, idx, w, *experts, real)
         return gated("Sgate", "Sup", "Sdown") + routed, \
             moe_share_counts(idx, lo, c.nHeld, real)
 

@@ -34,7 +34,7 @@ __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "RecurrentAttentionLayer", "KerasMultiHeadAttention",
            "paged_attention", "paged_attention_read",
            "paged_kernel_lowerings",
-           "paged_kernel_kv_passes",
+           "paged_kernel_kv_passes", "lowered_for_one_tpu",
            "paged_latent_attention", "paged_prefill_write",
            "paged_rows_write", "paged_step_tokens",
            "CacheSpec", "served_jit_entries", "drop_served_jits"]
@@ -494,14 +494,22 @@ def paged_kernel_kv_passes() -> int:
     return _kernelLowerings[1]
 
 
+def lowered_for_one_tpu(ctx) -> bool:
+    """Whether the program being lowered is built for ONE TPU: what a
+    primitive with a Mosaic kernel and a ``jax.numpy`` form chooses
+    between them by (no knob).  Not the CPU, and not several devices,
+    which a Mosaic kernel cannot be partitioned over."""
+    mc = ctx.module_context
+    return tuple(mc.platforms) == ("tpu",) and \
+        getattr(mc.axis_context, "num_devices", None) == 1
+
+
 def _lowered_as_kernel(ctx, poolDtype) -> bool:
     """Choose by what the program is lowered for, not by a knob: one TPU
     -> the kernel; the CPU, or several devices (a pool whose lanes are
-    split over a mesh, which a Mosaic kernel cannot be partitioned over)
-    -> the gathered reference.  Counts a kernel lowering."""
-    mc = ctx.module_context
-    kernel = tuple(mc.platforms) == ("tpu",) and \
-        getattr(mc.axis_context, "num_devices", None) == 1
+    split over a mesh) -> the gathered reference.  Counts a kernel
+    lowering."""
+    kernel = lowered_for_one_tpu(ctx)
     if kernel:
         _kernelLowerings[0] += 1
         _kernelLowerings[1] = _mxu_parts(poolDtype)

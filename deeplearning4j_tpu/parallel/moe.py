@@ -22,29 +22,40 @@ expert-parallel deployment, without the exchange.  It is told which
 experts it holds, routes every token over ALL experts
 (:func:`route_sigmoid_topk`), computes the part of the layer's output
 that its own experts contribute and leaves the rest out; no capacity
-factor, so no token is ever dropped.  Two forms of the same sum:
-:func:`moe_share_dense` (every held expert over every token: a decode
-step, where the experts' bytes bound the time whatever the form) and
-:func:`moe_share_grouped` (pairs sorted by expert, one grouped matmul a
-projection: a prefill, where all-over-all would be 16 times the work).
-:func:`moe_share_counts` counts what was routed where.
+factor, so no token is ever dropped.  Three forms of the same sum:
+:func:`moe_share_dense` (every held expert over every token: the
+reference form, and what runs on the CPU or over several devices),
+:func:`moe_share_step` (a decode step, where the experts' bytes bound the
+time: lowered for one TPU it is a kernel that reads only the held experts
+a real token of the step chose, their ids scalar-prefetched; elsewhere
+the dense form) and :func:`moe_share_grouped` (pairs sorted by expert,
+one grouped matmul a projection: a prefill, where all-over-all would be
+16 times the work).  :func:`moe_share_counts` counts what was routed
+where.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend import core as jex_core
+from jax.interpreters import mlir
 from jax.sharding import PartitionSpec as P
 
+from deeplearning4j_tpu.nn.conf.attention import lowered_for_one_tpu
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_expert_parallel",
            "MoELayer", "MoEFeedForwardLayer", "route_sigmoid_topk",
-           "moe_share_dense", "moe_share_grouped", "moe_share_counts"]
+           "moe_share_dense", "moe_share_step", "moe_share_grouped",
+           "moe_share_counts", "moe_step_kernel_lowerings"]
 
 
 def init_moe(key, n_experts: int, d_in: int, d_hidden: int, d_out: int,
@@ -185,37 +196,203 @@ def _held(idx, lo: int, n: int, real):
     return e, (e >= 0) & (e < n) & real[:, None]
 
 
+def _hit(idx, lo: int, n: int, real):
+    """``(n,)`` bool: the held experts that a real token chose."""
+    e, here = _held(idx, lo, n, real)
+    return jnp.any(here[..., None] & (e[..., None] == jnp.arange(n)),
+                   axis=(0, 1))
+
+
 def moe_share_counts(idx, lo: int, n: int, real):
     """``[pairs routed here, pairs whose expert is absent, held experts
     with a token]`` as int32, over the real tokens."""
-    e, here = _held(idx, lo, n, real)
-    routed = jnp.sum(here)
-    hit = jnp.sum(jnp.any(
-        here[..., None] & (e[..., None] == jnp.arange(n)), axis=(0, 1)))
+    routed = jnp.sum(_held(idx, lo, n, real)[1])
     return jnp.stack([routed, idx.shape[1] * jnp.sum(real) - routed,
-                      hit]).astype(jnp.int32)
+                      jnp.sum(_hit(idx, lo, n, real))]).astype(jnp.int32)
 
 
-def moe_share_dense(x, idx, w, Eg, Eu, Ed, lo: int):
+def _share_weights(idx, w, lo: int, n: int, real=None):
+    """``c (T, n)`` float32: the token's weight for expert ``lo + e``, 0
+    where it did not choose it (and for a token that is not ``real``)."""
+    c = jnp.sum(jnp.where(
+        (idx - lo)[..., None] == jnp.arange(n), w[..., None],
+        jnp.float32(0)), axis=1)
+    return c if real is None else jnp.where(real[:, None], c, jnp.float32(0))
+
+
+def moe_share_dense(x, idx, w, Eg, Eu, Ed, lo: int, real=None):
     """The held experts' part of the layer's output, every held expert
     over every token: ``sum_e c[t, e] Ed_e(silu(x Eg_e) * x Eu_e)`` with
     ``c`` the token's weight for expert ``lo + e``, 0 where it did not
-    choose it.  ``x (T, d)``; ``Eg, Eu (n, d, f)``, ``Ed (n, f, d)``;
-    float32 out.  The down-projection contracts experts and width at
-    once, so the weighted sum over experts is inside one matmul."""
+    choose it (and, given ``real (T,)``, for a token that is not real).
+    ``x (T, d)``; ``Eg, Eu (n, d, f)``, ``Ed (n, f, d)``; float32 out.
+    The down-projection contracts experts and width at once, so the
+    weighted sum over experts is inside one matmul."""
     n, _, f = Eg.shape
     T = x.shape[0]
     dt = Eg.dtype
     x = x.astype(dt)
-    c = jnp.sum(jnp.where(
-        (idx - lo)[..., None] == jnp.arange(n), w[..., None],
-        jnp.float32(0)), axis=1)
+    c = _share_weights(idx, w, lo, n, real)
     up = lambda W: jnp.einsum("td,edf->tef", x, W,
                               preferred_element_type=jnp.float32)
     h = jax.nn.silu(up(Eg)) * up(Eu) * c[..., None]           # (T, n, f)
     return jnp.matmul(h.reshape(T, n * f).astype(dt),
                       Ed.reshape(n * f, -1),
                       preferred_element_type=jnp.float32)
+
+
+# -- the step's form: only the held experts that were hit ----------------
+
+#: lanes of an expert's width ``f`` a place of the kernel's grid works on:
+#: three blocks (gate and up ``(d, tile)``, down ``(tile, d)``), each
+#: double-buffered by the pipeline: at d = 7,680 in bfloat16 7.86 MB a
+#: block, 47 MB of the 64 MB the call asks for.  Measured alone on a v5e
+#: at ``(32, 7680) x (16, 7680, 2048)`` with 4 / 10 / 16 experts hit (PR
+#: 37): 702 / 732 / 741 GB/s over the hit experts' bytes, where tiles of
+#: 256 lanes read 678 / 706 / 713 (a ``(d, 256)`` column block is 480
+#: runs of 8 KB) and 1,024 do not fit
+_EXPERT_TILE = 512
+
+
+def _hit_list(idx, lo: int, n: int, real):
+    """``(hit (n,) int32, nhit)``: the held experts a real token chose, in
+    rising order at the front of ``hit`` (a cumsum places each: no sort);
+    the entries past ``nhit`` name expert 0."""
+    chosen = _hit(idx, lo, n, real)
+    at = jnp.arange(n, dtype=jnp.int32)
+    place = jnp.cumsum(chosen) - 1
+    hit = jnp.sum(jnp.where(chosen & (place == at[:, None]), at, 0), axis=1)
+    return hit.astype(jnp.int32), jnp.sum(chosen).astype(jnp.int32)
+
+
+def _hit_kernel(_hit_ref, x_ref, c_ref, g_ref, u_ref, d_ref, o_ref):
+    """One place of the grid: one tile of one hit expert's width.  ``x``
+    (all rows) against the tile's columns of the gate and the up
+    projection, the token's weight for this expert on the product, and
+    the tile's rows of the down projection added into ``o_ref``, which
+    stays in VMEM over the whole grid."""
+    f32 = jnp.float32
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+    x = x_ref[...]
+    up = lambda ref: jnp.dot(x, ref[...], preferred_element_type=f32)
+    h = jax.nn.silu(up(g_ref)) * up(u_ref) * c_ref[...]       # (T, tile)
+    o_ref[...] += jnp.dot(h.astype(d_ref.dtype), d_ref[...],
+                          preferred_element_type=f32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _hit_call(hit, nhit, x, c, Eg, Eu, Ed, *, interpret):
+    """The kernel's call: ``x (T, d)`` in the weights' dtype, ``c (n, T,
+    1)`` float32, ``T`` whole sublane tiles.  The grid walks ``nhit``
+    experts x the tiles of their width; the index maps name the blocks of
+    expert ``hit[i]`` in the stacked weights, which go in whole (no
+    expert is sliced out or copied first), and the pipeline copies the
+    next place's three blocks while this one computes.  With no expert
+    hit the grid still walks one (``hit[0]`` = 0, whose weights ``c`` are
+    all 0: the output block is written, as zeros).  A jit of its own with
+    the weights as arguments: every expert layer of a step is then the
+    same computation, traced and lowered to Mosaic once a program (see
+    ``nn/conf/attention.py:_pages_call``)."""
+    n, d, f = Eg.shape
+    T, dout = x.shape[0], Ed.shape[-1]
+    tile = _EXPERT_TILE if f % _EXPERT_TILE == 0 else f
+    # index maps: ``i * 0`` and not ``0`` (the package enables x64, and a
+    # bare literal would be an int64 Mosaic has not)
+    return pl.pallas_call(
+        _hit_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(jnp.maximum(nhit, 1), f // tile),
+            in_specs=[
+                pl.BlockSpec((T, d), lambda i, j, hit: (i * 0, i * 0)),
+                pl.BlockSpec((None, T, 1),
+                             lambda i, j, hit: (hit[i], i * 0, i * 0)),
+                pl.BlockSpec((None, d, tile),
+                             lambda i, j, hit: (hit[i], i * 0, j)),
+                pl.BlockSpec((None, d, tile),
+                             lambda i, j, hit: (hit[i], i * 0, j)),
+                pl.BlockSpec((None, tile, dout),
+                             lambda i, j, hit: (hit[i], j, i * 0)),
+            ],
+            out_specs=pl.BlockSpec((T, dout),
+                                   lambda i, j, hit: (i * 0, i * 0))),
+        out_shape=jax.ShapeDtypeStruct((T, dout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name="moe_share_step",
+        interpret=interpret,
+    )(hit, x, c, Eg, Eu, Ed)
+
+
+def _share_hit(x, idx, w, Eg, Eu, Ed, lo: int, real, interpret=False):
+    """:func:`moe_share_dense`'s sum over the held experts that a real
+    token of ``x`` chose, expert by expert, through the kernel: an expert
+    nobody chose is not read.  Same operands and precision as the dense
+    form (the weights' dtype into the MXU, float32 sums, the float32
+    ``c``); the sum over experts runs in the order of the hit list where
+    the dense form contracts them in one matmul.  Rows are padded to
+    whole sublane tiles (16: a bfloat16 tile)."""
+    n = Eg.shape[0]
+    T = x.shape[0]
+    pad = -T % 16
+    c = jnp.pad(_share_weights(idx, w, lo, n, real), ((0, pad), (0, 0)))
+    out = _hit_call(*_hit_list(idx, lo, n, real),
+                    jnp.pad(x.astype(Eg.dtype), ((0, pad), (0, 0))),
+                    c.T[..., None], Eg, Eu, Ed, interpret=interpret)
+    return out[:T]
+
+
+#: how often the step's expert layer was lowered as the kernel (program
+#: telemetry: the batcher's gauge reads it around its warm-up)
+_stepKernelLowerings = [0]
+
+
+def moe_step_kernel_lowerings() -> int:
+    """How many times :func:`moe_share_step` has been lowered as the TPU
+    kernel in this process (once a program built for one TPU, whose
+    expert layers of one shape share the lowering; never on the CPU or
+    for several devices)."""
+    return _stepKernelLowerings[0]
+
+
+def _share_step_lowering(ctx, *args, lo):
+    kernel = lowered_for_one_tpu(ctx)
+    _stepKernelLowerings[0] += kernel
+    form = _share_hit if kernel else moe_share_dense
+    return mlir.lower_fun(lambda *a: form(*a[:-1], lo, a[-1]),
+                          multiple_results=False)(ctx, *args)
+
+
+_share_step_p = jex_core.Primitive("moe_share_step")
+
+
+@functools.partial(jax.jit, static_argnames=("lo",))
+def _share_step_eager(*args, lo):
+    """Outside any jit the primitive runs as a program of its own."""
+    return _share_step_p.bind(*args, lo=lo)
+
+
+_share_step_p.def_impl(_share_step_eager)
+_share_step_p.def_abstract_eval(
+    lambda x, idx, w, Eg, Eu, Ed, real, *, lo: jax.core.ShapedArray(
+        (x.shape[0], Ed.shape[-1]), jnp.float32))
+mlir.register_lowering(_share_step_p, _share_step_lowering)
+
+
+def moe_share_step(x, idx, w, Eg, Eu, Ed, lo: int, real):
+    """:func:`moe_share_dense` as a decode step runs it, where the
+    experts' bytes are the time: an expert's weights are read only if a
+    ``real (T,)`` token of this step chose it.  Chosen by what the
+    program is lowered for, not by a knob (the rule of
+    ``paged_attention``): one TPU -> the kernel over the hit experts
+    (:func:`_share_hit`); the CPU or several devices -> the dense form.
+    The rows of tokens that are not real come back as zeros in both."""
+    return _share_step_p.bind(x, idx, w, Eg, Eu, Ed, real, lo=lo)
 
 
 def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real):

@@ -65,6 +65,7 @@ from deeplearning4j_tpu.fault import injection as _inj
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_kernel_kv_passes,
                                                   paged_kernel_lowerings)
+from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
                                                DeadlineExceeded,
@@ -649,13 +650,17 @@ class ContinuousBatcher:
         tok0 = jnp.zeros((S, 1), jnp.int32)
         pt = jnp.asarray(self.pool.pageTable)
         step = self._stepFns["step"]
-        lowered = paged_kernel_lowerings()
+        lowered, experts = paged_kernel_lowerings(), \
+            moe_step_kernel_lowerings()
         prev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
         kernel = paged_kernel_lowerings() > lowered
         sm.paged_attention_kernel().set(1 if kernel else 0, model=self.name)
         sm.paged_attention_kv_passes().set(
             paged_kernel_kv_passes() if kernel else 0, model=self.name)
+        sm.moe_step_kernel().set(
+            1 if moe_step_kernel_lowerings() > experts else 0,
+            model=self.name)
         # and with a step's own output for ``prev``, as every later call
         # has it: beside committed params that is another entry of the
         # jit's cache than fresh zeros.  It stands in wherever no step is

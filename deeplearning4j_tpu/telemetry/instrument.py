@@ -436,6 +436,19 @@ class ServingMetrics:
             "step gathers (paged_attention_kernel 0)",
             labelnames=("model",))
 
+    def moe_step_kernel(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_moe_step_kernel",
+            "1 when the batcher's decode step was built with the kernel "
+            "that reads only the held experts a live slot's token chose "
+            "(an expert layer that holds a share, lowered for one TPU), 0 "
+            "when it multiplies every held expert over every slot (the "
+            "CPU, several devices) and for a model without such a layer; "
+            "the share of the held experts' weights a kernel step reads "
+            "is moe_experts_hit_total{phase=\"step\"} / (experts held x "
+            "expert layers x decode steps)",
+            labelnames=("model",))
+
     def kv_pages_free(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_kv_pages_free",
@@ -647,7 +660,8 @@ class ServingMetrics:
             "Held experts with at least one token, summed over the expert "
             "layers and the steps (or prefills): over experts held x "
             "layers x steps it is the share of the held experts' weights "
-            "a step has to read",
+            "a step has to read, and the share it DOES read where "
+            "moe_step_kernel is 1 (elsewhere the step reads them all)",
             labelnames=("model", "phase"))
 
     def loop_phase_seconds(self):

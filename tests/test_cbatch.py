@@ -383,6 +383,9 @@ def test_paged_attention_lowers_the_reference_off_the_tpu():
     assert get_registry().get(
         "dl4j_tpu_serving_paged_attention_kernel").value(
             model="gauge-lm") == 0
+    # and a model without an expert layer has no expert kernel anywhere
+    assert get_registry().get(
+        "dl4j_tpu_serving_moe_step_kernel").value(model="gauge-lm") == 0
 
 
 def test_kv_passes_gauge_reads_zero_for_a_gathered_step():

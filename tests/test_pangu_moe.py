@@ -273,6 +273,147 @@ def test_grouped_matmul_is_the_dense_and_masked_form_and_drops_nothing(
         assert (routed, absent, hit) == (4 * T, 0, 4)
 
 
+# -- the step's form: only the held experts that were hit ---------------------
+STEP_HELD = (32, 48)        # 16 of 256 experts, 8 a token, as the cell has it
+
+
+def _step_routing(case, T, seed=0):
+    """``(idx (T, 8), w (T, 8), real (T,), experts hit)`` over 256 experts
+    of which ``STEP_HELD`` are held: every choice absent, then the case's
+    held experts planted (no expert twice in a row of ``idx``)."""
+    lo, hi = STEP_HELD
+    r = np.random.RandomState(seed)
+    absent = np.r_[0:lo, hi:256]
+    idx = np.stack([r.permutation(absent)[:8] for _ in range(T)]).astype(
+        np.int32)
+    real = np.ones((T,), bool)
+    real[r.permutation(T)[:T // 4]] = False          # slots that hold nothing
+    live = np.flatnonzero(real)
+    if case == "all_16_hit":
+        hit = list(range(16))
+        for i, e in enumerate(r.permutation(16).tolist() * 2):
+            idx[live[i % len(live)], i // len(live)] = lo + e
+    elif case == "one_hit":
+        hit = [9]
+        idx[live[:5], 2] = lo + 9
+    elif case == "none_hit":
+        hit = []
+    elif case == "gaps_3_7_15":
+        hit = [3, 7, 15]
+        for i, t in enumerate(live[:9]):
+            idx[t, i % 8] = lo + hit[i % 3]
+        idx[live[9], :3] = [lo + 3, lo + 7, lo + 15]
+    else:                   # an expert whose only chooser is no real token
+        assert case == "only_chooser_not_real"
+        hit = [2, 11]
+        idx[live[:3], 0] = lo + 2
+        idx[live[3:6], 1] = lo + 11
+        idx[np.flatnonzero(~real)[:2], 5] = lo + 6
+    w = r.rand(T, 8).astype(np.float32) + 0.1
+    return idx, w, real, hit
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("T", [32, 128])
+@pytest.mark.parametrize("case", ["all_16_hit", "one_hit", "none_hit",
+                                  "gaps_3_7_15", "only_chooser_not_real"])
+def test_step_kernel_reads_the_hit_experts_and_is_the_dense_form(case, T,
+                                                                 dtype):
+    """``_share_hit`` (the Pallas kernel over the hit experts, their ids
+    scalar-prefetched; interpreted) against ``moe_share_dense``: the same
+    operands in the weights' dtype, float32 sums, the float32 weights
+    ``c``; only the order of the sum over experts differs (measured 1.5e-6
+    in float32 and 5e-7 in bfloat16 at outputs of size 2-3; a bfloat16
+    ``h`` may round the other way on a last float32 bit of its
+    up-projection, so that tolerance is a bfloat16 step of one element).
+    The hit list holds exactly the case's experts: one that only a slot
+    without a sequence chose is not in it, and with none hit the output
+    is zeros.  Two tiles of the experts' width, rows of any count."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.parallel import moe
+    lo, hi = STEP_HELD
+    n, d, f = hi - lo, 64, 2 * moe._EXPERT_TILE
+    idx, w, real, want_hit = _step_routing(case, T)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(ks[0], (T, d), jnp.float32)
+    Eg, Eu, Ed = (
+        (0.2 * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+        for k, shape in zip(ks[1:], [(n, d, f), (n, d, f), (n, f, d)]))
+    hit, nhit = moe._hit_list(jnp.asarray(idx), lo, n, jnp.asarray(real))
+    assert np.asarray(hit)[:int(nhit)].tolist() == want_hit
+    assert not np.asarray(hit)[int(nhit):].any()
+    want = np.asarray(moe.moe_share_dense(x, idx, w, Eg, Eu, Ed, lo, real))
+    got = np.asarray(moe._share_hit(x, idx, w, Eg, Eu, Ed, lo, real,
+                                    interpret=True))
+    assert got.shape == want.shape == (T, d) and got.dtype == np.float32
+    assert not got[~real].any() and not want[~real].any()
+    if case == "none_hit":
+        assert not got.any()
+    else:
+        assert np.abs(want[real]).max() > 0.5
+    tol = 1e-5 if dtype == "float32" else 4e-3
+    assert np.abs(got - want).max() < tol * max(1.0, np.abs(want).max())
+
+
+def test_hit_list_agrees_with_the_counter_on_random_routings():
+    """The list of hit experts the kernel prefetches and its length
+    against ``moe_share_counts``' third count (what
+    ``moe_experts_hit_pct.longgen`` reads) and against the set itself,
+    over random routings with random slots empty."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.parallel import moe
+    lo, hi = STEP_HELD
+    n = hi - lo
+    seen = set()
+    for seed in range(24):
+        r = np.random.RandomState(seed)
+        T = int(r.choice([1, 7, 32, 100]))
+        # a router that leans on a few experts, so that 0..16 are hit
+        among = r.permutation(256)[:int(r.choice([12, 40, 256]))]
+        idx = np.stack([r.permutation(among)[:8] for _ in range(T)]).astype(
+            np.int32)
+        real = r.rand(T) < r.choice([0.0, 0.3, 1.0])
+        hit, nhit = moe._hit_list(jnp.asarray(idx), lo, n, jnp.asarray(real))
+        held = idx[real][(idx[real] >= lo) & (idx[real] < hi)] - lo
+        assert np.asarray(hit)[:int(nhit)].tolist() == sorted(set(
+            held.tolist()))
+        assert int(nhit) == int(moe.moe_share_counts(
+            jnp.asarray(idx), lo, n, jnp.asarray(real))[2])
+        seen.add(int(nhit))
+    assert 0 in seen and max(seen) >= 12
+
+
+def test_step_form_is_the_dense_form_off_the_tpu():
+    """``moe_share_step`` chooses by what the program is lowered for: on
+    the CPU (and eagerly, and over the suite's eight devices) it IS
+    ``moe_share_dense`` with the empty slots' rows zeroed, bit for bit,
+    and no kernel lowering is counted."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deeplearning4j_tpu.parallel import moe
+    lo, hi = STEP_HELD
+    idx, w, real, _ = _step_routing("gaps_3_7_15", 32)
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(ks[0], (32, 64), jnp.float32)
+    Eg, Eu, Ed = (0.2 * jax.random.normal(k, shape, jnp.float32)
+                  for k, shape in zip(ks[1:], [(16, 64, 128), (16, 64, 128),
+                                               (16, 128, 64)]))
+    before = moe.moe_step_kernel_lowerings()
+    want = np.asarray(moe.moe_share_dense(x, idx, w, Eg, Eu, Ed, lo, real))
+    step = lambda *a: moe.moe_share_step(*a, lo, jnp.asarray(real))
+    args = (x, jnp.asarray(idx), jnp.asarray(w), Eg, Eu, Ed)
+    np.testing.assert_array_equal(np.asarray(step(*args)), want)
+    np.testing.assert_array_equal(np.asarray(jax.jit(step)(*args)), want)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
+    split = NamedSharding(mesh, P(None, None, "model"))
+    sharded = jax.jit(step)(x, args[1], args[2], jax.device_put(Eg, split),
+                            jax.device_put(Eu, split), Ed)
+    assert np.abs(np.asarray(sharded) - want).max() < 1e-5
+    assert moe.moe_step_kernel_lowerings() == before
+
+
 # -- the model against the reference ------------------------------------------
 def _close(got, want, dtype):
     """The tolerance of ``dtype``, as set out at the top."""
@@ -502,6 +643,8 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts_routing(
     # off the TPU the step gathers: the kernel's gauges say so
     assert sm.paged_attention_kernel().value(model="pangu") == 0
     assert sm.paged_attention_kv_passes().value(model="pangu") == 0
+    # and its expert layers multiply every held expert over every slot
+    assert sm.moe_step_kernel().value(model="pangu") == 0
     got = {k: v - before[k] for k, v in _routing().items()}
     pairs = TINY["num_experts_per_tok"] * EXPERT_LAYERS
     # the last step's counts are read with its tokens; the prefills' ride

@@ -252,6 +252,35 @@ def test_latent_kernel_alone_compiles_at_its_callers_row(one_chip):
     assert compiled(576).memory_analysis().temp_size_in_bytes > 1e9
 
 
+def test_expert_step_kernel_alone_compiles_at_its_callers_shapes(one_chip):
+    """``_hit_call`` by itself, under the package's x64, at the shapes its
+    one caller brings: 32 slots' tokens against 16 held experts of
+    ``(7680, 2048)`` bfloat16, the hit list and its length as data (the
+    grid's first dimension is dynamic).  The stacked weights go in whole:
+    nothing is set aside in HBM, no expert is copied out, and the three
+    double-buffered blocks fit the 64 MB of VMEM the call asks for."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.parallel import moe
+    assert jax.config.jax_enable_x64
+    n, d, f, T = 16, 7680, 2048, 32
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    up, down = sds((n, d, f), jnp.bfloat16), sds((n, f, d), jnp.bfloat16)
+    compiled = moe._hit_call.lower(
+        sds((n,)), sds(()), sds((T, d), jnp.bfloat16),
+        sds((n, T, 1), jnp.float32), up, up, down,
+        interpret=False).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "moe_share_step" in text
+    # found 0: x, the output block and the weights' blocks are VMEM windows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+    assert not re.search(r"= bf16\[[\d,]*(?:7680,2048|2048,7680)\]\S* "
+                         r"(?:copy|dynamic-slice|fusion|slice)\(", text)
+
+
 @pytest.mark.parametrize("bucket", [16, 256])
 def test_paged_prefill_write_updates_the_pool_in_place(paged, bucket):
     import jax
@@ -485,12 +514,14 @@ PANGU_SLOTS, PANGU_CAP, PANGU_BUCKET = 32, 6144, 4096
 @pytest.fixture(scope="module")
 def pangu(one_chip):
     """``(lm, params, pool arrays, i32, the compiled decode step, the
-    attention kernels lowered for it)``: shapes on the described chip."""
+    attention kernels and the expert kernels lowered for it)``: shapes on
+    the described chip."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.nlp.pangu_moe import PanguMoEConfig, PanguMoELM
     from deeplearning4j_tpu.nn.conf.attention import (
         paged_kernel_kv_passes, paged_kernel_lowerings)
+    from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
     from deeplearning4j_tpu.remote import KVCachePool
 
     def on_chip(tree):
@@ -509,18 +540,22 @@ def pangu(one_chip):
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    before = paged_kernel_lowerings()
+    before = paged_kernel_lowerings(), moe_step_kernel_lowerings()
     # ``prev`` is a step's own output: the tokens and the six counts
     step = lm.buildPagedDecodeFn().lower(
         params, *pool, i32(PANGU_SLOTS, 1), i32(PANGU_SLOTS, 7),
         i32(PANGU_SLOTS, perSeq), i32(PANGU_SLOTS),
         i32(PANGU_SLOTS)).compile()
     assert paged_kernel_kv_passes() == 1
-    return lm, params, pool, i32, step, paged_kernel_lowerings() - before
+    return lm, params, pool, i32, step, (
+        paged_kernel_lowerings() - before[0],
+        moe_step_kernel_lowerings() - before[1])
 
 
 def test_pangu_moe_decode_step_fits_and_reads_its_latent_rows_in_place(
         pangu):
+    """Of the decode step at the cell's sizes: 9 kernel calls, 5 named for
+    the latent kernel and 4 for the expert kernel."""
     lm, params, pool, i32, compiled, kernelsLowered = pangu
     perSeq = PANGU_CAP // PAGE_SIZE
     mem = compiled.memory_analysis()
@@ -537,11 +572,27 @@ def test_pangu_moe_decode_step_fits_and_reads_its_latent_rows_in_place(
     # all five layers attend ABSORBED through the latent kernel over the
     # live pages: no slot's capacity is gathered, no key or value formed
     text = compiled.as_text()
-    assert kernelsLowered == 5
-    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                          text)) == 5
+    # (the four expert layers are one lowering: same shapes, same rule)
+    assert kernelsLowered == (5, 1)
+    kernels = re.findall(
+        r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert sorted(kernels) == ["moe_share_step"] * 4 \
+        + ["paged_latent_attention"] * 5, kernels
     assert f"bf16[{PANGU_SLOTS * perSeq},{PAGE_SIZE},640]" not in text
     assert f"[{PANGU_SLOTS},{PANGU_CAP},128,128]" not in text
+    # the four expert layers read the held experts where they lie, through
+    # the kernel over the experts that were hit: the stacked weights go in
+    # whole, no expert or stack of them is sliced out or copied (the
+    # temporaries, found 14 MB, have no room for ONE projection of one
+    # expert; the shared expert's ``Sdown`` has an expert's shape and is
+    # prefetched by ``copy-start``, which is no ``copy``), and nothing is
+    # computed for all 16 (no (slots, 16, 2048) product)
+    assert not re.search(
+        r"= bf16\[(?:16,|1,)?(?:7680,2048|2048,7680)\]\S* "
+        r"(?:copy|dynamic-slice|slice)\(", text)
+    assert mem.temp_size_in_bytes < 7680 * 2048 * 2
+    assert not re.search(rf"\[{PANGU_SLOTS},(?:16,2048|32768)\]", text)
 
 
 def test_pangu_moe_prefill_groups_its_experts_and_fits_beside_the_step(
