@@ -49,16 +49,15 @@ from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_prefill_write,
                                                   paged_step_tokens,
                                                   served_jit_entries)
-from deeplearning4j_tpu.nlp.sambay import _mm
+from deeplearning4j_tpu.nlp.mamba import _mm, _rms
+from deeplearning4j_tpu.nlp.served import (JitByLength, attend_full,
+                                           slot_state_write)
 
 __all__ = ["OlmoHybridConfig", "OlmoHybridLM", "delta_rule_chunked"]
 
 _F32 = jnp.float32
 _I32 = jnp.int32
-_NEG = -1e30
 _HI = jax.lax.Precision.HIGHEST
-#: queries a block of the full-sequence attention holds against every key
-_QUERY_BLOCK = 512
 
 
 @dataclasses.dataclass
@@ -92,12 +91,6 @@ class OlmoHybridConfig:
     def layerKinds(self) -> List[str]:
         return ["full" if i % self.fullEvery == self.fullEvery - 1
                 else "linear" for i in range(self.nLayers)]
-
-
-def _rms(x, g, eps):
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                             + eps) * g.astype(_F32)
 
 
 def _l2(x):
@@ -189,33 +182,6 @@ def delta_rule_chunked(q, k, v, beta, g, chunk: int):
                         (W, U, M, Qg, KhT, gC))
     o = o.transpose(1, 0, 3, 2, 4).reshape(b, n * C, H, dv)
     return o[:, :T], S
-
-
-class _JitByLength:
-    """``run(params, tokens, start)`` jitted once for each length of
-    ``tokens`` under that length's name (``jit_prefill_2048``), where the
-    other served models keep one jit named ``jit_run`` for every bucket: a
-    prefill of 512 positions and one of 4,096 differ by eight times in work,
-    and a device trace then says which one it holds.  Stands where the one
-    jit stood (called, counted by ``served_jit_entries``); ``at(t)`` is a
-    length's own jit, for ``lower`` and ``trace``."""
-
-    def __init__(self, run, name: str):
-        self._run, self._name, self._jits = run, name, {}
-
-    def at(self, t: int):
-        if t not in self._jits:
-            def run(*args):
-                return self._run(*args)
-            run.__name__ = f"{self._name}_{t}"
-            self._jits[t] = jax.jit(run)
-        return self._jits[t]
-
-    def __call__(self, params, tokens, start):
-        return self.at(tokens.shape[1])(params, tokens, start)
-
-    def _cache_size(self) -> int:
-        return sum(fn._cache_size() for fn in self._jits.values())
 
 
 class OlmoHybridLM:
@@ -344,34 +310,6 @@ class OlmoHybridLM:
     # ------------------------------------------------------------------
     # full-sequence form: forward and prefill
     # ------------------------------------------------------------------
-    def _attend_full(self, q, k, v, start):
-        """Causal softmax attention over whole sequences: ``q (b, T, d)``
-        float32 after its norm, ``k, v (b, T, d)`` as they are stored; a
-        block of queries at a time against every key, so that the scores
-        of 4,096 positions are never held at once.  No key before
-        ``start`` is valid."""
-        c = self.config
-        b, T, _ = q.shape
-        H, dh = c.nHeads, c.headSize
-        cd = k.dtype
-        B = _QUERY_BLOCK if T % _QUERY_BLOCK == 0 else T
-        q4 = q.reshape(b, T, H, dh).astype(cd)
-        k4, v4 = k.reshape(b, T, H, dh), v.reshape(b, T, H, dh)
-        kpos = jnp.arange(T, dtype=_I32)[None, None, :]
-        real = kpos >= start[:, None, None]                  # (b, 1, T)
-
-        def block(i):
-            qb = jax.lax.dynamic_slice_in_dim(q4, i * B, B, axis=1)
-            s = jnp.einsum("bqhd,bkhd->bhqk", qb, k4,
-                           preferred_element_type=_F32) * dh ** -0.5
-            rows = i * B + jnp.arange(B, dtype=_I32)
-            valid = (kpos <= rows[None, :, None]) & real     # (b, B, T)
-            a = jax.nn.softmax(jnp.where(valid[:, None], s, _NEG), axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", a.astype(cd), v4,
-                              preferred_element_type=_F32)
-        o = jax.lax.map(block, jnp.arange(T // B, dtype=_I32))
-        return jnp.moveaxis(o, 0, 1).reshape(b, T, H * dh)
-
     def _run_full(self, params, tokens, start):
         """``tokens (b, T)`` LEFT-padded, ``start (b,)`` the first real
         position.  Returns the last layer's output and the cache state a
@@ -429,7 +367,9 @@ class OlmoHybridLM:
                 vR = _mm(x, lp["Wv"]).astype(cd)
                 pagedK = pagedK.at[fi, :, 0].set(kR)
                 pagedV = pagedV.at[fi, :, 0].set(vR)
-                out = _mm(self._attend_full(q, kR, vR, start), lp["Wo"])
+                out = _mm(attend_full(q.astype(cd), kR, vR, start,
+                                      nHeads=c.nHeads, nKvHeads=c.nHeads),
+                          lp["Wo"])
                 fi += 1
             # the stream is written out after every block: left to itself
             # XLA keeps each block's float32 contribution instead and has
@@ -456,7 +396,7 @@ class OlmoHybridLM:
         def run(params, tokens, start):
             x, state = self._run_full(params, tokens, start)
             return (self._logits(params, x[:, -1]),) + state
-        return _JitByLength(run, "prefill")
+        return JitByLength(run, "prefill")
 
     def prefillRaw(self, tokens, lengths=None):
         """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
@@ -559,11 +499,8 @@ class OlmoHybridLM:
         def write(k, v, delta, conv, kStack, vStack, deltaS, convS, pageIds,
                   slot):
             k, v = paged_prefill_write(k, v, kStack, vStack, pageIds)
-            z = jnp.zeros((), _I32)
-            put = lambda pool, part: jax.lax.dynamic_update_slice(
-                pool, part[:, None].astype(pool.dtype),
-                (z, slot.astype(_I32)) + (z,) * (pool.ndim - 2))
-            return k, v, put(delta, deltaS), put(conv, convS)
+            return (k, v, slot_state_write(delta, deltaS, slot),
+                    slot_state_write(conv, convS, slot))
         return jax.jit(write, donate_argnums=(0, 1, 2, 3))
 
     def compileCacheSize(self) -> int:

@@ -62,8 +62,8 @@ from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_rows_write,
                                                   paged_step_tokens,
                                                   served_jit_entries)
-from deeplearning4j_tpu.nlp.olmo_hybrid import _JitByLength, _rms
-from deeplearning4j_tpu.nlp.sambay import _mm
+from deeplearning4j_tpu.nlp.mamba import _mm, _rms
+from deeplearning4j_tpu.nlp.served import JitByLength
 from deeplearning4j_tpu.parallel.ring import (_FLASH_MIN_T, _flash_refusal,
                                               flash_attention)
 from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
@@ -388,7 +388,7 @@ class PanguMoELM:
             # row's own where there is one row (the scheduler's case)
             return (self._logits(params, x[:, -1]), rows,
                     jnp.broadcast_to(counts, (1, b) + counts.shape))
-        return _JitByLength(run, "prefill")
+        return JitByLength(run, "prefill")
 
     def prefillRaw(self, tokens, lengths=None):
         """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),

@@ -44,6 +44,8 @@ from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_prefill_write,
                                                   paged_step_tokens,
                                                   served_jit_entries)
+from deeplearning4j_tpu.nlp.mamba import _mm, mamba_full, mamba_step
+from deeplearning4j_tpu.nlp.served import slot_state_write
 
 __all__ = ["SambaYConfig", "SambaYLM"]
 
@@ -97,25 +99,11 @@ def _lambda_init(i: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * i)
 
 
-def _mm(a, w):
-    """``a @ w`` in the weight's dtype on the way in, float32 out."""
-    return jnp.matmul(a.astype(w.dtype), w, preferred_element_type=_F32)
-
-
 def _ln(x, g, b, eps):
     x = x.astype(_F32)
     xc = x - jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(xc * xc, axis=-1, keepdims=True)
     return xc * jax.lax.rsqrt(var + eps) * g.astype(_F32) + b.astype(_F32)
-
-
-def _ssm_step(s, Dt, ut, Bt, Ct, AT):
-    """One step of the selective scan over a batch: state ``s (b, N,
-    d_in)`` float32, ``Dt, ut (b, d_in)``, ``Bt, Ct (b, N)``, ``AT (N,
-    d_in)``; returns ``(s, y (b, d_in))`` before the ``D`` skip."""
-    s = jnp.exp(Dt[:, None, :] * AT[None]) * s \
-        + (Dt * ut)[:, None, :] * Bt[:, :, None]
-    return s, jnp.sum(s * Ct[:, :, None], axis=1)
 
 
 class SambaYLM:
@@ -211,15 +199,6 @@ class SambaYLM:
         u = _ln(x, lp["ln2_g"], lp["ln2_b"], self.config.eps)
         g = jax.nn.silu(_mm(u, lp["Wgate"])) * _mm(u, lp["Wup"])
         return x + _mm(g, lp["Wdown"]).astype(x.dtype)
-
-    def _ssm_inputs(self, lp, u):
-        """From the convolved ``u (..., d_in)`` float32: ``(Δ, B, C)``."""
-        c = self.config
-        R, N = c.dtRank, c.stateSize
-        dbc = _mm(u, lp["Wx"])
-        Dt = jax.nn.softplus(_mm(dbc[..., :R], lp["Wdt"])
-                             + lp["bdt"].astype(_F32))
-        return Dt, dbc[..., R:R + N], dbc[..., R + N:]
 
     def _diff_queries(self, q):
         """``q (b, tq, H*dh)`` as ``(b, tq, G, 2R, 2*dh)``: a row is kept
@@ -320,8 +299,8 @@ class SambaYLM:
         nothing: its ``u`` and its ``Δ`` are zero, and no key is valid
         there."""
         c = self.config
-        b, T = tokens.shape
-        dIn, K, W = c.innerSize, c.convKernel, c.window
+        T = tokens.shape[1]
+        K, W = c.convKernel, c.window
         half = c.nLayers // 2
         kpos = jnp.arange(T, dtype=_I32)
         real = (kpos[None, :] >= start[:, None])             # (b, T)
@@ -337,30 +316,12 @@ class SambaYLM:
                                            params["layers"])):
             h = _ln(x, lp["ln1_g"], lp["ln1_b"], c.eps)
             if kind == "mamba":
-                xz = _mm(h, lp["Win"])
-                u = xz[..., :dIn] * realF
-                z = xz[..., dIn:]
-                conv.append(u[:, T - (K - 1):].astype(cd))
-                up = jnp.concatenate(
-                    [jnp.zeros((b, K - 1, dIn), _F32), u], axis=1)
-                cw = lp["convW"].astype(_F32)
-                u = jax.nn.silu(sum(cw[k] * up[:, k:k + T] for k in range(K))
-                                + lp["convB"].astype(_F32))
-                Dt, B, C = self._ssm_inputs(lp, u)
-                Dt = Dt * realF
-                AT = -jnp.exp(lp["AlogT"].astype(_F32))
-
-                def step(s, t, AT=AT):
-                    return _ssm_step(s, *t, AT)
-                tm = lambda a: jnp.swapaxes(a, 0, 1)         # time-major
-                s, y = jax.lax.scan(
-                    step, jnp.zeros((b, c.stateSize, dIn), _F32),
-                    (tm(Dt), tm(u), tm(B), tm(C)), unroll=8)
+                out, y, s, tail = mamba_full(
+                    lp, h, realF, N=c.stateSize, K=K, R=c.dtRank)
                 ssm.append(s)
-                y = tm(y) + lp["D"].astype(_F32) * u
+                conv.append(tail.astype(cd))
                 if i == half:
                     mem = y
-                out = _mm(y * jax.nn.silu(z), lp["Wout"])
             elif kind == "gmu":
                 out = _mm(jax.nn.silu(_mm(h, lp["W1"])) * mem, lp["W2"])
             else:
@@ -456,7 +417,7 @@ class SambaYLM:
             raise ValueError(
                 "a recurrent state advances one token a step: speculative "
                 "verification (tq > 1) would need its roll-back")
-        dIn, K, W = c.innerSize, c.convKernel, c.window
+        W = c.window
         half = c.nLayers // 2
         ps = k.shape[2]
         rows = jnp.arange(S, dtype=_I32)
@@ -479,23 +440,13 @@ class SambaYLM:
                                            params["layers"])):
             h = _ln(x, lp["ln1_g"], lp["ln1_b"], c.eps)
             if kind == "mamba":
-                xz = _mm(h, lp["Win"])
-                u, z = xz[:, :dIn], xz[:, dIn:]
-                win = jnp.concatenate(
-                    [conv[mi].astype(_F32), u[:, None]], axis=1)  # (S, K, dIn)
-                conv = conv.at[mi].set(keep(win[:, 1:].astype(conv.dtype),
-                                            conv[mi]))
-                u = jax.nn.silu(
-                    jnp.sum(win * lp["convW"].astype(_F32)[None], axis=1)
-                    + lp["convB"].astype(_F32))
-                Dt, B, C = self._ssm_inputs(lp, u)
-                s, y = _ssm_step(ssm[mi], Dt, u, B, C,
-                                 -jnp.exp(lp["AlogT"].astype(_F32)))
-                ssm = ssm.at[mi].set(keep(s, ssm[mi]))
-                y = y + lp["D"].astype(_F32) * u
+                out, y, s, win = mamba_step(
+                    lp, h, ssm[mi], conv[mi], keep, N=c.stateSize,
+                    R=c.dtRank)
+                conv = conv.at[mi].set(win)
+                ssm = ssm.at[mi].set(s)
                 if i == half:
                     mem = y
-                out = _mm(y * jax.nn.silu(z), lp["Wout"])
                 mi += 1
             elif kind == "gmu":
                 out = _mm(jax.nn.silu(_mm(h, lp["W1"])) * mem, lp["W2"])
@@ -546,10 +497,7 @@ class SambaYLM:
         def write(k, v, ringK, ringV, ssm, conv, kStack, vStack, rK, rV,
                   ssmS, convS, pageIds, slot):
             k, v = paged_prefill_write(k, v, kStack, vStack, pageIds)
-            z = jnp.zeros((), _I32)
-            put = lambda pool, part: jax.lax.dynamic_update_slice(
-                pool, part[:, None].astype(pool.dtype),
-                (z, slot.astype(_I32)) + (z,) * (pool.ndim - 2))
+            put = lambda pool, part: slot_state_write(pool, part, slot)
             return (k, v, put(ringK, rK), put(ringV, rV), put(ssm, ssmS),
                     put(conv, convS))
         return jax.jit(write, donate_argnums=(0, 1, 2, 3, 4, 5))

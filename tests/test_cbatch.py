@@ -219,6 +219,15 @@ def _paged_case(rng, h, d, ps, P, tq, pos, start, table=None, layers=2,
          P=12, tq=4, pos=[157, 13], start=[18, 0]),
     dict(id="grouped_2_bf16_two_heads_a_tile", h=20, d=64, rep=2, ps=16,
          P=12, tq=1, pos=[170, 20], start=[0, 7], pool="bfloat16"),
+    # Jamba2-3B's row, the narrowest and the largest group: ONE KV head of
+    # 128 bfloat16 lanes (a row is one lane tile) read by 20 query heads;
+    # in pages of 16 rows (eight a chunk) and of 128 (a page a chunk)
+    dict(id="grouped_20_on_one_head_of_128", h=1, d=128, rep=20, ps=16,
+         P=20, tq=1, pos=[300, 45, 0], start=[140, 3, 0], pool="bfloat16",
+         parked=[2]),
+    dict(id="grouped_20_on_one_head_pages_of_128", h=1, d=128, rep=20,
+         ps=128, P=4, tq=1, pos=[300, 45, 511], start=[140, 3, 0],
+         pool="bfloat16"),
 ], ids=lambda c: c["id"])
 def test_paged_kernel_matches_the_gathered_reference(case):
     """The TPU kernel (Pallas interpret mode, here on the CPU) against

@@ -392,3 +392,55 @@ def test_published_configuration_counts_its_parameters(ref, family):
     name, shape, dtype = spec.slotState[0]
     assert (name, shape, np.dtype(dtype)) == ("ssm", (9, 16, 5120),
                                               np.float32)
+
+
+# -- the Mamba mixer moved to nlp/mamba.py (PR 38) ---------------------------
+GOLDEN = os.path.join(REPO, "tests", "fixtures", "sambay_logits_pr37.npz")
+
+
+def _golden(family, weights, dtype):
+    """What ``fixtures/sambay_logits_pr37.npz`` holds for ``dtype``: the
+    full forward's logits of a 24-token prompt, and the logits of a
+    left-padded prefill (11 tokens in the 16 bucket) and of 12
+    teacher-forced steps through the pool.  Recorded on commit 09ebd17
+    (PR 37), where the mixer still lay in ``sambay.py``, by
+    ``np.savez(GOLDEN, **{f"{form}_{dtype}": ...})`` over this function,
+    with ``canary`` = ``ref.logits(TINY, weights, _prompts([24])[0])`` of
+    the same machine."""
+    import jax
+    from deeplearning4j_tpu.remote import KVCachePool
+    lm = _lm(family, weights, dtype)
+    forward = np.asarray(lm.forward(np.asarray([_prompts([24])[0]])))[0]
+    pool = KVCachePool.forSpec(lm.cacheSpec(), PAGE, 1 + SLOTS * (CAP // PAGE),
+                               SLOTS, CAP // PAGE)
+    served = np.stack(list(_teacher_forced(
+        lm, pool, lm.buildPagedPrefillWriteFn(), jax.jit(lm.pagedLogits), 1,
+        _prompts([11])[0], 16, _prompts([12], seed=11)[0])))
+    return {"forward": forward, "served": served}
+
+
+@pytest.mark.parametrize("form", ["forward", "served"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_logits_are_bit_for_bit_what_they_were_before_the_mixer_moved(
+        ref, family, weights, dtype, form):
+    """``SambaYLM`` calls the shared Mamba-1 mixer (``nlp/mamba.py``, which
+    ``JambaLM`` calls too) where it held its own: the same operations in
+    the same order, so not one bit of a logit may differ.  The recording
+    is one machine's arithmetic: ``canary`` (the plain reference's logits,
+    code this PR did not touch, recorded beside it) says whether this
+    machine's CPU rounds as that one did; where it does not (on the host
+    of the machine with the chip, another CPU, all four cases differ from
+    the recording), the logits are held to the file's tolerances and the
+    case reads SKIPPED, so that a run says which machines checked
+    equality."""
+    with np.load(GOLDEN) as want:
+        got = _golden(family, weights, dtype)[form]
+        canary = np.asarray(ref.logits(TINY, weights, _prompts([24])[0]))
+        if np.array_equal(canary, want["canary"]):
+            np.testing.assert_array_equal(got, want[f"{form}_{dtype}"])
+            return
+        tol = TOL_F32 if dtype == "float32" else TOL_BF16
+        assert np.abs(got - want[f"{form}_{dtype}"]).max() < tol
+    pytest.skip("this machine's CPU rounds unlike the one that recorded "
+                "the fixture (the canary differs): equality not checked, "
+                "the logits lie within the file's tolerances")

@@ -190,6 +190,8 @@ KERNEL_ROWS = {
     "olmo_hybrid_30x128_bf16": (30, 128, "bfloat16", 288, 16, 4, 1),
     "gpt2_xl_25x64_f32": (25, 64, "float32", 64, 4, 48, 1),
     "grouped_kv_20x64_bf16": (10, 128, "bfloat16", 160, 32, 1, 4),
+    # Jamba2-3B: ONE KV head of 128 lanes, 20 query heads on it, 64 slots
+    "one_kv_head_1x128_bf16": (1, 128, "bfloat16", 256, 64, 2, 20),
 }
 
 
@@ -636,3 +638,110 @@ def test_pangu_moe_prefill_groups_its_experts_and_fits_beside_the_step(
     poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
     assert write.memory_analysis().alias_size_in_bytes >= poolBytes
     assert write.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+# -- AI21-Jamba2-3B as benchmark/configs/jamba2_3b.json serves it: whole, all
+# 28 layers and 65,536 rows in bfloat16, 64 slots of 4,096 positions for the
+# 2 attention layers (ONE KV head of 128 lanes), 26 Mamba states a slot
+JAMBA_BUCKET = 2048
+
+
+@pytest.fixture(scope="module")
+def jamba(one_chip):
+    """``(lm, params, pool arrays, i32, serving sizes, the compiled decode
+    step, the attention kernels lowered for it)``: shapes on the described
+    chip, at the published sizes and the configuration's own pool."""
+    import json
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.jamba import JambaConfig, JambaLM
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_kernel_kv_passes, paged_kernel_lowerings)
+    from deeplearning4j_tpu.remote import KVCachePool
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "jamba2_3b.json")) as f:
+        serving = json.load(f)["serving"]
+    slots, ps, cap = (serving[k] for k in ("max_slots", "page_size",
+                                           "capacity"))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    lm = JambaLM(JambaConfig(
+        vocabSize=65536, nLayers=28, hiddenSize=2560, nHeads=20, nKvHeads=1,
+        ffnSize=8192, attnPeriod=14, attnOffset=7, stateSize=16,
+        convKernel=4, expand=2, dtRank=160, maxLen=cap), params={})
+    params = on_chip(jax.eval_shape(lm._init_params))
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool.forSpec(
+        lm.cacheSpec(), ps, serving["num_pages"], slots, cap // ps).arrays))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    before = paged_kernel_lowerings()
+    step = lm.buildPagedDecodeFn().lower(
+        params, *pool, i32(slots, 1), i32(slots, 1), i32(slots, cap // ps),
+        i32(slots), i32(slots)).compile()
+    assert paged_kernel_kv_passes() == 1
+    return (lm, params, pool, i32, serving, step,
+            paged_kernel_lowerings() - before)
+
+
+def test_jamba_decode_step_fits_and_updates_its_state_in_place(jamba):
+    lm, params, pool, i32, serving, compiled, kernelsLowered = jamba
+    mem = compiled.memory_analysis()
+    # weights 6.06 GB + pages 0.27 + state 0.55 + windows 0.05: found
+    # 6.924 GB of arguments and 0.05-0.07 of temporaries
+    assert 6.9e9 < mem.argument_size_in_bytes < 7.0e9
+    assert mem.temp_size_in_bytes < 0.1e9
+    # k, v, ssm and conv are donated and come back aliased, not copied
+    _assert_one_step_program(compiled, pool)
+    assert not _whole_array_copies(compiled, pool)
+    # nor is the tied table re-laid for the head (gpt2_xl's copy.725)
+    assert not re.search(r" = bf16\[(?:65536,2560|2560,65536)\]\S* copy\(",
+                         compiled.as_text())
+    # the two attention layers read their pages through the kernel: 20 query
+    # heads on the ONE KV head's lane tile, nothing gathered over a slot's
+    # capacity
+    text = compiled.as_text()
+    assert kernelsLowered == 2
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert len(re.findall(r"%paged_attention[\w.]* = ", text)) == 2
+    assert "bf16[%d,%d,128]" % (serving["max_slots"],
+                                serving["capacity"]) not in text
+
+
+def test_jamba_prefill_and_admission_write_fit(jamba):
+    import jax
+    lm, params, pool, i32, serving, step, _ = jamba
+    ps = serving["page_size"]
+    traced = lm._prefillRawFn.at(JAMBA_BUCKET).trace(
+        params, i32(1, JAMBA_BUCKET), i32(1))
+    # the shared sequential scan, eight positions an iteration, once a
+    # Mamba layer, and the map over the 4 blocks of 512 queries of each
+    # attention layer
+    assert sorted(_scan_lengths(traced.jaxpr.jaxpr)) \
+        == [4] * 2 + [JAMBA_BUCKET] * 26
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_prefill_{JAMBA_BUCKET}")
+    mem = compiled.memory_analysis()
+    # ISSUE 38 reckoned 7.9 GB: what the step holds (weights and pool)
+    # with its temporaries, and beside it the prefill of the largest bucket
+    # with what it hands to the admission write.  Found: 6.924 + 0.066 +
+    # 0.755 + 0.012 = 7.76 GB
+    step = step.memory_analysis()
+    assert step.argument_size_in_bytes + step.temp_size_in_bytes \
+        + mem.temp_size_in_bytes + mem.output_size_in_bytes < 8.0e9
+    # no score of 2,048 keys for all 2,048 queries is ever held
+    assert not re.search(r"f32\[(?:1,)?(?:1,)?20,2048,2048\]", text)
+    state = jax.eval_shape(lm._prefillRawFn, params, i32(1, JAMBA_BUCKET),
+                           i32(1))[1:]
+    parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
+                                  sharding=pool[0].sharding) for p in state]
+    write = lm.buildPagedPrefillWriteFn().lower(
+        *pool, *parts, i32(JAMBA_BUCKET // ps), i32()).compile()
+    assert not _whole_array_copies(write, pool)
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert write.memory_analysis().alias_size_in_bytes >= poolBytes
+    assert write.memory_analysis().temp_size_in_bytes < 64e6
