@@ -18,9 +18,14 @@ by :meth:`SambaYLM.cacheSpec` and held side by side by the scheduler's
 - *paged* — the full layer's K/V rows, one per position, in pages that
   grow with the sequence; written by one layer, read by it and by every
   cross layer;
-- *ring* — each window layer's last ``W`` K/V rows per slot, written
-  modulo ``W``.  Which position a ring row holds follows from ``pos``
-  alone, so a reused slot's stale rows are masked, never zeroed;
+- *ring* — each window layer's last ``W`` K/V rows per slot.  Position
+  ``p`` of a sequence whose first real token is ``start`` sits at ring
+  row ``(p - start) % W``, so the live rows are always the interval ``0
+  .. min(pos - start, W - 1)`` (attention without positions does not
+  care in what order it meets them) and the step reads a ring as fixed
+  pages of one slot, where it lies.  What is live follows from ``pos``
+  and ``start`` alone: a reused slot's stale rows are masked, never
+  zeroed;
 - *recurrent* — each Mamba layer's float32 state ``(N, d_in)`` and the
   convolution's last ``K - 1`` inputs per slot, overwritten every step.
 
@@ -38,7 +43,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
+from deeplearning4j_tpu.nn.conf.attention import (_CHUNK_ROWS, CacheSpec,
                                                   drop_served_jits,
                                                   paged_attention_read,
                                                   paged_prefill_write,
@@ -232,10 +237,9 @@ class SambaYLM:
 
     def _diff_attend(self, lp, i, q, kRows, vRows, valid):
         """Differential attention of ``q (b, tq, H*dh)`` against rows
-        ``kRows, vRows (b, T, KV*dh)`` with ``valid (b, tq, T)``: the
-        window layers' in every form, the full and cross layers' in the
-        full-sequence forms (their step reads pages:
-        :meth:`_diff_attend_paged`).
+        ``kRows, vRows (b, T, KV*dh)`` with ``valid (b, tq, T)``: every
+        attention layer's in the full-sequence forms (the step reads
+        pages and rings where they lie: :meth:`_diff_attend_paged`).
 
         Query heads pair up (20 pairs) and KV heads pair up (10 pairs);
         query pair ``p`` reads KV pair ``g = p // R``, laid out as
@@ -258,10 +262,13 @@ class SambaYLM:
                        preferred_element_type=_F32)
         return self._sub_norm(lp, i, o)
 
-    def _diff_attend_paged(self, lp, i, q, k, v, pageTable, pos, start):
-        """:meth:`_diff_attend` of ``q (S, tq, H*dh)`` against the paged
-        layer's rows where they lie, through
-        :func:`paged_attention_read`: in :meth:`_diff_queries`' layout it
+    def _diff_attend_paged(self, lp, i, q, k, v, li, pageTable, pos, start):
+        """:meth:`_diff_attend` of ``q (S, tq, H*dh)`` against the rows
+        ``start <= j <= pos`` of layer ``li`` of the pools ``k, v
+        (layers, pages, pageSize, KV*dh)`` where they lie, through
+        :func:`paged_attention_read` — the paged layer's pages, or a
+        window layer's ring as its slot's fixed pages
+        (:meth:`_ring_step`): in :meth:`_diff_queries`' layout it
         is plain grouped attention, ``H`` query heads of ``2*dh`` lanes
         on ``G`` KV heads of ``2*dh``, scores scaled by ``1/sqrt(dh)``,
         the context over a group's whole ``2*dh`` lanes of V.  The
@@ -274,11 +281,43 @@ class SambaYLM:
         G = c.nKvHeads // 2
         qe = self._diff_queries(q.astype(_F32)).reshape(
             S, tq, c.nHeads, 2 * dh).transpose(0, 2, 1, 3)
-        ctx = paged_attention_read(qe, k, v, 0, pageTable, pos, start,
+        ctx = paged_attention_read(qe, k, v, li, pageTable, pos, start,
                                    scale=dh ** -0.5)       # (S, H, tq, 2dh)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(S, tq, G, -1, 2, 2 * dh)
         return self._sub_norm(
             lp, i, ctx[..., 0, :] - self._diff_lambda(lp, i) * ctx[..., 1, :])
+
+    def _ring_step(self, lp, i, q, kNew, vNew, ringK, ringV, wi, pos, start):
+        """A window layer's decode step over its ring, layer ``wi`` of
+        the stacks ``ringK, ringV (layers, S, W, KV*dh)``: this step's
+        row ``kNew, vNew (S, KV*dh)`` goes to ring row ``(pos - start) %
+        W`` (a slot whose ``pos`` is 0 keeps its rows as they are), then
+        ``q (S, 1, H*dh)`` attends over the live rows ``0 .. min(pos -
+        start, W - 1)`` where they lie (:meth:`_diff_attend_paged`).
+        ``(context, ringK, ringV)``.
+
+        What makes a ring a paged pool, with nothing copied: the stack
+        viewed as ``(layers, S * W/R, R, KV*dh)`` is a reshape of its
+        leading dimensions, and its page table is a constant — slot ``s``
+        owns the ``P = W/R`` pages ``s * P .. (s + 1) * P - 1`` for good.
+        A page is ``R`` rows: what the read's kernel copies as ONE chunk
+        (``_CHUNK_ROWS``), the whole ring where that is shorter."""
+        L, S, W, w = ringK.shape
+        R = math.gcd(W, _CHUNK_ROWS)
+        P = W // R
+        slots = jnp.arange(S, dtype=_I32)
+        age = jnp.maximum(pos - start, 0)
+        row = age % W
+        put = lambda ring, new: ring.at[wi, slots, row].set(jnp.where(
+            (pos > 0)[:, None], new, ring[wi, slots, row]))
+        ringK, ringV = put(ringK, kNew), put(ringV, vNew)
+        pages = lambda ring: ring.reshape(L, S * P, R, w)
+        last = jnp.minimum(age, W - 1)
+        o = self._diff_attend_paged(
+            lp, i, q, pages(ringK), pages(ringV), wi,
+            jnp.arange(S * P, dtype=_I32).reshape(S, P), last,
+            jnp.zeros_like(last))
+        return o, ringK, ringV
 
     def _logits(self, params, x):
         h = _ln(x, params["lnf_g"], params["lnf_b"], self.config.eps)
@@ -336,8 +375,8 @@ class SambaYLM:
                     pagedK.append(kR)
                     pagedV.append(vR)
                 elif kind == "window":
-                    ringK.append(self._ring_rows(kR))
-                    ringV.append(self._ring_rows(vR))
+                    ringK.append(self._ring_rows(kR, start))
+                    ringV.append(self._ring_rows(vR, start))
                 o = self._diff_attend(
                     lp, i, q, kR, vR,
                     valid & inWin if kind == "window" else valid)
@@ -350,14 +389,19 @@ class SambaYLM:
                  jnp.stack(conv))
         return x, state
 
-    def _ring_rows(self, rows):
-        """The last ``min(T, W)`` rows of ``rows (b, T, w)`` in ring
-        order: position ``p`` sits at ring row ``p % W``."""
+    def _ring_rows(self, rows, start):
+        """The last ``min(T, W)`` real rows of ``rows (b, T, w)``, whose
+        first real row is ``start (b,)``, in ring order: position ``p``
+        sits at ring row ``(p - start) % W``, so ring row ``r`` takes
+        the newest position that is ``r`` more than ``start`` modulo
+        ``W`` (a row no position has reached yet takes whatever is at
+        hand: the step writes it before it reads it)."""
         W = self.config.window
         T = rows.shape[1]
-        if T <= W:
-            return rows
-        return jnp.roll(rows[:, T - W:], T % W, axis=1)
+        r = jnp.arange(min(T, W), dtype=_I32)[None, :]
+        p = (T - 1) - (T - 1 - start[:, None] - r) % W
+        return jnp.take_along_axis(rows, jnp.maximum(p, 0)[:, :, None],
+                                   axis=1)
 
     @functools.cached_property
     def _fwd(self):
@@ -406,7 +450,9 @@ class SambaYLM:
         and the cross layers after it, read the pages through
         :func:`paged_attention_read` (lowered for one TPU: the kernel
         over the slots' live pages; elsewhere the gathered reference);
-        the window layers attend over their rings (:meth:`_diff_attend`).
+        a window layer writes its row into its ring and reads the ring's
+        live rows the same way, as its slot's fixed pages
+        (:meth:`_ring_step`): one read for paged rows and ring rows.
         A slot whose ``pos`` is 0 holds no sequence (or is
         deferred a round): its paged write lands on the scratch page
         through its zeroed page table, and its ring rows and recurrent
@@ -417,7 +463,6 @@ class SambaYLM:
             raise ValueError(
                 "a recurrent state advances one token a step: speculative "
                 "verification (tq > 1) would need its roll-back")
-        W = c.window
         half = c.nLayers // 2
         ps = k.shape[2]
         rows = jnp.arange(S, dtype=_I32)
@@ -425,11 +470,6 @@ class SambaYLM:
         # the paged layer: where this step's row goes
         phys = pageTable[rows, pos // ps]
         off = pos % ps
-        # a ring row r holds the newest position <= pos that is r mod W
-        rIdx = pos % W
-        r = jnp.arange(W, dtype=_I32)[None, :]
-        held = pos[:, None] - (pos[:, None] - r) % W
-        validR = (held >= start[:, None])[:, None]            # (S, 1, W)
         x = params["emb"][toks[:, 0]]                         # (S, d)
         cd = x.dtype
         keep = lambda new, old: jnp.where(
@@ -456,18 +496,14 @@ class SambaYLM:
                     kN = _mm(h, lp["Wk"]).astype(cd)
                     vN = _mm(h, lp["Wv"]).astype(cd)
                 if kind == "window":
-                    ringK = ringK.at[wi, rows, rIdx].set(
-                        keep(kN, ringK[wi, rows, rIdx]))
-                    ringV = ringV.at[wi, rows, rIdx].set(
-                        keep(vN, ringV[wi, rows, rIdx]))
-                    o = self._diff_attend(lp, i, q, ringK[wi], ringV[wi],
-                                          validR)
+                    o, ringK, ringV = self._ring_step(
+                        lp, i, q, kN, vN, ringK, ringV, wi, pos, start)
                     wi += 1
                 else:
                     if kind == "full":
                         k = k.at[0, phys, off].set(kN.astype(k.dtype))
                         v = v.at[0, phys, off].set(vN.astype(v.dtype))
-                    o = self._diff_attend_paged(lp, i, q, k, v, pageTable,
+                    o = self._diff_attend_paged(lp, i, q, k, v, 0, pageTable,
                                                 pos, start)
                 out = _mm(o[:, 0], lp["Wo"])
             x = self._ffn(lp, x + out.astype(cd))

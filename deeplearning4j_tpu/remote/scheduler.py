@@ -120,7 +120,10 @@ class KVCachePool:
       row (``paged_latent_attention``).
     - *ring*: ``ringK``/``ringV`` ``(ringLayers, maxSlots, ringRows,
       spec.rowWidth)``, a slot's last ``ringRows`` positions written
-      modulo ``ringRows``.
+      modulo ``ringRows``, counted from the sequence's first real token
+      (``(p - start) % ringRows``), so that the live rows are always the
+      first ``min(pos - start + 1, ringRows)`` and a step can read a ring
+      as fixed pages of its slot.
     - *recurrent*: one array ``(layers, maxSlots, ...)`` for each entry
       of ``spec.slotState``, overwritten every step.
 
@@ -135,10 +138,10 @@ class KVCachePool:
     how many slots are live.  Ring rows and recurrent state have no
     scratch: the step leaves them as they are for a slot whose ``pos``
     is 0, admission overwrites the recurrent state whole, and which
-    position a ring row holds follows from ``pos`` alone, so a reused
-    slot's stale rows are never valid.  ``ensure``/``release`` are plain
-    list edits — allocation never reallocates device memory and never
-    changes an executable shape.
+    ring rows are live follows from ``pos`` and ``start`` alone, so a
+    reused slot's stale rows are never valid.  ``ensure``/``release`` are
+    plain list edits — allocation never reallocates device memory and
+    never changes an executable shape.
     """
 
     def __init__(self, nLayers: int, nHeads: int, headSize: int,
@@ -654,10 +657,19 @@ class ContinuousBatcher:
             moe_step_kernel_lowerings()
         prev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
-        kernel = paged_kernel_lowerings() > lowered
+        # one kernel lowering for each layer whose rows the step read
+        # through it (``paged_kernel_lowerings``): every paged layer and,
+        # where the model reads them the same way, every ring layer
+        read = paged_kernel_lowerings() - lowered
+        kernel = read > 0
+        spec = self.pool.spec
         sm.paged_attention_kernel().set(1 if kernel else 0, model=self.name)
         sm.paged_attention_kv_passes().set(
             paged_kernel_kv_passes() if kernel else 0, model=self.name)
+        sm.ring_attention_kernel().set(
+            1 if spec.ringLayers
+            and read >= spec.pagedLayers + spec.ringLayers else 0,
+            model=self.name)
         sm.moe_step_kernel().set(
             1 if moe_step_kernel_lowerings() > experts else 0,
             model=self.name)

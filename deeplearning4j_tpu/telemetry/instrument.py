@@ -436,6 +436,17 @@ class ServingMetrics:
             "step gathers (paged_attention_kernel 0)",
             labelnames=("model",))
 
+    def ring_attention_kernel(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_ring_attention_kernel",
+            "1 when the batcher's decode step read its window layers' "
+            "rings through the same kernel as its pages, each ring as "
+            "its slot's fixed pages where it lies (lowered for one TPU: "
+            "as many kernel lowerings as the model has paged and ring "
+            "layers), 0 when it gathers them (the CPU, several devices) "
+            "and for a model without ring layers",
+            labelnames=("model",))
+
     def moe_step_kernel(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_moe_step_kernel",

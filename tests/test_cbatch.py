@@ -365,7 +365,7 @@ def test_differential_pairs_through_the_grouped_read(read, monkeypatch):
             sambay, "paged_attention_read",
             lambda qh, pk, pv, layer, *a, scale: A._attend_pages(
                 qh, pk, pv, *a, li=layer, scale=scale, interpret=True))
-    got = lm._diff_attend_paged(lp, li, q, k, v, table, pos, start)
+    got = lm._diff_attend_paged(lp, li, q, k, v, 0, table, pos, start)
     assert got.shape == want.shape == (S, 1, c.nHeads * c.headSize)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
@@ -392,9 +392,13 @@ def test_paged_attention_lowers_the_reference_off_the_tpu():
     assert get_registry().get(
         "dl4j_tpu_serving_paged_attention_kernel").value(
             model="gauge-lm") == 0
-    # and a model without an expert layer has no expert kernel anywhere
+    # and a model without an expert layer has no expert kernel anywhere,
+    # one without window layers no ring to read: both series are exposed
     assert get_registry().get(
         "dl4j_tpu_serving_moe_step_kernel").value(model="gauge-lm") == 0
+    assert get_registry().get(
+        "dl4j_tpu_serving_ring_attention_kernel").value(
+            model="gauge-lm") == 0
 
 
 def test_kv_passes_gauge_reads_zero_for_a_gathered_step():

@@ -287,6 +287,7 @@ def test_continuous_batcher_serves_the_reference_tokens(ref, weights,
     assert sm.cache_bytes().value(model="olmo", kind="paged") == 0
     assert sm.cache_bytes().value(model="olmo", kind="recurrent") == 0
     assert sm.ring_rows_in_use().value(model="olmo") == 0
+    assert sm.ring_attention_kernel().value(model="olmo") == 0
     # padded positions and real tokens of the five prefills: their ratio
     # is what the bucket ladder wastes
     assert count(sm.prefill_prompt_tokens()) - before[0] == 5 + 11 + 16 + 7 + 3

@@ -643,6 +643,7 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts_routing(
     # off the TPU the step gathers: the kernel's gauges say so
     assert sm.paged_attention_kernel().value(model="pangu") == 0
     assert sm.paged_attention_kv_passes().value(model="pangu") == 0
+    assert sm.ring_attention_kernel().value(model="pangu") == 0
     # and its expert layers multiply every held expert over every slot
     assert sm.moe_step_kernel().value(model="pangu") == 0
     got = {k: v - before[k] for k, v in _routing().items()}

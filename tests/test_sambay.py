@@ -164,6 +164,92 @@ def test_prefill_and_paged_decode_match_the_reference_logits(
     assert pool.usedPages() == 0 and pool.stateSlots() == 0
 
 
+# (start, pos) of the three slots, at the window of 8 rows: ``pos`` is the
+# position this step writes and reads up to
+RING_CASES = {
+    "not_yet_full": [(0, 5), (0, 3), (0, 1)],
+    # wrapped in absolute positions (pos >= W) but not yet W rows long:
+    # start 100, pos 520 at a window of 512
+    "not_yet_full_left_padded_and_wrapped": [(3, 9), (5, 11), (2, 8)],
+    "exactly_full": [(0, 7), (3, 10), (1, 8)],
+    "wrapped_several_times": [(0, 29), (3, 40), (5, 23)],
+    "idle_slot_beside_live_ones": [(0, 0), (2, 12), (0, 6)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_step_reads_its_ring_as_the_old_rule_read_it(family, weights, case):
+    """A window layer's step reads its ring through
+    ``paged_attention_read`` (here the gathered lowering), position ``p``
+    at ring row ``(p - start) % W`` and the live rows one interval.  It
+    must see the same rows as ``_diff_attend`` saw under the rule it
+    replaces (``p`` at row ``p % W``, a row valid iff the position it
+    holds is ``>= start``), stale rows of a slot's earlier tenant around
+    them; a slot whose ``pos`` is 0 keeps its ring rows, and in the whole
+    step its recurrent state, as they were."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.remote import KVCachePool
+    lm = _lm(family, weights, "float32")
+    c = lm.config
+    W, w = c.window, c.nKvHeads * c.headSize
+    i = [n for n, k in enumerate(c.layerKinds()) if k == "window"][1]
+    lp, wi = lm.params["layers"][i], 1
+    start, pos = (np.asarray(x, np.int32) for x in zip(*RING_CASES[case]))
+    live = pos > 0
+    rs = np.random.RandomState(sorted(RING_CASES).index(case))
+    T = int(pos.max()) + 1
+    kv = rs.randn(2, SLOTS, T, w).astype(np.float32)
+    q = rs.randn(SLOTS, 1, c.nHeads * c.headSize).astype(np.float32)
+    old = rs.randn(2, SLOTS, W, w).astype(np.float32)       # stale rows
+    new = rs.randn(2, 2, SLOTS, W, w).astype(np.float32)    # (K/V, layers)
+    for s in np.flatnonzero(live):
+        for p in range(start[s], pos[s] + 1):
+            old[:, s, p % W] = kv[:, s, p]
+            if p < pos[s]:
+                new[:, wi, s, (p - start[s]) % W] = kv[:, s, p]
+    before = new.copy()
+    r = np.arange(W)[None, :]
+    held = pos[:, None] - (pos[:, None] - r) % W
+    want = np.asarray(lm._diff_attend(
+        lp, i, jnp.asarray(q), jnp.asarray(old[0]), jnp.asarray(old[1]),
+        jnp.asarray(held >= start[:, None])[:, None]))
+    rows = kv[:, np.arange(SLOTS), pos]                     # this step's
+    got, ringK, ringV = lm._ring_step(
+        lp, i, jnp.asarray(q), jnp.asarray(rows[0]), jnp.asarray(rows[1]),
+        jnp.asarray(new[0]), jnp.asarray(new[1]), wi, jnp.asarray(pos),
+        jnp.asarray(start))
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got)[live], want[live],
+                               rtol=2e-5, atol=2e-6)
+    after = np.stack([np.asarray(ringK), np.asarray(ringV)])
+    for s in range(SLOTS):
+        wrote = (pos[s] - start[s]) % W
+        for row in range(W):
+            if live[s] and row == wrote:
+                np.testing.assert_array_equal(after[:, wi, s, row], rows[:, s])
+            else:
+                np.testing.assert_array_equal(after[:, wi, s, row],
+                                              before[:, wi, s, row])
+    np.testing.assert_array_equal(after[:, 0], before[:, 0])
+    if live.all():
+        return
+    # the whole step: an idle slot's rings and recurrent state stay put
+    pool = KVCachePool.forSpec(lm.cacheSpec(), PAGE, 1 + SLOTS * (CAP // PAGE),
+                               SLOTS, CAP // PAGE)
+    for s in np.flatnonzero(live):
+        assert pool.ensure(int(s), int(pos[s]) + 1)
+    arrays = [jnp.asarray(rs.randn(*a.shape), a.dtype) for a in pool.arrays]
+    out = jax.jit(lm.pagedLogits)(
+        lm.params, *arrays, jnp.zeros((SLOTS, 1), jnp.int32),
+        jnp.asarray(pool.pageTable), jnp.asarray(pos), jnp.asarray(start))
+    idle = np.flatnonzero(~live)
+    for a, b in zip(arrays[2:], out[3:]):
+        np.testing.assert_array_equal(np.asarray(a)[:, idle],
+                                      np.asarray(b)[:, idle])
+        assert not np.array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.fixture
 def batcher(family, weights):
     from deeplearning4j_tpu.remote import BucketLadder, ContinuousBatcher
@@ -231,16 +317,19 @@ def test_continuous_batcher_serves_the_reference_tokens(ref, weights,
 
 def test_paged_layer_is_read_through_the_gathered_lowering_here(family,
                                                                weights):
-    """The step reads its paged layer through ``paged_attention_read``,
-    which is lowered where the program is: here on the CPU the gathered
-    reference, so the batcher's gauges read 0 (1 / 1 on one TPU, where the
-    eight readers go through the kernel: ``tests/test_tpu_compile.py``)."""
+    """The step reads its paged layer and its rings through
+    ``paged_attention_read``, which is lowered where the program is: here
+    on the CPU the gathered reference, so the batcher's gauges read 0 (1 /
+    1 / 1 on one TPU, where the eight readers of the paged layer and the
+    two window layers go through the kernel:
+    ``tests/test_tpu_compile.py``)."""
     from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
     from deeplearning4j_tpu.remote import BucketLadder, ContinuousBatcher
     from deeplearning4j_tpu.telemetry import serving_metrics
     sm = serving_metrics()
     sm.paged_attention_kernel().set(1, model="sambay-gauge")
     sm.paged_attention_kv_passes().set(1, model="sambay-gauge")
+    sm.ring_attention_kernel().set(1, model="sambay-gauge")
     before = paged_kernel_lowerings()
     cb = ContinuousBatcher(
         _lm(family, weights, "float32"), name="sambay-gauge",
@@ -253,6 +342,7 @@ def test_paged_layer_is_read_through_the_gathered_lowering_here(family,
     assert paged_kernel_lowerings() == before
     assert sm.paged_attention_kernel().value(model="sambay-gauge") == 0
     assert sm.paged_attention_kv_passes().value(model="sambay-gauge") == 0
+    assert sm.ring_attention_kernel().value(model="sambay-gauge") == 0
 
 
 def test_preempt_replay_and_evacuate_return_the_same_tokens(ref, weights,
@@ -406,7 +496,13 @@ def _golden(family, weights, dtype):
     (PR 37), where the mixer still lay in ``sambay.py``, by
     ``np.savez(GOLDEN, **{f"{form}_{dtype}": ...})`` over this function,
     with ``canary`` = ``ref.logits(TINY, weights, _prompts([24])[0])`` of
-    the same machine."""
+    the same machine.  The ``served_*`` arrays were recorded anew the
+    same way in PR 39 (a machine whose canary is the file's), when the
+    window layers' step came to read its ring through
+    ``paged_attention_read``: the pair's difference is taken of two
+    float32 contexts there, where ``_diff_attend`` takes it of the
+    weights; the recording they replace is kept as ``served_*_pr37`` and
+    the new one held to it within the file's tolerances."""
     import jax
     from deeplearning4j_tpu.remote import KVCachePool
     lm = _lm(family, weights, dtype)
@@ -425,7 +521,9 @@ def test_logits_are_bit_for_bit_what_they_were_before_the_mixer_moved(
         ref, family, weights, dtype, form):
     """``SambaYLM`` calls the shared Mamba-1 mixer (``nlp/mamba.py``, which
     ``JambaLM`` calls too) where it held its own: the same operations in
-    the same order, so not one bit of a logit may differ.  The recording
+    the same order, so not one bit of a logit may differ (``served``:
+    since PR 39's recording, which lies within the file's tolerances of
+    PR 37's: :func:`_golden`).  The recording
     is one machine's arithmetic: ``canary`` (the plain reference's logits,
     code this PR did not touch, recorded beside it) says whether this
     machine's CPU rounds as that one did; where it does not (on the host
@@ -433,13 +531,16 @@ def test_logits_are_bit_for_bit_what_they_were_before_the_mixer_moved(
     the recording), the logits are held to the file's tolerances and the
     case reads SKIPPED, so that a run says which machines checked
     equality."""
+    tol = TOL_F32 if dtype == "float32" else TOL_BF16
     with np.load(GOLDEN) as want:
+        if form == "served":
+            assert np.abs(want[f"served_{dtype}"]
+                          - want[f"served_{dtype}_pr37"]).max() < tol
         got = _golden(family, weights, dtype)[form]
         canary = np.asarray(ref.logits(TINY, weights, _prompts([24])[0]))
         if np.array_equal(canary, want["canary"]):
             np.testing.assert_array_equal(got, want[f"{form}_{dtype}"])
             return
-        tol = TOL_F32 if dtype == "float32" else TOL_BF16
         assert np.abs(got - want[f"{form}_{dtype}"]).max() < tol
     pytest.skip("this machine's CPU rounds unlike the one that recorded "
                 "the fixture (the canary differs): equality not checked, "

@@ -192,6 +192,10 @@ KERNEL_ROWS = {
     "grouped_kv_20x64_bf16": (10, 128, "bfloat16", 160, 32, 1, 4),
     # Jamba2-3B: ONE KV head of 128 lanes, 20 query heads on it, 64 slots
     "one_kv_head_1x128_bf16": (1, 128, "bfloat16", 256, 64, 2, 20),
+    # SambaY's rings: a slot's 512 rows as 4 fixed pages of 128 rows (a
+    # page a chunk, where the others' chunk is 8 pages of 16), 8 window
+    # layers in one stack
+    "ring_10x128_bf16_pages_of_128": (10, 128, "bfloat16", 4, 32, 8, 4, 128),
 }
 
 
@@ -205,13 +209,14 @@ def test_paged_kernel_alone_compiles_at_its_callers_rows(one_chip, row):
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.conf import attention as A
-    h, d, dtype, perSeq, slots, layers, nRep = KERNEL_ROWS[row]
+    h, d, dtype, perSeq, slots, layers, nRep, *ps = KERNEL_ROWS[row]
+    ps = ps[0] if ps else PAGE_SIZE
 
     def sds(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    C = A._CHUNK_ROWS // PAGE_SIZE
+    C = A._CHUNK_ROWS // ps
     places = slots * -(-perSeq // C)
-    pool = sds((layers, 1 + slots * perSeq, PAGE_SIZE, h * d), dtype)
+    pool = sds((layers, 1 + slots * perSeq, ps, h * d), dtype)
     compiled = A._pages_call.lower(
         sds((1,)), sds((places * C,)), sds((places,)), sds((places,)),
         sds((places,)), sds(()), sds((slots,)), sds((slots,)),
@@ -219,7 +224,7 @@ def test_paged_kernel_alone_compiles_at_its_callers_rows(one_chip, row):
         tq=1, interpret=False).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes \
-        < PAGE_SIZE * h * d * pool.dtype.itemsize
+        < ps * h * d * pool.dtype.itemsize
 
 
 def test_latent_kernel_alone_compiles_at_its_callers_row(one_chip):
@@ -331,7 +336,7 @@ def sambay(one_chip):
 
 def _whole_array_copies(compiled, arrays):
     """``copy`` instructions whose result is as large as one of the
-    pool's arrays (a ring layer's slice re-laid for its matmul is not)."""
+    pool's arrays."""
     shapes = {str(a.dtype).replace("bfloat16", "bf16").replace(
         "float32", "f32") + "[" + ",".join(str(n) for n in a.shape) + "]"
         for a in arrays}
@@ -358,23 +363,33 @@ def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
     _assert_one_step_program(compiled, pool)
     assert not _whole_array_copies(compiled, pool)
     # the full layer and the 7 cross layers read the ONE paged layer
-    # through the kernel, its bfloat16 rows in one MXU pass a tile
+    # through the kernel, its bfloat16 rows in one MXU pass a tile, and
+    # each of the 8 window layers its ring, as its slots' fixed pages
     text = compiled.as_text()
     kinds = lm.config.layerKinds()
     assert kinds.count("full") + kinds.count("cross") == 8
-    # (one lowering serves the eight: they bind the same read of layer 0)
-    assert paged_kernel_lowerings() > before
+    assert kinds.count("window") == 8
+    # (one lowering serves the eight readers: they bind the same read of
+    # layer 0; one for each ring layer: what the batcher's gauge
+    # ``ring_attention_kernel`` counts)
+    spec = lm.cacheSpec()
+    assert paged_kernel_lowerings() - before \
+        == spec.pagedLayers + spec.ringLayers == 9
     assert paged_kernel_kv_passes() == 1
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 8
-    assert len(re.findall(r"%paged_attention[\w.]* = ", text)) == 8
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 16
+    assert len(re.findall(r"%paged_attention[\w.]* = ", text)) == 16
     # so no slot's capacity is gathered (32 slots of 2,560 rows of 1,280
-    # lanes) and no gathered row is re-laid into its 10 groups
-    rows = PHI_SLOTS, PHI_CAP
-    assert "bf16[%d,%d,1280]" % rows not in text
-    assert "bf16[%d,%d,10,128]" % rows not in text
-    # found: 75,677,184 bytes (790,092,288 with the gather and its two
-    # re-layouts)
-    assert mem.temp_size_in_bytes < 0.1e9
+    # lanes), no gathered row is re-laid into its 10 groups, and no ring
+    # layer is cut out of its stack (32 slots of 512 rows) or re-laid
+    # group-major for a matmul: the stacks are read where they lie, as
+    # pages of 128 rows
+    for rows in ((PHI_SLOTS, PHI_CAP), (PHI_SLOTS, 512)):
+        assert "bf16[%d,%d,1280]" % rows not in text
+        assert "bf16[%d,%d,10,128]" % rows not in text
+    assert "bf16[8,%d,128,1280]" % (PHI_SLOTS * 4) in text
+    # found: 38,221,824 bytes (75,677,184 with a ring layer's slice and
+    # its re-layout; 790,092,288 with the paged layer's gather and its two)
+    assert mem.temp_size_in_bytes < 0.05e9
 
 
 def test_sambay_prefill_and_admission_write_fit(sambay):
