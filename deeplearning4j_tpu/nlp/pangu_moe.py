@@ -54,7 +54,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   drop_served_jits,
@@ -63,7 +62,7 @@ from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_step_tokens,
                                                   served_jit_entries)
 from deeplearning4j_tpu.nlp.mamba import _mm, _rms
-from deeplearning4j_tpu.nlp.served import JitByLength
+from deeplearning4j_tpu.nlp.served import JitByLength, _rope
 from deeplearning4j_tpu.parallel.ring import (_FLASH_MIN_T, _flash_refusal,
                                               flash_attention)
 from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
@@ -110,17 +109,6 @@ class PanguMoEConfig:
     @property
     def nHeld(self) -> int:
         return self.expertsHeld[1] - self.expertsHeld[0]
-
-
-def _rope(x, pos, theta: float):
-    """``x (..., D)`` float32 turned by ``pos (...)``: lane ``i`` pairs
-    with lane ``i + D / 2``, angle ``pos * theta^(-2 i / D)``."""
-    half = x.shape[-1] // 2
-    inv = jnp.asarray(theta ** (-np.arange(half) / half), _F32)
-    ang = pos[..., None].astype(_F32) * inv
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * c - b * s, b * c + a * s], axis=-1)
 
 
 class PanguMoELM:

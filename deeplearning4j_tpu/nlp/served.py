@@ -1,14 +1,16 @@
 """What the served LMs of this package share outside their blocks: the
 prefill jitted once a prompt bucket under the bucket's name, the
-full-sequence attention a block of queries at a time, and the admission
-write of a slot's own state.  ``OlmoHybridLM``, ``JambaLM``, ``SambaYLM``
-and ``PanguMoELM`` call them; the mixers are the models' own
+full-sequence attention a block of queries at a time, the admission
+write of a slot's own state, and rotary positions in the half-split
+pairing.  ``OlmoHybridLM``, ``JambaLM``, ``SambaYLM``, ``PanguMoELM`` and
+``KeyeVLLM`` call them; the mixers are the models' own
 (:mod:`~deeplearning4j_tpu.nlp.mamba` for the two that run Mamba-1).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["JitByLength", "attend_full", "slot_state_write"]
 
@@ -17,6 +19,17 @@ _I32 = jnp.int32
 _NEG = -1e30
 #: queries a block of the full-sequence attention holds against every key
 QUERY_BLOCK = 512
+
+
+def _rope(x, pos, theta: float):
+    """``x (..., D)`` float32 turned by ``pos (...)``: lane ``i`` pairs
+    with lane ``i + D / 2``, angle ``pos * theta^(-2 i / D)``."""
+    half = x.shape[-1] // 2
+    inv = jnp.asarray(theta ** (-np.arange(half) / half), _F32)
+    ang = pos[..., None].astype(_F32) * inv
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * c - b * s, b * c + a * s], axis=-1)
 
 
 class JitByLength:

@@ -35,7 +35,8 @@ __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "paged_attention", "paged_attention_read",
            "paged_kernel_lowerings",
            "paged_kernel_kv_passes", "lowered_for_one_tpu",
-           "paged_latent_attention", "paged_prefill_write",
+           "paged_latent_attention", "paged_sparse_attention",
+           "paged_prefill_write",
            "paged_rows_write", "paged_step_tokens",
            "CacheSpec", "served_jit_entries", "drop_served_jits"]
 
@@ -745,6 +746,276 @@ _attend_latent_p.def_abstract_eval(
 mlir.register_lowering(_attend_latent_p, _attend_latent_lowering)
 
 
+# -- the sparse form: index rows choose which K/V rows a query reads ----
+
+def paged_sparse_attention(qh, kNew, vNew, qI, wI, kINew, poolK, poolV,
+                           poolI, li, pageTable, pos, start, *, topk):
+    """:func:`paged_attention` where a learned SELECTOR decides which of a
+    slot's live rows the query reads (DeepSeek-V3.2-Exp's lightning
+    indexer): beside its K and V rows a position keeps an INDEX ROW, one
+    key of ``CacheSpec.indexWidth`` lanes for all index heads, in a third
+    pool that the same page table addresses.
+
+    - ``qh`` (slots, kvHeads * nRep, 1, headSize), ``kNew`` / ``vNew``
+      (slots, kvHeads, 1, headSize): as in :func:`paged_attention`, one
+      new position a slot;
+    - ``qI`` (slots, indexHeads, indexWidth) float32, ``wI`` (slots,
+      indexHeads) float32: the new position's index queries and their
+      weights; ``kINew`` (slots, indexWidth): its index key;
+    - ``poolI`` (nLayers, numPages, pageSize, W): the stacked index rows,
+      ``W`` whole lane tiles (zeros behind the ``indexWidth`` lanes).
+
+    Writes the three new rows, scores every live row ``s`` of a slot,
+    ``I_s = sum_j wI_j ReLU(qI_j . kI_s)`` in float32, takes the ``topk``
+    largest (every live row while there are no more than ``topk``; of
+    equal scores the earlier position) and attends over those rows of K
+    and V alone, read through the page table: ``(ctx (slots, heads, 1,
+    headSize) float32, poolK, poolV, poolI)``.  The result depends on a
+    slot's logical content alone.  Lowered like :func:`paged_attention`:
+    for one TPU a kernel scores the live index pages where they lie
+    (:func:`_index_pages`); elsewhere every slot's whole capacity of
+    index rows is gathered (:func:`_index_gathered`).  Selection
+    (``lax.top_k``) and the read of the chosen rows (a row gather) are
+    the same in both."""
+    S, h, tq, d = kNew.shape
+    if tq != 1:
+        raise ValueError("the sparse read takes one new position a slot")
+    ps = poolK.shape[2]
+
+    def row(new, pool):
+        a = new.reshape(S, -1)
+        return jnp.pad(a, ((0, 0), (0, pool.shape[3] - a.shape[1]))
+                       ).astype(pool.dtype)
+    # every operation of the read carries its name in the compiled
+    # program's metadata (``op_name``), the XLA ones between the kernels
+    # too: a device trace's ops are told apart by it
+    with jax.named_scope("paged_sparse_attention"):
+        phys = jnp.take_along_axis(pageTable, (pos // ps)[:, None],
+                                   axis=1)[:, 0]
+        off = pos % ps
+        poolK = poolK.at[li, phys, off].set(row(kNew, poolK))
+        poolV = poolV.at[li, phys, off].set(row(vNew, poolV))
+        poolI = poolI.at[li, phys, off].set(row(kINew, poolI))
+        ctx = _attend_sparse_p.bind(
+            qh.astype(poolK.dtype), qI.astype(jnp.float32),
+            wI.astype(jnp.float32), poolK, poolV, poolI, pageTable, pos,
+            start, li=li, topk=int(topk))
+    return ctx, poolK, poolV, poolI
+
+
+def _index_gathered(qI, wI, poolI, pageTable, *, li):
+    """The reference formulation of the index scores: every slot's index
+    rows gathered in logical order, ``scores (S, capacity)`` float32 from
+    position 0 on."""
+    S, _, dI = qI.shape
+    f32 = jnp.float32
+    cap = pageTable.shape[1] * poolI.shape[2]
+    rows = poolI[li, pageTable].reshape(S, cap, -1)[..., :dI].astype(f32)
+    s = jnp.einsum("sjd,scd->sjc", qI, rows,
+                   precision=jax.lax.Precision.HIGHEST)
+    return jnp.einsum("sj,sjc->sc", wI, jnp.maximum(s, f32(0)),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+_INT_MIN = -2 ** 31
+
+
+def _order_key(x):
+    """float32 -> int32 that orders as the floats do (``-0.0`` taken as
+    ``0.0``): the bit pattern, its magnitude bits flipped below zero."""
+    i32 = jnp.int32
+    b = jax.lax.bitcast_convert_type(x + jnp.float32(0), i32)
+    return jnp.where(b < 0, b ^ i32(0x7FFFFFFF), b)
+
+
+def _kth_largest(key, k: int):
+    """``key (..., n)`` int32 -> the largest ``th (..., 1)`` such that at
+    least ``k`` keys are ``>= th`` (``INT_MIN`` where fewer than ``k`` keys
+    lie above it): bisection over the 32 bits, the sign first."""
+    i32 = jnp.int32
+    count = lambda th: jnp.sum(key >= th, axis=-1, keepdims=True, dtype=i32)
+    th = jnp.where(count(i32(0)) >= k, i32(0), i32(_INT_MIN))
+    th = jnp.broadcast_to(th, key.shape[:-1] + (1,))
+
+    def bit(i, th):
+        cand = th + jnp.left_shift(i32(1), i32(30) - i.astype(i32))
+        return jnp.where(count(cand) >= k, cand, th)
+    return jax.lax.fori_loop(0, 31, bit, th)
+
+
+def _select_mask(scores, valid, k: int):
+    """``scores (..., n)`` float32, ``valid (..., n)`` -> bool: the ``k``
+    valid positions of largest score (all of them where there are no more
+    than ``k``); of equal scores the earlier position.  What a stable
+    descending sort would take, found without one: the ``k``-th largest
+    score exactly, everything above it, and of the ties the first few."""
+    i32 = jnp.int32
+    key = jnp.where(valid, _order_key(scores), i32(_INT_MIN))
+    th = _kth_largest(key, k)
+    above = key > th
+    tie = (key == th) & (th > i32(_INT_MIN))
+    need = k - jnp.sum(above, axis=-1, keepdims=True, dtype=i32)
+    return above | (tie & (jnp.cumsum(tie, axis=-1, dtype=i32) <= need))
+
+
+def _select_attend(q, scores, first, poolK, poolV, pageTable, pos, start, *,
+                   li, topk):
+    """What both forms of the sparse read share: ``scores (S, n)`` of the
+    positions ``first[s] + arange(n)`` -> the ``topk`` best live ones
+    (``lax.top_k`` gives equal scores to the lower index, the earlier
+    position), their K and V rows gathered through the page table, and
+    softmax attention over them.  Queries and softmax weights enter the
+    matmuls rounded to the pool's dtype, as the rows are."""
+    S, H, _, d = q.shape
+    f32, i32 = jnp.float32, jnp.int32
+    ps = poolK.shape[2]
+    h = poolK.shape[3] // d
+    n = scores.shape[1]
+    j = first[:, None] + jnp.arange(n, dtype=i32)[None, :]
+    live = (j >= start[:, None]) & (j <= pos[:, None])
+    top, at = jax.lax.top_k(jnp.where(live, scores, f32(-jnp.inf)),
+                            min(topk, n))
+    keep = top > f32(-jnp.inf)                               # (S, k)
+    idx = jnp.minimum(first[:, None] + at.astype(i32),
+                      pageTable.shape[1] * ps - 1)
+    phys = jnp.take_along_axis(pageTable, idx // ps, axis=1)
+    k = poolK[li, phys, idx % ps].reshape(S, -1, h, d).astype(f32)
+    v = poolV[li, phys, idx % ps].reshape(S, -1, h, d).astype(f32)
+    sc = jnp.einsum("sgrd,skgd->sgrk", q.reshape(S, h, H // h, d
+                                                 ).astype(f32), k,
+                    precision=jax.lax.Precision.HIGHEST) * f32(d ** -0.5)
+    w = jax.nn.softmax(jnp.where(keep[:, None, None], sc, f32(_NEG)),
+                       axis=-1)
+    ctx = jnp.einsum("sgrk,skgd->sgrd", w.astype(poolV.dtype).astype(f32),
+                     v, precision=jax.lax.Precision.HIGHEST)
+    return ctx.reshape(S, H, 1, d)
+
+
+def _attend_sparse_gathered(q, qI, wI, poolK, poolV, poolI, pageTable, pos,
+                            start, *, li, topk):
+    scores = _index_gathered(qI, wI, poolI, pageTable, li=li)
+    return _select_attend(q, scores, jnp.zeros_like(pos), poolK, poolV,
+                          pageTable, pos, start, li=li, topk=topk)
+
+
+#: index rows a place of the scoring kernel's grid works on: a row is 128
+#: lanes, a sixth of a latent row (see :data:`_LATENT_CHUNK_ROWS`)
+_INDEX_CHUNK_ROWS = 512
+
+
+def _index_kernel(_li_ref, tbl_ref, slot_ref, chunk_ref, q_ref, w_ref,
+                  *refs, C):
+    """One place of the grid: ``C`` pages of one slot's index rows against
+    that slot's index queries (``q_ref (heads, W)`` float32, whole in
+    three bfloat16 pieces) -> the rows' scores ``(1, R)``: ReLU a head,
+    weighted by ``w_ref (heads, 128)`` (a head's weight in every lane),
+    summed over the heads."""
+    del tbl_ref, slot_ref, chunk_ref
+    r_refs, o_ref = refs[:C], refs[C]
+    rows = jnp.concatenate([r[...] for r in r_refs], axis=0)     # (R, W)
+    sc = _mxu_dot(q_ref[...], rows, (((1,), (1,)), ((), ())))    # (hI, R)
+    o_ref[...] = jnp.sum(jnp.maximum(sc, jnp.float32(0)) * w_ref[:, 0:1],
+                         axis=0, keepdims=True)
+
+
+def _index_pages(qI, wI, poolI, pageTable, pos, start, *, li,
+                 interpret=False):
+    """The index scores as a Pallas TPU kernel over :func:`_work_list`'s
+    live chunks: only the pages that hold live rows of a slot are read,
+    each once.  ``(scores (S, n) float32, first position (S,))``: a slot's
+    scores begin at its first live page; what lies outside its live rows
+    is whatever the buffer held (the caller masks by position).
+    ``interpret`` is for tests (the CPU)."""
+    S, hI, dI = qI.shape
+    ps, W = poolI.shape[2], poolI.shape[3]
+    i32, f32 = jnp.int32, jnp.float32
+    P = pageTable.shape[1]
+    C = max(1, min(_INDEX_CHUNK_ROWS // ps, P))
+    pos, start = pos.astype(i32), start.astype(i32)
+    tbl, slot, j0, _flag, total = _work_list(
+        pageTable.astype(i32), pos, start, tq=1, pageSize=ps, C=C)
+    # as _work_list places a slot's first chunk: on its first live page
+    n = jnp.minimum((pos + ps) // ps, P).astype(i32)
+    first = jnp.minimum(start // ps, n - 1).astype(i32) * ps
+    chunk = (j0 - first[slot]) // (C * ps)
+    out = _index_call(
+        jnp.full((1,), li, i32), tbl, slot, chunk.astype(i32), total,
+        jnp.pad(qI, ((0, 0), (0, 0), (0, W - dI))),
+        jnp.broadcast_to(wI[:, :, None], (S, hI, 128)).astype(f32), poolI,
+        interpret=interpret)
+    return out.reshape(S, -1), first
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _index_call(li, tbl, slot, chunk, total, q, w, pool, *, interpret):
+    """The scoring kernel's call: :func:`_latent_call`'s grid and page
+    blocks; a place writes its chunk's scores where the slot's row of the
+    output keeps that chunk."""
+    S, hI, W = q.shape
+    ps = pool.shape[2]
+    C = tbl.shape[0] // slot.shape[0]
+    NC = slot.shape[0] // S                  # chunks a slot can have
+
+    def page_spec(c):
+        return pl.BlockSpec(
+            (None, None, ps, W),
+            lambda w, li, tbl, *_: (li[0], tbl[w * C + c], w * 0, w * 0))
+
+    def slot_spec(width):
+        return pl.BlockSpec(
+            (None, hI, width),
+            lambda w, li, tbl, slot, *_: (slot[w], w * 0, w * 0))
+    return pl.pallas_call(
+        functools.partial(_index_kernel, C=C),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(total,),
+            in_specs=[slot_spec(W), slot_spec(128)]
+            + [page_spec(c) for c in range(C)],
+            out_specs=pl.BlockSpec(
+                (None, None, 1, C * ps),
+                lambda w, li, tbl, slot, chunk: (slot[w], chunk[w], w * 0,
+                                                 w * 0))),
+        out_shape=jax.ShapeDtypeStruct((S, NC, 1, C * ps), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        name="paged_sparse_attention_index",
+        interpret=interpret,
+    )(li, tbl, slot, chunk, q, w, *([pool] * C))
+
+
+def _attend_sparse_pages(q, qI, wI, poolK, poolV, poolI, pageTable, pos,
+                         start, *, li, topk, interpret=False):
+    scores, first = _index_pages(qI, wI, poolI, pageTable, pos, start,
+                                 li=li, interpret=interpret)
+    return _select_attend(q, scores, first, poolK, poolV, pageTable, pos,
+                          start, li=li, topk=topk)
+
+
+def _attend_sparse_lowering(ctx, *args, li, topk):
+    kernel = _lowered_as_kernel(ctx, ctx.avals_in[5].dtype)
+    return mlir.lower_fun(
+        functools.partial(
+            _attend_sparse_pages if kernel else _attend_sparse_gathered,
+            li=li, topk=topk),
+        multiple_results=False)(ctx, *args)
+
+
+_attend_sparse_p = jex_core.Primitive("paged_sparse_attention")
+
+
+@functools.partial(jax.jit, static_argnames=("li", "topk"))
+def _attend_sparse_eager(*args, li, topk):
+    return _attend_sparse_p.bind(*args, li=li, topk=topk)
+
+
+_attend_sparse_p.def_impl(_attend_sparse_eager)
+_attend_sparse_p.def_abstract_eval(
+    lambda q, *_, li, topk: jax.core.ShapedArray(q.shape, jnp.float32))
+mlir.register_lowering(_attend_sparse_p, _attend_sparse_lowering)
+
+
 @dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What a served model's layers keep between decode steps — the
@@ -764,7 +1035,11 @@ class CacheSpec:
       that only the keys have (the one rotated key all heads share); the
       row is stored in whole lane tiles of 128, zeros behind the
       ``latentWidth + ropeWidth`` that mean something
-      (:func:`paged_latent_attention`);
+      (:func:`paged_latent_attention`).  A model whose attention
+      SELECTS its rows (``indexWidth > 0``) keeps beside K and V a third
+      pool of *index rows* of the same pages: one key of ``indexWidth``
+      lanes a position a layer, stored as ``indexRowWidth``
+      (:func:`paged_sparse_attention`);
     - *ring*: ``ringLayers`` layers keep the last ``ringRows`` K/V rows
       of every slot, written modulo ``ringRows``;
     - *recurrent*: ``slotState`` names fixed-size arrays ``(name,
@@ -780,6 +1055,14 @@ class CacheSpec:
     slotState: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
     latentWidth: int = 0
     ropeWidth: int = 0
+    indexWidth: int = 0
+
+    @property
+    def indexRowWidth(self) -> int:
+        """Lanes of one stored index row (whole lane tiles of 128, zeros
+        behind the ``indexWidth`` that mean something); 0 for a model
+        with no selector, whose pool then has no third array."""
+        return -(-self.indexWidth // 128) * 128
 
     @property
     def rowWidth(self) -> int:

@@ -54,6 +54,7 @@ from deeplearning4j_tpu.nn.conf.layers import BaseLayer
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_expert_parallel",
            "MoELayer", "MoEFeedForwardLayer", "route_sigmoid_topk",
+           "route_softmax_topk",
            "moe_share_dense", "moe_share_step", "moe_share_grouped",
            "moe_share_counts", "moe_step_kernel_lowerings"]
 
@@ -187,6 +188,19 @@ def route_sigmoid_topk(x, Wr, k: int, scale: float):
     top, idx = lax.top_k(g, k)
     w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scale
     return idx.astype(jnp.int32), w
+
+
+def route_softmax_topk(x, Wr, k: int):
+    """Softmax gate over ALL experts, in float32 (Qwen3-MoE's router with
+    ``norm_topk_prob``): ``x (T, d)``, ``Wr (d, E)`` -> the ``k`` largest
+    of ``softmax(x Wr)`` as ``(idx (T, k) int32, w (T, k))`` with ``w = g /
+    (sum of the k)``.  Float32 at ``HIGHEST`` for the reason
+    :func:`route_sigmoid_topk` gives."""
+    g = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), Wr.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    top, idx = lax.top_k(g, k)
+    return idx.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
 
 
 def _held(idx, lo: int, n: int, real):

@@ -128,9 +128,13 @@ class KVCachePool:
       of ``spec.slotState``, overwritten every step.
 
     ``arrays`` is the tuple the step and the admission write take and
-    return, in this order: ``(k, v[, ringK, ringV][, *slotState])`` —
-    ``(k, v)`` when every layer is paged, ``(rows[, *slotState])`` for
-    latent rows.
+    return, in this order: ``(k, v[, index][, ringK, ringV][,
+    *slotState])`` — ``(k, v)`` when every layer is paged,
+    ``(rows[, *slotState])`` for latent rows.  ``index``
+    ``(pagedLayers, numPages, pageSize, spec.indexRowWidth)`` is there
+    for a model whose attention selects its rows (``spec.indexWidth``):
+    the same pages, free list and page tables address it, so allocating,
+    freeing and replaying a sequence cover it with nothing added.
 
     Page 0 is the SCRATCH page: inactive slots' table entries point at
     it, so the fixed-shape decode step can write their (ignored) K/V
@@ -170,6 +174,10 @@ class KVCachePool:
                  spec.rowWidth)
         arrays = [zeros(paged, spec.dtype, sharding)
                   for _ in range(spec.pagedPools)]
+        if spec.indexWidth:
+            # one key for every index head: no head to split it by
+            arrays.append(zeros(paged[:3] + (spec.indexRowWidth,),
+                                spec.dtype, slotSharding))
         if spec.ringLayers:
             ring = (spec.ringLayers, self.maxSlots, spec.ringRows,
                     spec.rowWidth)
@@ -186,10 +194,12 @@ class KVCachePool:
         itemsize = jnp.dtype(spec.dtype).itemsize
         rowBytes = spec.rowWidth * itemsize
         #: bytes of each kind per live unit: a page (K and V, or the one
-        #: latent row), a ring row (all ring layers, K and V), a slot's
-        #: recurrent state
+        #: latent row), a page's index rows, a ring row (all ring layers,
+        #: K and V), a slot's recurrent state
         self.pageBytes = spec.pagedLayers * self.pageSize \
             * spec.pagedPools * rowBytes
+        self.indexPageBytes = spec.pagedLayers * self.pageSize \
+            * spec.indexRowWidth * itemsize
         self.ringRowBytes = spec.ringLayers * 2 * rowBytes
         self.slotStateBytes = sum(
             int(np.prod(shape)) * jnp.dtype(dt).itemsize
@@ -533,7 +543,8 @@ class ContinuousBatcher:
         self.onSequenceFailure = None
         self._stepFns: Dict[str, object] = {}
         # what the model's step counts on the device and returns in the
-        # columns behind its tokens (row 0): ``(metric, labels)`` each
+        # columns behind its tokens (row 0): ``(metric, labels)`` each, or
+        # ``(metric, labels, unit)`` for a column worth ``unit`` a count
         self._stepCounters = tuple(getattr(lm, "stepCounters", ()))
         self._cacheSeen: Optional[int] = None
         self._busySteps = 0.0
@@ -1489,10 +1500,14 @@ class ContinuousBatcher:
             sm = serving_metrics()
             if self._stepCounters:
                 counted = g[0, g.shape[1] - len(self._stepCounters):]
-                for (metric, labels), n in zip(self._stepCounters, counted):
+                for (metric, labels, *unit), n in zip(self._stepCounters,
+                                                      counted):
                     if n:
+                        # a column may count in units of more than one
+                        # (a count too large for one int32 rides in two)
                         # jaxlint: disable=host-sync -- counted is a slice of g, the already-fetched host copy of this step's output
-                        getattr(sm, metric)().inc(int(n), model=self.name,
+                        n = int(n) * (unit[0] if unit else 1)
+                        getattr(sm, metric)().inc(n, model=self.name,
                                                   **labels)
             occupied = len(flight.slots) / self.maxSlots
             self._steps += 1
@@ -1792,6 +1807,10 @@ class ContinuousBatcher:
                              model=self.name, kind="paged")
         sm.cache_bytes().set(slots * self.pool.slotStateBytes,
                              model=self.name, kind="recurrent")
+        if self.pool.indexPageBytes:
+            sm.index_rows_bytes().set(
+                self.pool.usedPages() * self.pool.indexPageBytes,
+                model=self.name)
         if self.draftPool is not None:
             sm.kv_pages_in_use().set(self.draftPool.usedPages(),
                                      model=self.name, pool="draft")

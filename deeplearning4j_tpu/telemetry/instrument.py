@@ -489,6 +489,16 @@ class ServingMetrics:
             "window layer), recurrent (fixed state of the slots in use)",
             labelnames=("model", "kind"))
 
+    def index_rows_bytes(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_index_rows_bytes",
+            "Bytes of the third paged pool, the index rows of a model "
+            "whose attention selects its rows (CacheSpec.indexWidth), in "
+            "the pages in use: what a decode step's selector reads, "
+            "beside cache_bytes{kind=\"paged\"}, which counts K and V; "
+            "absent for a model without a selector",
+            labelnames=("model",))
+
     def ring_wraps(self):
         return get_registry().counter(
             "dl4j_tpu_serving_ring_wraps_total",
@@ -673,6 +683,24 @@ class ServingMetrics:
             "layers x steps it is the share of the held experts' weights "
             "a step has to read, and the share it DOES read where "
             "moe_step_kernel is 1 (elsewhere the step reads them all)",
+            labelnames=("model", "phase"))
+
+    # the selector of a sparse attention (paged_sparse_attention): counted
+    # like the routing above
+    def sparse_rows_scored(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_sparse_rows_scored_total",
+            "Live index rows the selector scored, summed over the layers "
+            "and the slots (step) or the real queries (prefill: a query "
+            "scores the real positions up to its own)",
+            labelnames=("model", "phase"))
+
+    def sparse_rows_selected(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_sparse_rows_selected_total",
+            "K/V rows attended after selection, summed as the rows "
+            "scored: min(live rows, topk) a query a layer; selected / "
+            "scored is the share of the live rows a query reads",
             labelnames=("model", "phase"))
 
     def loop_phase_seconds(self):

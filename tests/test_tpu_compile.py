@@ -760,3 +760,135 @@ def test_jamba_prefill_and_admission_write_fit(jamba):
     poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
     assert write.memory_analysis().alias_size_in_bytes >= poolBytes
     assert write.memory_analysis().temp_size_in_bytes < 64e6
+
+
+# -- Keye-VL-2.0-30B-A3B's language model as benchmark/configs/
+# keye_vl2_30b_a3b.json serves it: 6 of 48 layers at every published width,
+# 16 of 128 experts held, the whole vocabulary, bfloat16; 16 slots of
+# 34,816 positions in THREE pools (K, V, index rows), pages of 128
+KEYE_SLOTS, KEYE_CAP, KEYE_PAGE, KEYE_BUCKET = 16, 34816, 128, 32768
+
+
+@pytest.fixture(scope="module")
+def keye(one_chip):
+    """``(lm, params, pool arrays, i32, the compiled decode step, the
+    attention and the expert kernels lowered for it)``: shapes on the
+    described chip."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.keye_vl import KeyeVLConfig, KeyeVLLM
+    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
+    from deeplearning4j_tpu.remote import KVCachePool
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    lm = KeyeVLLM(KeyeVLConfig(
+        vocabSize=151936, nLayers=6, hiddenSize=2048, nHeads=32, nKvHeads=4,
+        headSize=128, expertSize=768, nExperts=128, expertsPerToken=8,
+        expertsHeld=(0, 16), indexHeads=16, indexSize=64, topk=2048,
+        maxLen=KEYE_CAP), params={})
+    params = on_chip(jax.eval_shape(lm._init_params))
+    perSeq = KEYE_CAP // KEYE_PAGE
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool.forSpec(
+        lm.cacheSpec(), KEYE_PAGE, 1 + KEYE_SLOTS * perSeq, KEYE_SLOTS,
+        perSeq).arrays))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    before = paged_kernel_lowerings(), moe_step_kernel_lowerings()
+    # ``prev`` is a step's own output: the tokens and the twelve counts
+    step = lm.buildPagedDecodeFn().lower(
+        params, *pool, i32(KEYE_SLOTS, 1),
+        i32(KEYE_SLOTS, 1 + len(lm.stepCounters)),
+        i32(KEYE_SLOTS, perSeq), i32(KEYE_SLOTS), i32(KEYE_SLOTS)).compile()
+    return lm, params, pool, i32, step, (
+        paged_kernel_lowerings() - before[0],
+        moe_step_kernel_lowerings() - before[1])
+
+
+def test_keye_decode_step_fits_and_scores_its_index_rows_in_place(keye):
+    """Of the decode step at the cell's sizes: 12 kernel calls, 6 that
+    score a slot's live index pages where they lie and 6 over the hit
+    experts; the selection is XLA's (a sort a layer) and so is the read of
+    the chosen rows (a row gather); every op of the read carries its
+    scope."""
+    lm, params, pool, i32, compiled, kernelsLowered = keye
+    perSeq = KEYE_CAP // KEYE_PAGE
+    pages = 1 + KEYE_SLOTS * perSeq
+    mem = compiled.memory_analysis()
+    # found: 10.11 GB of arguments (2.41 of weights, 6.85 of K and V rows,
+    # 0.86 of index rows stored 128 lanes wide) + 0.02 of temporaries
+    assert [a.shape for a in pool] == [(6, pages, KEYE_PAGE, 512)] * 2 + [
+        (6, pages, KEYE_PAGE, 128), (1, KEYE_SLOTS, 7)]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert 10.0e9 < mem.argument_size_in_bytes < 10.2e9
+    assert mem.temp_size_in_bytes < 0.1e9
+    # the three pools and the counts are donated and come back aliased,
+    # none copied
+    _assert_one_step_program(compiled, pool)
+    assert not _whole_array_copies(compiled, pool)
+    text = compiled.as_text()
+    assert kernelsLowered == (6, 1)
+    kernels = re.findall(
+        r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert sorted(kernels) == ["moe_share_step"] * 6 \
+        + ["paged_sparse_attention_index"] * 6, kernels
+    # no slot's capacity of index rows, keys or values is gathered: what
+    # leaves the pools is the 2,048 chosen rows a slot
+    for lanes in (128, 512):
+        assert f"bf16[{KEYE_SLOTS},{KEYE_CAP},{lanes}]" not in text
+        assert f"bf16[{KEYE_SLOTS},{perSeq},{KEYE_PAGE},{lanes}]" not in text
+    assert f"bf16[{KEYE_SLOTS},2048,512]" in text
+    # the read's instructions say so in their metadata: the benchmark's
+    # driver tells them from the rest of the step's by it
+    scoped = re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .* ([\w\-]+)\(.*op_name=\"jit\(step\)"
+        r"/paged_sparse_attention/", text, re.M)
+    assert sum(1 for _n, op in scoped if op == "sort") == 6
+    assert sum(1 for n, _op in scoped
+               if n.startswith("paged_sparse_attention_index")) == 6
+
+
+def test_keye_prefill_selects_and_attends_in_kernels_and_fits_beside_the_step(
+        keye):
+    import jax
+    lm, params, pool, i32, step, _ = keye
+    compiled = lm._prefillRawFn.at(KEYE_BUCKET).lower(
+        params, i32(1, KEYE_BUCKET), i32(1)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    # every layer's selection is the bisection kernel and its attention
+    # the flash kernel under the selection's tiles: no score of 32,768
+    # keys a query is held outside VMEM, for the indexer's 16 heads or
+    # the attention's 32
+    assert kernels.count("sparse_prefill_select") == 6
+    assert kernels.count("sparse_prefill_attention") == 6
+    assert not re.search(rf"f32\[[\d,]*{KEYE_BUCKET},{KEYE_BUCKET}\]", text)
+    # the held experts are multiplied by GROUP, 4,096 tokens a pass: no
+    # (tokens, tokens) matrix of a whole bucket brings the pairs home
+    assert f"[{KEYE_BUCKET},{KEYE_BUCKET}]" not in text
+    mem = compiled.memory_analysis()
+    # found: 10.11 + 0.02 (the step) + 2.49 of temporaries (1.07 of them
+    # a layer's selection as int8 tiles) + 0.45 of rows and logits out =
+    # 13.07 GB
+    step = step.memory_analysis()
+    assert step.argument_size_in_bytes + step.temp_size_in_bytes \
+        + mem.temp_size_in_bytes + mem.output_size_in_bytes < 13.5e9
+    state = jax.eval_shape(lm._prefillRawFn, params, i32(1, KEYE_BUCKET),
+                           i32(1))[1:]
+    assert [p.shape for p in state] == [
+        (6, 1, 4, KEYE_BUCKET, 128)] * 2 + [(6, 1, 1, KEYE_BUCKET, 128),
+                                            (1, 1, 7)]
+    parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
+                                  sharding=pool[0].sharding) for p in state]
+    write = lm.buildPagedPrefillWriteFn().lower(
+        *pool, *parts, i32(KEYE_BUCKET // KEYE_PAGE), i32()).compile()
+    assert not _whole_array_copies(write, pool)
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert write.memory_analysis().alias_size_in_bytes >= poolBytes
+    assert write.memory_analysis().temp_size_in_bytes < 0.5e9
