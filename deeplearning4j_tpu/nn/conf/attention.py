@@ -35,6 +35,7 @@ __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "paged_attention", "paged_attention_read",
            "paged_kernel_lowerings",
            "paged_kernel_kv_passes", "lowered_for_one_tpu",
+           "sparse_in_place_lowerings",
            "paged_latent_attention", "paged_sparse_attention",
            "paged_prefill_write",
            "paged_rows_write", "paged_step_tokens",
@@ -268,7 +269,7 @@ def _lane_tiles(heads, d):
 
 
 def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
-                  start_ref, q_ref, *refs, C, ps, tq, d):
+                  start_ref, *refs, C, ps, tq, d, masked=False):
     """One place of the grid: ``C`` pages of K and of V of one slot
     (``k_refs``/``v_refs``, each ``(ps, h*d)``, copied in by the
     pipeline while the place before computes), all ``tq`` queries of that
@@ -297,8 +298,16 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
     K and V enter the MXU as they are stored, in as many passes as
     :func:`_mxu_parts` of the pool's dtype says (a bfloat16 pool: one, and
     no float32 copy of a page is ever made); ``q`` and the weights keep
-    every float32 bit."""
+    every float32 bit.
+
+    ``masked`` (:func:`_selected_call` alone): an eighth scalar placed the
+    block of ``keep_ref (1, R)`` int32, this place's rows of a mask a
+    SELECTOR made, and a row counts only where it is set as well."""
     f32, bf16 = jnp.float32, jnp.bfloat16
+    if masked:
+        _chunk_ref, q_ref, keep_ref, *refs = refs
+    else:
+        q_ref, *refs = refs
     k_refs, v_refs = refs[:C], refs[C:2 * C]
     o_ref, qt_ref, sp_ref, c_ref, m_ref, l_ref, acc_ref = refs[2 * C:]
     w = pl.program_id(0)
@@ -353,6 +362,8 @@ def _pages_kernel(_li_ref, tbl_ref, slot_ref, j0_ref, flag_ref, pos_ref,
     scale = []                      # what each query's sums so far shrink by
     for i in range(tq):
         valid = (j >= start) & (j <= pos + i)
+        if masked:
+            valid = valid & (keep_ref[...] > 0)
         sc = functools.reduce(operator.add, (
             sp_ref[:, rows(part, i), :] for part in range(_F32_PARTS)))
         sc = jnp.where(valid, sc, f32(_NEG))                 # (T, B, R)
@@ -428,6 +439,18 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
     the step has chunks of live pages.  A jit of its own with the layer
     as an argument: every layer of a step is then the same computation,
     traced and lowered to Mosaic once a program and not once a layer."""
+    return _pages_grid((li, tbl, slot, j0, flag, pos, start), total, q, None,
+                       poolK, poolV, headSize=headSize, tq=tq,
+                       interpret=interpret)
+
+
+def _pages_grid(scalars, total, q, keep, poolK, poolV, *, headSize, tq,
+                interpret):
+    """:func:`_pages_call`'s ``pallas_call``, and :func:`_selected_call`'s:
+    with ``keep (S, chunks a slot, 1, R)`` the kernel is the masked one
+    under a name of its own, ``keep``'s block placed by the last of
+    ``scalars`` (the place's chunk of its slot)."""
+    tbl, slot = scalars[1], scalars[2]
     S, nq, hd = q.shape             # nq = tq x the query heads a KV head
     ps = poolK.shape[2]
     C = tbl.shape[0] // slot.shape[0]
@@ -439,6 +462,7 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
     # heads, piece by piece (whole bfloat16 sublane tiles of 16)
     rp = -(-_F32_PARTS * tq * B // 16) * 16
     f32 = jnp.float32
+    masked = keep is not None
 
     # index maps: ``w * 0`` and not ``0`` (the package enables x64, and a
     # bare literal would be an int64 Mosaic has not)
@@ -448,12 +472,17 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
             lambda w, li, tbl, *_: (li[0], tbl[w * C + c], w * 0, w * 0))
     row_spec = pl.BlockSpec(
         (None, nq, hd), lambda w, li, tbl, slot, *_: (slot[w], w * 0, w * 0))
+    keep_spec = pl.BlockSpec(
+        (None, None, 1, R),
+        lambda w, li, tbl, slot, *s: (slot[w], s[-1][w], w * 0, w * 0))
     return pl.pallas_call(
-        functools.partial(_pages_kernel, C=C, ps=ps, tq=tq, d=d),
+        functools.partial(_pages_kernel, C=C, ps=ps, tq=tq, d=d,
+                          masked=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
+            num_scalar_prefetch=len(scalars),
             grid=(total,),
-            in_specs=[row_spec] + [page_spec(c) for c in range(C)] * 2,
+            in_specs=[row_spec] + [keep_spec] * masked
+            + [page_spec(c) for c in range(C)] * 2,
             out_specs=row_spec,
             scratch_shapes=[
                 pltpu.VMEM((T, rp, g * d), f32),     # the queries' Q_t
@@ -467,10 +496,9 @@ def _pages_call(li, tbl, slot, j0, flag, total, pos, start, q, poolK, poolV,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 << 20),
-        name="paged_attention",
+        name="paged_selected_attention" if masked else "paged_attention",
         interpret=interpret,
-    )(li, tbl, slot, j0, flag, pos, start, q, *([poolK] * C),
-      *([poolV] * C))
+    )(*scalars, q, *([keep] * masked), *([poolK] * C), *([poolV] * C))
 
 
 #: how often the step's read was lowered as the kernel, and the MXU passes
@@ -771,12 +799,26 @@ def paged_sparse_attention(qh, kNew, vNew, qI, wI, kINew, poolK, poolV,
     equal scores the earlier position) and attends over those rows of K
     and V alone, read through the page table: ``(ctx (slots, heads, 1,
     headSize) float32, poolK, poolV, poolI)``.  The result depends on a
-    slot's logical content alone.  Lowered like :func:`paged_attention`:
-    for one TPU a kernel scores the live index pages where they lie
-    (:func:`_index_pages`); elsewhere every slot's whole capacity of
-    index rows is gathered (:func:`_index_gathered`).  Selection
-    (``lax.top_k``) and the read of the chosen rows (a row gather) are
-    the same in both."""
+    slot's logical content alone.  Lowered like :func:`paged_attention`,
+    from what the program is lowered for and its static shapes, in one of
+    three forms:
+
+    - one TPU, a slot's capacity x a row's bytes up to
+      :data:`_IN_PLACE_BYTES_A_PICK` a chosen row
+      (:func:`_attend_sparse_in_place`): a kernel scores the live index
+      pages where they lie (:func:`_index_pages`), the selection is a MASK
+      (:func:`_select_mask`, by bisection: no sort), and the chosen rows
+      are attended IN THE POOL by a masked pass of the paged-attention
+      kernel over the slot's live pages (:func:`_attend_selected_pages`):
+      no index of a chosen row is formed, no row leaves the pool;
+    - one TPU, a larger capacity (:func:`_attend_sparse_pages`): the same
+      scoring kernel, then ``lax.top_k`` and a row gather of the chosen K
+      and V rows through the page table (:func:`_select_attend`), whose
+      cost does not grow with what is live;
+    - elsewhere (the CPU, several devices; :func:`_attend_sparse_gathered`,
+      the reference formulation): every slot's whole capacity of index
+      rows is gathered (:func:`_index_gathered`), then
+      :func:`_select_attend`."""
     S, h, tq, d = kNew.shape
     if tq != 1:
         raise ValueError("the sparse read takes one new position a slot")
@@ -858,21 +900,29 @@ def _select_mask(scores, valid, k: int):
     return above | (tie & (jnp.cumsum(tie, axis=-1, dtype=i32) <= need))
 
 
+def _live_columns(n, first, pos, start):
+    """``(S, n)`` bool over a slot's scores: column ``c`` of slot ``s`` is
+    the position ``first[s] + c``, live while ``start <= . <= pos``."""
+    j = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+    return (j >= start[:, None]) & (j <= pos[:, None])
+
+
 def _select_attend(q, scores, first, poolK, poolV, pageTable, pos, start, *,
                    li, topk):
-    """What both forms of the sparse read share: ``scores (S, n)`` of the
-    positions ``first[s] + arange(n)`` -> the ``topk`` best live ones
-    (``lax.top_k`` gives equal scores to the lower index, the earlier
-    position), their K and V rows gathered through the page table, and
-    softmax attention over them.  Queries and softmax weights enter the
-    matmuls rounded to the pool's dtype, as the rows are."""
+    """The chosen rows read as a COPY, what the reference formulation and
+    the one-TPU form beyond the crossover share (under it the rows are
+    read where they lie: :func:`_attend_selected_pages`): ``scores (S,
+    n)`` of the positions ``first[s] + arange(n)`` -> the ``topk`` best
+    live ones (``lax.top_k`` gives equal scores to the lower index, the
+    earlier position), their K and V rows gathered through the page
+    table, and softmax attention over them.  Queries and softmax weights
+    enter the matmuls rounded to the pool's dtype, as the rows are."""
     S, H, _, d = q.shape
     f32, i32 = jnp.float32, jnp.int32
     ps = poolK.shape[2]
     h = poolK.shape[3] // d
     n = scores.shape[1]
-    j = first[:, None] + jnp.arange(n, dtype=i32)[None, :]
-    live = (j >= start[:, None]) & (j <= pos[:, None])
+    live = _live_columns(n, first, pos, start)
     top, at = jax.lax.top_k(jnp.where(live, scores, f32(-jnp.inf)),
                             min(topk, n))
     keep = top > f32(-jnp.inf)                               # (S, k)
@@ -987,19 +1037,123 @@ def _index_call(li, tbl, slot, chunk, total, q, w, pool, *, interpret):
 
 def _attend_sparse_pages(q, qI, wI, poolK, poolV, poolI, pageTable, pos,
                          start, *, li, topk, interpret=False):
+    """The one-TPU form beyond :data:`_IN_PLACE_BYTES_A_PICK`: the scoring
+    kernel, then :func:`_select_attend`'s sort and row gather."""
     scores, first = _index_pages(qI, wI, poolI, pageTable, pos, start,
                                  li=li, interpret=interpret)
     return _select_attend(q, scores, first, poolK, poolV, pageTable, pos,
                           start, li=li, topk=topk)
 
 
+#: bytes of K (and as many of V) a place of the MASKED pass's grid moves, as
+#: whole pages: 512 rows of Keye-VL's 1 KB row, 1 MB a place.  At
+#: :data:`_CHUNK_ROWS` a place of that pool would move 0.31 us of bytes
+#: under a place's own 0.3-0.4 us.  One layer's pass alone over 16 slots
+#: of 18,500 / 32,768 live rows (0.740 / 1.311 ms of bytes at 819 GB/s), ms
+#: a call at 256 / 512 / 1,024 / 2,048 rows a place: 1.132 / 0.968 / 0.956
+#: / 0.973 and 1.888 / 1.590 / 1.582 / 1.590; the whole read 1.573 / 1.294
+#: / 1.389 / 1.394 and 2.519 / 2.110 / 2.206 / 2.212 (my chip run, PR 41):
+#: from 512 rows on the pass costs the same, and at 512 its work list is
+#: the scoring kernel's (:data:`_INDEX_CHUNK_ROWS` rows of this page
+#: size), built once a step for both
+_SELECTED_PLACE_BYTES = 512 << 10
+
+#: the masked pass streams a slot's live pages, the gather fetches ``topk``
+#: rows whatever is live and sorts the capacity: in place while a slot's
+#: CAPACITY in bytes of K is at most this many a chosen row, 20 x ``topk``
+#: rows of 1 KB.  One layer's read with every slot FULL, in place against
+#: sort + gather, ms a call at a capacity of 17 / 19 / 20 / 22 x ``topk``
+#: = 2,048: 2.200 / 2.505, 2.481 / 2.621, 2.622 / 2.645, 2.867 / 2.766 (at
+#: 34 x, 1,024 rows a place: 4.574 / 3.726); half full the pass wins at
+#: each (1.235 / 2.277 ... 1.617 / 2.466; at 34 x 2.628 / 3.267) (my chip
+#: runs, PR 41).  The choice is static, so it is made for the full pool
+_IN_PLACE_BYTES_A_PICK = 20 << 10
+
+
+def _attend_selected_pages(q, keep, first, poolK, poolV, pageTable, pos,
+                           start, *, li, interpret=False):
+    """:func:`_attend_pages` over the rows a selector KEPT: ``keep (S, n)``
+    bool, column ``c`` of slot ``s`` the position ``first[s] + c`` (as
+    :func:`_index_pages` lays its scores: from the slot's first live
+    page).  The kernel's pass over the slot's live pages where they lie,
+    a row counting only where ``keep`` is set: no index of a kept row is
+    formed and no row leaves the pool.  ``q (S, H, 1, d)`` in the pool's
+    dtype; the context comes back float32."""
+    S, H, _, d = q.shape
+    ps, hd = poolK.shape[2:]
+    h = hd // d
+    i32 = jnp.int32
+    P = pageTable.shape[1]
+    C = max(1, min(_SELECTED_PLACE_BYTES // (hd * poolK.dtype.itemsize)
+                   // ps, P))
+    R = C * ps
+    pos, start = pos.astype(i32), start.astype(i32)
+    tbl, slot, j0, flag, total = _work_list(
+        pageTable.astype(i32), pos, start, tq=1, pageSize=ps, C=C)
+    width = -(-P // C) * R                   # every chunk a slot can have
+    n = min(keep.shape[1], width)
+    keep = jnp.pad(keep[:, :n], ((0, 0), (0, width - n))).astype(i32)
+    rows = (q.astype(jnp.float32) * jnp.float32(d ** -0.5)).reshape(
+        S, h, H // h, d).transpose(0, 2, 1, 3).reshape(S, H // h, hd)
+    out = _selected_call(
+        jnp.full((1,), li, i32), tbl, slot, j0, flag, total, pos, start,
+        ((j0 - first[slot]) // R).astype(i32), rows,
+        keep.reshape(S, -1, 1, R), poolK, poolV, headSize=d,
+        interpret=interpret)
+    return out.reshape(S, H // h, h, d).transpose(0, 2, 1, 3).reshape(
+        S, H, 1, d)
+
+
+@functools.partial(jax.jit, static_argnames=("headSize", "interpret"))
+def _selected_call(li, tbl, slot, j0, flag, total, pos, start, chunk, q,
+                   keep, poolK, poolV, *, headSize, interpret):
+    """:func:`_pages_call` under a mask: ``keep (S, chunks a slot, 1, R)``
+    int32, a place reading the block of its slot's ``chunk``.  Its device
+    op is ``paged_selected_attention*``."""
+    return _pages_grid((li, tbl, slot, j0, flag, pos, start, chunk), total,
+                       q, keep, poolK, poolV, headSize=headSize, tq=1,
+                       interpret=interpret)
+
+
+def _attend_sparse_in_place(q, qI, wI, poolK, poolV, poolI, pageTable, pos,
+                            start, *, li, topk, interpret=False):
+    """The one-TPU form up to :data:`_IN_PLACE_BYTES_A_PICK`: the scoring
+    kernel, the selection as a MASK (:func:`_select_mask`: what a stable
+    descending sort would take, found by bisection) and the masked pass
+    of the paged-attention kernel over the slot's live pages
+    (:func:`_attend_selected_pages`)."""
+    scores, first = _index_pages(qI, wI, poolI, pageTable, pos, start,
+                                 li=li, interpret=interpret)
+    keep = _select_mask(
+        scores, _live_columns(scores.shape[1], first, pos, start), topk)
+    return _attend_selected_pages(q, keep, first, poolK, poolV, pageTable,
+                                  pos, start, li=li, interpret=interpret)
+
+
+#: how often the sparse read was lowered as the masked pass
+_inPlaceLowerings = [0]
+
+
+def sparse_in_place_lowerings() -> int:
+    """How many times :func:`paged_sparse_attention`'s read has been
+    lowered as the masked pass over the live pages in this process (once a
+    layer of a program built for one TPU whose capacity is under the
+    crossover; never where it gathers the chosen rows)."""
+    return _inPlaceLowerings[0]
+
+
 def _attend_sparse_lowering(ctx, *args, li, topk):
-    kernel = _lowered_as_kernel(ctx, ctx.avals_in[5].dtype)
-    return mlir.lower_fun(
-        functools.partial(
-            _attend_sparse_pages if kernel else _attend_sparse_gathered,
-            li=li, topk=topk),
-        multiple_results=False)(ctx, *args)
+    form = _attend_sparse_gathered
+    if _lowered_as_kernel(ctx, ctx.avals_in[5].dtype):
+        poolK, pageTable = ctx.avals_in[3], ctx.avals_in[6]
+        rowBytes = poolK.shape[3] * poolK.dtype.itemsize
+        capacity = pageTable.shape[1] * poolK.shape[2]
+        form = _attend_sparse_pages
+        if capacity * rowBytes <= _IN_PLACE_BYTES_A_PICK * topk:
+            form = _attend_sparse_in_place
+            _inPlaceLowerings[0] += 1
+    return mlir.lower_fun(functools.partial(form, li=li, topk=topk),
+                          multiple_results=False)(ctx, *args)
 
 
 _attend_sparse_p = jex_core.Primitive("paged_sparse_attention")

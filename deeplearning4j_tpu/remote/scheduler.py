@@ -64,7 +64,8 @@ import numpy as np
 from deeplearning4j_tpu.fault import injection as _inj
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_kernel_kv_passes,
-                                                  paged_kernel_lowerings)
+                                                  paged_kernel_lowerings,
+                                                  sparse_in_place_lowerings)
 from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
@@ -664,8 +665,8 @@ class ContinuousBatcher:
         tok0 = jnp.zeros((S, 1), jnp.int32)
         pt = jnp.asarray(self.pool.pageTable)
         step = self._stepFns["step"]
-        lowered, experts = paged_kernel_lowerings(), \
-            moe_step_kernel_lowerings()
+        lowered, experts, inPlace = paged_kernel_lowerings(), \
+            moe_step_kernel_lowerings(), sparse_in_place_lowerings()
         prev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
         # one kernel lowering for each layer whose rows the step read
@@ -684,6 +685,9 @@ class ContinuousBatcher:
         sm.moe_step_kernel().set(
             1 if moe_step_kernel_lowerings() > experts else 0,
             model=self.name)
+        sm.sparse_read_in_place().set(
+            1 if spec.indexWidth and sparse_in_place_lowerings() - inPlace
+            >= spec.pagedLayers else 0, model=self.name)
         # and with a step's own output for ``prev``, as every later call
         # has it: beside committed params that is another entry of the
         # jit's cache than fresh zeros.  It stands in wherever no step is

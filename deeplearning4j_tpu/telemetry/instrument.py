@@ -447,6 +447,19 @@ class ServingMetrics:
             "and for a model without ring layers",
             labelnames=("model",))
 
+    def sparse_read_in_place(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_sparse_read_in_place",
+            "1 when every layer of the batcher's decode step whose "
+            "attention selects its rows reads the chosen K and V rows "
+            "where they lie: the paged-attention kernel's pass over the "
+            "slot's live pages under the selection's mask (lowered for "
+            "one TPU, the slot's capacity under the crossover), 0 when "
+            "it sorts the scores and gathers the chosen rows (a larger "
+            "capacity, the CPU, several devices) and for a model "
+            "without a selector",
+            labelnames=("model",))
+
     def moe_step_kernel(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_moe_step_kernel",

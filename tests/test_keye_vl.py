@@ -172,16 +172,22 @@ def _plain_sparse(q, qI, wI, K, V, KI, start, topk):
     return out
 
 
-@pytest.mark.parametrize("form", ["gathered", "kernel"])
+@pytest.mark.parametrize("form", ["gathered", "kernel", "in_place",
+                                  "in_place_3_pages"])
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
-def test_sparse_read_is_the_plain_formula(dtype, tol, form):
+def test_sparse_read_is_the_plain_formula(dtype, tol, form, monkeypatch):
     """``paged_sparse_attention`` (its gathered form and, interpreted, its
-    kernel over the live index pages) against the plain formula: slots
+    two one-TPU forms: the kernel over the live index pages with the sort
+    and the row gather behind it, and with the selection as a mask under
+    the paged-attention kernel's pass over the live pages, IN PLACE) against
+    the plain formula: slots
     with fewer live rows than ``topk``, exactly ``topk`` and more; a left
     pad that swallows whole pages; a new row on a page's first and last
     place; pages in no order that still hold an earlier tenant's rows; a
     ``pos`` 0 slot, whose rows land on the scratch page and whose output
-    is not read.  Tied index scores go to the earlier position."""
+    is not read.  Tied index scores go to the earlier position.  At 3
+    pages a place the masked pass takes 1 to 3 places a slot, the last of
+    slots 0, 1, 3 and 4 partly dead."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.conf import attention as A
     rs = np.random.RandomState(0)
@@ -205,19 +211,20 @@ def test_sparse_read_is_the_plain_formula(dtype, tol, form):
     poolI = jnp.asarray(np.pad(rowsI, ((0, 0),) * 3 + ((0, 128 - dI),)), dt)
     q, kN, vN = rnd(S, H, 1, d), rnd(S, h, 1, d), rnd(S, h, 1, d)
     qI, wI, kIN = rnd(S, hI, dI), rnd(S, hI), rnd(S, dI)
-    if form == "kernel":
-        kept = A._attend_sparse_p
-        prim = lambda *a, li, topk: A._attend_sparse_pages(
-            *a, li=li, topk=topk, interpret=True)
-        A._attend_sparse_p = type("P", (), {"bind": staticmethod(prim)})
-    try:
-        ctx, nK, nV, nI = A.paged_sparse_attention(
-            *(jnp.asarray(a) for a in (q, kN, vN, qI, wI, kIN)), poolK,
-            poolV, poolI, li, jnp.asarray(table), jnp.asarray(pos),
-            jnp.asarray(start), topk=topk)
-    finally:
-        if form == "kernel":
-            A._attend_sparse_p = kept
+    if form != "gathered":
+        lowered = A._attend_sparse_pages if form == "kernel" \
+            else A._attend_sparse_in_place
+        prim = lambda *a, li, topk: lowered(*a, li=li, topk=topk,
+                                            interpret=True)
+        monkeypatch.setattr(A, "_attend_sparse_p",
+                            type("P", (), {"bind": staticmethod(prim)}))
+    if form == "in_place_3_pages":
+        monkeypatch.setattr(A, "_SELECTED_PLACE_BYTES",
+                            3 * ps * h * d * dt.itemsize)
+    ctx, nK, nV, nI = A.paged_sparse_attention(
+        *(jnp.asarray(a) for a in (q, kN, vN, qI, wI, kIN)), poolK,
+        poolV, poolI, li, jnp.asarray(table), jnp.asarray(pos),
+        jnp.asarray(start), topk=topk)
     ctx = np.asarray(ctx)
     f = lambda a: np.asarray(a.astype(jnp.float32))
     nK, nV, nI = f(nK), f(nV), f(nI)
@@ -585,6 +592,7 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts(
     # off the TPU the step gathers: the kernels' gauges say so
     assert sm.paged_attention_kernel().value(model="keye") == 0
     assert sm.moe_step_kernel().value(model="keye") == 0
+    assert sm.sparse_read_in_place().value(model="keye") == 0
     got = {k: v - before[k] for k, v in _counted().items()}
     pairs = TINY["num_experts_per_tok"] * LAYERS
     assert got["moe_pairs_routed", "prefill"] \
@@ -606,6 +614,40 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts(
     k = np.minimum(n, TOPK)
     assert got["sparse_rows_selected", "prefill"] == LAYERS * sum(
         k * (k + 1) // 2 + (n - k) * k)
+
+
+@pytest.mark.parametrize("lowered,want", [(None, 0), (LAYERS - 1, 0),
+                                          (LAYERS, 1)])
+def test_gauge_reads_1_where_every_sparse_layer_was_lowered_in_place(
+        family, weights, monkeypatch, lowered, want):
+    """The batcher reads ``sparse_in_place_lowerings`` around its warm-up's
+    first step: gauge ``sparse_read_in_place`` is 1 where the step lowered
+    the masked pass for every layer that selects its rows, 0 where one of
+    them gathers.  On the CPU (``lowered`` None: the counters as they are)
+    the gathered reference is lowered and neither counter moves; which
+    form one TPU gets at which capacity is ``tests/test_tpu_compile.py``'s."""
+    from deeplearning4j_tpu.nn.conf import attention as A
+    from deeplearning4j_tpu.remote import (BucketLadder, ContinuousBatcher,
+                                           scheduler)
+    from deeplearning4j_tpu.telemetry import serving_metrics
+    before = A.paged_kernel_lowerings(), A.sparse_in_place_lowerings()
+    if lowered is not None:
+        counts = iter([before[1], before[1] + lowered])
+        monkeypatch.setattr(scheduler, "sparse_in_place_lowerings",
+                            lambda: next(counts))
+    cb = ContinuousBatcher(
+        _lm(family, weights, "float32"), name="keye-gauge", maxSlots=SLOTS,
+        pageSize=PAGE, numPages=1 + SLOTS * (CAP // PAGE),
+        ladder=BucketLadder(batchSizes=(SLOTS,), seqLens=(8,)))
+    serving_metrics().sparse_read_in_place().set(1 - want, model="keye-gauge")
+    cb.start()
+    try:
+        assert serving_metrics().sparse_read_in_place().value(
+            model="keye-gauge") == want
+    finally:
+        cb.shutdown()
+    assert (A.paged_kernel_lowerings(),
+            A.sparse_in_place_lowerings()) == before
 
 
 def test_preempt_replay_and_evacuate_rebuild_the_index_rows(ref, weights,
@@ -681,7 +723,8 @@ def test_every_serving_series_covers_the_model_under_the_batcher_s_name(
                  "dl4j_tpu_serving_moe_pairs_routed_total",
                  "dl4j_tpu_serving_moe_experts_hit_total",
                  "dl4j_tpu_serving_sparse_rows_scored_total",
-                 "dl4j_tpu_serving_sparse_rows_selected_total"):
+                 "dl4j_tpu_serving_sparse_rows_selected_total",
+                 "dl4j_tpu_serving_sparse_read_in_place"):
         assert want in names, want
     assert sm.decode_steps().value(model="keye") >= 11
     phases = {ln.split('phase="')[1].split('"')[0] for ln in mine

@@ -115,7 +115,10 @@ def _assert_one_step_program(compiled, cache):
     assert compiled.memory_analysis().alias_size_in_bytes >= cacheBytes
 
 
-def test_paged_decode_step_updates_the_pool_in_place(paged):
+@pytest.fixture(scope="module")
+def paged_step(paged):
+    """``(the compiled decode step, the attention kernels lowered for it,
+    their MXU passes)``: compiled once for the tests that read it."""
     from deeplearning4j_tpu.nn.conf.attention import (
         paged_kernel_kv_passes, paged_kernel_lowerings)
     lm, params, pool, i32 = paged
@@ -123,17 +126,24 @@ def test_paged_decode_step_updates_the_pool_in_place(paged):
     compiled = lm.buildPagedDecodeFn().lower(
         params, pool, pool, i32(SLOTS, 1), i32(SLOTS, 1),
         i32(SLOTS, PER_SEQ), i32(SLOTS), i32(SLOTS)).compile()
+    return compiled, paged_kernel_lowerings() - before, \
+        paged_kernel_kv_passes()
+
+
+def test_paged_decode_step_updates_the_pool_in_place(paged, paged_step):
+    lm, params, pool, i32 = paged
+    compiled, kernelsLowered, kvPasses = paged_step
     _assert_in_place(compiled, pool, "jit_step")
     _assert_one_step_program(compiled, [pool, pool])
     # every layer attends through the kernel that reads the live pages
     # where they lie: lowered for one TPU, so chosen with no knob, under
     # JAX_PLATFORMS=cpu
     text = compiled.as_text()
-    assert paged_kernel_lowerings() - before == LAYERS
+    assert kernelsLowered == LAYERS
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
                           text)) == LAYERS
     # a float32 pool enters the MXU in three bfloat16 pieces
-    assert paged_kernel_kv_passes() == 3
+    assert kvPasses == 3
     # so no slot's capacity is gathered (K or V of all four slots, 256
     # pages of 16 rows) and no gathered row is re-laid into heads
     # (f32[256,16,1600], f32[4,1024,25,64])
@@ -146,6 +156,19 @@ def test_paged_decode_step_updates_the_pool_in_place(paged):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
 
 
+def _rows_and_lanes(topo, one_chip, chips):
+    """Where a test of a lowering rule puts its small operands and its
+    pools: on the one chip, or over four with the pools' lanes split (the
+    tensor-parallel replica's placement)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    if chips == 1:
+        return one_chip, one_chip
+    mesh = Mesh(np.array(topo.devices), ("model",))
+    return NamedSharding(mesh, P()), NamedSharding(
+        mesh, P(None, None, None, "model"))
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_paged_attention_is_the_kernel_for_one_tpu_only(topo, one_chip,
                                                         chips):
@@ -155,17 +178,10 @@ def test_paged_attention_is_the_kernel_for_one_tpu_only(topo, one_chip,
     partitioned over) the gathered reference — no option says which."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from deeplearning4j_tpu.nn.conf.attention import (
         paged_attention, paged_kernel_lowerings)
     S, h, d, perSeq = 2, 4, 32, 4
-    if chips == 1:
-        rows = lanes = one_chip
-    else:
-        mesh = Mesh(np.array(topo.devices), ("model",))
-        rows = NamedSharding(mesh, P())
-        lanes = NamedSharding(mesh, P(None, None, None, "model"))
+    rows, lanes = _rows_and_lanes(topo, one_chip, chips)
 
     def sds(shape, dtype, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -348,7 +364,9 @@ def _whole_array_copies(compiled, arrays):
     return found
 
 
-def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
+@pytest.fixture(scope="module")
+def sambay_step(sambay):
+    """As ``paged_step``, SambaY's."""
     from deeplearning4j_tpu.nn.conf.attention import (
         paged_kernel_kv_passes, paged_kernel_lowerings)
     lm, params, pool, i32 = sambay
@@ -357,6 +375,14 @@ def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
     compiled = lm.buildPagedDecodeFn().lower(
         params, *pool, i32(PHI_SLOTS, 1), i32(PHI_SLOTS, 1),
         i32(PHI_SLOTS, perSeq), i32(PHI_SLOTS), i32(PHI_SLOTS)).compile()
+    return compiled, paged_kernel_lowerings() - before, \
+        paged_kernel_kv_passes()
+
+
+def test_sambay_decode_step_fits_and_updates_its_state_in_place(
+        sambay, sambay_step):
+    lm, params, pool, i32 = sambay
+    compiled, kernelsLowered, kvPasses = sambay_step
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     # the six arrays are donated and come back aliased, not copied
@@ -373,9 +399,8 @@ def test_sambay_decode_step_fits_and_updates_its_state_in_place(sambay):
     # layer 0; one for each ring layer: what the batcher's gauge
     # ``ring_attention_kernel`` counts)
     spec = lm.cacheSpec()
-    assert paged_kernel_lowerings() - before \
-        == spec.pagedLayers + spec.ringLayers == 9
-    assert paged_kernel_kv_passes() == 1
+    assert kernelsLowered == spec.pagedLayers + spec.ringLayers == 9
+    assert kvPasses == 1
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 16
     assert len(re.findall(r"%paged_attention[\w.]* = ", text)) == 16
     # so no slot's capacity is gathered (32 slots of 2,560 rows of 1,280
@@ -772,12 +797,13 @@ KEYE_SLOTS, KEYE_CAP, KEYE_PAGE, KEYE_BUCKET = 16, 34816, 128, 32768
 @pytest.fixture(scope="module")
 def keye(one_chip):
     """``(lm, params, pool arrays, i32, the compiled decode step, the
-    attention and the expert kernels lowered for it)``: shapes on the
-    described chip."""
+    attention and the expert kernels lowered for it and the sparse reads
+    lowered in place)``: shapes on the described chip."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.nlp.keye_vl import KeyeVLConfig, KeyeVLLM
-    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.nn.conf.attention import (
+        paged_kernel_lowerings, sparse_in_place_lowerings)
     from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
     from deeplearning4j_tpu.remote import KVCachePool
 
@@ -797,23 +823,27 @@ def keye(one_chip):
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    before = paged_kernel_lowerings(), moe_step_kernel_lowerings()
+    counts = (paged_kernel_lowerings, moe_step_kernel_lowerings,
+              sparse_in_place_lowerings)
+    before = [c() for c in counts]
     # ``prev`` is a step's own output: the tokens and the twelve counts
     step = lm.buildPagedDecodeFn().lower(
         params, *pool, i32(KEYE_SLOTS, 1),
         i32(KEYE_SLOTS, 1 + len(lm.stepCounters)),
         i32(KEYE_SLOTS, perSeq), i32(KEYE_SLOTS), i32(KEYE_SLOTS)).compile()
-    return lm, params, pool, i32, step, (
-        paged_kernel_lowerings() - before[0],
-        moe_step_kernel_lowerings() - before[1])
+    return lm, params, pool, i32, step, tuple(
+        c() - b for c, b in zip(counts, before))
 
 
 def test_keye_decode_step_fits_and_scores_its_index_rows_in_place(keye):
-    """Of the decode step at the cell's sizes: 12 kernel calls, 6 that
-    score a slot's live index pages where they lie and 6 over the hit
-    experts; the selection is XLA's (a sort a layer) and so is the read of
-    the chosen rows (a row gather); every op of the read carries its
-    scope."""
+    """Of the decode step at the cell's sizes: 18 kernel calls, 6 that
+    score a slot's live index pages where they lie, 6 masked passes of the
+    paged-attention kernel over its live K and V pages under the
+    selection's mask, and 6 over the hit experts; the selection is a mask
+    by bisection (no sort) and no chosen row leaves the pools (no row
+    gather); every op of the read carries its scope, and of them only the
+    scoring kernel is named for it (the benchmark's reader counts a call
+    by that name)."""
     lm, params, pool, i32, compiled, kernelsLowered = keye
     perSeq = KEYE_CAP // KEYE_PAGE
     pages = 1 + KEYE_SLOTS * perSeq
@@ -830,26 +860,101 @@ def test_keye_decode_step_fits_and_scores_its_index_rows_in_place(keye):
     _assert_one_step_program(compiled, pool)
     assert not _whole_array_copies(compiled, pool)
     text = compiled.as_text()
-    assert kernelsLowered == (6, 1)
+    # 34,816 positions are 17 x topk: under the crossover, all six in place
+    assert kernelsLowered == (6, 1, 6)
     kernels = re.findall(
         r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
         r"custom_call_target=\"tpu_custom_call\"", text, re.M)
     assert sorted(kernels) == ["moe_share_step"] * 6 \
+        + ["paged_selected_attention"] * 6 \
         + ["paged_sparse_attention_index"] * 6, kernels
-    # no slot's capacity of index rows, keys or values is gathered: what
-    # leaves the pools is the 2,048 chosen rows a slot
+    # no slot's capacity of index rows, keys or values is gathered, and
+    # neither are the 2,048 chosen rows a slot: nothing leaves the pools
     for lanes in (128, 512):
         assert f"bf16[{KEYE_SLOTS},{KEYE_CAP},{lanes}]" not in text
         assert f"bf16[{KEYE_SLOTS},{perSeq},{KEYE_PAGE},{lanes}]" not in text
-    assert f"bf16[{KEYE_SLOTS},2048,512]" in text
+    assert f"bf16[{KEYE_SLOTS},2048,512]" not in text
     # the read's instructions say so in their metadata: the benchmark's
-    # driver tells them from the rest of the step's by it
+    # driver tells them from the rest of the step's by it, and counts the
+    # read's calls by the instructions NAMED for the scope
     scoped = re.findall(
         r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .* ([\w\-]+)\(.*op_name=\"jit\(step\)"
         r"/paged_sparse_attention/", text, re.M)
-    assert sum(1 for _n, op in scoped if op == "sort") == 6
+    assert not any(op == "sort" for _n, op in scoped)
     assert sum(1 for n, _op in scoped
-               if n.startswith("paged_sparse_attention_index")) == 6
+               if n.startswith("paged_sparse_attention")) == 6
+    assert sum(1 for n, _op in scoped
+               if n.startswith("paged_selected_attention")) == 6
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("capacity", [KEYE_CAP, 262144])
+def test_sparse_read_is_in_place_under_the_crossover_on_one_tpu(
+        topo, one_chip, capacity, chips):
+    """Which form reads the chosen rows is decided where the program is
+    lowered, from static shapes: for one TPU the masked pass over the live
+    pages while a slot's capacity x a row's bytes is under
+    ``_IN_PLACE_BYTES_A_PICK`` a chosen row (the cell's 34,816 positions of
+    1 KB = 17 x ``topk``), the sort and the row gather beyond (the
+    published 262,144 = 128 x); for four devices the gathered reference,
+    and neither counter moves."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.conf import attention as A
+    S, perSeq = 2, capacity // KEYE_PAGE
+    rows, lanes = _rows_and_lanes(topo, one_chip, chips)
+
+    def sds(shape, dtype, sharding=rows):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    pool = sds((1, 1 + S * perSeq, KEYE_PAGE, 512), bf16, lanes)
+    before = A.paged_kernel_lowerings(), A.sparse_in_place_lowerings()
+    text = jax.jit(lambda *a: A.paged_sparse_attention(
+        *a[:9], 0, *a[9:], topk=2048)).lower(
+        sds((S, 32, 1, 128), bf16), sds((S, 4, 1, 128), bf16),
+        sds((S, 4, 1, 128), bf16), sds((S, 16, 64), f32), sds((S, 16), f32),
+        sds((S, 64), bf16), pool, pool,
+        sds((1, 1 + S * perSeq, KEYE_PAGE, 128), bf16, lanes),
+        sds((S, perSeq), i32), sds((S,), i32), sds((S,), i32)).as_text()
+    kernel = A.paged_kernel_lowerings() - before[0]
+    inPlace = A.sparse_in_place_lowerings() - before[1]
+    assert (kernel, inPlace) == (
+        (0, 0) if chips == 4 else (1, 1) if capacity == KEYE_CAP else (1, 0))
+    assert text.count("tpu_custom_call") == kernel + inPlace
+    assert ("paged_selected_attention" in text) == bool(inPlace)
+    assert ("chlo.top_k" in text or "stablehlo.sort" in text) \
+        == (not inPlace)
+
+
+STEPS_OF_THE_UNMASKED_KERNEL = {
+    "gpt2_xl": ("paged_step", 0), "phi4_mini_flash": ("sambay_step", 0),
+    "olmo_hybrid_7b": ("olmo", 4), "jamba2_3b": ("jamba", 5)}
+
+
+@pytest.mark.parametrize("config", sorted(STEPS_OF_THE_UNMASKED_KERNEL))
+def test_the_other_steps_call_the_unmasked_kernel_at_128_rows_a_place(
+        request, config):
+    """The four configurations whose decode step reads K and V through
+    ``paged_attention``'s kernel: every kernel of theirs that attends is
+    the UNMASKED one under its old name, and a place of its grid holds as
+    many pages of K (and of V) as make ``_CHUNK_ROWS`` = 128 rows.  The
+    masked variant and its rows-a-place rule are the sparse read's alone."""
+    fixture, at = STEPS_OF_THE_UNMASKED_KERNEL[config]
+    text = request.getfixturevalue(fixture)[at].as_text()
+    assert "paged_selected_attention" not in text
+    calls = re.findall(
+        r"^\s*%?paged_attention[\w.\-]* = \S+ custom-call\((.*?)\), "
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert calls
+    # an operand is printed by name: what it is stands where it is made
+    made = dict(re.findall(r"^\s*(?:ROOT\s+)?(%[\w.\-]+) = (\S+) ", text,
+                           re.M))
+    for operands in calls:
+        pools = [m.group(1) for name in re.findall(r"%[\w.\-]+", operands)
+                 for m in [re.match(r"(?:bf16|f32)\[\d+,\d+,(\d+),\d+\]",
+                                    made[name])] if m]
+        assert len(set(pools)) == 1 and len(pools) % 2 == 0, operands
+        assert len(pools) // 2 * int(pools[0]) == 128, operands
 
 
 def test_keye_prefill_selects_and_attends_in_kernels_and_fits_beside_the_step(
