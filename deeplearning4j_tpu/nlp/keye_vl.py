@@ -29,8 +29,11 @@ The prefill computes the same selection for every query of a bucket
 (:func:`sparse_attend_full`): on one TPU a kernel scores a block of
 queries against every key and finds each query's ``topk``-th largest
 score by bisection on the scores' bit patterns (the same set as a sort
-gives, without one), and a flash kernel attends under that mask; on the
-CPU or several devices the same blocks in ``jax.numpy``.
+gives, without one), and a flash kernel attends under that mask; both
+read the sequence's first real position, and a tile of keys or of queries
+that lies wholly before it, all left pads, is neither scored nor
+attended: a prompt costs what its real tokens cost, not its bucket.  On
+the CPU or several devices the same blocks in ``jax.numpy``.
 
 *Rotary positions* pair lane ``i`` with lane ``i + D / 2``
 (``served._rope``); a token's position is its index among the REAL
@@ -89,6 +92,7 @@ _I32 = jnp.int32
 _NEG = -1e30
 _ROUTING = ("moe_pairs_routed", "moe_pairs_absent", "moe_experts_hit")
 _SELECTOR = ("sparse_rows_scored", "sparse_rows_selected")
+_TILES = ("sparse_prefill_tiles_causal", "sparse_prefill_tiles_visited")
 #: a prefill's two counts of the selector are quadratic in the prompt
 #: (5.4e8 pairs scored a layer at 32,768), and sixteen admissions can
 #: wait for one step: each rides as two int32 columns, the count's high
@@ -176,16 +180,19 @@ def _sparse_full_blocked(q, k, v, qI, wI, kI, start, *, topk):
 def _select_kernel(start_ref, q_ref, w_ref, k_ref, o_ref, key_ref, *, Bq,
                    Bk, hI, topk):
     """One block of ``Bq`` queries of one sequence: their index scores
-    against every key up to the block's last query, ``Bk`` keys a chunk
-    (``q_ref (hI Bq, dI)`` float32, head-major: row ``j Bq + t``; ``k_ref
-    (T, dI)`` the stored keys), kept as ordered int32 keys in ``key_ref
+    against every key from the chunk that holds the sequence's first real
+    position up to the block's last query, ``Bk`` keys a chunk (``q_ref
+    (hI Bq, dI)`` float32, head-major: row ``j Bq + t``; ``k_ref (T,
+    dI)`` the stored keys), kept as ordered int32 keys in ``key_ref
     (chunks, Bq, Bk)``; each query's ``topk``-th largest by bisection;
     the selection written as int8 into ``o_ref (chunks, Bq, Bk)``.  The
-    chunks behind the block's last query are not touched."""
+    chunks of pads alone, those behind the block's last query and a block
+    of pad queries alone are not touched."""
     b, i = pl.program_id(0), pl.program_id(1)
     s0 = start_ref[b]
     q0 = i * _I32(Bq)
-    live = jax.lax.div(q0 + _I32(Bq - 1), _I32(Bk)) + _I32(1)   # chunks
+    first = jax.lax.div(s0, _I32(Bk))                           # chunks
+    live = jax.lax.div(q0 + _I32(Bq - 1), _I32(Bk)) + _I32(1)
     qpos = q0 + jax.lax.broadcasted_iota(_I32, (Bq, Bk), 0)
     lane = jax.lax.broadcasted_iota(_I32, (Bq, Bk), 1)
     zero = jnp.zeros((Bq, 1), _F32)      # counts ride as float32: exact
@@ -202,39 +209,42 @@ def _select_kernel(start_ref, q_ref, w_ref, k_ref, o_ref, key_ref, *, Bq,
         key_ref[c] = jnp.where((kpos >= s0) & (kpos <= qpos),
                                _order_key(acc), _I32(_INT_MIN))
         return carry
-    jax.lax.fori_loop(_I32(0), live, score, _I32(0))
 
     def count(pred):
         """How many keys of a query satisfy ``pred(key chunk)``."""
         return jax.lax.fori_loop(
-            _I32(0), live,
+            first, live,
             lambda c, n: n + jnp.sum(pred(key_ref[c]).astype(_F32),
                                      axis=1, keepdims=True), zero)
-    k = _F32(topk)
-    th = jnp.where(count(lambda x: x >= _I32(0)) >= k, _I32(0),
-                   _I32(_INT_MIN))
 
-    def bit(n, th):
-        cand = th + jnp.left_shift(_I32(1), _I32(30) - n)
-        return jnp.where(count(lambda x: x >= cand) >= k, cand, th)
-    th = jax.lax.fori_loop(_I32(0), _I32(31), bit, th)
-    need = k - count(lambda x: x > th)
-    held = th > _I32(_INT_MIN)
-    # a chunk's ties counted up to each lane: a 0/1 matmul on the MXU
-    upto = (jax.lax.broadcasted_iota(_I32, (Bk, Bk), 0)
-            <= jax.lax.broadcasted_iota(_I32, (Bk, Bk), 1)
-            ).astype(jnp.bfloat16)
+    @pl.when(q0 + _I32(Bq - 1) >= s0)
+    def _():
+        jax.lax.fori_loop(first, live, score, _I32(0))
+        k = _F32(topk)
+        th = jnp.where(count(lambda x: x >= _I32(0)) >= k, _I32(0),
+                       _I32(_INT_MIN))
 
-    def write(c, seen):
-        key = key_ref[c]
-        tie = (key == th) & held
-        rank = seen + jax.lax.dot_general(
-            tie.astype(jnp.bfloat16), upto, (((1,), (0,)), ((), ())),
-            preferred_element_type=_F32)
-        # jaxlint: disable=tracer-escape -- a Pallas ref, as above: the kernel's output block
-        o_ref[c] = ((key > th) | (tie & (rank <= need))).astype(jnp.int8)
-        return seen + jnp.sum(tie.astype(_F32), axis=1, keepdims=True)
-    jax.lax.fori_loop(_I32(0), live, write, zero)
+        def bit(n, th):
+            cand = th + jnp.left_shift(_I32(1), _I32(30) - n)
+            return jnp.where(count(lambda x: x >= cand) >= k, cand, th)
+        th = jax.lax.fori_loop(_I32(0), _I32(31), bit, th)
+        need = k - count(lambda x: x > th)
+        held = th > _I32(_INT_MIN)
+        # a chunk's ties counted up to each lane: a 0/1 matmul on the MXU
+        upto = (jax.lax.broadcasted_iota(_I32, (Bk, Bk), 0)
+                <= jax.lax.broadcasted_iota(_I32, (Bk, Bk), 1)
+                ).astype(jnp.bfloat16)
+
+        def write(c, seen):
+            key = key_ref[c]
+            tie = (key == th) & held
+            rank = seen + jax.lax.dot_general(
+                tie.astype(jnp.bfloat16), upto, (((1,), (0,)), ((), ())),
+                preferred_element_type=_F32)
+            # jaxlint: disable=tracer-escape -- a Pallas ref, as above: the kernel's output block
+            o_ref[c] = ((key > th) | (tie & (rank <= need))).astype(jnp.int8)
+            return seen + jnp.sum(tie.astype(_F32), axis=1, keepdims=True)
+        jax.lax.fori_loop(first, live, write, zero)
 
 
 @functools.partial(jax.jit, static_argnames=("topk", "interpret"))
@@ -273,22 +283,62 @@ def _select_call(start, qI, wI, kI, *, topk, interpret):
     )(start.astype(_I32), qh, wI.reshape(b, nQ, Bq, hI), kI)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref,
-                  acc_ref, *, Bq, Bk, r, scale):
-    """One (query block, key block) of one KV head: the ``r`` query heads
-    of the group ride as ``r Bq`` rows (head-major), all under the one
-    ``(Bq, Bk)`` tile of the selection; softmax online across the key
-    blocks up to the diagonal, in float32."""
-    i, c = pl.program_id(2), pl.program_id(3)
-    last = jax.lax.div(i * _I32(Bq) + _I32(Bq - 1), _I32(Bk))
+def _causal_tiles(T: int) -> int:
+    """The ``(_QUERY_BLOCK, _KEY_BLOCK)`` tiles at or under the diagonal
+    of ``T`` positions."""
+    return sum((min(q0 + _QUERY_BLOCK, T) - 1) // _KEY_BLOCK + 1
+               for q0 in range(0, T, _QUERY_BLOCK))
 
-    @pl.when(c == 0)
+
+@functools.partial(jax.jit, static_argnames=("T",))
+def _live_tiles(start, *, T):
+    """The flash kernel's grid steps for sequences of ``T`` positions
+    whose first real ones are ``start (b,)``: query block after
+    query block, each with its key blocks from the one that holds
+    ``start`` up to its diagonal; a block of pad queries alone keeps one
+    step, which runs no tile.  ``(tile, flag)``, each ``(b * steps,)``
+    int32 with room for every tile at or under the diagonal: ``tile`` is
+    ``query block * key blocks + key block``; ``flag`` adds 1 where the
+    step opens its query block, 2 where it closes it and 4 where it runs
+    its tile.  The steps past the live ones repeat the last one's tile
+    under flag 0: they fetch nothing.  A few small integer ops, the same
+    for every layer: a jit of its own, as ``_work_list``."""
+    Bq, Bk = _QUERY_BLOCK, _KEY_BLOCK
+    qEnd = jnp.arange(Bq - 1, T, Bq, dtype=_I32)[None, :]    # (1, nQ)
+    last = qEnd // Bk
+    s0 = start[:, None]
+    real = qEnd >= s0                                        # (b, nQ)
+    lo = jnp.where(real, s0 // Bk, last)
+    ends = jnp.cumsum(last - lo + 1, axis=1, dtype=_I32)
+    at = jnp.arange(_causal_tiles(T), dtype=_I32)[None, :]   # (1, steps)
+    i = jnp.minimum(jnp.sum(at[:, :, None] >= ends[:, None, :], axis=2,
+                            dtype=_I32), T // Bq - 1)
+    of = lambda a: jnp.take_along_axis(jnp.broadcast_to(a, real.shape), i,
+                                       axis=1)
+    c = jnp.minimum(of(lo) + at - of(ends - (last - lo + 1)), of(last))
+    flag = jnp.where(at < ends[:, -1:], (c == of(lo)) * 1
+                     + (c == of(last)) * 2 + of(real) * 4, 0)
+    return ((i * (T // Bk) + c).reshape(-1).astype(_I32),
+            flag.reshape(-1).astype(_I32))
+
+
+def _flash_kernel(tile_ref, flag_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
+                  m_ref, l_ref, acc_ref, *, steps, r, scale):
+    """One step of :func:`_live_tiles`, a (query block, key block) of one
+    KV head: the ``r`` query heads of the group ride as ``r Bq`` rows
+    (head-major), all under the one ``(Bq, Bk)`` tile of the selection;
+    softmax online across a query block's key blocks, in float32.  A
+    block of pad queries alone runs no tile and writes zeros."""
+    flag = flag_ref[pl.program_id(0) * _I32(steps) + pl.program_id(2)]
+    has = lambda bit: jnp.bitwise_and(flag, _I32(bit)) != _I32(0)
+
+    @pl.when(has(1))
     def _():
         m_ref[...] = jnp.full(m_ref.shape, _NEG, _F32)
         l_ref[...] = jnp.zeros(l_ref.shape, _F32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
 
-    @pl.when(c <= last)
+    @pl.when(has(4))
     def _():
         s = jax.lax.dot_general(
             q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
@@ -306,7 +356,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref,
             p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=_F32)
 
-    @pl.when(c == last)
+    @pl.when(has(2))
     def _():
         # a pad query reads nothing: zeros, not 0 / 0
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], _F32(1e-30))
@@ -314,45 +364,52 @@ def _flash_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _flash_call(q, k, v, keep, *, interpret):
+def _flash_call(start, q, k, v, keep, *, interpret):
     """Attention of ``q (b, T, H, dh)`` over ``k, v (b, T, G, dh)`` under
-    ``keep`` (:func:`_select_call`'s tiles): ``(b, T, H dh)``."""
+    ``keep`` (:func:`_select_call`'s tiles) for sequences whose first
+    real position is ``start (b,)``: ``(b, T, H dh)``.  The grid is
+    :func:`_live_tiles`'s steps a KV head: no step behind the diagonal,
+    none before ``start``."""
     b, T, H, dh = q.shape
     G = k.shape[2]
     r = H // G
     Bq, Bk = _QUERY_BLOCK, _KEY_BLOCK
     nQ, nK = T // Bq, T // Bk
+    steps = _causal_tiles(T)
     # (b, G, nQ, r * Bq, dh): a group's heads as rows of one block
     q5 = q.reshape(b, nQ, Bq, G, r, dh).transpose(0, 3, 1, 4, 2, 5).reshape(
         b, G, nQ, r * Bq, dh)
     kv = lambda a: a.transpose(0, 2, 1, 3)                   # (b, G, T, dh)
-
-    def upto(i, c):     # the key block, held at the diagonal behind it
-        return jnp.minimum(c, jax.lax.div(i * Bq + (Bq - 1), _I32(Bk)))
+    block = lambda b, n, tile: jax.lax.div(tile[b * steps + n], _I32(nK))
+    chunk = lambda b, n, tile: jax.lax.rem(tile[b * steps + n], _I32(nK))
     rows = pl.BlockSpec((None, None, None, r * Bq, dh),
-                        lambda b, g, i, c: (b, g, i, c * 0, c * 0))
+                        lambda b, g, n, tile, _: (b, g, block(b, n, tile),
+                                                  n * 0, n * 0))
     keys = pl.BlockSpec((None, None, Bk, dh),
-                        lambda b, g, i, c: (b, g, upto(i, c), c * 0))
+                        lambda b, g, n, tile, _: (b, g, chunk(b, n, tile),
+                                                  n * 0))
     o = pl.pallas_call(
-        functools.partial(_flash_kernel, Bq=Bq, Bk=Bk, r=r,
+        functools.partial(_flash_kernel, steps=steps, r=r,
                           scale=dh ** -0.5),
-        grid=(b, G, nQ, nK),
-        in_specs=[rows, keys, keys,
-                  pl.BlockSpec((None, None, None, Bq, Bk),
-                               lambda b, g, i, c: (b, i, upto(i, c), c * 0,
-                                                   c * 0))],
-        out_specs=rows,
-        scratch_shapes=[pltpu.VMEM((r * Bq, 1), _F32),
-                        pltpu.VMEM((r * Bq, 1), _F32),
-                        pltpu.VMEM((r * Bq, dh), _F32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, G, steps),
+            in_specs=[rows, keys, keys,
+                      pl.BlockSpec((None, None, None, Bq, Bk),
+                                   lambda b, g, n, tile, _: (
+                                       b, block(b, n, tile),
+                                       chunk(b, n, tile), n * 0, n * 0))],
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((r * Bq, 1), _F32),
+                            pltpu.VMEM((r * Bq, 1), _F32),
+                            pltpu.VMEM((r * Bq, dh), _F32)]),
         out_shape=jax.ShapeDtypeStruct((b, G, nQ, r * Bq, dh), v.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=64 << 20),
         name="sparse_prefill_attention",
         interpret=interpret,
-    )(q5, kv(k), kv(v), keep)
+    )(*_live_tiles(start.astype(_I32), T=T), q5, kv(k), kv(v), keep)
     return o.reshape(b, G, nQ, r, Bq, dh).transpose(0, 2, 4, 1, 3, 5).reshape(
         b, T, H * dh)
 
@@ -360,10 +417,11 @@ def _flash_call(q, k, v, keep, *, interpret):
 def _sparse_full_kernels(q, k, v, qI, wI, kI, start, *, topk,
                          interpret=False):
     """:func:`sparse_attend_full` as two Pallas TPU kernels: the
-    selection's tiles, then flash attention under them.  ``interpret`` is
-    for tests (the CPU)."""
+    selection's tiles, then flash attention under them; both read
+    ``start`` and visit only the tiles that hold a real key under a real
+    query.  ``interpret`` is for tests (the CPU)."""
     keep = _select_call(start, qI, wI, kI, topk=topk, interpret=interpret)
-    return _flash_call(q, k, v, keep, interpret=interpret)
+    return _flash_call(start, q, k, v, keep, interpret=interpret)
 
 
 def _sparse_full_lowering(ctx, *args, topk):
@@ -399,8 +457,10 @@ def sparse_attend_full(q, k, v, qI, wI, kI, start, *, topk: int):
     hI)`` float32, ``kI (b, T, dI)`` the index keys as stored, ``start
     (b,)`` the first real position.  Returns ``(b, T, H dh)`` in ``v``'s
     dtype; a pad query's row is not meaningful.  Lowered for one TPU, at
-    lengths the tiles divide, as the two kernels; elsewhere in
-    ``jax.numpy`` (no knob: the rule of ``paged_attention``)."""
+    lengths the tiles divide, as the two kernels, in which the key blocks
+    and the query blocks wholly before ``start`` are neither scored nor
+    attended (a real row does not depend on what lies there); elsewhere
+    in ``jax.numpy`` (no knob: the rule of ``paged_attention``)."""
     return _sparse_full_p.bind(q, k, v, qI.astype(_F32), wI.astype(_F32),
                                kI, start.astype(_I32), topk=int(topk))
 
@@ -416,12 +476,14 @@ class KeyeVLLM:
     #: for the batcher to add to ``serving_metrics()``: its own counts of
     #: the routing and of the selector, then those of the prefills since
     #: the step before (the selector's in two columns each, the first
-    #: worth :data:`_COUNT_UNIT`)
+    #: worth :data:`_COUNT_UNIT`, then the two counts of the prefill
+    #: kernels' tiles)
     stepCounters = tuple(
         [(name, {"phase": "step"}) for name in _ROUTING + _SELECTOR]
         + [(name, {"phase": "prefill"}) for name in _ROUTING]
         + [(name, {"phase": "prefill"}, unit) for name in _SELECTOR
-           for unit in (_COUNT_UNIT, 1)])
+           for unit in (_COUNT_UNIT, 1)]
+        + [(name, {"phase": "prefill"}) for name in _TILES])
 
     def __init__(self, config: Optional[KeyeVLConfig] = None, params=None,
                  **kw):
@@ -488,8 +550,8 @@ class KeyeVLLM:
         return CacheSpec(
             pagedLayers=c.nLayers, kvHeads=c.nKvHeads, headSize=c.headSize,
             dtype=jnp.dtype(c.dtype), indexWidth=c.indexSize,
-            slotState=(("counts", (1, len(_ROUTING) + 2 * len(_SELECTOR)),
-                        _I32),))
+            slotState=(("counts", (1, len(_ROUTING) + 2 * len(_SELECTOR)
+                                   + len(_TILES)), _I32),))
 
     # -- pieces shared by the full-sequence and the step forms ----------
     def _qkv(self, lp, h, p):
@@ -557,6 +619,22 @@ class KeyeVLLM:
                               jnp.sum(count % _COUNT_UNIT))]
         return jnp.stack(parts).astype(_I32)
 
+    def _prefill_tile_counts(self, start, T: int):
+        """``[causal, visited]`` int32 over the layers for prefills of a
+        bucket of ``T`` whose first real positions are ``start (b,)``:
+        the ``(_QUERY_BLOCK, _KEY_BLOCK)`` tiles at or under the bucket's
+        diagonal, and those of them that hold a real key under a real
+        query, which are the ones :func:`sparse_attend_full`'s kernels
+        visit."""
+        q0 = jnp.arange(0, T, _QUERY_BLOCK, dtype=_I32)[None, :]
+        qEnd = jnp.minimum(q0 + _QUERY_BLOCK, T) - 1         # (1, blocks)
+        s0 = start[:, None]
+        visited = jnp.where(qEnd >= s0,
+                            qEnd // _KEY_BLOCK - s0 // _KEY_BLOCK + 1, 0)
+        return self.config.nLayers * jnp.stack(
+            [start.shape[0] * _causal_tiles(T), jnp.sum(visited)]
+        ).astype(_I32)
+
     # ------------------------------------------------------------------
     # full-sequence form: forward and prefill
     # ------------------------------------------------------------------
@@ -565,8 +643,8 @@ class KeyeVLLM:
         position.  Returns the last layer's output, every layer's rows as
         the step will read them (K and V ``(L, b, G, T, dh)``, index rows
         ``(L, b, 1, T, W)``) and the counts over the real tokens
-        ``(7,)`` (the routing's three, the selector's two in two parts
-        each)."""
+        ``(9,)`` (the routing's three, the selector's two in two parts
+        each, the two of the kernels' tiles)."""
         c = self.config
         b, T = tokens.shape
         at = jnp.arange(T, dtype=_I32)[None, :]
@@ -598,7 +676,8 @@ class KeyeVLLM:
             routed = routed + n
             x = hold(y + ff.reshape(b, T, -1).astype(cd))
         return x, (kS, vS, iS), jnp.concatenate(
-            [routed, self._prefill_selector_counts((T - start).astype(_I32))])
+            [routed, self._prefill_selector_counts((T - start).astype(_I32)),
+             self._prefill_tile_counts(start, T)])
 
     @functools.cached_property
     def _fwd(self):
@@ -617,7 +696,7 @@ class KeyeVLLM:
         def run(params, tokens, start):
             x, rows, counts = self._run_full(params, tokens, start)
             b = tokens.shape[0]
-            # the counts ride as slot state: (1 layer, b, 7), the batch
+            # the counts ride as slot state: (1 layer, b, 9), the batch
             # row's own where there is one row (the scheduler's case)
             return (self._logits(params, x[:, -1]), *rows,
                     jnp.broadcast_to(counts, (1, b) + counts.shape))
@@ -626,7 +705,7 @@ class KeyeVLLM:
     def prefillRaw(self, tokens, lengths=None):
         """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab), kStack,
         vStack, indexStack, counts)``: the rows in
-        :func:`paged_rows_write`'s form and the counts ``(1, b, 7)`` in
+        :func:`paged_rows_write`'s form and the counts ``(1, b, 9)`` in
         the pool's order (the whole batch's in every row: the scheduler
         prefills one sequence at a time).  One executable per prompt
         bucket."""
@@ -648,10 +727,10 @@ class KeyeVLLM:
                     pageTable, pos, start):
         """One token per slot (``toks (S, 1)``) against the pool's arrays:
         ``((S, 1, vocab) logits, poolK, poolV, poolI, counts, counted
-        (12,))``.  A slot whose ``pos`` is 0 holds no sequence (or is
+        (14,))``.  A slot whose ``pos`` is 0 holds no sequence (or is
         deferred a round): its rows land on the scratch page through its
         zeroed page table and it is not counted.  ``counted`` are this
-        step's five counts, then the seven columns that the prefills
+        step's five counts, then the nine columns that the prefills
         since the last step left in ``counts``, which comes back
         zeroed."""
         c = self.config
@@ -689,7 +768,7 @@ class KeyeVLLM:
     def buildPagedDecodeFn(self):
         """FRESH jitted decode step over the pool's arrays: ``(params,
         poolK, poolV, poolI, counts, toks (S, 1), prev, pageTable, pos,
-        start) -> (out (S, 1 + 12), poolK, poolV, poolI, counts)``.
+        start) -> (out (S, 1 + 14), poolK, poolV, poolI, counts)``.
         Column 0 of ``out`` is the greedy token a slot; the columns
         behind it hold, in row 0, the counts :data:`stepCounters` names.
         ``prev`` is the step before's ``out`` (its first column is read),

@@ -716,6 +716,28 @@ class ServingMetrics:
             "scored is the share of the live rows a query reads",
             labelnames=("model", "phase"))
 
+    # the tiles of a sparse prefill's two kernels (nlp/keye_vl.py
+    # sparse_attend_full): computed from the bucket and the prompt's first
+    # real position beside the prefill, and carried as the counts above
+    def sparse_prefill_tiles_causal(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_sparse_prefill_tiles_causal_total",
+            "(query block, key block) tiles at or under the diagonal of "
+            "the prompt buckets prefilled, summed over the layers: what "
+            "selection and attention would visit if every position of a "
+            "bucket were real",
+            labelnames=("model", "phase"))
+
+    def sparse_prefill_tiles_visited(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_sparse_prefill_tiles_visited_total",
+            "Those of the causal tiles that hold a real key under a real "
+            "query, which the prefill's kernels score and attend (the "
+            "left pads' tiles are skipped); visited / causal is the share "
+            "of a bucket's quadratic work its prompts need, 1 for a full "
+            "bucket",
+            labelnames=("model", "phase"))
+
     def loop_phase_seconds(self):
         return get_registry().histogram(
             "dl4j_tpu_serving_loop_phase_seconds",

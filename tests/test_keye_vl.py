@@ -113,7 +113,7 @@ def test_cache_spec_names_index_rows_and_the_pool_holds_a_third_array(
     pool = KVCachePool.forSpec(spec, PAGE, 9, SLOTS, 4)
     assert [(a.shape, a.dtype) for a in pool.arrays] == [
         ((2, 9, PAGE, 32), jnp.bfloat16), ((2, 9, PAGE, 32), jnp.bfloat16),
-        ((2, 9, PAGE, 128), jnp.bfloat16), ((1, SLOTS, 7), jnp.int32)]
+        ((2, 9, PAGE, 128), jnp.bfloat16), ((1, SLOTS, 9), jnp.int32)]
     assert pool.pageBytes == 2 * PAGE * 2 * 32 * 2
     assert pool.indexPageBytes == 2 * PAGE * 128 * 2
     assert CacheSpec(6, 4, 128, indexWidth=64).indexRowWidth == 128
@@ -278,47 +278,73 @@ def test_selection_mask_is_what_a_stable_sort_takes():
         np.testing.assert_array_equal(got[r], want & valid[r], err_msg=str(r))
 
 
+@pytest.mark.parametrize("T,starts", [(1024, (0, 600)),
+                                      (2048, (0, 600, 1337, 1920))])
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
-def test_prefill_kernels_are_the_blocked_form(dtype, tol):
+def test_prefill_kernels_are_the_blocked_form(dtype, tol, T, starts):
     """``sparse_attend_full``'s two kernels (interpreted: the selection's
     tiles by bisection, flash attention under them) against its
-    ``jax.numpy`` form at 1,024 positions: two sequences, one left-padded
-    past a key block, 64 rows a query, tied index scores."""
+    ``jax.numpy`` form: sequences left-padded past none, one or several
+    key blocks and query blocks, 64 rows a query, tied index scores.  The
+    kernels visit no tile that lies wholly before ``start``: what the
+    pads' rows hold moves no bit of a real row."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nlp import keye_vl as M
     rs = np.random.RandomState(1)
-    b, T, H, G, dh, hI, dI, topk = 2, 1024, 4, 2, 16, 2, 8, 64
+    b, H, G, dh, hI, dI, topk = len(starts), 4, 2, 16, 2, 8, 64
     dt = jnp.dtype(dtype)
     rnd = lambda *shape: rs.standard_normal(shape).astype(np.float32)
-    q, k, v = (jnp.asarray(rnd(b, T, n, dh), dt) for n in (H, G, G))
-    kI = rnd(b, T, dI)
+    q, k, v = (rnd(b, T, n, dh) for n in (H, G, G))
+    kI, qI, wI = rnd(b, T, dI), rnd(b, T, hI, dI), rnd(b, T, hI)
     kI[:, 700:720] = kI[:, 3:4]                      # ties across blocks
-    qI, wI = jnp.asarray(rnd(b, T, hI, dI)), jnp.asarray(rnd(b, T, hI))
-    kI = jnp.asarray(kI, dt)
-    start = jnp.asarray([0, 600], jnp.int32)
-    want = np.asarray(M._sparse_full_blocked(
-        q, k, v, qI, wI, kI, start, topk=topk).astype(jnp.float32))
-    got = np.asarray(M._sparse_full_kernels(
-        q, k, v, qI, wI, kI, start, topk=topk, interpret=True
-    ).astype(jnp.float32))
-    real = np.arange(T)[None, :] >= np.asarray(start)[:, None]
+    kI[:, T - 100:T - 80] = kI[:, T - 120:T - 119]   # and behind any start
+    start = jnp.asarray(starts, jnp.int32)
+    real = np.arange(T)[None, :] >= np.asarray(starts)[:, None]
+    floats = (jnp.float32,) * 2
+
+    def run(fn, arrays, **kw):
+        cast = (jnp.asarray(a, d) for a, d in zip(
+            arrays, (dt, dt, dt) + floats + (dt,)))
+        return np.asarray(fn(*cast, start, topk=topk, **kw
+                             ).astype(jnp.float32))
+    arrays = (q, k, v, qI, wI, kI)
+    want = run(M._sparse_full_blocked, arrays)
+    got = run(M._sparse_full_kernels, arrays, interpret=True)
     np.testing.assert_allclose(got[real], want[real], rtol=tol, atol=tol)
     assert np.isfinite(got).all()
-    # the tiles themselves: what the blocked form selects
+    # other pads, large ones, and NaN in the key blocks that lie wholly
+    # before ``start`` (a block that is attended, even under a mask of
+    # zeros, adds 0 x NaN): the same real rows to the bit, pads finite
+    Bq, Bk = M._QUERY_BLOCK, M._KEY_BLOCK
+    lost = np.arange(T)[None, :] < np.asarray(starts)[:, None] // Bk * Bk
+    wide = lambda m, a: m.reshape(m.shape + (1,) * (a.ndim - 2))
+    other = tuple(np.where(wide(real, a), a, np.where(
+        wide(lost, a), np.nan, 1e3 * rnd(*a.shape))) for a in arrays)
+    again = run(M._sparse_full_kernels, other, interpret=True)
+    np.testing.assert_array_equal(again[real], got[real])
+    assert np.isfinite(again).all()
+    # the tiles themselves: what the blocked form selects, wherever a real
+    # key lies under a real query (the others are not written, nor read)
+    qI, wI, kI = jnp.asarray(qI), jnp.asarray(wI), jnp.asarray(kI, dt)
     keep = np.asarray(M._select_call(start, qI, wI, kI, topk=topk,
                                      interpret=True))
-    Bq, Bk = M._QUERY_BLOCK, M._KEY_BLOCK
     at = np.arange(T)
-    valid = (at[None, None, :] <= at[None, :, None]) \
-        & (at[None, None, :] >= np.asarray(start)[:, None, None])
+    valid = (at[None, None, :] <= at[None, :, None]) & real[:, None, :]
     sel = np.asarray(M._select_mask(M._index_scores(qI, wI, kI),
                                     jnp.asarray(valid), topk))
-    for i in range(T // Bq):
-        for c in range((i * Bq + Bq - 1) // Bk + 1):
-            np.testing.assert_array_equal(
-                keep[:, i, c] != 0,
-                sel[:, i * Bq:(i + 1) * Bq, c * Bk:(c + 1) * Bk])
+    visited = 0
+    for n, s0 in enumerate(starts):
+        for i in range(s0 // Bq, T // Bq):
+            for c in range(s0 // Bk, (i * Bq + Bq - 1) // Bk + 1):
+                visited += 1
+                np.testing.assert_array_equal(
+                    keep[n, i, c] != 0,
+                    sel[n, i * Bq:(i + 1) * Bq, c * Bk:(c + 1) * Bk])
     assert sel.sum(-1).max() == topk
+    lm = M.KeyeVLLM(M.KeyeVLConfig(nLayers=1), params={})
+    assert np.asarray(lm._prefill_tile_counts(start, T)).tolist() == [
+        b * sum((i * Bq + Bq - 1) // Bk + 1 for i in range(T // Bq)),
+        visited]
 
 
 # -- the expert layer: a share of the experts ----------------------------------
@@ -457,7 +483,7 @@ def test_prefill_and_paged_decode_match_the_reference_logits(
                                       dense=True))
         assert not _close(dense, want, dtype)
         assert pool.release(1) == -(-(bucket + 40) // PAGE)
-    counted = np.stack(counted)                  # (80 steps, 12)
+    counted = np.stack(counted)                  # (80 steps, 14)
     pairs = TINY["num_experts_per_tok"] * LAYERS
     assert (counted[:, 0] + counted[:, 1] == pairs).all()
     # a step scores its live rows (the new one among them) in every layer
@@ -477,6 +503,11 @@ def test_prefill_and_paged_decode_match_the_reference_logits(
     assert scored[[0, 40]].tolist() == [LAYERS * 66, LAYERS * 15]
     assert selected[[0, 40]].tolist() == [LAYERS * (21 + 5 * 6), LAYERS * 15]
     assert not scored[1:40].any() and not selected[41:].any()
+    # and the one tile a layer that a bucket under a block is, visited
+    assert [lm.stepCounters[i][0] for i in (12, 13)] == \
+        ["sparse_prefill_tiles_causal", "sparse_prefill_tiles_visited"]
+    assert counted[:, 12].tolist() == counted[:, 13].tolist() == \
+        [LAYERS] + [0] * 39 + [LAYERS] + [0] * 39
     assert not np.asarray(pool.arrays[3]).any()
     # the pages no sequence was ever given are as they were made
     for a in pool.arrays[:3]:
@@ -484,15 +515,52 @@ def test_prefill_and_paged_decode_match_the_reference_logits(
     assert pool.usedPages() == 0 and pool.stateSlots() == 0
 
 
-def test_prefill_counts_ride_in_two_columns_at_the_cell_s_lengths(family,
-                                                                  weights):
+@pytest.mark.parametrize("bucket", [8192, 16384, 32768])
+@pytest.mark.parametrize("pads", [lambda bucket: 0, lambda bucket: 37,
+                                  lambda bucket: bucket // 2 - 1],
+                         ids=["full", "less37", "half_and_one"])
+def test_prefill_counts_ride_in_two_columns_at_the_cell_s_lengths(
+        family, weights, bucket, pads):
     """Sixteen prefills of 32,768 tokens score 5.2e10 pairs in six layers:
     past an int32, so the count is kept as high and low parts that each
-    stay far inside one."""
+    stay far inside one.  The kernels' tiles, the bucket's and those a
+    prompt's real positions leave to visit, are a plain enumeration's and
+    stay inside one column with sixteen prefills behind one step."""
     import jax.numpy as jnp
-    from deeplearning4j_tpu.nlp.keye_vl import (_COUNT_UNIT, KeyeVLConfig,
+    from deeplearning4j_tpu.nlp.keye_vl import (_COUNT_UNIT, _KEY_BLOCK,
+                                                _QUERY_BLOCK, KeyeVLConfig,
                                                 KeyeVLLM)
     lm = KeyeVLLM(KeyeVLConfig(nLayers=6, topk=2048), params={})
+    start = pads(bucket)
+    tiles = [(i, c) for i in range(bucket // _QUERY_BLOCK)
+             for c in range(bucket // _KEY_BLOCK)
+             if c * _KEY_BLOCK <= i * _QUERY_BLOCK + _QUERY_BLOCK - 1]
+    real = [(i, c) for i, c in tiles
+            if (c + 1) * _KEY_BLOCK > start and (i + 1) * _QUERY_BLOCK > start]
+    got = np.asarray(lm._prefill_tile_counts(
+        jnp.asarray([start] * 16, jnp.int32), bucket))
+    assert got.dtype == np.int32
+    assert got.tolist() == [16 * 6 * len(tiles), 16 * 6 * len(real)]
+    if bucket == 32768:
+        assert len(tiles) == 8320
+    assert (len(real) == len(tiles)) == (start < _QUERY_BLOCK)
+    # the flash kernel's grid: those tiles in order and no others run; a
+    # block of pad queries keeps one step that opens and closes it (its
+    # zeros); every query block is opened once and closed once; the steps
+    # left over do nothing and stay on the last tile
+    from deeplearning4j_tpu.nlp.keye_vl import _live_tiles
+    tile, flag = (np.asarray(a) for a in _live_tiles(
+        jnp.asarray([start], jnp.int32), T=bucket))
+    nQ, nK = bucket // _QUERY_BLOCK, bucket // _KEY_BLOCK
+    assert tile.shape == flag.shape == (len(tiles),)
+    assert [divmod(t, nK) for t in tile[flag & 4 != 0]] == real
+    pads = start // _QUERY_BLOCK
+    live = len(real) + pads
+    assert (flag[:pads] == 3).all() and (flag[live:] == 0).all()
+    assert (tile[:pads] // nK).tolist() == list(range(pads))
+    assert (tile[live:] == tile[live - 1]).all()
+    for bit in (1, 2):
+        assert (tile[flag & bit != 0] // nK).tolist() == list(range(nQ))
     n = jnp.asarray([32768] * 16, jnp.int32)
     parts = np.asarray(lm._prefill_selector_counts(n)).astype(np.int64)
     assert (parts < 2 ** 26).all()
@@ -543,7 +611,8 @@ def _counted(name="keye"):
     return {(c, ph): getattr(sm, c)().value(model=name, phase=ph) or 0
             for c in ("moe_pairs_routed", "moe_pairs_absent",
                       "moe_experts_hit", "sparse_rows_scored",
-                      "sparse_rows_selected")
+                      "sparse_rows_selected", "sparse_prefill_tiles_causal",
+                      "sparse_prefill_tiles_visited")
             for ph in ("step", "prefill")}
 
 
@@ -569,12 +638,20 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts(
         time.sleep(0.05 * i)
         outs[i] = np.asarray(batcher.submit(
             {"tokens": prompts[i], "maxNewTokens": 40}))[0].tolist()
-        index_bytes.append(sm.index_rows_bytes().value(model="keye"))
+
+    def watch():
+        # read while requests are in flight: a quiet machine serves one
+        # in under the 50 ms to the next, and none then ends beside another
+        while any(t.is_alive() for t in threads):
+            index_bytes.append(sm.index_rows_bytes().value(model="keye"))
+            time.sleep(0.001)
     threads = [threading.Thread(target=go, args=(i,))
                for i in range(len(prompts))]
     for t in threads:
         t.start()
-    for t in threads:
+    watcher = threading.Thread(target=watch)
+    watcher.start()
+    for t in threads + [watcher]:
         t.join(120)
     for p, o in zip(prompts, outs):
         assert o is not None and len(o) == 40
@@ -582,7 +659,7 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts(
     pool = batcher.pool
     assert [a.shape for a in pool.arrays] == [
         (2, pool.numPages, PAGE, 32)] * 2 + [
-        (2, pool.numPages, PAGE, 128), (1, SLOTS, 7)]
+        (2, pool.numPages, PAGE, 128), (1, SLOTS, 9)]
     assert pool.usedPages() == 0 and pool.stateSlots() == 0
     assert pool.freePages() == pool.numPages - 1
     assert sm.cache_bytes().value(model="keye", kind="paged") == 0
@@ -614,6 +691,12 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts(
     k = np.minimum(n, TOPK)
     assert got["sparse_rows_selected", "prefill"] == LAYERS * sum(
         k * (k + 1) // 2 + (n - k) * k)
+    # a bucket of 8 or 16 positions lies inside one tile of the prefill's
+    # kernels, which any real token has to visit; a step has none
+    for tiles in ("sparse_prefill_tiles_causal",
+                  "sparse_prefill_tiles_visited"):
+        assert got[tiles, "prefill"] == LAYERS * len(prompts)
+        assert got[tiles, "step"] == 0
 
 
 @pytest.mark.parametrize("lowered,want", [(None, 0), (LAYERS - 1, 0),
@@ -724,6 +807,8 @@ def test_every_serving_series_covers_the_model_under_the_batcher_s_name(
                  "dl4j_tpu_serving_moe_experts_hit_total",
                  "dl4j_tpu_serving_sparse_rows_scored_total",
                  "dl4j_tpu_serving_sparse_rows_selected_total",
+                 "dl4j_tpu_serving_sparse_prefill_tiles_causal_total",
+                 "dl4j_tpu_serving_sparse_prefill_tiles_visited_total",
                  "dl4j_tpu_serving_sparse_read_in_place"):
         assert want in names, want
     assert sm.decode_steps().value(model="keye") >= 11

@@ -826,7 +826,7 @@ def keye(one_chip):
     counts = (paged_kernel_lowerings, moe_step_kernel_lowerings,
               sparse_in_place_lowerings)
     before = [c() for c in counts]
-    # ``prev`` is a step's own output: the tokens and the twelve counts
+    # ``prev`` is a step's own output: the tokens and the fourteen counts
     step = lm.buildPagedDecodeFn().lower(
         params, *pool, i32(KEYE_SLOTS, 1),
         i32(KEYE_SLOTS, 1 + len(lm.stepCounters)),
@@ -851,7 +851,7 @@ def test_keye_decode_step_fits_and_scores_its_index_rows_in_place(keye):
     # found: 10.11 GB of arguments (2.41 of weights, 6.85 of K and V rows,
     # 0.86 of index rows stored 128 lanes wide) + 0.02 of temporaries
     assert [a.shape for a in pool] == [(6, pages, KEYE_PAGE, 512)] * 2 + [
-        (6, pages, KEYE_PAGE, 128), (1, KEYE_SLOTS, 7)]
+        (6, pages, KEYE_PAGE, 128), (1, KEYE_SLOTS, 9)]
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     assert 10.0e9 < mem.argument_size_in_bytes < 10.2e9
     assert mem.temp_size_in_bytes < 0.1e9
@@ -988,7 +988,7 @@ def test_keye_prefill_selects_and_attends_in_kernels_and_fits_beside_the_step(
                            i32(1))[1:]
     assert [p.shape for p in state] == [
         (6, 1, 4, KEYE_BUCKET, 128)] * 2 + [(6, 1, 1, KEYE_BUCKET, 128),
-                                            (1, 1, 7)]
+                                            (1, 1, 9)]
     parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
                                   sharding=pool[0].sharding) for p in state]
     write = lm.buildPagedPrefillWriteFn().lower(
