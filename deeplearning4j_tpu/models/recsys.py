@@ -40,14 +40,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  drop_served_jits,
-                                                  paged_prefill_write,
-                                                  paged_step_tokens,
-                                                  served_jit_entries)
+from deeplearning4j_tpu.nn.conf.attention import CacheSpec
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import BaseLayer, register_layer
 from deeplearning4j_tpu.nn.lossfunctions import get_loss
+from deeplearning4j_tpu.nlp.served import ServedLM
 
 __all__ = ["FeatureInteractionLayer", "DotProductScorer",
            "RetrievalConfig", "RetrievalLM", "topk_retrieve"]
@@ -165,7 +162,7 @@ class RetrievalConfig:
         return self.embeddingDim
 
 
-class RetrievalLM:
+class RetrievalLM(ServedLM):
     """Top-k retrieval over an item corpus as a paged-decode "LM".
 
     ``userTable``/``itemTable`` are (vocabSize, embeddingDim) — for a
@@ -203,6 +200,8 @@ class RetrievalLM:
     # -- prefill --------------------------------------------------------
     @functools.cached_property
     def _prefillRawFn(self):
+        """(b, t) LEFT-padded user-feature ids -> (corpus scores (b,
+        vocab), kStack, vStack (1, b, 1, t, d))."""
         def run(params, tokens, start):
             b, t = tokens.shape
             d = params["user"].shape[1]
@@ -220,82 +219,42 @@ class RetrievalLM:
             return logits, kStack, vStack
         return jax.jit(run)
 
-    def prefillRaw(self, tokens, lengths=None):
-        """(b, t) LEFT-padded user-feature ids -> (corpus scores
-        (b, vocab), kStack, vStack (1, b, 1, t, d))."""
-        tokens = jnp.asarray(tokens, jnp.int32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds positional "
-                             f"capacity {self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), jnp.int32)
-        else:
-            start = t - jnp.asarray(lengths, jnp.int32)
-        return self._prefillRawFn(self.params, tokens, start)
-
     # -- decode ---------------------------------------------------------
-    def buildPagedDecodeFn(self):
-        """FRESH jitted retrieval step: ``(params, poolK, poolV,
-        toks (S, 1), prev (S, 1), pageTable, pos, start) -> (next item
-        (S, 1), poolK, poolV)`` over the token-major pools ``(1,
-        numPages, pageSize, d)`` (one layer, one head: a row is one
-        position's ``d`` channels).  ``toks`` carries each slot's
-        last-emitted item, or -1 where it is the step before's output
-        ``prev``; the step writes it into the V pool at ``pos``, masks
-        every item the pool says was already emitted, and emits the
-        next-ranked item.  Pool buffers are donated; fresh identity per
-        build for the same cache-hygiene reasons as the transformer
-        decode."""
-        def step(params, poolK, poolV, toks, prev, pageTable, pos, start):
-            toks = paged_step_tokens(toks, prev)
-            S = toks.shape[0]
-            ps = poolV.shape[2]
-            rows = jnp.arange(S)
-            # query embedding: position 0 of each slot's first page
-            u = poolK[0, pageTable[:, 0], 0, :]             # (S, d)
-            scores = u @ params["items"].T                  # (S, vocab)
-            # emitted-item history from the V pool (channel 0 over every
-            # held page position; prompt region holds -1 sentinels and
-            # unwritten positions are gated by pos)
-            hist = poolV[0, pageTable, :, 0].reshape(S, -1)
-            posidx = jnp.arange(hist.shape[1], dtype=jnp.int32)
-            emitted = jnp.where(posidx[None, :] < pos[:, None],
-                                hist.astype(jnp.int32), -1)
-            penalty = jnp.zeros_like(scores)
-            # mode="drop": the -1 invalid markers scatter out of bounds
-            penalty = penalty.at[
-                rows[:, None], emitted].set(_NEG_INF, mode="drop")
-            last = toks[:, -1]
-            penalty = penalty.at[rows, last].set(_NEG_INF)
-            nxt = jnp.argmax(scores + penalty,
-                             axis=-1).astype(jnp.int32)
-            # page in the last-emitted item at pos (inactive slots write
-            # to the scratch page through their zeroed page tables)
-            page = pageTable[rows, pos // ps]
-            poolV = poolV.at[0, page, pos % ps, 0].set(
-                last.astype(poolV.dtype))
-            return nxt[:, None], poolK, poolV
-        return jax.jit(step, donate_argnums=(1, 2))
-
-    def buildPagedPrefillWriteFn(self):
-        """FRESH jitted pool write — identical contract to the
-        transformer's: one sequence's stacked prefill K/V
-        ((1, 1, Tp, d)) into the pages ``pageIds`` of the ``(1,
-        numPages, pageSize, d)`` pools."""
-        def write(poolK, poolV, kStack, vStack, pageIds, slot=None):
-            return paged_prefill_write(poolK, poolV, kStack, vStack,
-                                       pageIds)
-        return jax.jit(write, donate_argnums=(0, 1))
-
-    def compileCacheSize(self) -> int:
-        """Jit-cache entries of the prefill (the serving tier's compile
-        hit/miss probe)."""
-        return served_jit_entries(self)
-
-    def dropCompiled(self) -> None:
-        """Forget the cached jits (pool or plan changed)."""
-        drop_served_jits(self)
+    def pagedLogits(self, params, poolK, poolV, toks, pageTable, pos,
+                    start):
+        """One retrieval rank a slot: ``toks (S, 1)``, each slot's
+        last-emitted item, against the token-major pools ``(1, numPages,
+        pageSize, d)`` (one layer, one head: a row is one position's
+        ``d`` channels) -> ``(scores (S, 1, vocab), poolK, poolV)``.  The
+        step writes the item into the V pool at ``pos`` and scores the
+        corpus with every item the pool says was already emitted masked
+        out, so that the arg-max ``ServedLM``'s step takes of it is the
+        next-ranked item."""
+        S = toks.shape[0]
+        ps = poolV.shape[2]
+        rows = jnp.arange(S)
+        # query embedding: position 0 of each slot's first page
+        u = poolK[0, pageTable[:, 0], 0, :]             # (S, d)
+        scores = u @ params["items"].T                  # (S, vocab)
+        # emitted-item history from the V pool (channel 0 over every
+        # held page position; prompt region holds -1 sentinels and
+        # unwritten positions are gated by pos)
+        hist = poolV[0, pageTable, :, 0].reshape(S, -1)
+        posidx = jnp.arange(hist.shape[1], dtype=jnp.int32)
+        emitted = jnp.where(posidx[None, :] < pos[:, None],
+                            hist.astype(jnp.int32), -1)
+        penalty = jnp.zeros_like(scores)
+        # mode="drop": the -1 invalid markers scatter out of bounds
+        penalty = penalty.at[
+            rows[:, None], emitted].set(_NEG_INF, mode="drop")
+        last = toks[:, -1]
+        penalty = penalty.at[rows, last].set(_NEG_INF)
+        # page in the last-emitted item at pos (inactive slots write
+        # to the scratch page through their zeroed page tables)
+        page = pageTable[rows, pos // ps]
+        poolV = poolV.at[0, page, pos % ps, 0].set(
+            last.astype(poolV.dtype))
+        return (scores + penalty)[:, None], poolK, poolV
 
 
 def topk_retrieve(batcher, userIds, k: int, timeout=None) -> np.ndarray:

@@ -35,14 +35,10 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  drop_served_jits,
-                                                  paged_attention,
-                                                  paged_prefill_write,
-                                                  paged_step_tokens,
-                                                  served_jit_entries)
+                                                  paged_attention)
 from deeplearning4j_tpu.nlp.mamba import _mm, _rms, mamba_full, mamba_step
-from deeplearning4j_tpu.nlp.served import (JitByLength, attend_full,
-                                           slot_state_write)
+from deeplearning4j_tpu.nlp.served import (JitByLength, ServedLM,
+                                           attend_full)
 
 __all__ = ["JambaConfig", "JambaLM"]
 
@@ -83,12 +79,11 @@ class JambaConfig:
                 else "mamba" for i in range(self.nLayers)]
 
 
-class JambaLM:
+class JambaLM(ServedLM):
     """The served model: ``forward`` (the recompute baseline), a bucketed
-    left-padded ``prefillRaw`` that also returns both kinds of cache
-    state, and the scheduler's fixed-shape decode step and admission
-    write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``, the
-    hooks the other served models have)."""
+    left-padded prefill that also returns both kinds of cache state, and
+    the step form ``pagedLogits``, from which ``ServedLM`` builds the
+    scheduler's fixed-shape decode step and admission write."""
 
     def __init__(self, config: Optional[JambaConfig] = None, params=None,
                  **kw):
@@ -212,7 +207,7 @@ class JambaLM:
                 out = _mm(attend_full(q, kR, vR, start, nHeads=c.nHeads,
                                       nKvHeads=c.nKvHeads), lp["Wo"])
             x = self._ffn(lp, x + out.astype(cd))
-        # the paged stacks in paged_prefill_write's form (L, b, h, T, d):
+        # the paged stacks in paged_rows_write's form (L, b, h, T, d):
         # one "head" as wide as a row
         return x, (jnp.stack(pagedK)[:, :, None], jnp.stack(pagedV)[:, :, None],
                    jnp.stack(ssm), jnp.stack(conv))
@@ -231,28 +226,14 @@ class JambaLM:
 
     @functools.cached_property
     def _prefillRawFn(self):
+        """``(last logits (b, vocab), kStack, vStack, ssm, conv)``: the
+        paged stacks in :func:`paged_rows_write`'s form ``(attention
+        layers, b, 1, t, KV*dh)`` and the slot state ``(Mamba layers, b,
+        ...)`` in the pool's order."""
         def run(params, tokens, start):
             x, state = self._run_full(params, tokens, start)
             return (self._logits(params, x[:, -1]),) + state
         return JitByLength(run, "prefill")
-
-    def prefillRaw(self, tokens, lengths=None):
-        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
-        kStack, vStack, ssm, conv)``: the paged stacks in
-        :func:`paged_prefill_write`'s form ``(attention layers, b, 1, t,
-        KV*dh)`` and the slot state ``(Mamba layers, b, ...)`` in the
-        pool's order.  One executable per prompt bucket, named by it
-        (``jit_prefill_<t>``)."""
-        tokens = jnp.asarray(tokens, _I32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds the capacity "
-                             f"{self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), _I32)
-        else:
-            start = t - jnp.asarray(lengths, _I32)
-        return self._prefillRawFn(self.params, tokens, start)
 
     # ------------------------------------------------------------------
     # step form — the continuous-batching scheduler's executables
@@ -295,35 +276,3 @@ class JambaLM:
                 ai += 1
             x = self._ffn(lp, x + out.astype(cd))
         return self._logits(params, x)[:, None], k, v, ssm, conv
-
-    def buildPagedDecodeFn(self):
-        """FRESH jitted decode step over the pool's arrays: ``(params, k,
-        v, ssm, conv, toks (S, 1), prev (S, 1), pageTable, pos, start) ->
-        (greedy (S, 1), k, v, ssm, conv)``.  The four arrays are DONATED,
-        a slot whose ``toks`` is -1 takes ``prev``; a fresh identity per
-        build, all as ``TransformerLM.buildPagedDecodeFn`` explains."""
-        def step(params, k, v, ssm, conv, toks, prev, pageTable, pos,
-                 start):
-            out = self.pagedLogits(params, k, v, ssm, conv,
-                                   paged_step_tokens(toks, prev), pageTable,
-                                   pos, start)
-            return (jnp.argmax(out[0], axis=-1).astype(_I32),) + out[1:]
-        return jax.jit(step, donate_argnums=(1, 2, 3, 4))
-
-    def buildPagedPrefillWriteFn(self):
-        """FRESH jitted admission write: one sequence's prefill state
-        (:meth:`prefillRaw`'s, batch row taken) into the pages
-        ``pageIds`` and into slot ``slot``'s recurrent state, which it
-        overwrites whole."""
-        def write(k, v, ssm, conv, kStack, vStack, ssmS, convS, pageIds,
-                  slot):
-            k, v = paged_prefill_write(k, v, kStack, vStack, pageIds)
-            return (k, v, slot_state_write(ssm, ssmS, slot),
-                    slot_state_write(conv, convS, slot))
-        return jax.jit(write, donate_argnums=(0, 1, 2, 3))
-
-    def compileCacheSize(self) -> int:
-        return served_jit_entries(self)
-
-    def dropCompiled(self) -> None:
-        drop_served_jits(self)

@@ -72,14 +72,10 @@ from jax.interpreters import mlir
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec, _INT_MIN,
                                                   _mxu_dot, _order_key,
                                                   _select_mask,
-                                                  drop_served_jits,
                                                   lowered_for_one_tpu,
-                                                  paged_rows_write,
-                                                  paged_sparse_attention,
-                                                  paged_step_tokens,
-                                                  served_jit_entries)
+                                                  paged_sparse_attention)
 from deeplearning4j_tpu.nlp.mamba import _mm, _rms
-from deeplearning4j_tpu.nlp.served import JitByLength, _rope
+from deeplearning4j_tpu.nlp.served import JitByLength, ServedLM, _rope
 from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
                                              moe_share_grouped,
                                              moe_share_step,
@@ -465,12 +461,12 @@ def sparse_attend_full(q, k, v, qI, wI, kI, start, *, topk: int):
                                kI, start.astype(_I32), topk=int(topk))
 
 
-class KeyeVLLM:
+class KeyeVLLM(ServedLM):
     """The served model: ``forward`` (the recompute baseline), a bucketed
-    left-padded ``prefillRaw`` that also returns the three kinds of row
-    and the counts, and the scheduler's fixed-shape decode step and
-    admission write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``,
-    the hooks the other served models have)."""
+    left-padded prefill that also returns the three kinds of row and the
+    counts, and the step form ``pagedLogits``, from which ``ServedLM``
+    builds the scheduler's fixed-shape decode step and admission
+    write."""
 
     #: what the step returns in the columns behind its tokens (row 0),
     #: for the batcher to add to ``serving_metrics()``: its own counts of
@@ -693,6 +689,10 @@ class KeyeVLLM:
 
     @functools.cached_property
     def _prefillRawFn(self):
+        """``(last logits (b, vocab), kStack, vStack, indexStack,
+        counts)``: the rows in :func:`paged_rows_write`'s form and the
+        counts ``(1, b, 9)`` in the pool's order (the whole batch's in
+        every row: the scheduler prefills one sequence at a time)."""
         def run(params, tokens, start):
             x, rows, counts = self._run_full(params, tokens, start)
             b = tokens.shape[0]
@@ -701,24 +701,6 @@ class KeyeVLLM:
             return (self._logits(params, x[:, -1]), *rows,
                     jnp.broadcast_to(counts, (1, b) + counts.shape))
         return JitByLength(run, "prefill")
-
-    def prefillRaw(self, tokens, lengths=None):
-        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab), kStack,
-        vStack, indexStack, counts)``: the rows in
-        :func:`paged_rows_write`'s form and the counts ``(1, b, 9)`` in
-        the pool's order (the whole batch's in every row: the scheduler
-        prefills one sequence at a time).  One executable per prompt
-        bucket."""
-        tokens = jnp.asarray(tokens, _I32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds the capacity "
-                             f"{self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), _I32)
-        else:
-            start = t - jnp.asarray(lengths, _I32)
-        return self._prefillRawFn(self.params, tokens, start)
 
     # ------------------------------------------------------------------
     # step form — the continuous-batching scheduler's executables
@@ -764,45 +746,3 @@ class KeyeVLLM:
         return (self._logits(params, x)[:, None], poolK, poolV, poolI,
                 jnp.zeros_like(counts),
                 jnp.concatenate([routed, seen, left]))
-
-    def buildPagedDecodeFn(self):
-        """FRESH jitted decode step over the pool's arrays: ``(params,
-        poolK, poolV, poolI, counts, toks (S, 1), prev, pageTable, pos,
-        start) -> (out (S, 1 + 14), poolK, poolV, poolI, counts)``.
-        Column 0 of ``out`` is the greedy token a slot; the columns
-        behind it hold, in row 0, the counts :data:`stepCounters` names.
-        ``prev`` is the step before's ``out`` (its first column is read),
-        a slot whose ``toks`` is -1 takes it; the four arrays are
-        DONATED; a fresh identity per build, all as
-        ``TransformerLM.buildPagedDecodeFn`` explains."""
-        def step(params, poolK, poolV, poolI, counts, toks, prev, pageTable,
-                 pos, start):
-            logits, poolK, poolV, poolI, counts, counted = self.pagedLogits(
-                params, poolK, poolV, poolI, counts,
-                paged_step_tokens(toks, prev[:, :1]), pageTable, pos, start)
-            tok = jnp.argmax(logits, axis=-1).astype(_I32)    # (S, 1)
-            tail = jnp.zeros((tok.shape[0], counted.shape[0]), _I32
-                             ).at[0].set(counted)
-            return (jnp.concatenate([tok, tail], axis=1), poolK, poolV,
-                    poolI, counts)
-        return jax.jit(step, donate_argnums=(1, 2, 3, 4))
-
-    def buildPagedPrefillWriteFn(self):
-        """FRESH jitted admission write: one sequence's three stacks of
-        rows (:meth:`prefillRaw`'s, batch row taken) into the pages
-        ``pageIds`` of the three pools, and its prefill's counts ADDED to
-        slot ``slot``'s row of ``counts`` (the next step returns and
-        clears them)."""
-        def write(poolK, poolV, poolI, counts, kStack, vStack, iStack, new,
-                  pageIds, slot):
-            return (paged_rows_write(poolK, kStack, pageIds),
-                    paged_rows_write(poolV, vStack, pageIds),
-                    paged_rows_write(poolI, iStack, pageIds),
-                    counts.at[:, slot].add(new))
-        return jax.jit(write, donate_argnums=(0, 1, 2, 3))
-
-    def compileCacheSize(self) -> int:
-        return served_jit_entries(self)
-
-    def dropCompiled(self) -> None:
-        drop_served_jits(self)

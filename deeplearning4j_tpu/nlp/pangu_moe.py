@@ -56,13 +56,9 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  drop_served_jits,
-                                                  paged_latent_attention,
-                                                  paged_rows_write,
-                                                  paged_step_tokens,
-                                                  served_jit_entries)
+                                                  paged_latent_attention)
 from deeplearning4j_tpu.nlp.mamba import _mm, _rms
-from deeplearning4j_tpu.nlp.served import JitByLength, _rope
+from deeplearning4j_tpu.nlp.served import JitByLength, ServedLM, _rope
 from deeplearning4j_tpu.parallel.ring import (_FLASH_MIN_T, _flash_refusal,
                                               flash_attention)
 from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
@@ -111,12 +107,12 @@ class PanguMoEConfig:
         return self.expertsHeld[1] - self.expertsHeld[0]
 
 
-class PanguMoELM:
+class PanguMoELM(ServedLM):
     """The served model: ``forward`` (the recompute baseline), a bucketed
-    left-padded ``prefillRaw`` that also returns the latent rows and the
-    routing's counts, and the scheduler's fixed-shape decode step and
-    admission write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``,
-    the hooks the other served models have)."""
+    left-padded prefill that also returns the latent rows and the
+    routing's counts, and the step form ``pagedLogits``, from which
+    ``ServedLM`` builds the scheduler's fixed-shape decode step and
+    admission write."""
 
     #: what the step returns in the columns behind its tokens (row 0),
     #: for the batcher to add to ``serving_metrics()``: its own counts of
@@ -369,6 +365,11 @@ class PanguMoELM:
 
     @functools.cached_property
     def _prefillRawFn(self):
+        """``(last logits (b, vocab), rowStack, counts)``: the latent rows
+        in :func:`paged_rows_write`'s form ``(layers, b, 1, t, W)`` and
+        the routing's counts ``(1, b, 3)`` in the pool's order (the whole
+        batch's in every row: the scheduler prefills one sequence at a
+        time)."""
         def run(params, tokens, start):
             x, rows, counts = self._run_full(params, tokens, start)
             b = tokens.shape[0]
@@ -377,24 +378,6 @@ class PanguMoELM:
             return (self._logits(params, x[:, -1]), rows,
                     jnp.broadcast_to(counts, (1, b) + counts.shape))
         return JitByLength(run, "prefill")
-
-    def prefillRaw(self, tokens, lengths=None):
-        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
-        rowStack, counts)``: the latent rows in
-        :func:`paged_rows_write`'s form ``(layers, b, 1, t, W)`` and the
-        routing's counts ``(1, b, 3)`` in the pool's order (the whole
-        batch's in every row: the scheduler prefills one sequence at a
-        time).  One executable per prompt bucket."""
-        tokens = jnp.asarray(tokens, _I32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds the capacity "
-                             f"{self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), _I32)
-        else:
-            start = t - jnp.asarray(lengths, _I32)
-        return self._prefillRawFn(self.params, tokens, start)
 
     # ------------------------------------------------------------------
     # step form — the continuous-batching scheduler's executables
@@ -443,38 +426,3 @@ class PanguMoELM:
         left = jnp.sum(routing, axis=(0, 1)).astype(_I32)
         return (self._logits(params, x)[:, None], rows,
                 jnp.zeros_like(routing), jnp.concatenate([counts, left]))
-
-    def buildPagedDecodeFn(self):
-        """FRESH jitted decode step over the pool's arrays: ``(params,
-        rows, routing, toks (S, 1), prev, pageTable, pos, start) ->
-        (out (S, 1 + 6), rows, routing)``.  Column 0 of ``out`` is the
-        greedy token a slot; the columns behind it hold, in row 0, the
-        counts :data:`stepCounters` names.  ``prev`` is the step before's
-        ``out`` (its first column is read), a slot whose ``toks`` is -1
-        takes it; both arrays are DONATED; a fresh identity per build,
-        all as ``TransformerLM.buildPagedDecodeFn`` explains."""
-        def step(params, rows, routing, toks, prev, pageTable, pos, start):
-            logits, rows, routing, counts = self.pagedLogits(
-                params, rows, routing, paged_step_tokens(toks, prev[:, :1]),
-                pageTable, pos, start)
-            tok = jnp.argmax(logits, axis=-1).astype(_I32)    # (S, 1)
-            tail = jnp.zeros((tok.shape[0], counts.shape[0]), _I32
-                             ).at[0].set(counts)
-            return jnp.concatenate([tok, tail], axis=1), rows, routing
-        return jax.jit(step, donate_argnums=(1, 2))
-
-    def buildPagedPrefillWriteFn(self):
-        """FRESH jitted admission write: one sequence's latent rows
-        (:meth:`prefillRaw`'s, batch row taken) into the pages
-        ``pageIds``, and its prefill's counts ADDED to slot ``slot``'s
-        row of ``routing`` (the next step returns and clears them)."""
-        def write(rows, routing, rowStack, counts, pageIds, slot):
-            return (paged_rows_write(rows, rowStack, pageIds),
-                    routing.at[:, slot].add(counts))
-        return jax.jit(write, donate_argnums=(0, 1))
-
-    def compileCacheSize(self) -> int:
-        return served_jit_entries(self)
-
-    def dropCompiled(self) -> None:
-        drop_served_jits(self)

@@ -44,13 +44,9 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.attention import (_CHUNK_ROWS, CacheSpec,
-                                                  drop_served_jits,
-                                                  paged_attention_read,
-                                                  paged_prefill_write,
-                                                  paged_step_tokens,
-                                                  served_jit_entries)
+                                                  paged_attention_read)
 from deeplearning4j_tpu.nlp.mamba import _mm, mamba_full, mamba_step
-from deeplearning4j_tpu.nlp.served import slot_state_write
+from deeplearning4j_tpu.nlp.served import ServedLM
 
 __all__ = ["SambaYConfig", "SambaYLM"]
 
@@ -111,12 +107,11 @@ def _ln(x, g, b, eps):
     return xc * jax.lax.rsqrt(var + eps) * g.astype(_F32) + b.astype(_F32)
 
 
-class SambaYLM:
+class SambaYLM(ServedLM):
     """The served model: ``forward`` (the recompute baseline), a bucketed
-    left-padded ``prefillRaw`` that also returns every kind of cache
-    state, and the scheduler's fixed-shape decode step and admission
-    write (``buildPagedDecodeFn`` / ``buildPagedPrefillWriteFn``, the
-    hooks ``TransformerLM`` has)."""
+    left-padded prefill that also returns every kind of cache state, and
+    the step form ``pagedLogits``, from which ``ServedLM`` builds the
+    scheduler's fixed-shape decode step and admission write."""
 
     def __init__(self, config: Optional[SambaYConfig] = None, params=None,
                  **kw):
@@ -382,7 +377,7 @@ class SambaYLM:
                     valid & inWin if kind == "window" else valid)
                 out = _mm(o, lp["Wo"])
             x = self._ffn(lp, x + out.astype(cd))
-        # the paged stacks in paged_prefill_write's form (L, b, h, T, d):
+        # the paged stacks in paged_rows_write's form (L, b, h, T, d):
         # one "head" as wide as a row
         state = (jnp.stack(pagedK)[:, :, None], jnp.stack(pagedV)[:, :, None],
                  jnp.stack(ringK), jnp.stack(ringV), jnp.stack(ssm),
@@ -417,27 +412,14 @@ class SambaYLM:
 
     @functools.cached_property
     def _prefillRawFn(self):
+        """``(last logits (b, vocab), kStack, vStack, ringK, ringV, ssm,
+        conv)``: the paged stacks in :func:`paged_rows_write`'s form ``(1,
+        b, 1, t, KV*dh)`` and the slot state ``(layers, b, ...)`` in the
+        pool's order."""
         def run(params, tokens, start):
             x, state = self._run_full(params, tokens, start)
             return (self._logits(params, x[:, -1]),) + state
         return jax.jit(run)
-
-    def prefillRaw(self, tokens, lengths=None):
-        """(b, t) LEFT-padded prompt -> ``(last logits (b, vocab),
-        kStack, vStack, ringK, ringV, ssm, conv)``: the paged stacks in
-        :func:`paged_prefill_write`'s form ``(1, b, 1, t, KV*dh)`` and
-        the slot state ``(layers, b, ...)`` in the pool's order.  One
-        executable per prompt bucket."""
-        tokens = jnp.asarray(tokens, _I32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds the capacity "
-                             f"{self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), _I32)
-        else:
-            start = t - jnp.asarray(lengths, _I32)
-        return self._prefillRawFn(self.params, tokens, start)
 
     # ------------------------------------------------------------------
     # step form — the continuous-batching scheduler's executables
@@ -509,37 +491,3 @@ class SambaYLM:
             x = self._ffn(lp, x + out.astype(cd))
         return (self._logits(params, x)[:, None], k, v, ringK, ringV, ssm,
                 conv)
-
-    def buildPagedDecodeFn(self):
-        """FRESH jitted decode step over the pool's arrays: ``(params,
-        k, v, ringK, ringV, ssm, conv, toks (S, 1), prev (S, 1),
-        pageTable, pos, start) -> (greedy (S, 1), k, v, ringK, ringV,
-        ssm, conv)``.  The six arrays are DONATED, a slot whose ``toks``
-        is -1 takes ``prev``; a fresh identity per build, all as
-        ``TransformerLM.buildPagedDecodeFn`` explains."""
-        def step(params, k, v, ringK, ringV, ssm, conv, toks, prev,
-                 pageTable, pos, start):
-            out = self.pagedLogits(params, k, v, ringK, ringV, ssm, conv,
-                                   paged_step_tokens(toks, prev), pageTable,
-                                   pos, start)
-            return (jnp.argmax(out[0], axis=-1).astype(_I32),) + out[1:]
-        return jax.jit(step, donate_argnums=(1, 2, 3, 4, 5, 6))
-
-    def buildPagedPrefillWriteFn(self):
-        """FRESH jitted admission write: one sequence's prefill state
-        (:meth:`prefillRaw`'s, batch row taken) into the pages
-        ``pageIds`` and into slot ``slot``'s ring rows and recurrent
-        state, which it overwrites whole."""
-        def write(k, v, ringK, ringV, ssm, conv, kStack, vStack, rK, rV,
-                  ssmS, convS, pageIds, slot):
-            k, v = paged_prefill_write(k, v, kStack, vStack, pageIds)
-            put = lambda pool, part: slot_state_write(pool, part, slot)
-            return (k, v, put(ringK, rK), put(ringV, rV), put(ssm, ssmS),
-                    put(conv, convS))
-        return jax.jit(write, donate_argnums=(0, 1, 2, 3, 4, 5))
-
-    def compileCacheSize(self) -> int:
-        return served_jit_entries(self)
-
-    def dropCompiled(self) -> None:
-        drop_served_jits(self)

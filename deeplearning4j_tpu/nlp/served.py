@@ -1,10 +1,14 @@
-"""What the served LMs of this package share outside their blocks: the
+"""What the served models of this package share outside their blocks.
+
+:class:`ServedLM` is their base and owns the calling convention between
+a model and ``ContinuousBatcher`` (``remote/scheduler.py``): the prefill
+wrapper, the paged step and the admission write, built once from what a
+model says of itself.  Beside it, what several models' blocks call: the
 prefill jitted once a prompt bucket under the bucket's name, the
 full-sequence attention a block of queries at a time, the admission
 write of a slot's own state, and rotary positions in the half-split
-pairing.  ``OlmoHybridLM``, ``JambaLM``, ``SambaYLM``, ``PanguMoELM`` and
-``KeyeVLLM`` call them; the mixers are the models' own
-(:mod:`~deeplearning4j_tpu.nlp.mamba` for the two that run Mamba-1).
+pairing (the mixers are the models' own,
+:mod:`~deeplearning4j_tpu.nlp.mamba` for the two that run Mamba-1).
 """
 from __future__ import annotations
 
@@ -12,7 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["JitByLength", "attend_full", "slot_state_write"]
+from deeplearning4j_tpu.nn.conf.attention import paged_rows_write
+
+__all__ = ["ServedLM", "JitByLength", "attend_full", "paged_step_tokens",
+           "slot_state_write"]
 
 _F32 = jnp.float32
 _I32 = jnp.int32
@@ -38,8 +45,8 @@ class JitByLength:
     other served models keep one jit named ``jit_run`` for every bucket: a
     prefill of 512 positions and one of 4,096 differ by eight times in work,
     and a device trace then says which one it holds.  Stands where the one
-    jit stood (called, counted by ``served_jit_entries``); ``at(t)`` is a
-    length's own jit, for ``lower`` and ``trace``."""
+    jit stood (called, counted by ``ServedLM.compileCacheSize``); ``at(t)``
+    is a length's own jit, for ``lower`` and ``trace``."""
 
     def __init__(self, run, name: str):
         self._run, self._name, self._jits = run, name, {}
@@ -100,3 +107,156 @@ def slot_state_write(pool, part, slot):
     return jax.lax.dynamic_update_slice(
         pool, part[:, None].astype(pool.dtype),
         (z, slot.astype(_I32)) + (z,) * (pool.ndim - 2))
+
+
+def paged_step_tokens(toks, prev):
+    """Where each slot's input token of a paged decode step comes from:
+    ``toks`` ((S, tq) int32, from the host) wherever it names a token,
+    and the step before's output ``prev`` ((S, 1), still on the device)
+    wherever the host wrote ``-1`` because it had not read that token
+    yet.  Part of the step's own program, so the scheduler's loop can
+    dispatch a step before it has fetched the one before
+    (``ContinuousBatcher``)."""
+    return jnp.where(toks < 0, prev, toks)
+
+
+class ServedLM:
+    """A model behind ``ContinuousBatcher``: the one place that says how
+    the scheduler calls a model.  A model supplies what is its own:
+
+    - ``config.maxLen``, ``params`` and ``cacheSpec()``: what its layers
+      keep between steps.  The pool's arrays, ``n`` of them in the order
+      of ``cacheSpec().arrayKinds``, are what the step and the write
+      below take and return;
+    - ``_prefillRawFn``, a ``cached_property``: ``run(params, tokens (b,
+      t), start (b,)) -> (last logits (b, vocab), *parts)`` jitted
+      (``jax.jit(run)``, or ``JitByLength(run, "prefill")`` for a
+      program a bucket), one part for each array of the pool in the
+      pool's order: rows as :func:`paged_rows_write` takes them with a
+      batch axis behind the layers', slot state ``(layers, b, ...)``;
+    - ``pagedLogits(params, *arrays, toks (S, tq), pageTable, pos, start)
+      -> (logits (S, tq, vocab), *arrays)``, pure;
+    - ``stepCounters``, where its step counts on the device: ``(metric,
+      labels[, unit])`` a column.  ``pagedLogits`` then returns one value
+      more, ``counted (len(stepCounters),)`` int32, and the LAST array of
+      the pool is the counts' carry: an admission ADDS its prefill's
+      part to its slot's row, the step returns what it finds there in
+      ``counted`` and hands the array back zeroed.
+
+    From these the base builds, for every model alike, what the scheduler
+    calls.  ``_fwd``, where a model has a full forward, is a
+    ``cached_property`` too, and counted and dropped with the prefill.
+    """
+
+    #: the jits a model caches on itself (``cached_property``); its step
+    #: and its write are built fresh for the scheduler, which owns them
+    _SERVED_JITS = ("_fwd", "_prefillRawFn")
+    stepCounters: tuple = ()
+
+    def prefillRaw(self, tokens, lengths=None):
+        """(b, t) LEFT-padded prompt, ``lengths`` its rows' real lengths
+        (none padded where omitted) -> ``(last logits (b, vocab),
+        *parts)`` as ``_prefillRawFn`` returns them.  Always mask-padded:
+        one executable per prompt bucket whatever the raggedness."""
+        tokens = jnp.asarray(tokens, _I32)
+        t = tokens.shape[1]
+        if t > self.config.maxLen:
+            raise ValueError(f"prompt length {t} exceeds the capacity "
+                             f"{self.config.maxLen}")
+        if lengths is None:
+            start = jnp.zeros((tokens.shape[0],), _I32)
+        else:
+            start = t - jnp.asarray(lengths, _I32)
+        return self._prefillRawFn(self.params, tokens, start)
+
+    def restartFromPrompt(self, tokens, lengths=None):
+        """Restart hook for preemption and serving failover: rebuild a
+        sequence's state from its ORIGINAL prompt, with exactly the
+        dispatch the first admission used (same executable, same bucket
+        shape), so the step-by-step replay that follows regenerates the
+        identical token prefix.  The batcher additionally teacher-forces
+        the tokens already delivered, so the prefix a client sees never
+        depends on bit-wise reproducibility across replicas: a quantized
+        or differently placed survivor can override this hook and still
+        satisfy the exactly-once contract."""
+        return self.prefillRaw(tokens, lengths=lengths)
+
+    def buildPagedDecodeFn(self):
+        """FRESH jitted decode step over a ``KVCachePool``'s arrays:
+        ``step(params, *arrays, toks (S, 1), prev, pageTable, pos, start)
+        -> (out, *arrays)``.  Column 0 of ``out (S, 1 +
+        len(stepCounters))`` is the greedy token a slot; the columns
+        behind it hold, in row 0, the counts ``stepCounters`` names.
+        ``prev`` is the step before's ``out``, still on the device: a
+        slot whose ``toks`` is -1 takes its first column
+        (:func:`paged_step_tokens`).  The arrays are DONATED (the pool
+        swaps in the returned ones).  A fresh function identity per
+        build is deliberate: JAX's jaxpr cache keys on function identity
+        + avals, so reusing one closure across a pool/plan rebuild could
+        resurrect constraints traced for the old layout — the scheduler
+        pops and rebuilds these on every pool/plan change.  Traces know
+        the program by the inner function's name (``jit_step``)."""
+        n = len(self.cacheSpec().arrayKinds)
+
+        def step(params, *args):
+            arrays, (toks, prev, pageTable, pos, start) = args[:n], args[n:]
+            logits, *arrays = self.pagedLogits(
+                params, *arrays, paged_step_tokens(toks, prev[:, :1]),
+                pageTable, pos, start)
+            out = jnp.argmax(logits, axis=-1).astype(_I32)    # (S, tq)
+            if self.stepCounters:
+                *arrays, counted = arrays
+                tail = jnp.zeros((out.shape[0], counted.shape[0]), _I32
+                                 ).at[0].set(counted)
+                out = jnp.concatenate([out, tail], axis=1)
+            return (out, *arrays)
+        return jax.jit(step, donate_argnums=tuple(range(1, 1 + n)))
+
+    def buildPagedPrefillWriteFn(self):
+        """FRESH jitted admission write: ``write(*arrays, *parts,
+        pageIds, slot) -> arrays``, one sequence's prefill state
+        (:meth:`prefillRaw`'s parts, batch row taken) into the pool.  By
+        the kind of each array (``cacheSpec().arrayKinds``): rows go into
+        the pages ``pageIds`` ((Tp / pageSize,) int32, ``Tp`` a page
+        multiple; :func:`paged_rows_write`), ring rows and recurrent
+        state over slot ``slot``'s, whole (:func:`slot_state_write`), and
+        the counts of a model with ``stepCounters`` are added to the
+        slot's.  One cache entry per prompt bucket (warmed at start);
+        the arrays are DONATED; traces know the program as
+        ``jit_write``.  A model that keeps pages only may be called
+        without the ``slot``."""
+        kinds = self.cacheSpec().arrayKinds
+        n = len(kinds)
+        added = n - 1 if self.stepCounters else None
+
+        def write(*args):
+            arrays, parts, (pageIds, *slot) = args[:n], args[n:2 * n], \
+                args[2 * n:]
+            out = []
+            for i, (kind, pool, part) in enumerate(zip(kinds, arrays, parts)):
+                if kind in ("paged", "index"):
+                    out.append(paged_rows_write(pool, part, pageIds))
+                elif i == added:
+                    out.append(pool.at[:, slot[0]].add(part))
+                else:
+                    out.append(slot_state_write(pool, part, slot[0]))
+            return tuple(out)
+        return jax.jit(write, donate_argnums=tuple(range(n)))
+
+    def compileCacheSize(self) -> int:
+        """Jit-cache entries of the forward and the prefill, the serving
+        tier's compile hit/miss probe.  The batcher reads it every decode
+        step, so it looks at built jits only and builds none."""
+        n = 0
+        for name in self._SERVED_JITS:
+            fn = self.__dict__.get(name)
+            if fn is not None:
+                n += int(fn._cache_size())
+        return n
+
+    def dropCompiled(self) -> None:
+        """Forget the cached jits (the scheduler calls this when the pool
+        or the plan changes; the next call traces afresh): a reused
+        closure would resurrect the old placement's trace."""
+        for name in self._SERVED_JITS:
+            self.__dict__.pop(name, None)

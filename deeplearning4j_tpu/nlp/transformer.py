@@ -8,16 +8,18 @@ This model keeps decode O(1) per token by writing every layer's K/V into
 the pages of a ``KVCachePool`` (``remote/scheduler.py``), with all
 executable shapes STATIC:
 
-- :meth:`prefillRaw` runs a LEFT-padded prompt bucket through the stack
-  once (causal attention dispatching through
-  ``parallel.ring.dot_product_attention``) and returns the per-layer K/V
-  for the scheduler to copy into pool pages;
-- :meth:`buildPagedDecodeFn` builds the step that feeds ONE token per
-  slot against the pool (:func:`~deeplearning4j_tpu.nn.conf.attention.
-  paged_attention`: lowered for one TPU it reads each slot's live pages
-  where they lie, anywhere else it gathers the slot's capacity under a
-  mask) — fixed (slots, page-table width) shapes, so the batcher warms
-  one executable and never re-traces in steady state;
+- the prefill (``prefillRaw``, the base's wrapper of ``_prefillRawFn``)
+  runs a LEFT-padded prompt bucket through the stack once (causal
+  attention dispatching through ``parallel.ring.dot_product_attention``)
+  and returns the per-layer K/V for the scheduler to copy into pool
+  pages;
+- :meth:`pagedLogits` feeds ONE token per slot against the pool
+  (:func:`~deeplearning4j_tpu.nn.conf.attention.paged_attention`:
+  lowered for one TPU it reads each slot's live pages where they lie,
+  anywhere else it gathers the slot's capacity under a mask) — fixed
+  (slots, page-table width) shapes, so the batcher warms one executable
+  (:class:`~deeplearning4j_tpu.nlp.served.ServedLM` builds the step
+  from it) and never re-traces in steady state;
 - :meth:`forward` is the plain causal forward, and :meth:`generate` the
   greedy recompute over it: the reference the served path is held to.
 
@@ -34,12 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  drop_served_jits,
-                                                  paged_attention,
-                                                  paged_prefill_write,
-                                                  paged_step_tokens,
-                                                  served_jit_entries)
+from deeplearning4j_tpu.nn.conf.attention import CacheSpec, paged_attention
+from deeplearning4j_tpu.nlp.served import ServedLM
 
 __all__ = ["TransformerLMConfig", "TransformerLM"]
 
@@ -60,7 +58,7 @@ class TransformerLMConfig:
         return self.nHeads * self.headSize
 
 
-class TransformerLM:
+class TransformerLM(ServedLM):
     """GPT-style causal LM: one block body, attended causally over the
     sequence (``forward``, ``prefillRaw``) or against pool pages (the
     paged decode step)."""
@@ -169,9 +167,10 @@ class TransformerLM:
     # ------------------------------------------------------------------
     @functools.cached_property
     def _prefillRawFn(self):
-        """Prefill that returns the per-layer K/V heads STACKED
-        ((nLayers, b, h, t, d)) — the continuous scheduler copies them
-        straight into pool pages."""
+        """Prefill of a (b, t) LEFT-padded prompt: the last logits (b,
+        vocab) and the per-layer K/V heads STACKED ((nLayers, b, h, t,
+        d)) — the continuous scheduler copies them straight into pool
+        pages."""
         def run(params, tokens, start):
             # start[b] = index of the first REAL token (left padding);
             # position ids count from the real start so padded and
@@ -191,35 +190,6 @@ class TransformerLM:
                     jnp.stack(ks), jnp.stack(vs))
         return jax.jit(run)
 
-    def prefillRaw(self, tokens, lengths=None):
-        """(b, t) LEFT-padded prompt -> (last logits (b, vocab),
-        kStack, vStack (nLayers, b, h, t, d)).  Always mask-padded (one
-        executable per prompt bucket regardless of raggedness)."""
-        tokens = jnp.asarray(tokens, jnp.int32)
-        t = tokens.shape[1]
-        if t > self.config.maxLen:
-            raise ValueError(f"prompt length {t} exceeds cache capacity "
-                             f"{self.config.maxLen}")
-        if lengths is None:
-            start = jnp.zeros((tokens.shape[0],), jnp.int32)
-        else:
-            start = t - jnp.asarray(lengths, jnp.int32)
-        return self._prefillRawFn(self.params, tokens, start)
-
-    def restartFromPrompt(self, tokens, lengths=None):
-        """Restart hook for preemption and serving failover: rebuild a
-        sequence's KV state from its ORIGINAL prompt, with exactly the
-        dispatch the first admission used (same executable, same bucket
-        shape), so the step-by-step replay that follows regenerates the
-        identical token prefix — greedy decode is deterministic given
-        identical ops on identical shapes.  The continuous batcher
-        additionally teacher-forces the already-delivered tokens during
-        replay, so the prefix a client sees never depends on bit-wise
-        reproducibility across replicas (a quantized or differently
-        placed survivor can override this hook and still satisfy the
-        exactly-once contract)."""
-        return self.prefillRaw(tokens, lengths=lengths)
-
     def cacheSpec(self) -> CacheSpec:
         """What the layers keep between decode steps, for the
         scheduler's pool: every layer owns K/V pages, nothing else."""
@@ -233,9 +203,7 @@ class TransformerLM:
         the paged decode step takes its arg-max of, and what a parity
         check compares with :meth:`forward`.  Every layer writes and
         reads the stacked pools in place.  Position-embedding ids are
-        clipped so a speculative over-write past ``maxLen`` (tokens that
-        will be discarded by the accept rule) can't index out of the
-        table."""
+        clipped so a row past ``maxLen`` can't index out of the table."""
         tq = toks.shape[1]
         pos_ids = jnp.clip(
             (pos - start)[:, None] + jnp.arange(tq, dtype=jnp.int32),
@@ -247,77 +215,6 @@ class TransformerLM:
                 lp, x, lambda qh, kh, vh: paged_attention(
                     qh, kh, vh, poolK, poolV, li, pageTable, pos, start))
         return self._logits(params, x), poolK, poolV
-
-    def _paged_step_math(self, params, poolK, poolV, toks, pageTable,
-                         pos, start):
-        """:meth:`pagedLogits` reduced to (S, tq) greedy tokens."""
-        logits, poolK, poolV = self.pagedLogits(params, poolK, poolV, toks,
-                                                pageTable, pos, start)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), poolK, poolV
-
-    def buildPagedDecodeFn(self):
-        """FRESH jitted paged decode/verify step over a
-        ``KVCachePool``'s buffers: ``(params, poolK, poolV, toks (S,tq),
-        prev (S,1), pageTable, pos, start) -> (greedy (S,tq), poolK,
-        poolV)``.  tq=1 is the plain decode step; tq=draftK+1 the
-        speculative verify.  A slot whose ``toks`` is -1 takes ``prev``,
-        the step before's greedy output, still on the device
-        (:func:`paged_step_tokens`).  Pool buffers are DONATED (the pool
-        swaps in the returned arrays).  A fresh function identity per
-        build is deliberate: JAX's jaxpr cache keys on function identity
-        + avals, so reusing one closure across a pool/plan rebuild could
-        resurrect constraints traced for the old layout — the scheduler
-        pops and rebuilds these on every pool/plan change."""
-        def step(params, poolK, poolV, toks, prev, pageTable, pos, start):
-            return self._paged_step_math(
-                params, poolK, poolV, paged_step_tokens(toks, prev),
-                pageTable, pos, start)
-        return jax.jit(step, donate_argnums=(1, 2))
-
-    def buildPagedProposeFn(self, draftK: int):
-        """FRESH jitted paged draft proposal: k greedy tokens per slot in
-        ONE dispatch (``lax.scan`` inside the executable; k+1 steps so
-        the k-th proposal's K/V is already paged in on a full accept).
-        Same donation and fresh-identity contract as
-        :meth:`buildPagedDecodeFn`."""
-        draftK = int(draftK)
-
-        def propose(params, poolK, poolV, tok, pageTable, pos, start):
-            def body(carry, _):
-                poolK, poolV, tok, pos = carry
-                greedy, poolK, poolV = self._paged_step_math(
-                    params, poolK, poolV, tok[:, None], pageTable, pos,
-                    start)
-                nxt = greedy[:, 0]
-                return (poolK, poolV, nxt, pos + 1), nxt
-            (poolK, poolV, _, _), props = jax.lax.scan(
-                body, (poolK, poolV, tok, pos), None, length=draftK + 1)
-            return jnp.transpose(props)[:, :draftK], poolK, poolV
-        return jax.jit(propose, donate_argnums=(1, 2))
-
-    def buildPagedPrefillWriteFn(self):
-        """FRESH jitted pool write: copy one sequence's stacked prefill
-        K/V ((L, h, Tp, d), Tp a page multiple) into the pages named by
-        ``pageIds`` ((Tp/pageSize,) int32).  One cache entry per prompt
-        bucket (warmed at start).  The layout is
-        :func:`paged_prefill_write`'s; the wrapper gives each build its
-        own identity and the program the name traces know it by
-        (``jit_write``).  The scheduler also passes the ``slot``; pages
-        are all this model keeps, so it goes unused."""
-        def write(poolK, poolV, kStack, vStack, pageIds, slot=None):
-            return paged_prefill_write(poolK, poolV, kStack, vStack,
-                                       pageIds)
-        return jax.jit(write, donate_argnums=(0, 1))
-
-    def compileCacheSize(self) -> int:
-        """Jit-cache entries of the forward and the prefill — the
-        serving tier's compile hit/miss probe."""
-        return served_jit_entries(self)
-
-    def dropCompiled(self) -> None:
-        """Forget the cached jits (the scheduler calls this when the
-        pool or the plan changes; the next call traces afresh)."""
-        drop_served_jits(self)
 
     # ------------------------------------------------------------------
     def generate(self, prompts, maxNewTokens: int) -> np.ndarray:

@@ -37,9 +37,7 @@ __all__ = ["SelfAttentionLayer", "LearnedSelfAttentionLayer",
            "paged_kernel_kv_passes", "lowered_for_one_tpu",
            "sparse_in_place_lowerings",
            "paged_latent_attention", "paged_sparse_attention",
-           "paged_prefill_write",
-           "paged_rows_write", "paged_step_tokens",
-           "CacheSpec", "served_jit_entries", "drop_served_jits"]
+           "paged_rows_write", "CacheSpec"]
 
 
 def _mha(x_btn, Wq, Wk, Wv, Wo, nHeads, mask=None, q_btn=None, impl="auto",
@@ -1239,6 +1237,20 @@ class CacheSpec:
         latent pool."""
         return 1 if self.latentWidth else 2
 
+    @property
+    def arrayKinds(self) -> Tuple[str, ...]:
+        """The kind of every array of this model's pool, in the order the
+        step and the admission write take and return them: ``"paged"``
+        (K and V, or the one latent pool), ``"index"``, ``"ring"`` (K
+        then V), ``"slot"`` (one for each entry of ``slotState``).  The
+        one definition of that order: ``KVCachePool`` allocates by it
+        and :class:`~deeplearning4j_tpu.nlp.served.ServedLM` builds the
+        step and the write by it."""
+        return (("paged",) * self.pagedPools
+                + ("index",) * bool(self.indexWidth)
+                + ("ring",) * (2 * bool(self.ringLayers))
+                + ("slot",) * len(self.slotState))
+
 
 def paged_rows_write(pool, stack, pageIds):
     """Copy one sequence's stacked prefill rows ((L, h, Tp, d), ``Tp`` a
@@ -1248,53 +1260,6 @@ def paged_rows_write(pool, stack, pageIds):
     ps = pool.shape[2]
     return pool.at[:, pageIds].set(stack.transpose(0, 2, 1, 3).reshape(
         L, Tp // ps, ps, h * d).astype(pool.dtype))
-
-
-def paged_prefill_write(poolK, poolV, kStack, vStack, pageIds):
-    """:func:`paged_rows_write` for the K and the V pool of
-    :func:`paged_attention`.  Returns the two pools."""
-    return (paged_rows_write(poolK, kStack, pageIds),
-            paged_rows_write(poolV, vStack, pageIds))
-
-
-def paged_step_tokens(toks, prev):
-    """Where each slot's input token of a paged decode step comes from:
-    ``toks`` ((S, tq) int32, from the host) wherever it names a token,
-    and the step before's output ``prev`` ((S, 1), still on the device)
-    wherever the host wrote ``-1`` because it had not read that token
-    yet.  Part of the step's own program in every served model, so the
-    scheduler's loop can dispatch a step before it has fetched the one
-    before (``ContinuousBatcher``)."""
-    return jnp.where(toks < 0, prev, toks)
-
-
-#: the jits a served model caches on itself (``cached_property``): the
-#: full forward and the prefill.  Its paged step and pool write are built
-#: fresh for the scheduler, which owns them.
-_SERVED_JITS = ("_fwd", "_prefillRawFn")
-
-
-def served_jit_entries(model) -> int:
-    """Jit-cache entries across ``model``'s own executables: a served
-    model's ``compileCacheSize()``.  The batcher reads it every decode
-    step, so it looks at built jits only and builds none."""
-    n = 0
-    for name in _SERVED_JITS:
-        fn = model.__dict__.get(name)
-        if fn is not None:
-            try:
-                n += int(fn._cache_size())
-            except Exception:
-                pass
-    return n
-
-
-def drop_served_jits(model) -> None:
-    """A served model's ``dropCompiled()``: JAX's jaxpr cache keys on
-    function identity + avals (not shardings), so after a change of pool
-    or plan a reused closure would resurrect the old placement's trace."""
-    for name in _SERVED_JITS:
-        model.__dict__.pop(name, None)
 
 
 @dataclasses.dataclass

@@ -116,8 +116,7 @@ def _pop_inference_caches(model) -> None:
         model.__dict__.pop(k, None)
 
 
-def apply_inference_plan(model, plan: "ShardingPlan",
-                         tensorParallel: Optional[bool] = None):
+def apply_inference_plan(model, plan: "ShardingPlan"):
     """Inference-mode plan application — the serving tier's TP replica
     path (ROADMAP item 1): place a raw-params model's weight pytree
     (``model.params``, TransformerLM-style) onto ``plan``'s mesh and
@@ -130,11 +129,9 @@ def apply_inference_plan(model, plan: "ShardingPlan",
     all GSPMD needs — the jitted prefill/decode executables partition
     themselves and insert the collectives, so a model too big for one
     chip serves over several with no code change above this call.
-    ``tensorParallel`` overrides the plan's flag (a small DRAFT model
-    riding a TP mesh replicates instead).  Returns the model.
+    Returns the model.
     """
-    tp = plan.tensorParallel if tensorParallel is None \
-        else bool(tensorParallel)
+    tp = plan.tensorParallel
     jmesh = plan.mesh.mesh
     msize = plan.mesh.modelSize
     axis = plan.modelAxis

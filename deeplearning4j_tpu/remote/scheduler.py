@@ -1,5 +1,5 @@
 """Iteration-level continuous batching: paged KV-cache pool, admit/retire
-scheduler, speculative decode, and replica fan-out.
+scheduler, and replica fan-out.
 
 PR 8's :class:`~deeplearning4j_tpu.remote.serving.BucketedExecutor` runs a
 whole ``generate()`` per coalesced group — one slow long prompt holds its
@@ -21,12 +21,9 @@ replica busy:
   admission, so no request starves behind later arrivals), each new
   token streams back to the waiting client as its step completes, and a
   pool squeeze preempts the youngest slot (restart-with-skip) instead of
-  wedging.  With a small draft :class:`~deeplearning4j_tpu.nlp.
-  transformer.TransformerLM` attached, every step becomes a speculative
-  round: the draft proposes ``draftK`` tokens in one fused scan, the
-  target verifies all of them in ONE batched forward, and the
-  accept-prefix rule keeps the output BIT-IDENTICAL to target-only
-  greedy decode — between 1 and draftK+1 tokens for two dispatches.
+  wedging.  What it calls of a model is what
+  :class:`~deeplearning4j_tpu.nlp.served.ServedLM` builds: ``prefillRaw``,
+  the step, the admission write.
 - :class:`ReplicaSet` — fan-out behind one
   :class:`~deeplearning4j_tpu.remote.serving.ModelRegistry` route:
   each replica is its own executor whose weights are placed by
@@ -38,12 +35,11 @@ replica busy:
   ``serving_queue_depth`` alert's firing/resolved edges.
 
 Compile discipline: every executable (per-bucket prefill + pool write,
-the tq=1 decode step, the tq=draftK+1 verify step, the draft's proposal
-scan) is warmed at ``start()``; admit/retire churn in steady state must
-hold the jit-miss counter FLAT.  Pool or plan changes pop every cached
-step fn and rebuild fresh closures — JAX's jaxpr cache keys on function
-identity + avals, so a reused closure could resurrect the old layout's
-traced constraints.
+the decode step) is warmed at ``start()``; admit/retire churn in steady
+state must hold the jit-miss counter FLAT.  Pool or plan changes pop
+every cached step fn and rebuild fresh closures — JAX's jaxpr cache keys
+on function identity + avals, so a reused closure could resurrect the old
+layout's traced constraints.
 """
 from __future__ import annotations
 
@@ -129,8 +125,8 @@ class KVCachePool:
       of ``spec.slotState``, overwritten every step.
 
     ``arrays`` is the tuple the step and the admission write take and
-    return, in this order: ``(k, v[, index][, ringK, ringV][,
-    *slotState])`` — ``(k, v)`` when every layer is paged,
+    return, in the order of ``spec.arrayKinds``: ``(k, v[, index][,
+    ringK, ringV][, *slotState])`` — ``(k, v)`` when every layer is paged,
     ``(rows[, *slotState])`` for latent rows.  ``index``
     ``(pagedLayers, numPages, pageSize, spec.indexRowWidth)`` is there
     for a model whose attention selects its rows (``spec.indexWidth``):
@@ -173,21 +169,18 @@ class KVCachePool:
             return a if sh is None else jax.device_put(a, sh)
         paged = (spec.pagedLayers, self.numPages, self.pageSize,
                  spec.rowWidth)
-        arrays = [zeros(paged, spec.dtype, sharding)
-                  for _ in range(spec.pagedPools)]
-        if spec.indexWidth:
-            # one key for every index head: no head to split it by
-            arrays.append(zeros(paged[:3] + (spec.indexRowWidth,),
-                                spec.dtype, slotSharding))
-        if spec.ringLayers:
-            ring = (spec.ringLayers, self.maxSlots, spec.ringRows,
-                    spec.rowWidth)
-            arrays += [zeros(ring, spec.dtype, slotSharding)
-                       for _ in range(2)]
-        for _name, shape, dt in spec.slotState:
-            arrays.append(zeros((shape[0], self.maxSlots) + tuple(shape[1:]),
-                                dt, slotSharding))
-        self._arrays = tuple(arrays)
+        rows = {"paged": paged,
+                # one key for every index head: no head to split it by
+                "index": paged[:3] + (spec.indexRowWidth,),
+                "ring": (spec.ringLayers, self.maxSlots, spec.ringRows,
+                         spec.rowWidth)}
+        kinds = spec.arrayKinds             # the slot state comes last
+        shapes = [(rows[k], spec.dtype) for k in kinds if k != "slot"] + [
+            ((shape[0], self.maxSlots) + tuple(shape[1:]), dt)
+            for _name, shape, dt in spec.slotState]
+        self._arrays = tuple(
+            zeros(shape, dt, sharding if kind == "paged" else slotSharding)
+            for kind, (shape, dt) in zip(kinds, shapes))
         self.pageTable = np.zeros((self.maxSlots, self.maxPagesPerSeq),
                                   np.int32)
         self._free = deque(range(1, self.numPages))
@@ -405,8 +398,7 @@ def _finish_seq(seq: _Seq, error: Optional[BaseException],
 
 class ContinuousBatcher:
     """The iteration-level scheduler: one shared fixed-slot decode batch,
-    admit/retire between steps, token streaming, optional speculative
-    decode, paged KV memory.
+    admit/retire between steps, token streaming, paged KV memory.
 
     Registry-compatible executor surface (``start``/``submit``/
     ``submitStream``/``queuedRows``/``shutdown``), so it hosts behind
@@ -433,10 +425,7 @@ class ContinuousBatcher:
     the last K/V row went into a page the sequence held at dispatch, and
     device order puts the next occupant's prefill write after it.  At
     most one step is ever unread, and it is read before the loop waits
-    and dropped when the loop exits or a batch fails.  With a draft
-    model the accept rule moves ``pos`` by a number only the host knows,
-    so nothing is left unread: the same loop reads each step as soon as
-    it is dispatched.
+    and dropped when the loop exits or a batch fails.
 
     The price is paid by an arrival: its prefill queues on the device
     behind the step just dispatched, so the first token comes up to one
@@ -445,60 +434,38 @@ class ContinuousBatcher:
     exchange for every later token coming sooner.
     """
 
-    def __init__(self, lm, name: str = "default", draft=None,
-                 draftK: int = 4, pageSize: int = 8,
+    def __init__(self, lm, name: str = "default", pageSize: int = 8,
                  numPages: Optional[int] = None, maxSlots: int = 4,
                  ladder: Optional[BucketLadder] = None,
                  admission: Optional[AdmissionControl] = None,
                  eosToken: Optional[int] = None, plan=None, device=None,
                  retireLogSize: int = 64):
         self.lm = lm
-        self.draft = draft
-        self.draftK = int(draftK) if draft is not None else 0
-        if draft is not None:
-            if self.draftK < 1:
-                raise ValueError("draftK must be >= 1 with a draft model")
-            if draft.config.vocabSize != lm.config.vocabSize:
-                raise ValueError("draft and target must share a vocabulary")
-            specs = (lm.cacheSpec(), draft.cacheSpec())
-            if any(s.ringLayers or s.slotState for s in specs):
-                raise ValueError(
-                    "speculative decode rolls a rejected proposal back by "
-                    "position alone: a model with ring or recurrent state "
-                    "cannot be the target or the draft")
         self.name = str(name)
         cfg = lm.config
         self.pageSize = int(pageSize)
-        self._maxPagesPerSeq = -(-(cfg.maxLen + self.draftK)
-                                 // self.pageSize)
+        self._maxPagesPerSeq = -(-cfg.maxLen // self.pageSize)
         self._numPages = int(numPages) if numPages is not None else \
             1 + int(maxSlots) * self._maxPagesPerSeq
         self.maxSlots = int(maxSlots)
         self.eosToken = int(eosToken) if eosToken is not None else None
         self.admission = admission or AdmissionControl()
-        # the SMALLER cache bounds every admissible position when a
-        # draft rides along (both models ingest the same prompt)
-        effCap = cfg.maxLen if draft is None \
-            else min(cfg.maxLen, draft.config.maxLen)
         if ladder is None:
             ladder = BucketLadder(
                 batchSizes=(self.maxSlots,),
                 seqLens=tuple(
                     s for s in (16, 32, 64, 128, 256, 512, 1024)
-                    if s <= max(effCap // 2, self.pageSize)
+                    if s <= max(cfg.maxLen // 2, self.pageSize)
                     and s % self.pageSize == 0) or (self.pageSize,))
         for s in ladder.seqLens:
             if s % self.pageSize:
                 raise ValueError(
                     f"prompt bucket {s} is not a multiple of the page "
                     f"size {self.pageSize} (prefill copies whole pages)")
-            if s >= effCap:
+            if s >= cfg.maxLen:
                 raise ValueError(
                     f"prompt bucket {s} leaves no room to generate "
-                    f"within the capacity {effCap}"
-                    + (" (bounded by the draft model)"
-                       if draft is not None and
-                       draft.config.maxLen < cfg.maxLen else ""))
+                    f"within the capacity {cfg.maxLen}")
         self.ladder = ladder
         self.plan = None
         self._device = device
@@ -546,7 +513,7 @@ class ContinuousBatcher:
         # what the model's step counts on the device and returns in the
         # columns behind its tokens (row 0): ``(metric, labels)`` each, or
         # ``(metric, labels, unit)`` for a column worth ``unit`` a count
-        self._stepCounters = tuple(getattr(lm, "stepCounters", ()))
+        self._stepCounters = tuple(lm.stepCounters)
         self._cacheSeen: Optional[int] = None
         self._busySteps = 0.0
         self._steps = 0
@@ -560,8 +527,6 @@ class ContinuousBatcher:
                 from deeplearning4j_tpu.parallel.meshtrainer import \
                     place_replica
                 place_replica(lm, device)
-                if draft is not None:
-                    place_replica(draft, device)
             self._buildPools()
 
     # -- placement ------------------------------------------------------
@@ -580,10 +545,9 @@ class ContinuousBatcher:
         return NamedSharding(mesh.mesh, P())
 
     def _buildPools(self) -> None:
-        """One pool per model, shaped by what the model says its layers
+        """The model's pool, shaped by what the model says its layers
         keep (``cacheSpec()``).  On a TP mesh the paged buffers split by
-        heads; ring and recurrent state, and the draft's pool, replicate
-        (as the draft's params do)."""
+        heads; ring and recurrent state replicate."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         spec = self.lm.cacheSpec()
         whole = NamedSharding(self.plan.mesh.mesh, P()) \
@@ -593,23 +557,16 @@ class ContinuousBatcher:
             self._maxPagesPerSeq,
             sharding=self._poolSharding(spec.splitHeads),
             slotSharding=whole)
-        self.draftPool = None if self.draft is None else \
-            KVCachePool.forSpec(
-                self.draft.cacheSpec(), self.pageSize, self._numPages,
-                self.maxSlots, self._maxPagesPerSeq, sharding=whole)
 
     def applyPlan(self, plan) -> None:
         """Inference-mode :class:`~deeplearning4j_tpu.parallel.
         meshtrainer.ShardingPlan` application — the TP replica path:
-        shard the target's weights over the plan's model axis, replicate
-        the draft's, rebuild both pools ON the mesh, and pop every
-        cached step executable so the next warm traces fresh closures
-        against the new placement."""
+        shard the model's weights over the plan's model axis, rebuild
+        the pool ON the mesh, and pop every cached step executable so
+        the next warm traces fresh closures against the new placement."""
         from deeplearning4j_tpu.parallel.meshtrainer import \
             apply_inference_plan
         apply_inference_plan(self.lm, plan)
-        if self.draft is not None:
-            apply_inference_plan(self.draft, plan, tensorParallel=False)
         self.plan = plan
         self._buildPools()
         self._invalidateFns()
@@ -617,12 +574,10 @@ class ContinuousBatcher:
     # -- executables ----------------------------------------------------
     def _invalidateFns(self) -> None:
         """Pool or plan changed: drop every cached step fn (and the
-        models' cached jits) so nothing re-dispatches a trace whose
+        model's cached jits) so nothing re-dispatches a trace whose
         constraints belong to the old layout."""
         self._stepFns.clear()
-        for m in (self.lm, self.draft):
-            if m is not None:
-                m.dropCompiled()
+        self.lm.dropCompiled()
         self._warmed = False
         self._cacheSeen = None
 
@@ -631,29 +586,18 @@ class ContinuousBatcher:
             return
         self._stepFns["step"] = self.lm.buildPagedDecodeFn()
         self._stepFns["write"] = self.lm.buildPagedPrefillWriteFn()
-        if self.draft is not None:
-            self._stepFns["propose"] = \
-                self.draft.buildPagedProposeFn(self.draftK)
-            self._stepFns["dwrite"] = self.draft.buildPagedPrefillWriteFn()
 
     def compileCacheSize(self) -> int:
-        """Executable-cache entries across every model and scheduler fn
-        — the flat-across-churn acceptance probe."""
-        n = self.lm.compileCacheSize()
-        if self.draft is not None:
-            n += self.draft.compileCacheSize()
-        for fn in self._stepFns.values():
-            try:
-                n += int(fn._cache_size())
-            except Exception:
-                pass
-        return n
+        """Executable-cache entries across the model's and the
+        scheduler's fns — the flat-across-churn acceptance probe."""
+        return self.lm.compileCacheSize() + sum(
+            int(fn._cache_size()) for fn in self._stepFns.values()
+            if hasattr(fn, "_cache_size"))
 
     def warm(self) -> float:
         """Compile every steady-state executable BEFORE traffic: one
         prefill + pool write per prompt bucket (scratch pages take the
-        dummy writes), the tq=1 decode step, and with a draft the
-        tq=draftK+1 verify plus the proposal scan."""
+        dummy writes) and the decode step."""
         if self._warmed:
             return 0.0
         sm = serving_metrics()
@@ -694,15 +638,6 @@ class ContinuousBatcher:
         # unread.
         self._noPrev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, prev, pt, zeros, zeros)
-        if self.draft is not None:
-            _g, *self.pool.arrays = step(
-                self.lm.params, *self.pool.arrays,
-                jnp.zeros((S, self.draftK + 1), jnp.int32), self._noPrev,
-                pt, zeros, zeros)
-            dpt = jnp.asarray(self.draftPool.pageTable)
-            _p, *self.draftPool.arrays = self._stepFns["propose"](
-                self.draft.params, *self.draftPool.arrays, zeros, dpt,
-                zeros, zeros)
         slot0 = jnp.zeros((), jnp.int32)
         for Tp in self.ladder.seqLens:
             dummy = np.zeros((1, Tp), np.int32)
@@ -710,11 +645,7 @@ class ContinuousBatcher:
             # slot 0's ring rows and recurrent state take the dummy
             # writes: an admission overwrites them before any step reads
             _l, *state = self.lm.prefillRaw(dummy, lengths=[1])
-            self._writeState(self.pool, "write", state, ids, slot0)
-            if self.draft is not None:
-                _l, *state = self.draft.prefillRaw(dummy, lengths=[1])
-                self._writeState(self.draftPool, "dwrite", state, ids,
-                                 slot0)
+            self._writeState(state, ids, slot0)
         jax.block_until_ready(self.pool.arrays)  # jaxlint: sync-ok -- warm-up fence: compile cost must land in warmup_seconds, not the first request
         self._atRest()
         self._warmed = True
@@ -823,19 +754,11 @@ class ContinuousBatcher:
             raise ValueError("maxNewTokens must be >= 1")
         Tp = self.ladder.seqBucket(toks.shape[1])    # 400 above top bucket
         cap = self.lm.config.maxLen
-        if self.draft is not None:
-            # the draft ingests the same positions — the SMALLER cache
-            # bounds what is admissible (reject here, not on the loop
-            # thread inside draft.prefillRaw)
-            cap = min(cap, self.draft.config.maxLen)
         if Tp + n > cap:
             raise ValueError(
                 f"prompt bucket {Tp} + maxNewTokens {n} exceeds the "
-                f"positional capacity {cap}"
-                + (" (bounded by the draft model)" if self.draft is not None
-                   and self.draft.config.maxLen < self.lm.config.maxLen
-                   else ""))
-        pages = self.pool.pagesFor(Tp + n + self.draftK)
+                f"positional capacity {cap}")
+        pages = self.pool.pagesFor(Tp + n)
         if pages > self.pool.maxPagesPerSeq:
             raise ValueError(
                 f"prompt bucket {Tp} + maxNewTokens {n} can never fit "
@@ -1059,8 +982,8 @@ class ContinuousBatcher:
         self._drainedAt = time.perf_counter()
 
     def _starved(self) -> Optional[Tuple[float, bool]]:
-        """Before every dispatch onto the device (a prefill, a draft's
-        proposal, a step): since when it has stood idle, and whether that
+        """Before every dispatch onto the device (a prefill, a step):
+        since when it has stood idle, and whether that
         instant is an upper bound; None while it holds work.  From a
         drain instant on record the stretch is exact; where none is on
         record and what was dispatched last ``is_ready()``, the device
@@ -1208,10 +1131,8 @@ class ContinuousBatcher:
                 if not head.cancelled and not expired:
                     if free is None:
                         return
-                    want = self.pool.pagesFor(head.bucket)
-                    if self.pool.freePages() < want or (
-                            self.draftPool is not None and
-                            self.draftPool.freePages() < want):
+                    if self.pool.freePages() < \
+                            self.pool.pagesFor(head.bucket):
                         return
                 self._queue.popleft()
                 self._queuedRows -= 1
@@ -1240,8 +1161,6 @@ class ContinuousBatcher:
                 # fails ITS sequence only — free whatever the slot
                 # already holds and keep admitting
                 self.pool.release(free)
-                if self.draftPool is not None:
-                    self.draftPool.release(free)
                 if self._slotSeq[free] is seq:
                     self._retireSlot(free, error=e)
                 else:
@@ -1257,8 +1176,6 @@ class ContinuousBatcher:
                              queueWait, trace_id=tid, model=self.name)
         Tp = seq.bucket
         self.pool.ensure(slot, Tp)
-        if self.draftPool is not None:
-            self.draftPool.ensure(slot, Tp)
         padded = seq.tokens if seq.realLen == Tp else np.concatenate(
             [np.zeros((1, Tp - seq.realLen), np.int32), seq.tokens],
             axis=1)
@@ -1267,9 +1184,8 @@ class ContinuousBatcher:
         # through the model's restart hook — same executable + bucket as
         # a first admission, but the hook is the seam a survivor with
         # different numerics can override
-        prefill = getattr(self.lm, "restartFromPrompt",
-                          self.lm.prefillRaw) \
-            if seq.restarts > 0 else self.lm.prefillRaw
+        prefill = self.lm.restartFromPrompt if seq.restarts > 0 \
+            else self.lm.prefillRaw
         span = tracer().span(
             "serving.prefill",
             observe=lambda dt: observe_exemplar(
@@ -1284,14 +1200,7 @@ class ContinuousBatcher:
             self._fed(starved, logits)
             with tracer().span("serving.state.write", replica=self.name,
                                slot=slot):
-                self._writeState(self.pool, "write", state, ids, slotA)
-            if self.draft is not None:
-                _l, *state = self.draft.prefillRaw(
-                    padded, lengths=[seq.realLen])
-                dids = jnp.asarray(self.draftPool.heldIds(slot)[:nP],
-                                   jnp.int32)
-                self._writeState(self.draftPool, "dwrite", state, dids,
-                                 slotA)
+                self._writeState(state, ids, slotA)
             # the row's slice is the last thing this admission gives the
             # device, behind the prefill and the state writes; a device
             # runs what it is given in order, so when its read returns
@@ -1328,14 +1237,14 @@ class ContinuousBatcher:
         if self._emit(seq, first):
             self._retireSlot(slot)
 
-    def _writeState(self, pool: KVCachePool, fn: str, state, pageIds,
-                    slot) -> None:
+    def _writeState(self, state, pageIds, slot) -> None:
         """Write what one sequence's prefill leaves behind (``state``:
         ``prefillRaw``'s parts after the logits, batch row 0) — pages,
-        ring rows, recurrent state — into ``pool``, at ``pageIds`` and
+        ring rows, recurrent state — into the pool, at ``pageIds`` and
         ``slot``."""
-        pool.arrays = self._stepFns[fn](
-            *pool.arrays, *(part[:, 0] for part in state), pageIds, slot)
+        self.pool.arrays = self._stepFns["write"](
+            *self.pool.arrays, *(part[:, 0] for part in state), pageIds,
+            slot)
 
     def _emit(self, seq: _Seq, tok: int) -> bool:
         """Deliver one token; True when the sequence is finished.  After
@@ -1383,8 +1292,7 @@ class ContinuousBatcher:
 
     def _stepOnce(self) -> None:
         """One iteration's decode work: dispatch the next step, then read
-        the one that was dispatched an iteration ago — or, with a draft,
-        the one just dispatched."""
+        the one that was dispatched an iteration ago."""
         delay = _inj.replica_slowdown(self.name)
         if delay:
             time.sleep(delay)           # injected brownout (SlowReplica)
@@ -1393,21 +1301,17 @@ class ContinuousBatcher:
             with self._phase("grow"):
                 active, deferred = self._growPages()
             stepArgs["active"] = len(active)
-            flight, propsH = self._dispatch(active, deferred) if active \
-                else (None, None)
-            if self.draft is None:
-                # one step ahead: what was just dispatched stays unread
-                # while the host delivers the step before it
-                flight, self._inflight = self._inflight, flight
+            flight = self._dispatch(active, deferred) if active else None
+            # one step ahead: what was just dispatched stays unread
+            # while the host delivers the step before it
+            flight, self._inflight = self._inflight, flight
             if flight is not None:
-                self._land(flight, propsH)
+                self._land(flight)
 
-    def _dispatch(self, active: List[int], deferred: set
-                  ) -> Tuple[_Flight, Optional[np.ndarray]]:
+    def _dispatch(self, active: List[int], deferred: set) -> _Flight:
         """Upload the slots' state and dispatch one step for ``active``;
         the host's ``pos`` moves on by what was dispatched, and a
-        sequence whose quota this step fills leaves its slot.  Returns
-        the step and the draft's proposals (None without a draft)."""
+        sequence whose quota this step fills leaves its slot."""
         ahead = self._inflight
         with self._phase("upload"):
             # copies: the slots' state moves on while the step runs, and
@@ -1435,32 +1339,11 @@ class ContinuousBatcher:
             pt = jnp.asarray(ptH)
             pos = jnp.asarray(posH)
             startA = jnp.asarray(startH)
-            if self.draft is not None:
-                dptH = self.draftPool.pageTable.copy()
-                for s in deferred:
-                    dptH[s, :] = 0
-                tokA, dpt = jnp.asarray(tokH), jnp.asarray(dptH)
-            else:
-                tokA = jnp.asarray(tokH[:, None])
+            tokA = jnp.asarray(tokH[:, None])
         step = self._stepFns["step"]
         with self._phase("dispatch"):
             prev = self._noPrev if ahead is None else ahead.greedy
             starved = self._starved()
-            if self.draft is not None:
-                props, *self.draftPool.arrays = \
-                    self._stepFns["propose"](
-                        self.draft.params, *self.draftPool.arrays,
-                        tokA, dpt, pos, startA)
-                self._fed(starved, props)
-                # jaxlint: sync-ok -- proposals route through the host to form the verify batch (accept rule is host-side)
-                propsH = np.asarray(props)
-                self._drainedAt = time.perf_counter()   # all it had
-                tokA = jnp.asarray(np.concatenate([tokH[:, None], propsH],
-                                                  axis=1))
-                del props, dpt
-                starved = self._starved()
-            else:
-                propsH = None
             greedy, *self.pool.arrays = step(
                 self.lm.params, *self.pool.arrays, tokA, prev, pt, pos,
                 startA)
@@ -1485,20 +1368,19 @@ class ContinuousBatcher:
                     # prefill write behind this step's K/V row); the
                     # token finds the sequence by the flight's record
                     self._retireSlot(s, parting=True)
-            return flight, propsH
+            return flight
 
-    def _land(self, flight: _Flight, propsH) -> None:
+    def _land(self, flight: _Flight) -> None:
         """Read a dispatched step's tokens, deliver them, do the books."""
         with self._phase("fetch"):
             # jaxlint: sync-ok -- greedy tokens ARE the response payload (streamed per step)
             g = np.asarray(flight.greedy)
             if flight.greedy is self._given:
                 # nothing was dispatched behind this step (the last
-                # before the loop waits; every step under a draft): the
-                # device ran out of work here
+                # before the loop waits): the device ran out of work here
                 self._drainedAt = time.perf_counter()
         with self._phase("emit"):
-            self._emitStep(flight, g, propsH)
+            self._emitStep(flight, g)
         with self._phase("bookkeep"):
             flight.greedy = None        # freed inside a phase, as above
             sm = serving_metrics()
@@ -1542,7 +1424,6 @@ class ContinuousBatcher:
                                                        stage="decode")
                 self._retireSlot(s, error=DeadlineExceeded(
                     "end-to-end deadline expired mid-decode"))
-        tq = self.draftK + 1 if self.draft is not None else 1
         # page growth in ADMISSION-AGE order: a slot may only preempt
         # YOUNGER slots, and when none are left it DEFERS one step
         # instead — the oldest sequence therefore always progresses and
@@ -1552,10 +1433,7 @@ class ContinuousBatcher:
         for s in list(self._admitOrder):
             if self._slotSeq[s] is None:
                 continue
-            need = int(self._pos[s]) + tq
-            while not (self.pool.ensure(s, need) and
-                       (self.draftPool is None or
-                        self.draftPool.ensure(s, need))):
+            while not self.pool.ensure(s, int(self._pos[s]) + 1):
                 order = list(self._admitOrder)
                 younger = order[order.index(s) + 1:]
                 victim = next((v for v in reversed(younger)
@@ -1568,9 +1446,9 @@ class ContinuousBatcher:
                   if s is not None and i not in deferred]
         return active, deferred
 
-    def _emitStep(self, flight: _Flight, g, propsH) -> None:
-        """Deliver one step's tokens, slot by slot: accept rule, emission,
-        slot state, timeline note, retirement."""
+    def _emitStep(self, flight: _Flight, g) -> None:
+        """Deliver one step's tokens, slot by slot: emission, slot state,
+        timeline note, retirement."""
         sm = serving_metrics()
         for s in flight.slots:
             seq = flight.seqs[s]
@@ -1583,35 +1461,17 @@ class ContinuousBatcher:
             if seq.cancelled:
                 self._retire(s, seq)
                 continue
-            remForced = len(seq.forced) - len(seq.emitted)
-            if propsH is not None and remForced <= 0:
-                a = 0
-                while a < self.draftK and propsH[s, a] == g[s, a]:
-                    a += 1
-                newToks = g[s, :a + 1]
-                sm.draft_proposed().inc(self.draftK, model=self.name)
-                sm.draft_accepted().inc(a, model=self.name)
-            else:
-                newToks = g[s, :1]
-            if remForced > 0:
+            if len(seq.emitted) < len(seq.forced):
                 # teacher-forced replay: override the computed token
                 # with the one the sequence already produced before the
-                # move.  Capped to ONE token per step even in
-                # speculative mode — the unaccepted proposals' KV
-                # writes get overwritten by the existing partial-accept
-                # semantics, exactly as on a short accept.
-                # jaxlint: sync-ok -- forced tokens are host-side replay state, never device values
-                newToks = np.asarray(
-                    [int(seq.forced[len(seq.emitted)])], np.int32)
-            done = False
-            for t in newToks:
-                # jaxlint: disable=host-sync -- newToks is the already-materialized host copy of this step's greedy tokens
-                done = self._emit(seq, int(t))
-                if done:
-                    break
+                # move
+                tok = int(seq.forced[len(seq.emitted)])
+            else:
+                # jaxlint: disable=host-sync -- g is the already-materialized host copy of this step's greedy tokens
+                tok = int(g[s, 0])
+            done = self._emit(seq, tok)
             if held:
-                self._pos[s] += len(newToks) - 1    # one went at dispatch
-                self._tok[s] = int(newToks[-1])
+                self._tok[s] = tok
             timeline_store().note(
                 seq.ctx.traceId if seq.ctx is not None else None,
                 "serving.decode.step", replica=self.name, slot=s,
@@ -1768,8 +1628,6 @@ class ContinuousBatcher:
         returns it and the number of pages freed."""
         seq = self._slotSeq[slot]
         freed = self.pool.release(slot)
-        if self.draftPool is not None:
-            freed += self.draftPool.release(slot)
         self._slotSeq[slot] = None
         self._pos[slot] = self._start[slot] = self._tok[slot] = 0
         if slot in self._admitOrder:
@@ -1815,11 +1673,6 @@ class ContinuousBatcher:
             sm.index_rows_bytes().set(
                 self.pool.usedPages() * self.pool.indexPageBytes,
                 model=self.name)
-        if self.draftPool is not None:
-            sm.kv_pages_in_use().set(self.draftPool.usedPages(),
-                                     model=self.name, pool="draft")
-            sm.kv_pages_free().set(self.draftPool.freePages(),
-                                   model=self.name, pool="draft")
 
 
 class _ReplicaQueueDepthRule(ThresholdRule):

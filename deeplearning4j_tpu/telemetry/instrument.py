@@ -412,7 +412,7 @@ class ServingMetrics:
         return get_registry().gauge(
             "dl4j_tpu_serving_kv_pages_in_use",
             "KV-cache pages currently allocated to admitted sequences, "
-            "per model and pool (target / draft)",
+            "per model and pool (a batcher has one, \"target\")",
             labelnames=("model", "pool"))
 
     def paged_attention_kernel(self):
@@ -552,8 +552,7 @@ class ServingMetrics:
             "Decode steps dispatched while the step before was still "
             "unread on the device, so that the device ran while the host "
             "emitted and prepared (over decode_steps_total: the share of "
-            "steps for which the loop was one step ahead; 0 with a draft "
-            "model)",
+            "steps for which the loop was one step ahead)",
             labelnames=("model",))
 
     def decode_tokens_discarded(self):
@@ -562,20 +561,6 @@ class ServingMetrics:
             "Tokens computed for a sequence that had left its slot by "
             "the time they were read (EOS, cancel, deadline or preemption "
             "learnt one step late): the price of running one step ahead",
-            labelnames=("model",))
-
-    def draft_proposed(self):
-        return get_registry().counter(
-            "dl4j_tpu_serving_draft_tokens_proposed_total",
-            "Tokens proposed by the speculative-decode draft model, "
-            "per slot-round",
-            labelnames=("model",))
-
-    def draft_accepted(self):
-        return get_registry().counter(
-            "dl4j_tpu_serving_draft_tokens_accepted_total",
-            "Draft proposals accepted by the target model's verify "
-            "forward (accept rate = accepted / proposed)",
             labelnames=("model",))
 
     def replicas(self):
@@ -747,11 +732,9 @@ class ServingMetrics:
             "token), grow (deadline sweep, page growth, preemption), "
             "upload (host slot state to device arrays), dispatch (the "
             "step executable's call until it returns), fetch (the wait for "
-            "the step dispatched an iteration earlier, or with a draft "
-            "model the one just dispatched, and its tokens' D2H: what is "
-            "left of the device step once the other phases ran beside "
-            "it), emit "
-            "(accept rule, delivery, timeline, retire), bookkeep "
+            "the step dispatched an iteration earlier and its tokens' "
+            "D2H: what is left of the device step once the other phases "
+            "ran beside it), emit (delivery, timeline, retire), bookkeep "
             "(counters, gauges, compile-cache size); one observation a "
             "phase a loop iteration, per model",
             labelnames=("model", "phase"),
@@ -773,8 +756,7 @@ class ServingMetrics:
             "returned reads the device's gap FROM BELOW: it leaves out "
             "that read's D2H and wake-up at its front and the launch "
             "behind the dispatch call at its back (together 1-2 ms on a "
-            "TPU v5e); so every wait and admit stretch, and loop where "
-            "each step is read before the next (a draft model).  A loop "
+            "TPU v5e); so every wait and admit stretch.  A loop "
             "stretch that is_ready() alone found, at a dispatch with the "
             "step before unread, is an UPPER BOUND: the time since the "
             "dispatch before returned, of which the device worked one "

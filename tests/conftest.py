@@ -79,7 +79,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "cbatch: iteration-level continuous-batching tests "
         "(paged KV pool, admit/retire scheduler, token streaming, "
-        "speculative decode bit-identity, replica fan-out)")
+        "replica fan-out)")
     config.addinivalue_line(
         "markers", "recsys: recommender-tier tests (sharded embedding "
         "tables, two-phase dedup'd sparse lookup, ragged ingestion "

@@ -1,7 +1,7 @@
 """Iteration-level continuous batching (ISSUE 15): paged KV pool,
-admit/retire scheduler invariants, token streaming, speculative-decode
-bit-identity, KV-headroom admission, replica fan-out (TP + DP) and the
-queue-depth autoscale remediation."""
+admit/retire scheduler invariants, token streaming, KV-headroom
+admission, replica fan-out (TP + DP) and the queue-depth autoscale
+remediation."""
 import json
 import threading
 import time
@@ -45,7 +45,7 @@ def _token_major(hist, ps):
 
 @pytest.mark.parametrize("tq,layers,pos,start", [
     (1, 1, 7, 2),       # the plain decode step
-    (3, 2, 6, 1),       # a speculative verify: writes 6, 7 | 8 cross a page
+    (3, 2, 6, 1),       # several rows a slot: writes 6, 7 | 8 cross a page
 ])
 def test_paged_attention_matches_masked_softmax(tq, layers, pos, start):
     """The pooled page-table lookup is plain softmax attention over the
@@ -299,7 +299,7 @@ def test_paged_kernel_depends_on_a_slots_logical_content_alone(how, rep):
     """Bit for bit: two slots swapped give swapped results; the same rows
     held by other physical pages give the same result; and what another
     slot holds (its length, its rows) changes nothing — what preemption's
-    replay and speculative decoding's token identity rest on.  With two
+    replay rests on.  With two
     query heads a KV head as with one."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.conf import attention as A
@@ -437,15 +437,14 @@ def test_prefill_write_then_paged_step_equals_forward_logits():
     row for row — ``chip_smoke.py``'s own check, as it runs on the chip."""
     import chip_smoke
     import jax.numpy as jnp
-    from deeplearning4j_tpu.nn.conf.attention import paged_prefill_write
     from deeplearning4j_tpu.remote import KVCachePool
     L, h, d, ps, ids = 2, 2, 4, 4, [3, 1]
     stack = np.random.RandomState(2).randn(L, h, 2 * ps, d).astype(np.float32)
     pool = KVCachePool(L, h, d, ps, numPages=5, maxSlots=2, maxPagesPerSeq=4)
     assert pool.k.shape == pool.v.shape == (L, 5, ps, h * d)
-    pk, pv = paged_prefill_write(pool.k, pool.v, jnp.asarray(stack),
-                                 jnp.asarray(-stack),
-                                 jnp.asarray(ids, jnp.int32))
+    pk, pv = _lm(layers=L).buildPagedPrefillWriteFn()(
+        pool.k, pool.v, jnp.asarray(stack), jnp.asarray(-stack),
+        jnp.asarray(ids, jnp.int32))
     want = np.zeros(pool.k.shape, np.float32)
     for t in range(2 * ps):
         want[:, ids[t // ps], t % ps] = stack[:, :, t].reshape(L, h * d)
@@ -912,60 +911,6 @@ def test_invalidate_drops_every_jit_and_warm_rebuilds_the_served_set(kind):
     assert cb.compileCacheSize() == first
 
 
-# ------------------------------------------------ speculative decode ----
-
-def test_speculative_decode_bit_identical_to_greedy():
-    """Accept-prefix speculative decode == target-only greedy, exactly,
-    through the continuous batcher (paged pools, per-slot accept
-    lengths), with a zero-tail draft that accepts everything."""
-    import jax.numpy as jnp
-    from deeplearning4j_tpu.remote import BucketLadder
-    target = _lm(layers=2, seed=7)
-    draft = _lm(layers=1, seed=9)
-    rng = np.random.RandomState(0)
-    # zero-tail: target's second layer contributes nothing and the
-    # draft IS its first layer: logits identical => acceptance is total
-    lp = target.params["layers"][1]
-    lp["Wo"] = jnp.zeros_like(lp["Wo"])
-    lp["Wp"] = jnp.zeros_like(lp["Wp"])
-    lp["bp"] = jnp.zeros_like(lp["bp"])
-    draft.params = {"emb": target.params["emb"],
-                    "pos": target.params["pos"],
-                    "lnf_g": target.params["lnf_g"],
-                    "lnf_b": target.params["lnf_b"],
-                    "layers": [target.params["layers"][0]]}
-    # continuous batcher with the draft: concurrent ragged requests,
-    # per-slot accept lengths, still bit-identical
-    cb = ContinuousBatcher(target, name="cb-spec", draft=draft, draftK=3,
-                           pageSize=8, maxSlots=2,
-                           ladder=BucketLadder(batchSizes=(2,),
-                                               seqLens=(16,))).start()
-    try:
-        prompts = [rng.randint(1, 40, (1, int(rng.randint(3, 15)))
-                               ).astype(np.int32) for _ in range(3)]
-        outs = [None] * 3
-        ths = [threading.Thread(target=lambda i=i: outs.__setitem__(
-            i, cb.submit({"tokens": prompts[i][0].tolist(),
-                          "maxNewTokens": 8}, timeout=120)))
-            for i in range(3)]
-        for th in ths:
-            th.start()
-        for th in ths:
-            th.join(timeout=120)
-        for i in range(3):
-            np.testing.assert_array_equal(outs[i],
-                                          target.generate(prompts[i], 8))
-        sm = serving_metrics()
-        assert sm.draft_proposed().value(model="cb-spec") > 0
-        # the accept rule needs every step's tokens before the next can
-        # be formed: with a draft the loop is never a step ahead
-        assert sm.decode_steps().value(model="cb-spec") > 0
-        assert sm.decode_steps_overlapped().value(model="cb-spec") == 0
-        assert sm.decode_tokens_discarded().value(model="cb-spec") == 0
-    finally:
-        cb.shutdown()
-
-
 # --------------------------------- admission + enqueue-time rejection ----
 
 def test_kv_headroom_sheds_and_enqueue_rejects():
@@ -1053,13 +998,11 @@ def test_enqueue_rejection_is_offender_only():
         fs.makeRequest(np.zeros((0, 4), np.float32))
 
 
-def test_step_failure_recovers_and_draft_bounds_capacity():
+def test_step_failure_recovers_and_timeout_reaps():
     """A dispatch failure mid-step errors the affected sequences and the
     scheduler thread SURVIVES (pools rebuilt — the failed call may have
-    consumed the donated buffers — and re-warmed); a draft with a
-    smaller cache bounds admissible requests at enqueue time; a
-    timed-out submit reaps its queued rows instead of leaving phantom
-    backlog."""
+    consumed the donated buffers — and re-warmed); a timed-out submit
+    reaps its queued rows instead of leaving phantom backlog."""
     from deeplearning4j_tpu.remote import BucketLadder
     lm = _lm(layers=1)
     cb = ContinuousBatcher(lm, name="cb-fail", pageSize=8, maxSlots=2,
@@ -1095,14 +1038,6 @@ def test_step_failure_recovers_and_draft_bounds_capacity():
         assert cb.queuedRows() == 0
     finally:
         cb.shutdown()
-    # draft with a smaller cache: ladder and admission bound by it
-    draft = _lm(layers=1, maxLen=32, seed=9)
-    cb2 = ContinuousBatcher(_lm(layers=1, maxLen=128), name="cb-cap",
-                            draft=draft, draftK=2, pageSize=8,
-                            maxSlots=2)
-    assert max(cb2.ladder.seqLens) < 32
-    with pytest.raises(ValueError, match="draft"):
-        cb2._makeSeqs({"tokens": [1, 2, 3], "maxNewTokens": 25})
 
 
 # ----------------------------------------------- replica fan-out ------

@@ -969,6 +969,33 @@ class TestDonationUseAfter:
         # the finding anchors in loop_bad's handler, not loop_good
         assert "self._failBatchBad(e)" in f.context
 
+    def test_a_run_of_donated_positions_reaches_a_starred_argument(
+            self, tmp_path):
+        # ServedLM's builders donate ``tuple(range(1, 1 + n))``: the
+        # run's literal start is what the starred call site needs
+        res = lint(tmp_path, {"m.py": """
+            import jax
+            class Served:
+                def buildPagedDecodeFn(self):
+                    n = len(self.kinds)
+                    def step(params, *args):
+                        return args[:n]
+                    return jax.jit(step,
+                                   donate_argnums=tuple(range(1, 1 + n)))
+            class Batcher:
+                def __init__(self, lm):
+                    self.stepFn = lm.buildPagedDecodeFn()
+                def loop(self, params, tok):
+                    try:
+                        out, *self.arrays = self.stepFn(
+                            params, *self.arrays, tok)
+                    except Exception:
+                        return self.arrays
+                    return params
+        """}, rules=["donation-use-after"])
+        assert rule_ids(res) == ["donation-use-after"]
+        assert "self.arrays" in res.findings[0].message
+
     def test_aotdispatch_wrapper_preserves_donation(self, tmp_path):
         res = lint(tmp_path, {"m.py": """
             import jax

@@ -215,14 +215,12 @@ def test_capture_closes_a_span_left_by_an_exception(capture):
 
 # ----------------------------------------------- the decode loop's phases --
 
-@pytest.mark.parametrize("spec", [False, True], ids=["plain", "draft"])
-def test_every_phase_once_a_step_and_the_loop_is_covered(spec):
+def test_every_phase_once_a_step_and_the_loop_is_covered():
     quota = 25
-    kw = dict(draft=_lm(seed=9, vocab=512), draftK=2) if spec else {}
     # wide enough that a step takes milliseconds on the CPU: what lies
     # between two phases (a span's own bookkeeping) is some 20 us
     cb = ContinuousBatcher(_lm(layers=4, vocab=512, heads=4, headSize=32),
-                           name="ph", maxSlots=2, pageSize=8, **kw).start()
+                           name="ph", maxSlots=2, pageSize=8).start()
     try:
         _generate(cb, quota)
         # the stream has ended, so its last step was read: the loop goes
@@ -235,17 +233,11 @@ def test_every_phase_once_a_step_and_the_loop_is_covered(spec):
     ahead = count("dl4j_tpu_serving_decode_steps_overlapped_total")
     assert count("dl4j_tpu_serving_decode_tokens_discarded_total") == 0
     cells = _phase_cells("ph")
-    if spec:
-        # a draft's accept rule needs each step's tokens before the next
-        # can be formed: dispatched and read in one iteration, as ever
-        assert steps >= 1 and ahead == 0
-        grows = steps
-    else:
-        # one stream that never waits: every step but the first was
-        # dispatched while the one before it was unread, and the last is
-        # read by an iteration that grows nothing and dispatches nothing
-        assert steps == quota - 1 and ahead == steps - 1
-        grows = steps + 1
+    # one stream that never waits: every step but the first was
+    # dispatched while the one before it was unread, and the last is
+    # read by an iteration that grows nothing and dispatches nothing
+    assert steps == quota - 1 and ahead == steps - 1
+    grows = steps + 1
     for p in STEP_PHASES:
         want = grows if p == "grow" else steps
         assert cells[p][0] == want, (p, cells[p], want)
@@ -302,14 +294,9 @@ def test_an_idle_batcher_accrues_wait_and_nothing_else():
 
 # ------------------------------------------------------ the drain clock --
 
-DRAFT = pytest.mark.parametrize("spec", [False, True],
-                                ids=["plain", "draft"])
-
-
-def _batcher(name, spec, slots=2):
-    kw = dict(draft=_lm(seed=9), draftK=2) if spec else {}
-    return ContinuousBatcher(_lm(), name=name, maxSlots=slots, pageSize=8,
-                             **kw).start()
+def _batcher(name, slots=2):
+    return ContinuousBatcher(_lm(), name=name, maxSlots=slots,
+                             pageSize=8).start()
 
 
 def _idle_events(cause=None):
@@ -344,10 +331,9 @@ def slowdown():
     injection.clear_serving_faults()
 
 
-@DRAFT
-def test_an_idle_stretch_before_a_request_is_booked_to_wait(spec):
+def test_an_idle_stretch_before_a_request_is_booked_to_wait():
     t0 = time.perf_counter()
-    cb = _batcher("w", spec)
+    cb = _batcher("w")
     try:
         time.sleep(0.3)
         # one token: the prefill is the only dispatch, and the stretch it
@@ -377,10 +363,8 @@ def test_an_idle_stretch_before_a_request_is_booked_to_wait(spec):
     assert not _stalls("device.idle.")
 
 
-@DRAFT
-def test_an_admission_books_one_stretch_to_admit(spec,
-                                                 every_stretch_an_event):
-    cb = _batcher("a", spec)
+def test_an_admission_books_one_stretch_to_admit(every_stretch_an_event):
+    cb = _batcher("a")
     per_client, quota = 3, 6
 
     def client():
@@ -422,10 +406,9 @@ def test_an_admission_books_one_stretch_to_admit(spec,
             closes, opened[0]["ts"] + opened[0]["dur"])
 
 
-@DRAFT
-def test_a_slow_loop_is_booked_to_loop_and_not_to_admit(spec, slowdown):
+def test_a_slow_loop_is_booked_to_loop_and_not_to_admit(slowdown):
     delay, quota = 0.03, 9
-    cb = _batcher("s", spec)
+    cb = _batcher("s")
     try:
         slowdown("s", delay)
         t0 = time.perf_counter()
@@ -438,21 +421,19 @@ def test_a_slow_loop_is_booked_to_loop_and_not_to_admit(spec, slowdown):
     cells = _idle_cells("s")
     # the first step's dispatch ends the admission's stretch, which holds
     # one delay; every later one finds the device idle for a delay and
-    # the loop's own work (with a draft: each step is read before the
-    # next, so the stretch is exact; without: is_ready() found it, and
-    # the stretch since the dispatch before is a bound)
+    # the loop's own work (is_ready() found it, and the stretch since
+    # the dispatch before is a bound)
     assert cells["admit"][0] == 1 and cells["admit"][1] < 2 * delay + 0.1
     count, total = cells["loop"]
     assert steps - 1 <= count <= 2 * steps
     assert 0.7 * delay * (steps - 1) <= total <= elapsed
     bounds = {e["args"]["bound"] for e in _idle_events("loop")}
-    assert bounds == ({False} if spec else {True})
+    assert bounds == {True}
 
 
-@DRAFT
 def test_idle_stretches_are_disjoint_and_inside_the_wall_time(
-        spec, every_stretch_an_event, slowdown):
-    cb = _batcher("d", spec)
+        every_stretch_an_event, slowdown):
+    cb = _batcher("d")
     try:
         _generate(cb, 5)
         time.sleep(0.15)
@@ -478,7 +459,7 @@ def test_idle_stretches_are_disjoint_and_inside_the_wall_time(
 
 
 def test_a_stall_leaves_one_instant_with_what_it_coincided_with(slowdown):
-    cb = _batcher("st", False)
+    cb = _batcher("st")
     try:
         time.sleep(0.15)                # a wait of 0.1 s or more: no stall
         slowdown("st", 0.12)

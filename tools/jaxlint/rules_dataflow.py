@@ -64,6 +64,15 @@ class Donation:
 def _int_values(node: ast.AST) -> List[int]:
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
         return [node.value]
+    if isinstance(node, ast.Call) and dotted(node.func) in ("tuple", "range") \
+            and node.args:
+        # ``tuple(range(1, 1 + n))``: a run of positions whose end only
+        # the run time knows.  Its literal start is enough: the call that
+        # feeds such a run spreads a starred argument over it, which is
+        # donated when any donated position lies at or past the star
+        if dotted(node.func) == "tuple":
+            return _int_values(node.args[0])
+        return _int_values(node.args[0])[:1] if len(node.args) > 1 else [0]
     if isinstance(node, (ast.Tuple, ast.List)):
         return [e.value for e in node.elts
                 if isinstance(e, ast.Constant) and
