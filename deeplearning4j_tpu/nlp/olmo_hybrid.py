@@ -19,13 +19,13 @@ What the model keeps between decode steps is TWO kinds of state, named by
   inputs of its three depthwise convolutions; overwritten every step, in
   place.
 
-The delta rule has two forms here.  The decode step runs the recurrence
-itself, one token a slot: ``S' = a S; u = b (v - S'^T k); S = S' + k
-u^T; o = S^T q``.  Forward and prefill run its CHUNKED form
-(:func:`delta_rule_chunked`): within a chunk of ``C`` positions the
-rank-one updates are folded into matmuls by the UT transform, and only
-the ``(H, dk, dv)`` state is carried from chunk to chunk, so a prompt of
-4,096 positions is 64 steps of a scan and not 4,096.
+The delta rule, with ONE decay a head, is :mod:`~deeplearning4j_tpu.nlp
+.delta`'s, which ``LingLM`` calls too with a decay a channel.  The decode
+step runs the recurrence itself, one token a slot
+(:func:`~deeplearning4j_tpu.nlp.delta.delta_rule_step`): ``S' = a S; u =
+b (v - S'^T k); S = S' + k u^T; o = S^T q``.  Forward and prefill run its
+CHUNKED form (:func:`~deeplearning4j_tpu.nlp.delta.delta_rule_chunked`),
+so a prompt of 4,096 positions is 64 steps of a scan and not 4,096.
 
 Precision: weights, residual stream and K/V in the parameters' dtype
 (bfloat16 as served); the delta state, ``alpha``/``beta``, the q/k
@@ -41,19 +41,20 @@ from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_attention)
+from deeplearning4j_tpu.nlp.delta import (delta_rule_chunked,
+                                          delta_rule_step, l2_normalise,
+                                          short_conv_full, short_conv_step)
 from deeplearning4j_tpu.nlp.mamba import _mm, _rms
 from deeplearning4j_tpu.nlp.served import (JitByLength, ServedLM,
                                            attend_full)
 
-__all__ = ["OlmoHybridConfig", "OlmoHybridLM", "delta_rule_chunked"]
+__all__ = ["OlmoHybridConfig", "OlmoHybridLM"]
 
 _F32 = jnp.float32
 _I32 = jnp.int32
-_HI = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass
@@ -87,97 +88,6 @@ class OlmoHybridConfig:
     def layerKinds(self) -> List[str]:
         return ["full" if i % self.fullEvery == self.fullEvery - 1
                 else "linear" for i in range(self.nLayers)]
-
-
-def _l2(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
-def _inv_unit_lower(A):
-    """``(I + A)^-1`` for strictly lower-triangular ``A (..., C, C)``,
-    ``C`` a power of two, by doubling: with ``X`` the inverse of the
-    diagonal blocks of size ``s``, the blocks of size ``2 s`` have the
-    inverse ``[[X1, 0], [-X2 A21 X1, X2]]``, which is ``X - X A_off X``
-    for ``A_off`` the ``A21`` corners alone.  ``log2 C`` rounds of two
-    matmuls, no substitution row by row, and exact up to rounding (no
-    power of ``A`` is ever formed)."""
-    C = A.shape[-1]
-    if C & (C - 1):
-        raise ValueError(f"the chunk {C} is no power of two")
-    i = np.arange(C)
-    X = jnp.broadcast_to(jnp.eye(C, dtype=A.dtype), A.shape)
-    s = 1
-    while s < C:
-        corner = (i[:, None] // (2 * s) == i[None, :] // (2 * s)) \
-            & (i[:, None] % (2 * s) >= s) & (i[None, :] % (2 * s) < s)
-        off = jnp.where(corner, A, 0)
-        X = X - jnp.matmul(jnp.matmul(X, off, precision=_HI), X,
-                           precision=_HI)
-        s *= 2
-    return X
-
-
-def delta_rule_chunked(q, k, v, beta, g, chunk: int):
-    """The gated delta rule over whole sequences, chunk by chunk.
-
-    ``q, k (b, T, H, dk)``, ``v (b, T, H, dv)``, ``beta (b, T, H)`` and
-    ``g = log alpha (b, T, H)``, all float32; the state starts at zero.
-    Returns ``(o (b, T, H, dv), S_T (b, H, dk, dv))`` of the recurrence
-    ``S' = alpha_t S; u_t = beta_t (v_t - S'^T k_t); S = S' + k_t u_t^T;
-    o_t = S^T q_t``.
-
-    Within a chunk, with ``G_i`` the running sum of ``g`` from the
-    chunk's start, ``gamma = exp(G)`` and ``Gam_ij = exp(G_i - G_j)``
-    (taken in log space: no division by a ``gamma`` that has underflowed),
-    the ``u`` of the chunk solve ``(I + A) u = diag(beta) (V - diag(gamma)
-    K S)`` with ``A = strictly-lower(diag(beta) (K K^T * Gam))`` and ``S``
-    the state the chunk starts from.  So with ``T = (I + A)^-1
-    diag(beta)``, ``W = T diag(gamma) K`` and ``U = T V`` (no ``S`` in
-    them: computed for every chunk at once), the scan over chunks is
-    ``u = U - W S; o = diag(gamma) Q S + (Q K^T * Gam * lower) u; S <-
-    gamma_C S + (diag(gamma_C / gamma) K)^T u``.
-
-    A length that is no multiple of the chunk is padded on the right with
-    positions that change nothing (``beta`` 0, ``alpha`` 1, ``k`` 0)."""
-    b, T, H, dk = q.shape
-    dv = v.shape[-1]
-    C = int(chunk)
-    pad = -T % C
-    if pad:
-        z = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-        q, k, v, beta, g = z(q), z(k), z(v), z(beta), z(g)
-    n = (T + pad) // C
-    # chunk-major, heads before rows: (n, b, H, C, ...)
-    rows = lambda a: a.reshape(b, n, C, H, -1).transpose(1, 0, 3, 2, 4)
-    q, k, v = rows(q), rows(k), rows(v)
-    beta, g = rows(beta)[..., 0], rows(g)[..., 0]            # (n, b, H, C)
-    G = jnp.cumsum(g, axis=-1)
-    i = np.arange(C)
-    lower = i[:, None] >= i[None, :]
-    Gam = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :],
-                            -jnp.inf))                       # 0 above
-    mm = functools.partial(jnp.matmul, precision=_HI)
-    kT = jnp.swapaxes(k, -1, -2)
-    A = jnp.where(i[:, None] > i[None, :],
-                  beta[..., None] * mm(k, kT) * Gam, 0)
-    Tm = _inv_unit_lower(A) * beta[..., None, :]
-    gamma = jnp.exp(G)[..., None]
-    W = mm(Tm, gamma * k)
-    U = mm(Tm, v)
-    M = mm(q, kT) * Gam
-    Qg = gamma * q
-    KhT = jnp.swapaxes(jnp.exp(G[..., -1:] - G)[..., None] * k, -1, -2)
-    gC = jnp.exp(G[..., -1])[..., None, None]                # (n, b, H, 1, 1)
-
-    def body(S, xs):
-        W, U, M, Qg, KhT, gC = xs
-        u = U - mm(W, S)
-        o = mm(Qg, S) + mm(M, u)
-        return gC * S + mm(KhT, u), o
-    S, o = jax.lax.scan(body, jnp.zeros((b, H, dk, dv), _F32),
-                        (W, U, M, Qg, KhT, gC))
-    o = o.transpose(1, 0, 3, 2, 4).reshape(b, n * C, H, dv)
-    return o[:, :T], S
 
 
 class OlmoHybridLM(ServedLM):
@@ -281,7 +191,8 @@ class OlmoHybridLM(ServedLM):
         c = self.config
         H, dk = c.linHeads, c.linKeyDim
         split = lambda a: jax.nn.silu(a).reshape(a.shape[:-1] + (H, -1))
-        return _l2(split(q)) * dk ** -0.5, _l2(split(k)), split(v)
+        return (l2_normalise(split(q)) * dk ** -0.5,
+                l2_normalise(split(k)), split(v))
 
     def _gdn_out(self, lp, o, h):
         """``(RMSNorm_dv(o) * g_norm * silu(h Wg)) Wo``."""
@@ -315,7 +226,6 @@ class OlmoHybridLM(ServedLM):
         ``alpha`` 1, and no key is valid there."""
         c = self.config
         b, T = tokens.shape
-        K = c.convKernel
         realF = (jnp.arange(T, dtype=_I32)[None, :] >= start[:, None]
                  ).astype(_F32)[..., None]                   # (b, T, 1)
         x = params["emb"][tokens]
@@ -337,13 +247,8 @@ class OlmoHybridLM(ServedLM):
             if kind == "linear":
                 # q, k and v one after the other (side by side they are
                 # 189 MB in float32 at 4,096 positions, three times over)
-                def convolved(w, taps):
-                    u = _mm(x, lp[w]) * realF
-                    up = jnp.concatenate(
-                        [jnp.zeros((b, K - 1, u.shape[-1]), _F32), u], axis=1)
-                    t = lp[taps].astype(_F32)
-                    return (sum(t[j] * up[:, j:j + T] for j in range(K)),
-                            u[:, T - (K - 1):])
+                convolved = lambda w, taps: short_conv_full(
+                    _mm(x, lp[w]) * realF, lp[taps])
                 (q, tq), (k, tk), (v, tv) = (
                     convolved("Wq", "convQ"), convolved("Wk", "convK"),
                     convolved("Wv", "convV"))
@@ -425,24 +330,17 @@ class OlmoHybridLM(ServedLM):
             if kind == "linear":
                 qkv = jnp.concatenate([_mm(x, lp["Wq"]), _mm(x, lp["Wk"]),
                                        _mm(x, lp["Wv"])], axis=-1)
-                win = jnp.concatenate(
-                    [conv[li].astype(_F32), qkv[:, None]], axis=1)
-                conv = conv.at[li].set(keep(win[:, 1:].astype(conv.dtype),
-                                            conv[li]))
                 taps = jnp.concatenate(
                     [lp["convQ"], lp["convK"], lp["convV"]], axis=-1)
-                u = jnp.sum(win * taps.astype(_F32)[None], axis=1)
+                u, win = short_conv_step(conv[li], qkv, taps)
+                conv = conv.at[li].set(keep(win.astype(conv.dtype),
+                                            conv[li]))
                 nk = c.linHeads * c.linKeyDim
                 q, kk, vv = self._heads(u[:, :nk], u[:, nk:2 * nk],
                                         u[:, 2 * nk:])
                 beta, g = self._gates(lp, x)
-                # the recurrence itself, in float32 on the VPU (a matmul
-                # would round the state to bfloat16 on its way in)
-                Sd = jnp.exp(g)[..., None, None] * delta[li]  # (S, H, dk, dv)
-                u = beta[..., None] * (
-                    vv - jnp.sum(Sd * kk[..., None], axis=2))
-                Sd = Sd + kk[..., None] * u[..., None, :]
-                o = jnp.sum(Sd * q[..., None], axis=2)
+                Sd, o = delta_rule_step(delta[li], q, kk, vv, beta,
+                                        jnp.exp(g)[..., None, None])
                 delta = delta.at[li].set(keep(Sd, delta[li]))
                 out = self._gdn_out(lp, o, x)
                 li += 1

@@ -20,7 +20,10 @@ what a position keeps is one LATENT ROW ``[c_kv | k_r]`` (after norm and
 rotation), from which keys and values both come: the model's
 ``cacheSpec()`` names it, the scheduler's pool holds one array of them,
 and :func:`~deeplearning4j_tpu.nn.conf.attention.paged_latent_attention`
-reads it (on one TPU the kernel over the live pages).
+reads it (on one TPU the kernel over the live pages).  Everything behind
+the queries (the row, both forms, the flash kernel for long prefills) is
+:class:`~deeplearning4j_tpu.nlp.latent.LatentAttention`'s, which
+``LingLM`` inherits too; the compressed query is this model's own.
 
 *Rotary positions* pair lane ``i`` with lane ``i + rope / 2`` and turn
 the pair by ``pos * theta^(-2 i / rope)``; a token's position is its
@@ -55,12 +58,10 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
-                                                  paged_latent_attention)
+from deeplearning4j_tpu.nn.conf.attention import CacheSpec
+from deeplearning4j_tpu.nlp.latent import LatentAttention
 from deeplearning4j_tpu.nlp.mamba import _mm, _rms
-from deeplearning4j_tpu.nlp.served import JitByLength, ServedLM, _rope
-from deeplearning4j_tpu.parallel.ring import (_FLASH_MIN_T, _flash_refusal,
-                                              flash_attention)
+from deeplearning4j_tpu.nlp.served import JitByLength, ServedLM
 from deeplearning4j_tpu.parallel.moe import (moe_share_counts,
                                              moe_share_grouped,
                                              moe_share_step,
@@ -70,10 +71,6 @@ __all__ = ["PanguMoEConfig", "PanguMoELM"]
 
 _F32 = jnp.float32
 _I32 = jnp.int32
-_NEG = -1e30
-#: queries a block of the full-sequence attention holds against every
-#: key: 128 heads of float32 scores over 4,096 keys are 0.54 GB a block
-_QUERY_BLOCK = 256
 _COUNTS = ("moe_pairs_routed", "moe_pairs_absent", "moe_experts_hit")
 
 
@@ -107,7 +104,7 @@ class PanguMoEConfig:
         return self.expertsHeld[1] - self.expertsHeld[0]
 
 
-class PanguMoELM(ServedLM):
+class PanguMoELM(LatentAttention, ServedLM):
     """The served model: ``forward`` (the recompute baseline), a bucketed
     left-padded prefill that also returns the latent rows and the
     routing's counts, and the step form ``pagedLogits``, from which
@@ -203,24 +200,8 @@ class PanguMoELM(ServedLM):
         c = self.config
         q = _mm(_rms(_mm(h, lp["Wdq"]), lp["qnorm"], c.eps), lp["Wuq"])
         q = q.reshape(q.shape[:-1] + (c.nHeads, c.nopeDim + c.ropeDim))
-        return q[..., :c.nopeDim], _rope(q[..., c.nopeDim:], p[..., None],
-                                         c.ropeTheta)
-
-    def _row_wide(self, a):
-        """``a (..., latent + rope)`` with zeros behind, to the width of a
-        stored row (whole lane tiles)."""
-        pad = self.cacheSpec().rowWidth - a.shape[-1]
-        return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, pad),))
-
-    def _latent_row(self, lp, h, p, dtype):
-        """The row a position keeps, as it is stored: ``[RMSNorm(c_kv) |
-        RoPE(k_r) | zeros to whole lane tiles]``."""
-        c = self.config
-        ckr = _mm(h, lp["Wdkv"])
-        return self._row_wide(jnp.concatenate(
-            [_rms(ckr[..., :c.kvRank], lp["kvnorm"], c.eps),
-             _rope(ckr[..., c.kvRank:], p, c.ropeTheta)], axis=-1)
-        ).astype(dtype)
+        return q[..., :c.nopeDim], self._rotate(q[..., c.nopeDim:],
+                                                p[..., None])
 
     def _ffn(self, lp, h, real, grouped: bool):
         """``(FFN(h), counts)`` for ``h (T, d)`` float32: the dense FFN,
@@ -248,69 +229,6 @@ class PanguMoELM(ServedLM):
     # ------------------------------------------------------------------
     # full-sequence form: forward and prefill (attention unabsorbed)
     # ------------------------------------------------------------------
-    def _attend_full(self, qn, qr, kn, kr, v, start):
-        """Causal softmax attention over whole sequences with every key
-        and value formed: ``qn (b, T, H, nope)``, ``qr (b, T, H, rope)``
-        float32; ``kn (b, T, H, nope)``, ``kr (b, T, rope)`` (one for all
-        heads), ``v (b, T, H, vDim)`` in the stream's dtype.  A block of
-        queries at a time against every key; no key before ``start`` is
-        valid."""
-        c = self.config
-        b, T = qn.shape[:2]
-        cd = v.dtype
-        if T >= _FLASH_MIN_T and _flash_refusal(T, T) is None:
-            return self._attend_flash(qn, qr, kn, kr, v, start)
-        B = _QUERY_BLOCK if T % _QUERY_BLOCK == 0 else T
-        qn, qr = qn.astype(cd), qr.astype(cd)
-        kpos = jnp.arange(T, dtype=_I32)[None, None, :]
-        real = kpos >= start[:, None, None]                  # (b, 1, T)
-        scale = (c.nopeDim + c.ropeDim) ** -0.5
-
-        def block(i):
-            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * B, B, axis=1)
-            s = jnp.einsum("bqhd,bkhd->bhqk", cut(qn), kn,
-                           preferred_element_type=_F32) \
-                + jnp.einsum("bqhd,bkd->bhqk", cut(qr), kr,
-                             preferred_element_type=_F32)
-            rows = i * B + jnp.arange(B, dtype=_I32)
-            valid = (kpos <= rows[None, :, None]) & real     # (b, B, T)
-            a = jax.nn.softmax(jnp.where(valid[:, None], s * scale, _NEG),
-                               axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", a.astype(cd), v,
-                              preferred_element_type=_F32)
-        o = jax.lax.map(block, jnp.arange(T // B, dtype=_I32))
-        return jnp.moveaxis(o, 0, 1).reshape(b, T, c.nHeads * c.vDim)
-
-    def _attend_flash(self, qn, qr, kn, kr, v, start, interpret=False):
-        """:meth:`_attend_full` through the flash kernel
-        (``parallel/ring.py``), which holds no score outside VMEM and
-        skips the blocks above the diagonal; chosen as the attention
-        layers choose it: on a TPU, from 1,024 positions, at lengths its
-        blocks divide.  The kernel is causal and takes no key mask, so
-        every sequence is turned until its real tokens come FIRST and its
-        pads lie behind them, where no real query looks; the output is
-        turned back.  Heads lead, the lanes are padded to whole tiles
-        (192 -> 256 for queries and keys, 128 -> 256 for values) and the
-        queries carry the difference between the kernel's scale
-        (lanes^-1/2) and the model's.  ``interpret`` is for tests."""
-        c = self.config
-        b, T, H, _ = qn.shape
-        cd = v.dtype
-        d = c.nopeDim + c.ropeDim
-        D = -(-d // 128) * 128
-        turn = jax.vmap(lambda a, by: jnp.roll(a, by, axis=0))
-
-        def laid(a):
-            a = jnp.pad(a.astype(cd), ((0, 0),) * 3 + ((0, D - a.shape[-1]),))
-            return turn(a, -start).transpose(0, 2, 1, 3)     # (b, H, T, D)
-        q = jnp.concatenate([qn, qr], axis=-1) * (D / d) ** 0.5
-        k = jnp.concatenate([kn, jnp.broadcast_to(
-            kr[:, :, None], (b, T, H, c.ropeDim))], axis=-1)
-        o = flash_attention(laid(q), laid(k), laid(v), causal=True,
-                            interpret=interpret)[..., :c.vDim]
-        return turn(o.transpose(0, 2, 1, 3), start).reshape(
-            b, T, H * c.vDim).astype(_F32)
-
     def _run_full(self, params, tokens, start):
         """``tokens (b, T)`` LEFT-padded, ``start (b,)`` the first real
         position.  Returns the last layer's output, every layer's latent
@@ -332,13 +250,7 @@ class PanguMoELM(ServedLM):
             qn, qr = self._queries(lp, h, p)
             row = self._latent_row(lp, h, p, cd)
             rows = rows.at[li, :, 0].set(row)
-            ckv, kr = row[..., :c.kvRank], row[..., c.kvRank:c.kvRank
-                                               + c.ropeDim]
-            heads = lambda eq, W: jnp.einsum(
-                eq, ckv, W, preferred_element_type=_F32).astype(cd)
-            o = self._attend_full(
-                qn, qr, heads("btr,hrd->bthd", lp["Wuk"]), kr,
-                heads("btr,hdr->bthd", lp["Wuv"]), start)
+            o = self._latent_full(lp, qn, qr, row, start)
             # the stream is written out after every add (see
             # OlmoHybridLM._run_full)
             y = hold(x + _rms(_mm(o, lp["Wo"]), lp["norm2"],
@@ -398,7 +310,7 @@ class PanguMoELM(ServedLM):
             raise ValueError(
                 "the step takes one token a slot: speculative "
                 "verification (tq > 1) would need a position a query")
-        H, R = c.nHeads, c.kvRank
+        H = c.nHeads
         active = pos > 0
         p = jnp.maximum(pos - start, 0)
         x = params["emb"][toks[:, 0]]                         # (S, d)
@@ -407,16 +319,9 @@ class PanguMoELM(ServedLM):
         for li, lp in enumerate(params["layers"]):
             h = _rms(x, lp["norm1"], c.eps)
             qn, qr = self._queries(lp, h, p)                  # (S, H, .)
-            # q~ = q_nope W_uk^T a head: the query in the latent's lanes
-            qa = jnp.einsum("shd,hrd->shr", qn.astype(cd), lp["Wuk"],
-                            preferred_element_type=_F32)
-            qh = self._row_wide(jnp.concatenate([qa, qr], axis=-1))
-            ctx, rows = paged_latent_attention(
-                qh[:, :, None], self._latent_row(lp, h, p, cd)[:, None],
-                rows, li, pageTable, pos, start, valueWidth=R,
-                scale=(c.nopeDim + c.ropeDim) ** -0.5)
-            o = jnp.einsum("shr,hdr->shd", ctx[:, :, 0].astype(cd),
-                           lp["Wuv"], preferred_element_type=_F32)
+            o, rows = self._latent_step(
+                lp, qn, qr, self._latent_row(lp, h, p, cd), rows, li,
+                pageTable, pos, start)
             y = x + _rms(_mm(o.reshape(S, H * c.vDim), lp["Wo"]),
                          lp["norm2"], c.eps).astype(cd)
             ff, n = self._ffn(lp, _rms(y, lp["norm3"], c.eps), active,

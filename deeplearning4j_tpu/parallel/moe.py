@@ -203,6 +203,34 @@ def route_softmax_topk(x, Wr, k: int):
     return idx.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
 
 
+def route_sigmoid_group_topk(x, Wr, bias, k: int, n_group: int,
+                             topk_group: int, scale: float):
+    """Sigmoid gate over ALL experts with GROUP-LIMITED choice and a
+    correction bias (DeepSeek-V3's ``noaux_tc``), in float32: ``x (T,
+    d)``, ``Wr (d, E)``, ``bias (E,)``.  ``s = sigmoid(x Wr)``; the choice
+    is made on ``c = s + bias``: the experts lie in ``n_group`` groups of
+    ``E / n_group``, a group's score is the sum of its two largest ``c``,
+    the ``topk_group`` best groups stay, and among their experts the ``k``
+    largest ``c`` are chosen.  The weights are free of the bias: ``w =
+    s_chosen / (sum of the k) * scale``.  Returns ``(idx (T, k) int32, w
+    (T, k))``.  Float32 at ``HIGHEST`` for the reason
+    :func:`route_sigmoid_topk` gives."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), Wr.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    c = s + bias.astype(jnp.float32)
+    T, E = c.shape
+    grouped = c.reshape(T, n_group, E // n_group)
+    _, best = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], axis=-1),
+                        topk_group)                          # (T, topk_group)
+    stays = jnp.any(best[..., None] == jnp.arange(n_group), axis=1)
+    _, idx = lax.top_k(jnp.where(stays[..., None], grouped, -jnp.inf
+                                 ).reshape(T, E), k)
+    top = jnp.take_along_axis(s, idx, axis=-1)
+    return idx.astype(jnp.int32), \
+        top / jnp.sum(top, axis=-1, keepdims=True) * scale
+
+
 def _held(idx, lo: int, n: int, real):
     """``(idx - lo, held here)`` for the chosen experts ``idx (T, k)`` of
     the real tokens ``real (T,)``."""
@@ -409,7 +437,7 @@ def moe_share_step(x, idx, w, Eg, Eu, Ed, lo: int, real):
     return _share_step_p.bind(x, idx, w, Eg, Eu, Ed, real, lo=lo)
 
 
-def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real):
+def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None):
     """:func:`moe_share_dense`'s sum by GROUPS: the token-expert pairs
     whose expert is held, sorted by expert, and one grouped matmul
     (``lax.ragged_dot``) a projection over the rows of each expert's
@@ -417,12 +445,17 @@ def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real):
     of 256 experts at 8 a token), not of ``n`` experts over every token.
 
     Nothing is dropped and no shape depends on the routing: the sorted
-    pairs are taken ``T`` rows a pass, for as many passes as hold a held
-    pair (one, unless the router leans on this chip's experts; ``k`` at
-    most).  A pass gathers its rows' tokens, multiplies by group, and
-    adds each row's weighted output to its token through a 0/1 matrix on
-    the MXU (``T x T``; a scatter-add of rows would serialise)."""
+    pairs are taken ``passRows`` rows a pass (``T`` where omitted), for as
+    many passes as hold a held pair (one, unless the router leans on this
+    chip's experts; ``k`` at most).  A pass gathers its rows' tokens,
+    multiplies by group, and adds each row's weighted output to its token
+    through a 0/1 matrix on the MXU (``T x passRows``; a scatter-add of
+    rows would serialise).  Every pass streams all the held experts'
+    weights whatever its rows (PERF.md section 5), so a share that
+    expects more than one held pair a token (128 of 512 experts at 8 a
+    token: two) names the rows that hold them in ONE pass."""
     T, k = idx.shape
+    R = T if passRows is None else passRows
     n = Eg.shape[0]
     dt = Eg.dtype
     f32 = jnp.float32
@@ -430,29 +463,32 @@ def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real):
     e, here = _held(idx, lo, n, real)
     key = jnp.where(here, e, n).reshape(-1)        # absent pairs last
     order = jnp.argsort(key).astype(jnp.int32)                   # (T k,)
+    if T * k % R:        # whole passes: the rows behind the pairs are dead
+        order = jnp.pad(order, (0, -(T * k) % R))
     ends = jnp.cumsum(jnp.sum(
         key[:, None] == jnp.arange(n), axis=0)).astype(jnp.int32)
     total = ends[-1]
     wflat = w.reshape(-1)
-    at = jnp.arange(T, dtype=jnp.int32)
+    at = jnp.arange(R, dtype=jnp.int32)
     gmm = lambda a, W, sizes: lax.ragged_dot(
         a, W, sizes, preferred_element_type=f32)
 
     def one_pass(carry):
         b, out = carry
-        rows = lax.dynamic_slice_in_dim(order, b * T, T)
-        live = b * T + at < total
-        edge = jnp.clip(ends - b * T, 0, T)
+        rows = lax.dynamic_slice_in_dim(order, b * R, R)
+        live = b * R + at < total
+        edge = jnp.clip(ends - b * R, 0, R)
         sizes = jnp.diff(edge, prepend=0).astype(jnp.int32)
         tok = rows // k
         xs = x[tok]
         h = jax.nn.silu(gmm(xs, Eg, sizes)) * gmm(xs, Eu, sizes) \
             * jnp.where(live, wflat[rows], f32(0))[:, None]
         y = jnp.where(live[:, None], gmm(h.astype(dt), Ed, sizes), f32(0))
-        home = (at[:, None] == tok[None, :]) & live[None, :]     # (T, T)
+        home = (jnp.arange(T, dtype=jnp.int32)[:, None] == tok[None, :]) \
+            & live[None, :]                                      # (T, R)
         return b + 1, out + jnp.matmul(home.astype(dt), y.astype(dt),
                                        preferred_element_type=f32)
-    _, out = lax.while_loop(lambda c: c[0] * T < total, one_pass,
+    _, out = lax.while_loop(lambda c: c[0] * R < total, one_pass,
                             (jnp.int32(0), jnp.zeros((T, Ed.shape[-1]), f32)))
     return out
 

@@ -331,7 +331,7 @@ class _Seq:
         self.parent = parent
         self.row = int(row)
         self.emitted: List[int] = []
-        self.streamQ: Optional[_stdqueue.Queue] = None
+        self.streamQ: Optional[_stdqueue.SimpleQueue] = None
         self.streamed = 0               # tokens pushed to the stream, ever
         self.streamSkip = 0             # re-emissions to swallow after a preempt
         self.cancelled = False
@@ -479,6 +479,8 @@ class ContinuousBatcher:
         # what stands in for its output when there is none (``warm``)
         self._inflight: Optional[_Flight] = None
         self._noPrev = None
+        #: the last step's small inputs, host copies and device arrays
+        self._uploaded: Tuple[tuple, list] = ((), [])
         # sequences that have left their slot, and given back their
         # pages, with tokens still unread on the device: the step that
         # computes a quota's last token is known at its dispatch
@@ -901,7 +903,7 @@ class ContinuousBatcher:
             raise ValueError("streaming serves a single sequence per "
                              "request")
         seq = seqs[0]
-        seq.streamQ = _stdqueue.Queue()
+        seq.streamQ = _stdqueue.SimpleQueue()
         self._admitGate(1, seq.pages, singleStep=(seq.quota == 1),
                         deadline=parent.deadline, ctx=parent.ctx)
         heartbeat = payload.get("keepAliveSeconds")
@@ -1336,10 +1338,8 @@ class ContinuousBatcher:
                     # device, unless a replay forces it (known ahead)
                     n = len(seq.emitted)
                     tokH[s] = seq.forced[n] if n < len(seq.forced) else -1
-            pt = jnp.asarray(ptH)
-            pos = jnp.asarray(posH)
-            startA = jnp.asarray(startH)
-            tokA = jnp.asarray(tokH[:, None])
+            pt, pos, startA, tokA = self._upload(ptH, posH, startH,
+                                                 tokH[:, None])
         step = self._stepFns["step"]
         with self._phase("dispatch"):
             prev = self._noPrev if ahead is None else ahead.greedy
@@ -1369,6 +1369,24 @@ class ContinuousBatcher:
                     # token finds the sequence by the flight's record
                     self._retireSlot(s, parting=True)
             return flight
+
+    def _upload(self, *host: np.ndarray) -> list:
+        """The step's small inputs on the device: ONE transfer for those
+        whose values differ from the step before's, and that step's
+        device arrays for the rest.  ``pos`` moves every step; a page
+        table only when a slot takes a page, ``start`` at an admission,
+        the tokens when one is new or forced (a slot that goes on reads
+        -1: its input is on the device)."""
+        hostWas, dev = self._uploaded
+        fresh = [i for i, h in enumerate(host)
+                 if i >= len(hostWas) or not np.array_equal(h, hostWas[i])]
+        dev = list(dev) + [None] * (len(host) - len(dev))
+        for i, a in zip(fresh, jax.device_put([host[i] for i in fresh])):
+            dev[i] = a
+        # the host copies are never written again (the caller made them
+        # for this step), so a device array that aliases one stays true
+        self._uploaded = (host, dev)
+        return dev
 
     def _land(self, flight: _Flight) -> None:
         """Read a dispatched step's tokens, deliver them, do the books."""

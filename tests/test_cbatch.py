@@ -816,6 +816,45 @@ def test_a_parted_sequence_keeps_the_batcher_busy_until_it_is_read():
         cb.shutdown()
 
 
+def test_a_step_uploads_only_the_inputs_whose_values_changed():
+    """``_dispatch`` hands the step the device arrays of the step before
+    for every small input whose VALUES did not change (page table, start,
+    the tokens of slots that go on) and uploads the rest in one transfer:
+    ``pos`` every step, the page table when a slot takes a page, all of
+    them at an admission; the tokens served are ``generate``'s."""
+    lm, ref = _lm(layers=1), _lm(layers=1)
+    first = np.random.RandomState(11).randint(1, 40, 6).tolist()
+    second = np.random.RandomState(12).randint(1, 40, 5).tolist()
+    cb = _by_hand(lm, "cb-upload", maxSlots=2)
+    try:
+        g1, _ = _stream(cb, first, 14)
+        kept = []
+        for i in range(12):
+            cb._iterate()
+            kept.append(cb._uploaded)
+        for (hostA, devA), (hostB, devB) in zip(kept[1:], kept[2:]):
+            for i, (a, b) in enumerate(zip(hostA, hostB)):
+                assert (devA[i] is devB[i]) == np.array_equal(a, b)
+                np.testing.assert_array_equal(np.asarray(devB[i]), b)
+        pt, pos, start, tok = range(4)
+        reused = [[a is b for a, b in zip(devA, devB)]
+                  for (_, devA), (_, devB) in zip(kept[1:], kept[2:])]
+        assert all(r[start] and r[tok] and not r[pos] for r in reused)
+        # pages of 8 rows: of these ten steps one, and only one, found a
+        # slot on a new page
+        assert sum(not r[pt] for r in reused) == 1
+        g2, _ = _stream(cb, second, 4)      # an admission moves them all
+        before = cb._uploaded[1]
+        cb._iterate()
+        assert not any(a is b for a, b in zip(before, cb._uploaded[1]))
+        _run_out(cb)
+        assert list(g1) == _greedy(ref, first, 14)
+        assert list(g2) == _greedy(ref, second, 4)
+        assert cb.pool.usedPages() == 0
+    finally:
+        cb.shutdown()
+
+
 @pytest.mark.parametrize("how", ["preempted_by_hand", "pool_squeeze"])
 def test_preempt_defer_and_replay_with_a_step_unread_deliver_once(how):
     """A preemption with a step unread loses that step's token for the

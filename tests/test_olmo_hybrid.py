@@ -97,7 +97,7 @@ def test_chunked_delta_rule_is_the_recurrence(ref, case, chunk):
     of either chunk.  Float32 on both sides: what differs is the order of
     the sums (measured 4e-6 at outputs of size 3 to 6)."""
     import jax.numpy as jnp
-    from deeplearning4j_tpu.nlp.olmo_hybrid import delta_rule_chunked
+    from deeplearning4j_tpu.nlp.delta import delta_rule_chunked
     rs = np.random.RandomState(0)
     b, T, H, dk, dv = 2, 37, 3, 8, 16
     unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
@@ -426,3 +426,69 @@ def test_published_configuration_counts_its_parameters(ref, family):
     assert [(n, s, np.dtype(t)) for n, s, t in spec.slotState] == [
         ("delta", (12, 30, 96, 192), np.dtype("float32")),
         ("conv", (12, 3, 11520), np.dtype("bfloat16"))]
+
+
+# -- the delta rule moved to nlp/delta.py (PR 44) ----------------------------
+GOLDEN = os.path.join(REPO, "tests", "fixtures", "olmo_pangu_logits_pr43.npz")
+
+
+def _delta_case():
+    rs = np.random.RandomState(5)
+    b, T, H, dk, dv = 2, 37, 3, 8, 16
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    return tuple(np.asarray(a, np.float32) for a in (
+        unit(rs.randn(b, T, H, dk)), unit(rs.randn(b, T, H, dk)),
+        rs.randn(b, T, H, dv), rs.uniform(0.0, 2.0, (b, T, H)),
+        np.log(rs.uniform(1e-3, 1.0, (b, T, H)))))
+
+
+def _golden(family, weights, dtype):
+    """What ``fixtures/olmo_pangu_logits_pr43.npz`` holds of this model
+    for ``dtype`` (keys ``olmo_<form>_<dtype>``): the full forward's
+    logits of a 24-token prompt, the logits of a left-padded prefill (11
+    tokens in the 16 bucket) and of 12 teacher-forced steps through the
+    pool, and ``delta_rule_chunked``'s outputs and end state on
+    :func:`_delta_case` at chunks of 8.  Recorded on commit 424521a (PR
+    43), where the rule still lay in ``olmo_hybrid.py``, by ``np.savez``
+    over this function and ``test_pangu_moe._golden``, with
+    ``olmo_canary`` = ``ref.logits(TINY, weights, _prompts([24])[0])`` of
+    the same machine."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.delta import delta_rule_chunked
+    from deeplearning4j_tpu.remote import KVCachePool
+    lm = _lm(family, weights, dtype)
+    forward = np.asarray(lm.forward(np.asarray([_prompts([24])[0]])))[0]
+    pool = KVCachePool.forSpec(lm.cacheSpec(), PAGE, 1 + SLOTS * (CAP // PAGE),
+                               SLOTS, CAP // PAGE)
+    served = np.stack(list(_teacher_forced(
+        lm, pool, lm.buildPagedPrefillWriteFn(), jax.jit(lm.pagedLogits), 1,
+        _prompts([11])[0], 16, _prompts([12], seed=7)[0])))
+    o, S = delta_rule_chunked(*(jnp.asarray(a) for a in _delta_case()), 8)
+    return {"forward": forward, "served": served,
+            "rule": np.concatenate([np.asarray(o).ravel(),
+                                    np.asarray(S).ravel()])}
+
+
+@pytest.mark.parametrize("form", ["forward", "served", "rule"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_logits_are_bit_for_bit_what_they_were_before_the_rule_moved(
+        ref, family, weights, dtype, form):
+    """``nlp/delta.py`` computes what ``olmo_hybrid.py`` computed, in the
+    same order, so not one bit of a logit (or of the rule's own outputs)
+    may differ from the recording of PR 43's tree.  The recording is one
+    machine's arithmetic: where this machine's CPU rounds unlike it (the
+    canary, the plain reference's logits, differs), the values are held
+    to the file's tolerances and the case reads SKIPPED (as
+    ``test_sambay.py``'s does)."""
+    tol = TOL_F32 if dtype == "float32" or form == "rule" else TOL_BF16
+    with np.load(GOLDEN) as want:
+        got = _golden(family, weights, dtype)[form]
+        canary = np.asarray(ref.logits(TINY, weights, _prompts([24])[0]))
+        if np.array_equal(canary, want["olmo_canary"]):
+            np.testing.assert_array_equal(got, want[f"olmo_{form}_{dtype}"])
+            return
+        assert np.abs(got - want[f"olmo_{form}_{dtype}"]).max() < tol
+    pytest.skip("this machine's CPU rounds unlike the one that recorded "
+                "the fixture (the canary differs): equality not checked, "
+                "the values lie within the file's tolerances")

@@ -763,3 +763,50 @@ def test_published_configuration_counts_its_parameters(ref, family):
         - ref.decode_step_bytes(config, 0.0) == 64 * 2 * per["expert"]
     assert ref.decode_step_bytes(config, 1000.0) == ref.param_bytes(config) \
         + 1000 * 5 * 2 * 576
+
+
+# -- the latent attention moved to nlp/latent.py (PR 44) ---------------------
+GOLDEN = os.path.join(REPO, "tests", "fixtures", "olmo_pangu_logits_pr43.npz")
+
+
+def _golden(family, weights, dtype):
+    """What ``fixtures/olmo_pangu_logits_pr43.npz`` holds of this model
+    for ``dtype`` (keys ``pangu_<form>_<dtype>``): the full forward's
+    logits of a 24-token prompt, and the logits of a left-padded prefill
+    (11 tokens in the 16 bucket) and of 12 teacher-forced ABSORBED steps
+    through the pool.  Recorded on commit 424521a (PR 43), where the
+    latent pieces still lay in ``pangu_moe.py``, by ``np.savez`` over
+    this function and ``test_olmo_hybrid._golden``, with ``pangu_canary``
+    = ``ref.logits(TINY, weights, _prompts([24])[0])`` of the same
+    machine."""
+    import jax
+    from deeplearning4j_tpu.remote import KVCachePool
+    lm = _lm(family, weights, dtype)
+    forward = np.asarray(lm.forward(np.asarray([_prompts([24])[0]])))[0]
+    pool = KVCachePool.forSpec(lm.cacheSpec(), PAGE, 1 + SLOTS * (CAP // PAGE),
+                               SLOTS, CAP // PAGE)
+    served = np.stack(list(_teacher_forced(
+        lm, pool, lm.buildPagedPrefillWriteFn(), jax.jit(lm.pagedLogits), 1,
+        _prompts([11])[0], 16, _prompts([12], seed=7)[0])))
+    return {"forward": forward, "served": served}
+
+
+@pytest.mark.parametrize("form", ["forward", "served"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_logits_are_bit_for_bit_what_they_were_before_the_latent_moved(
+        ref, family, weights, dtype, form):
+    """``nlp/latent.py`` computes what ``pangu_moe.py`` computed, in the
+    same order, so not one bit of a logit may differ from the recording
+    of PR 43's tree; where this machine's CPU rounds unlike the recording
+    one (the canary differs) the logits are held to the file's tolerances
+    and the case reads SKIPPED (as ``test_sambay.py``'s does)."""
+    with np.load(GOLDEN) as want:
+        got = _golden(family, weights, dtype)[form]
+        canary = np.asarray(ref.logits(TINY, weights, _prompts([24])[0]))
+        if np.array_equal(canary, want["pangu_canary"]):
+            np.testing.assert_array_equal(got, want[f"pangu_{form}_{dtype}"])
+            return
+        assert _close(got, want[f"pangu_{form}_{dtype}"], dtype)
+    pytest.skip("this machine's CPU rounds unlike the one that recorded "
+                "the fixture (the canary differs): equality not checked, "
+                "the logits lie within the file's tolerances")

@@ -640,12 +640,12 @@ def test_pangu_moe_decode_step_fits_and_reads_its_latent_rows_in_place(
 def test_pangu_moe_prefill_groups_its_experts_and_fits_beside_the_step(
         pangu, monkeypatch):
     import jax
-    from deeplearning4j_tpu.nlp import pangu_moe
+    from deeplearning4j_tpu.nlp import latent
     from deeplearning4j_tpu.parallel import ring
     lm, params, pool, i32, step, _ = pangu
     # the program asks ``jax.default_backend()`` whether the flash kernel
     # can run, and here that is the CPU: steer it to the chip's choice
-    for mod in (ring, pangu_moe):
+    for mod in (ring, latent):
         monkeypatch.setattr(mod, "_flash_refusal", lambda *a, **k: None)
     compiled = lm._prefillRawFn.at(PANGU_BUCKET).lower(
         params, i32(1, PANGU_BUCKET), i32(1)).compile()
@@ -997,3 +997,130 @@ def test_keye_prefill_selects_and_attends_in_kernels_and_fits_beside_the_step(
     poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
     assert write.memory_analysis().alias_size_in_bytes >= poolBytes
     assert write.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+# -- Ling-3.0-flash as benchmark/configs/ling3_flash.json serves it: published
+# layers 1-7 (six KDA layers, one MLA layer; one dense FFN, six expert layers
+# holding 128 of 512 experts), a quarter of the vocabulary, every width as
+# published, bfloat16; 64 slots of 12,288 positions (96 pages of 128) of one
+# latent row in the MLA layer, six float32 delta states a slot beside them
+LING_SLOTS, LING_CAP, LING_PAGE, LING_BUCKET = 64, 12288, 128, 8192
+
+
+@pytest.fixture(scope="module")
+def ling(one_chip):
+    """``(lm, params, pool arrays, i32, the compiled decode step, the
+    attention and the expert kernels lowered for it)``: shapes on the
+    described chip."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.ling import LingConfig, LingLM
+    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
+    from deeplearning4j_tpu.remote import KVCachePool
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    lm = LingLM(LingConfig(
+        vocabSize=39296, nLayers=7, firstLayer=1, denseLayers=1, mlaEvery=6,
+        hiddenSize=2560, nHeads=32, headDim=128, kvRank=512, nopeDim=128,
+        ropeDim=64, vDim=128, ffnSize=6144, expertSize=768, nExperts=512,
+        expertsPerToken=8, expertsHeld=(0, 128), nGroups=8, groupsPerToken=4,
+        maxLen=LING_CAP), params={})
+    params = on_chip(jax.eval_shape(lm._init_params))
+    perSeq = LING_CAP // LING_PAGE
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool.forSpec(
+        lm.cacheSpec(), LING_PAGE, 1 + LING_SLOTS * perSeq, LING_SLOTS,
+        perSeq).arrays))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    before = paged_kernel_lowerings(), moe_step_kernel_lowerings()
+    step = lm.buildPagedDecodeFn().lower(
+        params, *pool, i32(LING_SLOTS, 1), i32(LING_SLOTS, 7),
+        i32(LING_SLOTS, perSeq), i32(LING_SLOTS), i32(LING_SLOTS)).compile()
+    return lm, params, pool, i32, step, (
+        paged_kernel_lowerings() - before[0],
+        moe_step_kernel_lowerings() - before[1])
+
+
+def test_ling_decode_step_fits_and_updates_three_kinds_of_state_in_place(
+        ling):
+    """Of the decode step at the cell's sizes: 13 kernel calls, 6 that
+    update a KDA layer's states where they lie (each state read once and
+    written once), 1 latent read with 32 query heads as the rows of its
+    matmul, 6 over the hit experts of 128 held."""
+    lm, params, pool, i32, compiled, kernelsLowered = ling
+    perSeq = LING_CAP // LING_PAGE
+    mem = compiled.memory_analysis()
+    # found: 12.18 GB of arguments (10.34 of weights, 1.01 of latent rows,
+    # 0.81 of delta states, 0.03 of windows) + 0.04 of temporaries
+    assert [a.shape for a in pool] == [
+        (1, 1 + LING_SLOTS * perSeq, LING_PAGE, 640),
+        (6, LING_SLOTS, 32, 128, 128), (6, LING_SLOTS, 3, 12288),
+        (1, LING_SLOTS, 3)]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < 0.1e9
+    _assert_one_step_program(compiled, pool)
+    assert not _whole_array_copies(compiled, pool)
+    text = compiled.as_text()
+    # (the six expert layers are one lowering: same shapes, same rule)
+    assert kernelsLowered == (1, 1)
+    kernels = re.findall(
+        r"^\s*%?([a-z_]+)[\w.\-]* = .*? custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert sorted(kernels) == ["kda_step"] * 6 + ["moe_share_step"] * 6 \
+        + ["paged_latent_attention"], kernels
+    # no layer's states (134 MB) are sliced out, updated and put back, and
+    # no slot's capacity of latent rows is gathered
+    assert not re.search(
+        rf"= f32\[(?:1,)?{LING_SLOTS},32,128,128\]\S* "
+        r"(?:copy|dynamic-slice|slice|fusion)\(", text)
+    assert f"bf16[{LING_SLOTS * perSeq},{LING_PAGE},640]" not in text
+    # the kernels and the convolution windows' shift carry the scope the
+    # benchmark cuts the step by
+    scoped = [line for line in text.splitlines() if "/kda_step/" in line]
+    assert sum("custom_call_target=\"tpu_custom_call\"" in line
+               for line in scoped) == 6
+    # nothing is computed for all 128 held experts (no (slots, 128, 768))
+    assert not re.search(rf"\[{LING_SLOTS},(?:128,768|98304)\]", text)
+
+
+def test_ling_prefill_chunks_its_delta_rule_and_fits_beside_the_step(
+        ling, monkeypatch):
+    import jax
+    from deeplearning4j_tpu.nlp import latent
+    from deeplearning4j_tpu.parallel import ring
+    lm, params, pool, i32, step, _ = ling
+    for mod in (ring, latent):
+        monkeypatch.setattr(mod, "_flash_refusal", lambda *a, **k: None)
+    traced = lm._prefillRawFn.at(LING_BUCKET).trace(
+        params, i32(1, LING_BUCKET), i32(1))
+    # the chunked form: the only loops over positions are the scans over
+    # the 128 chunks of each KDA layer -- nothing runs 8,192 times
+    assert sorted(_scan_lengths(traced.jaxpr.jaxpr)) == [128] * 6
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert "kda_chunked" in text and "/kda_step/" not in text
+    # the MLA layer's unabsorbed attention is the flash kernel, and the
+    # held experts are multiplied by GROUP (three ragged dots a layer)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) >= 1
+    assert text.count("ragged-dot") >= 3 * 6
+    mem = compiled.memory_analysis()
+    # found: 12.18 + 0.04 (the step) + 1.97 + 0.02 (the 8,192 prefill)
+    # = 14.21 GB
+    step = step.memory_analysis()
+    assert step.argument_size_in_bytes + step.temp_size_in_bytes \
+        + mem.temp_size_in_bytes + mem.output_size_in_bytes < 14.8e9
+    state = jax.eval_shape(lm._prefillRawFn, params, i32(1, LING_BUCKET),
+                           i32(1))[1:]
+    parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
+                                  sharding=pool[0].sharding) for p in state]
+    write = lm.buildPagedPrefillWriteFn().lower(
+        *pool, *parts, i32(LING_BUCKET // LING_PAGE), i32()).compile()
+    assert not _whole_array_copies(write, pool)
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert write.memory_analysis().alias_size_in_bytes >= poolBytes
+    assert write.memory_analysis().temp_size_in_bytes < 0.1e9
