@@ -32,8 +32,13 @@ score by bisection on the scores' bit patterns (the same set as a sort
 gives, without one), and a flash kernel attends under that mask; both
 read the sequence's first real position, and a tile of keys or of queries
 that lies wholly before it, all left pads, is neither scored nor
-attended: a prompt costs what its real tokens cost, not its bucket.  On
-the CPU or several devices the same blocks in ``jax.numpy``.
+attended: a prompt costs what its real tokens cost, not its bucket.  The
+flash kernel's tile is a KV head's query heads (eight as served)
+against 512 keys under ONE tile of the selection, added to the scores as
+0 or ``-inf``; its online softmax keeps the running maximum the same in
+every lane and the running sum as a partial sum a lane, so that a tile
+crosses lanes once (``_flash_kernel``).  On the CPU or several devices the
+same blocks in ``jax.numpy``.
 
 *Rotary positions* pair lane ``i`` with lane ``i + D / 2``
 (``served._rope``); a token's position is its index among the REAL
@@ -103,6 +108,9 @@ _MOE_BLOCK = 4096
 #: blocks against every key)
 _QUERY_BLOCK = 128
 _KEY_BLOCK = 512
+#: lanes of a vector register: the flash kernel keeps a row's softmax sum
+#: as this many partial sums (``_KEY_BLOCK`` is a multiple)
+_LANES = 128
 
 
 @dataclasses.dataclass
@@ -324,7 +332,31 @@ def _flash_kernel(tile_ref, flag_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
     KV head: the ``r`` query heads of the group ride as ``r Bq`` rows
     (head-major), all under the one ``(Bq, Bk)`` tile of the selection;
     softmax online across a query block's key blocks, in float32.  A
-    block of pad queries alone runs no tile and writes zeros."""
+    block of pad queries alone runs no tile and writes zeros.
+
+    A tile's scores are ``(r Bq, Bk)`` float32, 2 MB, and what it costs
+    beside its two matmuls is what crosses LANES (measured alone, PERF.md
+    §5): so a tile reduces over lanes once, for the row maximum, and
+    broadcasts nothing along them.
+
+    * The selection is ONE additive tile for the ``r`` heads: 0 where
+      ``keep`` is set, ``-inf`` where not, added to the scores seen as
+      ``(r, Bq, Bk)``.  ``m`` starts at the finite ``_NEG``, so beside it
+      a masked score gives ``exp(-inf - m) = 0`` exactly and
+      ``max(m, -inf) = m``: no second mask, and a row that has met no kept
+      key yet keeps ``m = _NEG``, ``l = 0``, ``acc = 0`` (a tile with no
+      kept key multiplies them by ``exp(0) = 1``).  With ``-inf`` for the
+      start too the first such tile would give ``exp(nan)``.
+    * ``m_ref (r Bq, _LANES)`` holds each row's running maximum in the
+      scores' OWN units, the same in every lane, so ``s - m`` takes a lane
+      tile of scores against it elementwise.  The scale sits inside the
+      exponent, ``p = exp((s - m) scale)``: a float32 factor on float32
+      differences, never folded into the bfloat16 queries.
+    * ``l_ref (r Bq, _LANES)`` holds each row's sum as ``_LANES`` PARTIAL
+      sums, lane ``j`` the keys ``j, j + _LANES, ...`` of every block:
+      ``shrink`` is a row's one factor for all of them, so the lanes are
+      added up once, when the query block closes.
+    """
     flag = flag_ref[pl.program_id(0) * _I32(steps) + pl.program_id(2)]
     has = lambda bit: jnp.bitwise_and(flag, _I32(bit)) != _I32(0)
 
@@ -336,26 +368,31 @@ def _flash_kernel(tile_ref, flag_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
 
     @pl.when(has(4))
     def _():
+        Bq, Bk = keep_ref.shape
+        W = m_ref.shape[1]
+        bias = jnp.where(keep_ref[...].astype(_I32) > _I32(0), _F32(0),
+                         _F32(-jnp.inf))                     # (Bq, Bk)
         s = jax.lax.dot_general(
             q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=_F32) * _F32(scale)       # (r Bq, Bk)
-        keep = keep_ref[...].astype(_I32).astype(_F32)
-        valid = jnp.concatenate([keep] * r, axis=0) > _F32(0)
-        s = jnp.where(valid, s, _F32(_NEG))
-        mOld = m_ref[...]
+            preferred_element_type=_F32)                     # (r Bq, Bk)
+        s = (s.reshape(r, Bq, Bk) + bias[None]).reshape(r * Bq, Bk)
+        mOld = m_ref[...]                                    # (r Bq, W)
         mNew = jnp.maximum(mOld, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - mNew), _F32(0))
-        shrink = jnp.exp(mOld - mNew)
-        l_ref[...] = shrink * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        shrink = jnp.exp((mOld - mNew) * _F32(scale))
+        p = [jnp.exp((s[:, c:c + W] - mNew) * _F32(scale))
+             for c in range(0, Bk, W)]
+        l_ref[...] = shrink * l_ref[...] + functools.reduce(jnp.add, p)
         m_ref[...] = mNew
-        acc_ref[...] = shrink * acc_ref[...] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+        acc_ref[...] = shrink[:, :1] * acc_ref[...] + jax.lax.dot_general(
+            jnp.concatenate([x.astype(v_ref.dtype) for x in p], axis=1),
+            v_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=_F32)
 
     @pl.when(has(2))
     def _():
         # a pad query reads nothing: zeros, not 0 / 0
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], _F32(1e-30))
+        l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l, _F32(1e-30))
                       ).astype(o_ref.dtype)
 
 
@@ -396,8 +433,8 @@ def _flash_call(start, q, k, v, keep, *, interpret):
                                        b, block(b, n, tile),
                                        chunk(b, n, tile), n * 0, n * 0))],
             out_specs=rows,
-            scratch_shapes=[pltpu.VMEM((r * Bq, 1), _F32),
-                            pltpu.VMEM((r * Bq, 1), _F32),
+            scratch_shapes=[pltpu.VMEM((r * Bq, _LANES), _F32),
+                            pltpu.VMEM((r * Bq, _LANES), _F32),
                             pltpu.VMEM((r * Bq, dh), _F32)]),
         out_shape=jax.ShapeDtypeStruct((b, G, nQ, r * Bq, dh), v.dtype),
         compiler_params=pltpu.CompilerParams(

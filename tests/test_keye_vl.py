@@ -278,20 +278,27 @@ def test_selection_mask_is_what_a_stable_sort_takes():
         np.testing.assert_array_equal(got[r], want & valid[r], err_msg=str(r))
 
 
-@pytest.mark.parametrize("T,starts", [(1024, (0, 600)),
-                                      (2048, (0, 600, 1337, 1920))])
+@pytest.mark.parametrize("T,starts,H,G,topk", [
+    (1024, (0, 600), 4, 2, 64),
+    (2048, (0, 600, 1337, 1920), 4, 2, 64),
+    (1024, (0, 600), 8, 1, 64),          # the cell's eight heads a KV head
+    (2048, (0, 600), 4, 2, 4)])          # a first key block with no kept key
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
-def test_prefill_kernels_are_the_blocked_form(dtype, tol, T, starts):
+def test_prefill_kernels_are_the_blocked_form(dtype, tol, T, starts, H, G,
+                                              topk):
     """``sparse_attend_full``'s two kernels (interpreted: the selection's
     tiles by bisection, flash attention under them) against its
     ``jax.numpy`` form: sequences left-padded past none, one or several
-    key blocks and query blocks, 64 rows a query, tied index scores.  The
-    kernels visit no tile that lies wholly before ``start``: what the
+    key blocks and query blocks, 64 rows a query (or 4: then a real query
+    keeps no key in the first key block it visits and some in a later
+    one, so its running maximum is still at its start when the first kept
+    key arrives), two or eight query heads a KV head, tied index scores.
+    The kernels visit no tile that lies wholly before ``start``: what the
     pads' rows hold moves no bit of a real row."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.nlp import keye_vl as M
     rs = np.random.RandomState(1)
-    b, H, G, dh, hI, dI, topk = len(starts), 4, 2, 16, 2, 8, 64
+    b, dh, hI, dI = len(starts), 16, 2, 8
     dt = jnp.dtype(dtype)
     rnd = lambda *shape: rs.standard_normal(shape).astype(np.float32)
     q, k, v = (rnd(b, T, n, dh) for n in (H, G, G))
@@ -341,6 +348,10 @@ def test_prefill_kernels_are_the_blocked_form(dtype, tol, T, starts):
                     keep[n, i, c] != 0,
                     sel[n, i * Bq:(i + 1) * Bq, c * Bk:(c + 1) * Bk])
     assert sel.sum(-1).max() == topk
+    if topk < 8:
+        first = np.stack([keep[n, :, s0 // Bk] for n, s0 in enumerate(starts)]
+                         ).any(-1).reshape(b, T)
+        assert (real & sel.any(-1) & ~first).any()
     lm = M.KeyeVLLM(M.KeyeVLConfig(nLayers=1), params={})
     assert np.asarray(lm._prefill_tile_counts(start, T)).tolist() == [
         b * sum((i * Bq + Bq - 1) // Bk + 1 for i in range(T // Bq)),
