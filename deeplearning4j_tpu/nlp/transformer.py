@@ -25,6 +25,18 @@ executable shapes STATIC:
 
 Weights follow the pre-LN GPT block (LN → attention → residual, LN → FFN
 → residual) with tied input/output embeddings.
+
+The tied table ``params["emb"]`` is HELD with its rows padded with zeros
+to whole lane tiles of 128 columns (GPT-2 XL: 1,600 → 1,664), laid out
+once, when a tree is given to the model (:func:`lane_aligned`).  A TPU
+keeps a float32 ``(vocab, width)`` array whose width is no whole number
+of lane tiles (1,600 is 12.5) VOCABULARY-minor in HBM, which suits the
+head's product and not the lookup, which needs rows: every program that
+does both then makes itself a row-minor copy of the whole table, 322 MB
+a decode step and a prefill, however the product is written.  At whole
+tiles the array lies row-minor and both read it as it lies.  The lookup
+drops the pad columns and the head multiplies a zero-padded ``h``: the
+sums gain exact zeros.
 """
 from __future__ import annotations
 
@@ -40,6 +52,39 @@ from deeplearning4j_tpu.nn.conf.attention import CacheSpec, paged_attention
 from deeplearning4j_tpu.nlp.served import ServedLM
 
 __all__ = ["TransformerLMConfig", "TransformerLM"]
+
+#: columns of one lane tile of the TPU's (8, 128) layout
+_LANES = 128
+#: rows of the table that :func:`_pad_columns` moves at a time
+_PAD_ROWS = 1024
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_columns(emb, pad: int):
+    """``emb (V, W)`` with ``pad`` zero columns behind each row, a block
+    of rows at a time into a zero table that is updated in place.  (One
+    ``jnp.pad`` of the whole table computes the same and holds a second
+    table-sized temporary while it runs, 335 MB at GPT-2 XL's sizes: the
+    TPU keeps a ``(V, 1600)`` array vocabulary-minor and a ``(V, 1664)``
+    one row-minor, so the pad is a transposition.)"""
+    V, W = emb.shape
+    R = min(V, _PAD_ROWS)
+
+    def block(i, out):
+        r0 = jnp.minimum(i * R, V - R)      # the last block overlaps
+        rows = jax.lax.dynamic_slice(emb, (r0, 0), (R, W))
+        return jax.lax.dynamic_update_slice(
+            out, jnp.pad(rows, ((0, 0), (0, pad))), (r0, 0))
+    return jax.lax.fori_loop(0, -(-V // R), block,
+                             jnp.zeros((V, W + pad), emb.dtype))
+
+
+def lane_aligned(emb):
+    """The tied table ``(vocab, width)`` with its rows padded with zeros
+    to the next multiple of 128 columns; the array itself, nothing
+    copied, where its width already is one."""
+    pad = -emb.shape[1] % _LANES
+    return _pad_columns(emb, pad) if pad else emb
 
 
 @dataclasses.dataclass
@@ -67,21 +112,40 @@ class TransformerLM(ServedLM):
         self.config = config or TransformerLMConfig(**kw)
         self.params = self._init_params()
 
+    @property
+    def params(self) -> Dict:
+        """The parameter tree the programs are given.  Assigning a tree
+        lays its tied table out in whole lane tiles
+        (:func:`lane_aligned`), once; a tree whose table already is (the
+        model's own, a re-placed one) is kept as it is."""
+        return self._params
+
+    @params.setter
+    def params(self, tree: Dict) -> None:
+        emb = lane_aligned(tree["emb"])
+        self._params = tree if emb is tree["emb"] else {**tree, "emb": emb}
+
     # ------------------------------------------------------------------
     def _init_params(self) -> Dict:
         c = self.config
         rng = np.random.RandomState(c.seed)
         H, F = c.hiddenSize, c.ffnMult * c.hiddenSize
 
+        def draw(*shape):
+            return (rng.randn(*shape) * c.initializerRange).astype(np.float32)
+
         def init(*shape):
-            return jnp.asarray(
-                (rng.randn(*shape) * c.initializerRange).astype(np.float32))
+            return jnp.asarray(draw(*shape))
 
         # float32 spelled out on every leaf: the package enables x64, so
         # a dtype-less jnp.ones/zeros is float64 and promotes everything
         # after the first LayerNorm (a TPU has no f64 unit)
         f32 = jnp.float32
-        p = {"emb": init(c.vocabSize, H), "pos": init(c.maxLen, H),
+        # the tied table in whole lane tiles (the module's text says why),
+        # padded on the host: the device is sent the one array it keeps
+        p = {"emb": jnp.asarray(np.pad(draw(c.vocabSize, H),
+                                       ((0, 0), (0, -H % _LANES)))),
+             "pos": init(c.maxLen, H),
              "lnf_g": jnp.ones((H,), f32), "lnf_b": jnp.zeros((H,), f32),
              "layers": []}
         for _ in range(c.nLayers):
@@ -137,12 +201,25 @@ class TransformerLM(ServedLM):
             qh, kh, vh, mask=mask, causal=True), kh, vh)
 
     def _embed(self, params, tokens, pos_ids):
-        x = params["emb"][tokens]                      # (b, t, H)
+        """Token rows + position rows, ``(b, t, H)``.  The table's rows
+        are as wide as the array given (whole lane tiles where the model
+        holds it, ``H`` where a caller passes the plain table): the
+        first ``H`` columns of the rows looked up are the embedding, the
+        rest are zeros."""
+        x = params["emb"][tokens][..., :self.config.hiddenSize]
         return x + params["pos"][pos_ids]
 
     def _logits(self, params, x):
+        """Final LayerNorm and the tied head: ``h`` padded with zeros to
+        the width of the table it is given, contracted with the table's
+        minor dimension (no transpose of the table is asked for).  The
+        pad columns of both are zero, so the sums gain exact zeros."""
         h = self._ln(x, params["lnf_g"], params["lnf_b"])
-        return jnp.matmul(h, params["emb"].T)          # tied head
+        emb = params["emb"]
+        h = jnp.pad(h, [(0, 0)] * (h.ndim - 1)
+                    + [(0, emb.shape[1] - h.shape[-1])])
+        return jax.lax.dot_general(
+            h, emb, (((h.ndim - 1,), (1,)), ((), ())))
 
     # ------------------------------------------------------------------
     # full forward (the recompute baseline the paged path must match)
@@ -208,7 +285,7 @@ class TransformerLM(ServedLM):
         pos_ids = jnp.clip(
             (pos - start)[:, None] + jnp.arange(tq, dtype=jnp.int32),
             0, self.config.maxLen - 1)
-        x = params["emb"][toks] + params["pos"][pos_ids]
+        x = self._embed(params, toks, pos_ids)
         for li, lp in enumerate(params["layers"]):
             # called inside _block, before the pools are rebound below
             x, poolK, poolV = self._block(

@@ -634,6 +634,10 @@ class ContinuousBatcher:
         sm.sparse_read_in_place().set(
             1 if spec.indexWidth and sparse_in_place_lowerings() - inPlace
             >= spec.pagedLayers else 0, model=self.name)
+        emb = self.lm.params.get("emb")
+        if emb is not None:
+            sm.tied_table_lane_aligned().set(
+                1 if emb.shape[-1] % 128 == 0 else 0, model=self.name)
         # and with a step's own output for ``prev``, as every later call
         # has it: beside committed params that is another entry of the
         # jit's cache than fresh zeros.  It stands in wherever no step is

@@ -473,6 +473,19 @@ class ServingMetrics:
             "expert layers x decode steps)",
             labelnames=("model",))
 
+    def tied_table_lane_aligned(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_tied_table_lane_aligned",
+            "1 when the embedding table the batcher's decode step is "
+            "given (params[\"emb\"]) is whole lane tiles of 128 columns "
+            "wide, so that the lookup and a tied head read it as it lies "
+            "(TransformerLM pads its table with zero columns to that "
+            "width when it is given a tree), 0 when not: a width that is "
+            "not whole tiles makes the TPU's compiler copy the whole "
+            "table inside every step; no series for a model with no "
+            "params[\"emb\"]",
+            labelnames=("model",))
+
     def kv_pages_free(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_kv_pages_free",

@@ -24,6 +24,10 @@ pytestmark = pytest.mark.cbatch
 LAYERS, HEADS, HEAD_SIZE, VOCAB, MAX_LEN = 48, 25, 64, 50257, 1024
 SLOTS, PAGE_SIZE, NUM_PAGES = 4, 16, 129
 PER_SEQ = MAX_LEN // PAGE_SIZE
+# the width the model holds its tied table in: 1,600 columns are 12.5 lane
+# tiles of 128, padded with zeros to 13 (nlp/transformer.py:lane_aligned)
+HIDDEN = HEADS * HEAD_SIZE
+TABLE_WIDTH = -(-HIDDEN // 128) * 128
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +49,9 @@ def one_chip(topo):
 @pytest.fixture(scope="module")
 def paged(one_chip):
     """The model's hooks and its arguments as shapes on the described
-    chip: ``(lm, params, pool, i32)``.  ``eval_shape`` allocates nothing."""
+    chip: ``(lm, params, pool, i32)``, the tied table in the shape the
+    model holds it in (``(VOCAB, TABLE_WIDTH)``).  ``eval_shape``
+    allocates nothing."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.nlp.transformer import (TransformerLM,
@@ -58,11 +64,12 @@ def paged(one_chip):
 
     # a one-layer model of distinct toy sizes gives the parameter tree;
     # its sizes are then read as the real ones (drawing 1.56 B weights
-    # on the host to learn their shapes would take minutes)
+    # on the host to learn their shapes would take minutes); the toy's
+    # table is held one lane tile wide, 128 columns, like no other leaf
     lm = TransformerLM(TransformerLMConfig(vocabSize=3, nLayers=1, nHeads=1,
                                            headSize=8, ffnMult=4, maxLen=5))
-    real = {3: VOCAB, 5: MAX_LEN, 8: HEADS * HEAD_SIZE,
-            32: 4 * HEADS * HEAD_SIZE}
+    real = {3: VOCAB, 5: MAX_LEN, 8: HIDDEN, 32: 4 * HIDDEN,
+            128: TABLE_WIDTH}
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         tuple(real[n] for n in a.shape), a.dtype), lm.params)
     params["layers"] = params["layers"] * LAYERS
@@ -89,6 +96,40 @@ def _pool_copies(compiled, pool):
         if m and m.group(2).startswith(shape):
             found.append(m.group(1))
     return found
+
+
+def _table_copies(compiled):
+    """``copy`` instructions of the optimized program whose result is as
+    tall as the tied table (``f32[50257,...]``): each moves the whole
+    table, 322 MB, every call."""
+    return re.findall(rf"(%?[\w.\-]+) = f32\[{VOCAB},\d+\]\S* copy\(",
+                      compiled.as_text())
+
+
+def _table_as_stored(compiled):
+    """The tied table as the program's ``entry_computation_layout`` has
+    it, ``f32[50257,W]{minor-to-major...}``: how the array lies in HBM."""
+    return re.search(rf"f32\[{VOCAB},\d+\]\{{[\d,]+", compiled.as_text()
+                     .splitlines()[0]).group(0)
+
+
+def _with_plain_table(params):
+    """``params`` with the table as the checkpoint has it, ``(VOCAB,
+    1600)``: what a caller hands a program around the model's setter."""
+    import jax
+    emb = params["emb"]
+    return {**params, "emb": jax.ShapeDtypeStruct(
+        (VOCAB, HIDDEN), emb.dtype, sharding=emb.sharding)}
+
+
+def _lower_step(lm, params, pool, i32):
+    return lm.buildPagedDecodeFn().lower(
+        params, pool, pool, i32(SLOTS, 1), i32(SLOTS, 1),
+        i32(SLOTS, PER_SEQ), i32(SLOTS), i32(SLOTS))
+
+
+def _lower_prefill(lm, params, pool, i32):
+    return lm._prefillRawFn.lower(params, i32(1, 256), i32(1))
 
 
 def _assert_in_place(compiled, pool, what):
@@ -123,9 +164,7 @@ def paged_step(paged):
         paged_kernel_kv_passes, paged_kernel_lowerings)
     lm, params, pool, i32 = paged
     before = paged_kernel_lowerings()
-    compiled = lm.buildPagedDecodeFn().lower(
-        params, pool, pool, i32(SLOTS, 1), i32(SLOTS, 1),
-        i32(SLOTS, PER_SEQ), i32(SLOTS), i32(SLOTS)).compile()
+    compiled = _lower_step(lm, params, pool, i32).compile()
     return compiled, paged_kernel_lowerings() - before, \
         paged_kernel_kv_passes()
 
@@ -150,10 +189,64 @@ def test_paged_decode_step_updates_the_pool_in_place(paged, paged_step):
     gathered = f"f32[{SLOTS * PER_SEQ},{PAGE_SIZE},{HEADS * HEAD_SIZE}]"
     split = f"f32[{SLOTS},{MAX_LEN},{HEADS},{HEAD_SIZE}]"
     assert gathered not in text and split not in text
-    # found: 407,141,376 bytes (403,424,256 with the gather: the
-    # temporaries are the tied embedding table re-laid for the logits,
-    # 321 MB, not attention's); one pool is 634 MB
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
+    # the tied table is read as it lies by the lookup and by the head:
+    # held in whole lane tiles (13 of 128 columns) it lies row-minor in
+    # HBM and one layout serves both.  Found: 69,622,272 bytes of
+    # temporaries; 407,173,632 with the table 1,600 wide, 322 MB of them
+    # the whole table copied every step (``copy.725``: at 12.5 lane tiles
+    # the array lies vocabulary-minor, whatever form the head's product is
+    # written in, and the lookup gets a row-minor copy -- the case
+    # below); one pool is 634 MB
+    assert _table_as_stored(compiled) == f"f32[{VOCAB},{TABLE_WIDTH}]{{1,0"
+    assert not _table_copies(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_prefill_reads_the_tied_table_as_it_lies(paged):
+    """The 256 bucket's prefill (``jit_run``) looks up 256 rows and
+    multiplies one: no copy of the table either (found with the table
+    1,600 wide: ``copy.394``, 384,142,848 bytes of temporaries)."""
+    compiled = _lower_prefill(*paged).compile()
+    assert not _table_copies(compiled)
+    # found: 0 bytes beside its outputs (the K/V stacks, 157 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_laying_the_table_out_holds_no_second_table(paged):
+    """The one-time pad (``_pad_columns``, run when a ``(50257, 1600)``
+    tree is assigned to ``lm.params``): the compiler keeps such an array
+    vocabulary-minor and the padded one row-minor, so one ``jnp.pad`` of
+    the whole table holds a table-sized temporary beside its result
+    (found: 334,565,376 bytes, and as much under ``peak_bytes_reserved``
+    on the chip); block by block into a table updated in place, none."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.transformer import _pad_columns
+    _lm, params, _pool, _i32 = paged
+    plain = _with_plain_table(params)["emb"]
+    pad = TABLE_WIDTH - HIDDEN
+    whole = jax.jit(lambda e: jnp.pad(e, ((0, 0), (0, pad)))).lower(
+        plain).compile()
+    assert whole.memory_analysis().temp_size_in_bytes > 0.33e9
+    compiled = _pad_columns.lower(plain, pad).compile()
+    assert compiled.memory_analysis().output_size_in_bytes > 0.33e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6   # found: 0
+
+
+@pytest.mark.parametrize("lower", [_lower_step, _lower_prefill],
+                         ids=["step", "prefill"])
+def test_a_table_of_12_5_lane_tiles_is_copied_whole(paged, lower):
+    """What the padding is for: handed the table as the checkpoint has
+    it, ``(50257, 1600)``, the same code gives the parent's program --
+    one table-sized copy a call and its 322 MB among the temporaries.
+    An array whose rows are no whole number of lane tiles lies
+    VOCABULARY-minor in HBM (dimension 0 minor), which suits the head;
+    the lookup needs rows and gets a row-minor copy of the whole table."""
+    lm, params, pool, i32 = paged
+    compiled = lower(lm, _with_plain_table(params), pool, i32).compile()
+    assert _table_as_stored(compiled) == f"f32[{VOCAB},{HIDDEN}]{{0,1"
+    assert len(_table_copies(compiled)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes > 0.32e9
 
 
 def _rows_and_lanes(topo, one_chip, chips):
