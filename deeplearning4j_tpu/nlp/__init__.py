@@ -11,6 +11,8 @@ from deeplearning4j_tpu.nlp.bert_iterator import BertIterator  # noqa: F401
 from deeplearning4j_tpu.nlp.jamba import JambaConfig, JambaLM  # noqa: F401
 from deeplearning4j_tpu.nlp.keye_vl import KeyeVLConfig, KeyeVLLM  # noqa: F401
 from deeplearning4j_tpu.nlp.ling import LingConfig, LingLM  # noqa: F401
+from deeplearning4j_tpu.nlp.nemotron_h import (  # noqa: F401
+    NemotronHConfig, NemotronHLM)
 from deeplearning4j_tpu.nlp.olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig, OlmoHybridLM)
 from deeplearning4j_tpu.nlp.pangu_moe import (  # noqa: F401

@@ -31,7 +31,10 @@ a real token of the step chose, their ids scalar-prefetched; elsewhere
 the dense form) and :func:`moe_share_grouped` (pairs sorted by expert,
 one grouped matmul a projection: a prefill, where all-over-all would be
 16 times the work).  :func:`moe_share_counts` counts what was routed
-where.
+where.  An expert is what the caller hands over: three matrices ``Eg, Eu,
+Ed`` and ``act(g, u)`` of both pre-activations (``silu(g) * u`` where
+none is given), or, with ``Eg=None``, two matrices and ``act(u)`` of the
+one (:func:`relu2`): ``Ed act(x Eu)``, of whatever widths in and out.
 """
 from __future__ import annotations
 
@@ -56,7 +59,7 @@ __all__ = ["init_moe", "moe_apply", "moe_apply_expert_parallel",
            "MoELayer", "MoEFeedForwardLayer", "route_sigmoid_topk",
            "route_softmax_topk",
            "moe_share_dense", "moe_share_step", "moe_share_grouped",
-           "moe_share_counts", "moe_step_kernel_lowerings"]
+           "moe_share_counts", "moe_step_kernel_lowerings", "relu2"]
 
 
 def init_moe(key, n_experts: int, d_in: int, d_hidden: int, d_out: int,
@@ -262,22 +265,45 @@ def _share_weights(idx, w, lo: int, n: int, real=None):
     return c if real is None else jnp.where(real[:, None], c, jnp.float32(0))
 
 
-def moe_share_dense(x, idx, w, Eg, Eu, Ed, lo: int, real=None):
+def _silu_gate(g, u):
+    return jax.nn.silu(g) * u
+
+
+def relu2(u):
+    """``relu(u)²``: the activation of an expert of two matrices."""
+    return jnp.square(jax.nn.relu(u))
+
+
+def _expert(Eg, Eu, act):
+    """``(the matrices x is multiplied by, the activation of their
+    products)``: gate and up under ``act(g, u)`` (``silu(g) * u`` where
+    none is given), or with ``Eg=None`` the one matrix under ``act(u)``."""
+    if Eg is None:
+        if act is None:
+            raise ValueError("an expert of two matrices names its "
+                             "activation: act(u)")
+        return (Eu,), act
+    return (Eg, Eu), act or _silu_gate
+
+
+def moe_share_dense(x, idx, w, Eg, Eu, Ed, lo: int, real=None, act=None):
     """The held experts' part of the layer's output, every held expert
     over every token: ``sum_e c[t, e] Ed_e(silu(x Eg_e) * x Eu_e)`` with
     ``c`` the token's weight for expert ``lo + e``, 0 where it did not
     choose it (and, given ``real (T,)``, for a token that is not real).
-    ``x (T, d)``; ``Eg, Eu (n, d, f)``, ``Ed (n, f, d)``; float32 out.
-    The down-projection contracts experts and width at once, so the
-    weighted sum over experts is inside one matmul."""
-    n, _, f = Eg.shape
+    ``x (T, d)``; ``Eg, Eu (n, d, f)``, ``Ed (n, f, d')``; float32 out.
+    With ``Eg=None`` an expert is ``Ed_e act(x Eu_e)`` (see the module's
+    docstring).  The down-projection contracts experts and width at
+    once, so the weighted sum over experts is inside one matmul."""
+    ins, act = _expert(Eg, Eu, act)
+    n, _, f = Eu.shape
     T = x.shape[0]
-    dt = Eg.dtype
+    dt = Eu.dtype
     x = x.astype(dt)
     c = _share_weights(idx, w, lo, n, real)
     up = lambda W: jnp.einsum("td,edf->tef", x, W,
                               preferred_element_type=jnp.float32)
-    h = jax.nn.silu(up(Eg)) * up(Eu) * c[..., None]           # (T, n, f)
+    h = act(*map(up, ins)) * c[..., None]                     # (T, n, f)
     return jnp.matmul(h.reshape(T, n * f).astype(dt),
                       Ed.reshape(n * f, -1),
                       preferred_element_type=jnp.float32)
@@ -292,8 +318,30 @@ def moe_share_dense(x, idx, w, Eg, Eu, Ed, lo: int, real=None):
 #: at ``(32, 7680) x (16, 7680, 2048)`` with 4 / 10 / 16 experts hit (PR
 #: 37): 702 / 732 / 741 GB/s over the hit experts' bytes, where tiles of
 #: 256 lanes read 678 / 706 / 713 (a ``(d, 256)`` column block is 480
-#: runs of 8 KB) and 1,024 do not fit
+#: runs of 8 KB) and 1,024 do not fit.  An expert of two matrices at
+#: ``(1024, 2688)`` / ``(2688, 1024)`` takes its width WHOLE
+#: (:func:`_expert_tile`): inside a step of 64 slots with ~490 of 640 held
+#: experts hit the five calls read 749 GB/s, 91.4% of the peak (a traced
+#: run of ``nemotron3_super.agent_closed64``, PR 48); three tiles of 896
+#: lanes were not timed
 _EXPERT_TILE = 512
+#: what the blocks of one place may take of the 64 MB, double-buffered
+_EXPERT_BLOCKS_BYTES = 48 << 20
+
+
+def _expert_tile(f: int, rows: int, itemsize: int) -> int:
+    """Lanes of the width ``f`` a place works on, ``rows`` being the
+    weights' rows a lane of the width brings (``d`` for each matrix into
+    the width, ``d'`` for the one out of it): :data:`_EXPERT_TILE` where
+    it divides ``f``; else the width whole, or where that does not fit
+    the largest part of it in whole lane tiles that does (2,688 = 21 x
+    128 at 1,024 rows twice: whole, 22 MB)."""
+    if f % _EXPERT_TILE == 0:
+        return _EXPERT_TILE
+    fits = lambda t: 2 * rows * t * itemsize <= _EXPERT_BLOCKS_BYTES
+    if fits(f) or f % 128:
+        return f
+    return max(t for t in range(128, f, 128) if f % t == 0 and fits(t))
 
 
 def _hit_list(idx, lo: int, n: int, real):
@@ -307,13 +355,15 @@ def _hit_list(idx, lo: int, n: int, real):
     return hit.astype(jnp.int32), jnp.sum(chosen).astype(jnp.int32)
 
 
-def _hit_kernel(_hit_ref, x_ref, c_ref, g_ref, u_ref, d_ref, o_ref):
+def _hit_kernel(act, _hit_ref, x_ref, c_ref, *refs):
     """One place of the grid: one tile of one hit expert's width.  ``x``
     (all rows) against the tile's columns of the gate and the up
-    projection, the token's weight for this expert on the product, and
-    the tile's rows of the down projection added into ``o_ref``, which
-    stays in VMEM over the whole grid."""
+    projection (or of the one matrix of an expert of two), the token's
+    weight for this expert on their activation, and the tile's rows of
+    the down projection added into ``o_ref``, which stays in VMEM over
+    the whole grid."""
     f32 = jnp.float32
+    *in_refs, d_ref, o_ref = refs
 
     @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _():
@@ -321,15 +371,16 @@ def _hit_kernel(_hit_ref, x_ref, c_ref, g_ref, u_ref, d_ref, o_ref):
 
     x = x_ref[...]
     up = lambda ref: jnp.dot(x, ref[...], preferred_element_type=f32)
-    h = jax.nn.silu(up(g_ref)) * up(u_ref) * c_ref[...]       # (T, tile)
+    h = act(*map(up, in_refs)) * c_ref[...]                   # (T, tile)
     o_ref[...] += jnp.dot(h.astype(d_ref.dtype), d_ref[...],
                           preferred_element_type=f32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _hit_call(hit, nhit, x, c, Eg, Eu, Ed, *, interpret):
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+def _hit_call(hit, nhit, x, c, *E, act=_silu_gate, interpret):
     """The kernel's call: ``x (T, d)`` in the weights' dtype, ``c (n, T,
-    1)`` float32, ``T`` whole sublane tiles.  The grid walks ``nhit``
+    1)`` float32, ``T`` whole sublane tiles, ``E`` the matrices into the
+    width and, last, the one out of it.  The grid walks ``nhit``
     experts x the tiles of their width; the index maps name the blocks of
     expert ``hit[i]`` in the stacked weights, which go in whole (no
     expert is sliced out or copied first), and the pipeline copies the
@@ -339,13 +390,14 @@ def _hit_call(hit, nhit, x, c, Eg, Eu, Ed, *, interpret):
     the weights as arguments: every expert layer of a step is then the
     same computation, traced and lowered to Mosaic once a program (see
     ``nn/conf/attention.py:_pages_call``)."""
-    n, d, f = Eg.shape
+    *ins, Ed = E
+    n, d, f = ins[0].shape
     T, dout = x.shape[0], Ed.shape[-1]
-    tile = _EXPERT_TILE if f % _EXPERT_TILE == 0 else f
+    tile = _expert_tile(f, len(ins) * d + dout, Ed.dtype.itemsize)
     # index maps: ``i * 0`` and not ``0`` (the package enables x64, and a
     # bare literal would be an int64 Mosaic has not)
     return pl.pallas_call(
-        _hit_kernel,
+        functools.partial(_hit_kernel, act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(jnp.maximum(nhit, 1), f // tile),
@@ -353,10 +405,9 @@ def _hit_call(hit, nhit, x, c, Eg, Eu, Ed, *, interpret):
                 pl.BlockSpec((T, d), lambda i, j, hit: (i * 0, i * 0)),
                 pl.BlockSpec((None, T, 1),
                              lambda i, j, hit: (hit[i], i * 0, i * 0)),
-                pl.BlockSpec((None, d, tile),
-                             lambda i, j, hit: (hit[i], i * 0, j)),
-                pl.BlockSpec((None, d, tile),
-                             lambda i, j, hit: (hit[i], i * 0, j)),
+                *(pl.BlockSpec((None, d, tile),
+                               lambda i, j, hit: (hit[i], i * 0, j))
+                  for _ in ins),
                 pl.BlockSpec((None, tile, dout),
                              lambda i, j, hit: (hit[i], j, i * 0)),
             ],
@@ -368,10 +419,11 @@ def _hit_call(hit, nhit, x, c, Eg, Eu, Ed, *, interpret):
             vmem_limit_bytes=64 << 20),
         name="moe_share_step",
         interpret=interpret,
-    )(hit, x, c, Eg, Eu, Ed)
+    )(hit, x, c, *E)
 
 
-def _share_hit(x, idx, w, Eg, Eu, Ed, lo: int, real, interpret=False):
+def _share_hit(x, idx, w, Eg, Eu, Ed, lo: int, real, act=None,
+               interpret=False):
     """:func:`moe_share_dense`'s sum over the held experts that a real
     token of ``x`` chose, expert by expert, through the kernel: an expert
     nobody chose is not read.  Same operands and precision as the dense
@@ -379,13 +431,14 @@ def _share_hit(x, idx, w, Eg, Eu, Ed, lo: int, real, interpret=False):
     ``c``); the sum over experts runs in the order of the hit list where
     the dense form contracts them in one matmul.  Rows are padded to
     whole sublane tiles (16: a bfloat16 tile)."""
-    n = Eg.shape[0]
+    ins, act = _expert(Eg, Eu, act)
+    n = Eu.shape[0]
     T = x.shape[0]
     pad = -T % 16
     c = jnp.pad(_share_weights(idx, w, lo, n, real), ((0, pad), (0, 0)))
     out = _hit_call(*_hit_list(idx, lo, n, real),
-                    jnp.pad(x.astype(Eg.dtype), ((0, pad), (0, 0))),
-                    c.T[..., None], Eg, Eu, Ed, interpret=interpret)
+                    jnp.pad(x.astype(Eu.dtype), ((0, pad), (0, 0))),
+                    c.T[..., None], *ins, Ed, act=act, interpret=interpret)
     return out[:T]
 
 
@@ -402,42 +455,51 @@ def moe_step_kernel_lowerings() -> int:
     return _stepKernelLowerings[0]
 
 
-def _share_step_lowering(ctx, *args, lo):
+def _share_step_lowering(ctx, *args, lo, act):
     kernel = lowered_for_one_tpu(ctx)
     _stepKernelLowerings[0] += kernel
     form = _share_hit if kernel else moe_share_dense
-    return mlir.lower_fun(lambda *a: form(*a[:-1], lo, a[-1]),
+
+    def lowered(x, idx, w, *E, real):
+        # three matrices an expert, or two: no gate
+        return form(x, idx, w, *((None,) * (3 - len(E)) + E), lo, real,
+                    act=act)
+    return mlir.lower_fun(lambda *a: lowered(*a[:-1], real=a[-1]),
                           multiple_results=False)(ctx, *args)
 
 
 _share_step_p = jex_core.Primitive("moe_share_step")
 
 
-@functools.partial(jax.jit, static_argnames=("lo",))
-def _share_step_eager(*args, lo):
+@functools.partial(jax.jit, static_argnames=("lo", "act"))
+def _share_step_eager(*args, lo, act):
     """Outside any jit the primitive runs as a program of its own."""
-    return _share_step_p.bind(*args, lo=lo)
+    return _share_step_p.bind(*args, lo=lo, act=act)
 
 
 _share_step_p.def_impl(_share_step_eager)
 _share_step_p.def_abstract_eval(
-    lambda x, idx, w, Eg, Eu, Ed, real, *, lo: jax.core.ShapedArray(
-        (x.shape[0], Ed.shape[-1]), jnp.float32))
+    lambda x, idx, w, *E_real, lo, act: jax.core.ShapedArray(
+        (x.shape[0], E_real[-2].shape[-1]), jnp.float32))
 mlir.register_lowering(_share_step_p, _share_step_lowering)
 
 
-def moe_share_step(x, idx, w, Eg, Eu, Ed, lo: int, real):
+def moe_share_step(x, idx, w, Eg, Eu, Ed, lo: int, real, act=None):
     """:func:`moe_share_dense` as a decode step runs it, where the
     experts' bytes are the time: an expert's weights are read only if a
     ``real (T,)`` token of this step chose it.  Chosen by what the
     program is lowered for, not by a knob (the rule of
     ``paged_attention``): one TPU -> the kernel over the hit experts
     (:func:`_share_hit`); the CPU or several devices -> the dense form.
-    The rows of tokens that are not real come back as zeros in both."""
-    return _share_step_p.bind(x, idx, w, Eg, Eu, Ed, real, lo=lo)
+    The rows of tokens that are not real come back as zeros in both.
+    ``Eg=None`` and ``act`` as :func:`moe_share_dense` takes them."""
+    E = (Eu, Ed) if Eg is None else (Eg, Eu, Ed)
+    return _share_step_p.bind(x, idx, w, *E, real, lo=lo,
+                              act=_expert(Eg, Eu, act)[1])
 
 
-def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None):
+def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None,
+                      act=None):
     """:func:`moe_share_dense`'s sum by GROUPS: the token-expert pairs
     whose expert is held, sorted by expert, and one grouped matmul
     (``lax.ragged_dot``) a projection over the rows of each expert's
@@ -453,11 +515,13 @@ def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None):
     rows would serialise).  Every pass streams all the held experts'
     weights whatever its rows (PERF.md section 5), so a share that
     expects more than one held pair a token (128 of 512 experts at 8 a
-    token: two) names the rows that hold them in ONE pass."""
+    token: two) names the rows that hold them in ONE pass.  ``Eg=None``
+    and ``act`` as :func:`moe_share_dense` takes them."""
+    ins, act = _expert(Eg, Eu, act)
     T, k = idx.shape
     R = T if passRows is None else passRows
-    n = Eg.shape[0]
-    dt = Eg.dtype
+    n = Eu.shape[0]
+    dt = Eu.dtype
     f32 = jnp.float32
     x = x.astype(dt)
     e, here = _held(idx, lo, n, real)
@@ -481,7 +545,7 @@ def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None):
         sizes = jnp.diff(edge, prepend=0).astype(jnp.int32)
         tok = rows // k
         xs = x[tok]
-        h = jax.nn.silu(gmm(xs, Eg, sizes)) * gmm(xs, Eu, sizes) \
+        h = act(*(gmm(xs, W, sizes) for W in ins)) \
             * jnp.where(live, wflat[rows], f32(0))[:, None]
         y = jnp.where(live[:, None], gmm(h.astype(dt), Ed, sizes), f32(0))
         home = (jnp.arange(T, dtype=jnp.int32)[:, None] == tok[None, :]) \

@@ -62,6 +62,7 @@ from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_kernel_kv_passes,
                                                   paged_kernel_lowerings,
                                                   sparse_in_place_lowerings)
+from deeplearning4j_tpu.nlp.mamba import ssd_step_kernel_lowerings
 from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
@@ -611,8 +612,9 @@ class ContinuousBatcher:
         tok0 = jnp.zeros((S, 1), jnp.int32)
         pt = jnp.asarray(self.pool.pageTable)
         step = self._stepFns["step"]
-        lowered, experts, inPlace = paged_kernel_lowerings(), \
-            moe_step_kernel_lowerings(), sparse_in_place_lowerings()
+        lowered, experts, inPlace, ssd = paged_kernel_lowerings(), \
+            moe_step_kernel_lowerings(), sparse_in_place_lowerings(), \
+            ssd_step_kernel_lowerings()
         prev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, tok0, pt, zeros, zeros)
         # one kernel lowering for each layer whose rows the step read
@@ -631,6 +633,8 @@ class ContinuousBatcher:
         sm.moe_step_kernel().set(
             1 if moe_step_kernel_lowerings() > experts else 0,
             model=self.name)
+        sm.ssd_step_kernel().set(
+            1 if ssd_step_kernel_lowerings() > ssd else 0, model=self.name)
         sm.sparse_read_in_place().set(
             1 if spec.indexWidth and sparse_in_place_lowerings() - inPlace
             >= spec.pagedLayers else 0, model=self.name)

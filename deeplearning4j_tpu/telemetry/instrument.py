@@ -473,6 +473,16 @@ class ServingMetrics:
             "expert layers x decode steps)",
             labelnames=("model",))
 
+    def ssd_step_kernel(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_ssd_step_kernel",
+            "1 when the batcher's decode step was built with the kernel "
+            "that reads every Mamba-2 (SSD) state once and writes it once "
+            "in place (nlp/mamba.py:ssd_state_step, lowered for one TPU), "
+            "0 when it runs the recurrence as jax.numpy (the CPU, several "
+            "devices) and for a model without such a layer",
+            labelnames=("model",))
+
     def tied_table_lane_aligned(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_tied_table_lane_aligned",

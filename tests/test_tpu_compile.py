@@ -1218,3 +1218,150 @@ def test_ling_prefill_chunks_its_delta_rule_and_fits_beside_the_step(
     poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
     assert write.memory_analysis().alias_size_in_bytes >= poolBytes
     assert write.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+# -- Nemotron-3-Super as benchmark/configs/nemotron3_super.json serves it:
+# published blocks 27-37 (MEMEMEMEM*E: five Mamba-2 blocks, five expert
+# blocks holding 128 of 512 latent experts, one attention block), a quarter
+# of the vocabulary, every width as published, bfloat16; 64 slots of 5,120
+# positions (40 pages of 128) of K and V rows of 2 heads in the attention
+# block, five float32 SSD states of 4.19 MB a slot beside them
+NEMO_SLOTS, NEMO_CAP, NEMO_PAGE, NEMO_BUCKET = 64, 5120, 128, 2048
+
+
+@pytest.fixture(scope="module")
+def nemotron(one_chip):
+    """``(lm, params, pool arrays, i32, the compiled decode step, the
+    attention and the expert kernels lowered for it)``: shapes on the
+    described chip."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nlp.nemotron_h import (NemotronHConfig,
+                                                   NemotronHLM)
+    from deeplearning4j_tpu.nn.conf.attention import paged_kernel_lowerings
+    from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
+    from deeplearning4j_tpu.remote import KVCachePool
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    lm = NemotronHLM(NemotronHConfig(
+        vocabSize=32768, pattern="MEMEMEMEM*E", firstBlock=27,
+        hiddenSize=4096, nHeads=32, nKvHeads=2, headDim=128, mambaHeads=128,
+        mambaHeadDim=64, nGroups=8, stateSize=128, convKernel=4, chunk=128,
+        latentSize=1024, expertSize=2688, sharedSize=5376, routerWidth=512,
+        expertsPerToken=22, expertsHeld=(0, 128), maxLen=NEMO_CAP), params={})
+    params = on_chip(jax.eval_shape(lm._init_params))
+    perSeq = NEMO_CAP // NEMO_PAGE
+    pool = on_chip(jax.eval_shape(lambda: KVCachePool.forSpec(
+        lm.cacheSpec(), NEMO_PAGE, 1 + NEMO_SLOTS * perSeq, NEMO_SLOTS,
+        perSeq).arrays))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    before = paged_kernel_lowerings(), moe_step_kernel_lowerings()
+    step = lm.buildPagedDecodeFn().lower(
+        params, *pool, i32(NEMO_SLOTS, 1), i32(NEMO_SLOTS, 7),
+        i32(NEMO_SLOTS, perSeq), i32(NEMO_SLOTS), i32(NEMO_SLOTS)).compile()
+    return lm, params, pool, i32, step, (
+        paged_kernel_lowerings() - before[0],
+        moe_step_kernel_lowerings() - before[1])
+
+
+def test_nemotron_decode_step_fits_and_updates_its_states_in_place(nemotron):
+    """Of the decode step at the cell's sizes: 11 kernel calls, 5 that
+    update a Mamba-2 block's states where they lie (a slot's 4 MB read
+    once and written once), 5 over the hit experts of 128 held (two
+    matrices an expert, the width 2,688 whole) and 1 paged read with 16
+    query heads on each of 2 KV heads."""
+    lm, params, pool, i32, compiled, kernelsLowered = nemotron
+    perSeq = NEMO_CAP // NEMO_PAGE
+    mem = compiled.memory_analysis()
+    # found: 11.01 GB of arguments (9.30 of weights, 0.34 of pages, 1.34
+    # of SSD states, 0.02 of windows) + 0.027 of temporaries
+    assert [a.shape for a in pool] == [
+        (1, 1 + NEMO_SLOTS * perSeq, NEMO_PAGE, 256)] * 2 + [
+        (5, NEMO_SLOTS, 128, 64, 128), (5, NEMO_SLOTS, 3, 10240),
+        (1, NEMO_SLOTS, 3)]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    # no temporary the size of a layer's held experts (1.41 GB) or states
+    assert mem.temp_size_in_bytes < 0.1e9
+    _assert_one_step_program(compiled, pool)
+    assert not _whole_array_copies(compiled, pool)
+    text = compiled.as_text()
+    # (the five expert layers are one lowering: same shapes, same rule)
+    assert kernelsLowered == (1, 1)
+    kernels = re.findall(
+        r"^\s*%?([a-z_]+)[\w.\-]* = .*? custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert sorted(kernels) == ["moe_share_step"] * 5 + ["paged_attention"] \
+        + ["ssd_step"] * 5, kernels
+    # no layer's states (268 MB) are sliced out, updated and put back
+    assert not re.search(
+        rf"= f32\[(?:1,)?{NEMO_SLOTS},(?:128,64,128|8,16,64,128)\]\S* "
+        r"(?:copy|dynamic-slice|slice|fusion)\(", text)
+    # the state kernels and the experts' carry the scopes the benchmark
+    # cuts the step by
+    for scope in ("/ssd_step/", "/latent_moe/moe_share_step/"):
+        scoped = [ln for ln in text.splitlines() if scope in ln]
+        assert sum("custom_call_target=\"tpu_custom_call\"" in ln
+                   for ln in scoped) == 5
+    # nothing is computed for all 128 held experts (no (slots, 128, 2688))
+    assert not re.search(rf"\[{NEMO_SLOTS},(?:128,2688|344064)\]", text)
+
+
+def test_nemotron_prefill_chunks_its_ssd_and_fits_beside_the_step(nemotron):
+    import jax
+    lm, params, pool, i32, step, _ = nemotron
+    traced = lm._prefillRawFn.at(NEMO_BUCKET).trace(
+        params, i32(1, NEMO_BUCKET), i32(1))
+    # the chunked form: the only loops over positions are the scans over
+    # the 16 chunks of each Mamba-2 block and the attention block's 4
+    # query blocks -- nothing runs 2,048 times
+    assert sorted(_scan_lengths(traced.jaxpr.jaxpr)) == [4] + [16] * 5
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert "ssd_prefill" in text and "/ssd_step/" not in text
+    # the held experts are multiplied by GROUP (two ragged dots a layer)
+    assert text.count("ragged-dot") >= 2 * 5
+    mem = compiled.memory_analysis()
+    # found: 11.01 + 0.045 (the step) + 0.55 + 0.02 (the 2,048 prefill);
+    # the 4,096 prefill holds 0.98 GB of temporaries
+    assert mem.temp_size_in_bytes < 0.8e9
+    step = step.memory_analysis()
+    assert step.argument_size_in_bytes + step.temp_size_in_bytes \
+        + mem.temp_size_in_bytes + mem.output_size_in_bytes < 12.5e9
+    state = jax.eval_shape(lm._prefillRawFn, params, i32(1, NEMO_BUCKET),
+                           i32(1))[1:]
+    parts = [jax.ShapeDtypeStruct(p.shape[:1] + p.shape[2:], p.dtype,
+                                  sharding=pool[0].sharding) for p in state]
+    write = lm.buildPagedPrefillWriteFn().lower(
+        *pool, *parts, i32(NEMO_BUCKET // NEMO_PAGE), i32()).compile()
+    assert not _whole_array_copies(write, pool)
+    poolBytes = sum(a.size * a.dtype.itemsize for a in pool)
+    assert write.memory_analysis().alias_size_in_bytes >= poolBytes
+    assert write.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_expert_step_kernel_compiles_for_an_expert_of_two_matrices(one_chip):
+    """``_hit_call`` by itself at this model's shapes: 64 slots' latent
+    rows against 128 held experts of ``(1024, 2688)`` and ``(2688,
+    1024)`` bfloat16 under ``relu2``, the width 2,688 (21 lane tiles) in
+    ONE place of the grid: two double-buffered blocks of 5.5 MB each."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.parallel import moe
+    n, d, f, T = 128, 1024, 2688, 64
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = moe._hit_call.lower(
+        sds((n,)), sds(()), sds((T, d), jnp.bfloat16),
+        sds((n, T, 1), jnp.float32), sds((n, d, f), jnp.bfloat16),
+        sds((n, f, d), jnp.bfloat16), act=moe.relu2,
+        interpret=False).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "moe_share_step" in text
+    assert moe._expert_tile(f, 2 * d, 2) == f
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
