@@ -29,12 +29,15 @@ reference form, and what runs on the CPU or over several devices),
 time: lowered for one TPU it is a kernel that reads only the held experts
 a real token of the step chose, their ids scalar-prefetched; elsewhere
 the dense form) and :func:`moe_share_grouped` (pairs sorted by expert,
-one grouped matmul a projection: a prefill, where all-over-all would be
-16 times the work).  :func:`moe_share_counts` counts what was routed
-where.  An expert is what the caller hands over: three matrices ``Eg, Eu,
-Ed`` and ``act(g, u)`` of both pre-activations (``silu(g) * u`` where
-none is given), or, with ``Eg=None``, two matrices and ``act(u)`` of the
-one (:func:`relu2`): ``Ed act(x Eu)``, of whatever widths in and out.
+each expert's matrices over the rows of its own group: a prefill, where
+all-over-all would be 16 times the work; lowered for one TPU a pass is
+one kernel that walks the (row tile, expert) pairs that meet and streams
+each such expert's weights once, elsewhere one ``lax.ragged_dot`` a
+projection).  :func:`moe_share_counts` counts what was routed where.  An
+expert is what the caller hands over: three matrices ``Eg, Eu, Ed`` and
+``act(g, u)`` of both pre-activations (``silu(g) * u`` where none is
+given), or, with ``Eg=None``, two matrices and ``act(u)`` of the one
+(:func:`relu2`): ``Ed act(x Eu)``, of whatever widths in and out.
 """
 from __future__ import annotations
 
@@ -59,7 +62,8 @@ __all__ = ["init_moe", "moe_apply", "moe_apply_expert_parallel",
            "MoELayer", "MoEFeedForwardLayer", "route_sigmoid_topk",
            "route_softmax_topk",
            "moe_share_dense", "moe_share_step", "moe_share_grouped",
-           "moe_share_counts", "moe_step_kernel_lowerings", "relu2"]
+           "moe_share_counts", "moe_step_kernel_lowerings",
+           "moe_grouped_kernel_lowerings", "relu2"]
 
 
 def init_moe(key, n_experts: int, d_in: int, d_hidden: int, d_out: int,
@@ -498,25 +502,219 @@ def moe_share_step(x, idx, w, Eg, Eu, Ed, lo: int, real, act=None):
                               act=_expert(Eg, Eu, act)[1])
 
 
+# -- the prefill's form: pairs sorted by expert, one grouped pass ---------
+
+#: rows of the sorted pairs a place of the grouped kernel's grid works on.
+#: A visit multiplies the WHOLE tile by its expert's blocks and masks the
+#: rows outside the expert's group, so a group's two edges cost up to a
+#: tile of wasted rows each: 128 is the fewest rows that keep the MXU's
+#: 128 x 128 weight tiles busy, and at 819 GB/s over 197 TFLOP/s a tile of
+#: up to ~240 rows multiplies a freshly streamed block in less time than
+#: the next block takes to arrive.  Alone on a v5e tiles of 256 rows read
+#: the same to 1% at every caller's shape they fit (PERF.md section 5)
+_GROUP_ROWS = 128
+
+
+def _rows_ragged(xs, wrow, edge, *E, act):
+    """A pass's rows through their experts, the compiler's way: one
+    ``lax.ragged_dot`` a matrix over the rows of each expert's group.
+    ``xs (R, d)`` the sorted pairs' tokens, ``wrow (R,)`` float32 the
+    pair's weight (0 for a dead row), ``edge (n,)`` where each expert's
+    group ends among the rows; ``E`` the matrices into the width and,
+    last, the one out of it.  Returns ``(R, d')`` float32."""
+    *ins, Ed = E
+    sizes = jnp.diff(edge, prepend=0).astype(jnp.int32)
+    gmm = lambda a, W: lax.ragged_dot(a, W, sizes,
+                                      preferred_element_type=jnp.float32)
+    h = act(*(gmm(xs, W) for W in ins)) * wrow[:, None]
+    return gmm(h.astype(Ed.dtype), Ed)
+
+
+def _visits(edge, tile: int, tiles: int):
+    """The grouped kernel's walk: every (row tile, expert) whose group
+    has a row in the tile, tiles rising and experts rising within a tile,
+    as ``(tile, expert, group start, group end)`` of ``tiles + n - 1``
+    int32 each (the most there can be: a group's first tile is no earlier
+    than the last of the group before) and how many are real.  Built
+    with comparisons and a cumsum: no sort, no loop."""
+    n = edge.shape[0]
+    start = jnp.concatenate([jnp.zeros((1,), edge.dtype), edge[:-1]])
+    first = start // tile
+    count = jnp.where(edge > start, (edge - 1) // tile - first + 1, 0)
+    upto = jnp.cumsum(count)
+    v = jnp.arange(tiles + n - 1, dtype=jnp.int32)
+    e = jnp.minimum(jnp.sum(v[:, None] >= upto, axis=1), n - 1)
+    # one gather: the tile of the expert's first visit less that visit's
+    # place in the walk, and the group's bounds
+    base, lo, hi = jnp.stack([first - (upto - count), start, edge])[:, e]
+    i32 = lambda a: a.astype(jnp.int32)
+    return i32(jnp.clip(base + v, 0, tiles - 1)), i32(e), i32(lo), i32(hi), \
+        i32(upto[-1])
+
+
+def _grouped_kernel(act, vt_ref, _ve_ref, lo_ref, hi_ref, x_ref, w_ref,
+                    *refs):
+    """One place of the grid: one visit (a tile of the sorted rows x an
+    expert with rows in it) x one tile of the expert's width.  The
+    tile's rows against the tile's columns of the matrices into the
+    width, the pair's weight on their activation -- 0 for the rows that
+    are not this expert's -- and the tile's rows of the matrix out of it
+    added into ``o_ref``, which stays in VMEM over the row tile's
+    visits."""
+    f32 = jnp.float32
+    *in_refs, d_ref, o_ref = refs
+    v, j = pl.program_id(0), pl.program_id(1)
+    rows = x_ref.shape[0]
+
+    @pl.when((j == 0) & ((v == 0)
+                         | (vt_ref[v] != vt_ref[jnp.maximum(v - 1, 0)])))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+    row = vt_ref[v] * jnp.int32(rows) \
+        + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    mine = (row >= lo_ref[v]) & (row < hi_ref[v])
+    x = x_ref[...]
+    up = lambda ref: jnp.dot(x, ref[...], preferred_element_type=f32)
+    h = act(*map(up, in_refs)) * jnp.where(mine, w_ref[...], f32(0))
+    o_ref[...] += jnp.dot(h.astype(d_ref.dtype), d_ref[...],
+                          preferred_element_type=f32)
+
+
+@functools.partial(jax.jit, static_argnames=("act", "rows", "interpret"))
+def _grouped_call(vt, ve, lo, hi, nvis, xs, wrow, *E, act, rows, interpret):
+    """The grouped kernel's call: ``xs (R, d)`` in the weights' dtype,
+    ``wrow (R, 1)`` float32, ``R`` whole tiles of ``rows``; the walk of
+    :func:`_visits` scalar-prefetched, the grid's first dimension its
+    ``nvis`` real visits (one at least).  The index maps name the blocks
+    of expert ``ve[v]`` in the stacked weights, which go in whole (as in
+    :func:`_hit_call`): consecutive visits of one expert name the same
+    blocks, which the pipeline then does not copy again (where the width
+    is one tile), and an expert with no row in the pass is never named.
+    The rows of a tile no visit names are not written.  A jit of its own
+    for the reason :func:`_hit_call` gives."""
+    *ins, Ed = E
+    _, d, f = ins[0].shape
+    dout = Ed.shape[-1]
+    tile = _expert_tile(f, len(ins) * d + dout, Ed.dtype.itemsize)
+    at = lambda v, j, vt, ve, lo, hi: (vt[v], v * 0)
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, act),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(jnp.maximum(nvis, 1), f // tile),
+            in_specs=[
+                pl.BlockSpec((rows, d), at),
+                pl.BlockSpec((rows, 1), at),
+                *(pl.BlockSpec((None, d, tile),
+                               lambda v, j, vt, ve, lo, hi: (ve[v], v * 0, j))
+                  for _ in ins),
+                pl.BlockSpec((None, tile, dout),
+                             lambda v, j, vt, ve, lo, hi: (ve[v], j, v * 0)),
+            ],
+            out_specs=pl.BlockSpec((rows, dout), at)),
+        out_shape=jax.ShapeDtypeStruct((xs.shape[0], dout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        name="moe_share_grouped",
+        interpret=interpret,
+    )(vt, ve, lo, hi, xs, wrow, *E)
+
+
+def _rows_kernel(xs, wrow, edge, *E, act, rows=None, interpret=False):
+    """:func:`_rows_ragged`'s product through the grouped kernel: every
+    expert with a row in the pass streams its weights once, from where
+    they lie, under the matmuls of its rows; the activations between the
+    two projections never leave VMEM.  Same operands and precision (the
+    weights' dtype into the MXU, float32 sums, the float32 pair weight
+    on the activation).  ``rows`` a tile: :data:`_GROUP_ROWS`, or all of
+    a pass that has fewer (in whole sublane tiles of 16; the pass is
+    padded to whole tiles)."""
+    R = xs.shape[0]
+    rows = rows or min(_GROUP_ROWS, R + -R % 16)
+    pad = -R % rows
+    out = _grouped_call(
+        *_visits(edge, rows, (R + pad) // rows),
+        jnp.pad(xs, ((0, pad), (0, 0))),
+        jnp.pad(wrow, (0, pad))[:, None], *E,
+        act=act, rows=rows, interpret=interpret)
+    return out[:R]
+
+
+#: how often a pass of the grouped form was lowered as the kernel
+_groupedKernelLowerings = [0]
+
+
+def moe_grouped_kernel_lowerings() -> int:
+    """How many times a pass of :func:`moe_share_grouped` has been
+    lowered as the TPU kernel in this process (once a program built for
+    one TPU, whose expert layers of one shape share the lowering; never
+    on the CPU or for several devices)."""
+    return _groupedKernelLowerings[0]
+
+
+def _grouped_rows_lowering(ctx, *args, act):
+    """A pass's rows through their experts, chosen by what the program is
+    lowered for, not by a knob (the rule of ``paged_attention``): one TPU
+    -> the grouped kernel (:func:`_rows_kernel`); the CPU or several
+    devices -> the compiler's ``lax.ragged_dot`` (:func:`_rows_ragged`)."""
+    kernel = lowered_for_one_tpu(ctx)
+    _groupedKernelLowerings[0] += kernel
+    return mlir.lower_fun(
+        functools.partial(_rows_kernel if kernel else _rows_ragged, act=act),
+        multiple_results=False)(ctx, *args)
+
+
+_grouped_rows_p = jex_core.Primitive("moe_share_grouped_rows")
+
+
+@functools.partial(jax.jit, static_argnames=("act",))
+def _grouped_rows_eager(*args, act):
+    """Outside any jit the primitive runs as a program of its own."""
+    return _grouped_rows_p.bind(*args, act=act)
+
+
+_grouped_rows_p.def_impl(_grouped_rows_eager)
+_grouped_rows_p.def_abstract_eval(
+    lambda xs, wrow, edge, *E, act: jax.core.ShapedArray(
+        (xs.shape[0], E[-1].shape[-1]), jnp.float32))
+mlir.register_lowering(_grouped_rows_p, _grouped_rows_lowering)
+
+
 def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None,
                       act=None):
     """:func:`moe_share_dense`'s sum by GROUPS: the token-expert pairs
-    whose expert is held, sorted by expert, and one grouped matmul
-    (``lax.ragged_dot``) a projection over the rows of each expert's
-    group — the work of the pairs that exist (half a pair a token for 16
-    of 256 experts at 8 a token), not of ``n`` experts over every token.
+    whose expert is held, sorted by expert, and each expert's matrices
+    over the rows of its group alone -- the work of the pairs that exist
+    (half a pair a token for 16 of 256 experts at 8 a token), not of
+    ``n`` experts over every token.
 
     Nothing is dropped and no shape depends on the routing: the sorted
     pairs are taken ``passRows`` rows a pass (``T`` where omitted), for as
     many passes as hold a held pair (one, unless the router leans on this
     chip's experts; ``k`` at most).  A pass gathers its rows' tokens,
-    multiplies by group, and adds each row's weighted output to its token
-    through a 0/1 matrix on the MXU (``T x passRows``; a scatter-add of
-    rows would serialise).  Every pass streams all the held experts'
-    weights whatever its rows (PERF.md section 5), so a share that
-    expects more than one held pair a token (128 of 512 experts at 8 a
-    token: two) names the rows that hold them in ONE pass.  ``Eg=None``
-    and ``act`` as :func:`moe_share_dense` takes them."""
+    multiplies by group (the primitive ``moe_share_grouped_rows``:
+    lowered for one TPU a kernel that streams the weights of each expert
+    with a row in the pass once, at half to four fifths of the HBM's
+    pace; on the CPU or for several devices one ``lax.ragged_dot`` a
+    projection, which streams ALL the held experts' weights whatever the
+    rows, at an eighth to a third of it: PERF.md section 5), and adds
+    each row's weighted output to its token through a 0/1 matrix on the
+    MXU (``T x passRows``; a scatter-add of rows would serialise).  A
+    pass costs its experts' bytes whatever its rows, so a
+    share that expects more than one held pair a token (128 of 512
+    experts at 8 a token: two) names the rows that hold them in ONE pass.
+    ``Eg=None`` and ``act`` as :func:`moe_share_dense` takes them."""
+    return _share_grouped(_grouped_rows_p.bind, x, idx, w, Eg, Eu, Ed, lo,
+                          real, passRows, act)
+
+
+def _share_grouped(through, x, idx, w, Eg, Eu, Ed, lo, real, passRows, act):
+    """:func:`moe_share_grouped` with a pass's rows taken ``through`` the
+    given form: the primitive's ``bind``, or :func:`_rows_ragged` /
+    :func:`_rows_kernel` by name (a test hands the kernel in interpret
+    mode)."""
     ins, act = _expert(Eg, Eu, act)
     T, k = idx.shape
     R = T if passRows is None else passRows
@@ -534,20 +732,16 @@ def moe_share_grouped(x, idx, w, Eg, Eu, Ed, lo: int, real, passRows=None,
     total = ends[-1]
     wflat = w.reshape(-1)
     at = jnp.arange(R, dtype=jnp.int32)
-    gmm = lambda a, W, sizes: lax.ragged_dot(
-        a, W, sizes, preferred_element_type=f32)
 
     def one_pass(carry):
         b, out = carry
         rows = lax.dynamic_slice_in_dim(order, b * R, R)
         live = b * R + at < total
         edge = jnp.clip(ends - b * R, 0, R)
-        sizes = jnp.diff(edge, prepend=0).astype(jnp.int32)
         tok = rows // k
-        xs = x[tok]
-        h = act(*(gmm(xs, W, sizes) for W in ins)) \
-            * jnp.where(live, wflat[rows], f32(0))[:, None]
-        y = jnp.where(live[:, None], gmm(h.astype(dt), Ed, sizes), f32(0))
+        y = jnp.where(live[:, None], through(
+            x[tok], jnp.where(live, wflat[rows], f32(0)), edge, *ins, Ed,
+            act=act), f32(0))
         home = (jnp.arange(T, dtype=jnp.int32)[:, None] == tok[None, :]) \
             & live[None, :]                                      # (T, R)
         return b + 1, out + jnp.matmul(home.astype(dt), y.astype(dt),

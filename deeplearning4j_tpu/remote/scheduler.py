@@ -63,7 +63,8 @@ from deeplearning4j_tpu.nn.conf.attention import (CacheSpec,
                                                   paged_kernel_lowerings,
                                                   sparse_in_place_lowerings)
 from deeplearning4j_tpu.nlp.mamba import ssd_step_kernel_lowerings
-from deeplearning4j_tpu.parallel.moe import moe_step_kernel_lowerings
+from deeplearning4j_tpu.parallel.moe import (moe_grouped_kernel_lowerings,
+                                             moe_step_kernel_lowerings)
 from deeplearning4j_tpu.remote.serving import (AdmissionControl,
                                                BucketLadder,
                                                DeadlineExceeded,
@@ -649,6 +650,7 @@ class ContinuousBatcher:
         self._noPrev, *self.pool.arrays = step(
             self.lm.params, *self.pool.arrays, tok0, prev, pt, zeros, zeros)
         slot0 = jnp.zeros((), jnp.int32)
+        grouped = moe_grouped_kernel_lowerings()
         for Tp in self.ladder.seqLens:
             dummy = np.zeros((1, Tp), np.int32)
             ids = jnp.zeros(Tp // self.pageSize, jnp.int32)   # scratch
@@ -656,6 +658,9 @@ class ContinuousBatcher:
             # writes: an admission overwrites them before any step reads
             _l, *state = self.lm.prefillRaw(dummy, lengths=[1])
             self._writeState(state, ids, slot0)
+        sm.moe_grouped_kernel().set(
+            1 if moe_grouped_kernel_lowerings() > grouped else 0,
+            model=self.name)
         jax.block_until_ready(self.pool.arrays)  # jaxlint: sync-ok -- warm-up fence: compile cost must land in warmup_seconds, not the first request
         self._atRest()
         self._warmed = True

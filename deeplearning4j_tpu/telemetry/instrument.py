@@ -473,6 +473,19 @@ class ServingMetrics:
             "expert layers x decode steps)",
             labelnames=("model",))
 
+    def moe_grouped_kernel(self):
+        return get_registry().gauge(
+            "dl4j_tpu_serving_moe_grouped_kernel",
+            "1 when a prefill of the batcher's ladder was built with the "
+            "kernel that streams the weights of each held expert with a "
+            "pair in the pass once, under its rows' matmuls "
+            "(parallel/moe.py:moe_share_grouped, lowered for one TPU), 0 "
+            "when its grouped experts are the compiler's ragged dot, "
+            "which streams every held expert's weights at a third of the "
+            "memory's pace or less (the CPU, several devices) and for a model "
+            "without such a layer",
+            labelnames=("model",))
+
     def ssd_step_kernel(self):
         return get_registry().gauge(
             "dl4j_tpu_serving_ssd_step_kernel",

@@ -317,6 +317,7 @@ def test_serving_telemetry_covers_the_model_unchanged(ref, weights, batcher):
     assert sm.paged_attention_kv_passes().value(model="jamba") == 0
     assert sm.ring_attention_kernel().value(model="jamba") == 0
     assert sm.moe_step_kernel().value(model="jamba") == 0
+    assert sm.moe_grouped_kernel().value(model="jamba") == 0
     assert sm.slot_occupancy().value(model="jamba") is not None
     assert count(sm.decode_steps()) - before[2] >= 29
     assert count(sm.prefill_prompt_tokens()) - before[0] == 11 + 3

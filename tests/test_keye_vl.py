@@ -680,6 +680,7 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts(
     # off the TPU the step gathers: the kernels' gauges say so
     assert sm.paged_attention_kernel().value(model="keye") == 0
     assert sm.moe_step_kernel().value(model="keye") == 0
+    assert sm.moe_grouped_kernel().value(model="keye") == 0
     assert sm.sparse_read_in_place().value(model="keye") == 0
     got = {k: v - before[k] for k, v in _counted().items()}
     pairs = TINY["num_experts_per_tok"] * LAYERS

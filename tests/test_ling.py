@@ -647,6 +647,7 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts_routing(
     # off the TPU the step gathers and multiplies every held expert
     assert sm.paged_attention_kernel().value(model="ling") == 0
     assert sm.moe_step_kernel().value(model="ling") == 0
+    assert sm.moe_grouped_kernel().value(model="ling") == 0
     got = {k: v - before[k] for k, v in _routing().items()}
     pairs = TINY["num_experts_per_tok"] * EXPERT_LAYERS
     assert got["pairs_routed", "prefill"] + got["pairs_absent", "prefill"] \

@@ -646,6 +646,7 @@ def test_continuous_batcher_serves_the_reference_tokens_and_counts_routing(
     assert sm.ring_attention_kernel().value(model="pangu") == 0
     # and its expert layers multiply every held expert over every slot
     assert sm.moe_step_kernel().value(model="pangu") == 0
+    assert sm.moe_grouped_kernel().value(model="pangu") == 0
     got = {k: v - before[k] for k, v in _routing().items()}
     pairs = TINY["num_experts_per_tok"] * EXPERT_LAYERS
     # the last step's counts are read with its tokens; the prefills' ride

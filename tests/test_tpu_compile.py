@@ -8,6 +8,7 @@ The topology is described inside a fixture (never at import): one process
 at a time may load libtpu, and under xdist every worker imports this file.
 Keep every such compile in THIS file, so one worker holds the library.
 """
+import functools
 import os
 import re
 
@@ -368,6 +369,13 @@ def test_latent_kernel_alone_compiles_at_its_callers_row(one_chip):
     assert compiled(576).memory_analysis().temp_size_in_bytes > 1e9
 
 
+def _kernel_names(text):
+    """The Pallas kernels of a compiled program, by the name each call's
+    instruction carries."""
+    return re.findall(r"^\s*(?:ROOT )?%?([a-z_]+)[\w.\-]* = .*? custom-call\(.*"
+                      r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+
+
 def test_expert_step_kernel_alone_compiles_at_its_callers_shapes(one_chip):
     """``_hit_call`` by itself, under the package's x64, at the shapes its
     one caller brings: 32 slots' tokens against 16 held experts of
@@ -395,6 +403,44 @@ def test_expert_step_kernel_alone_compiles_at_its_callers_shapes(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
     assert not re.search(r"= bf16\[[\d,]*(?:7680,2048|2048,7680)\]\S* "
                          r"(?:copy|dynamic-slice|fusion|slice)\(", text)
+
+
+@pytest.mark.parametrize("rows,n,d,f,matrices", [
+    (7 * 4096, 128, 1024, 2688, 2),     # nemotron3_super: in the latent
+    (3 * 8192, 128, 2560, 768, 3),      # ling3_flash
+    (4096, 16, 7680, 2048, 3),          # openpangu: four tiles of 512
+    (4096, 16, 2048, 768, 3)])          # keye_vl: a block of 4,096 tokens
+def test_grouped_expert_kernel_alone_compiles_at_its_callers_shapes(
+        one_chip, rows, n, d, f, matrices):
+    """A pass of ``moe_share_grouped`` through the kernel by itself
+    (``_rows_kernel``: the walk of visits built from the groups' ends,
+    then ``_grouped_call``), under the package's x64, at the largest pass
+    of each of its four callers in bfloat16: the stacked weights go in
+    whole, the only thing set aside in HBM is the pairs' weights as a
+    column (one lane of 128 used: 15 MB at 28,672 rows), and the
+    double-buffered blocks fit the 64 MB of VMEM the call asks for."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.parallel import moe
+    assert jax.config.jax_enable_x64
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    E = [sds((n, d, f), bf16)] * (matrices - 1) + [sds((n, f, d), bf16)]
+    act = moe.relu2 if matrices == 2 else moe._silu_gate
+    before = moe.moe_grouped_kernel_lowerings()
+    compiled = jax.jit(functools.partial(moe._rows_kernel, act=act)).lower(
+        sds((rows, d), bf16), sds((rows,), jnp.float32),
+        sds((n,), jnp.int32), *E).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == ["moe_share_grouped"]
+    assert "ragged-dot" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * 512 + 1e6
+    assert not re.search(rf"= bf16\[[\d,]*(?:{d},{f}|{f},{d})\]\S* "
+                         r"(?:copy|dynamic-slice|fusion|slice)\(", text)
+    # called by hand, not through the primitive's rule: nothing counted
+    assert moe.moe_grouped_kernel_lowerings() == before
 
 
 @pytest.mark.parametrize("bucket", [16, 256])
@@ -709,9 +755,7 @@ def test_pangu_moe_decode_step_fits_and_reads_its_latent_rows_in_place(
     text = compiled.as_text()
     # (the four expert layers are one lowering: same shapes, same rule)
     assert kernelsLowered == (5, 1)
-    kernels = re.findall(
-        r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
-        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    kernels = _kernel_names(text)
     assert sorted(kernels) == ["moe_share_step"] * 4 \
         + ["paged_latent_attention"] * 5, kernels
     assert f"bf16[{PANGU_SLOTS * perSeq},{PAGE_SIZE},640]" not in text
@@ -746,12 +790,14 @@ def test_pangu_moe_prefill_groups_its_experts_and_fits_beside_the_step(
     # every layer's unabsorbed attention is the flash kernel: no score of
     # 4,096 keys a query is held outside VMEM
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                          text)) >= 5
+                          text)) >= 5 + 4
     assert not re.search(rf"f32\[(?:1,)?128,\d+,{PANGU_BUCKET}\]", text)
-    # the held experts are multiplied by GROUP (the compiler's own ragged
-    # dot, three a layer), never all 16 over every token: no (tokens, 16,
-    # 2048) intermediate in any layout
-    assert text.count("ragged-dot") >= 3 * 4
+    # the held experts are multiplied by GROUP (the grouped kernel, one
+    # call an expert layer in place of the compiler's three ragged dots),
+    # never all 16 over every token: no (tokens, 16, 2048) intermediate in
+    # any layout
+    assert "ragged-dot" not in text
+    assert _kernel_names(text).count("moe_share_grouped") == 4
     assert not re.search(rf"\[(?:{PANGU_BUCKET},16,2048|16,{PANGU_BUCKET}"
                          rf",2048|{PANGU_BUCKET},32768)\]", text)
     mem = compiled.memory_analysis()
@@ -955,9 +1001,7 @@ def test_keye_decode_step_fits_and_scores_its_index_rows_in_place(keye):
     text = compiled.as_text()
     # 34,816 positions are 17 x topk: under the crossover, all six in place
     assert kernelsLowered == (6, 1, 6)
-    kernels = re.findall(
-        r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
-        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    kernels = _kernel_names(text)
     assert sorted(kernels) == ["moe_share_step"] * 6 \
         + ["paged_selected_attention"] * 6 \
         + ["paged_sparse_attention_index"] * 6, kernels
@@ -1057,9 +1101,7 @@ def test_keye_prefill_selects_and_attends_in_kernels_and_fits_beside_the_step(
     compiled = lm._prefillRawFn.at(KEYE_BUCKET).lower(
         params, i32(1, KEYE_BUCKET), i32(1)).compile()
     text = compiled.as_text()
-    kernels = re.findall(
-        r"^\s*%?([a-z_]+)[\w.\-]* = \S+ custom-call\(.*"
-        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    kernels = _kernel_names(text)
     # every layer's selection is the bisection kernel and its attention
     # the flash kernel under the selection's tiles: no score of 32,768
     # keys a query is held outside VMEM, for the indexer's 16 heads or
@@ -1067,9 +1109,13 @@ def test_keye_prefill_selects_and_attends_in_kernels_and_fits_beside_the_step(
     assert kernels.count("sparse_prefill_select") == 6
     assert kernels.count("sparse_prefill_attention") == 6
     assert not re.search(rf"f32\[[\d,]*{KEYE_BUCKET},{KEYE_BUCKET}\]", text)
-    # the held experts are multiplied by GROUP, 4,096 tokens a pass: no
-    # (tokens, tokens) matrix of a whole bucket brings the pairs home
+    # the held experts are multiplied by GROUP, 4,096 tokens a pass (the
+    # grouped kernel, one call a layer inside the loop over the blocks, no
+    # ragged dot): no (tokens, tokens) matrix of a whole bucket brings the
+    # pairs home
     assert f"[{KEYE_BUCKET},{KEYE_BUCKET}]" not in text
+    assert "ragged-dot" not in text
+    assert kernels.count("moe_share_grouped") == 6
     mem = compiled.memory_analysis()
     # found: 10.11 + 0.02 (the step) + 2.49 of temporaries (1.07 of them
     # a layer's selection as int8 tiles) + 0.45 of rows and logits out =
@@ -1161,9 +1207,7 @@ def test_ling_decode_step_fits_and_updates_three_kinds_of_state_in_place(
     text = compiled.as_text()
     # (the six expert layers are one lowering: same shapes, same rule)
     assert kernelsLowered == (1, 1)
-    kernels = re.findall(
-        r"^\s*%?([a-z_]+)[\w.\-]* = .*? custom-call\(.*"
-        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    kernels = _kernel_names(text)
     assert sorted(kernels) == ["kda_step"] * 6 + ["moe_share_step"] * 6 \
         + ["paged_latent_attention"], kernels
     # no layer's states (134 MB) are sliced out, updated and put back, and
@@ -1198,10 +1242,12 @@ def test_ling_prefill_chunks_its_delta_rule_and_fits_beside_the_step(
     text = compiled.as_text()
     assert "kda_chunked" in text and "/kda_step/" not in text
     # the MLA layer's unabsorbed attention is the flash kernel, and the
-    # held experts are multiplied by GROUP (three ragged dots a layer)
+    # held experts are multiplied by GROUP (the grouped kernel, one call
+    # an expert layer in place of three ragged dots)
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                          text)) >= 1
-    assert text.count("ragged-dot") >= 3 * 6
+                          text)) >= 1 + 6
+    assert "ragged-dot" not in text
+    assert _kernel_names(text).count("moe_share_grouped") == 6
     mem = compiled.memory_analysis()
     # found: 12.18 + 0.04 (the step) + 1.97 + 0.02 (the 8,192 prefill)
     # = 14.21 GB
@@ -1291,9 +1337,7 @@ def test_nemotron_decode_step_fits_and_updates_its_states_in_place(nemotron):
     text = compiled.as_text()
     # (the five expert layers are one lowering: same shapes, same rule)
     assert kernelsLowered == (1, 1)
-    kernels = re.findall(
-        r"^\s*%?([a-z_]+)[\w.\-]* = .*? custom-call\(.*"
-        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    kernels = _kernel_names(text)
     assert sorted(kernels) == ["moe_share_step"] * 5 + ["paged_attention"] \
         + ["ssd_step"] * 5, kernels
     # no layer's states (268 MB) are sliced out, updated and put back
@@ -1322,8 +1366,10 @@ def test_nemotron_prefill_chunks_its_ssd_and_fits_beside_the_step(nemotron):
     compiled = traced.lower().compile()
     text = compiled.as_text()
     assert "ssd_prefill" in text and "/ssd_step/" not in text
-    # the held experts are multiplied by GROUP (two ragged dots a layer)
-    assert text.count("ragged-dot") >= 2 * 5
+    # the held experts are multiplied by GROUP (the grouped kernel, one
+    # call an expert block in place of two ragged dots)
+    assert "ragged-dot" not in text
+    assert _kernel_names(text).count("moe_share_grouped") == 5
     mem = compiled.memory_analysis()
     # found: 11.01 + 0.045 (the step) + 0.55 + 0.02 (the 2,048 prefill);
     # the 4,096 prefill holds 0.98 GB of temporaries
