@@ -44,6 +44,7 @@ layout's traced constraints.
 from __future__ import annotations
 
 import functools
+import math
 import queue as _stdqueue
 import random
 import threading
@@ -73,9 +74,11 @@ from deeplearning4j_tpu.remote.serving import (AdmissionControl,
 from deeplearning4j_tpu.telemetry import (SERVING_LOOP_PHASES,
                                           RequestContext, ThresholdRule,
                                           current_context, flight_recorder,
-                                          gc_pause_seconds,
-                                          observe_exemplar, serving_metrics,
-                                          timeline_store, tracer)
+                                          gc_pause_seconds, get_registry,
+                                          observe_exemplar,
+                                          register_thread_role,
+                                          serving_metrics, timeline_store,
+                                          tracer)
 
 __all__ = ["KVCachePool", "ContinuousBatcher", "ReplicaSet"]
 
@@ -87,6 +90,70 @@ _PROBE_FN = None
 #: starved for, this long is a stall and leaves ``serving.loop.stall``
 _IDLE_EVENT_SECONDS = 0.001
 _STALL_SECONDS = 0.1
+# a streamed token that waited this long between the loop's put and its
+# consumer's get is also a Chrome event (at 64 slots a lower bar would put
+# an event a token into the tracer's ring, under its lock, from 64 threads)
+_QUEUED_EVENT_SECONDS = 0.01
+# a stream's consumer adds its hand-off sums to the registry this often
+_HANDOFF_FLUSH_TOKENS = 32
+# the roles dl4j_tpu_process_thread_cpu_seconds_total books this module's
+# threads under: the loop (cbatch-<name>) and the replicas' health probes
+register_thread_role("cbatch-", "serving_loop")
+register_thread_role("replica-probe-", "telemetry")
+register_thread_role("probe-once-", "telemetry")
+
+
+def _cpu_clock_read_seconds() -> float:
+    """What one read of the calling thread's CPU clock costs on this
+    host: the least of five batches of twenty (a batch that was preempted
+    reads high)."""
+    best = math.inf
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(20):
+            time.thread_time()
+        best = min(best, (time.perf_counter() - t0) / 20)
+    return best
+
+
+class _LoopClock:
+    """The loop thread's second clock: when its phases read the thread's
+    CPU clock, and what is made of the readings.
+
+    A read costs 0.3 us where the kernel serves it and 6 us alone, 17
+    among the server's threads, behind a sandbox (gVisor, the benchmark's
+    host, PR 50), where sixteen reads an iteration cost a 64-slot loop
+    0.27 ms a step: so the clock is read in one iteration of ``every``,
+    chosen from what a read costs here so that the reads come to about a
+    microsecond an iteration (every iteration where a read costs that or
+    less); the phases of the other iterations observe their wall time
+    alone.
+
+    Where the CPU clock moves a scheduler tick at a time (10 ms on that
+    host), a phase of a millisecond reads 0 or a whole tick: what a phase
+    read beyond its own wall time is kept as that phase's ``credit`` and
+    set against its next observations, so that a phase's wall less its
+    off-CPU seconds add up to the CPU seconds read in it.  With a clock
+    that counts nanoseconds the credit stays 0."""
+
+    def __init__(self):
+        self.every = min(16, max(1, math.ceil(
+            _cpu_clock_read_seconds() / 1e-6)))
+        self.on = True
+        self._iterations = 0
+        self._credit = dict.fromkeys(SERVING_LOOP_PHASES, 0.0)
+
+    def iteration(self) -> None:
+        self._iterations += 1
+        self.on = self._iterations % self.every == 0
+
+    def offcpu(self, phase: str, seconds: float, cpu: float) -> float:
+        """Seconds of a phase of ``seconds`` that its thread did not run
+        for, given that its CPU clock gained ``cpu`` in it."""
+        credit = self._credit[phase] + cpu
+        ran = min(seconds, credit)
+        self._credit[phase] = credit - ran
+        return seconds - ran
 
 
 def _probe_fn():
@@ -524,6 +591,8 @@ class ContinuousBatcher:
         self._phaseObservers = {
             p: functools.partial(self._observePhase, p)
             for p in SERVING_LOOP_PHASES}
+        self._clock = _LoopClock()
+        self._phaseHeld: dict = {}
         if plan is not None:
             self.applyPlan(plan)            # shards params, builds pools
         else:
@@ -688,6 +757,7 @@ class ContinuousBatcher:
         sm.queue_wait_seconds()
         sm.prefill_seconds()
         sm.loop_phase_seconds()
+        sm.loop_phase_offcpu_seconds()
         # the ring series exist from the start and read 0 until a step
         # updates them: never, for a model without window layers, so a
         # reader of every kind of cache state finds all of them
@@ -926,8 +996,23 @@ class ContinuousBatcher:
                 raise ValueError("keepAliveSeconds must be > 0")
         self._enqueue(seqs)
 
+        def book(queued: float, write: float, tokens: int) -> None:
+            sm = serving_metrics()
+            sm.stream_token_seconds().inc(queued, model=self.name,
+                                          stage="queued")
+            sm.stream_token_seconds().inc(write, model=self.name,
+                                          stage="write")
+            sm.stream_tokens_delivered().inc(tokens, model=self.name)
+
         def gen():
             from deeplearning4j_tpu.remote.server import KEEPALIVE
+            # the token's hand-off, on the consumer's thread: seconds
+            # from the loop's put to this get's return (queued) and from
+            # there to the consumer asking for the next item (write),
+            # summed here and added to the registry every
+            # _HANDOFF_FLUSH_TOKENS tokens and at the end, never a token
+            queued = write = 0.0
+            tokens = 0
             try:
                 while True:
                     try:
@@ -944,9 +1029,27 @@ class ContinuousBatcher:
                         return
                     if isinstance(item, BaseException):
                         raise item
+                    tok, putAt = item
+                    got = time.perf_counter()
+                    lay = got - putAt
+                    queued += lay
+                    tokens += 1
+                    if lay >= _QUEUED_EVENT_SECONDS:
+                        # on this thread's track; no profiler annotation:
+                        # the stretch lies in the past
+                        tracer().record_complete(
+                            "serving.stream.queued", putAt, lay,
+                            args={"replica": self.name, "row": seq.row})
                     # jaxlint: disable=host-sync -- stream items are host ints pushed by _emit
-                    yield int(item)
+                    yield int(tok)
+                    write += time.perf_counter() - got
+                    if tokens >= _HANDOFF_FLUSH_TOKENS:
+                        book(queued, write, tokens)
+                        queued = write = 0.0
+                        tokens = 0
             finally:
+                if tokens:
+                    book(queued, write, tokens)
                 if not seq.parent.event.is_set():
                     seq.cancelled = True
         return gen()
@@ -966,27 +1069,57 @@ class ContinuousBatcher:
     def _phase(self, phase: str):
         """One phase of the loop thread (``SERVING_LOOP_PHASES``): the
         span ``serving.loop.<phase>``, its profiler annotation and one
-        observation of ``dl4j_tpu_serving_loop_phase_seconds``, all from
-        the same two clock reads.  Loop thread only; never entered while
-        ``_cv`` is held (scheduler -> registry lock order)."""
-        return tracer().span("serving.loop." + phase,
+        observation of ``dl4j_tpu_serving_loop_phase_seconds`` and, in
+        the iterations that read the thread's CPU clock (``_LoopClock``:
+        every one where a read is cheap), of
+        ``..._loop_phase_offcpu_seconds``, all from the same two reads
+        of each clock.  Loop thread only; never entered while ``_cv`` is
+        held (scheduler -> registry lock order)."""
+        return tracer().span("serving.loop." + phase, cpu=self._clock.on,
                              observe=self._phaseObservers[phase])
 
-    def _observePhase(self, phase: str, seconds: float) -> None:
-        serving_metrics().loop_phase_seconds().observe(
-            seconds, model=self.name, phase=phase)
-        if seconds >= _STALL_SECONDS and phase != "wait":
-            self._stall(phase, seconds)
+    def _phaseCells(self, phase: str):
+        """Both phase histograms and this model's cell of each for
+        ``phase``, looked up once and held while the process's registry
+        holds the first: an observation then costs one registry lookup
+        for the pair, where two histograms by their accessors cost two
+        and a label lookup each."""
+        held = self._phaseHeld.get(phase)
+        if held is None or get_registry().get(held[0].name) is not held[0]:
+            sm = serving_metrics()
+            wall, off = sm.loop_phase_seconds(), sm.loop_phase_offcpu_seconds()
+            held = self._phaseHeld[phase] = (
+                wall, wall.cell(model=self.name, phase=phase),
+                off, off.cell(model=self.name, phase=phase))
+        return held
 
-    def _stall(self, what: str, seconds: float) -> None:
+    def _observePhase(self, phase: str, seconds: float,
+                      cpu: Optional[float] = None) -> None:
+        """``cpu``: what the thread's CPU clock gained in the phase, in
+        the iterations that read it (``_LoopClock``)."""
+        wall, wallCell, off, offCell = self._phaseCells(phase)
+        wall.observe_cell(wallCell, seconds)
+        offcpu = None
+        if cpu is not None:
+            offcpu = self._clock.offcpu(phase, seconds, cpu)
+            off.observe_cell(offCell, offcpu)
+        if seconds >= _STALL_SECONDS and phase != "wait":
+            self._stall(phase, seconds, offcpu)
+
+    def _stall(self, what: str, seconds: float,
+               offcpu: Optional[float] = None) -> None:
         """A loop phase, or a stretch the device was starved for, of
         ``_STALL_SECONDS`` or more has just ended: leave what a process
-        can cheaply know it coincided with."""
+        can cheaply know it coincided with (``offcpu_seconds``: of a
+        phase, what the loop thread did not run for; None where the CPU
+        clock was not read in it, and for a starved stretch, which is no
+        one phase's)."""
         tracer().instant(
             "serving.loop.stall", replica=self.name, phase=what,
             seconds=round(seconds, 6),
             gc_seconds=round(
                 gc_pause_seconds(time.perf_counter() - seconds), 6),
+            offcpu_seconds=None if offcpu is None else round(offcpu, 6),
             threads=threading.active_count(), queued=self._queuedRows)
 
     # -- the drain clock ------------------------------------------------
@@ -1084,6 +1217,7 @@ class ContinuousBatcher:
         the parent of everything in it: what a span costs between two
         phases is then inside a span too, so no instant of the loop
         thread is without a name."""
+        self._clock.iteration()
         with tracer().span("serving.loop.iteration"):
             with self._phase("admit"):
                 self._admit()
@@ -1268,6 +1402,7 @@ class ContinuousBatcher:
         twice."""
         seq.emitted.append(tok)
         serving_metrics().decode_tokens().inc(model=self.name)
+        now = time.perf_counter()
         # latency decomposition observes FRESH tokens only: a replayed
         # prefix (len(emitted) <= len(forced)) was already delivered, so
         # re-observing it would double-count.  lastTokT deliberately
@@ -1275,7 +1410,6 @@ class ContinuousBatcher:
         # inter-token gap then CONTAINS the failover, which is exactly
         # what the client experienced.
         if len(seq.emitted) > len(seq.forced):
-            now = time.perf_counter()
             tid = seq.ctx.traceId if seq.ctx is not None else None
             parent = seq.parent
             if parent.firstTokenAt is None:
@@ -1299,7 +1433,9 @@ class ContinuousBatcher:
             if seq.streamSkip > 0:
                 seq.streamSkip -= 1
             else:
-                seq.streamQ.put(tok)
+                # the put's instant rides with the token: its consumer
+                # books what lay between here and its own get
+                seq.streamQ.put((tok, now))
                 seq.streamed += 1
         if len(seq.emitted) >= seq.quota:
             return True
@@ -1624,7 +1760,7 @@ class ContinuousBatcher:
                 for seq in self._takeParted():
                     self._finishSeq(seq, err)
             threading.Thread(target=reap, daemon=True,
-                             name=f"cbatch-wedge-reap-{self.name}"
+                             name=f"wedge-reap-cbatch-{self.name}"
                              ).start()
         out: List[_Seq] = []
         ts = timeline_store()

@@ -23,6 +23,22 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from deeplearning4j_tpu.telemetry import register_thread_role
+
+register_thread_role("serving-handler-", "serving_handler")
+
+
+class RequestThreadsHTTPServer(ThreadingHTTPServer):
+    """``ThreadingHTTPServer`` whose request threads say what they are:
+    named ``serving-handler-<native id>``, the role under which
+    ``dl4j_tpu_process_thread_cpu_seconds_total`` books them.  Shared by
+    ``JsonModelServer`` here and ``serving.InferenceServer``."""
+
+    def process_request_thread(self, request, client_address):
+        th = threading.current_thread()
+        th.name = f"serving-handler-{th.native_id}"
+        super().process_request_thread(request, client_address)
+
 
 def reply_safely(handler, code: int, body: bytes, ctype: str,
                  headers: Optional[Dict[str, str]] = None) -> None:
@@ -134,7 +150,7 @@ class JsonModelServer:
         self.port = port
         # restrict ComputationGraph responses to these named outputs
         self.outputNames = list(outputNames) if outputNames else None
-        self._httpd: Optional[ThreadingHTTPServer] = None
+        self._httpd: Optional[RequestThreadsHTTPServer] = None
         self._thread: Optional[threading.Thread] = None
         self._parallelInference = bool(parallelInference)
         self._batchLimit = int(batchLimit)
@@ -266,7 +282,8 @@ class JsonModelServer:
                 self._reply(code, json.dumps(body).encode("utf-8"),
                             "application/json")
 
-        self._httpd = ThreadingHTTPServer(("127.0.0.1", self.port), Handler)
+        self._httpd = RequestThreadsHTTPServer(("127.0.0.1", self.port),
+                                               Handler)
         self.port = self._httpd.server_address[1]
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         daemon=True)

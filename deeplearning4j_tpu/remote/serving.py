@@ -39,7 +39,7 @@ import os
 import threading
 import time
 from collections import deque
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from http.server import BaseHTTPRequestHandler
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -833,7 +833,7 @@ class InferenceServer:
     def __init__(self, registry: ModelRegistry, port: int = 0):
         self.registry = registry
         self.port = port
-        self._httpd: Optional[ThreadingHTTPServer] = None
+        self._httpd = None
         self._thread: Optional[threading.Thread] = None
 
     def start(self) -> "InferenceServer":
@@ -998,7 +998,10 @@ class InferenceServer:
                 self._reply_json(code, body)
                 return code, model
 
-        self._httpd = ThreadingHTTPServer(("127.0.0.1", self.port), Handler)
+        from deeplearning4j_tpu.remote.server import \
+            RequestThreadsHTTPServer
+        self._httpd = RequestThreadsHTTPServer(("127.0.0.1", self.port),
+                                               Handler)
         self.port = self._httpd.server_address[1]
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         daemon=True)

@@ -62,7 +62,8 @@ from deeplearning4j_tpu.telemetry.runlog import (  # noqa: F401
     record_event, run_scope, run_span_attrs, set_fleet_timeline)
 from deeplearning4j_tpu.telemetry.registry import (  # noqa: F401
     DEFAULT_BUCKETS, Counter, Gauge, Histogram, MetricsRegistry,
-    gc_pause_seconds, get_registry, set_registry)
+    gc_pause_seconds, get_registry, register_thread_role, set_registry,
+    thread_role)
 from deeplearning4j_tpu.telemetry.timeseries import (  # noqa: F401
     MetricsRetention, ensure_retention, retention, set_retention)
 from deeplearning4j_tpu.telemetry.tracing import (  # noqa: F401

@@ -776,6 +776,58 @@ class ServingMetrics:
             labelnames=("model", "phase"),
             buckets=SERVING_LOOP_PHASE_BUCKETS)
 
+    def loop_phase_offcpu_seconds(self):
+        return get_registry().histogram(
+            "dl4j_tpu_serving_loop_phase_offcpu_seconds",
+            "Seconds of a loop phase in which the loop thread did NOT "
+            "run: the phase's wall time (loop_phase_seconds, the same "
+            "two reads) less what the thread's own CPU clock "
+            "(time.thread_time()) gained between them; one observation "
+            "a phase in every loop iteration that reads that clock: all "
+            "of them where a read costs a microsecond or less, one in "
+            "up to sixteen where it costs more (whole iterations, so a "
+            "sum over phases over the count of fetch is a mean a step "
+            "either way).  Where the clock moves a scheduler tick at a "
+            "time, what a phase reads beyond its wall time is set "
+            "against its next observations: sums hold, single "
+            "observations do not.  In "
+            "wait and fetch the thread blocks by design and this is the "
+            "wait; in grow, emit and bookkeep nothing blocks by design "
+            "and this is the time the thread waited for the interpreter "
+            "or for a core; upload, dispatch and admit call into the "
+            "runtime and may hold either; per model",
+            labelnames=("model", "phase"),
+            buckets=SERVING_LOOP_PHASE_BUCKETS)
+
+    # the token's hand-off from the loop thread to the thread that
+    # writes it to the client (submitStream's generator keeps the sums
+    # in locals and adds them here every 32 tokens and at the end)
+    def stream_token_seconds(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_stream_token_seconds_total",
+            "Seconds streamed tokens spent between the loop thread and "
+            "the client's socket, by stage: queued (from the loop's put "
+            "to the consumer's get returning: the handler thread's "
+            "wake-up and its wait for the interpreter, and with a "
+            "consumer slower than the decode step the time the token "
+            "lay in the queue), write (from there to the consumer "
+            "asking for the next token: json.dumps, the chunk's bytes, "
+            "write, flush; a client that reads slowly shows here once "
+            "the socket's buffer is full); over "
+            "stream_tokens_delivered_total it is seconds a token; per "
+            "model",
+            labelnames=("model", "stage"))
+
+    def stream_tokens_delivered(self):
+        return get_registry().counter(
+            "dl4j_tpu_serving_stream_tokens_delivered_total",
+            "Tokens a stream's consumer took off its queue (sentinels "
+            "and keep-alives count nothing; a replayed prefix is "
+            "swallowed before the queue and so not counted twice; a "
+            "token put for a consumer that had hung up is never "
+            "delivered), per model",
+            labelnames=("model",))
+
     def device_idle_seconds(self):
         return get_registry().histogram(
             "dl4j_tpu_serving_device_idle_seconds",

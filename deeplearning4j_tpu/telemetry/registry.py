@@ -30,6 +30,7 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "get_registry", "set_registry", "gc_pause_seconds",
+           "thread_role", "register_thread_role",
            "DEFAULT_BUCKETS"]
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
@@ -216,13 +217,25 @@ class Histogram(_Metric):
         if not bs:
             raise ValueError(f"{self.name}: need at least one bucket")
         self.buckets = tuple(bs)
+        # the tuple the bounds were given as: an accessor that asks again
+        # with the same one (a module's constant, on a hot path) is known
+        # to agree without sorting it again
+        self._given = buckets if isinstance(buckets, tuple) else None
 
     def _new_cell(self) -> _HistCell:
         return _HistCell(len(self.buckets))
 
     def observe(self, value: float, **labels) -> None:
-        v = float(value)
-        cell = self._cell(labels)
+        self.observe_cell(self._cell(labels), float(value))
+
+    def cell(self, **labels) -> _HistCell:
+        """The cell of one label set, for a caller that observes into it
+        many times a second (``observe_cell``) and must not pay the label
+        lookup each time.  It stays this histogram's until the histogram
+        leaves its registry."""
+        return self._cell(labels)
+
+    def observe_cell(self, cell: _HistCell, v: float) -> None:
         i = len(self.buckets)
         for j, b in enumerate(self.buckets):
             if v <= b:
@@ -295,6 +308,20 @@ class MetricsRegistry:
         # the process's two garbage-collection series, a pair of cells a
         # generation, once this registry is the process's (``_gc_series``)
         self._gcCells: Optional[list] = None
+        # run at the top of snapshot() and exposition(), before any lock
+        # of this registry is taken: for series that are read off the
+        # process when somebody looks and never written on a hot path
+        self._collectHooks: List = []
+
+    def add_collect_hook(self, fn) -> None:
+        """``fn(registry)`` runs whenever this registry is read whole
+        (``snapshot()``, ``exposition()``), on the reader's thread."""
+        if fn not in self._collectHooks:
+            self._collectHooks.append(fn)
+
+    def _collect(self) -> None:
+        for fn in list(self._collectHooks):
+            fn(self)
 
     def _gc_series(self) -> None:
         """Give this registry the process's garbage-collection series.
@@ -329,8 +356,9 @@ class MetricsRegistry:
                         f"{name}: labelnames {tuple(labelnames)} != "
                         f"registered {existing.labelnames}")
                 buckets = kw.get("buckets")
-                if buckets is not None and tuple(sorted(
-                        float(b) for b in buckets)) != existing.buckets:
+                if buckets is not None and buckets is not existing._given \
+                        and tuple(sorted(
+                            float(b) for b in buckets)) != existing.buckets:
                     # silently observing into someone else's bounds would
                     # leave the caller's expected le series empty
                     raise ValueError(
@@ -377,6 +405,7 @@ class MetricsRegistry:
 
     def exposition(self) -> str:
         """Prometheus text format, trailing newline included."""
+        self._collect()
         with self._lock:
             metrics = [self._metrics[n] for n in sorted(self._metrics)]
         lines: List[str] = []
@@ -388,6 +417,7 @@ class MetricsRegistry:
         """JSON-able {name: metric.data()} dump of every registered metric
         — what :class:`~deeplearning4j_tpu.telemetry.federation.
         SnapshotWriter` persists and the aggregator merges."""
+        self._collect()
         with self._lock:
             metrics = [(n, self._metrics[n]) for n in sorted(self._metrics)]
         return {n: m.data() for n, m in metrics}
@@ -429,7 +459,107 @@ def gc_pause_seconds(since: float) -> float:
     return sum(s for end, s in list(_gc_recent) if end >= since)
 
 
-_default._gc_series()
+# -- CPU time by thread role ---------------------------------------------
+# Nothing writes this series on a hot path: whoever reads the process's
+# registry whole pays for one read of every live Python thread's CPU clock
+# (``clock_gettime``: one system call a thread, no ``/proc``; on
+# the benchmark's gVisor host a walk over the tasks' files cost 36-76 ms
+# and held a device-to-host copy up for up to 0.18 s, PR 50), and what
+# each thread gained since it was last seen is added to its role's counter.
+# A thread says its role by the prefix of its name; whoever makes the
+# thread registers the prefix (``register_thread_role``).
+
+_CPU_SERIES = "dl4j_tpu_process_thread_cpu_seconds_total"
+#: ``[prefix of threading.Thread.name, role]``, first match wins; a thread
+#: under no prefix is ``python_other``.  These are this package's own
+#: threads; other packages add theirs where they make them
+_thread_roles: List[Tuple[str, str]] = [
+    ("telemetry-", "telemetry"),   # snapshot writers, health, exports
+    ("metrics-retention", "telemetry"),        # telemetry/timeseries.py
+    ("otlp-exporter", "telemetry"),            # telemetry/otlp.py
+]
+_thread_lock = threading.Lock()
+#: thread -> ns of CPU when last seen; a thread that has ended is dropped
+_thread_seen: Dict[threading.Thread, int] = {}
+
+
+def register_thread_role(prefix: str, role: str) -> None:
+    """Threads whose name starts with ``prefix`` are booked under
+    ``role``; said once, by the module that makes them."""
+    if (prefix, role) not in _thread_roles:
+        _thread_roles.append((prefix, role))
+
+
+def thread_role(name: str) -> str:
+    """The role a Python thread of that name is booked under."""
+    for prefix, role in _thread_roles:
+        if name.startswith(prefix):
+            return role
+    return "python_other"
+
+
+def _cpu_clock_ns(thread: threading.Thread) -> Optional[int]:
+    """A Python thread's CPU clock, read from any thread: Linux's clock
+    id of a task, made from its kernel id (the id
+    ``pthread_getcpuclockid`` hands out, without going through a
+    ``pthread_t`` that may have gone stale: a task that has ended is a
+    plain EINVAL); None where it has ended or the platform has no such
+    clock."""
+    tid = thread.native_id
+    try:
+        return None if tid is None else time.clock_gettime_ns((~tid << 3) | 6)
+    except (AttributeError, OSError, OverflowError):
+        return None
+
+
+def _collect_thread_times(registry: "MetricsRegistry") -> None:
+    """The collect hook of the process's registry.  A registry that is no
+    longer the process's (a test swapped it out) books nothing: a
+    thread's gain is booked once.  The clocks are read under the lock
+    that guards what was last seen, so two readers at once cannot set an
+    older reading against a newer one."""
+    if registry is not _default:
+        return
+    gained: Dict[str, int] = {}
+    with _thread_lock:
+        threads = threading.enumerate()
+        for th in threads:
+            now = _cpu_clock_ns(th)
+            if now is None:
+                continue
+            was = _thread_seen.get(th, 0)
+            if now > was:
+                role = thread_role(th.name)
+                gained[role] = gained.get(role, 0) + now - was
+                _thread_seen[th] = now
+        for th in set(_thread_seen).difference(threads):
+            del _thread_seen[th]
+    if not gained:
+        return
+    cpu = registry.counter(
+        _CPU_SERIES,
+        "Seconds the process's Python threads ran on a CPU, by role, from "
+        "each thread's own CPU clock, added up when the registry is read: "
+        "serving_loop (a continuous batcher's loop thread), "
+        "serving_handler (the HTTP fronts' request threads), telemetry "
+        "(retention sampler, OTLP exporter, snapshot writers, health "
+        "monitor and probes), python_other.  The runtime's own threads "
+        "are no Python threads and are not counted; a thread that ends "
+        "between two reads loses what it gained since the first (a "
+        "request thread lives one connection: serving_handler reads "
+        "low); absent where the platform has no per-thread CPU clock",
+        labelnames=("role",))
+    for role, ns in gained.items():
+        cpu.inc(ns * 1e-9, role=role)
+
+
+def _process_series(registry: MetricsRegistry) -> None:
+    """Give ``registry`` the series that belong to the process."""
+    registry._gc_series()
+    registry.add_collect_hook(_collect_thread_times)
+
+
+_process_series(_default)
 gc.callbacks.append(_on_gc)
 
 
@@ -442,7 +572,7 @@ def set_registry(registry: MetricsRegistry) -> MetricsRegistry:
     """Swap the process-global registry (tests); returns the previous one."""
     global _default
     if registry._gcCells is None:
-        registry._gc_series()
+        _process_series(registry)
     with _default_lock:
         prev, _default = _default, registry
     return prev

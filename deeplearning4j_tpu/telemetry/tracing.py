@@ -44,17 +44,23 @@ class _Span:
     one costs several times as much)."""
 
     __slots__ = ("_tracer", "_name", "_observe", "_attrs", "_start",
-                 "_depth", "_id", "_ann", "seconds")
+                 "_cpu", "_depth", "_id", "_ann", "seconds")
 
-    def __init__(self, tr: "Tracer", name: str, observe, attrs: dict):
+    def __init__(self, tr: "Tracer", name: str, observe, attrs: dict,
+                 cpu: bool):
         self._tracer, self._name = tr, name
         self._observe, self._attrs = observe, attrs
+        #: the thread's CPU clock at the entry, for a span that asked for
+        #: it (``cpu=True``); None costs a span nothing
+        self._cpu: Optional[float] = 0.0 if cpu else None
         #: the region's duration, once it has been left
         self.seconds: Optional[float] = None
 
     def __enter__(self) -> dict:
         tr = self._tracer
         self._start = start = time.perf_counter()
+        if self._cpu is not None:
+            self._cpu = time.thread_time()
         self._ann = ann = TraceAnnotation(ANNOTATION_PREFIX + self._name)
         ann.__enter__()
         tr._track.depth += 1
@@ -72,12 +78,20 @@ class _Span:
         with tr._lock:
             tr._live.pop(self._id, None)
         self._ann.__exit__(None, None, None)
+        # the CPU clock is read inside the wall clock's two reads, so the
+        # thread cannot have run for longer than the region lasted
+        cpu = None if self._cpu is None else time.thread_time() - self._cpu
         self.seconds = seconds = time.perf_counter() - self._start
         self._attrs["depth"] = self._depth
+        if cpu is not None:
+            self._attrs["cpu_s"] = cpu
         tr.record_complete(self._name, self._start, seconds,
                            args=self._attrs)
         if self._observe is not None:
-            self._observe(seconds)
+            if cpu is None:
+                self._observe(seconds)
+            else:
+                self._observe(seconds, cpu)
 
 
 class Tracer:
@@ -97,7 +111,8 @@ class Tracer:
         self._next_span_id = 0
 
     # -- spans ------------------------------------------------------------
-    def span(self, name: str, observe: Optional[Callable] = None, **attrs):
+    def span(self, name: str, observe: Optional[Callable] = None,
+             cpu: bool = False, **attrs):
         """Time a nested region: ``with tracer().span(name) as args``.
         The body may add to ``args``; everything lands in the Chrome
         event's ``args``.  Entering reads the clock once and leaving reads
@@ -105,8 +120,16 @@ class Tracer:
         profiler annotation ``"dl4j." + name`` and — where the phase has
         a histogram — its one observation, ``observe(seconds)``: the
         three cannot disagree.  A body that raises still closes all
-        three."""
-        return _Span(self, name, observe, attrs)
+        three.
+
+        ``cpu=True`` (the decode loop's phases) reads the calling
+        thread's CPU clock (``time.thread_time()``) beside each of the
+        two: the seconds the thread RAN in the region land in the event's
+        ``args`` as ``cpu_s`` and the observer is called
+        ``observe(seconds, cpu_seconds)``; wall less CPU is the time the
+        thread did not run (it blocked, or it waited for the interpreter
+        or for a core)."""
+        return _Span(self, name, observe, attrs, cpu)
 
     def record_complete(self, name: str, start: float, duration: float,
                         args: Optional[dict] = None,

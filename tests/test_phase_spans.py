@@ -14,6 +14,14 @@ stood idle to its cause at the dispatch that ends it
 (``dl4j_tpu_serving_device_idle_seconds{model, cause}``, the Chrome event
 ``serving.device.idle``), a stall leaves ``serving.loop.stall``, and the
 collector's pauses are two process counters.
+
+The second clock (ISSUE 50): a loop phase also reads its thread's CPU clock
+(``cpu_s`` in the Chrome event, ``..._loop_phase_offcpu_seconds`` = wall
+less CPU), a streamed token's hand-off is booked by its consumer
+(``..._stream_token_seconds_total{stage}``, the Chrome event
+``serving.stream.queued``), and every Python thread's CPU time is added up
+by role whenever the registry is read
+(``dl4j_tpu_process_thread_cpu_seconds_total{role}``).
 """
 import gc
 import glob
@@ -24,6 +32,9 @@ import time
 import jax
 import numpy as np
 import pytest
+
+from test_cbatch import _by_hand, _run_out
+from tools import emit_cost, span_cost
 
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.fault import injection
@@ -46,13 +57,20 @@ pytestmark = pytest.mark.telemetry
 
 STEP_PHASES = ("grow", "upload", "dispatch", "fetch", "emit", "bookkeep")
 LOOP_HIST = "dl4j_tpu_serving_loop_phase_seconds"
+OFFCPU_HIST = "dl4j_tpu_serving_loop_phase_offcpu_seconds"
 IDLE_HIST = "dl4j_tpu_serving_device_idle_seconds"
+STREAM_SECONDS = "dl4j_tpu_serving_stream_token_seconds_total"
+STREAM_TOKENS = "dl4j_tpu_serving_stream_tokens_delivered_total"
+THREAD_CPU = "dl4j_tpu_process_thread_cpu_seconds_total"
 
 
 @pytest.fixture(autouse=True)
-def fresh_telemetry():
+def fresh_telemetry(monkeypatch):
     prev_reg = telemetry.set_registry(MetricsRegistry())
     prev_tr = set_tracer(Tracer())
+    # a CPU clock that costs nothing to read is read in every iteration
+    # (a loaded machine must not decide what these tests count)
+    monkeypatch.setattr(scheduler, "_cpu_clock_read_seconds", lambda: 0.0)
     yield
     set_tracer(prev_tr)
     telemetry.set_registry(prev_reg)
@@ -474,16 +492,22 @@ def test_a_stall_leaves_one_instant_with_what_it_coincided_with(slowdown):
     for st, e in zip(stalls, long[1:]):
         assert st["ph"] == "i" and st["tid"] == e["tid"]
         assert set(st["args"]) == {"replica", "phase", "seconds",
-                                   "gc_seconds", "threads", "queued"}
+                                   "gc_seconds", "offcpu_seconds",
+                                   "threads", "queued"}
         assert st["args"]["seconds"] == pytest.approx(e["dur"] * 1e-6,
                                                       abs=1e-5)
         assert st["args"]["threads"] >= 2 and st["args"]["queued"] == 0
         assert 0.0 <= st["args"]["gc_seconds"] <= st["args"]["seconds"]
-    # a loop phase of 0.1 s or more leaves one too; a wait slice does not
+        # a stretch is no one phase's: the phases inside it say theirs
+        assert st["args"]["offcpu_seconds"] is None
+    # a loop phase of 0.1 s or more leaves one too, with what of it the
+    # thread did not run for; a wait slice does not
     seen = len(_stalls())
-    cb._observePhase("emit", 0.2)
-    cb._observePhase("wait", 0.2)
-    assert [e["args"]["phase"] for e in _stalls()[seen:]] == ["emit"]
+    cb._observePhase("emit", 0.2, 0.05)
+    cb._observePhase("wait", 0.2, 0.0)
+    emit, = _stalls()[seen:]
+    assert emit["args"]["phase"] == "emit"
+    assert emit["args"]["offcpu_seconds"] == pytest.approx(0.15)
 
 
 def test_a_collection_raises_both_gc_series():
@@ -515,6 +539,454 @@ def test_a_collection_raises_both_gc_series():
     finally:
         telemetry.set_registry(prev)
     assert read()[1] == after[1]
+
+
+# ------------------------------------- the loop thread's second clock --
+
+@pytest.fixture(scope="module")
+def two_clocks():
+    """One stream of 12 tokens through a real loop, a registry and a
+    tracer of its own: both phase histograms' cells and the events."""
+    prev_reg = telemetry.set_registry(MetricsRegistry())
+    prev_tr = set_tracer(Tracer())
+    cb = ContinuousBatcher(_lm(), name="tc", maxSlots=2, pageSize=8)
+    cb._clock.every = 1             # whatever a read costs here
+    cb.start()
+    try:
+        _generate(cb, 12)
+        time.sleep(0.12)            # and a slice of waiting
+    finally:
+        cb.shutdown()
+        seen = {"wall": _cells(LOOP_HIST, "tc", "phase"),
+                "off": _cells(OFFCPU_HIST, "tc", "phase"),
+                "events": tracer().events()}
+        set_tracer(prev_tr)
+        telemetry.set_registry(prev_reg)
+    return seen
+
+
+@pytest.mark.parametrize("phase", SERVING_LOOP_PHASES)
+def test_both_phase_histograms_count_alike_and_offcpu_is_inside_wall(
+        two_clocks, phase):
+    (n_wall, wall), (n_off, off) = (two_clocks[k][phase]
+                                    for k in ("wall", "off"))
+    assert n_off == n_wall >= 1
+    assert 0.0 <= off <= wall
+    # the Chrome events of the phase carry the thread's CPU seconds, and
+    # wall less CPU over them is what the second histogram holds
+    evs = [e for e in two_clocks["events"]
+           if e["name"] == "serving.loop." + phase]
+    assert len(evs) == n_wall
+    assert all(0.0 <= e["args"]["cpu_s"] for e in evs)
+    assert sum(max(0.0, e["dur"] * 1e-6 - e["args"]["cpu_s"])
+               for e in evs) == pytest.approx(off, rel=1e-6, abs=1e-9)
+    if phase == "wait":
+        # the thread sleeps in it by design: off the CPU all but all of it
+        assert off >= 0.8 * wall
+
+
+def test_only_a_loop_phase_reads_the_cpu_clock(two_clocks):
+    by_name = {}
+    for e in two_clocks["events"]:
+        by_name.setdefault(e["name"], []).append("cpu_s" in e["args"])
+    phases = {"serving.loop." + p for p in SERVING_LOOP_PHASES}
+    assert phases <= set(by_name)
+    for name, has in by_name.items():
+        assert all(has) if name in phases else not any(has), name
+    assert {"serving.loop.iteration", "serving.decode.step",
+            "serving.prefill", "serving.state.write"} <= set(by_name)
+    # nor a span entered from any other thread, with an observer or not
+    seen = []
+    with tracer().span("elsewhere", observe=seen.append):
+        pass
+    ev, = [e for e in tracer().events() if e["name"] == "elsewhere"]
+    assert "cpu_s" not in ev["args"]
+    assert seen == [pytest.approx(ev["dur"] * 1e-6)]
+
+
+def _spin(seconds):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def _ran_late():
+    """Seconds this thread has been runnable and waited for a core, by
+    the kernel's books; 0.0 where it keeps none."""
+    try:
+        with open("/proc/self/task/%d/schedstat"
+                  % threading.get_native_id()) as f:
+            return int(f.read().split()[1]) * 1e-9
+    except (OSError, ValueError, IndexError):
+        return 0.0
+
+
+@pytest.mark.parametrize("body, seconds, low, high", [
+    (time.sleep, 0.05, 0.8, 1.0),   # a phase that sleeps: off the CPU
+    (_spin, 0.01, 0.0, 0.2),        # a phase that spins: on it
+], ids=["sleep", "spin"])
+def test_offcpu_tells_a_phase_that_waits_from_one_that_runs(
+        body, seconds, low, high):
+    cb = span_cost.loop_thread()    # a phase needs no model
+    cb.name = "oc"
+    shares = []
+    for _ in range(10):             # a busy machine may take a try's core:
+        late = _ran_late()          # what it waited for one is the machine's
+        with cb._phase("emit"):
+            body(seconds)
+        late = _ran_late() - late
+        (n, wall), (m, off) = (_cells(h, "oc", "phase")["emit"]
+                               for h in (LOOP_HIST, OFFCPU_HIST))
+        assert n == m == len(shares) + 1 and wall >= seconds * n
+        assert off <= wall
+        ev = [e for e in tracer().events()
+              if e["name"] == "serving.loop.emit"][-1]
+        dur = ev["dur"] * 1e-6
+        shares.append(max(0.0, dur - ev["args"]["cpu_s"] - late) / dur)
+        if low <= shares[-1] <= high:
+            break
+    assert low <= shares[-1] <= high, shares
+
+
+def test_a_dear_cpu_clock_is_read_in_one_iteration_of_some(monkeypatch):
+    # 3.2 us a read (a sandbox between the thread and its kernel): the
+    # clock is read in every fourth iteration, whole iterations of it
+    monkeypatch.setattr(scheduler, "_cpu_clock_read_seconds",
+                        lambda: 3.2e-6)
+    cb = ContinuousBatcher(_lm(), name="dear", maxSlots=2,
+                           pageSize=8).start()
+    try:
+        assert cb._clock.every == 4
+        _generate(cb, 30)
+    finally:
+        cb.shutdown()
+    wall, off = (_cells(h, "dear", "phase") for h in (LOOP_HIST,
+                                                      OFFCPU_HIST))
+    its = wall["admit"][0]
+    assert off["admit"][0] == its // 4
+    for p in STEP_PHASES:
+        assert its // 4 - 1 <= off[p][0] <= its // 4 < wall[p][0]
+    # a step that was read was read whole: a mean a step is a mean
+    assert off["fetch"][0] == off["emit"][0] == off["bookkeep"][0]
+    read = [e for e in tracer().events()
+            if e["name"].startswith("serving.loop.")
+            and "cpu_s" in e.get("args", {})]
+    assert len(read) == sum(n for p, (n, _s) in off.items())
+
+
+def test_a_coarse_cpu_clock_still_adds_up_phase_by_phase():
+    clock = scheduler._LoopClock()
+    # a clock that moves 10 ms at a time under phases of 1 and 3 ms: emit
+    # is on the CPU all the time, bookkeep a third of it
+    ticks = {"emit": [0.0] * 9 + [0.01], "bookkeep": [0.0, 0.01] + [0.0] * 8}
+    off = {"emit": [], "bookkeep": []}
+    for _ in range(5):
+        for i in range(10):
+            off["emit"].append(clock.offcpu("emit", 0.001, ticks["emit"][i]))
+            off["bookkeep"].append(
+                clock.offcpu("bookkeep", 0.003, ticks["bookkeep"][i]))
+    assert all(0.0 <= v <= 0.001 for v in off["emit"])
+    assert all(0.0 <= v <= 0.003 for v in off["bookkeep"])
+    # wall less off-CPU is the CPU time read, up to the tick in hand
+    assert 0.05 - sum(off["emit"]) == pytest.approx(0.05 - 0.009, abs=1e-9)
+    assert 0.15 - sum(off["bookkeep"]) == pytest.approx(0.05, abs=1e-9)
+    # and a clock that counts nanoseconds keeps no credit: wall less CPU
+    assert clock.offcpu("grow", 0.002, 0.0005) == pytest.approx(0.0015)
+    assert clock.offcpu("grow", 0.002, 0.002) == 0.0
+    assert clock.offcpu("grow", 0.002, 0.0) == 0.002
+
+
+# -------------------------------------------------- the token's hand-off --
+
+def _stream_counts(model):
+    reg = get_registry()
+    seconds = reg.get(STREAM_SECONDS)
+    return {"tokens": reg.get(STREAM_TOKENS).value(model=model),
+            "queued": seconds.value(model=model, stage="queued"),
+            "write": seconds.value(model=model, stage="write")}
+
+
+@pytest.mark.parametrize("how", ["finished", "cancelled", "replayed"])
+def test_tokens_delivered_are_the_tokens_a_consumer_took(how):
+    quota = 40                      # over a flush of 32 and a tail
+    ref = _lm().generate(np.asarray([[1, 2, 3]], np.int32), quota)[0]
+    cb = _by_hand(_lm(), "hd-" + how, maxSlots=2)
+    try:
+        gen = cb.submitStream({"tokens": [1, 2, 3], "maxNewTokens": quota})
+        seq = cb._queue[-1]
+        take = 35 if how == "cancelled" else quota
+        if how == "replayed":
+            for _ in range(9):
+                cb._iterate()
+            assert 0 < seq.streamed < quota
+            cb._preempt(cb._slotSeq.index(seq))
+        if how == "cancelled":
+            while seq.streamed <= take:
+                cb._iterate()
+            got = [next(gen) for _ in range(take)]
+            gen.close()             # the client hung up: the loop learns
+            assert seq.cancelled    # it at its next step
+            _run_out(cb)
+            assert seq.streamed > take      # put for nobody: not delivered
+        else:
+            _run_out(cb)
+            got = list(gen)         # the end's sentinel is no token
+            assert seq.streamed == quota and seq.streamSkip == 0
+            assert seq.restarts == (how == "replayed")
+        assert got == ref[:take].tolist()
+        counts = _stream_counts(cb.name)
+        assert counts["tokens"] == take
+        assert counts["queued"] > 0.0 and counts["write"] > 0.0
+    finally:
+        cb.shutdown()
+
+
+def test_a_slow_consumers_time_is_write_and_the_next_tokens_lie_in_queued():
+    quota, nap = 4, 0.05
+    cb = _by_hand(_lm(), "slow", maxSlots=2)
+    try:
+        gen = cb.submitStream({"tokens": [1, 2, 3], "maxNewTokens": quota,
+                               "keepAliveSeconds": 0.01})
+        # a keep-alive while nothing has been computed counts nothing
+        assert not isinstance(next(gen), int)
+        _run_out(cb)                # all four lie in the queue from here
+        t0 = time.perf_counter()
+        got = []
+        for tok in gen:
+            got.append(tok)
+            time.sleep(nap)         # a client that reads slowly
+        elapsed = time.perf_counter() - t0
+        assert len(got) == quota
+        counts = _stream_counts("slow")
+        assert counts["tokens"] == quota
+        # every token's consumer slept before it asked for the next
+        assert quota * nap <= counts["write"] <= elapsed
+        # and token i lay there through the i naps before it
+        lay = nap * sum(range(quota))
+        assert lay <= counts["queued"] <= lay + elapsed
+        evs = _spans("serving.stream.queued")
+        # the others lay 10 ms or more; the first only for the steps
+        # that followed its own
+        assert quota - 1 <= len(evs) <= quota
+        assert all(e["args"] == {"replica": "slow", "row": 0} for e in evs)
+        assert sum(e["dur"] for e in evs) * 1e-6 <= counts["queued"]
+        # on the consumer's track, which is this thread's and no loop's
+        with tracer().span("here"):
+            pass
+        assert {e["tid"] for e in evs} == {_spans("here")[0]["tid"]}
+    finally:
+        cb.shutdown()
+
+
+# ------------------------------------------------------- CPU, by role --
+
+def _role_seconds():
+    """{role: seconds} off a whole read of the registry (the read is what
+    adds the threads' gains up)."""
+    data = get_registry().snapshot().get(THREAD_CPU)
+    if data is None:
+        return {}
+    return {key[0]: v for key, v in data["cells"]}
+
+
+def _burn(cpu_seconds, then=lambda: None):
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < cpu_seconds:
+        pass
+    then()
+
+
+@pytest.mark.parametrize("name, role", [
+    ("cbatch-lm", "serving_loop"),
+    ("serving-handler-7", "serving_handler"),
+    ("telemetry-snapshot-h0", "telemetry"),
+    ("metrics-retention", "telemetry"),
+    ("replica-probe-r0", "telemetry"),
+    ("Thread-9 (worker)", "python_other"),
+    ("wedge-reap-cbatch-lm", "python_other"),
+])
+def test_a_threads_cpu_is_booked_under_the_role_its_name_says(name, role):
+    assert telemetry.thread_role(name) == role
+    before = _role_seconds()
+    th = threading.Thread(target=_burn, args=(0.2,), name=name)
+    th.start()
+    th.join(30)
+    assert not th.is_alive()
+    # it has ended, but not yet been looked for: found gone, its gain is
+    # lost, and nothing falls
+    lost = _role_seconds()
+    assert all(lost[r] >= before.get(r, 0.0) for r in lost)
+    live = threading.Event()
+    th = threading.Thread(target=_burn, args=(0.2, lambda: live.wait(30)),
+                          name=name)
+    th.start()
+    try:
+        deadline = time.monotonic() + 30
+        while _role_seconds().get(role, 0.0) - lost.get(role, 0.0) < 0.15:
+            assert time.monotonic() < deadline, "its 0.2 s never showed"
+            time.sleep(0.02)
+    finally:
+        live.set()
+        th.join(30)
+    after = _role_seconds()
+    # its 0.2 s and, under python_other, this thread's polling
+    assert 0.15 <= after[role] - lost.get(role, 0.0) <= 0.3 + (
+        0.3 if role == "python_other" else 0.0)
+    # monotone: what a dead thread had booked stays
+    ended = _role_seconds()
+    assert all(ended[r] >= after[r] for r in after)
+
+
+@pytest.mark.parametrize("prefix, role, name, want", [
+    ("etl-worker-", "etl", "etl-worker-3", "etl"),      # an owner's own
+    ("cbatch-", "elsewhere", "cbatch-lm", "serving_loop"),  # first said wins
+    ("telemetry-", "telemetry", "telemetry-x", "telemetry"),    # said twice
+], ids=["new", "taken", "again"])
+def test_whoever_makes_a_thread_registers_its_role(prefix, role, name, want,
+                                                   monkeypatch):
+    roles = telemetry.registry._thread_roles
+    monkeypatch.setattr(telemetry.registry, "_thread_roles", list(roles))
+    telemetry.register_thread_role(prefix, role)
+    telemetry.register_thread_role(prefix, role)
+    said = telemetry.registry._thread_roles
+    assert said.count((prefix, role)) == 1
+    assert len(said) == len(roles) + ((prefix, role) not in roles)
+    assert telemetry.thread_role(name) == want
+
+
+@pytest.fixture
+def burnt():
+    """A live thread named for the loop's role that has burnt 0.2 s of
+    CPU and now waits, and the role's seconds before it was started."""
+    before = _role_seconds().get("serving_loop", 0.0)
+    done, live = threading.Event(), threading.Event()
+    th = threading.Thread(
+        target=_burn, args=(0.2, lambda: (done.set(), live.wait(30))),
+        name="cbatch-burnt")
+    th.start()
+    try:
+        assert done.wait(30)
+        yield th, before
+    finally:
+        live.set()
+        th.join(30)
+
+
+def test_two_readers_at_once_book_a_threads_cpu_once(burnt, monkeypatch):
+    th, before = burnt
+    read = telemetry.registry._cpu_clock_ns
+    second = []
+
+    def slow(thread):
+        # the first reader, at that thread's clock, lets a second reader
+        # start and run into it
+        if thread is th and not second:
+            second.append(threading.Thread(target=get_registry().snapshot))
+            second[0].start()
+            time.sleep(0.05)
+        return read(thread)
+
+    monkeypatch.setattr(telemetry.registry, "_cpu_clock_ns", slow)
+    get_registry().snapshot()
+    second[0].join(30)
+    gained = get_registry().get(THREAD_CPU).value(role="serving_loop") \
+        - before
+    assert 0.19 <= gained <= 0.3
+
+
+def test_a_reading_older_than_the_last_books_nothing(burnt, monkeypatch):
+    th, before = burnt
+    read = telemetry.registry._cpu_clock_ns
+    _role_seconds()                 # seen once, at its 0.2 s
+    at = read(th)
+    readings = iter([at + 120_000_000, at + 100_000_000, at + 130_000_000])
+    monkeypatch.setattr(telemetry.registry, "_cpu_clock_ns",
+                        lambda t: next(readings) if t is th else read(t))
+    gained = []
+    for _ in range(3):
+        gained.append(_role_seconds()["serving_loop"] - before)
+    base = gained[0] - 0.12
+    assert 0.19 <= base <= 0.3
+    assert [g - base for g in gained] == pytest.approx([0.12, 0.12, 0.13])
+
+
+def test_a_thread_without_a_cpu_clock_is_passed_over(burnt, monkeypatch):
+    th, before = burnt
+    read = telemetry.registry._cpu_clock_ns
+    monkeypatch.setattr(telemetry.registry, "_cpu_clock_ns",
+                        lambda t: None if t is th else read(t))
+    assert _role_seconds().get("serving_loop", 0.0) == before
+    # a platform with no such clock at all: the call raises, nothing read
+    monkeypatch.undo()
+    monkeypatch.delattr(time, "clock_gettime_ns")
+    assert read(th) is None
+
+
+def test_the_http_fronts_request_threads_say_their_role():
+    from deeplearning4j_tpu.remote.server import RequestThreadsHTTPServer
+    from http.server import BaseHTTPRequestHandler
+    import urllib.request
+    names = []
+
+    class Handler(BaseHTTPRequestHandler):
+        def log_message(self, *a):
+            pass
+
+        def do_GET(self):
+            names.append(threading.current_thread().name)
+            self.send_response(204)
+            self.end_headers()
+
+    httpd = RequestThreadsHTTPServer(("127.0.0.1", 0), Handler)
+    th = threading.Thread(target=httpd.serve_forever, daemon=True)
+    th.start()
+    try:
+        url = "http://127.0.0.1:%d/" % httpd.server_address[1]
+        with urllib.request.urlopen(url, timeout=10) as resp:
+            assert resp.status == 204
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+        th.join(10)
+    assert len(names) == 1
+    assert telemetry.thread_role(names[0]) == "serving_handler"
+
+
+def test_a_registry_that_is_not_the_processs_books_no_thread():
+    mine = get_registry()
+    other = MetricsRegistry()
+    prev = telemetry.set_registry(other)    # the hook is other's too now
+    telemetry.set_registry(prev)
+    assert prev is mine
+    other.snapshot()
+    assert other.get(THREAD_CPU) is None
+    # a hook runs at the top of both whole reads, once each
+    calls = []
+    other.add_collect_hook(calls.append)
+    other.add_collect_hook(calls.append)
+    other.snapshot()
+    other.exposition()
+    assert calls == [other, other]
+
+
+# -------------------------------------- what one step's delivery costs --
+
+def test_the_per_sink_cost_harness_runs():
+    out = emit_cost.measure(slots=4, steps=6, rounds=1)
+    assert out["consumers_ended"]
+    assert set(out["sink_us_a_token"]) == set(emit_cost.SINKS)
+    for variant in ("all",) + emit_cost.SINKS:
+        # counts only: a time means something on the chip's host alone
+        assert out[variant]["wall_us_a_token"] > 0.0
+        assert 0.0 <= out[variant]["cpu_us_a_token"]
+    # the stubs took their sinks out and put them back
+    from deeplearning4j_tpu.telemetry import (observe_exemplar,
+                                              timeline_store)
+    assert scheduler.observe_exemplar is observe_exemplar
+    assert scheduler.timeline_store is timeline_store
+    assert get_registry().get(
+        "dl4j_tpu_serving_decode_tokens_total").value(model="m") == \
+        4 * 7 * 4                   # every variant but its own stub
 
 
 # ------------------------------------------------ h2d in every fit path --
